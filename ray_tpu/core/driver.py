@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import atexit
 import os
+import tempfile
 import time
 import uuid
 from typing import Optional
@@ -53,7 +54,7 @@ class DriverRuntime:
                 if prev:
                     session_id = prev
             self.session_dir = os.path.join(
-                "/tmp/ray_tpu", f"session-{session_id}")
+                tempfile.gettempdir(), "ray_tpu", f"session-{session_id}")
             os.makedirs(self.session_dir, exist_ok=True)
             node_res = node_resources_from_env(num_cpus, num_tpus, resources)
             self.control = ControlServer(

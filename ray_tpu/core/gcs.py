@@ -14,10 +14,13 @@ Design deviations from the reference, deliberate for the TPU-first rebuild:
     layered on in the multi-host control plane.
   - Scheduling is event-driven FIFO + resource fit over one node; the
     hybrid pack/spread policy slot is where multi-node placement goes.
-  - TPU chips are scheduled like GPUs in the reference
-    (resource vector entries) but workers granted TPU get exclusive chip
-    visibility via TPU_VISIBLE_CHIPS/JAX_PLATFORMS env, because on TPU a
-    chip belongs to exactly one process (no MPS-style sharing).
+  - TPU chips are scheduled like GPUs in the reference (resource
+    vector entries).  On TPU a chip belongs to exactly one process (no
+    MPS-style sharing), but chip VISIBILITY is not wired yet: a worker
+    granted TPU inherits the driver's environment and sees every chip
+    of the host (accelerators/tpu.py get_visibility_env has no caller,
+    nothing sets TPU_VISIBLE_CHIPS at spawn).  One TPU worker per host
+    at a time is what works today; see ROADMAP.md.
 """
 
 from __future__ import annotations

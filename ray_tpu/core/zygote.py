@@ -17,7 +17,8 @@ in the template, so XLA client threads/devices are created per-child,
 after the fork, honoring each worker's own XLA_FLAGS.
 
 Workers whose spawn genuinely needs a fresh exec — container runtime
-envs (chroot wrapper) and TPU-visible workers (sitecustomize path) —
+envs (chroot wrapper) and TPU-class workers (full site init, the
+driver's own environment) —
 keep the subprocess.Popen path in node_manager.spawn_worker_process.
 """
 

@@ -27,32 +27,22 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import dispatch
+
 _NEG_INF = float(-1e30)
-
-
-def _interpret_mode() -> bool:
-    return os.environ.get("RAY_TPU_PALLAS_INTERPRET", "") in ("1", "true")
-
-
-def _platform() -> str:
-    try:
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
 
 
 def _can_use_pallas(seq_q: int, seq_k: int, head_dim: int,
                     block_q: int, block_k: int) -> bool:
-    if _interpret_mode():
+    if dispatch.interpret_mode():
         return seq_q % block_q == 0 and seq_k % block_k == 0
     return (
-        _platform() == "tpu"
+        dispatch.platform() == "tpu"
         and seq_q % block_q == 0
         and seq_k % block_k == 0
         and head_dim % 64 == 0
@@ -194,7 +184,7 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, sq), jnp.float32),
         ],
-        interpret=_interpret_mode(),
+        interpret=dispatch.interpret_mode(),
     )(offs, qf, kf, vf)
     out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     return out, lse[:, 0, :]  # lse: [bh, sq]
@@ -366,7 +356,7 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
                                    lambda g, i, offs: (g, i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        interpret=_interpret_mode(),
+        interpret=dispatch.interpret_mode(),
     )(offs, qf, kf, vf, dof, lse8, delta8, dlse8)
 
     dk, dv = pl.pallas_call(
@@ -390,7 +380,7 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
-        interpret=_interpret_mode(),
+        interpret=dispatch.interpret_mode(),
     )(offs, qf, kf, vf, dof, lse8, delta8, dlse8)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
@@ -465,15 +455,42 @@ def flash_attention(q, k, v, causal: bool = True,
     back to the jnp reference elsewhere.  Heads must already be expanded
     (GQA repeat happens in the model).  When sq < sk the windows are
     end-aligned (decode convention), matching attention_reference.
+
+    Under an ambient multi-device mesh (jax.sharding.set_mesh) the kernel
+    runs per shard inside a shard_map — batch over the data/fsdp axes,
+    heads over the tensor axis (parallel/sharding.DEFAULT_RULES): GSPMD
+    cannot partition a Mosaic kernel itself.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     sq, sk, d = q.shape[1], k.shape[1], q.shape[-1]
     bq = min(block_q, sq)
     bk = min(block_k, sk)
-    if _can_use_pallas(sq, sk, d, bq, bk):
+    if not _can_use_pallas(sq, sk, d, bq, bk):
+        dispatch.record("flash_attention", "xla")
+        return attention_reference(q, k, v, causal, sm_scale)
+    dispatch.record("flash_attention", "interpret"
+                    if dispatch.interpret_mode() else "pallas")
+
+    def kernel(q, k, v):
         out, _ = flash_attention_chunk(
             q, k, v, sk - sq, 0, causal=causal, sm_scale=sm_scale,
             block_q=bq, block_k=bk)
         return out
-    return attention_reference(q, k, v, causal, sm_scale)
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty or mesh.size == 1:
+        return kernel(q, k, v)
+    from jax.sharding import PartitionSpec as P
+
+    # An axis shards a dim only where it divides it; otherwise that dim
+    # is computed replicated (small eval batches on a wide mesh).
+    sizes = dict(mesh.shape)
+    batch = tuple(a for a in ("data", "fsdp") if a in sizes)
+    if q.shape[0] % math.prod(sizes[a] for a in batch):
+        batch = ()
+    heads = "tensor" if ("tensor" in sizes
+                         and q.shape[2] % sizes["tensor"] == 0) else None
+    spec = P(batch or None, None, heads, None)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

@@ -44,16 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _platform() -> str:
-    try:
-        return jax.devices()[0].platform
-    except Exception:  # noqa: BLE001
-        return "cpu"
-
-
-def _interpret_mode() -> bool:
-    return os.environ.get("RAY_TPU_PALLAS_INTERPRET", "") == "1"
+from ray_tpu.ops import dispatch
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
@@ -73,14 +64,17 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     KVH = KD // D
     W = block_tables.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
-    on_tpu = _platform() == "tpu"
+    on_tpu = dispatch.platform() == "tpu"
     # Kernel tiling constraints: fused row must fill whole lanes and a
     # page must cover the bf16 sublane tile.
     kernel_ok = (KD % 128 == 0 and H % KVH == 0 and page % 8 == 0)
-    if (on_tpu or _interpret_mode()) and kernel_ok:
+    if (on_tpu or dispatch.interpret_mode()) and kernel_ok:
+        dispatch.record("paged_attention",
+                        "pallas" if on_tpu else "interpret")
         return _paged_attention_pallas(
             q, k_pages, v_pages, block_tables, context_lens, scale,
             interpret=not on_tpu)
+    dispatch.record("paged_attention", "xla")
     return _paged_attention_gather(
         q, k_pages, v_pages, block_tables, context_lens, scale)
 
@@ -549,6 +543,10 @@ def write_token_rows(k_pages, v_pages, k_new, v_new, block_tables,
         kn, vn = _dup_tail(kn), _dup_tail(vn)
         B = Bp
     grid = (B // SB,)
+    # There is no XLA formulation of this write (see above), so off TPU
+    # the same kernel runs interpreted.
+    on_tpu = dispatch.platform() == "tpu"
+    dispatch.record("write_token_rows", "pallas" if on_tpu else "interpret")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=grid,
@@ -577,7 +575,7 @@ def write_token_rows(k_pages, v_pages, k_new, v_new, block_tables,
         # Indices count every positional operand including the three
         # scalar-prefetch arrays: 3 = k_pages -> out 0, 4 = v_pages.
         input_output_aliases={3: 0, 4: 1},
-        interpret=_platform() != "tpu",
+        interpret=not on_tpu,
     )
     return kernel(pages, strips, rows, k_pages, v_pages, kn, vn)
 

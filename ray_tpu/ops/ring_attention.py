@@ -145,7 +145,6 @@ def ring_attention(q, k, v, mesh=None, causal: bool = True,
     sharded over ``batch_axes``, heads over ``heads_axis``; the sequence
     axis rotates K/V chunks around the ring.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if mesh is None:
@@ -160,6 +159,6 @@ def ring_attention(q, k, v, mesh=None, causal: bool = True,
 
     fn = functools.partial(
         ring_attention_sharded, axis_name=seq_axis, causal=causal)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)(q, k, v)
+        check_vma=False)(q, k, v)

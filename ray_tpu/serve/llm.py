@@ -18,11 +18,12 @@ import time
 from typing import Any, Dict, List, Optional, Sequence
 
 from ray_tpu.models import transformer as tfm
+from ray_tpu.ops import dispatch
 from ray_tpu.serve.deployment import deployment
 from ray_tpu.serve import llm_engine as _eng
 from ray_tpu.serve.llm_engine import (PrefixCache,
                                       RequestShed, _env_float, _env_int)
-from ray_tpu.util import flight_recorder, tracing
+from ray_tpu.util import device_stats, flight_recorder, tracing
 
 
 def _request_trace() -> Optional[tuple]:
@@ -58,11 +59,11 @@ class LLMServer:
                  max_batch: int = 8, **engine_kwargs):
         """Extra engine knobs pass through to LLMEngine (multi_step,
         pipeline_depth, enable_prefix_caching, speculative_k, ...).
-        TPU serving guidance (measured, DECODE_BENCH_r04): page_size
-        >= 64 — the decode kernel streams one fused-head page per DMA,
-        so tiny pages are latency-bound — and multi_step 16-32 with the
-        default pipelined dispatch keeps the chip busy while bounding
-        admission latency; the tiny defaults here suit CPU tests."""
+        TPU serving guidance: page_size >= 64 — the decode kernel
+        streams one fused-head page per DMA, so tiny pages are
+        latency-bound — and multi_step 16-32 with the default pipelined
+        dispatch keeps the chip busy while bounding admission latency;
+        the tiny defaults here suit CPU tests."""
         import threading
 
         if config is None:
@@ -78,6 +79,10 @@ class LLMServer:
         self.engine = LLMEngine(
             config, params, page_size=page_size, num_pages=num_pages,
             max_batch=max_batch, **engine_kwargs)
+        # The device THIS replica holds, as its own jax reports it: a
+        # replica deployed without num_tpus serves on the host CPU, and
+        # only the replica can say which it got.
+        self._device = device_stats.backend_info()
         self._cv = threading.Condition()
         self._results: Dict[int, List[int]] = {}
         self._shed: Dict[int, str] = {}
@@ -391,6 +396,12 @@ class LLMServer:
                 "kv_exports": eng.kv_exports,
                 "kv_imports": eng.kv_imports,
                 "handoff_fallbacks": self.handoff_fallbacks,
+                "device": self._device,
+                # {op: {path: times traced}} — which attention ops took
+                # the Pallas kernels, ran interpreted, or fell to XLA.
+                "kernels": dispatch.taken(),
+                "hbm_peak_bytes": int((device_stats.memory_stats() or {})
+                                      .get("peak_bytes_in_use", 0)),
             }
             if eng.prefix_cache is not None:
                 # Compact hot-prefix digest: rides the load report so

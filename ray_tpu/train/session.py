@@ -103,11 +103,12 @@ class _TrainSession:
             else:
                 return
             if prev is not None and now > prev:
-                tracing.record_span(
-                    "device.step", prev, now,
-                    attributes={"plane": "train",
-                                "roofline_fraction": round(frac, 5),
-                                "mfu": round(mfu, 5)})
+                attrs = {"plane": "train"}
+                if frac is not None:  # None: device peaks unknown
+                    attrs["roofline_fraction"] = round(frac, 5)
+                    attrs["mfu"] = round(mfu, 5)
+                tracing.record_span("device.step", prev, now,
+                                    attributes=attrs)
         except Exception:  # raylint: allow-swallow(telemetry must never fail a train step report)
             pass
 

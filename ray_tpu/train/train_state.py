@@ -121,7 +121,7 @@ class ShardedTrainStep:
         self.param_shardings = tree_shardings(
             mesh, self.param_logical, rules)
         self.batch_sharding = data_sharding(mesh)
-        self._params_treedef = jax.tree.structure(self.param_logical)
+        self._params_treedef = jax.tree.structure(self.param_shardings)
 
         self._init = jax.jit(self._init_fn)
         self._step = jax.jit(self._step_fn, donate_argnums=(0,))
@@ -137,8 +137,15 @@ class ShardedTrainStep:
         return {"params": params, "opt_state": opt_state,
                 "step": jnp.zeros((), jnp.int32)}
 
+    def _mesh_scope(self):
+        """The mesh as the ambient abstract mesh: activation constraints
+        by logical name, ring attention's "auto" and the flash kernel's
+        shard_map all read jax.sharding.get_abstract_mesh(), which a
+        bare `with mesh:` no longer sets."""
+        return jax.sharding.set_mesh(self.mesh)
+
     def init(self, rng):
-        with self.mesh:
+        with self._mesh_scope():
             return self._init(rng)
 
     # -- step ---------------------------------------------------------------
@@ -164,7 +171,7 @@ class ShardedTrainStep:
 
     def step(self, state, batch):
         batch = jax.device_put(batch, self.batch_sharding)
-        with self.mesh:
+        with self._mesh_scope():
             return self._step(state, batch)
 
     # -- eval ----------------------------------------------------------------
@@ -177,5 +184,5 @@ class ShardedTrainStep:
 
     def eval_step(self, params, batch):
         batch = jax.device_put(batch, self.batch_sharding)
-        with self.mesh:
+        with self._mesh_scope():
             return self._eval(params, batch)

@@ -146,26 +146,42 @@ def memory_stats() -> Optional[Dict[str, Any]]:
         return None
 
 
-# Per-device-kind peak specs: (HBM bytes/s, dense peak FLOP/s).  The
-# bandwidth column matches scripts/bench_decode.py's roofline table;
-# RAY_TPU_DEVICE_HBM_GBPS / RAY_TPU_DEVICE_PEAK_TFLOPS override both
-# (required for meaningful numbers on CPU hosts).
-_PEAK_SPECS = {
+# Peak rates of one chip by jax `device_kind`: (HBM bytes/s, dense bf16
+# FLOP/s).  Source: Google Cloud TPU documentation, system-architecture
+# pages for v4, v5e ("TPU v5 lite"), v5p and v6e ("TPU v6 lite").  THE
+# one table: bench.py and scripts/bench_decode.py read it too.  A kind
+# that is not here has no assumed peak — RAY_TPU_DEVICE_HBM_GBPS /
+# RAY_TPU_DEVICE_PEAK_TFLOPS supply one explicitly (CPU hosts, tests).
+PEAK_SPECS = {
+    "TPU v4": (1228e9, 275e12),
     "TPU v5 lite": (819e9, 197e12),
     "TPU v5": (2765e9, 459e12),
-    "TPU v4": (1228e9, 275e12),
+    "TPU v5p": (2765e9, 459e12),
+    "TPU v6 lite": (1640e9, 918e12),
 }
-_DEFAULT_SPECS = (819e9, 197e12)
 
 
-def peak_specs() -> Tuple[float, float]:
-    """(hbm_bytes_per_s, peak_flops_per_s) for the local backend."""
+def peak_specs_for(device_kind: str) -> Tuple[float, float]:
+    """(hbm_bytes_per_s, peak_flops_per_s) of a known device kind; an
+    unknown kind is a KeyError — benchmarks must not assume a peak."""
+    try:
+        return PEAK_SPECS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak rates recorded for device kind {device_kind!r}; "
+            f"known: {sorted(PEAK_SPECS)}") from None
+
+
+def peak_specs() -> Optional[Tuple[float, float]]:
+    """(hbm_bytes_per_s, peak_flops_per_s) for the local backend, or
+    None when its kind is unknown and no override supplies both."""
     hbm = _env_float("RAY_TPU_DEVICE_HBM_GBPS", 0.0) * 1e9
     tf = _env_float("RAY_TPU_DEVICE_PEAK_TFLOPS", 0.0) * 1e12
     if hbm and tf:
         return hbm, tf
-    kind = backend_info().get("device_kind", "")
-    spec = _PEAK_SPECS.get(kind, _DEFAULT_SPECS)
+    spec = PEAK_SPECS.get(backend_info().get("device_kind", ""))
+    if spec is None:
+        return None
     return (hbm or spec[0], tf or spec[1])
 
 
@@ -400,34 +416,37 @@ def note_step(*, tokens_per_s: float, bytes_per_token: float,
     `bytes_per_token` / `flops_per_token` are the MODELED per-token
     traffic and compute (same terms bench_decode uses offline:
     weights + live KV for bytes, 2*params for flops).  Returns
-    (roofline_fraction, mfu)."""
+    (roofline_fraction, mfu) — (None, None), and no fraction in the
+    step record or the gauges, when the device's peaks are unknown."""
     global _last_step
     if not _enabled:
         return 0.0, 0.0
-    peak_bw, peak_flops = peak_specs()
-    achieved_bytes_s = tokens_per_s * max(0.0, bytes_per_token)
-    achieved_flops_s = tokens_per_s * max(0.0, flops_per_token)
-    frac = achieved_bytes_s / peak_bw if peak_bw else 0.0
-    mfu = achieved_flops_s / peak_flops if peak_flops else 0.0
     step = {
         "kind": "step", "ts": time.time(), "plane": plane,
         "tokens_per_s": round(tokens_per_s, 2),
         "bytes_per_token": int(bytes_per_token),
         "flops_per_token": int(flops_per_token),
-        "roofline_fraction": round(frac, 5),
-        "mfu": round(mfu, 5),
     }
+    frac = mfu = None
+    specs = peak_specs()
+    if specs is not None:
+        peak_bw, peak_flops = specs
+        frac = tokens_per_s * max(0.0, bytes_per_token) / peak_bw
+        mfu = tokens_per_s * max(0.0, flops_per_token) / peak_flops
+        step["roofline_fraction"] = round(frac, 5)
+        step["mfu"] = round(mfu, 5)
     if extra:
         step.update(extra)
     with _lock:
         _last_step = step
-    try:
-        m = _metrics()
-        m[1].set(frac, tags={"plane": plane})
-        m[2].set(mfu, tags={"plane": plane})
-    except Exception as exc:
-        warn_once(logger, "device-metrics", exc,
-                  "could not update device metrics")
+    if specs is not None:
+        try:
+            m = _metrics()
+            m[1].set(frac, tags={"plane": plane})
+            m[2].set(mfu, tags={"plane": plane})
+        except Exception as exc:
+            warn_once(logger, "device-metrics", exc,
+                      "could not update device metrics")
     _journal(step)
     return frac, mfu
 
@@ -474,9 +493,9 @@ def profile_fields() -> Dict[str, Any]:
             out["recompiles"] = rec
         ls = last_step()
         if ls:
-            out["roofline_fraction"] = ls["roofline_fraction"]
-            out["mfu"] = ls["mfu"]
-            out["tokens_per_s"] = ls["tokens_per_s"]
+            for key in ("roofline_fraction", "mfu", "tokens_per_s"):
+                if key in ls:  # no fraction when the peaks are unknown
+                    out[key] = ls[key]
         led_frac = _watermark_fraction
         if led_frac:
             out["hbm_watermark_fraction"] = round(led_frac, 4)

@@ -14,8 +14,6 @@ os.environ.setdefault("RAY_TPU_CHIPS", "none")
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 import time  # noqa: E402
 
 import numpy as np  # noqa: E402

@@ -10,8 +10,6 @@ os.environ.setdefault("RAY_TPU_CHIPS", "none")
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
-
 import ray_tpu
 from ray_tpu import tune
 from ray_tpu.train import Checkpoint, RunConfig

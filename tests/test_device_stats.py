@@ -182,8 +182,29 @@ def test_note_step_gauges_and_overrides(monkeypatch):
         flops_per_token=1.0) == (0.0, 0.0)
 
 
+def test_unknown_device_kind_emits_no_fraction(monkeypatch):
+    """No peak rate is assumed for a device that is not in the table:
+    the step is recorded, the fractions are not."""
+    monkeypatch.delenv("RAY_TPU_DEVICE_HBM_GBPS", raising=False)
+    monkeypatch.delenv("RAY_TPU_DEVICE_PEAK_TFLOPS", raising=False)
+    assert device_stats.peak_specs() is None  # device_kind "cpu"
+    assert device_stats.note_step(
+        tokens_per_s=10.0, bytes_per_token=1e6,
+        flops_per_token=1e7) == (None, None)
+    ls = device_stats.last_step()
+    assert ls["tokens_per_s"] == pytest.approx(10.0)
+    assert "roofline_fraction" not in ls and "mfu" not in ls
+    assert "mfu" not in device_stats.profile_fields()
+    assert device_stats.peak_specs_for("TPU v5 lite") == (819e9, 197e12)
+    with pytest.raises(KeyError):
+        device_stats.peak_specs_for("TPU v99")
+
+
 def test_engine_step_sampler_device_fields(monkeypatch):
     monkeypatch.setenv("RAY_TPU_SERVE_STEP_SAMPLE_EVERY", "2")
+    # A CPU has no recorded peaks: fractions need them given explicitly.
+    monkeypatch.setenv("RAY_TPU_DEVICE_HBM_GBPS", "100")
+    monkeypatch.setenv("RAY_TPU_DEVICE_PEAK_TFLOPS", "1")
     from ray_tpu.models import transformer as tfm
     from ray_tpu.serve.llm_engine import LLMEngine
 
@@ -251,6 +272,8 @@ def test_device_journal_and_opsdump(tmp_path, monkeypatch):
     journal.reset()
     monkeypatch.setenv("RAY_TPU_OPS_JOURNAL_DIR", str(tmp_path))
     monkeypatch.setattr(device_stats, "_warmup", 0)
+    monkeypatch.setenv("RAY_TPU_DEVICE_HBM_GBPS", "100")
+    monkeypatch.setenv("RAY_TPU_DEVICE_PEAK_TFLOPS", "1")
     try:
         device_stats.note_step(tokens_per_s=100.0, bytes_per_token=1e6,
                                flops_per_token=1e7, plane="serve")
@@ -425,7 +448,7 @@ def test_bench_index_every_known_file_parses():
         assert per_source.get(name, 0) > 0, f"{name} extracted 0 rows"
     # Known headline metrics survive extraction.
     metrics = {r["metric"] for r in index["rows"]}
-    for want in ("train_mfu", "decode_tokens_per_sec",
+    for want in ("decode_tokens_per_sec",
                  "serve_tokens_per_sec",
                  "multi_client_tasks_async.overhead"):
         assert want in metrics, sorted(metrics)
