@@ -1,0 +1,289 @@
+"""Train driver: a model configuration trained through the program's own
+path, `JaxTrainer` -> worker group -> `ShardedTrainStep`, on the token
+batches the cell's traffic generator feeds it.
+
+The mix names its generator (`benchmark/generators/<kind>.py`); this
+driver asks it for a `plan(mix, seed, seconds)` here and, on the worker,
+for `batches(plan, rows, seq, vocab)`, an iterator of int32 arrays
+`[rows, seq + 1]`, one a step.  It does not know one kind from another.
+
+The loop below runs ON the train worker, which holds the chip(s); this
+process never initialises a JAX backend.  The loop is the benchmark's (a
+user's train_loop_per_worker), the step is the program's.
+"""
+
+from __future__ import annotations
+
+import os
+import time
+from typing import Any, Dict, List
+
+from benchmark import harness
+from benchmark.harness import BenchFailure, say
+
+
+def train_loop(cfg: Dict[str, Any]) -> None:
+    """Runs on the worker.  Reports each step as a user's loop would,
+    then one final record with everything the driver reads."""
+    import glob
+
+    import jax
+    import jax.monitoring
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.reference import dense_rope_swiglu as ref
+    from ray_tpu import train
+    from ray_tpu.models import transformer as tfm
+    from ray_tpu.ops import dispatch
+    from ray_tpu.parallel.mesh import build_mesh
+    from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
+
+    compiles = {"n": 0}
+
+    def on_event(name, secs, **kw):
+        if name.endswith("backend_compile_duration") \
+                or "cache_retrieval" in name:
+            compiles["n"] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+
+    model, tr, plan = cfg["model"], cfg["train"], cfg["plan"]
+    chips = cfg["chips"]
+    devices = jax.devices()
+    dev = {"platform": devices[0].platform, "kind": devices[0].device_kind,
+           "count": len(devices)}
+    if len(devices) < chips:
+        raise RuntimeError(f"the worker sees {len(devices)} devices, the "
+                           f"cell needs {chips}")
+    seq, batch = tr["sequence_length"], tr["batch_rows"]
+    config = tfm.TransformerConfig(
+        **harness.model_kwargs(model, seq),
+        remat_policy=tr["remat_policy"], fused_ce=tr["fused_ce"])
+    mesh = build_mesh(axes=tr["mesh_axes"], devices=devices[:chips])
+    moments = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
+        tr["adam_moment_dtype"]]
+    ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
+        warmup_steps=tr["lr_warmup_steps"], total_steps=tr["lr_total_steps"],
+        mu_dtype=moments, nu_dtype=moments))
+    seed = cfg["seed"] % (2 ** 31 - 1)
+    t = time.perf_counter()
+    state = ts.init(jax.random.key(seed))
+    jax.block_until_ready(state)
+    init_s = time.perf_counter() - t
+    feed = harness.load_generator(cfg["generator"]).batches(
+        plan, batch, seq, config.vocab_size)
+    tokens = next(feed)
+    batch_dev = {"tokens": jnp.asarray(tokens)}
+
+    wq = state["params"]["blocks"]["wq"]
+    shards = wq.addressable_shards
+    placement = {"shard_devices": sorted({s.device.id for s in shards}),
+                 "shard_shape": list(shards[0].data.shape),
+                 "full_shape": list(wq.shape)}
+    del wq, shards
+
+    # Reference, before the first step donates the state: the plain
+    # float32 loss on the same rows with the same weights.
+    rows = int(tr["reference_rows"])
+    t = time.perf_counter()
+    ref_loss = ref.loss(state["params"], tokens[:rows],
+                        ref.dims_from_config(model))
+    program_ref_loss = None
+    if rows != batch:
+        program_ref_loss = float(ts.eval_step(
+            state["params"], {"tokens": jnp.asarray(tokens[:rows])}))
+    ref_s = time.perf_counter() - t
+
+    losses: List[float] = []
+    first, batches_moved = True, 1
+
+    def one_step(sync: bool):
+        nonlocal state, tokens, batch_dev, first, batches_moved
+        if first:       # the first step takes the batch the reference saw
+            first = False
+        else:
+            nxt = next(feed)
+            if nxt is not tokens:   # a fixed batch goes to the device once
+                tokens, batch_dev = nxt, {"tokens": jnp.asarray(nxt)}
+                batches_moved += 1
+        with jax.profiler.TraceAnnotation("bench:step_dispatch"):
+            state, metrics = ts.step(state, batch_dev)
+        if sync:
+            with jax.profiler.TraceAnnotation("bench:sync_loss"):
+                losses.append(float(metrics["loss"]))
+            with jax.profiler.TraceAnnotation("bench:report"):
+                train.report({"step": len(losses), "loss": losses[-1]})
+
+    t = time.perf_counter()
+    one_step(True)
+    first_step_s = time.perf_counter() - t
+    for _ in range(plan["warmup_steps"]):
+        one_step(True)
+    if program_ref_loss is None:
+        program_ref_loss = losses[0]
+
+    # the window
+    seconds, per_sync = cfg["seconds"], max(1, plan["steps_per_sync"])
+    tcfg = cfg.get("trace") or None
+    trace_info: Dict[str, Any] = {}
+    compiles_before = compiles["n"]
+    n_before = len(losses)
+    ends: List[float] = []
+    steps_done = 0
+    t_start_epoch = time.time()
+    t_start = time.perf_counter()
+    tracing = False
+    while True:
+        if tcfg and not tracing and not trace_info \
+                and time.perf_counter() - t_start >= tcfg["start_s"]:
+            os.makedirs(tcfg["dir"], exist_ok=True)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 2
+            trace_info = {"t_start_epoch": time.time(),
+                          "first_step": steps_done}
+            jax.profiler.start_trace(tcfg["dir"], profiler_options=opts)
+            tracing = True
+        steps_done += 1
+        one_step(steps_done % per_sync == 0)
+        if steps_done % per_sync == 0:
+            ends.append(time.perf_counter())
+        if tracing and steps_done - trace_info["first_step"] \
+                >= tcfg["steps"] and steps_done % per_sync == 0:
+            jax.profiler.stop_trace()
+            tracing = False
+            trace_info["steps"] = steps_done - trace_info["first_step"]
+            trace_info["t_end_epoch"] = time.time()
+            files = sorted(glob.glob(os.path.join(
+                tcfg["dir"], "plugins", "profile", "*", "*.xplane.pb")))
+            trace_info["xplane"] = files[-1] if files else None
+        if ends and ends[-1] - t_start >= seconds and not tracing:
+            break
+    window_compiles = compiles["n"] - compiles_before
+
+    train.report({"final": {
+        "device": dev, "memory_peak_bytes": harness.peak_bytes(devices),
+        "init_s": init_s,
+        "first_step_s": first_step_s, "reference_s": ref_s,
+        "reference_loss": ref_loss, "program_reference_loss":
+        program_ref_loss, "placement": placement,
+        "losses": losses, "window_first_loss_index": n_before,
+        "step_ends": [e - t_start for e in ends],
+        "steps": steps_done, "steps_per_sync": per_sync,
+        "batches_moved": batches_moved,
+        "tokens_per_step": batch * seq,
+        "t_start_epoch": t_start_epoch,
+        "window_compiles": window_compiles,
+        "kernels": dispatch.taken(), "trace": trace_info}})
+
+
+def run(resolved: dict, args, t_process_start: float) -> dict:
+    import ray_tpu
+    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+
+    cfg, mix, cell = resolved["config"], resolved["mix"], resolved["cell"]
+    model, tr = cfg["model"], cfg["train"]
+    rehearse, chips = args.rehearse, cell["chips"]
+    plan = harness.load_generator(mix["generator"]).plan(
+        mix, args.seed, args.seconds)
+    trace = None
+    if args.trace:
+        trace = dict(cfg["trace"], dir=os.path.join(
+            harness.OUT_DIR, "trace", cell["name"]))
+    ray_tpu.init(num_tpus=chips if rehearse else None)
+    try:
+        have = ray_tpu.cluster_resources().get("TPU", 0)
+        if have < chips:
+            raise BenchFailure(f"this host offers {have:g} TPU chips, the "
+                               f"cell needs {chips}")
+        t = time.perf_counter()
+        result = JaxTrainer(
+            train_loop,
+            train_loop_config={"model": model, "train": tr, "plan": plan,
+                               "generator": mix["generator"],
+                               "chips": chips, "seed": args.seed,
+                               "seconds": args.seconds, "trace": trace},
+            scaling_config=ScalingConfig(num_workers=1, use_tpu=True,
+                                         tpu_chips_per_worker=chips),
+            run_config=RunConfig(
+                name=cell["name"],
+                storage_path=os.path.join(harness.OUT_DIR, "train")),
+        ).fit()
+        fit_s = time.perf_counter() - t
+    finally:
+        ray_tpu.shutdown()
+    final = [h["final"] for h in result.metrics_history if "final" in h]
+    if not final:
+        raise BenchFailure("the train loop sent no final record")
+    rec = final[-1]
+    harness.check_device(rec["device"], chips, rehearse)
+    ends = rec["step_ends"]
+    elapsed = ends[-1]
+    tokens_per_s = (len(ends) * rec["steps_per_sync"]
+                    * rec["tokens_per_step"] / elapsed)
+    setup_s = rec["t_start_epoch"] - t_process_start
+    window_losses = rec["losses"][rec["window_first_loss_index"]:]
+    say("train", fit_s=fit_s, init_s=rec["init_s"],
+        first_step_s=rec["first_step_s"], reference_s=rec["reference_s"],
+        steps=rec["steps"], batches_moved=rec["batches_moved"],
+        elapsed_s=elapsed,
+        step_s_median=harness.percentile(
+            [b - a for a, b in zip([0.0] + ends, ends)], 50)
+        / rec["steps_per_sync"],
+        first_loss=rec["losses"][0], last_loss=rec["losses"][-1],
+        reference_loss=rec["reference_loss"],
+        program_reference_loss=rec["program_reference_loss"],
+        window_compiles=rec["window_compiles"], placement=rec["placement"],
+        kernels=rec["kernels"], tokens_per_s=tokens_per_s)
+
+    check = cfg["reference_check"]
+    faults = []
+    if not rehearse:
+        k = harness.kernels_ok(rec["kernels"], cfg["must_take_pallas"])
+        if k:
+            faults.append(k)
+    diff = abs(rec["program_reference_loss"] - rec["reference_loss"])
+    if not diff <= check["tolerance"]:
+        faults.append(f"loss {rec['program_reference_loss']} against the "
+                      f"reference's {rec['reference_loss']}: off by {diff}, "
+                      f"tolerance {check['tolerance']}")
+    if not all(l == l and abs(l) < 1e4 for l in rec["losses"]):
+        faults.append("a loss is not finite")
+    if mix.get("loss_must_fall") and not window_losses[-1] < window_losses[0]:
+        faults.append(f"the loss did not fall over the window: "
+                      f"{window_losses[0]} -> {window_losses[-1]}")
+    if chips > 1:
+        place = rec["placement"]
+        if len(place["shard_devices"]) != chips \
+                or place["shard_shape"] == place["full_shape"]:
+            faults.append(f"parameters are not sharded over {chips} "
+                          f"distinct devices: {place}")
+    if rec["window_compiles"]:
+        faults.append(f"{rec['window_compiles']} compiles inside the window")
+    say("correct", faults=faults)
+    # steps' median wall time: what the rate is when the profiler's start
+    # and stop (seconds, in a traced run) are not in it
+    walls = [b - a for a, b in zip([0.0] + ends, ends)]
+    steady = (rec["steps_per_sync"] * rec["tokens_per_step"]
+              / harness.percentile(walls, 50))
+    counters = {
+        "steady_tokens_per_s": steady,
+        "window_compiles": rec["window_compiles"],
+        "batches_moved": rec["batches_moved"],
+        "step_ends": ends, "steps_per_sync": rec["steps_per_sync"],
+        "tokens_per_step": rec["tokens_per_step"],
+        "trace_steps": rec["trace"].get("steps"),
+        "values": {"train_tokens_per_s": tokens_per_s},
+        "device": rec["device"], "model": model, "train": tr,
+        "chips": chips, "seconds": args.seconds,
+    }
+    return {"correct": not faults and not rehearse,
+            "attempted": rec["steps"], "failed": 0,
+            "values": {"train_tokens_per_s": tokens_per_s,
+                       "setup_s": setup_s},
+            "device": rec["device"],
+            "memory_peak_bytes": rec["memory_peak_bytes"],
+            "spans": [], "counters": counters,
+            "trace_file": rec["trace"].get("xplane"),
+            "trace_t0_epoch": rec["trace"].get("t_start_epoch")}
