@@ -8,6 +8,7 @@ ray.remote :3149, ray.get_actor :2902).
 from __future__ import annotations
 
 import inspect
+import time
 from typing import Any, List, Optional, Sequence, Union
 
 from ray_tpu.core import runtime as _runtime_mod
@@ -37,7 +38,9 @@ def init(num_cpus: Optional[float] = None,
     mode (address=...) remote workers are spawned by the cluster's own
     daemons and keep the config the cluster was started with."""
     from ray_tpu.core import knobs as _knobs
+    from ray_tpu.util import tracing as _tracing
 
+    t_called = time.time()
     _knobs.apply_interpreter_tuning()
     rt = _runtime_mod._global_runtime
     if rt is not None and getattr(rt, "is_initialized", False):
@@ -66,11 +69,18 @@ def init(num_cpus: Optional[float] = None,
         _lc.export_to_env(logging_config)
         global _logging_config_exported
         _logging_config_exported = True
-    return DriverRuntime(
-        num_cpus=num_cpus, num_tpus=num_tpus, resources=resources,
-        namespace=namespace, address=address,
-        log_to_driver=log_to_driver,
-        _system_config=_system_config)
+    # The start-up timeline (always recorded; JaxTrainer.fit writes it to
+    # timeline.json): interpreter and imports, then the runtime.
+    t_process = _tracing.process_start_time()
+    if t_process is not None:
+        _tracing.record_span("startup.process", t_process, t_called,
+                             force=True)
+    with _tracing.trace_span("startup.runtime", force=True, start=t_called):
+        return DriverRuntime(
+            num_cpus=num_cpus, num_tpus=num_tpus, resources=resources,
+            namespace=namespace, address=address,
+            log_to_driver=log_to_driver,
+            _system_config=_system_config)
 
 
 _ADDRESS_FILE = "/tmp/ray_tpu/cluster_address"

@@ -36,6 +36,8 @@ class DriverRuntime:
         Connect mode (``address=``): attach this driver to an existing
         cluster's control server — counterpart of ray.init(address=...)
         joining a running GCS (worker.py:1225 connect-only path)."""
+        from ray_tpu.util import tracing  # ray_tpu.util imports this module
+
         reset_config()
         self.config: Config = get_config().apply_overrides(_system_config)
         if address:
@@ -57,22 +59,27 @@ class DriverRuntime:
                 tempfile.gettempdir(), "ray_tpu", f"session-{session_id}")
             os.makedirs(self.session_dir, exist_ok=True)
             node_res = node_resources_from_env(num_cpus, num_tpus, resources)
-            self.control = ControlServer(
-                session_id, self.config, node_res, self.session_dir,
-                namespace=namespace)
+            with tracing.trace_span("startup.head", force=True):
+                self.control = ControlServer(
+                    session_id, self.config, node_res, self.session_dir,
+                    namespace=namespace)
             control_addr = self.control.address
-        self.core = CoreClient(
-            control_addr, WorkerID.from_random().hex(),
-            kind="driver", config=self.config, thin=thin)
-        if address:
-            self.session_dir = self.core.session_dir
-        self.namespace = namespace
-        # Worker stdout/stderr → driver console (reference log_monitor.py
-        # behavior; see core/log_monitor.py).
-        self.log_monitor = None
-        if log_to_driver:
-            from ray_tpu.core.log_monitor import LogMonitor
-            self.log_monitor = LogMonitor(self.session_dir).start()
+        # This process's attach to its node: in head mode the head is the
+        # node's manager, so what is left is the driver's own client, its
+        # mapping of the object store, and the log monitor.
+        with tracing.trace_span("startup.node_manager", force=True):
+            self.core = CoreClient(
+                control_addr, WorkerID.from_random().hex(),
+                kind="driver", config=self.config, thin=thin)
+            if address:
+                self.session_dir = self.core.session_dir
+            self.namespace = namespace
+            # Worker stdout/stderr → driver console (reference
+            # log_monitor.py behavior; see core/log_monitor.py).
+            self.log_monitor = None
+            if log_to_driver:
+                from ray_tpu.core.log_monitor import LogMonitor
+                self.log_monitor = LogMonitor(self.session_dir).start()
         self.is_initialized = True
         set_runtime(self)
         atexit.register(self._atexit)

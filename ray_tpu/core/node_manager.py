@@ -37,7 +37,7 @@ from ray_tpu.core.config import get_config, reset_config
 from ray_tpu.core.ids import ObjectID
 from ray_tpu.core.object_store import ShmObjectStore
 from ray_tpu.core.resources import node_resources_from_env
-from ray_tpu.util import compile_cache
+from ray_tpu.util import compile_cache, tracing
 
 
 def zygote_enabled() -> bool:
@@ -63,15 +63,18 @@ def cpu_worker_env(env: dict) -> dict:
 
 
 def prewarm_zygote() -> None:
-    """Start warming this process's worker template (no-op when disabled)."""
+    """Start warming this process's worker template (no-op when disabled).
+    The span is what the caller pays to start it; the template warms in
+    its own process."""
     if not zygote_enabled():
         return
-    try:
-        from ray_tpu.core.zygote import get_zygote
+    with tracing.trace_span("startup.worker_template", force=True):
+        try:
+            from ray_tpu.core.zygote import get_zygote
 
-        get_zygote().prewarm()
-    except Exception:
-        pass
+            get_zygote().prewarm()
+        except Exception:
+            pass
 
 
 def _has_exec_only_env_vars(runtime_env: Optional[dict]) -> bool:

@@ -185,6 +185,7 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
             jax.ShapeDtypeStruct((b * h, 8, sq), jnp.float32),
         ],
         interpret=dispatch.interpret_mode(),
+        name="flash_fwd",
     )(offs, qf, kf, vf)
     out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     return out, lse[:, 0, :]  # lse: [bh, sq]
@@ -357,6 +358,7 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
         ),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         interpret=dispatch.interpret_mode(),
+        name="flash_bwd_dq",
     )(offs, qf, kf, vf, dof, lse8, delta8, dlse8)
 
     dk, dv = pl.pallas_call(
@@ -381,6 +383,7 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
         interpret=dispatch.interpret_mode(),
+        name="flash_bwd_dkv",
     )(offs, qf, kf, vf, dof, lse8, delta8, dlse8)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)

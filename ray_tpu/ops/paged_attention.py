@@ -366,6 +366,7 @@ def _paged_attention_pallas(q, k_pages, v_pages, block_tables,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
+        name="paged_attention_decode",
     )
     out = kernel(block_tables.astype(jnp.int32),
                  context_lens.astype(jnp.int32), bctx, q, k_pages,
@@ -576,6 +577,7 @@ def write_token_rows(k_pages, v_pages, k_new, v_new, block_tables,
         # scalar-prefetch arrays: 3 = k_pages -> out 0, 4 = v_pages.
         input_output_aliases={3: 0, 4: 1},
         interpret=not on_tpu,
+        name="write_token_rows",
     )
     return kernel(pages, strips, rows, k_pages, v_pages, kn, vn)
 

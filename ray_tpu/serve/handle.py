@@ -29,12 +29,18 @@ class DeploymentResponse:
         self._lock = threading.Lock()
         self._ref: Optional[ObjectRef] = None
         self._assigned_hex: Optional[str] = None
+        self._assigned_router: Optional[Router] = None
         self._released = False
         self._submit()
 
     def _submit(self):
         h = self._handle
-        hex_id, actor = h._router().assign_replica(
+        # Kept for _release: it runs as a future's callback on the RPC
+        # receive thread, where looking the router up again (a blocking
+        # controller lookup once serve.shutdown has dropped it) would wait
+        # for a reply only that thread can receive.
+        router = self._assigned_router = h._router()
+        hex_id, actor = router.assign_replica(
             timeout_s=h._assign_timeout_s,
             model_id=h._multiplexed_model_id,
             phase=h._phase, prefix_keys=h._prefix_hint,
@@ -63,7 +69,7 @@ class DeploymentResponse:
                 return
             self._released = True
             hex_id = self._assigned_hex
-        self._handle._router().release(hex_id)
+        self._assigned_router.release(hex_id)
 
     def result(self, timeout_s: Optional[float] = 60.0) -> Any:
         """Resolve; retries through another replica if the assigned one

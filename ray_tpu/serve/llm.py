@@ -15,6 +15,7 @@ Usage:
 from __future__ import annotations
 
 import time
+from contextlib import ExitStack, contextmanager
 from typing import Any, Dict, List, Optional, Sequence
 
 from ray_tpu.models import transformer as tfm
@@ -99,15 +100,30 @@ class LLMServer:
             target=self._engine_loop, daemon=True, name="llm-engine")
         self._thread.start()
 
+    @contextmanager
+    def _locked(self, who: str):
+        """The server's one lock, with the time `who` (engine,
+        add_request, generate_stream) waited for it as a `serve.lock_wait`
+        span.  The engine thread holds the lock through `engine.step()`
+        (the `serve.engine_step` span) and takes it again at once, so a
+        handler's wait here is what holds a request off the engine."""
+        with ExitStack() as waiting:
+            waiting.enter_context(
+                tracing.trace_span("serve.lock_wait", {"who": who}))
+            with self._cv:
+                waiting.close()
+                yield
+
     def _engine_loop(self):
         while not self._stopped:
-            with self._cv:
+            with self._locked("engine"):
                 while not self.engine.has_work() and not self._stopped:
                     self._cv.wait(timeout=1.0)
                 if self._stopped:
                     return
                 try:
-                    done = self.engine.step()
+                    with tracing.trace_span("serve.engine_step"):
+                        done = self.engine.step()
                 except Exception as e:  # noqa: BLE001
                     # A dead engine must fail waiters loudly, not hang
                     # them: record the error and wake everyone.
@@ -142,7 +158,7 @@ class LLMServer:
                          max_new_tokens: int, temperature: float
                          ) -> List[List[int]]:
         trace = _request_trace()
-        with self._cv:
+        with self._locked("add_request"):
             if self._engine_error is not None:
                 raise RuntimeError(
                     f"LLM engine failed: {self._engine_error}")
@@ -196,7 +212,7 @@ class LLMServer:
 
         prompt = list(prompt_tokens)
         trace = _request_trace()
-        with self._cv:
+        with self._locked("add_request"):
             if self._engine_error is not None:
                 raise RuntimeError(
                     f"LLM engine failed: {self._engine_error}")
@@ -288,7 +304,7 @@ class LLMServer:
         rid = None
         if reason is None:
             try:
-                with self._cv:
+                with self._locked("add_request"):
                     if self._engine_error is not None:
                         raise RuntimeError(
                             f"LLM engine failed: {self._engine_error}")
@@ -332,7 +348,7 @@ class LLMServer:
         trace = None
         if ctx is not None and ctx.trace_ctx is not None:
             trace = (ctx.trace_ctx[0], ctx.span_id or ctx.trace_ctx[1])
-        with self._cv:
+        with self._locked("add_request"):
             if self._engine_error is not None:
                 raise RuntimeError(
                     f"LLM engine failed: {self._engine_error}")
@@ -345,7 +361,7 @@ class LLMServer:
         sent = 0
         try:
             while True:
-                with self._cv:
+                with self._locked("generate_stream"):
                     if self._engine_error is not None:
                         raise RuntimeError(
                             f"LLM engine failed: {self._engine_error}")
@@ -374,7 +390,7 @@ class LLMServer:
                     return
         except GeneratorExit:
             # Consumer dropped the stream mid-generation.
-            with self._cv:
+            with self._locked("generate_stream"):
                 self.engine.abort(rid, "cancelled")
                 self.engine.shed.pop(rid, None)
                 self._shed.pop(rid, None)

@@ -52,6 +52,12 @@ class JaxBackendConfig(BackendConfig):
         return JaxBackend
 
 
+def _joins_workers(config: "JaxBackendConfig", num_workers: int) -> bool:
+    if config.distributed_init is None:
+        return num_workers > 1
+    return bool(config.distributed_init)
+
+
 def _free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
@@ -69,24 +75,34 @@ def _jax_env(config: JaxBackendConfig) -> Dict[str, str]:
     return env
 
 
-def _init_jax_distributed(coordinator: str, num_processes: int,
-                          process_id: int, platform: Optional[str]):
-    """Runs ON the train worker (before any other jax use there).
+def _start_jax(coordinator: Optional[str], num_processes: int,
+               process_id: int, platform: Optional[str]):
+    """Runs ON the train worker, before the user's loop: imports JAX,
+    joins the workers into one runtime when there is a `coordinator`, and
+    makes the process's first device query, which creates the backend
+    (on a TPU worker, the TPU client).  The import and the query are the
+    worker's `startup.import_jax` and `startup.device_client` spans.
 
     Env vars (JAX_PLATFORMS / XLA_FLAGS) were already applied by
     TrainWorker.__init__ from _jax_env — the single authoritative path.
-    The jax.config override is still needed: importing ray_tpu.train
-    imported jax in this worker, and jax read JAX_PLATFORMS then."""
-    import jax
+    The jax.config override is still needed where a worker forked from a
+    warm template had JAX imported, and JAX_PLATFORMS read, before that."""
+    from ray_tpu.util import tracing
+
+    with tracing.trace_span("startup.import_jax", force=True):
+        import jax
 
     if platform:
         jax.config.update("jax_platforms", platform)
-    jax.distributed.initialize(
-        coordinator_address=coordinator,
-        num_processes=num_processes,
-        process_id=process_id)
+    if coordinator:
+        jax.distributed.initialize(
+            coordinator_address=coordinator,
+            num_processes=num_processes,
+            process_id=process_id)
+    with tracing.trace_span("startup.device_client", force=True):
+        devices = jax.devices()
     return {"process_id": jax.process_index(),
-            "global_devices": len(jax.devices()),
+            "global_devices": len(devices),
             "local_devices": len(jax.local_devices())}
 
 
@@ -105,18 +121,19 @@ class JaxBackend(Backend):
         import ray_tpu
 
         n = worker_group.num_workers
-        do_dist = backend_config.distributed_init
-        if do_dist is None:
-            do_dist = n > 1
-        if not do_dist:
+        do_dist = _joins_workers(backend_config, n)
+        if not do_dist and n > 1:
+            # distributed_init=False over several workers: the loop joins
+            # them itself, and must find no backend made before it does.
             return
-        port = backend_config.coordinator_port or _free_port()
-        coordinator = f"127.0.0.1:{port}"
-        # TODO multi-node: use rank-0 worker's node IP from node_info().
+        coordinator = None
+        if do_dist:
+            port = backend_config.coordinator_port or _free_port()
+            coordinator = f"127.0.0.1:{port}"
+            # TODO multi-node: use rank-0 worker's node IP from node_info().
         refs = [
-            w.run.remote(
-                _init_jax_distributed, coordinator, n, i,
-                backend_config.platform)
+            w.run.remote(_start_jax, coordinator, n, i,
+                         backend_config.platform)
             for i, w in enumerate(worker_group.workers)
         ]
         infos = ray_tpu.get(refs, timeout=120)
@@ -130,6 +147,8 @@ class JaxBackend(Backend):
     def on_shutdown(self, worker_group, backend_config: JaxBackendConfig):
         import ray_tpu
 
+        if not _joins_workers(backend_config, worker_group.num_workers):
+            return
         try:
             ray_tpu.get(
                 [w.run.remote(_shutdown_jax_distributed)

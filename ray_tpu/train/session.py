@@ -15,9 +15,10 @@ import dataclasses
 import queue
 import threading
 import time
-from typing import Any, Dict, Iterable, Optional
+from typing import Any, Callable, Dict, Optional
 
 from ray_tpu.train.checkpoint import Checkpoint
+from ray_tpu.util import tracing
 
 _session_lock = threading.Lock()
 _session: Optional["_TrainSession"] = None
@@ -60,12 +61,20 @@ class _TrainSession:
         self.finished = threading.Event()
         self.error: Optional[BaseException] = None
         self._last_report_t: Optional[float] = None
+        # (checkpoint, metrics) -> the persisted Checkpoint; set by the
+        # worker that persists (rank 0).
+        self.persist_checkpoint: Optional[Callable] = None
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None) -> None:
-        self._note_device_step(metrics)
-        self.result_queue.put({"metrics": dict(metrics),
-                               "checkpoint": checkpoint})
+        # The queue holds one item: a driver that is slow to poll blocks
+        # the loop here, and the span shows it.
+        with tracing.trace_span("train.report"):
+            self._note_device_step(metrics)
+            if checkpoint is not None and self.persist_checkpoint:
+                checkpoint = self.persist_checkpoint(checkpoint, metrics)
+            self.result_queue.put({"metrics": dict(metrics),
+                                   "checkpoint": checkpoint})
 
     def _note_device_step(self, metrics: Dict[str, Any]) -> None:
         """Device-plane step hook (same accounting the serve engine's
@@ -82,10 +91,10 @@ class _TrainSession:
         if flops is None and nbytes is None and tok_s is None:
             return
         try:
-            from ray_tpu.util import device_stats, tracing
+            from ray_tpu.util import device_stats
 
             if tok_s is not None:
-                frac, mfu = device_stats.note_step(
+                device_stats.note_step(
                     tokens_per_s=float(tok_s),
                     bytes_per_token=float(
                         metrics.get("bytes_per_token", 0.0)),
@@ -95,20 +104,11 @@ class _TrainSession:
             elif prev is not None and now > prev:
                 # One report == one step: per-"token" terms collapse to
                 # per-step terms at 1/dt steps per second.
-                frac, mfu = device_stats.note_step(
+                device_stats.note_step(
                     tokens_per_s=1.0 / (now - prev),
                     bytes_per_token=float(nbytes or 0.0),
                     flops_per_token=float(flops or 0.0),
                     plane="train")
-            else:
-                return
-            if prev is not None and now > prev:
-                attrs = {"plane": "train"}
-                if frac is not None:  # None: device peaks unknown
-                    attrs["roofline_fraction"] = round(frac, 5)
-                    attrs["mfu"] = round(mfu, 5)
-                tracing.record_span("device.step", prev, now,
-                                    attributes=attrs)
         except Exception:  # raylint: allow-swallow(telemetry must never fail a train step report)
             pass
 

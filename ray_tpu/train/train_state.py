@@ -24,6 +24,7 @@ from ray_tpu.parallel.sharding import (
     data_sharding,
     tree_shardings,
 )
+from ray_tpu.util import device_stats, tracing
 
 
 def default_optimizer(learning_rate: float = 3e-4,
@@ -123,8 +124,21 @@ class ShardedTrainStep:
         self.batch_sharding = data_sharding(mesh)
         self._params_treedef = jax.tree.structure(self.param_shardings)
 
-        self._init = jax.jit(self._init_fn)
-        self._step = jax.jit(self._step_fn, donate_argnums=(0,))
+        self._init = device_stats.count_compiles(
+            jax.jit(self._init_fn), "train.init")
+        self._step = device_stats.count_compiles(
+            jax.jit(self._step_fn, donate_argnums=(0,)), "train.step")
+        self._spanned: set = set()
+        self._steps = 0
+
+    def _span(self, name: str, **attrs):
+        """Host time to place the inputs and enqueue one program.  The
+        FIRST call of each program holds its compile or cache load and is
+        recorded whatever the tracing flag says (the start-up timeline
+        reads it); later ones follow the flag, and a running profile."""
+        first = name not in self._spanned
+        self._spanned.add(name)
+        return tracing.trace_span(name, attrs or None, force=first)
 
     # -- init ---------------------------------------------------------------
     def _init_fn(self, rng):
@@ -145,7 +159,7 @@ class ShardedTrainStep:
         return jax.sharding.set_mesh(self.mesh)
 
     def init(self, rng):
-        with self._mesh_scope():
+        with self._span("train.init"), self._mesh_scope():
             return self._init(rng)
 
     # -- step ---------------------------------------------------------------
@@ -170,9 +184,11 @@ class ShardedTrainStep:
                 "step": state["step"] + 1}, metrics
 
     def step(self, state, batch):
-        batch = jax.device_put(batch, self.batch_sharding)
-        with self._mesh_scope():
-            return self._step(state, batch)
+        self._steps += 1
+        with self._span("train.step", step=self._steps):
+            batch = jax.device_put(batch, self.batch_sharding)
+            with self._mesh_scope():
+                return self._step(state, batch)
 
     # -- eval ----------------------------------------------------------------
     @functools.cached_property
@@ -180,9 +196,10 @@ class ShardedTrainStep:
         def eval_fn(params, batch):
             return self.loss_fn(params, batch).astype(jnp.float32)
 
-        return jax.jit(eval_fn)
+        return device_stats.count_compiles(jax.jit(eval_fn), "train.eval")
 
     def eval_step(self, params, batch):
-        batch = jax.device_put(batch, self.batch_sharding)
-        with self._mesh_scope():
-            return self._eval(params, batch)
+        with self._span("train.eval"):
+            batch = jax.device_put(batch, self.batch_sharding)
+            with self._mesh_scope():
+                return self._eval(params, batch)

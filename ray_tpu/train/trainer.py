@@ -14,6 +14,9 @@ DataParallelTrainer — the JAX backend is the default.
 from __future__ import annotations
 
 import dataclasses
+import json
+import logging
+import os
 import time
 from typing import Any, Callable, Dict, List, Optional
 
@@ -21,6 +24,11 @@ import ray_tpu
 from ray_tpu.train.backend import BackendConfig, JaxBackendConfig
 from ray_tpu.train.checkpoint import Checkpoint, StorageContext
 from ray_tpu.train.config import RunConfig, ScalingConfig
+from ray_tpu.util import device_stats, tracing
+
+logger = logging.getLogger(__name__)
+
+TIMELINE_FILE = "timeline.json"
 
 
 class TrainingFailedError(RuntimeError):
@@ -38,6 +46,9 @@ class Result:
     error: Optional[BaseException] = None
     metrics_history: List[Dict[str, Any]] = dataclasses.field(
         default_factory=list)
+    # Where the run's start-up went, as <path>/timeline.json holds it
+    # (`_write_timeline`); None when no attempt got as far as its loop.
+    timeline: Optional[Dict[str, Any]] = None
 
     @property
     def best_checkpoints(self):
@@ -83,6 +94,7 @@ class DataParallelTrainer:
 
     # ------------------------------------------------------------------
     def fit(self) -> Result:
+        t_entered = time.time()
         if not ray_tpu.is_initialized():
             ray_tpu.init()
         cfg = self.run_config
@@ -111,15 +123,16 @@ class DataParallelTrainer:
         try:
             while True:
                 try:
-                    metrics = self._run_attempt(
-                        storage, latest_ckpt, history,
+                    metrics, timeline = self._run_attempt(
+                        storage, latest_ckpt, history, t_entered,
                         callbacks=callbacks, handle=handle)
                     callbacks.on_trial_complete(trial=handle)
                     return Result(
                         metrics=metrics,
                         checkpoint=storage.latest_checkpoint(),
                         path=storage.run_dir,
-                        metrics_history=history)
+                        metrics_history=history,
+                        timeline=timeline)
                 except TrainingFailedError:
                     callbacks.on_trial_error(trial=handle)
                     raise
@@ -134,50 +147,68 @@ class DataParallelTrainer:
                             f"failure(s): {e}") from e
                     # restart from the latest persisted checkpoint
                     latest_ckpt = storage.latest_checkpoint() or latest_ckpt
+                    t_entered = time.time()
         finally:
             callbacks.on_experiment_end(trials=[handle])
 
     # ------------------------------------------------------------------
     def _run_attempt(self, storage: StorageContext,
                      checkpoint: Optional[Checkpoint],
-                     history: List[Dict[str, Any]],
-                     callbacks=None, handle=None) -> Optional[Dict]:
+                     history: List[Dict[str, Any]], t_entered: float,
+                     callbacks=None, handle=None) -> tuple:
+        """One attempt: (rank 0's last metrics, the run's timeline).
+        `startup.fit` (from `t_entered`: fit() entered, or the last
+        attempt failed) holds the attempt's start-up phases and ends when
+        every worker's loop thread has started."""
         from ray_tpu.train.worker_group import WorkerGroup
         from ray_tpu.train.backend import _jax_env
 
         sc = self.scaling_config
         env = _jax_env(self.backend_config) \
             if isinstance(self.backend_config, JaxBackendConfig) else None
-        group = WorkerGroup(
-            sc.num_workers, sc.worker_resources(), storage.run_dir,
-            placement_strategy=sc.placement_strategy, env=env,
-            num_to_keep=self.run_config.checkpoint_config.num_to_keep)
         backend = self.backend_config.backend_cls()
+        group = None
         try:
-            backend.on_start(group, self.backend_config)
+            with tracing.trace_span("startup.fit", force=True,
+                                    start=t_entered):
+                group = WorkerGroup(
+                    sc.num_workers, sc.worker_resources(), storage.run_dir,
+                    placement_strategy=sc.placement_strategy, env=env,
+                    num_to_keep=self.run_config.checkpoint_config.num_to_keep)
+                with tracing.trace_span("startup.backend_on_start",
+                                        force=True):
+                    backend.on_start(group, self.backend_config)
 
-            shards: Dict[int, Dict[str, Any]] = {
-                i: {} for i in range(sc.num_workers)}
-            for name, ds in self.datasets.items():
-                for i, shard in enumerate(_shard_dataset(ds, sc.num_workers)):
-                    shards[i][name] = shard
+                shards: Dict[int, Dict[str, Any]] = {
+                    i: {} for i in range(sc.num_workers)}
+                for name, ds in self.datasets.items():
+                    for i, shard in enumerate(
+                            _shard_dataset(ds, sc.num_workers)):
+                        shards[i][name] = shard
 
-            backend.on_training_start(group, self.backend_config)
-            ray_tpu.get([
-                w.start_training.remote(
-                    self.train_loop_per_worker, self.train_loop_config,
-                    checkpoint.as_directory() if checkpoint else None,
-                    shards[i], storage.name)
-                for i, w in enumerate(group.workers)
-            ], timeout=120)
+                with tracing.trace_span("startup.start_training",
+                                        force=True):
+                    backend.on_training_start(group, self.backend_config)
+                    ray_tpu.get([
+                        w.start_training.remote(
+                            self.train_loop_per_worker,
+                            self.train_loop_config,
+                            checkpoint.as_directory() if checkpoint
+                            else None,
+                            shards[i], storage.name)
+                        for i, w in enumerate(group.workers)
+                    ], timeout=120)
 
-            return self._poll_results(group, history,
-                                      callbacks=callbacks, handle=handle)
+            metrics = self._poll_results(group, history,
+                                         callbacks=callbacks, handle=handle)
+            return metrics, _write_timeline(group, storage.run_dir,
+                                            since=t_entered)
         finally:
-            try:
-                backend.on_shutdown(group, self.backend_config)
-            finally:
-                group.shutdown()
+            if group is not None:
+                try:
+                    backend.on_shutdown(group, self.backend_config)
+                finally:
+                    group.shutdown()
 
     def _poll_results(self, group, history,
                       callbacks=None, handle=None) -> Optional[Dict]:
@@ -216,6 +247,43 @@ class DataParallelTrainer:
                                                   result=entry)
             time.sleep(0.01)
         return last_rank0
+
+
+def _write_timeline(group, run_dir: str, since: float
+                    ) -> Optional[Dict[str, Any]]:
+    """Where the run's start-up went: the driver's forced spans (the
+    runtime's, and this attempt's: those begun at or after `since`) merged
+    with every worker's (asked once, after the loops have ended), as
+    span dicts (`tracing.span_row_to_dict`'s keys) with `worker` and
+    `pid`; every `xla.compile` span apart; each process's `compile_totals`.  Written to
+    <run_dir>/timeline.json beside the loggers' result.json and returned
+    for `Result.timeline`.  Never fails a finished run."""
+    from ray_tpu.train.worker_group import timeline_spans
+
+    try:
+        parts = ray_tpu.get([w.timeline.remote() for w in group.workers],
+                            timeout=60)
+        spans = [s for s in timeline_spans("driver")
+                 if s["start"] >= since
+                 or s["name"] in tracing.RUNTIME_STARTUP_SPANS]
+        totals = {"driver": device_stats.compile_totals()}
+        for part in parts:
+            spans.extend(part["spans"])
+            totals[f"rank{part['rank']}"] = part["compile_totals"]
+        doc = {"spans": [s for s in spans if s["name"] != "xla.compile"],
+               "compiles": [s for s in spans if s["name"] == "xla.compile"],
+               "compile_totals": totals}
+        # Through JSON, so that Result.timeline is what the file holds.
+        text = json.dumps(doc, default=str)
+        os.makedirs(run_dir, exist_ok=True)
+        tmp = os.path.join(run_dir, TIMELINE_FILE + ".tmp")
+        with open(tmp, "w") as f:
+            f.write(text)
+        os.replace(tmp, os.path.join(run_dir, TIMELINE_FILE))
+        return json.loads(text)
+    except Exception:  # noqa: BLE001 — a record of the run, not the run
+        logger.warning("could not write %s", TIMELINE_FILE, exc_info=True)
+        return None
 
 
 @dataclasses.dataclass

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 import traceback
 from typing import Any, Callable, Dict, List, Optional
 
@@ -18,6 +19,21 @@ import ray_tpu
 from ray_tpu.train.checkpoint import Checkpoint, StorageContext
 from ray_tpu.train import session as _session_mod
 from ray_tpu.train.session import TrainContext, _TrainSession
+from ray_tpu.util import device_stats, tracing
+
+# What a worker hands back for the run's timeline: the start-up phases,
+# the train programs' spans and every compile event.  Bounded: with
+# tracing enabled every step is in the ring.
+TIMELINE_PREFIXES = ("startup.", "train.", "xla.compile")
+TIMELINE_MAX_ROWS = 4096
+
+
+def timeline_spans(worker: str) -> List[Dict[str, Any]]:
+    """This process's timeline spans, oldest first, each with who
+    recorded it (`worker`, `pid`)."""
+    pid = os.getpid()
+    return [{**s, "worker": worker, "pid": pid}
+            for s in tracing.get_spans(TIMELINE_PREFIXES)[:TIMELINE_MAX_ROWS]]
 
 
 class TrainWorker:
@@ -43,6 +59,10 @@ class TrainWorker:
                 os.environ[k] = " ".join(kept + [v])
             else:
                 os.environ[k] = v
+        t_start = tracing.process_start_time()
+        if t_start is not None:
+            tracing.record_span("startup.worker_boot", t_start, time.time(),
+                                attributes={"rank": rank}, force=True)
 
     # -- generic execution (WorkerGroup.execute parity) ---------------------
     def run(self, fn: Callable, *args, **kwargs):
@@ -73,6 +93,9 @@ class TrainWorker:
 
         def runner():
             s = self.session
+            now = time.time()
+            tracing.record_span("startup.loop_entered", now, now,
+                                attributes={"rank": self.rank}, force=True)
             try:
                 if _takes_config(train_fn):
                     train_fn(config)
@@ -89,16 +112,9 @@ class TrainWorker:
 
         # Persist checkpoints worker-side (rank 0), reference
         # storage.py:508 persist_current_checkpoint runs on the worker.
-        orig_report = self.session.report
-
-        def reporting(metrics, checkpoint=None):
-            if checkpoint is not None and self.rank == 0:
-                persisted = storage.persist_checkpoint(
-                    checkpoint.as_directory(), metrics)
-                checkpoint = persisted
-            orig_report(metrics, checkpoint)
-
-        self.session.report = reporting
+        if self.rank == 0:
+            self.session.persist_checkpoint = lambda ckpt, metrics: \
+                storage.persist_checkpoint(ckpt.as_directory(), metrics)
         self.thread = threading.Thread(target=runner, daemon=True)
         self.thread.start()
         return True
@@ -119,6 +135,14 @@ class TrainWorker:
         if item.get("checkpoint") is not None:
             item["checkpoint_path"] = item.pop("checkpoint").as_directory()
         return item
+
+    def timeline(self) -> Dict[str, Any]:
+        """This worker's part of the run's timeline (`JaxTrainer.fit`
+        asks once, after the loop has ended): its start-up, train and
+        compile spans, and `device_stats.compile_totals()`."""
+        return {"rank": self.rank,
+                "spans": timeline_spans(f"rank{self.rank}"),
+                "compile_totals": device_stats.compile_totals()}
 
     def shutdown(self) -> bool:
         return True
@@ -154,23 +178,35 @@ class WorkerGroup:
         )
 
         self.num_workers = num_workers
-        self._pg = placement_group(
-            [dict(resources_per_worker) for _ in range(num_workers)],
-            strategy=placement_strategy)
-        if not self._pg.wait(timeout_seconds=60):
-            remove_placement_group(self._pg)
-            raise RuntimeError(
-                f"placement group for {num_workers} train workers "
-                f"({resources_per_worker}/worker) not schedulable")
+        self.workers: List = []
+        with tracing.trace_span("startup.placement_group", force=True):
+            self._pg = placement_group(
+                [dict(resources_per_worker) for _ in range(num_workers)],
+                strategy=placement_strategy)
+            if not self._pg.wait(timeout_seconds=60):
+                remove_placement_group(self._pg)
+                raise RuntimeError(
+                    f"placement group for {num_workers} train workers "
+                    f"({resources_per_worker}/worker) not schedulable")
         cls = ray_tpu.remote(TrainWorker)
-        self.workers: List = [
-            cls.options(
-                resources=dict(resources_per_worker),
-                scheduling_strategy=PlacementGroupSchedulingStrategy(
-                    placement_group=self._pg, placement_group_bundle_index=i),
-            ).remote(i, num_workers, run_dir, env, num_to_keep)
-            for i in range(num_workers)
-        ]
+        # Until every worker process is up and its TrainWorker built: what
+        # a spawn costs is then this span's, not the next caller's.
+        with tracing.trace_span("startup.worker_spawn", force=True):
+            self.workers = [
+                cls.options(
+                    resources=dict(resources_per_worker),
+                    scheduling_strategy=PlacementGroupSchedulingStrategy(
+                        placement_group=self._pg,
+                        placement_group_bundle_index=i),
+                ).remote(i, num_workers, run_dir, env, num_to_keep)
+                for i in range(num_workers)
+            ]
+            try:
+                ray_tpu.get([w.node_info.remote() for w in self.workers],
+                            timeout=120)
+            except BaseException:
+                self.shutdown()
+                raise
 
     def execute(self, fn: Callable, *args, **kwargs) -> List[Any]:
         return ray_tpu.get(

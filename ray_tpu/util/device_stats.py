@@ -17,6 +17,10 @@ Design rules:
     where jax provides it, an argument-signature set otherwise), so
     it works identically under JAX_PLATFORMS=cpu — shape churn on a
     CPU host is the same bug as on a TPU host.
+  - What a compile COST comes from JAX itself: one ``jax.monitoring``
+    listener (``install_compile_listener``) turns every trace,
+    lowering, backend-compile and persistent-cache event of the process
+    into an ``xla.compile`` span and the ``compile_totals()``.
 """
 
 import logging
@@ -88,6 +92,7 @@ def reset() -> None:
     with _lock:
         _compiles.clear()
         _components.clear()
+        _totals.update(_ZERO_TOTALS)
         _watermark_bytes = 0
         _watermark_fraction = 0.0
         _last_step = None
@@ -327,6 +332,7 @@ def count_compiles(fn, name: Optional[str] = None):
     """Wrap a jitted callable so every (re)compilation is counted per
     function with shapes + wall time.  Transparent to callers."""
     label = name or getattr(fn, "__name__", None) or repr(fn)
+    install_compile_listener()
     return _CompileTracked(fn, label)
 
 
@@ -342,6 +348,121 @@ def recompiles_after_warmup() -> Dict[str, int]:
     with _lock:
         return {k: v["after_warmup"] for k, v in _compiles.items()
                 if v["after_warmup"]}
+
+
+# ---------------------------------------------------------------------------
+# compile cost: JAX's own monitoring events
+
+# event name -> the `event` attribute of its xla.compile span
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+# A program the persistent cache could hold, compiled and written to it.
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+_ZERO_TOTALS = {"compiles": 0, "cache_hits": 0, "cache_misses": 0,
+                "compile_s": 0.0, "cache_retrieval_s": 0.0,
+                "trace_lower_s": 0.0}
+_totals: Dict[str, Any] = dict(_ZERO_TOTALS)
+_listener_installed = False
+_thread = threading.local()
+
+
+# Trace and lowering events shorter than this get no span of their own
+# (every eager primitive traces for microseconds); the totals count all.
+_MIN_TRACE_SPAN_S = 1e-3
+
+
+def _outer_seconds(secs: float, end: float) -> float:
+    """Trace and lowering events nest: a jit traced inside another
+    reports its own duration, then the outer one reports both.  Of an
+    event that ended at `end`, the seconds not already reported by events
+    inside it on this thread, so that the total is the union."""
+    start = end - secs
+    seen = getattr(_thread, "intervals", None)
+    if seen is None:
+        seen = _thread.intervals = []
+    inner = 0.0
+    while seen and seen[-1][0] >= start - 1e-4:
+        inner += seen.pop()[1]
+    seen.append((start, secs))
+    del seen[:-64]
+    return max(0.0, secs - inner)
+
+
+def _on_compile_event(name: str, secs: float, **kw) -> None:
+    kind = _COMPILE_EVENTS.get(name)
+    if kind is None or not _enabled:
+        return
+    end = time.time()
+    hit = kind == "cache_retrieval"
+    with _lock:
+        if hit:
+            # JAX reports the retrieval inside the backend-compile event
+            # it served, on the same thread: remember it for that one.
+            _totals["cache_hits"] += 1
+            _totals["cache_retrieval_s"] += secs
+            _thread.retrieved_s = secs
+        elif kind == "backend_compile":
+            retrieved = getattr(_thread, "retrieved_s", None)
+            _thread.retrieved_s = None
+            hit = retrieved is not None
+            _totals["compiles"] += 1
+            _totals["compile_s"] += max(0.0, secs - (retrieved or 0.0))
+        else:
+            _totals["trace_lower_s"] += _outer_seconds(secs, end)
+            if secs < _MIN_TRACE_SPAN_S:
+                return
+    try:
+        from ray_tpu.util import tracing
+
+        tracing.record_span(
+            "xla.compile", end - secs, end, force=True,
+            attributes={"event": kind, "seconds": secs, "cache_hit": hit,
+                        "program": tracing.current_span_name() or "",
+                        "fun_name": str(kw.get("fun_name", ""))})
+    except Exception as exc:
+        warn_once(logger, "device-compile-span", exc,
+                  "could not record an xla.compile span")
+
+
+def _on_cache_event(name: str, **kw) -> None:
+    if name == _CACHE_MISS_EVENT and _enabled:
+        with _lock:
+            _totals["cache_misses"] += 1
+
+
+def install_compile_listener() -> bool:
+    """Register the process's one pair of ``jax.monitoring`` listeners,
+    once, if JAX is already loaded here (never imports it).  Called by
+    whatever first learns that the process has JAX: ``count_compiles``,
+    a ``tracing.trace_span``, ``compile_totals``."""
+    global _listener_installed
+    mon = sys.modules.get("jax.monitoring")
+    if mon is None or _listener_installed:
+        return _listener_installed
+    with _lock:
+        if _listener_installed:
+            return True
+        _listener_installed = True
+    mon.register_event_duration_secs_listener(_on_compile_event)
+    mon.register_event_listener(_on_cache_event)
+    return True
+
+
+def compile_totals() -> Dict[str, Any]:
+    """Process totals since start: ``compiles`` (backend compile events,
+    served from the persistent cache or not), ``cache_hits`` (those that
+    were), ``cache_misses`` (programs compiled and written to the cache:
+    0 in a warm run), and the seconds spent compiling (``compile_s``),
+    loading from the cache (``cache_retrieval_s``) and tracing + lowering
+    (``trace_lower_s``)."""
+    install_compile_listener()
+    with _lock:
+        return dict(_totals)
 
 
 # ---------------------------------------------------------------------------

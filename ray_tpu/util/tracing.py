@@ -22,6 +22,13 @@ metadata; here tracing is self-contained (zero extra deps, zero egress):
   - `export_chrome_trace(path)` merges local spans with the cluster task
     timeline (util/timeline.py, including its wire/scheduler lanes)
     into one chrome-trace file Perfetto can open.
+  - The profiler bridge: in a process that has already loaded JAX every
+    `trace_span` also enters a `jax.profiler.TraceAnnotation` named
+    "ray_tpu:<name>".  It costs about a microsecond while no profile is
+    taken and lands on the xplane's host plane, on the device planes'
+    clock, while one is; the ring keeps its own rule (enabled, or
+    forced).  Each annotation carries its epoch start (`t_epoch`), so
+    any one event gives the offset between the two clocks.
 """
 
 from __future__ import annotations
@@ -30,6 +37,7 @@ import contextvars
 import json
 import os
 import random
+import sys
 import threading
 import time
 import uuid
@@ -90,6 +98,61 @@ def _stack() -> List[str]:
     if not hasattr(_local, "stack"):
         _local.stack = []
     return _local.stack
+
+
+def _names() -> List[str]:
+    """Names of the trace_span()s open on this thread, recorded in the
+    ring or not: `current_span_name()` is what a compile event is
+    attributed to."""
+    if not hasattr(_local, "names"):
+        _local.names = []
+    return _local.names
+
+
+def current_span_name() -> Optional[str]:
+    names = _names()
+    return names[-1] if names else None
+
+
+# ---------------------------------------------------------------------------
+# Profiler bridge
+# ---------------------------------------------------------------------------
+
+ANNOTATION_PREFIX = "ray_tpu:"
+_jax_seen = False
+
+
+def _annotation_cls():
+    """jax.profiler.TraceAnnotation where this process has ALREADY loaded
+    JAX, else None: a sys.modules peek, never an import.  The first
+    sighting also installs device_stats' compile listener: "the process
+    has JAX" is learned here before anywhere else."""
+    global _jax_seen
+    cls = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+    if cls is not None and not _jax_seen:
+        _jax_seen = True
+        from ray_tpu.util import device_stats
+
+        device_stats.install_compile_listener()
+    return cls
+
+
+def _scalars(attributes: Optional[Dict[str, Any]]) -> Dict[str, Any]:
+    return {k: v for k, v in (attributes or {}).items()
+            if isinstance(v, (str, int, float))}
+
+
+def _mark_recorded(name: str, start: float, end: float,
+                   span_id: Optional[str]) -> None:
+    """A span recorded after the fact cannot be laid on the profiler's
+    clock at its own time.  While a profile runs it leaves a zero-length
+    "ray_tpu:recorded:<name>" marker that carries its epoch interval;
+    the marker's own `t_epoch` is the offset to place it by."""
+    cls = _annotation_cls()
+    if cls is not None and cls.is_enabled():
+        with cls(f"{ANNOTATION_PREFIX}recorded:{name}", t_epoch=time.time(),
+                 start=start, end=end, span_id=span_id or ""):
+            pass
 
 
 _rand = random.Random(uuid.uuid4().int)
@@ -167,39 +230,86 @@ def record_span(name: str, start: float, end: float,
                 trace_id: Optional[str] = None,
                 span_id: Optional[str] = None,
                 force: bool = False) -> Optional[str]:
-    """Record a completed span (no-op unless tracing is enabled or
-    `force` — execution spans restored from a remote context record even
-    in non-traced worker processes, so a worker-side export still shows
-    them)."""
+    """Record a completed span (the ring takes it only when tracing is
+    enabled or `force` — execution spans restored from a remote context
+    record even in non-traced worker processes, so a worker-side export
+    still shows them).  A running profile gets a marker either way."""
     if not (_enabled or force):
+        _mark_recorded(name, start, end, None)
         return None
     span_id = span_id or _new_id()
     _append_span((span_id,
                   parent_id or current_span_id(),
                   trace_id or current_trace_id(),
                   name, start, end, attributes))
+    _mark_recorded(name, start, end, span_id)
     return span_id
 
 
 @contextmanager
-def trace_span(name: str, attributes: Optional[Dict[str, Any]] = None):
+def trace_span(name: str, attributes: Optional[Dict[str, Any]] = None,
+               force: bool = False, start: Optional[float] = None):
     """Context manager for a nested span; cheap no-op when disabled.
     A caller-provided `attributes` dict is kept by identity, so fields
-    added inside (or just after) the block land on the span."""
-    if not _enabled:
+    added inside (or just after) the block land on the span.
+
+    `force` records in the ring whatever the tracing flag says (the
+    start-up timeline, a program's first call).  `start` is the epoch
+    time the span began where that was before the block (process start,
+    a caller's entry).  In a process that has loaded JAX the block also
+    runs under a "ray_tpu:<name>" profiler annotation (module docstring)."""
+    ann_cls = _annotation_cls()
+    record = _enabled or force
+    if not record and ann_cls is None:
         yield None
         return
-    span_id = _new_id()
-    parent = current_span_id()
-    trace_id = current_trace_id()
-    _stack().append(span_id)
-    start = time.time()
+    span_id = parent = trace_id = None
+    if record:
+        span_id = _new_id()
+        parent = current_span_id()
+        trace_id = current_trace_id()
+        _stack().append(span_id)
+    _names().append(name)
+    t0 = time.time()
+    ann = None
+    if ann_cls is not None:
+        # TraceMe encodes its keyword metadata only while a profile runs.
+        meta = _scalars(attributes) if ann_cls.is_enabled() else {}
+        ann = ann_cls(ANNOTATION_PREFIX + name,
+                      **{**meta, "t_epoch": t0, "span_id": span_id or ""})
+        ann.__enter__()
     try:
         yield span_id
     finally:
-        _stack().pop()
-        _append_span((span_id, parent, trace_id, name, start,
-                      time.time(), attributes))
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        _names().pop()
+        if record:
+            _stack().pop()
+            _append_span((span_id, parent, trace_id, name,
+                          t0 if start is None else start,
+                          time.time(), attributes))
+
+
+# The driver's start-up phases that belong to the runtime, not to one
+# JaxTrainer.fit: recorded once, by ray_tpu.init.
+RUNTIME_STARTUP_SPANS = ("startup.process", "startup.runtime",
+                         "startup.head", "startup.node_manager",
+                         "startup.worker_template")
+
+
+def process_start_time() -> Optional[float]:
+    """Epoch time the OS started this process (Linux: field 22 of
+    /proc/self/stat, clock ticks since boot, against /proc/uptime; 10 ms
+    steps), or None where /proc does not say."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return time.time() - (uptime - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError):  # raylint: allow-swallow(no /proc here: None is the documented answer)
+        return None
 
 
 # ---------------------------------------------------------------------------
@@ -258,12 +368,6 @@ def clock_offset() -> float:
     return _CLOCK_OFFSET
 
 
-def mono_to_epoch(t_mono: float) -> float:
-    """Convert a time.monotonic() reading from THIS process to epoch
-    seconds (comparable across processes, same basis as span times)."""
-    return t_mono + _CLOCK_OFFSET
-
-
 def serve_trace_enabled() -> bool:
     """Request-journey tracing gate for the serve data plane
     (RAY_TPU_SERVE_TRACE, default on).  Read per request — an env read
@@ -319,13 +423,16 @@ def new_span_id() -> str:
 # Introspection / export
 # ---------------------------------------------------------------------------
 
-def get_spans() -> List[Dict[str, Any]]:
+def get_spans(prefixes: Tuple[str, ...] = ()) -> List[Dict[str, Any]]:
+    """The ring as dicts, oldest first; with `prefixes`, only the spans
+    whose name starts with one of them."""
     with _spans_lock:
         rows = list(_spans)
     return [{"span_id": s, "parent_id": p, "trace_id": t, "name": n,
              "start": st, "end": en,
              "attributes": {} if a is None else a}
-            for s, p, t, n, st, en, a in rows]
+            for s, p, t, n, st, en, a in rows
+            if not prefixes or n.startswith(prefixes)]
 
 
 def clear_spans() -> None:

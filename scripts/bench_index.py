@@ -31,8 +31,10 @@ from typing import Any, Dict, List, Optional
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # Everything bench-shaped the repo root accumulates.  MULTICHIP/SCALE
-# predate the *_BENCH naming and are folded in explicitly.
-PATTERNS = ("BENCH_r*.json", "*BENCH*.json", "MULTICHIP_r*.json",
+# predate the *_BENCH naming and are folded in explicitly.  A name ends
+# in BENCH or BENCH_r<n>: BENCHMARK.json is the benchmark's contract,
+# not a result.
+PATTERNS = ("*BENCH.json", "*BENCH_r*.json", "MULTICHIP_r*.json",
             "SCALE_r*.json")
 
 _ROUND_RE = re.compile(r"_r(\d+)\.json$")

@@ -21,6 +21,19 @@ read as nested slices on a single row.  Device-plane records become
 roofline/MFU counter tracks plus instant recompile markers on a
 "device plane" process.  `--since` / `--until` take epoch seconds;
 `--last N` means "the last N seconds".
+
+A profiler trace joins the same picture: `--xplane <file>.xplane.pb`
+lays the device's program executions ("XLA Modules") and the host
+annotations (`ray_tpu:<span>` and any other `<prefix>:<name>`) beside
+the spans, moved from the profiler's clock to the epoch clock by the
+`t_epoch` every `ray_tpu:` annotation carries (ray_tpu/util/tracing.py);
+`--timeline <run_dir>/timeline.json` adds a train run's start-up and
+train-step spans (JaxTrainer.fit writes the file).  Neither needs a
+journal:
+
+    python scripts/opsdump.py --xplane t.xplane.pb \
+        --timeline run/timeline.json --out trace.json
+    python scripts/opsdump.py --xplane t.xplane.pb --stats  # the offset
 """
 
 from __future__ import annotations
@@ -28,15 +41,18 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
+import statistics
 import sys
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional, Tuple
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from ray_tpu.util import journal  # noqa: E402
 from ray_tpu.util.tracing import (  # noqa: E402
+    ANNOTATION_PREFIX,
     span_row_to_dict,
     spans_to_chrome_events,
 )
@@ -52,6 +68,12 @@ _SERVE_PID = 1 << 22
 # Synthetic process for device-plane telemetry (roofline/MFU counter
 # tracks + recompile instant markers), one thread lane per OS pid.
 _DEVICE_PID = (1 << 22) + 1
+# Synthetic processes for a profiler trace: one per xplane plane.
+_XPLANE_PID = (1 << 22) + 16
+# A host annotation somebody named on purpose: "<prefix>:<name>" (ours,
+# a benchmark's "bench:"), not the runtime's own "Class::Method" events.
+_ANNOTATION = re.compile(r"^\w+:[^:\s]")
+_DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+$")
 
 
 def serve_request_events(spans: List[dict]) -> List[Dict[str, Any]]:
@@ -211,6 +233,77 @@ def device_events(envs: List[dict]) -> List[Dict[str, Any]]:
     return events
 
 
+def read_xplane(path: str) -> Dict[str, Dict[str, list]]:
+    """{plane: {line: [(name, start_ns, duration_ns, stats)]}} of the
+    device planes' "XLA Modules" lines and the host planes' annotations."""
+    from jax.profiler import ProfileData
+
+    planes: Dict[str, Dict[str, list]] = {}
+    for plane in ProfileData.from_file(path).planes:
+        device = bool(_DEVICE_PLANE.match(plane.name))
+        for line in plane.lines:
+            if device and line.name != "XLA Modules":
+                continue
+            kept = [(e.name, float(e.start_ns), float(e.duration_ns),
+                     dict(e.stats) if not device else {})
+                    for e in line.events
+                    if device or _ANNOTATION.match(e.name)]
+            if kept:
+                planes.setdefault(plane.name, {})[line.name] = kept
+    return planes
+
+
+def clock_offset(planes: Dict[str, Dict[str, list]]
+                 ) -> Optional[Tuple[float, float, int]]:
+    """(offset_s, spread_s, n): epoch = profiler time + offset, the
+    median over every `ray_tpu:` annotation that carries `t_epoch`, with
+    the widest disagreement between two of them; None when none does."""
+    offsets = [float(st["t_epoch"]) - start / 1e9
+               for lines in planes.values() for evs in lines.values()
+               for name, start, _, st in evs
+               if name.startswith(ANNOTATION_PREFIX) and "t_epoch" in st]
+    if not offsets:
+        return None
+    return (statistics.median(offsets), max(offsets) - min(offsets),
+            len(offsets))
+
+
+def xplane_events(planes: Dict[str, Dict[str, list]], offset_s: float
+                  ) -> List[Dict[str, Any]]:
+    """The trace's events as chrome slices on the epoch clock."""
+    events: List[Dict[str, Any]] = []
+    for i, (plane, lines) in enumerate(sorted(planes.items())):
+        pid = _XPLANE_PID + i
+        for tid, (line, evs) in enumerate(sorted(lines.items())):
+            for name, start, dur, st in evs:
+                events.append({
+                    "cat": "xplane", "name": name[:120], "ph": "X",
+                    "pid": pid, "tid": tid,
+                    "ts": start / 1e3 + offset_s * 1e6, "dur": dur / 1e3,
+                    "args": {k: v for k, v in st.items()
+                             if isinstance(v, (str, int, float))}})
+            events.append({"ph": "M", "pid": pid, "tid": tid,
+                           "name": "thread_name", "args": {"name": line}})
+        events.append({"ph": "M", "pid": pid, "name": "process_name",
+                       "args": {"name": f"profile {plane}"}})
+    return events
+
+
+def timeline_events(path: str) -> List[Dict[str, Any]]:
+    """A train run's timeline.json: each process's spans on its pid."""
+    with open(path) as f:
+        doc = json.load(f)
+    by_proc: Dict[tuple, List[dict]] = {}
+    for s in doc.get("spans", []) + doc.get("compiles", []):
+        by_proc.setdefault((s.get("pid", 0), s.get("worker", "")),
+                           []).append(s)
+    events: List[Dict[str, Any]] = []
+    for (pid, worker), spans in sorted(by_proc.items()):
+        events.extend(spans_to_chrome_events(
+            spans, pid=pid or 1, process_name=f"train {worker} ({pid})"))
+    return events
+
+
 def dump_stats(directory: str) -> Dict[str, Any]:
     out: Dict[str, Any] = {"dir": directory}
     for stream in STREAMS:
@@ -268,21 +361,44 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="",
                     help="output file (default: stdout)")
     ap.add_argument("--stats", action="store_true",
-                    help="print per-stream segment/record counts "
-                         "instead of a trace")
+                    help="print per-stream segment/record counts (and a "
+                         "profile's clock offset) instead of a trace")
+    ap.add_argument("--xplane", default="",
+                    help="a profiler trace (.xplane.pb) to lay beside "
+                         "the spans, on the epoch clock")
+    ap.add_argument("--timeline", default="",
+                    help="a train run's timeline.json to add")
     args = ap.parse_args(argv)
-    if not args.dir:
-        ap.error("--dir required (or set RAY_TPU_OPS_JOURNAL_DIR)")
+    if not (args.dir or args.xplane or args.timeline):
+        ap.error("--dir required (or set RAY_TPU_OPS_JOURNAL_DIR), "
+                 "unless --xplane or --timeline is given")
     since = args.since
     if args.last > 0:
         since = max(since, time.time() - args.last)
+    planes, offset = {}, None
+    if args.xplane:
+        planes = read_xplane(args.xplane)
+        offset = clock_offset(planes)
     if args.stats:
-        print(json.dumps(dump_stats(args.dir), indent=2))
+        stats = dump_stats(args.dir) if args.dir else {}
+        if args.xplane:
+            stats["xplane"] = None if offset is None else {
+                "clock_offset_s": offset[0],
+                "offset_spread_ms": offset[1] * 1e3,
+                "annotations": offset[2]}
+        print(json.dumps(stats, indent=2))
         return 0
     streams = tuple(s.strip() for s in args.streams.split(",")
                     if s.strip())
     events = build_trace(args.dir, since=since, until=args.until,
-                         streams=streams)
+                         streams=streams) if args.dir else []
+    if args.xplane:
+        if offset is None:
+            print("no ray_tpu: annotation carries t_epoch: the profile "
+                  "stays on its own clock", file=sys.stderr)
+        events.extend(xplane_events(planes, offset[0] if offset else 0.0))
+    if args.timeline:
+        events.extend(timeline_events(args.timeline))
     payload = json.dumps({"traceEvents": events}, default=str)
     if args.out:
         with open(args.out, "w") as fh:

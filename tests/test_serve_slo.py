@@ -314,6 +314,35 @@ def _get_json(url):
         return json.loads(r.read())
 
 
+def test_release_does_not_look_the_router_up_again():
+    """A response's slot is released by a future's callback, on the RPC
+    receive thread: it must go to the router that assigned it and never
+    look one up (after serve.shutdown that is a blocking controller
+    lookup, whose reply only that thread could receive: the cluster test
+    below hung there when the killed replica's death arrived late)."""
+    import threading
+
+    from ray_tpu.serve.handle import DeploymentResponse
+
+    released = []
+
+    class _Router:
+        def release(self, hex_id):
+            released.append(hex_id)
+
+    class _Handle:
+        def _router(self):
+            raise AssertionError("looked the router up from a callback")
+
+    resp = object.__new__(DeploymentResponse)
+    resp._handle, resp._lock = _Handle(), threading.Lock()
+    resp._assigned_hex, resp._assigned_router = "abc", _Router()
+    resp._released = False
+    resp._release()
+    resp._release()                     # idempotent
+    assert released == ["abc"]
+
+
 def test_cluster_journey_trace_slo_and_partial_timeline(
         tmp_path, monkeypatch):
     """End to end on a real local cluster with the ops journal on:
