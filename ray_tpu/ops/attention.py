@@ -4,17 +4,27 @@ The reference framework ships no attention kernels (SURVEY.md §5 — long-conte
 machinery is absent in-tree); on TPU this is a core op.  Design:
 
   - `flash_attention(q, k, v, causal=...)`: online-softmax tiled kernel
-    (Pallas, grid over (batch*heads, q-blocks), fori_loop over k-blocks) so
-    the s×s score matrix never materializes in HBM.
+    (Pallas, grid over (batch*heads, q-tiles), K/V of one head resident in
+    VMEM) so the s×s score matrix never materializes in HBM.
   - `flash_attention_chunk(...)`: the offset-aware variant returning
     (out, lse) — the building block ring attention uses per K/V chunk
     (ops/ring_attention.py); positions enter as DYNAMIC scalars so the
     same compiled kernel serves every ring step.
   - Backward: Pallas dq and dk/dv kernels recomputing scores blockwise
     from the saved logsumexp (standard flash backward — dq grid over
-    q-blocks, dkv grid over k-blocks); the s×s matrix never exists in
+    q-tiles, dkv grid over k-tiles); the s×s matrix never exists in
     the backward either.  The lse OUTPUT is differentiable too (ring
     attention's merge weights depend on it): ds += p * dlse.
+  - One plan for the three kernels, made from what each can see (head
+    size, sm_scale, the offsets): a program's long tile meets the blocks
+    wholly under the diagonal in a loop and the blocks the diagonal
+    crosses in straight-line steps against only the part of the tile
+    that can see them, so few scores above the diagonal are computed;
+    scores are held [keys, queries], so softmax reduces down sublanes;
+    a power-of-two sm_scale (head size 64) is folded into the operand
+    tile, which is exact; tile and block sizes come from `default_blocks`
+    unless passed.  The comment above `_scale_is_exact` has the reasons,
+    `dispatch.taken()["flash_attention.plan"]` what ran.
   - CPU / odd-shape fallback: `attention_reference` with identical
     semantics — the numerical ground truth in tests (which compare both
     paths in interpret mode, values and grads).
@@ -71,91 +81,267 @@ def attention_reference(q, k, v, causal: bool = True,
 
 
 # ---------------------------------------------------------------------------
-# Pallas forward kernel (offset-aware, emits logsumexp)
+# The plan: what each kernel decides from what it can see
 # ---------------------------------------------------------------------------
 # Scalar-prefetch arg offs = [q_off, kv_off]: global position of this
 # operand's row/col 0.  The plain causal call uses (sk - sq, 0) (ends
 # aligned); ring attention passes each chunk's global offsets, so one
 # compiled kernel serves every ring step (fully-unmasked, diagonal, and
 # fully-masked chunks alike).
+#
+# What the v5e charges for at head size 64 (PERF.md, PR 29): the MXU,
+# whose tiles a 64-wide contraction or result half fills, and the
+# cross-LANE reductions of softmax.  The mask and the scale are free (the
+# vector slots they take are idle anyway).  So:
+#
+#   - A program's tile is `tile` long and is worked against blocks of
+#     `inner` of the other operand, tile = R * inner.  Blocks wholly under
+#     the diagonal meet the whole tile in a loop; the R - 1 blocks the
+#     diagonal then crosses are straight-line steps, each against only the
+#     part of the tile that can see it (a static slice), so the scores
+#     above the diagonal are mostly never computed.  Which blocks those
+#     are comes from `offs` (`_first_narrow_block`), so the offsets may be
+#     traced and lie off the block grid; every step masks, and a step whose
+#     block lies outside the operand is masked whole.
+#   - All three kernels hold scores TRANSPOSED, [keys, queries]: softmax's
+#     max and sum then run down sublanes (plain vector max / add), lse,
+#     delta and dlse broadcast as they are stored, and p^T · do, ds^T · q
+#     need no transposed operand.  The forward's accumulator and dq are
+#     held [d, queries] and turned once a program.
+#   - A power-of-two scale (`_scale_is_exact`: 1/sqrt(64) = 0.125) leaves
+#     the score tile: scaling a bf16 or f32 value by a power of two only
+#     moves its exponent, so (q·scale)·k^T equals (q·k^T)·scale bit for
+#     bit.  The [tile, d] operand is scaled once a program, ds stays
+#     unscaled and the f32 accumulator of dq / dk is scaled at the end.
+#     Any other scale (head size 128) keeps its per-score multiplies.
+#   - Tile and block sizes come from `default_blocks` (head size, lengths,
+#     dtype) unless the caller passes block_q / block_k, which then hold
+#     for all three kernels.
+
+def _scale_is_exact(sm_scale: float) -> bool:
+    """True when sm_scale is a power of two, so it commutes with every
+    rounding between the operand tile and the accumulator."""
+    return sm_scale > 0 and math.frexp(sm_scale)[0] == 0.5
+
+
+def _tile_and_inner(seq_tile: int, seq_inner: int):
+    """The longest tile up to 2048 that divides seq_tile, against blocks
+    of 512; where the lengths do not divide so, the old single size
+    min(512, length) for each."""
+    inner = min(512, seq_inner)
+    tile = next((t for t in (2048, 1024, 512)
+                 if seq_tile % t == 0 and t % inner == 0), None)
+    return (tile or min(512, seq_tile)), inner
+
+
+def default_blocks(head_dim: int, seq_q: int, seq_k: int, dtype):
+    """((block_q, block_k) of the forward, of dq, of dk/dv) for a caller
+    that passes none, from the v5e's sweep at sequence 2048, bf16, head
+    sizes 64 and 128 (PERF.md, PR 29).  The forward and dq tile the
+    queries, dk/dv tiles the keys.  Blocks of 256 or 128 leave fewer
+    scores above the diagonal and run 5-10 % faster, but each block is one
+    more straight-line step to trace in every process that builds the
+    kernel, and a train worker's start pays for that (PERF.md)."""
+    del head_dim, dtype        # the sweep gave one answer for those it ran
+    fwd = _tile_and_inner(seq_q, seq_k)
+    kv_tile, q_inner = _tile_and_inner(seq_k, seq_q)
+    return fwd, fwd, (q_inner, kv_tile)
+
+
+def _narrow_steps(tile: int, inner: int) -> int:
+    """R - 1: how many blocks on the diagonal meet less than the tile."""
+    return tile // inner - 1 if tile % inner == 0 else 0
+
+
+def _first_narrow_block(tile_min, inner: int):
+    """Index of the first block of `inner` that the tile's first `inner`
+    positions cannot see (tile_min: the tile's first position less the
+    other operand's): blocks before it meet the whole tile, block
+    index + t - 1 (t >= 1) only the tile from t * inner on.  -(-a // b)
+    is ceil for either sign (NOT lax.div, which truncates toward zero)."""
+    return -jnp.floor_divide(-tile_min, inner) + 1
+
+
+def _dead_share(q_off: int, kv_off: int, seq_q: int, seq_k: int,
+                block_q: int, block_k: int) -> float:
+    """Share of the scores a kernel that tiles the queries computes that
+    lie above the diagonal, for static offsets: `_walk_blocks`' bounds in
+    Python, for the plan record."""
+    narrow = _narrow_steps(block_q, block_k)
+    num_k = seq_k // block_k
+    computed = 0
+    for qi in range(seq_q // block_q):
+        row_min = q_off - kv_off + qi * block_q
+        if narrow:
+            j1 = -(-row_min // block_k) + 1
+            computed += min(max(j1, 0), num_k) * block_q * block_k
+            computed += sum((block_q - t * block_k) * block_k
+                            for t in range(1, narrow + 1)
+                            if 0 <= j1 + t - 1 < num_k)
+        else:
+            hi = (row_min + block_q - 1) // block_k + 1
+            computed += min(max(hi, 0), num_k) * block_q * block_k
+    live = sum(min(max(q_off - kv_off + 1 + row, 0), seq_k)
+               for row in range(seq_q))
+    return 1.0 - live / computed if computed else 0.0
+
+
+def _query_minus_key(num_keys: int, num_queries: int):
+    """[keys, queries] int32: index of the query less index of the key.
+    Made once a program; a step's causal mask is a static slice of it
+    compared with one scalar (first key position - first query position)."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (num_keys, num_queries), 1)
+            - jax.lax.broadcasted_iota(jnp.int32, (num_keys, num_queries), 0))
+
+
+_NOTHING_VISIBLE = 1 << 30      # a `first` no query index reaches
+
+
+def _keep(visible, x, otherwise: float):
+    """x where visible, else the constant.  lax.select, not jnp.where: a
+    step's shapes are its own, so each jnp.where would be one more jit to
+    trace in every process that builds the kernel."""
+    return jax.lax.select(visible, x, jax.lax.full_like(x, otherwise))
+
+
+def _dot(a, b, contract_a: int, contract_b: int):
+    """MXU matmul in the operands' NATIVE dtype with f32 accumulation: a
+    bf16×bf16 matmul runs the MXU at full rate, while upcasting inputs
+    to f32 forces the multi-pass f32 path (~3-6× slower)."""
+    return jax.lax.dot_general(
+        a, b, (((contract_a,), (contract_b,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _scaled(x, sm_scale: float):
+    return (x.astype(jnp.float32) * sm_scale).astype(x.dtype)
+
+
+def _put(old, at: int, new):
+    """`old` [.., queries] with its queries from `at` on (static) replaced."""
+    return jnp.concatenate([old[:, :at], new], axis=1) if at else new
+
+
+def _narrow_block(j, inner: int, num_blocks: int, first):
+    """(start, first) of a block on the diagonal, which may lie outside
+    the operand (a chunk from the past or the future): then an inside
+    block is read and nothing of it is visible."""
+    inside = jnp.logical_and(j >= 0, j < num_blocks)
+    start = jnp.minimum(jnp.maximum(j, 0), num_blocks - 1) * inner
+    return start, jax.lax.select(inside, first,
+                                 jnp.int32(_NOTHING_VISIBLE))
+
+
+def _walk_blocks(step, carry, causal: bool, tile_min, tile: int, inner: int,
+                 num_blocks: int):
+    """Run step(start, first, carry, lo) over the blocks of `inner` keys
+    that a tile of queries meets (the forward's and dq's order: the loop,
+    then the diagonal).  start: the block's first key; first: that key's
+    position less the tile's first query's, for the mask; lo (static):
+    where in the tile the queries that can see the block begin."""
+    def whole(j, carry):
+        return step(j * inner, j * inner - tile_min, carry, 0)
+
+    if not causal:
+        return jax.lax.fori_loop(0, num_blocks, whole, carry)
+    narrow = _narrow_steps(tile, inner)
+    if not narrow:
+        hi = jnp.floor_divide(tile_min + tile - 1, inner) + 1
+        return jax.lax.fori_loop(0, jnp.clip(hi, 0, num_blocks), whole, carry)
+    j1 = _first_narrow_block(tile_min, inner)
+    carry = jax.lax.fori_loop(0, jnp.clip(j1, 0, num_blocks), whole, carry)
+    for t in range(1, narrow + 1):
+        j = j1 + (t - 1)
+        carry = step(*_narrow_block(j, inner, num_blocks,
+                                    j * inner - tile_min), carry, t * inner)
+    return carry
+
+
+# ---------------------------------------------------------------------------
+# Pallas forward kernel (offset-aware, emits logsumexp)
+# ---------------------------------------------------------------------------
 
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                 causal: bool, block_q: int, block_k: int, seq_k: int,
-                sm_scale: float):
+                sm_scale: float, fold_scale: bool):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    # Keep q in its NATIVE dtype: on TPU a bf16×bf16 matmul with f32
-    # accumulation runs the MXU at full rate, while upcasting inputs to
-    # f32 forces the multi-pass f32 path (~3-6× slower).  sm_scale is
-    # applied to the f32 scores after the matmul instead.
     q = q_ref[0]  # [block_q, d]
     d = q.shape[-1]
+    if fold_scale:
+        q = _scaled(q, sm_scale)
+    query_minus_key = _query_minus_key(block_k, block_q) if causal else None
 
-    m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-
-    num_k_blocks = seq_k // block_k
-    if causal:
-        q_off = offs_ref[0]
-        kv_off = offs_ref[1]
-        # Last k-block any row of this q-block may attend to:
-        # col <= q_off - kv_off + row_max.  floor_divide (NOT lax.div,
-        # which truncates toward zero) so negative row_max yields hi=0.
-        row_max = q_off - kv_off + (qi + 1) * block_q - 1
-        hi = jnp.clip(jnp.floor_divide(row_max, block_k) + 1,
-                      0, num_k_blocks)
-    else:
-        hi = num_k_blocks
-
-    def body(j, carry):
-        m, l, acc = carry
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        # [block_q, block_k] f32
+    def step(start, first, carry, lo: int):
+        """The key block at `start` against queries [lo, block_q) of the
+        tile.  Scores, statistics and the accumulator are held [keys | d,
+        queries]."""
+        m, l, acc = (x[:, lo:] for x in carry)
+        start = pl.multiple_of(start, block_k)
+        k_blk = k_ref[0, pl.ds(start, block_k), :]
+        v_blk = v_ref[0, pl.ds(start, block_k), :]
+        s = _dot(k_blk, q[lo:], 1, 1)              # [block_k, block_q - lo]
+        if not fold_scale:
+            s = s * sm_scale
         if causal:
-            rows = offs_ref[0] + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = offs_ref[1] + j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(cols <= rows, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            s = _keep(query_minus_key[:, lo:] >= first, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = l * alpha + jnp.sum(p, axis=0, keepdims=True)
         # p in v's dtype for the second MXU matmul (f32 accumulation
         # preserved by preferred_element_type) — same as every
         # production flash kernel; probabilities are in [0, 1] so bf16
         # rounding here is benign relative to the softmax itself.
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+        acc_new = acc * alpha + _dot(v_blk, p.astype(v_blk.dtype), 0, 0)
+        return tuple(_put(old, lo, new) for old, new in
+                     zip(carry, (m_new, l_new, acc_new)))
 
-    m, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, acc0))
+    carry = (jnp.full((1, block_q), _NEG_INF, jnp.float32),
+             jnp.zeros((1, block_q), jnp.float32),
+             jnp.zeros((d, block_q), jnp.float32))
+    m, l, acc = _walk_blocks(
+        step, carry, causal, offs_ref[0] - offs_ref[1] + qi * block_q,
+        block_q, block_k, seq_k // block_k)
     l_safe = jnp.maximum(l, 1e-30)
-    # Rows with no visible keys (possible in ring chunks "from the
+    # Queries with no visible keys (possible in ring chunks "from the
     # future"): m stayed at -inf, so p accumulated exp(0)=1 garbage —
     # zero the output and mark lse = -inf ("no weight" for the merge).
     valid = m > _NEG_INF / 2
-    o_ref[0] = jnp.where(valid, acc / l_safe, 0.0).astype(o_ref.dtype)
+    o_ref[0] = jnp.where(valid, acc / l_safe, 0.0).T.astype(o_ref.dtype)
     lse = jnp.where(valid & (l > 0), m + jnp.log(l_safe), _NEG_INF)
     # lse is logically [block_q]; stored broadcast over an 8-sublane axis so
     # the block shape ends in (8, block_q) per Mosaic's tiling constraint.
-    lse_ref[0] = jnp.broadcast_to(lse[:, 0][None, :], (8, block_q))
+    lse_ref[0] = jnp.broadcast_to(lse, (8, block_q))
 
 
+def _compiler_params():
+    """Scoped VMEM above the v5e's default of 16 MiB: at sequence 8192
+    and head size 128 the resident K and V (double-buffered) and a
+    [256, 2048] f32 score tile with its neighbours need 17.4 MiB."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(vmem_limit_bytes=32 << 20)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
 def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
-               block_q: int, block_k: int):
+               block_q: int, block_k: int,
+               fold_scale: Optional[bool] = None):
+    """fold_scale is for the tests alone (None: fold when exact).
+
+    Jitted so that one trace serves both of a train step's calls (the
+    primal under jax.checkpoint and the custom VJP's forward rule) and one
+    lowering both of the program's copies (primal and remat): a kernel
+    with straight-line steps is slow to trace, in every process."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
     sk = k.shape[1]
+    if fold_scale is None:
+        fold_scale = _scale_is_exact(sm_scale)
     # fold batch*heads, put seq in the middle: [bh, s, d]
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
@@ -164,7 +350,7 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
     grid = (b * h, sq // block_q)
     kernel = functools.partial(
         _fwd_kernel, causal=causal, block_q=block_q, block_k=block_k,
-        seq_k=sk, sm_scale=sm_scale)
+        seq_k=sk, sm_scale=sm_scale, fold_scale=fold_scale)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -184,6 +370,7 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, sq), jnp.float32),
         ],
+        compiler_params=_compiler_params(),
         interpret=dispatch.interpret_mode(),
         name="flash_fwd",
     )(offs, qf, kf, vf)
@@ -195,118 +382,121 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
 # Pallas backward kernels: recompute-by-block using the saved logsumexp.
 # Standard flash backward split (the reference design point is the public
 # flash-attention algorithm, not the Ray repo): dq iterates k-blocks per
-# q-block; dk/dv iterate q-blocks per k-block.  delta = rowsum(do * out)
-# is precomputed outside; dlse is the cotangent of the lse OUTPUT (zero
-# for plain flash_attention, nonzero under ring attention's merge).
+# q-tile; dk/dv iterate q-blocks per k-tile.  corr = delta - dlse is
+# precomputed outside: delta = rowsum(do * out), and dlse is the cotangent
+# of the lse OUTPUT (zero for plain flash_attention, nonzero under ring
+# attention's merge).  Both follow the forward's plan; with an exact scale
+# ds = p * (dp - corr) stays unscaled per score.
 # ---------------------------------------------------------------------------
 
-def _bwd_recompute_p(q, k, lse_row, rows, cols, causal, sm_scale):
-    """Shared score recompute: p_ij = exp(q·k·scale - lse_i), masked."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
-    p = jnp.exp(s - lse_row[:, None])
-    if causal:
-        p = jnp.where(cols <= rows, p, 0.0)
-    return p
-
-
 def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   delta_ref, dlse_ref, dq_ref, *, causal: bool,
-                   block_q: int, block_k: int, seq_k: int, sm_scale: float):
+                   corr_ref, dq_ref, *, causal: bool,
+                   block_q: int, block_k: int, seq_k: int, sm_scale: float,
+                   fold_scale: bool):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     q = q_ref[0]                              # [block_q, d] native dtype
     do = do_ref[0]                            # [block_q, d] native dtype
-    lse = lse_ref[0, 0, :]                    # [block_q]
-    # (delta + (-dlse)) enters every column uniformly: fold into one term.
-    corr = delta_ref[0, 0, :] - dlse_ref[0, 0, :]  # [block_q]
     d = q.shape[-1]
+    if fold_scale:
+        q = _scaled(q, sm_scale)
+    query_minus_key = _query_minus_key(block_k, block_q) if causal else None
 
-    num_k_blocks = seq_k // block_k
-    if causal:
-        row_max = offs_ref[0] - offs_ref[1] + (qi + 1) * block_q - 1
-        hi = jnp.clip(jnp.floor_divide(row_max, block_k) + 1,
-                      0, num_k_blocks)
-    else:
-        hi = num_k_blocks
-
-    def body(j, dq):
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
+    def step(start, first, dq, lo: int):
+        """The key block at `start` against queries [lo, block_q) of the
+        tile; dq is held [d, queries]."""
+        start = pl.multiple_of(start, block_k)
+        k_blk = k_ref[0, pl.ds(start, block_k), :]
+        v_blk = v_ref[0, pl.ds(start, block_k), :]
+        s = _dot(k_blk, q[lo:], 1, 1)              # [block_k, block_q - lo]
+        if not fold_scale:
+            s = s * sm_scale
+        # lse and corr: [1, queries] as stored, sliced from the REF (a
+        # value sliced off the lane grid does not broadcast in Mosaic).
+        p = jnp.exp(s - lse_ref[0, 0:1, lo:])
         if causal:
-            rows = offs_ref[0] + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = offs_ref[1] + j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-        else:
-            rows = cols = None
-        p = _bwd_recompute_p(q, k_blk, lse, rows, cols, causal, sm_scale)
-        dp = jax.lax.dot_general(                  # do · v^T
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [block_q, block_k]
-        ds = p * (dp - corr[:, None]) * sm_scale
-        return dq + jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            p = _keep(query_minus_key[:, lo:] >= first, p, 0.0)
+        dp = _dot(v_blk, do[lo:], 1, 1)                 # dp^T = v · do^T
+        ds = p * (dp - corr_ref[0, 0:1, lo:])
+        if not fold_scale:
+            ds = ds * sm_scale
+        return _put(dq, lo, dq[:, lo:]
+                    + _dot(k_blk, ds.astype(k_blk.dtype), 0, 0))
 
-    dq = jax.lax.fori_loop(0, hi, body, jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dq = _walk_blocks(
+        step, jnp.zeros((d, block_q), jnp.float32), causal,
+        offs_ref[0] - offs_ref[1] + qi * block_q, block_q, block_k,
+        seq_k // block_k)
+    if fold_scale:
+        dq = dq * sm_scale
+    dq_ref[0] = dq.T.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, dlse_ref, dk_ref, dv_ref, *, causal: bool,
-                    block_q: int, block_k: int, seq_q: int, sm_scale: float):
+                    corr_ref, dk_ref, dv_ref, *, causal: bool,
+                    block_q: int, block_k: int, seq_q: int, sm_scale: float,
+                    fold_scale: bool):
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
     k = k_ref[0]                              # [block_k, d] native dtype
     v = v_ref[0]
     d = k.shape[-1]
+    k_s = _scaled(k, sm_scale) if fold_scale else k
+    num_q = seq_q // block_q
+    query_minus_key = _query_minus_key(block_k, block_q) if causal else None
+    # this tile's first key less the queries' first position
+    tile_min = offs_ref[1] - offs_ref[0] + ki * block_k
 
-    num_q_blocks = seq_q // block_q
-    if causal:
-        # First q-block whose last row can see this k-block's first col.
-        lo = jnp.clip(
-            jnp.floor_divide(offs_ref[1] + ki * block_k - offs_ref[0],
-                             block_q),
-            0, num_q_blocks)
-    else:
-        lo = 0
-
-    def body(j, carry):
+    def step(start, first, carry, hi: int):
+        """The query block at `start` against keys [0, hi) of the tile (the
+        roles turn: the diagonal cuts the tile's END off).  Scores are
+        held [keys, queries], as in the forward."""
         dk, dv = carry
-        q_blk = q_ref[0, pl.ds(j * block_q, block_q), :]
-        do_blk = do_ref[0, pl.ds(j * block_q, block_q), :]
-        lse_blk = lse_ref[0, 0, pl.ds(j * block_q, block_q)]
-        corr = (delta_ref[0, 0, pl.ds(j * block_q, block_q)]
-                - dlse_ref[0, 0, pl.ds(j * block_q, block_q)])
+        start = pl.multiple_of(start, block_q)
+        q_blk = q_ref[0, pl.ds(start, block_q), :]
+        do_blk = do_ref[0, pl.ds(start, block_q), :]
+        lse_blk = lse_ref[0, 0:1, pl.ds(start, block_q)]    # [1, block_q]
+        corr = corr_ref[0, 0:1, pl.ds(start, block_q)]
+        s = _dot(k_s[:hi], q_blk, 1, 1)                     # [hi, block_q]
+        if not fold_scale:
+            s = s * sm_scale
+        p = jnp.exp(s - lse_blk)
         if causal:
-            rows = offs_ref[0] + j * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = offs_ref[1] + ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-        else:
-            rows = cols = None
-        p = _bwd_recompute_p(q_blk, k, lse_blk, rows, cols, causal,
-                             sm_scale)                 # [block_q, block_k]
-        dv_new = dv + jax.lax.dot_general(             # p^T · do
-            p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [block_k, d]
-        dp = jax.lax.dot_general(                      # do · v^T
-            do_blk, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - corr[:, None]) * sm_scale
-        dk_new = dk + jax.lax.dot_general(             # ds^T · q
-            ds.astype(q_blk.dtype), q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            p = _keep(query_minus_key[:hi] >= first, p, 0.0)
+        dv_new = dv[:hi] + _dot(p.astype(do_blk.dtype), do_blk, 1, 0)
+        ds = p * (_dot(v[:hi], do_blk, 1, 1) - corr)        # dp^T = v · do^T
+        if not fold_scale:
+            ds = ds * sm_scale
+        dk_new = dk[:hi] + _dot(ds.astype(q_blk.dtype), q_blk, 1, 0)
+        if hi < block_k:
+            dk_new = jnp.concatenate([dk_new, dk[hi:]], axis=0)
+            dv_new = jnp.concatenate([dv_new, dv[hi:]], axis=0)
         return dk_new, dv_new
 
-    dk, dv = jax.lax.fori_loop(
-        lo, num_q_blocks, body,
-        (jnp.zeros((block_k, d), jnp.float32),
-         jnp.zeros((block_k, d), jnp.float32)))
+    def whole(j, carry):
+        return step(j * block_q, tile_min - j * block_q, carry, block_k)
+
+    carry = (jnp.zeros((block_k, d), jnp.float32),
+             jnp.zeros((block_k, d), jnp.float32))
+    if causal:
+        # First query block whose last row sees this tile's first key,
+        # then one block for each further `block_q` keys of the tile.
+        first_blk = jnp.floor_divide(tile_min, block_q)
+        narrow = _narrow_steps(block_k, block_q)
+        for t in range(narrow):
+            j = first_blk + t
+            carry = step(*_narrow_block(j, block_q, num_q,
+                                        tile_min - j * block_q),
+                         carry, (t + 1) * block_q)
+        carry = jax.lax.fori_loop(
+            jnp.clip(first_blk + narrow, 0, num_q), num_q, whole, carry)
+    else:
+        carry = jax.lax.fori_loop(0, num_q, whole, carry)
+    dk, dv = carry
+    if fold_scale:
+        dk = dk * sm_scale
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -317,13 +507,14 @@ def _lse8(x, bh, s):
 
 
 def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
-               block_q, block_k):
+               dq_blocks, dkv_blocks):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
     sk = k.shape[1]
     bh = b * h
+    fold_scale = _scale_is_exact(sm_scale)
     qf = q.transpose(0, 2, 1, 3).reshape(bh, sq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(bh, sk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(bh, sk, d)
@@ -332,16 +523,18 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
                     * out.transpose(0, 2, 1, 3).reshape(bh, sq, d)
                     .astype(jnp.float32), axis=-1)      # [bh, sq]
     lse8 = _lse8(lse, bh, sq)
-    delta8 = _lse8(delta, bh, sq)
-    dlse8 = _lse8(dlse.astype(jnp.float32), bh, sq)
+    # (delta + (-dlse)) enters every key of a query uniformly: one term.
+    corr8 = _lse8(delta - dlse.astype(jnp.float32), bh, sq)
 
     seq_spec = pl.BlockSpec((1, 8, sq), lambda g, i, offs: (g, 0, 0))
     full_q = pl.BlockSpec((1, sq, d), lambda g, i, offs: (g, 0, 0))
     full_k = pl.BlockSpec((1, sk, d), lambda g, i, offs: (g, 0, 0))
 
+    block_q, block_k = dq_blocks
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, block_q=block_q,
-                          block_k=block_k, seq_k=sk, sm_scale=sm_scale),
+                          block_k=block_k, seq_k=sk, sm_scale=sm_scale,
+                          fold_scale=fold_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, sq // block_q),
@@ -351,19 +544,21 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
                 pl.BlockSpec((1, block_q, d), lambda g, i, offs: (g, i, 0)),
                 pl.BlockSpec((1, 8, block_q), lambda g, i, offs: (g, 0, i)),
                 pl.BlockSpec((1, 8, block_q), lambda g, i, offs: (g, 0, i)),
-                pl.BlockSpec((1, 8, block_q), lambda g, i, offs: (g, 0, i)),
             ],
             out_specs=pl.BlockSpec((1, block_q, d),
                                    lambda g, i, offs: (g, i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        compiler_params=_compiler_params(),
         interpret=dispatch.interpret_mode(),
         name="flash_bwd_dq",
-    )(offs, qf, kf, vf, dof, lse8, delta8, dlse8)
+    )(offs, qf, kf, vf, dof, lse8, corr8)
 
+    block_q, block_k = dkv_blocks
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal, block_q=block_q,
-                          block_k=block_k, seq_q=sq, sm_scale=sm_scale),
+                          block_k=block_k, seq_q=sq, sm_scale=sm_scale,
+                          fold_scale=fold_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, sk // block_k),
@@ -371,7 +566,7 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
                 full_q,
                 pl.BlockSpec((1, block_k, d), lambda g, i, offs: (g, i, 0)),
                 pl.BlockSpec((1, block_k, d), lambda g, i, offs: (g, i, 0)),
-                full_q, seq_spec, seq_spec, seq_spec,
+                full_q, seq_spec, seq_spec,
             ],
             out_specs=[
                 pl.BlockSpec((1, block_k, d), lambda g, i, offs: (g, i, 0)),
@@ -382,9 +577,10 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
+        compiler_params=_compiler_params(),
         interpret=dispatch.interpret_mode(),
         name="flash_bwd_dkv",
-    )(offs, qf, kf, vf, dof, lse8, delta8, dlse8)
+    )(offs, qf, kf, vf, dof, lse8, corr8)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     dk = dk.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
@@ -393,16 +589,17 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
 
 
 # ---------------------------------------------------------------------------
-# custom VJP over (out, lse)
+# custom VJP over (out, lse).  `blocks` is the (block_q, block_k) of the
+# forward, of dq and of dk/dv, in that order.
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_lse(q, k, v, offs, causal, sm_scale, block_q, block_k):
-    return _flash_fwd(q, k, v, offs, causal, sm_scale, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_lse(q, k, v, offs, causal, sm_scale, blocks):
+    return _flash_fwd(q, k, v, offs, causal, sm_scale, *blocks[0])
 
 
-def _flash_lse_fwd(q, k, v, offs, causal, sm_scale, block_q, block_k):
-    out, lse = _flash_fwd(q, k, v, offs, causal, sm_scale, block_q, block_k)
+def _flash_lse_fwd(q, k, v, offs, causal, sm_scale, blocks):
+    out, lse = _flash_fwd(q, k, v, offs, causal, sm_scale, *blocks[0])
     # Named residuals: under jax.checkpoint with
     # save_only_these_names("attn_out", "attn_lse") (the transformer's
     # "save_attn" remat policy) the kernel outputs are kept from the
@@ -418,11 +615,11 @@ def _flash_lse_fwd(q, k, v, offs, causal, sm_scale, block_q, block_k):
     return (out, lse), (q_r, k_r, v_r, out_r, lse_r, offs)
 
 
-def _flash_lse_bwd(causal, sm_scale, block_q, block_k, res, cts):
+def _flash_lse_bwd(causal, sm_scale, blocks, res, cts):
     q, k, v, out, lse, offs = res
     dout, dlse = cts
     dq, dk, dv = _flash_bwd(q, k, v, out, lse, offs, dout, dlse,
-                            causal, sm_scale, block_q, block_k)
+                            causal, sm_scale, blocks[1], blocks[2])
     return dq, dk, dv, None  # offs (int positions) has no gradient
 
 
@@ -432,6 +629,39 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 # ---------------------------------------------------------------------------
 # Public entry points
 # ---------------------------------------------------------------------------
+
+def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
+                 seq_q: int, seq_k: int, blocks) -> None:
+    """Say in `dispatch.taken()` what the kernels were built to do: each
+    kernel's (block_q x block_k), whether the scale left the score tile,
+    and what share of each kernel's computed scores lies above the
+    diagonal (known here only when the offsets are static; a traced
+    offset decides it at run time)."""
+    if not causal:
+        dead = "dead0%"
+    elif isinstance(q_off, int) and isinstance(kv_off, int):
+        (fq, fk), (dq_q, dq_k), (kv_q, kv_k) = blocks
+        # dk/dv tiles the keys: the same walk with the sequences reversed
+        shares = (_dead_share(q_off, kv_off, seq_q, seq_k, fq, fk),
+                  _dead_share(q_off, kv_off, seq_q, seq_k, dq_q, dq_k),
+                  _dead_share(1 - kv_off - seq_k, 1 - q_off - seq_q,
+                              seq_k, seq_q, kv_k, kv_q))
+        dead = "dead" + "/".join("%.0f" % (100 * x) for x in shares) + "%"
+    else:
+        dead = "dead_by_offset"
+    scale = "scale_folded" if _scale_is_exact(sm_scale) else "scale_per_score"
+    sizes = ",".join("%s%dx%d" % (name, bq, bk) for name, (bq, bk)
+                     in zip(("fwd", "dq", "dkv"), blocks))
+    dispatch.record("flash_attention.plan", f"{sizes},{scale},{dead}")
+
+
+def _chunk(q, k, v, q_off, kv_off, causal, sm_scale, blocks):
+    _record_plan(q_off, kv_off, causal, sm_scale, q.shape[1], k.shape[1],
+                 blocks)
+    offs = jnp.stack([jnp.asarray(q_off, jnp.int32),
+                      jnp.asarray(kv_off, jnp.int32)])
+    return _flash_lse(q, k, v, offs, causal, sm_scale, blocks)
+
 
 def flash_attention_chunk(q, k, v, q_off, kv_off, causal: bool = True,
                           sm_scale: Optional[float] = None,
@@ -444,20 +674,31 @@ def flash_attention_chunk(q, k, v, q_off, kv_off, causal: bool = True,
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    offs = jnp.stack([jnp.asarray(q_off, jnp.int32),
-                      jnp.asarray(kv_off, jnp.int32)])
-    return _flash_lse(q, k, v, offs, causal, sm_scale, block_q, block_k)
+    return _chunk(q, k, v, q_off, kv_off, causal, sm_scale,
+                  ((block_q, block_k),) * 3)
 
 
 def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
-                    block_q: int = 512, block_k: int = 512):
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None):
     """Tiled attention. q:[b,s,h,d], k/v:[b,t,h,d] -> [b,s,h,d].
 
     Uses the Pallas kernels on TPU (or in interpret mode for tests); falls
     back to the jnp reference elsewhere.  Heads must already be expanded
     (GQA repeat happens in the model).  When sq < sk the windows are
     end-aligned (decode convention), matching attention_reference.
+
+    The kernels follow one plan made from what they can see (the comment
+    above `_scale_is_exact`): a program's tile meets the blocks under the
+    diagonal whole and the blocks on it only with the part that can see
+    them; scores are held [keys, queries] where softmax reduces; a
+    power-of-two sm_scale (head size 64: 0.125) is applied to the [tile,
+    d] operand and the accumulators instead of every score, which is
+    exact.  Block sizes the caller does not pass come from
+    `default_blocks(head_dim, sq, sk, dtype)`, each kernel its own; a
+    block_q / block_k that is passed holds for all three kernels.
+    `dispatch.taken()` holds the plan under "flash_attention.plan".
 
     Under an ambient multi-device mesh (jax.sharding.set_mesh) the kernel
     runs per shard inside a shard_map — batch over the data/fsdp axes,
@@ -467,19 +708,18 @@ def flash_attention(q, k, v, causal: bool = True,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     sq, sk, d = q.shape[1], k.shape[1], q.shape[-1]
-    bq = min(block_q, sq)
-    bk = min(block_k, sk)
-    if not _can_use_pallas(sq, sk, d, bq, bk):
+    if block_q is None and block_k is None:
+        blocks = default_blocks(d, sq, sk, q.dtype)
+    else:
+        blocks = ((min(block_q or 512, sq), min(block_k or 512, sk)),) * 3
+    if not all(_can_use_pallas(sq, sk, d, bq, bk) for bq, bk in blocks):
         dispatch.record("flash_attention", "xla")
         return attention_reference(q, k, v, causal, sm_scale)
     dispatch.record("flash_attention", "interpret"
                     if dispatch.interpret_mode() else "pallas")
 
     def kernel(q, k, v):
-        out, _ = flash_attention_chunk(
-            q, k, v, sk - sq, 0, causal=causal, sm_scale=sm_scale,
-            block_q=bq, block_k=bk)
-        return out
+        return _chunk(q, k, v, sk - sq, 0, causal, sm_scale, blocks)[0]
 
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or mesh.size == 1:

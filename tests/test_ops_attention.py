@@ -171,3 +171,197 @@ def test_backward_never_materializes_s_by_s():
             assert elems < score_elems, (
                 f"intermediate of shape {aval.shape} is score-matrix "
                 "sized — flash backward must recompute by block")
+
+
+# ---------------------------------------------------------------------------
+# The kernels' plan (two loops, folded scale, block sizes): every shape a
+# caller sends, against the reference, values and all three gradients.
+# ---------------------------------------------------------------------------
+
+def _grads_and_value(fn, q, k, v, w):
+    def loss(q, k, v):
+        return jnp.sum(fn(q, k, v) * w)
+
+    return fn(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+# (sq, sk, block_q, block_k); None, None is `default_blocks`' own plan.
+# sq < sk is end-aligned, and (128, 320, 128, 64) puts the diagonal 192
+# rows in, off the q-block grid.  block_q = R * block_k narrows the
+# forward's and dq's steps on the diagonal, block_k = R * block_q dk/dv's.
+_SHAPES = [(256, 256, 128, 128), (512, 512, 256, 256), (512, 512, 512, 512),
+           (512, 512, 256, 128), (512, 512, 128, 256), (128, 384, 128, 128),
+           (128, 320, 128, 64), (512, 512, 512, 128), (512, 512, 128, 512),
+           (256, 768, 256, 128), (1024, 1024, None, None),
+           (256, 1024, None, None)]
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk", _SHAPES)
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d", [64, 128])     # scale folded / kept per score
+def test_plan_values_and_grads_match_reference(d, causal, sq, sk, bq, bk):
+    ks = jax.random.split(jax.random.PRNGKey(sq + sk + (bq or 0) + (bk or 0) + d), 4)
+    q = jax.random.normal(ks[0], (1, sq, 2, d), jnp.float32)
+    k = jax.random.normal(ks[1], (1, sk, 2, d), jnp.float32)
+    v = jax.random.normal(ks[2], (1, sk, 2, d), jnp.float32)
+    w = jax.random.normal(ks[3], (1, sq, 2, d), jnp.float32)
+    assert attn._scale_is_exact(d ** -0.5) == (d == 64)
+
+    out, grads = _grads_and_value(
+        lambda q, k, v: attn.flash_attention(
+            q, k, v, causal=causal, block_q=bq, block_k=bk), q, k, v, w)
+    ref, ref_grads = _grads_and_value(
+        lambda q, k, v: attn.attention_reference(q, k, v, causal=causal),
+        q, k, v, w)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    for g, r in zip(grads, ref_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=5e-4, rtol=5e-4)
+
+
+# q_off - kv_off for 2 q-blocks of 128 over 4 k-blocks of 128: what the
+# q-blocks' key ranges look like against the diagonal.
+_DELTAS = [(600, "wholly past: the unmasked loop alone"),
+           (0, "diagonal in the first block (q-block 0)"),
+           (200, "diagonal in middle blocks, off the block grid"),
+           (384, "diagonal in the last block; q-block 1 wholly past"),
+           (-100, "rows before the chunk see nothing"),
+           (-300, "wholly future: neither loop runs")]
+
+
+@pytest.mark.parametrize("delta,what", _DELTAS)
+@pytest.mark.parametrize("bq,bk", [(128, 128), (256, 128), (128, 256)])
+@pytest.mark.parametrize("d", [64, 128])
+def test_chunk_positions_values_lse_and_grads(d, bq, bk, delta, what):
+    """flash_attention_chunk at every position of a chunk against the
+    diagonal, with a loss that reads out AND lse (nonzero dlse, as ring
+    attention's merge gives): values, lse and dq, dk, dv against the
+    explicit-mask reference."""
+    from ray_tpu.ops import ring_attention as ring
+
+    b, sq, sk, h = 1, 256, 512, 2
+    ks = jax.random.split(jax.random.PRNGKey(1000 + delta + d), 5)
+    q = jax.random.normal(ks[0], (b, sq, h, d), jnp.float32)
+    k = jax.random.normal(ks[1], (b, sk, h, d), jnp.float32)
+    v = jax.random.normal(ks[2], (b, sk, h, d), jnp.float32)
+    w = jax.random.normal(ks[3], (b, sq, h, d), jnp.float32)
+    u = jax.random.normal(ks[4], (b, h, sq), jnp.float32)
+    q_off, kv_off = 1000 + delta, 1000
+    mask = ((q_off + jnp.arange(sq))[:, None]
+            >= (kv_off + jnp.arange(sk))[None, :])[None, None]
+
+    def flash(q, k, v):
+        out, lse = attn.flash_attention_chunk(
+            q, k, v, jnp.int32(q_off), jnp.int32(kv_off), causal=True,
+            block_q=bq, block_k=bk)
+        return out, lse.reshape(b, h, sq)
+
+    def ref(q, k, v):
+        return ring._chunk_attention(q, k, v, mask, d ** -0.5)
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            return (jnp.sum(out * w)
+                    + jnp.sum(jnp.where(lse > -1e29, lse, 0.0) * u))
+        return f
+
+    (out, lse), (o_ref, lse_ref) = flash(q, k, v), ref(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(o_ref),
+                               atol=2e-5, rtol=2e-5)
+    hidden = np.asarray(lse_ref) < -1e29
+    assert (np.asarray(lse) < -1e29).tolist() == hidden.tolist()
+    assert not np.asarray(out)[hidden.transpose(0, 2, 1)].any()
+    if delta == -300:
+        assert hidden.all()
+    np.testing.assert_allclose(np.asarray(lse)[~hidden],
+                               np.asarray(lse_ref)[~hidden],
+                               atol=2e-5, rtol=2e-5)
+    g = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, r in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   atol=5e-4, rtol=5e-4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("causal", [True, False])
+def test_folded_scale_is_bit_for_bit_the_per_score_scale(causal, dtype):
+    """Head size 64: 0.125 is a power of two, so scaling the q tile once
+    gives the very scores that scaling each of them gives."""
+    q, k, v = (x.astype(dtype) for x in _rand_qkv(7, 1, 512, 2, 64))
+    offs = jnp.zeros((2,), jnp.int32)
+    folded = attn._flash_fwd(q, k, v, offs, causal, 0.125, 256, 128,
+                             fold_scale=True)
+    kept = attn._flash_fwd(q, k, v, offs, causal, 0.125, 256, 128,
+                           fold_scale=False)
+    for a, b_ in zip(folded, kept):
+        assert np.array_equal(np.asarray(a.astype(jnp.float32)),
+                              np.asarray(b_.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("scale,exact", [
+    (0.125, True), (64 ** -0.5, True), (0.25, True), (1.0, True),
+    (128 ** -0.5, False), (0.1, False), (0.0, False)])
+def test_scale_is_folded_only_when_a_power_of_two(scale, exact):
+    assert attn._scale_is_exact(scale) is exact
+
+
+@pytest.mark.parametrize("bq,bk,delta,dead", [
+    (512, 512, 0, 1 - 2098176 / (10 * 512 * 512)),
+    (2048, 256, 0, 1 - 2098176 / (256 * sum(2048 - 256 * t
+                                            for t in range(8)))),
+    (128, 128, 0, 1 - 2098176 / (136 * 128 * 128)),
+    (1024, 256, 5000, 0.0),              # a chunk from the past
+    (1024, 256, -5000, 0.0),             # from the future: nothing runs
+    (512, 128, 64, None), (256, 512, 0, None), (128, 128, -100, None)])
+def test_dead_share_counts_what_the_forward_computes(bq, bk, delta, dead):
+    """The plan record's share of computed scores above the diagonal, at
+    sequence 2048: known cases, and bounds off the block grid."""
+    got = attn._dead_share(1000 + delta, 1000, 2048, 2048, bq, bk)
+    if dead is None:
+        assert 0.0 <= got < 0.6
+    else:
+        assert got == pytest.approx(dead, abs=1e-9)
+
+
+@pytest.mark.parametrize("sq,sk,plan", [
+    (2048, 2048, ((2048, 512), (2048, 512), (512, 2048))),
+    (1024, 1024, ((1024, 512), (1024, 512), (512, 1024))),
+    (256, 2048, ((256, 512), (256, 512), (256, 2048))),
+    (4096, 4096, ((2048, 512), (2048, 512), (512, 2048))),
+    (1536, 1536, ((512, 512), (512, 512), (512, 512))),
+    (128, 128, ((128, 128), (128, 128), (128, 128))),
+    (128, 320, ((128, 320), (128, 320), (128, 320))),
+    (100, 100, ((100, 100), (100, 100), (100, 100)))])
+def test_default_blocks(sq, sk, plan):
+    got = attn.default_blocks(64, sq, sk, jnp.bfloat16)
+    assert got == plan
+    for bq, bk in got:
+        assert sq % bq == 0 and sk % bk == 0
+
+
+def test_plan_is_recorded_beside_the_path():
+    from ray_tpu.ops import dispatch
+
+    q, k, v = _rand_qkv(8, 1, 2048, 1, 64)
+    before = dispatch.taken()
+    jax.make_jaxpr(lambda q, k, v: attn.flash_attention(q, k, v))(q, k, v)
+    after = dispatch.taken()
+
+    def new(op):
+        return {p: n - before.get(op, {}).get(p, 0)
+                for p, n in after.get(op, {}).items()
+                if n - before.get(op, {}).get(p, 0)}
+
+    assert new("flash_attention") == {"interpret": 1}
+    assert new("flash_attention.plan") == {
+        "fwd2048x512,dq2048x512,dkv512x2048,scale_folded,dead20/20/20%": 1}
+    # a traced offset (ring attention) and head size 128
+    q, k, v = _rand_qkv(9, 1, 128, 1, 128)
+    jax.make_jaxpr(lambda q, k, v, o: attn.flash_attention_chunk(
+        q, k, v, o, 0))(q, k, v, jnp.int32(0))
+    plans = dispatch.taken()["flash_attention.plan"]
+    assert plans.get("fwd128x128,dq128x128,dkv128x128,scale_per_score,"
+                     "dead_by_offset")

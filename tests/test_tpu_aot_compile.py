@@ -54,6 +54,21 @@ def _compiled_text(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _custom_calls_as_traced(fn, *shapes):
+    """The compiled module's Mosaic custom-call lines, printed the way
+    the profiler names an operation in a trace: result and operand
+    shapes, no layouts."""
+    from jax._src.lib import _jax
+
+    opts = _jax.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    opts.include_layout_in_shapes = False
+    opts.print_backend_config = False
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    return [l for l in text.splitlines() if "tpu_custom_call" in l]
+
+
 @pytest.mark.parametrize("table_width", [2, 16])
 def test_paged_gqa_decode_kernel_compiles_for_v5e(one_chip, table_width):
     def sds(shape, dtype):
@@ -109,6 +124,105 @@ def test_flash_backward_compiles_for_v5e(one_chip):
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
     assert text.count("tpu_custom_call") >= 2  # dq and dk/dv kernels
+
+
+# The benchmark's cells (SmolLM2-1.7B: 32 heads of 64, sequence 2048):
+# 5 rows on one chip, 40 rows under the fsdp=4 mesh.
+CELL_ROWS = {"train-d12": 5, "train-fsdp4": 40}
+
+
+def _roofline_kernel_pattern():
+    """The pattern by which the benchmark's roofline reader finds the
+    forward kernel in a trace (an HLO line's result and first operand)."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmark", "layer_metrics",
+                        "flash_fwd_roofline.train.py")
+    spec = importlib.util.spec_from_file_location("_roofline_reader", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    return reader.KERNEL
+
+
+def _cell_calls(topo, monkeypatch, cell, fn):
+    """The kernel calls of fn(q, k, v) compiled at a cell's own attention
+    shapes: on one chip or under the fsdp=4 mesh, through the public
+    flash_attention."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    monkeypatch.setattr(attention.dispatch, "platform", lambda: "tpu")
+    monkeypatch.setattr(attention.dispatch, "interpret_mode", lambda: False)
+    shape = (CELL_ROWS[cell], 2048, 32, 64)
+    if cell == "train-d12":
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                 sharding=SingleDeviceSharding(
+                                     topo.devices[0]))
+        return _custom_calls_as_traced(fn, x, x, x)
+    mesh = Mesh(topo.devices, ("fsdp",))
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("fsdp")))
+    with jax.sharding.set_mesh(mesh):
+        return _custom_calls_as_traced(fn, x, x, x)
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_ROWS))
+def test_cell_flash_forward_keeps_the_face_the_roofline_reader_finds(
+        topo, monkeypatch, cell):
+    """One forward custom-call a layer, taking s32[2] offsets first and
+    returning (out bf16[bh, s, d], lse f32[bh, 8, s]): what
+    benchmark/layer_metrics/flash_fwd_roofline.train.py matches.  A
+    forward split in two, or a result of another rank or dtype, would
+    turn that metric to null without failing anything else."""
+    import re
+
+    calls = _cell_calls(topo, monkeypatch, cell,
+                        lambda q, k, v: attention.flash_attention(q, k, v))
+    assert len(calls) == 1, calls
+    found = re.search(_roofline_kernel_pattern(), calls[0])
+    assert found, calls[0]
+    bh = 5 * 32 if cell == "train-d12" else 10 * 32   # a chip's share
+    assert f"(bf16[{bh},2048,64]" in found.group(0)
+    assert f"f32[{bh},8,2048])" in found.group(0)
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_ROWS))
+def test_cell_flash_backward_compiles_for_v5e(topo, monkeypatch, cell):
+    import re
+
+    def loss(q, k, v):
+        return attention.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    calls = _cell_calls(topo, monkeypatch, cell,
+                        jax.grad(loss, argnums=(0, 1, 2)))
+    assert len(calls) == 3, calls      # forward, dq, dk/dv
+    # the backward kernels must NOT look like the forward to the reader
+    pattern = _roofline_kernel_pattern()
+    assert sum(bool(re.search(pattern, l)) for l in calls) == 1
+
+
+def test_traced_call_records_path_and_plan(one_chip, monkeypatch):
+    monkeypatch.setattr(attention.dispatch, "platform", lambda: "tpu")
+    monkeypatch.setattr(attention.dispatch, "interpret_mode", lambda: False)
+    monkeypatch.setattr(attention.dispatch, "_taken", {})
+    x = jax.ShapeDtypeStruct((5, 2048, 32, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    jax.jit(lambda q, k, v: attention.flash_attention(q, k, v)).lower(x, x, x)
+    taken = attention.dispatch.taken()
+    assert taken["flash_attention"] == {"pallas": 1}
+    (plan, times), = taken["flash_attention.plan"].items()
+    sizes = ",".join(f"{name}{bq}x{bk}" for name, (bq, bk) in zip(
+        ("fwd", "dq", "dkv"),
+        attention.default_blocks(64, 2048, 2048, jnp.bfloat16)))
+    assert plan.startswith(sizes + ",scale_folded,dead") and times == 1
+    shares = plan.rsplit("dead", 1)[1].rstrip("%").split("/")
+    assert len(shares) == 3 and all(0 < int(x) <= 20 for x in shares)
+    # head size 128: the scale stays on the scores
+    y = jax.ShapeDtypeStruct((8, 2048, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    jax.jit(lambda q, k, v: attention.flash_attention(q, k, v)).lower(y, y, y)
+    assert any("scale_per_score" in p for p in
+               attention.dispatch.taken()["flash_attention.plan"])
 
 
 def test_flash_under_fsdp_mesh_is_shard_mapped(topo, monkeypatch):
