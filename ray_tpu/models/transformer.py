@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.models import common
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
     with_logical_constraint,
@@ -272,65 +273,23 @@ def _block(x, bp, cos, sin, positions, mask, config: TransformerConfig):
         x = x + out2d.reshape(b, s, h)
     else:
         aux = jnp.zeros((), jnp.float32)
-        gate = jax.nn.silu(y @ bp["w_gate"].astype(c.dtype))
-        up = y @ bp["w_up"].astype(c.dtype)
-        ffn = with_logical_constraint(gate * up, ("batch", "seq", "mlp"))
-        mlp_out = checkpoint_name(
-            ffn @ bp["w_down"].astype(c.dtype), "mlp_out")
-        x = x + mlp_out
+        x = x + common.swiglu(y, bp["w_gate"], bp["w_up"], bp["w_down"],
+                              c.dtype)
     return with_logical_constraint(x, ("batch", "seq", "embed")), aux
 
 
 def _embed_tokens(params, tokens, c: TransformerConfig):
-    x = params["tok_embed"].astype(c.dtype)[tokens]
-    return with_logical_constraint(x, ("batch", "seq", "embed"))
+    return common.embed_tokens(params["tok_embed"], tokens, c.dtype)
 
 
 def _lm_head(params, x, c: TransformerConfig):
-    """Final norm + weight-tied head (bf16 operands, fp32 accumulation:
-    the MXU's native mode — an fp32xfp32 einsum here ran at half rate
-    for ~10% of the model's FLOPs)."""
+    """Final norm + weight-tied head."""
     x = rms_norm(x, params["final_norm"], c.rms_eps)
-    logits = jnp.einsum(
-        "bsh,vh->bsv", x.astype(c.dtype),
-        params["tok_embed"].astype(c.dtype),
-        preferred_element_type=jnp.float32)
-    return with_logical_constraint(logits, ("batch", "seq", "vocab"))
+    return common.tied_logits(x, params["tok_embed"], c.dtype)
 
 
 def _maybe_remat(block_fn, c: TransformerConfig):
-    if not c.remat:
-        return block_fn
-    if c.remat_policy == "dots":
-        return jax.checkpoint(
-            block_fn,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    if c.remat_policy == "save_attn":
-        # Middle ground between "full" (recompute everything, min HBM)
-        # and "dots" (save every matmul, OOMs at billion scale): keep
-        # only the flash kernel's outputs (out + lse, named in
-        # ops/attention.py _flash_lse_fwd) so the backward re-derives
-        # the cheap projections but never re-runs the attention kernel.
-        return jax.checkpoint(
-            block_fn,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                "attn_out", "attn_lse"))
-    if c.remat_policy == "dots_no_mlp":
-        # "dots" minus its biggest buffers: save every matmul output
-        # EXCEPT the gate/up MLP intermediates ([b, s, intermediate] —
-        # 4x the hidden-size tensors), which the backward recomputes
-        # from the saved layer input.  ~40% of dots' activation memory
-        # for ~0.6N of the 2N recompute "full" pays — the policy that
-        # fits billion-class models at useful batch sizes.
-        return jax.checkpoint(
-            block_fn,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                "attn_q", "attn_k", "attn_v", "attn_out", "attn_lse",
-                "attn_proj", "mlp_out"))
-    if c.remat_policy == "full":
-        return jax.checkpoint(block_fn)
-    raise ValueError(f"unknown remat_policy {c.remat_policy!r}; expected "
-                     "'full', 'dots', 'save_attn' or 'dots_no_mlp'")
+    return common.maybe_remat(block_fn, c.remat, c.remat_policy)
 
 
 def forward_hidden(params: Dict[str, Any], tokens,
@@ -371,11 +330,7 @@ def forward(params: Dict[str, Any], tokens, config: TransformerConfig,
     (zero for dense models)."""
     c = config
     x, aux_total = forward_hidden(params, tokens, c, positions)
-    logits = jnp.einsum(
-        "bsh,vh->bsv", x.astype(c.dtype),
-        params["tok_embed"].astype(c.dtype),
-        preferred_element_type=jnp.float32)
-    logits = with_logical_constraint(logits, ("batch", "seq", "vocab"))
+    logits = common.tied_logits(x, params["tok_embed"], c.dtype)
     if return_aux:
         return logits, aux_total
     return logits
@@ -451,13 +406,9 @@ def loss_fn_pipelined(params, batch, config: TransformerConfig,
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     logits = forward_pipelined(params, inputs, config, num_stages,
                                num_microbatches, mesh=mesh)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     mask = batch.get("mask")
-    if mask is not None:
-        mask = mask[:, 1:]
-        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
-    return jnp.mean(nll)
+    return common.logits_ce(logits, targets,
+                            None if mask is None else mask[:, 1:])
 
 
 def loss_fn(params, batch, config: TransformerConfig):
@@ -466,28 +417,14 @@ def loss_fn(params, batch, config: TransformerConfig):
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     mask = batch.get("mask")
+    if mask is not None:
+        mask = mask[:, 1:]
     if config.fused_ce:
-        from ray_tpu.ops.fused_ce import fused_ce_nll
-
-        b, s = inputs.shape
         x, aux = forward_hidden(params, inputs, config)
-        nll = fused_ce_nll(x.reshape(b * s, -1), params["tok_embed"],
-                           targets.reshape(-1))
-        if mask is not None:
-            m = mask[:, 1:].reshape(-1)
-            ce = jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1)
-        else:
-            ce = jnp.mean(nll)
+        ce = common.fused_ce(x, params["tok_embed"], targets, mask)
     else:
         logits, aux = forward(params, inputs, config, return_aux=True)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(
-            logp, targets[..., None], axis=-1)[..., 0]
-        if mask is not None:
-            m = mask[:, 1:]
-            ce = jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1)
-        else:
-            ce = jnp.mean(nll)
+        ce = common.logits_ce(logits, targets, mask)
     if config.num_experts > 0:
         ce = ce + config.router_aux_coef * aux / config.num_layers
     return ce

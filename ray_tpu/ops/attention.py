@@ -3,9 +3,12 @@
 The reference framework ships no attention kernels (SURVEY.md §5 — long-context
 machinery is absent in-tree); on TPU this is a core op.  Design:
 
-  - `flash_attention(q, k, v, causal=...)`: online-softmax tiled kernel
-    (Pallas, grid over (batch*heads, q-tiles), K/V of one head resident in
-    VMEM) so the s×s score matrix never materializes in HBM.
+  - `flash_attention(q, k, v, causal=..., window=...)`: online-softmax
+    tiled kernel (Pallas, grid over (batch*heads, q-tiles), K/V of one head
+    resident in VMEM) so the s×s score matrix never materializes in HBM.
+    Causal, or causal under a sliding window (query t sees keys s with
+    0 <= t - s < window): the blocks wholly behind a tile's window are not
+    visited, the trailing edge is masked in forward, dq and dk/dv.
   - `flash_attention_chunk(...)`: the offset-aware variant returning
     (out, lse) — the building block ring attention uses per K/V chunk
     (ops/ring_attention.py); positions enter as DYNAMIC scalars so the
@@ -64,8 +67,11 @@ def _can_use_pallas(seq_q: int, seq_k: int, head_dim: int,
 # ---------------------------------------------------------------------------
 
 def attention_reference(q, k, v, causal: bool = True,
-                        sm_scale: Optional[float] = None):
-    """Plain attention. q:[b,s,h,d] k,v:[b,t,h,d] -> [b,s,h,d]."""
+                        sm_scale: Optional[float] = None,
+                        window: Optional[int] = None):
+    """Plain attention. q:[b,s,h,d] k:[b,t,h,d] v:[b,t,h,e] -> [b,s,h,e].
+    With a window (causal only) query t sees keys s with 0 <= t - s <
+    window."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
@@ -75,6 +81,9 @@ def attention_reference(q, k, v, causal: bool = True,
         # Align ends: query i attends keys j where j - (sk - sq) <= i.
         mask = (jnp.arange(sk)[None, :] - (sk - sq)
                 <= jnp.arange(sq)[:, None])
+        if window is not None:
+            mask = mask & (jnp.arange(sk)[None, :] - (sk - sq)
+                           > jnp.arange(sq)[:, None] - window)
         logits = jnp.where(mask[None, None], logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
@@ -83,8 +92,8 @@ def attention_reference(q, k, v, causal: bool = True,
 # ---------------------------------------------------------------------------
 # The plan: what each kernel decides from what it can see
 # ---------------------------------------------------------------------------
-# Scalar-prefetch arg offs = [q_off, kv_off]: global position of this
-# operand's row/col 0.  The plain causal call uses (sk - sq, 0) (ends
+# Scalar-prefetch arg offs = [q_off, kv_off] (+ [window] under a sliding
+# window): global position of this operand's row/col 0.  The plain causal call uses (sk - sq, 0) (ends
 # aligned); ring attention passes each chunk's global offsets, so one
 # compiled kernel serves every ring step (fully-unmasked, diagonal, and
 # fully-masked chunks alike).
@@ -134,15 +143,24 @@ def _tile_and_inner(seq_tile: int, seq_inner: int):
     return (tile or min(512, seq_tile)), inner
 
 
-def default_blocks(head_dim: int, seq_q: int, seq_k: int, dtype):
+def default_blocks(head_dim: int, seq_q: int, seq_k: int, dtype,
+                   window: Optional[int] = None):
     """((block_q, block_k) of the forward, of dq, of dk/dv) for a caller
     that passes none, from the v5e's sweep at sequence 2048, bf16, head
     sizes 64 and 128 (PERF.md, PR 29).  The forward and dq tile the
     queries, dk/dv tiles the keys.  Blocks of 256 or 128 leave fewer
     scores above the diagonal and run 5-10 % faster, but each block is one
     more straight-line step to trace in every process that builds the
-    kernel, and a train worker's start pays for that (PERF.md)."""
+    kernel, and a train worker's start pays for that (PERF.md).
+
+    Under a window shorter than the keys a long tile would meet every
+    block of its window with all its queries, most of which cannot see
+    it: tile and block are both 512 then, so a tile visits the block on
+    its diagonal and the one behind it."""
     del head_dim, dtype        # the sweep gave one answer for those it ran
+    if window is not None and window < seq_k:
+        short = (min(512, seq_q), min(512, seq_k))
+        return short, short, short
     fwd = _tile_and_inner(seq_q, seq_k)
     kv_tile, q_inner = _tile_and_inner(seq_k, seq_q)
     return fwd, fwd, (q_inner, kv_tile)
@@ -162,27 +180,47 @@ def _first_narrow_block(tile_min, inner: int):
     return -jnp.floor_divide(-tile_min, inner) + 1
 
 
-def _dead_share(q_off: int, kv_off: int, seq_q: int, seq_k: int,
-                block_q: int, block_k: int) -> float:
-    """Share of the scores a kernel that tiles the queries computes that
-    lie above the diagonal, for static offsets: `_walk_blocks`' bounds in
-    Python, for the plan record."""
+def _walk_counts(q_off: int, kv_off: int, seq_q: int, seq_k: int,
+                 block_q: int, block_k: int, window: Optional[int] = None):
+    """(scores computed, blocks visited) by a kernel that tiles the
+    queries, for static offsets: `_walk_blocks`' bounds in Python."""
     narrow = _narrow_steps(block_q, block_k)
     num_k = seq_k // block_k
-    computed = 0
+    computed = visited = 0
     for qi in range(seq_q // block_q):
         row_min = q_off - kv_off + qi * block_q
+        lo = 0 if window is None else min(
+            max((row_min - (window - 1)) // block_k, 0), num_k)
         if narrow:
             j1 = -(-row_min // block_k) + 1
-            computed += min(max(j1, 0), num_k) * block_q * block_k
-            computed += sum((block_q - t * block_k) * block_k
-                            for t in range(1, narrow + 1)
-                            if 0 <= j1 + t - 1 < num_k)
+            whole = max(min(max(j1, 0), num_k) - lo, 0)
+            steps = [t for t in range(1, narrow + 1)
+                     if 0 <= j1 + t - 1 < num_k]
+            computed += whole * block_q * block_k + sum(
+                (block_q - t * block_k) * block_k for t in steps)
+            visited += whole + len(steps)
         else:
             hi = (row_min + block_q - 1) // block_k + 1
-            computed += min(max(hi, 0), num_k) * block_q * block_k
-    live = sum(min(max(q_off - kv_off + 1 + row, 0), seq_k)
-               for row in range(seq_q))
+            whole = max(min(max(hi, 0), num_k) - lo, 0)
+            computed += whole * block_q * block_k
+            visited += whole
+    return computed, visited
+
+
+def _dead_share(q_off: int, kv_off: int, seq_q: int, seq_k: int,
+                block_q: int, block_k: int,
+                window: Optional[int] = None) -> float:
+    """Share of the scores a kernel that tiles the queries computes that
+    no query may see (above the diagonal or behind the window), for
+    static offsets, for the plan record."""
+    computed, _ = _walk_counts(q_off, kv_off, seq_q, seq_k, block_q,
+                               block_k, window)
+    live = 0
+    for row in range(seq_q):
+        last = min(q_off - kv_off + row, seq_k - 1)
+        first = 0 if window is None else max(
+            q_off - kv_off + row - (window - 1), 0)
+        live += max(last - first + 1, 0)
     return 1.0 - live / computed if computed else 0.0
 
 
@@ -232,24 +270,38 @@ def _narrow_block(j, inner: int, num_blocks: int, first):
                                  jnp.int32(_NOTHING_VISIBLE))
 
 
+def _visible(query_minus_key, first, window: Optional[int]):
+    """The causal mask of a step, and under a window its trailing edge:
+    a key `window` or more positions behind the query is out."""
+    seen = query_minus_key >= first
+    if window is not None:
+        seen = jnp.logical_and(seen, query_minus_key < first + window)
+    return seen
+
+
 def _walk_blocks(step, carry, causal: bool, tile_min, tile: int, inner: int,
-                 num_blocks: int):
+                 num_blocks: int, window: Optional[int] = None):
     """Run step(start, first, carry, lo) over the blocks of `inner` keys
     that a tile of queries meets (the forward's and dq's order: the loop,
     then the diagonal).  start: the block's first key; first: that key's
     position less the tile's first query's, for the mask; lo (static):
-    where in the tile the queries that can see the block begin."""
+    where in the tile the queries that can see the block begin.  Under a
+    window the loop starts at the block that holds the first key the
+    tile's first query sees: the blocks behind it are not visited."""
     def whole(j, carry):
         return step(j * inner, j * inner - tile_min, carry, 0)
 
     if not causal:
         return jax.lax.fori_loop(0, num_blocks, whole, carry)
     narrow = _narrow_steps(tile, inner)
+    lo = 0 if window is None else jnp.clip(
+        jnp.floor_divide(tile_min - (window - 1), inner), 0, num_blocks)
     if not narrow:
         hi = jnp.floor_divide(tile_min + tile - 1, inner) + 1
-        return jax.lax.fori_loop(0, jnp.clip(hi, 0, num_blocks), whole, carry)
+        return jax.lax.fori_loop(lo, jnp.clip(hi, 0, num_blocks), whole,
+                                 carry)
     j1 = _first_narrow_block(tile_min, inner)
-    carry = jax.lax.fori_loop(0, jnp.clip(j1, 0, num_blocks), whole, carry)
+    carry = jax.lax.fori_loop(lo, jnp.clip(j1, 0, num_blocks), whole, carry)
     for t in range(1, narrow + 1):
         j = j1 + (t - 1)
         carry = step(*_narrow_block(j, inner, num_blocks,
@@ -263,10 +315,11 @@ def _walk_blocks(step, carry, causal: bool, tile_min, tile: int, inner: int,
 
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                 causal: bool, block_q: int, block_k: int, seq_k: int,
-                sm_scale: float, fold_scale: bool):
+                sm_scale: float, fold_scale: bool, windowed: bool = False):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
+    window = offs_ref[2] if windowed else None
     q = q_ref[0]  # [block_q, d]
     d = q.shape[-1]
     if fold_scale:
@@ -285,7 +338,8 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         if not fold_scale:
             s = s * sm_scale
         if causal:
-            s = _keep(query_minus_key[:, lo:] >= first, s, _NEG_INF)
+            s = _keep(_visible(query_minus_key[:, lo:], first, window),
+                      s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -303,7 +357,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
              jnp.zeros((d, block_q), jnp.float32))
     m, l, acc = _walk_blocks(
         step, carry, causal, offs_ref[0] - offs_ref[1] + qi * block_q,
-        block_q, block_k, seq_k // block_k)
+        block_q, block_k, seq_k // block_k, window)
     l_safe = jnp.maximum(l, 1e-30)
     # Queries with no visible keys (possible in ring chunks "from the
     # future"): m stayed at -inf, so p accumulated exp(0)=1 garbage —
@@ -325,10 +379,11 @@ def _compiler_params():
     return pltpu.CompilerParams(vmem_limit_bytes=32 << 20)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
                block_q: int, block_k: int,
-               fold_scale: Optional[bool] = None):
+               fold_scale: Optional[bool] = None,
+               windowed: bool = False):
     """fold_scale is for the tests alone (None: fold when exact).
 
     Jitted so that one trace serves both of a train step's calls (the
@@ -350,7 +405,8 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
     grid = (b * h, sq // block_q)
     kernel = functools.partial(
         _fwd_kernel, causal=causal, block_q=block_q, block_k=block_k,
-        seq_k=sk, sm_scale=sm_scale, fold_scale=fold_scale)
+        seq_k=sk, sm_scale=sm_scale, fold_scale=fold_scale,
+        **({"windowed": True} if windowed else {}))
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -392,10 +448,11 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
 def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    corr_ref, dq_ref, *, causal: bool,
                    block_q: int, block_k: int, seq_k: int, sm_scale: float,
-                   fold_scale: bool):
+                   fold_scale: bool, windowed: bool = False):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
+    window = offs_ref[2] if windowed else None
     q = q_ref[0]                              # [block_q, d] native dtype
     do = do_ref[0]                            # [block_q, d] native dtype
     d = q.shape[-1]
@@ -416,7 +473,8 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         # value sliced off the lane grid does not broadcast in Mosaic).
         p = jnp.exp(s - lse_ref[0, 0:1, lo:])
         if causal:
-            p = _keep(query_minus_key[:, lo:] >= first, p, 0.0)
+            p = _keep(_visible(query_minus_key[:, lo:], first, window),
+                      p, 0.0)
         dp = _dot(v_blk, do[lo:], 1, 1)                 # dp^T = v · do^T
         ds = p * (dp - corr_ref[0, 0:1, lo:])
         if not fold_scale:
@@ -427,7 +485,7 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     dq = _walk_blocks(
         step, jnp.zeros((d, block_q), jnp.float32), causal,
         offs_ref[0] - offs_ref[1] + qi * block_q, block_q, block_k,
-        seq_k // block_k)
+        seq_k // block_k, window)
     if fold_scale:
         dq = dq * sm_scale
     dq_ref[0] = dq.T.astype(dq_ref.dtype)
@@ -436,10 +494,11 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     corr_ref, dk_ref, dv_ref, *, causal: bool,
                     block_q: int, block_k: int, seq_q: int, sm_scale: float,
-                    fold_scale: bool):
+                    fold_scale: bool, windowed: bool = False):
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
+    window = offs_ref[2] if windowed else None
     k = k_ref[0]                              # [block_k, d] native dtype
     v = v_ref[0]
     d = k.shape[-1]
@@ -464,7 +523,7 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             s = s * sm_scale
         p = jnp.exp(s - lse_blk)
         if causal:
-            p = _keep(query_minus_key[:hi] >= first, p, 0.0)
+            p = _keep(_visible(query_minus_key[:hi], first, window), p, 0.0)
         dv_new = dv[:hi] + _dot(p.astype(do_blk.dtype), do_blk, 1, 0)
         ds = p * (_dot(v[:hi], do_blk, 1, 1) - corr)        # dp^T = v · do^T
         if not fold_scale:
@@ -490,8 +549,12 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             carry = step(*_narrow_block(j, block_q, num_q,
                                         tile_min - j * block_q),
                          carry, (t + 1) * block_q)
+        # Under a window the loop ends with the block that holds the last
+        # query to see the tile's last key.
+        end = num_q if window is None else jnp.clip(jnp.floor_divide(
+            tile_min + block_k + window - 2, block_q) + 1, 0, num_q)
         carry = jax.lax.fori_loop(
-            jnp.clip(first_blk + narrow, 0, num_q), num_q, whole, carry)
+            jnp.clip(first_blk + narrow, 0, num_q), end, whole, carry)
     else:
         carry = jax.lax.fori_loop(0, num_q, whole, carry)
     dk, dv = carry
@@ -507,7 +570,7 @@ def _lse8(x, bh, s):
 
 
 def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
-               dq_blocks, dkv_blocks):
+               dq_blocks, dkv_blocks, windowed=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -515,6 +578,7 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
     sk = k.shape[1]
     bh = b * h
     fold_scale = _scale_is_exact(sm_scale)
+    windowed = {"windowed": True} if windowed else {}
     qf = q.transpose(0, 2, 1, 3).reshape(bh, sq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(bh, sk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(bh, sk, d)
@@ -534,7 +598,7 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, block_q=block_q,
                           block_k=block_k, seq_k=sk, sm_scale=sm_scale,
-                          fold_scale=fold_scale),
+                          fold_scale=fold_scale, **windowed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, sq // block_q),
@@ -558,7 +622,7 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal, block_q=block_q,
                           block_k=block_k, seq_q=sq, sm_scale=sm_scale,
-                          fold_scale=fold_scale),
+                          fold_scale=fold_scale, **windowed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, sk // block_k),
@@ -590,16 +654,18 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
 
 # ---------------------------------------------------------------------------
 # custom VJP over (out, lse).  `blocks` is the (block_q, block_k) of the
-# forward, of dq and of dk/dv, in that order.
+# forward, of dq and of dk/dv, in that order, then the window (or None).
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _flash_lse(q, k, v, offs, causal, sm_scale, blocks):
-    return _flash_fwd(q, k, v, offs, causal, sm_scale, *blocks[0])
+    return _flash_fwd(q, k, v, offs, causal, sm_scale, *blocks[0], None,
+                      blocks[3] is not None)
 
 
 def _flash_lse_fwd(q, k, v, offs, causal, sm_scale, blocks):
-    out, lse = _flash_fwd(q, k, v, offs, causal, sm_scale, *blocks[0])
+    out, lse = _flash_fwd(q, k, v, offs, causal, sm_scale, *blocks[0], None,
+                          blocks[3] is not None)
     # Named residuals: under jax.checkpoint with
     # save_only_these_names("attn_out", "attn_lse") (the transformer's
     # "save_attn" remat policy) the kernel outputs are kept from the
@@ -619,7 +685,8 @@ def _flash_lse_bwd(causal, sm_scale, blocks, res, cts):
     q, k, v, out, lse, offs = res
     dout, dlse = cts
     dq, dk, dv = _flash_bwd(q, k, v, out, lse, offs, dout, dlse,
-                            causal, sm_scale, blocks[1], blocks[2])
+                            causal, sm_scale, blocks[1], blocks[2],
+                            blocks[3] is not None)
     return dq, dk, dv, None  # offs (int positions) has no gradient
 
 
@@ -634,32 +701,49 @@ def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
                  seq_q: int, seq_k: int, blocks) -> None:
     """Say in `dispatch.taken()` what the kernels were built to do: each
     kernel's (block_q x block_k), whether the scale left the score tile,
-    and what share of each kernel's computed scores lies above the
-    diagonal (known here only when the offsets are static; a traced
-    offset decides it at run time)."""
+    and what share of each kernel's computed scores no query may see
+    (known here only when the offsets are static; a traced offset decides
+    it at run time); under a window also the window and the share of the
+    forward's (tile, block) pairs that it visits."""
+    (fq, fk), (dq_q, dq_k), (kv_q, kv_k), window = blocks
+    static = isinstance(q_off, int) and isinstance(kv_off, int)
     if not causal:
         dead = "dead0%"
-    elif isinstance(q_off, int) and isinstance(kv_off, int):
-        (fq, fk), (dq_q, dq_k), (kv_q, kv_k) = blocks
+    elif static:
         # dk/dv tiles the keys: the same walk with the sequences reversed
-        shares = (_dead_share(q_off, kv_off, seq_q, seq_k, fq, fk),
-                  _dead_share(q_off, kv_off, seq_q, seq_k, dq_q, dq_k),
+        shares = (_dead_share(q_off, kv_off, seq_q, seq_k, fq, fk, window),
+                  _dead_share(q_off, kv_off, seq_q, seq_k, dq_q, dq_k,
+                              window),
                   _dead_share(1 - kv_off - seq_k, 1 - q_off - seq_q,
-                              seq_k, seq_q, kv_k, kv_q))
+                              seq_k, seq_q, kv_k, kv_q, window))
         dead = "dead" + "/".join("%.0f" % (100 * x) for x in shares) + "%"
     else:
         dead = "dead_by_offset"
     scale = "scale_folded" if _scale_is_exact(sm_scale) else "scale_per_score"
     sizes = ",".join("%s%dx%d" % (name, bq, bk) for name, (bq, bk)
                      in zip(("fwd", "dq", "dkv"), blocks))
-    dispatch.record("flash_attention.plan", f"{sizes},{scale},{dead}")
+    plan = f"{sizes},{scale},{dead}"
+    if window is not None:
+        plan += f",window{window}"
+        if static:
+            _, visited = _walk_counts(q_off, kv_off, seq_q, seq_k, fq, fk,
+                                      window)
+            plan += ",visited%.1f%%" % (
+                100.0 * visited / ((seq_q // fq) * (seq_k // fk)))
+    dispatch.record("flash_attention.plan", plan)
 
 
-def _chunk(q, k, v, q_off, kv_off, causal, sm_scale, blocks):
+def _chunk(q, k, v, q_off, kv_off, causal, sm_scale, blocks, window=None):
+    if window is not None and not causal:
+        raise ValueError("a window needs causal=True")
+    blocks = (*blocks, window)
     _record_plan(q_off, kv_off, causal, sm_scale, q.shape[1], k.shape[1],
                  blocks)
-    offs = jnp.stack([jnp.asarray(q_off, jnp.int32),
-                      jnp.asarray(kv_off, jnp.int32)])
+    # Under a window the scalars are [q_off, kv_off, window]: the kernels
+    # read the window there, and a windowed call shows in a trace by its
+    # first operand, s32[3] (the benchmark's swa reader finds it so).
+    offs = jnp.stack([jnp.asarray(x, jnp.int32) for x in
+                      (q_off, kv_off) + (() if window is None else (window,))])
     return _flash_lse(q, k, v, offs, causal, sm_scale, blocks)
 
 
@@ -681,8 +765,14 @@ def flash_attention_chunk(q, k, v, q_off, kv_off, causal: bool = True,
 def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None):
+                    block_k: Optional[int] = None,
+                    window: Optional[int] = None):
     """Tiled attention. q:[b,s,h,d], k/v:[b,t,h,d] -> [b,s,h,d].
+
+    window (causal only): query t sees keys s with 0 <= t - s < window.
+    The kernels do not visit the blocks wholly behind a tile's window
+    and mask the trailing edge in the blocks they do; window=None builds
+    exactly the causal kernels.
 
     Uses the Pallas kernels on TPU (or in interpret mode for tests); falls
     back to the jnp reference elsewhere.  Heads must already be expanded
@@ -708,18 +798,21 @@ def flash_attention(q, k, v, causal: bool = True,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     sq, sk, d = q.shape[1], k.shape[1], q.shape[-1]
+    if window is not None and window >= sk:
+        window = None          # every key a query may see is in its window
     if block_q is None and block_k is None:
-        blocks = default_blocks(d, sq, sk, q.dtype)
+        blocks = default_blocks(d, sq, sk, q.dtype, window)
     else:
         blocks = ((min(block_q or 512, sq), min(block_k or 512, sk)),) * 3
     if not all(_can_use_pallas(sq, sk, d, bq, bk) for bq, bk in blocks):
         dispatch.record("flash_attention", "xla")
-        return attention_reference(q, k, v, causal, sm_scale)
+        return attention_reference(q, k, v, causal, sm_scale, window)
     dispatch.record("flash_attention", "interpret"
                     if dispatch.interpret_mode() else "pallas")
 
     def kernel(q, k, v):
-        return _chunk(q, k, v, sk - sq, 0, causal, sm_scale, blocks)[0]
+        return _chunk(q, k, v, sk - sq, 0, causal, sm_scale, blocks,
+                      window)[0]
 
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or mesh.size == 1:

@@ -29,6 +29,11 @@ DEFAULT_RULES: Rules = (
     ("mlp", "tensor"),
     ("vocab", "tensor"),
     ("expert", "expert"),
+    # State-space layers (models/hybrid.py): the inner width shards like
+    # the feed-forward's; a channel's state and conv taps stay together.
+    ("ssm_inner", "tensor"),
+    ("ssm_state", None),
+    ("ssm_conv", None),
     # Layer dim shards over the stage axis: with stage>1 each device
     # holds its pipeline stage's contiguous run of layers at rest, so
     # the [L,...] -> [S, L/S, ...] regroup in the pipelined forward is a

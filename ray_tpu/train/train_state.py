@@ -1,4 +1,4 @@
-"""Sharded train state + jitted training step for the flagship transformer.
+"""Sharded train state + jitted training step for the in-tree models.
 
 The reference's per-strategy process-group setup (train/torch/config.py:65
 `_setup_torch_process_group`, DDP wrap in train_loop_utils.py:158) collapses on
@@ -6,18 +6,23 @@ TPU into ONE jitted function over a named mesh: GSPMD inserts the gradient
 psum on the `data`/`fsdp` axes, parameter all-gathers for FSDP, and tensor
 collectives for TP.  This module owns that step; trainers (train/),
 learners (rl/) and the bench harness all reuse it.
+
+The model is the configuration's: a config dataclass lives in its model's
+module (models/transformer.py's TransformerConfig, models/hybrid.py's
+HybridConfig), and that module offers `init_params(config, key)`,
+`logical_axes(config)` and `loss_fn(params, batch, config)`.
 """
 
 from __future__ import annotations
 
 import functools
+import importlib
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
 
-from ray_tpu.models import transformer as tfm
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
     Rules,
@@ -86,7 +91,8 @@ def _constrain_like_params(tree: Any, params_treedef, param_shardings):
 
 
 class ShardedTrainStep:
-    """Factory for sharded init/step functions on a mesh.
+    """Factory for sharded init/step functions on a mesh, for the model
+    whose config it is given (the config's type names the model's module).
 
     Usage:
         ts = ShardedTrainStep(config, mesh)
@@ -94,12 +100,14 @@ class ShardedTrainStep:
         state, metrics = ts.step(state, batch)   # batch: {"tokens": [b, s+1]}
     """
 
-    def __init__(self, config: tfm.TransformerConfig, mesh,
+    def __init__(self, config, mesh,
                  optimizer: Optional[optax.GradientTransformation] = None,
                  rules: Rules = DEFAULT_RULES,
                  loss_fn: Optional[Callable] = None,
                  num_microbatches: Optional[int] = None):
         self.config = config
+        self.model = model = importlib.import_module(type(config).__module__)
+        self._evaluators: Dict[str, Any] = {}
         self.mesh = mesh
         self.optimizer = optimizer or default_optimizer()
         self.rules = rules
@@ -113,12 +121,12 @@ class ShardedTrainStep:
         if loss_fn is not None:
             self.loss_fn = loss_fn
         elif self.num_stages > 1:
-            self.loss_fn = lambda p, b: tfm.loss_fn_pipelined(
+            self.loss_fn = lambda p, b: model.loss_fn_pipelined(
                 p, b, config, self.num_stages, self.num_microbatches,
                 mesh=mesh)
         else:
-            self.loss_fn = lambda p, b: tfm.loss_fn(p, b, config)
-        self.param_logical = tfm.logical_axes(config)
+            self.loss_fn = lambda p, b: model.loss_fn(p, b, config)
+        self.param_logical = model.logical_axes(config)
         self.param_shardings = tree_shardings(
             mesh, self.param_logical, rules)
         self.batch_sharding = data_sharding(mesh)
@@ -142,7 +150,7 @@ class ShardedTrainStep:
 
     # -- init ---------------------------------------------------------------
     def _init_fn(self, rng):
-        params = tfm.init_params(self.config, rng)
+        params = self.model.init_params(self.config, rng)
         params = jax.tree.map(
             jax.lax.with_sharding_constraint, params, self.param_shardings)
         opt_state = self.optimizer.init(params)
@@ -198,8 +206,20 @@ class ShardedTrainStep:
 
         return device_stats.count_compiles(jax.jit(eval_fn), "train.eval")
 
-    def eval_step(self, params, batch):
+    def _evaluator(self, name: str):
+        if name not in self._evaluators:
+            fn = getattr(self.model, name)
+            self._evaluators[name] = device_stats.count_compiles(
+                jax.jit(lambda params, batch: fn(params, batch, self.config)),
+                "train.eval")
+        return self._evaluators[name]
+
+    def eval_step(self, params, batch, output: Optional[str] = None):
+        """The loss of `batch`; or, with `output`, what the model module's
+        function of that name gives for (params, batch, config), e.g. a
+        model's `token_nll`: the same sharded forward-only program."""
+        program = self._eval if output is None else self._evaluator(output)
         with self._span("train.eval"):
             batch = jax.device_put(batch, self.batch_sharding)
             with self._mesh_scope():
-                return self._eval(params, batch)
+                return program(params, batch)

@@ -365,3 +365,106 @@ def test_plan_is_recorded_beside_the_path():
     plans = dispatch.taken()["flash_attention.plan"]
     assert plans.get("fwd128x128,dq128x128,dkv128x128,scale_per_score,"
                      "dead_by_offset")
+
+
+# ---------------------------------------------------------------------------
+# Sliding window: query t sees keys s with 0 <= t - s < window
+# ---------------------------------------------------------------------------
+
+# (sq, sk, window, block_q, block_k); None, None is `default_blocks`' plan
+# (tile = block = 512 under a window).  (2048, 2048, 512) is the benchmark's
+# window at a quarter of its sequence; the others put the window's trailing
+# edge off the block grid, inside one block, over a long tile's narrow
+# steps, and over end-aligned queries (sq < sk).
+_WINDOWS = [(2048, 2048, 512, None, None), (1024, 1024, 300, 256, 256),
+            (512, 512, 100, 256, 128), (1024, 1024, 512, 1024, 256),
+            (512, 512, 130, 128, 512), (256, 768, 200, 128, 128),
+            (512, 512, 1, 128, 128)]
+
+
+@pytest.mark.parametrize("sq,sk,window,bq,bk", _WINDOWS)
+@pytest.mark.parametrize("d", [64, 128])
+def test_window_values_and_grads_match_masked_reference(d, sq, sk, window,
+                                                        bq, bk):
+    ks = jax.random.split(jax.random.PRNGKey(sq + sk + window + d), 4)
+    heads = 1 if sq >= 2048 else 2
+    q = jax.random.normal(ks[0], (1, sq, heads, d), jnp.float32)
+    k = jax.random.normal(ks[1], (1, sk, heads, d), jnp.float32)
+    v = jax.random.normal(ks[2], (1, sk, heads, d), jnp.float32)
+    w = jax.random.normal(ks[3], (1, sq, heads, d), jnp.float32)
+    out, grads = _grads_and_value(
+        lambda q, k, v: attn.flash_attention(
+            q, k, v, window=window, block_q=bq, block_k=bk), q, k, v, w)
+    # the masked reference, written out here: end-aligned positions
+    behind = (jnp.arange(sq)[:, None] + (sk - sq)) - jnp.arange(sk)[None, :]
+    seen = (behind >= 0) & (behind < window)
+
+    def masked(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+        p = jax.nn.softmax(jnp.where(seen[None, None], s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+    ref, ref_grads = _grads_and_value(masked, q, k, v, w)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    for g, r in zip(grads, ref_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=5e-4, rtol=5e-4)
+    # the XLA fallback takes the same window
+    np.testing.assert_allclose(
+        np.asarray(attn.attention_reference(q, k, v, window=window)),
+        np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("window", [512, 4096])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_window_at_least_the_sequence_is_bit_for_bit_the_causal_call(
+        window, dtype, monkeypatch):
+    """A window no query can reach the end of builds the causal kernels:
+    the same values and gradients to the bit, and the causal plan."""
+    monkeypatch.setattr(attn.dispatch, "_taken", {})
+    q, k, v = (x.astype(dtype) for x in _rand_qkv(11, 1, 512, 2, 64))
+    w = _rand_qkv(12, 1, 512, 2, 64)[0].astype(dtype)
+    out_w, g_w = _grads_and_value(
+        lambda q, k, v: attn.flash_attention(q, k, v, window=window),
+        q, k, v, w)
+    out_c, g_c = _grads_and_value(
+        lambda q, k, v: attn.flash_attention(q, k, v), q, k, v, w)
+    for a, b_ in zip((out_w, *g_w), (out_c, *g_c)):
+        assert np.array_equal(np.asarray(a.astype(jnp.float32)),
+                              np.asarray(b_.astype(jnp.float32)))
+    plans = attn.dispatch.taken()["flash_attention.plan"]
+    assert len(plans) == 1 and "window" not in next(iter(plans))
+
+
+@pytest.mark.parametrize("seq,window,blocks,visited,dead", [
+    # a tile of 512 meets the block on its diagonal and the one behind it
+    (8192, 512, None, (1 + 15 * 2) / 256, 0.5),
+    (2048, 512, None, 7 / 16, 0.5),
+    # a long tile meets every block of its window with all its queries
+    (2048, 512, (2048, 512), 4 / 4, None),
+    (2048, 100, (256, 256), (1 + 7 * 2) / 64, None)])
+def test_window_plan_record(seq, window, blocks, visited, dead, monkeypatch):
+    """`flash_attention.plan` carries the window and the share of (tile,
+    block) pairs the forward visits; `default_blocks` drops the long tile
+    under a window; `_dead_share` counts the scores behind the window."""
+    monkeypatch.setattr(attn.dispatch, "_taken", {})
+    bq, bk = blocks or (None, None)
+    x = jax.ShapeDtypeStruct((1, seq, 1, 64), jnp.float32)
+    jax.eval_shape(lambda q, k, v: attn.flash_attention(
+        q, k, v, window=window, block_q=bq, block_k=bk), x, x, x)
+    (plan, times), = attn.dispatch.taken()["flash_attention.plan"].items()
+    assert times == 1 and f",window{window},visited" in plan
+    got = float(plan.rsplit("visited", 1)[1].rstrip("%")) / 100
+    assert got == pytest.approx(visited, abs=6e-4)
+    if blocks is None:
+        assert attn.default_blocks(64, seq, seq, jnp.float32, window) == (
+            (512, 512),) * 3
+        assert plan.startswith("fwd512x512,dq512x512,dkv512x512,")
+    if dead is not None:
+        assert attn._dead_share(0, 0, seq, seq, 512, 512, window) \
+            == pytest.approx(dead, abs=2e-3)
+        # without the window the same blocks waste less: only the diagonal
+        assert attn._dead_share(0, 0, seq, seq, 512, 512) < dead
+    with pytest.raises(ValueError):     # a window is causal
+        attn._chunk(x, x, x, 0, 0, False, 0.125, ((512, 512),) * 3, window)

@@ -131,18 +131,22 @@ def test_flash_backward_compiles_for_v5e(one_chip):
 CELL_ROWS = {"train-d12": 5, "train-fsdp4": 40}
 
 
-def _roofline_kernel_pattern():
-    """The pattern by which the benchmark's roofline reader finds the
-    forward kernel in a trace (an HLO line's result and first operand)."""
+def _reader(name):
+    """A layer-metric reader under benchmark/layer_metrics/, by file name."""
     import importlib.util
 
     path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                        "benchmark", "layer_metrics",
-                        "flash_fwd_roofline.train.py")
-    spec = importlib.util.spec_from_file_location("_roofline_reader", path)
+                        "benchmark", "layer_metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location("_reader", path)
     reader = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(reader)
-    return reader.KERNEL
+    return reader
+
+
+def _roofline_kernel_pattern():
+    """The pattern by which the benchmark's roofline reader finds the
+    forward kernel in a trace (an HLO line's result and first operand)."""
+    return _reader("flash_fwd_roofline.train").KERNEL
 
 
 def _cell_calls(topo, monkeypatch, cell, fn):
@@ -239,3 +243,142 @@ def test_flash_under_fsdp_mesh_is_shard_mapped(topo, monkeypatch):
         text = _compiled_text(
             lambda q, k, v: attention.flash_attention(q, k, v), x, x, x)
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# The hybrid cell (train-hybrid-d8: Phi-4-mini-flash-reasoning's widths,
+# 1 x 8192 tokens): selective scan, windowed flash, the step's bytes
+# ---------------------------------------------------------------------------
+
+HYBRID_ROWS, HYBRID_SEQ, D_INNER, D_STATE = 1, 8192, 5120, 16
+
+
+def _on_tpu(monkeypatch, module):
+    monkeypatch.setattr(module.dispatch, "platform", lambda: "tpu")
+    monkeypatch.setattr(module.dispatch, "interpret_mode", lambda: False)
+
+
+def _scan_shapes(one_chip):
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, t, c, n = HYBRID_ROWS, HYBRID_SEQ, D_INNER, D_STATE
+    return (sds((b, t, c), jnp.bfloat16), sds((b, t, c)), sds((c, n)),
+            sds((b, t, n)), sds((b, t, n)), sds((c,)))
+
+
+def test_cell_scan_kernels_compile_and_keep_the_faces_the_readers_find(
+        one_chip, monkeypatch):
+    """Forward alone, forward with saved states and backward at the cell's
+    widths; each custom-call is found by exactly the pattern that
+    benchmark/scan_faces.py gives the scan readers for it."""
+    import re
+
+    from ray_tpu.ops import selective_scan as ss
+
+    _on_tpu(monkeypatch, ss)
+    monkeypatch.setattr(ss.dispatch, "_taken", {})
+    forward, backward = _reader("selective_scan_share.hybrid").KERNELS
+    assert _reader("selective_scan_roofline.hybrid").KERNEL == forward
+    shapes = _scan_shapes(one_chip)
+    calls = _custom_calls_as_traced(ss.selective_scan, *shapes)
+    assert len(calls) == 1 and re.search(forward, calls[0]), calls
+    assert not re.search(backward, calls[0])
+
+    def loss(*a):
+        return ss.selective_scan(*a).astype(jnp.float32).sum()
+
+    calls = _custom_calls_as_traced(
+        jax.grad(loss, argnums=tuple(range(6))), *shapes)
+    assert len(calls) == 2, calls       # forward with states, backward
+    assert sorted((bool(re.search(forward, l)), bool(re.search(backward, l)))
+                  for l in calls) == [(False, True), (True, False)]
+    taken = ss.dispatch.taken()
+    assert taken["selective_scan"] == {"pallas": 2}
+    assert list(taken["selective_scan.plan"]) == [
+        "chunk128,channels1024,seq8192,state16"]
+
+
+def test_cell_windowed_flash_compiles_and_is_told_from_the_full_call(
+        one_chip, monkeypatch):
+    """Differential attention's call at the cell's widths (40 heads, q and
+    k padded to 128): windowed and full, forward and backward.  The
+    windowed forward's first operand is s32[3], which is how
+    swa_fwd_roofline.hybrid tells it from the full call's s32[2]
+    (flash_fwd_roofline.hybrid)."""
+    import re
+
+    _on_tpu(monkeypatch, attention)
+    monkeypatch.setattr(attention.dispatch, "_taken", {})
+    swa = _reader("swa_fwd_roofline.hybrid").KERNEL
+    full = _reader("flash_fwd_roofline.hybrid").KERNEL
+    assert full == _roofline_kernel_pattern()   # the dense cells' face
+    x = jax.ShapeDtypeStruct((HYBRID_ROWS, HYBRID_SEQ, 40, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    for window, mine, other in ((512, swa, full), (None, full, swa)):
+        def attend(q, k, v, window=window):
+            return attention.flash_attention(q, k, v, sm_scale=0.125,
+                                             window=window)
+
+        def loss(q, k, v):
+            return attend(q, k, v).astype(jnp.float32).sum()
+
+        calls = _custom_calls_as_traced(attend, x, x, x)
+        assert len(calls) == 1 and re.search(mine, calls[0]), calls
+        assert not re.search(other, calls[0])
+        assert "(bf16[40,8192,128], f32[40,8,8192])" in calls[0]
+        calls = _custom_calls_as_traced(jax.grad(loss, argnums=(0, 1, 2)),
+                                        x, x, x)
+        assert len(calls) == 3          # forward, dq, dk/dv
+        assert sum(bool(re.search(mine, l)) for l in calls) == 1
+        assert not any(re.search(other, l) for l in calls)
+    plans = list(attention.dispatch.taken()["flash_attention.plan"])
+    assert any(p.endswith(",window512,visited12.1%") for p in plans), plans
+    assert any("window" not in p for p in plans)
+
+
+def test_cell_hybrid_step_program_fits_a_v5e(topo, monkeypatch):
+    """The cell's whole step program (eight layers of five kinds, an
+    eighth of the vocabulary, 1 x 8192 tokens, full remat, fused CE,
+    bfloat16 moments) by AOT memory_analysis: under 15.75 GiB."""
+    import json
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmark.drivers import train_model
+    from ray_tpu.ops import selective_scan as ss
+    from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
+
+    _on_tpu(monkeypatch, attention)
+    _on_tpu(monkeypatch, ss)
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmark", "configs",
+                        "phi4-mini-flash-train-d8.json")
+    doc = json.load(open(path))
+    tr = doc["train"]
+    config = train_model.build_config(doc["program"], doc["model"], tr)
+    mesh = Mesh(topo.devices[:1], ("fsdp",))
+    whole = NamedSharding(mesh, P())
+    ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
+        warmup_steps=tr["lr_warmup_steps"], total_steps=tr["lr_total_steps"],
+        mu_dtype=jnp.bfloat16, nu_dtype=jnp.bfloat16))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    with jax.sharding.set_mesh(mesh):
+        state = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=whole),
+            jax.eval_shape(ts._init_fn, key))
+        batch = {"tokens": jax.ShapeDtypeStruct(
+            (tr["batch_rows"], tr["sequence_length"] + 1), jnp.int32,
+            sharding=whole)}
+        compiled = jax.jit(ts._step_fn, donate_argnums=(0,)).lower(
+            state, batch).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 13.0 * 2 ** 30 < total < 15.75 * 2 ** 30, total / 2 ** 30
+    text = compiled.as_text()
+    # The two (mamba, window) pairs are ONE scanned body: a scan layer is
+    # forward, forward again under remat, backward (3 calls), an attention
+    # layer forward, forward again, dq, dk/dv (4).  So the pair's body 7,
+    # the lone mamba 3, the full layer 4, the cross layer 4.
+    assert text.count("tpu_custom_call") == 7 + 3 + 4 + 4
