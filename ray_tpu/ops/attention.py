@@ -8,17 +8,17 @@ machinery is absent in-tree); on TPU this is a core op.  Design:
     resident in VMEM) so the s×s score matrix never materializes in HBM.
     Causal, or causal under a sliding window (query t sees keys s with
     0 <= t - s < window): the blocks wholly behind a tile's window are not
-    visited, the trailing edge is masked in forward, dq and dk/dv.
+    visited, the trailing edge is masked in forward and backward.
   - `flash_attention_chunk(...)`: the offset-aware variant returning
     (out, lse) — the building block ring attention uses per K/V chunk
     (ops/ring_attention.py); positions enter as DYNAMIC scalars so the
     same compiled kernel serves every ring step.
-  - Backward: Pallas dq and dk/dv kernels recomputing scores blockwise
-    from the saved logsumexp (standard flash backward — dq grid over
-    q-tiles, dkv grid over k-tiles); the s×s matrix never exists in
-    the backward either.  The lse OUTPUT is differentiable too (ring
-    attention's merge weights depend on it): ds += p * dlse.
-  - One plan for the three kernels, made from what each can see (head
+  - Backward: ONE Pallas kernel (grid over k-tiles) that recomputes the
+    scores blockwise from the saved logsumexp once and gives dq, dk and
+    dv from them; the s×s matrix never exists in the backward either.
+    The lse OUTPUT is differentiable too (ring attention's merge weights
+    depend on it): ds += p * dlse.
+  - One plan for the two kernels, made from what each can see (head
     size, sm_scale, the offsets): a program's long tile meets the blocks
     wholly under the diagonal in a loop and the blocks the diagonal
     crosses in straight-line steps against only the part of the tile
@@ -112,20 +112,22 @@ def attention_reference(q, k, v, causal: bool = True,
 #     are comes from `offs` (`_first_narrow_block`), so the offsets may be
 #     traced and lie off the block grid; every step masks, and a step whose
 #     block lies outside the operand is masked whole.
-#   - All three kernels hold scores TRANSPOSED, [keys, queries]: softmax's
+#   - Both kernels hold scores TRANSPOSED, [keys, queries]: softmax's
 #     max and sum then run down sublanes (plain vector max / add), lse,
 #     delta and dlse broadcast as they are stored, and p^T · do, ds^T · q
-#     need no transposed operand.  The forward's accumulator and dq are
-#     held [d, queries] and turned once a program.
+#     need no transposed operand.  The forward's accumulator is held
+#     [d, queries] and turned once a program, the backward's sum of dq
+#     [d, queries] and turned once a head.
 #   - A power-of-two scale (`_scale_is_exact`: 1/sqrt(64) = 0.125) leaves
 #     the score tile: scaling a bf16 or f32 value by a power of two only
 #     moves its exponent, so (q·scale)·k^T equals (q·k^T)·scale bit for
-#     bit.  The [tile, d] operand is scaled once a program, ds stays
-#     unscaled and the f32 accumulator of dq / dk is scaled at the end.
+#     bit.  The [tile, d] operand is scaled once a program (in the
+#     backward k, which then carries the scale into dq as well), ds stays
+#     unscaled and the f32 accumulator of dk is scaled at the end.
 #     Any other scale (head size 128) keeps its per-score multiplies.
 #   - Tile and block sizes come from `default_blocks` (head size, lengths,
 #     dtype) unless the caller passes block_q / block_k, which then hold
-#     for all three kernels.
+#     for both kernels.
 
 def _scale_is_exact(sm_scale: float) -> bool:
     """True when sm_scale is a power of two, so it commutes with every
@@ -145,10 +147,10 @@ def _tile_and_inner(seq_tile: int, seq_inner: int):
 
 def default_blocks(head_dim: int, seq_q: int, seq_k: int, dtype,
                    window: Optional[int] = None):
-    """((block_q, block_k) of the forward, of dq, of dk/dv) for a caller
+    """((block_q, block_k) of the forward, of the backward) for a caller
     that passes none, from the v5e's sweep at sequence 2048, bf16, head
-    sizes 64 and 128 (PERF.md, PR 29).  The forward and dq tile the
-    queries, dk/dv tiles the keys.  Blocks of 256 or 128 leave fewer
+    sizes 64 and 128 (PERF.md, PR 29).  The forward tiles the queries,
+    the backward the keys.  Blocks of 256 or 128 leave fewer
     scores above the diagonal and run 5-10 % faster, but each block is one
     more straight-line step to trace in every process that builds the
     kernel, and a train worker's start pays for that (PERF.md).
@@ -160,10 +162,9 @@ def default_blocks(head_dim: int, seq_q: int, seq_k: int, dtype,
     del head_dim, dtype        # the sweep gave one answer for those it ran
     if window is not None and window < seq_k:
         short = (min(512, seq_q), min(512, seq_k))
-        return short, short, short
-    fwd = _tile_and_inner(seq_q, seq_k)
+        return short, short
     kv_tile, q_inner = _tile_and_inner(seq_k, seq_q)
-    return fwd, fwd, (q_inner, kv_tile)
+    return _tile_and_inner(seq_q, seq_k), (q_inner, kv_tile)
 
 
 def _narrow_steps(tile: int, inner: int) -> int:
@@ -282,7 +283,7 @@ def _visible(query_minus_key, first, window: Optional[int]):
 def _walk_blocks(step, carry, causal: bool, tile_min, tile: int, inner: int,
                  num_blocks: int, window: Optional[int] = None):
     """Run step(start, first, carry, lo) over the blocks of `inner` keys
-    that a tile of queries meets (the forward's and dq's order: the loop,
+    that a tile of queries meets (the forward's order: the loop,
     then the diagonal).  start: the block's first key; first: that key's
     position less the tile's first query's, for the mask; lo (static):
     where in the tile the queries that can see the block begin.  Under a
@@ -370,13 +371,16 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     lse_ref[0] = jnp.broadcast_to(lse, (8, block_q))
 
 
-def _compiler_params():
+def _compiler_params(vmem_mib: int = 32):
     """Scoped VMEM above the v5e's default of 16 MiB: at sequence 8192
-    and head size 128 the resident K and V (double-buffered) and a
-    [256, 2048] f32 score tile with its neighbours need 17.4 MiB."""
+    and head size 128 the forward's resident K and V (double-buffered) and
+    a [256, 2048] f32 score tile with its neighbours need 17.4 MiB; the
+    backward, which holds a head's q, do, dq and dq's float32 sum whole,
+    34.9 MiB inside the hybrid step program (of the chip's 128), so it
+    asks for 48."""
     from jax.experimental.pallas import tpu as pltpu
 
-    return pltpu.CompilerParams(vmem_limit_bytes=32 << 20)
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_mib << 20)
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
@@ -435,66 +439,36 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
 
 
 # ---------------------------------------------------------------------------
-# Pallas backward kernels: recompute-by-block using the saved logsumexp.
-# Standard flash backward split (the reference design point is the public
-# flash-attention algorithm, not the Ray repo): dq iterates k-blocks per
-# q-tile; dk/dv iterate q-blocks per k-tile.  corr = delta - dlse is
-# precomputed outside: delta = rowsum(do * out), and dlse is the cotangent
-# of the lse OUTPUT (zero for plain flash_attention, nonzero under ring
-# attention's merge).  Both follow the forward's plan; with an exact scale
-# ds = p * (dp - corr) stays unscaled per score.
+# Pallas backward kernel: ONE pass recomputes the scores by block from the
+# saved logsumexp and gives dq, dk and dv (the public flash-attention
+# backward, with its two passes folded into the one that tiles the keys).
+# corr = delta - dlse is precomputed outside: delta = rowsum(do * out), and
+# dlse is the cotangent of the lse OUTPUT (zero for plain flash_attention,
+# nonzero under ring attention's merge).
+#
+# A program holds one key tile (k, v: [block_k, d]) and a head's WHOLE q,
+# do, lse and corr in VMEM, and meets the query blocks that can see the
+# tile: the forward's plan with the roles turned (the diagonal cuts the
+# tile's END off in straight-line steps, then the loop; under a window the
+# loop ends early).  A step makes s, p, dp and ds once, [keys, queries],
+# and five matmuls from them: s, dp, dv += p . do, dk += ds . q and the
+# query block's dq = k^T . ds.  dk and dv are the program's own f32
+# accumulators.  dq of the head's WHOLE query sequence is summed, held
+# [d, sq], in a float32 scratch that lives across the key-tile axis: zeroed
+# at the axis's first index, added to by every step (its block's columns),
+# and at the last index turned, rounded once and stored to the dq output,
+# whose block (the head's whole [sq, d]) does not move along that axis.
+# One key tile (sk == block_k: `default_blocks` at sequences up to 2048, no
+# window) or several (longer sequences, a window, passed blocks, ring
+# chunks): the same sum in the same precision.
+# With an exact scale k carries sm_scale into both s and dq, ds stays
+# unscaled per score and the f32 accumulator of dk is scaled at the end.
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   corr_ref, dq_ref, *, causal: bool,
-                   block_q: int, block_k: int, seq_k: int, sm_scale: float,
-                   fold_scale: bool, windowed: bool = False):
-    from jax.experimental import pallas as pl
-
-    qi = pl.program_id(1)
-    window = offs_ref[2] if windowed else None
-    q = q_ref[0]                              # [block_q, d] native dtype
-    do = do_ref[0]                            # [block_q, d] native dtype
-    d = q.shape[-1]
-    if fold_scale:
-        q = _scaled(q, sm_scale)
-    query_minus_key = _query_minus_key(block_k, block_q) if causal else None
-
-    def step(start, first, dq, lo: int):
-        """The key block at `start` against queries [lo, block_q) of the
-        tile; dq is held [d, queries]."""
-        start = pl.multiple_of(start, block_k)
-        k_blk = k_ref[0, pl.ds(start, block_k), :]
-        v_blk = v_ref[0, pl.ds(start, block_k), :]
-        s = _dot(k_blk, q[lo:], 1, 1)              # [block_k, block_q - lo]
-        if not fold_scale:
-            s = s * sm_scale
-        # lse and corr: [1, queries] as stored, sliced from the REF (a
-        # value sliced off the lane grid does not broadcast in Mosaic).
-        p = jnp.exp(s - lse_ref[0, 0:1, lo:])
-        if causal:
-            p = _keep(_visible(query_minus_key[:, lo:], first, window),
-                      p, 0.0)
-        dp = _dot(v_blk, do[lo:], 1, 1)                 # dp^T = v · do^T
-        ds = p * (dp - corr_ref[0, 0:1, lo:])
-        if not fold_scale:
-            ds = ds * sm_scale
-        return _put(dq, lo, dq[:, lo:]
-                    + _dot(k_blk, ds.astype(k_blk.dtype), 0, 0))
-
-    dq = _walk_blocks(
-        step, jnp.zeros((d, block_q), jnp.float32), causal,
-        offs_ref[0] - offs_ref[1] + qi * block_q, block_q, block_k,
-        seq_k // block_k, window)
-    if fold_scale:
-        dq = dq * sm_scale
-    dq_ref[0] = dq.T.astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    corr_ref, dk_ref, dv_ref, *, causal: bool,
-                    block_q: int, block_k: int, seq_q: int, sm_scale: float,
-                    fold_scale: bool, windowed: bool = False):
+def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
+                dq_ref, dk_ref, dv_ref, kt_ref, dqt_ref, *, causal: bool,
+                block_q: int, block_k: int, seq_q: int, sm_scale: float,
+                fold_scale: bool, windowed: bool = False):
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
@@ -503,10 +477,19 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     v = v_ref[0]
     d = k.shape[-1]
     k_s = _scaled(k, sm_scale) if fold_scale else k
+    # k^T for dq, turned once a program into scratch: the steps' matmuls
+    # read it as a plain operand (a transpose that feeds the MXU directly,
+    # or a transposed-left matmul in the step, fails a check of the
+    # compiler at a key tile of 2048).
+    kt_ref[...] = k_s.T
     num_q = seq_q // block_q
     query_minus_key = _query_minus_key(block_k, block_q) if causal else None
     # this tile's first key less the queries' first position
     tile_min = offs_ref[1] - offs_ref[0] + ki * block_k
+
+    @pl.when(ki == 0)
+    def _():
+        dqt_ref[...] = jnp.zeros(dqt_ref.shape, dqt_ref.dtype)
 
     def step(start, first, carry, hi: int):
         """The query block at `start` against keys [0, hi) of the tile (the
@@ -514,10 +497,11 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         held [keys, queries], as in the forward."""
         dk, dv = carry
         start = pl.multiple_of(start, block_q)
-        q_blk = q_ref[0, pl.ds(start, block_q), :]
-        do_blk = do_ref[0, pl.ds(start, block_q), :]
-        lse_blk = lse_ref[0, 0:1, pl.ds(start, block_q)]    # [1, block_q]
-        corr = corr_ref[0, 0:1, pl.ds(start, block_q)]
+        rows = pl.ds(start, block_q)
+        q_blk = q_ref[0, rows, :]
+        do_blk = do_ref[0, rows, :]
+        lse_blk = lse_ref[0, 0:1, rows]                     # [1, block_q]
+        corr = corr_ref[0, 0:1, rows]
         s = _dot(k_s[:hi], q_blk, 1, 1)                     # [hi, block_q]
         if not fold_scale:
             s = s * sm_scale
@@ -528,7 +512,11 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         ds = p * (_dot(v[:hi], do_blk, 1, 1) - corr)        # dp^T = v · do^T
         if not fold_scale:
             ds = ds * sm_scale
-        dk_new = dk[:hi] + _dot(ds.astype(q_blk.dtype), q_blk, 1, 0)
+        ds = ds.astype(q_blk.dtype)
+        dk_new = dk[:hi] + _dot(ds, q_blk, 1, 0)
+        # dq^T += k^T · ds, [d, block_q] (a step whose block lies outside
+        # the operand reads an inside block and adds exact zeros to it).
+        dqt_ref[:, rows] += _dot(kt_ref[:, :hi], ds, 1, 0)
         if hi < block_k:
             dk_new = jnp.concatenate([dk_new, dk[hi:]], axis=0)
             dv_new = jnp.concatenate([dv_new, dv[hi:]], axis=0)
@@ -563,6 +551,10 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[0] = dqt_ref[...].T.astype(dq_ref.dtype)
+
 
 def _lse8(x, bh, s):
     """[bh, s] f32 -> [bh, 8, s] sublane-broadcast (Mosaic tiling)."""
@@ -570,15 +562,14 @@ def _lse8(x, bh, s):
 
 
 def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
-               dq_blocks, dkv_blocks, windowed=False):
+               blocks, windowed=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
     sk = k.shape[1]
     bh = b * h
-    fold_scale = _scale_is_exact(sm_scale)
-    windowed = {"windowed": True} if windowed else {}
+    block_q, block_k = blocks
     qf = q.transpose(0, 2, 1, 3).reshape(bh, sq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(bh, sk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(bh, sk, d)
@@ -592,58 +583,28 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
 
     seq_spec = pl.BlockSpec((1, 8, sq), lambda g, i, offs: (g, 0, 0))
     full_q = pl.BlockSpec((1, sq, d), lambda g, i, offs: (g, 0, 0))
-    full_k = pl.BlockSpec((1, sk, d), lambda g, i, offs: (g, 0, 0))
-
-    block_q, block_k = dq_blocks
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal, block_q=block_q,
-                          block_k=block_k, seq_k=sk, sm_scale=sm_scale,
-                          fold_scale=fold_scale, **windowed),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh, sq // block_q),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda g, i, offs: (g, i, 0)),
-                full_k, full_k,
-                pl.BlockSpec((1, block_q, d), lambda g, i, offs: (g, i, 0)),
-                pl.BlockSpec((1, 8, block_q), lambda g, i, offs: (g, 0, i)),
-                pl.BlockSpec((1, 8, block_q), lambda g, i, offs: (g, 0, i)),
-            ],
-            out_specs=pl.BlockSpec((1, block_q, d),
-                                   lambda g, i, offs: (g, i, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        compiler_params=_compiler_params(),
-        interpret=dispatch.interpret_mode(),
-        name="flash_bwd_dq",
-    )(offs, qf, kf, vf, dof, lse8, corr8)
-
-    block_q, block_k = dkv_blocks
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal, block_q=block_q,
+    k_tile = pl.BlockSpec((1, block_k, d), lambda g, i, offs: (g, i, 0))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, causal=causal, block_q=block_q,
                           block_k=block_k, seq_q=sq, sm_scale=sm_scale,
-                          fold_scale=fold_scale, **windowed),
+                          fold_scale=_scale_is_exact(sm_scale),
+                          **({"windowed": True} if windowed else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, sk // block_k),
-            in_specs=[
-                full_q,
-                pl.BlockSpec((1, block_k, d), lambda g, i, offs: (g, i, 0)),
-                pl.BlockSpec((1, block_k, d), lambda g, i, offs: (g, i, 0)),
-                full_q, seq_spec, seq_spec,
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_k, d), lambda g, i, offs: (g, i, 0)),
-                pl.BlockSpec((1, block_k, d), lambda g, i, offs: (g, i, 0)),
-            ],
+            in_specs=[full_q, k_tile, k_tile, full_q, seq_spec, seq_spec],
+            out_specs=[full_q, k_tile, k_tile],
+            scratch_shapes=[pltpu.VMEM((d, block_k), k.dtype),
+                            pltpu.VMEM((d, sq), jnp.float32)],
         ),
         out_shape=[
+            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(48),
         interpret=dispatch.interpret_mode(),
-        name="flash_bwd_dkv",
+        name="flash_bwd",
     )(offs, qf, kf, vf, dof, lse8, corr8)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
@@ -654,18 +615,18 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
 
 # ---------------------------------------------------------------------------
 # custom VJP over (out, lse).  `blocks` is the (block_q, block_k) of the
-# forward, of dq and of dk/dv, in that order, then the window (or None).
+# forward and of the backward, in that order, then the window (or None).
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _flash_lse(q, k, v, offs, causal, sm_scale, blocks):
     return _flash_fwd(q, k, v, offs, causal, sm_scale, *blocks[0], None,
-                      blocks[3] is not None)
+                      blocks[2] is not None)
 
 
 def _flash_lse_fwd(q, k, v, offs, causal, sm_scale, blocks):
     out, lse = _flash_fwd(q, k, v, offs, causal, sm_scale, *blocks[0], None,
-                          blocks[3] is not None)
+                          blocks[2] is not None)
     # Named residuals: under jax.checkpoint with
     # save_only_these_names("attn_out", "attn_lse") (the transformer's
     # "save_attn" remat policy) the kernel outputs are kept from the
@@ -685,8 +646,8 @@ def _flash_lse_bwd(causal, sm_scale, blocks, res, cts):
     q, k, v, out, lse, offs = res
     dout, dlse = cts
     dq, dk, dv = _flash_bwd(q, k, v, out, lse, offs, dout, dlse,
-                            causal, sm_scale, blocks[1], blocks[2],
-                            blocks[3] is not None)
+                            causal, sm_scale, blocks[1],
+                            blocks[2] is not None)
     return dq, dk, dv, None  # offs (int positions) has no gradient
 
 
@@ -700,29 +661,30 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
                  seq_q: int, seq_k: int, blocks) -> None:
     """Say in `dispatch.taken()` what the kernels were built to do: each
-    kernel's (block_q x block_k), whether the scale left the score tile,
-    and what share of each kernel's computed scores no query may see
-    (known here only when the offsets are static; a traced offset decides
-    it at run time); under a window also the window and the share of the
-    forward's (tile, block) pairs that it visits."""
-    (fq, fk), (dq_q, dq_k), (kv_q, kv_k), window = blocks
+    kernel's (block_q x block_k), that dq comes out of the backward's one
+    pass and over how many key tiles it is summed there, whether the scale
+    left the score tile, and what share of each kernel's computed scores
+    no query may see (known here only when the offsets are static; a
+    traced offset decides it at run time); under a window also the window
+    and the share of the forward's (tile, block) pairs that it visits."""
+    (fq, fk), (kv_q, kv_k), window = blocks
     static = isinstance(q_off, int) and isinstance(kv_off, int)
     if not causal:
         dead = "dead0%"
     elif static:
-        # dk/dv tiles the keys: the same walk with the sequences reversed
+        # the backward tiles the keys: the same walk with the sequences
+        # reversed
         shares = (_dead_share(q_off, kv_off, seq_q, seq_k, fq, fk, window),
-                  _dead_share(q_off, kv_off, seq_q, seq_k, dq_q, dq_k,
-                              window),
                   _dead_share(1 - kv_off - seq_k, 1 - q_off - seq_q,
                               seq_k, seq_q, kv_k, kv_q, window))
         dead = "dead" + "/".join("%.0f" % (100 * x) for x in shares) + "%"
     else:
         dead = "dead_by_offset"
     scale = "scale_folded" if _scale_is_exact(sm_scale) else "scale_per_score"
-    sizes = ",".join("%s%dx%d" % (name, bq, bk) for name, (bq, bk)
-                     in zip(("fwd", "dq", "dkv"), blocks))
-    plan = f"{sizes},{scale},{dead}"
+    plan = f"fwd{fq}x{fk},bwd{kv_q}x{kv_k},dq_in_pass"
+    if seq_k // kv_k > 1:
+        plan += f",dq_over{seq_k // kv_k}tiles"
+    plan += f",{scale},{dead}"
     if window is not None:
         plan += f",window{window}"
         if static:
@@ -759,7 +721,7 @@ def flash_attention_chunk(q, k, v, q_off, kv_off, causal: bool = True,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     return _chunk(q, k, v, q_off, kv_off, causal, sm_scale,
-                  ((block_q, block_k),) * 3)
+                  ((block_q, block_k),) * 2)
 
 
 def flash_attention(q, k, v, causal: bool = True,
@@ -787,7 +749,7 @@ def flash_attention(q, k, v, causal: bool = True,
     d] operand and the accumulators instead of every score, which is
     exact.  Block sizes the caller does not pass come from
     `default_blocks(head_dim, sq, sk, dtype)`, each kernel its own; a
-    block_q / block_k that is passed holds for all three kernels.
+    block_q / block_k that is passed holds for both kernels.
     `dispatch.taken()` holds the plan under "flash_attention.plan".
 
     Under an ambient multi-device mesh (jax.sharding.set_mesh) the kernel
@@ -803,7 +765,7 @@ def flash_attention(q, k, v, causal: bool = True,
     if block_q is None and block_k is None:
         blocks = default_blocks(d, sq, sk, q.dtype, window)
     else:
-        blocks = ((min(block_q or 512, sq), min(block_k or 512, sk)),) * 3
+        blocks = ((min(block_q or 512, sq), min(block_k or 512, sk)),) * 2
     if not all(_can_use_pallas(sq, sk, d, bq, bk) for bq, bk in blocks):
         dispatch.record("flash_attention", "xla")
         return attention_reference(q, k, v, causal, sm_scale, window)

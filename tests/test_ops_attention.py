@@ -188,7 +188,8 @@ def _grads_and_value(fn, q, k, v, w):
 # (sq, sk, block_q, block_k); None, None is `default_blocks`' own plan.
 # sq < sk is end-aligned, and (128, 320, 128, 64) puts the diagonal 192
 # rows in, off the q-block grid.  block_q = R * block_k narrows the
-# forward's and dq's steps on the diagonal, block_k = R * block_q dk/dv's.
+# forward's steps on the diagonal, block_k = R * block_q the backward's.
+# sk // block_k is the number of key tiles the backward sums dq over.
 _SHAPES = [(256, 256, 128, 128), (512, 512, 256, 256), (512, 512, 512, 512),
            (512, 512, 256, 128), (512, 512, 128, 256), (128, 384, 128, 128),
            (128, 320, 128, 64), (512, 512, 512, 128), (512, 512, 128, 512),
@@ -327,15 +328,17 @@ def test_dead_share_counts_what_the_forward_computes(bq, bk, delta, dead):
 
 
 @pytest.mark.parametrize("sq,sk,plan", [
-    (2048, 2048, ((2048, 512), (2048, 512), (512, 2048))),
-    (1024, 1024, ((1024, 512), (1024, 512), (512, 1024))),
-    (256, 2048, ((256, 512), (256, 512), (256, 2048))),
-    (4096, 4096, ((2048, 512), (2048, 512), (512, 2048))),
-    (1536, 1536, ((512, 512), (512, 512), (512, 512))),
-    (128, 128, ((128, 128), (128, 128), (128, 128))),
-    (128, 320, ((128, 320), (128, 320), (128, 320))),
-    (100, 100, ((100, 100), (100, 100), (100, 100)))])
+    (2048, 2048, ((2048, 512), (512, 2048))),
+    (1024, 1024, ((1024, 512), (512, 1024))),
+    (256, 2048, ((256, 512), (256, 2048))),
+    (4096, 4096, ((2048, 512), (512, 2048))),
+    (1536, 1536, ((512, 512), (512, 512))),
+    (128, 128, ((128, 128), (128, 128))),
+    (128, 320, ((128, 320), (128, 320))),
+    (100, 100, ((100, 100), (100, 100)))])
 def test_default_blocks(sq, sk, plan):
+    """(forward, backward): the forward tiles the queries, the backward
+    the keys."""
     got = attn.default_blocks(64, sq, sk, jnp.bfloat16)
     assert got == plan
     for bq, bk in got:
@@ -357,19 +360,36 @@ def test_plan_is_recorded_beside_the_path():
 
     assert new("flash_attention") == {"interpret": 1}
     assert new("flash_attention.plan") == {
-        "fwd2048x512,dq2048x512,dkv512x2048,scale_folded,dead20/20/20%": 1}
+        "fwd2048x512,bwd512x2048,dq_in_pass,scale_folded,dead20/20%": 1}
     # a traced offset (ring attention) and head size 128
     q, k, v = _rand_qkv(9, 1, 128, 1, 128)
     jax.make_jaxpr(lambda q, k, v, o: attn.flash_attention_chunk(
         q, k, v, o, 0))(q, k, v, jnp.int32(0))
     plans = dispatch.taken()["flash_attention.plan"]
-    assert plans.get("fwd128x128,dq128x128,dkv128x128,scale_per_score,"
+    assert plans.get("fwd128x128,bwd128x128,dq_in_pass,scale_per_score,"
                      "dead_by_offset")
 
 
 # ---------------------------------------------------------------------------
 # Sliding window: query t sees keys s with 0 <= t - s < window
 # ---------------------------------------------------------------------------
+
+def _masked_reference(sq, sk, d, causal, window):
+    """Attention under the explicit mask, end-aligned (written out here)."""
+    behind = (jnp.arange(sq)[:, None] + (sk - sq)) - jnp.arange(sk)[None, :]
+    seen = jnp.ones((sq, sk), bool)
+    if causal:
+        seen = behind >= 0
+    if window is not None:
+        seen = seen & (behind < window)
+
+    def masked(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+        p = jax.nn.softmax(jnp.where(seen[None, None], s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+    return masked
+
 
 # (sq, sk, window, block_q, block_k); None, None is `default_blocks`' plan
 # (tile = block = 512 under a window).  (2048, 2048, 512) is the benchmark's
@@ -395,16 +415,8 @@ def test_window_values_and_grads_match_masked_reference(d, sq, sk, window,
     out, grads = _grads_and_value(
         lambda q, k, v: attn.flash_attention(
             q, k, v, window=window, block_q=bq, block_k=bk), q, k, v, w)
-    # the masked reference, written out here: end-aligned positions
-    behind = (jnp.arange(sq)[:, None] + (sk - sq)) - jnp.arange(sk)[None, :]
-    seen = (behind >= 0) & (behind < window)
-
-    def masked(q, k, v):
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
-        p = jax.nn.softmax(jnp.where(seen[None, None], s, -jnp.inf), axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-
-    ref, ref_grads = _grads_and_value(masked, q, k, v, w)
+    ref, ref_grads = _grads_and_value(
+        _masked_reference(sq, sk, d, True, window), q, k, v, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
     for g, r in zip(grads, ref_grads):
@@ -459,12 +471,180 @@ def test_window_plan_record(seq, window, blocks, visited, dead, monkeypatch):
     assert got == pytest.approx(visited, abs=6e-4)
     if blocks is None:
         assert attn.default_blocks(64, seq, seq, jnp.float32, window) == (
-            (512, 512),) * 3
-        assert plan.startswith("fwd512x512,dq512x512,dkv512x512,")
+            (512, 512),) * 2
+        assert plan.startswith(
+            f"fwd512x512,bwd512x512,dq_in_pass,dq_over{seq // 512}tiles,")
     if dead is not None:
         assert attn._dead_share(0, 0, seq, seq, 512, 512, window) \
             == pytest.approx(dead, abs=2e-3)
         # without the window the same blocks waste less: only the diagonal
         assert attn._dead_share(0, 0, seq, seq, 512, 512) < dead
     with pytest.raises(ValueError):     # a window is causal
-        attn._chunk(x, x, x, 0, 0, False, 0.125, ((512, 512),) * 3, window)
+        attn._chunk(x, x, x, 0, 0, False, 0.125, ((512, 512),) * 2, window)
+
+
+# ---------------------------------------------------------------------------
+# dq out of the backward's one pass: stored where a head has one key tile,
+# summed in float32 over the key-tile axis where it has several
+# ---------------------------------------------------------------------------
+
+# causal, window: causal, not causal, a window off the block grid
+_MASKS = [(True, None), (False, None), (True, 200)]
+
+
+@pytest.mark.parametrize("key_tiles", [1, 2, 4])
+@pytest.mark.parametrize("causal,window", _MASKS)
+@pytest.mark.parametrize("d", [64, 128])     # scale folded / kept per score
+def test_dq_from_the_one_pass_matches_reference(d, causal, window,
+                                                key_tiles, monkeypatch):
+    """dq, dk and dv of the fused backward at 1, 2 and 4 key tiles, the
+    query block a quarter of the longest tile (so the diagonal's narrow
+    steps run); the plan record says over how many tiles dq was summed."""
+    monkeypatch.setattr(attn.dispatch, "_taken", {})
+    sq = sk = 512
+    bq, bk = 128, sk // key_tiles
+    ks = jax.random.split(jax.random.PRNGKey(31 + d + key_tiles), 4)
+    q, k, v, w = (jax.random.normal(x, (1, sq, 2, d), jnp.float32)
+                  for x in ks)
+    out, grads = _grads_and_value(
+        lambda q, k, v: attn.flash_attention(
+            q, k, v, causal=causal, window=window, block_q=bq, block_k=bk),
+        q, k, v, w)
+    ref, ref_grads = _grads_and_value(
+        _masked_reference(sq, sk, d, causal, window), q, k, v, w)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    for g, r in zip(grads, ref_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=5e-4, rtol=5e-4)
+    (plan, _), = attn.dispatch.taken()["flash_attention.plan"].items()
+    assert f"bwd{bq}x{bk},dq_in_pass," in plan
+    assert (f",dq_over{key_tiles}tiles," in plan) == (key_tiles > 1)
+
+
+@pytest.mark.parametrize("key_tiles", [1, 2, 4])
+@pytest.mark.parametrize("d", [64, 128])
+def test_dq_with_fewer_queries_than_keys_off_the_block_grid(d, key_tiles):
+    """sq < sk end-aligned with the diagonal 192 rows in, off the query
+    blocks: some key tiles meet no query block whole, and the last meets
+    them all."""
+    sq, bq, sk = 128, 64, {1: 320, 2: 384, 4: 512}[key_tiles]
+    bk = sk // key_tiles
+    ks = jax.random.split(jax.random.PRNGKey(77 + d + key_tiles), 4)
+    q, w = (jax.random.normal(x, (1, sq, 2, d), jnp.float32)
+            for x in ks[:2])
+    k, v = (jax.random.normal(x, (1, sk, 2, d), jnp.float32)
+            for x in ks[2:])
+    _, grads = _grads_and_value(
+        lambda q, k, v: attn.flash_attention(q, k, v, block_q=bq,
+                                             block_k=bk), q, k, v, w)
+    _, ref_grads = _grads_and_value(
+        _masked_reference(sq, sk, d, True, None), q, k, v, w)
+    for g, r in zip(grads, ref_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=5e-4, rtol=5e-4)
+
+
+@pytest.mark.parametrize("delta,what", _DELTAS)
+@pytest.mark.parametrize("key_tiles", [1, 4])
+def test_chunk_dq_at_every_position_with_nonzero_dlse(key_tiles, delta, what):
+    """flash_attention_chunk's dq (traced offsets, a loss that reads lse)
+    where the chunk's keys are one tile and where they are four: a tile
+    wholly in the future adds nothing, one wholly in the past its whole
+    block, and dq is their sum."""
+    from ray_tpu.ops import ring_attention as ring
+
+    b, sq, sk, h, d = 1, 256, 512, 1, 64
+    bq, bk = 128, sk // key_tiles
+    ks = jax.random.split(jax.random.PRNGKey(2000 + delta), 5)
+    q, w = (jax.random.normal(x, (b, sq, h, d), jnp.float32) for x in ks[:2])
+    k, v = (jax.random.normal(x, (b, sk, h, d), jnp.float32) for x in ks[2:4])
+    u = jax.random.normal(ks[4], (b, h, sq), jnp.float32)
+    q_off, kv_off = 1000 + delta, 1000
+    mask = ((q_off + jnp.arange(sq))[:, None]
+            >= (kv_off + jnp.arange(sk))[None, :])[None, None]
+
+    def flash(q, k, v, q_off, kv_off):
+        out, lse = attn.flash_attention_chunk(
+            q, k, v, q_off, kv_off, causal=True, block_q=bq, block_k=bk)
+        return out, lse.reshape(b, h, sq)
+
+    def loss(fn):
+        def f(q, k, v, *offs):
+            out, lse = fn(q, k, v, *offs)
+            return (jnp.sum(out * w)
+                    + jnp.sum(jnp.where(lse > -1e29, lse, 0.0) * u))
+        return f
+
+    g = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(
+        q, k, v, jnp.int32(q_off), jnp.int32(kv_off))
+    g_ref = jax.grad(loss(lambda q, k, v: ring._chunk_attention(
+        q, k, v, mask, d ** -0.5)), argnums=(0, 1, 2))(q, k, v)
+    for a, r in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   atol=5e-4, rtol=5e-4)
+    if delta == -300:
+        assert not any(np.asarray(a).any() for a in g)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_dq_over_four_key_tiles_is_one_float32_sum_rounded_once(d):
+    """With float32 inputs dq summed over 4 key tiles equals dq from 1 key
+    tile to 2e-5 relative: the sum over the key-tile axis is kept in
+    float32.  A running sum rounded to bfloat16 after each tile (a relative
+    1 / 256 each time) fails this by two orders."""
+    sq = sk = 512
+    ks = jax.random.split(jax.random.PRNGKey(5 + d), 4)
+    q, k, v, w = (jax.random.normal(x, (1, sq, 1, d), jnp.float32)
+                  for x in ks)
+
+    def dq(block_k):
+        return np.asarray(_grads_and_value(
+            lambda q, k, v: attn.flash_attention(q, k, v, block_q=128,
+                                                 block_k=block_k),
+            q, k, v, w)[1][0])
+
+    one, four = dq(512), dq(128)
+    scale = np.abs(one).max()
+    assert np.abs(four - one).max() <= 2e-5 * scale
+    # the control: what rounding the running sum to bfloat16 would do
+    rounded = np.asarray(jnp.asarray(one).astype(jnp.bfloat16)
+                         .astype(jnp.float32))
+    assert np.abs(rounded - one).max() > 1e-3 * scale
+
+
+def _pallas_calls(jaxpr):
+    """Every pallas_call equation of a jaxpr, nested jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+            continue
+        for val in eqn.params.values():
+            inner = getattr(val, "jaxpr", val)      # a ClosedJaxpr's own
+            if hasattr(inner, "eqns"):
+                found += _pallas_calls(inner)
+    return found
+
+
+@pytest.mark.parametrize("key_tiles", [1, 4])
+def test_one_backward_kernel_gives_dq_dk_dv_in_the_operands_dtype(key_tiles):
+    """bfloat16 operands: forward and ONE backward pallas_call, whose three
+    results leave it in the operands' dtype whether dq was summed over one
+    key tile or four (the float32 sum is the kernel's scratch; nothing is
+    left for XLA to round)."""
+    sq = sk = 512
+    x = jax.ShapeDtypeStruct((1, sq, 2, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return attn.flash_attention(
+            q, k, v, block_q=128,
+            block_k=sk // key_tiles).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
+    calls = _pallas_calls(jaxpr.jaxpr)
+    backward = [c for c in calls if len(c.outvars) == 3]
+    assert len(calls) == 2 and len(backward) == 1, calls
+    for out, seq in zip(backward[0].outvars, (sq, sk, sk)):
+        assert out.aval.dtype == jnp.bfloat16
+        assert out.aval.shape == (2, seq, 64)

@@ -123,7 +123,32 @@ def test_flash_backward_compiles_for_v5e(one_chip):
         return _flash(q, k, v).astype(jnp.float32).sum()
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
-    assert text.count("tpu_custom_call") >= 2  # dq and dk/dv kernels
+    assert text.count("tpu_custom_call") == 2  # forward, backward
+
+
+@pytest.mark.parametrize("d,dtype,blocks,causal", [
+    (64, jnp.bfloat16, (128, 128), True),     # ring attention's default
+    (64, jnp.float32, (256, 512), True),      # float32 operands, one
+    (128, jnp.float32, (256, 512), True),     # narrow step on the diagonal
+    (64, jnp.bfloat16, (256, 512), False)])
+def test_chunk_backward_compiles_with_traced_offsets(one_chip, d, dtype,
+                                                     blocks, causal):
+    """flash_attention_chunk's backward as ring attention calls it: traced
+    offsets, a cotangent on lse, dq summed over several key tiles.  With
+    float32 operands k's transpose reaches the dq matmul with no convert
+    between (the compiler refused that before it went through scratch)."""
+    x = jax.ShapeDtypeStruct((1, 2048, 8, d), dtype, sharding=one_chip)
+    off = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, q_off, kv_off):
+        out, lse = attention.flash_attention_chunk(
+            q, k, v, q_off, kv_off, causal=causal, block_q=blocks[0],
+            block_k=blocks[1])
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), x, x, x, off,
+                          off)
+    assert text.count("tpu_custom_call") == 2  # forward, backward
 
 
 # The benchmark's cells (SmolLM2-1.7B: 32 heads of 64, sequence 2048):
@@ -199,10 +224,52 @@ def test_cell_flash_backward_compiles_for_v5e(topo, monkeypatch, cell):
 
     calls = _cell_calls(topo, monkeypatch, cell,
                         jax.grad(loss, argnums=(0, 1, 2)))
-    assert len(calls) == 3, calls      # forward, dq, dk/dv
-    # the backward kernels must NOT look like the forward to the reader
+    assert len(calls) == 2, calls      # forward, backward
+    # the backward kernel must NOT look like the forward to the reader
     pattern = _roofline_kernel_pattern()
     assert sum(bool(re.search(pattern, l)) for l in calls) == 1
+
+
+@pytest.mark.parametrize("cell,window,grad", [
+    ("train-d12", None, "bf16[160,2048,64]"),
+    ("train-fsdp4", None, "bf16[320,2048,64]"),
+    ("train-hybrid-d8", None, "bf16[40,8192,128]"),
+    ("train-hybrid-d8", 512, "bf16[40,8192,128]")])
+def test_cell_fused_backward_is_one_call_no_forward_reader_matches(
+        topo, monkeypatch, cell, window, grad):
+    """The backward at the three cells' shapes (a chip's share under
+    fsdp=4; the hybrid's call causal and windowed, where dq is summed over
+    4 and 16 key tiles) is ONE custom call with three results, dq, dk and
+    dv, each in the operands' dtype.  Neither forward reader's pattern (a two-result (bf16, f32) tuple
+    behind s32[2] or s32[3]) finds it, so flash_fwd_roofline.* and
+    swa_fwd_roofline.hybrid keep reading the forward alone."""
+    import re
+
+    def attend(q, k, v):
+        if cell != "train-hybrid-d8":
+            return attention.flash_attention(q, k, v)
+        return attention.flash_attention(q, k, v, sm_scale=0.125,
+                                         window=window)
+
+    def loss(q, k, v):
+        return attend(q, k, v).astype(jnp.float32).sum()
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))
+    if cell == "train-hybrid-d8":
+        _on_tpu(monkeypatch, attention)
+        x = jax.ShapeDtypeStruct(
+            (HYBRID_ROWS, HYBRID_SEQ, 40, 128), jnp.bfloat16,
+            sharding=SingleDeviceSharding(topo.devices[0]))
+        calls = _custom_calls_as_traced(grads, x, x, x)
+    else:
+        calls = _cell_calls(topo, monkeypatch, cell, grads)
+    forward_faces = (_roofline_kernel_pattern(),
+                     _reader("swa_fwd_roofline.hybrid").KERNEL)
+    backward = [l for l in calls
+                if not any(re.search(f, l) for f in forward_faces)]
+    assert len(calls) == 2 and len(backward) == 1, calls
+    offs = "s32[2]" if window is None else "s32[3]"
+    assert f"= ({grad}, {grad}, {grad}) custom-call({offs} " in backward[0]
 
 
 def test_traced_call_records_path_and_plan(one_chip, monkeypatch):
@@ -216,11 +283,12 @@ def test_traced_call_records_path_and_plan(one_chip, monkeypatch):
     assert taken["flash_attention"] == {"pallas": 1}
     (plan, times), = taken["flash_attention.plan"].items()
     sizes = ",".join(f"{name}{bq}x{bk}" for name, (bq, bk) in zip(
-        ("fwd", "dq", "dkv"),
+        ("fwd", "bwd"),
         attention.default_blocks(64, 2048, 2048, jnp.bfloat16)))
-    assert plan.startswith(sizes + ",scale_folded,dead") and times == 1
+    assert plan.startswith(sizes + ",dq_in_pass,scale_folded,dead") \
+        and times == 1
     shares = plan.rsplit("dead", 1)[1].rstrip("%").split("/")
-    assert len(shares) == 3 and all(0 < int(x) <= 20 for x in shares)
+    assert len(shares) == 2 and all(0 < int(x) <= 20 for x in shares)
     # head size 128: the scale stays on the scores
     y = jax.ShapeDtypeStruct((8, 2048, 16, 128), jnp.bfloat16,
                              sharding=one_chip)
@@ -329,7 +397,7 @@ def test_cell_windowed_flash_compiles_and_is_told_from_the_full_call(
         assert "(bf16[40,8192,128], f32[40,8,8192])" in calls[0]
         calls = _custom_calls_as_traced(jax.grad(loss, argnums=(0, 1, 2)),
                                         x, x, x)
-        assert len(calls) == 3          # forward, dq, dk/dv
+        assert len(calls) == 2          # forward, backward
         assert sum(bool(re.search(mine, l)) for l in calls) == 1
         assert not any(re.search(other, l) for l in calls)
     plans = list(attention.dispatch.taken()["flash_attention.plan"])
@@ -379,6 +447,6 @@ def test_cell_hybrid_step_program_fits_a_v5e(topo, monkeypatch):
     text = compiled.as_text()
     # The two (mamba, window) pairs are ONE scanned body: a scan layer is
     # forward, forward again under remat, backward (3 calls), an attention
-    # layer forward, forward again, dq, dk/dv (4).  So the pair's body 7,
-    # the lone mamba 3, the full layer 4, the cross layer 4.
-    assert text.count("tpu_custom_call") == 7 + 3 + 4 + 4
+    # layer the same (3).  So the pair's body 6, the lone mamba 3, the full
+    # layer 3, the cross layer 3.
+    assert text.count("tpu_custom_call") == 6 + 3 + 3 + 3
