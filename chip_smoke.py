@@ -7,7 +7,7 @@
 Serve: ray_tpu.init() -> serve.run(LLMServer, num_tpus=1) at the full
 width of the 1.1 B GQA model -> requests through the HTTP proxy (client
 -> proxy -> router -> handle -> replica -> engine).  Train: JaxTrainer
-takes 3 ShardedTrainStep steps on the 0.9 B rung of bench.py's ladder.
+takes 3 ShardedTrainStep steps of a 0.9 B dense model (TRAIN_MODEL).
 The phases run one after the other, each torn down before the next: a
 chip belongs to one process at a time.  This driver never initialises a
 JAX backend; the device is reported by the worker that holds it.
@@ -37,7 +37,7 @@ SERVE_ENGINE = dict(page_size=128, num_pages=320, max_batch=128,
 TRAIN_MODEL = dict(vocab_size=32000, hidden_size=1792, intermediate_size=7168,
                    num_layers=16, num_heads=14, num_kv_heads=14,
                    max_seq_len=2048, remat_policy="full", fused_ce=True)
-# Batch 6 is the ladder's "0.9B-b6" row: at batch 8 the step program
+# Batch 6: at batch 8 the step program
 # needs 15.95 GiB (AOT memory_analysis for v5e) against 15.75 GiB of HBM.
 # The four-chip comparison needs a batch that fsdp=4 divides.
 TRAIN_BATCH = {1: 6, 4: 4}

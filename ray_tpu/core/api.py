@@ -37,11 +37,9 @@ def init(num_cpus: Optional[float] = None,
     by workers this process spawns (core/logging_config.py).  In connect
     mode (address=...) remote workers are spawned by the cluster's own
     daemons and keep the config the cluster was started with."""
-    from ray_tpu.core import knobs as _knobs
     from ray_tpu.util import tracing as _tracing
 
     t_called = time.time()
-    _knobs.apply_interpreter_tuning()
     rt = _runtime_mod._global_runtime
     if rt is not None and getattr(rt, "is_initialized", False):
         if ignore_reinit_error:
@@ -83,7 +81,19 @@ def init(num_cpus: Optional[float] = None,
             _system_config=_system_config)
 
 
-_ADDRESS_FILE = "/tmp/ray_tpu/cluster_address"
+def _state_dir() -> str:
+    """Where `ray-tpu start --head` leaves the cluster's address: beside
+    the session directories, under the temp dir this process was given."""
+    import os
+    import tempfile
+
+    return os.path.join(tempfile.gettempdir(), "ray_tpu")
+
+
+def _address_file() -> str:
+    import os
+
+    return os.path.join(_state_dir(), "cluster_address")
 
 
 def _resolve_cluster_address() -> str:
@@ -93,12 +103,12 @@ def _resolve_cluster_address() -> str:
     if env and env != "auto":
         return env
     try:
-        with open(_ADDRESS_FILE) as f:
+        with open(_address_file()) as f:
             return f.read().strip()
     except FileNotFoundError:
         raise RayTpuError(
             "address='auto' but no running cluster found (no "
-            f"RAY_TPU_ADDRESS env var and no {_ADDRESS_FILE}); start one "
+            f"RAY_TPU_ADDRESS env var and no {_address_file()}); start one "
             "with `ray-tpu start --head`") from None
 
 

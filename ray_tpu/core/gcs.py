@@ -586,18 +586,8 @@ def _profile_history_summary(samples: List[dict]) -> Dict[str, Any]:
 # ClusterResourceManager view).
 
 
-def _gcs_shards() -> int:
-    """RAY_TPU_GCS_SHARDS: owner-keyed submit-ingress shards (0 =
-    legacy single-lock ingress, used by the paired benchmarks)."""
-    try:
-        return max(0, int(os.environ.get("RAY_TPU_GCS_SHARDS", "8")))
-    except ValueError:
-        return 8
-
-
-def _node_index_enabled() -> bool:
-    return os.environ.get("RAY_TPU_NODE_INDEX", "1").strip().lower() \
-        not in ("0", "false", "no")
+# Owner-keyed submit-ingress shards, and shards of the task table.
+_GCS_SHARDS = 8
 
 
 class ShardedTaskTable:
@@ -615,7 +605,7 @@ class ShardedTaskTable:
 
     __slots__ = ("_shards", "_locks", "_n")
 
-    def __init__(self, n: int = 8):
+    def __init__(self, n: int = _GCS_SHARDS):
         self._n = max(1, n)
         self._shards: List[Dict[str, Any]] = [
             {} for _ in range(self._n)]
@@ -906,12 +896,10 @@ class ControlServer:
         # specs to a per-owner-shard deque WITHOUT the global lock; the
         # scheduler (and any reader that could observe an undrained
         # spec) drains them under the lock.  deque append/popleft are
-        # GIL-atomic, so the ingress itself is lock-free.  None = legacy
-        # single-lock ingress (RAY_TPU_GCS_SHARDS=0).
-        n_shards = _gcs_shards()
-        self._ingress: Optional[List[deque]] = (
-            [deque() for _ in range(n_shards)] if n_shards else None)
-        self._node_index = None  # built after journal restore
+        # GIL-atomic, so the ingress itself is lock-free.
+        self._ingress: List[deque] = [
+            deque() for _ in range(_GCS_SHARDS)]
+        self._node_index = _NodeIndex(self)  # filled after journal restore
         self._lease_timer = None  # timer-wheel handle for lease expiry
         try:
             self._idle_wait_s = float(os.environ.get(
@@ -935,7 +923,7 @@ class ControlServer:
         # DirectActorTaskSubmitter::DisconnectActor).
         self.actor_inflight: Dict[str, Set[str]] = {}
         self.obj_actor: Dict[str, str] = {}
-        self.tasks = ShardedTaskTable(max(1, n_shards or 8))
+        self.tasks = ShardedTaskTable()
         # Lineage: object hex -> producing task hex, kept even after the
         # object entry itself is freed so a lost dependency can be
         # re-created (reference lineage map, task_manager.h:208).
@@ -1018,11 +1006,7 @@ class ControlServer:
             if node is not None:
                 node.draining = True
 
-        # O(1)-amortized node selection (RAY_TPU_NODE_INDEX=0 restores
-        # the legacy full-scan policies, byte-for-byte).
-        if _node_index_enabled():
-            self._node_index = _NodeIndex(self)
-            self._node_index.rebuild()
+        self._node_index.rebuild()
 
         # Scheduler observability (util/metrics.py): lease decisions and
         # task-event ingest volume export through the same /metrics
@@ -1346,7 +1330,7 @@ class ControlServer:
             node.alive = False
             node.available = ResourceSet()
             node.conn = None
-            self._index_touch(node_id)
+            self._node_index.touch(node_id)
             self._drop_drain_state_locked(node_id)
             for w in list(self.workers.values()):
                 if w.node_id == node_id and w.state != "dead":
@@ -1561,7 +1545,7 @@ class ControlServer:
                 address=msg.get("address", ""), conn=conn,
                 store_key=msg.get("store_key", ""),
                 shm_dir=msg.get("shm_dir", ""))
-            self._index_touch(node_id)
+            self._node_index.touch(node_id)
             conn.meta["node_id"] = node_id
         # Force a view broadcast so the (re)joining manager gets the
         # current resource view even when nothing else changed.
@@ -2336,8 +2320,7 @@ class ControlServer:
     def _ingress_pending(self) -> bool:
         """Any submitted-but-undrained specs in the ingress shards?
         deque truthiness is GIL-atomic, so this is safe lock-free."""
-        ing = self._ingress
-        return ing is not None and any(ing)
+        return any(self._ingress)
 
     def _ingress_shard_of(self, spec) -> int:
         # Owner id keys the shard so one owner's submissions stay FIFO
@@ -2351,11 +2334,8 @@ class ControlServer:
         queue/table.  Amortized O(1) per task (each spec is drained
         exactly once); the empty check is a handful of GIL-atomic deque
         reads."""
-        ing = self._ingress
-        if ing is None:
-            return
         drained = 0
-        for shard in ing:
+        for shard in self._ingress:
             while True:
                 try:
                     spec, ts = shard.popleft()
@@ -2386,28 +2366,24 @@ class ControlServer:
 
     def _op_submit_task(self, conn, msg):
         spec = msg["spec"]
-        if self._ingress is not None:
-            self._ingress[self._ingress_shard_of(spec)].append(
-                (spec, time.time()))
-            if self._m_shard_ops is not None:
-                try:
-                    self._m_shard_ops.inc()
-                except Exception:  # raylint: allow-swallow(telemetry only)
-                    pass
-        else:
-            with self.lock:
-                self._enqueue_task_locked(spec, time.time())
+        self._ingress[self._ingress_shard_of(spec)].append(
+            (spec, time.time()))
+        if self._m_shard_ops is not None:
+            try:
+                self._m_shard_ops.inc()
+            except Exception:  # raylint: allow-swallow(telemetry only)
+                pass
         self._wake.set()
 
     def _op_submit_task_batch(self, conn, msg):
         """Coalesced submission (runtime.py _queue_for_flush): one frame
-        for a whole burst of tasks.  With ingress shards enabled the
-        burst is staged lock-free on the owner's shard and drained by
-        the scheduler; submission no longer contends with dispatch or
-        completion on the global lock."""
+        for a whole burst of tasks.  The burst is staged lock-free on
+        the owner's shard and drained by the scheduler, so submission
+        does not contend with dispatch or completion on the global
+        lock."""
         now = time.time()
         specs = msg["specs"]
-        if self._ingress is not None and specs:
+        if specs:
             shard = self._ingress[self._ingress_shard_of(specs[0])]
             for spec in specs:
                 shard.append((spec, now))
@@ -2416,10 +2392,6 @@ class ControlServer:
                     self._m_shard_ops.inc(len(specs))
                 except Exception:  # raylint: allow-swallow(telemetry only)
                     pass
-        else:
-            with self.lock:
-                for spec in specs:
-                    self._enqueue_task_locked(spec, now)
         self._wake.set()
 
     # -- C++-defined tasks/actors ---------------------------------------
@@ -2582,7 +2554,9 @@ class ControlServer:
         import json as _json
 
         with self.lock:
-            entry = self.objects.get(msg["obj"])
+            # A named task's return entry is registered when its spec
+            # drains from the submit ingress, which a poll can outrun.
+            entry = self._object_entry_or_drain_locked(msg["obj"])
             if entry is None:
                 return {"status": "error", "error": "object not found"}
             if entry.state == PENDING:
@@ -2711,7 +2685,7 @@ class ControlServer:
                 continue
             del pending[i]
             node.available = node.available.subtract(need)
-            self._index_touch(w.node_id)
+            self._node_index.touch(w.node_id)
             w.acquired = need
             w.charge = ("node", w.node_id)
             w.state = "busy"
@@ -3404,7 +3378,7 @@ class ControlServer:
             self.nodes[node_id] = NodeState(
                 node_id=node_id, total=res, available=res,
                 labels=msg.get("labels") or {})
-            self._index_touch(node_id)
+            self._node_index.touch(node_id)
             self._journal_put(f"node/{node_id}", {
                 "resources": res.to_dict(),
                 "labels": msg.get("labels") or {}})
@@ -3427,7 +3401,7 @@ class ControlServer:
                 return {"accepted": False, "reason": "cannot drain head"}
             node.draining = True
             node.drain_reason = msg.get("reason", "")
-            self._index_touch(node_id)
+            self._node_index.touch(node_id)
             self._drain_migrating.setdefault(node_id, set())
             # Journaled: a restarted head must keep draining (the
             # autoscalers are waiting on drain_status == "gone"; losing
@@ -3502,7 +3476,7 @@ class ControlServer:
             node = self.nodes.get(b.node_id)
             if node is not None and node.alive:
                 node.available = node.available.add(b.available)
-                self._index_touch(b.node_id)
+                self._node_index.touch(b.node_id)
         pg.bundles = []
         pg.state = "PENDING"
 
@@ -3621,7 +3595,7 @@ class ControlServer:
                 return False
             node.alive = False
             node.available = ResourceSet()
-            self._index_touch(node_id)
+            self._node_index.touch(node_id)
             self._drop_drain_state_locked(node_id)
             self._journal_del(f"node/{node_id}")
             for w in list(self.workers.values()):
@@ -3773,45 +3747,30 @@ class ControlServer:
         elif strategy in ("SPREAD", "STRICT_SPREAD"):
             used_nodes: Set[str] = set()
             placement = []
-            if idx is not None:
-                for bi, need in enumerate(needs):
-                    def fresh_ok(nid, _n=need):
-                        return (nid not in used_nodes
-                                and _n.is_subset_of(avail(nid)))
+            for bi, need in enumerate(needs):
+                def fresh_ok(nid, _n=need):
+                    return (nid not in used_nodes
+                            and _n.is_subset_of(avail(nid)))
 
-                    pick = None
+                pick = None
+                for bucket in idx.buckets_low_to_high():
+                    pick = idx.probe(bucket, bi, fresh_ok)
+                    if pick is not None:
+                        break
+                if pick is None and strategy == "SPREAD":
+                    # SPREAD tolerates reuse once fresh nodes run out
                     for bucket in idx.buckets_low_to_high():
-                        pick = idx.probe(bucket, bi, fresh_ok)
+                        pick = idx.probe(
+                            bucket, bi,
+                            lambda nid, _n=need:
+                            _n.is_subset_of(avail(nid)))
                         if pick is not None:
                             break
-                    if pick is None and strategy == "SPREAD":
-                        # SPREAD tolerates reuse once fresh nodes run out
-                        for bucket in idx.buckets_low_to_high():
-                            pick = idx.probe(
-                                bucket, bi,
-                                lambda nid, _n=need:
-                                _n.is_subset_of(avail(nid)))
-                            if pick is not None:
-                                break
-                    if pick is None:
-                        return False
-                    placement.append(pick)
-                    used_nodes.add(pick)
-                    virt[pick] = avail(pick).subtract(need)
-            else:
-                alive = [n for n in self.nodes.values() if n.schedulable]
-                for need in needs:
-                    cands = [n for n in alive if fits(n.node_id, need)]
-                    fresh = [n for n in cands
-                             if n.node_id not in used_nodes]
-                    pool = fresh if fresh else (
-                        [] if strategy == "STRICT_SPREAD" else cands)
-                    if not pool:
-                        return False
-                    node = min(pool, key=self._utilization)
-                    placement.append(node.node_id)
-                    used_nodes.add(node.node_id)
-                    virt[node.node_id] = avail(node.node_id).subtract(need)
+                if pick is None:
+                    return False
+                placement.append(pick)
+                used_nodes.add(pick)
+                virt[pick] = avail(pick).subtract(need)
         else:
             raise ValueError(f"unknown PG strategy {strategy}")
 
@@ -3820,7 +3779,7 @@ class ControlServer:
         for i, (need, node_id) in enumerate(zip(needs, placement)):
             node = self.nodes[node_id]
             node.available = node.available.subtract(need)
-            self._index_touch(node_id)
+            self._node_index.touch(node_id)
             pg.bundles.append(Bundle(index=i, node_id=node_id,
                                      reserved=need, available=need))
         pg.state = "CREATED"
@@ -3838,7 +3797,7 @@ class ControlServer:
             node = self.nodes.get(b.node_id)
             if node is not None and node.alive:
                 node.available = node.available.add(b.available)
-                self._index_touch(b.node_id)
+                self._node_index.touch(b.node_id)
         pg.state = "REMOVED"
         pg.bundles = []
         self._journal_del(f"pg/{pg.pg_hex}")
@@ -4156,7 +4115,7 @@ class ControlServer:
         node = self.nodes.get(w.node_id)
         if node is not None and node.alive:
             node.available = node.available.add(acquired)
-            self._index_touch(node.node_id)
+            self._node_index.touch(node.node_id)
 
     def _utilization(self, node: NodeState,
                      avail: Optional[ResourceSet] = None) -> float:
@@ -4208,11 +4167,6 @@ class ControlServer:
                 out[nid] = out.get(nid, 0) + entry.size
         return out
 
-    @staticmethod
-    def _locality_enabled() -> bool:
-        return os.environ.get("RAY_TPU_NO_LOCALITY", "").strip().lower() \
-            not in ("1", "true", "yes")
-
     def _pick_node(self, need: ResourceSet, spec,
                    avail_of=None) -> Optional[tuple]:
         """Lock held. Choose a node (or PG bundle) for this task/actor.
@@ -4257,14 +4211,9 @@ class ControlServer:
             if not st.soft:
                 return None
             # soft: fall through to default policy
-        idx = getattr(self, "_node_index", None)
-        alive = [n for n in self.nodes.values() if n.schedulable] \
-            if (idx is None
-                or (st is not None
-                    and type(st).__name__
-                    == "NodeLabelSchedulingStrategy")) else []
         if st is not None and \
                 type(st).__name__ == "NodeLabelSchedulingStrategy":
+            alive = [n for n in self.nodes.values() if n.schedulable]
             hard = st.hard or {}
             soft = st.soft or {}
 
@@ -4285,66 +4234,20 @@ class ControlServer:
             node = min(feasible, key=lambda n: (
                 self._utilization(n, node_avail(n)), n.node_id))
             return node.node_id, ("node", node.node_id)
-        if idx is not None:
-            # Utilization-bucketed candidate walk: O(1) amortized per
-            # pick instead of an O(nodes) feasibility prefilter + sort.
-            return self._pick_node_indexed(need, spec, st, node_avail)
-        feasible = [n for n in alive if need.is_subset_of(node_avail(n))]
-        if not feasible:
-            return None
-
-        def util(n):
-            return self._utilization(n, node_avail(n))
-
-        if st == "SPREAD":
-            # least-utilized first; rotate among the tied minimum so
-            # zero-resource tasks still fan out across nodes.  The tie-break
-            # hashes the task id (not a global counter) so a task's target is
-            # stable across scheduling passes while it waits for a worker.
-            feasible.sort(key=lambda n: (util(n), n.node_id))
-            lowest = util(feasible[0])
-            ties = [n for n in feasible if util(n) == lowest]
-            tid = getattr(spec, "task_id", None) or spec.actor_id
-            # hash() (not a raw prefix slice): ids are counter-derived,
-            # so any fixed byte slice can alias mod len(ties).
-            node = ties[hash(tid.binary()) % len(ties)]
-            return node.node_id, ("node", node.node_id)
-        # hybrid default: pack onto the busiest node below the spread
-        # threshold; above it, spread to the least utilized.  Utilization
-        # ties break by bytes of this task's shm args already resident on
-        # the candidate (locality-aware placement, reference
-        # lease_policy.cc LocalityAwareLeasePolicy) — feasibility always
-        # dominates, so locality never overrides resources.  Env
-        # RAY_TPU_NO_LOCALITY=1 restores the legacy tie-break exactly
-        # (with no locality data both keys collapse to the old ones).
-        threshold = 0.5
-        loc = (self._locality_bytes(spec) if self._locality_enabled()
-               else {})
-        below = [n for n in feasible if util(n) < threshold]
-        if below:
-            node = max(below, key=lambda n: (util(n),
-                                             loc.get(n.node_id, 0),
-                                             n.is_head))
-        else:
-            node = min(feasible, key=lambda n: (util(n),
-                                                -loc.get(n.node_id, 0),
-                                                not n.is_head))
-        if loc.get(node.node_id, 0) > 0:
-            if self._m_locality_hits is not None:
-                self._m_locality_hits.inc()
-        return node.node_id, ("node", node.node_id)
+        return self._pick_node_indexed(need, spec, st, node_avail)
 
     def _pick_node_indexed(self, need: ResourceSet, spec, st,
                            node_avail) -> Optional[tuple]:
         """Lock held.  `_pick_node`'s SPREAD/hybrid tail over the
-        utilization-bucketed index.  Bucket membership is computed from
-        ACTUAL availability; feasibility is re-verified against the
-        caller's (possibly virtual) view on every candidate, so a stale
-        bucket can only cost placement optimality within one 1/8
-        utilization slice, never correctness.  The PR-3 locality
-        tie-break becomes an index consult: the nodes already holding
-        this task's shm args are checked directly (O(arg locations))
-        before the bucket walk."""
+        utilization-bucketed index: O(1) amortized per pick.  Bucket
+        membership is computed from ACTUAL availability; feasibility is
+        re-verified against the caller's (possibly virtual) view on
+        every candidate, so a stale bucket can only cost placement
+        optimality within one 1/8 utilization slice, never correctness.
+        Locality (reference lease_policy.cc LocalityAwareLeasePolicy)
+        is an index consult: the nodes already holding this task's shm
+        args are checked directly (O(arg locations)) before the bucket
+        walk; feasibility always dominates."""
         idx = self._node_index
         nodes = self.nodes
 
@@ -4384,8 +4287,7 @@ class ControlServer:
         # hybrid pack-then-spread (threshold 0.5), locality consult
         # first: a fitting below-threshold node already holding the
         # most arg bytes wins outright.
-        loc = (self._locality_bytes(spec) if self._locality_enabled()
-               else {})
+        loc = self._locality_bytes(spec)
         if loc:
             best, best_bytes = None, 0
             for nid, nbytes in sorted(loc.items(),
@@ -4447,14 +4349,6 @@ class ControlServer:
                 del self.broken_envs[key]  # expired: allow a fresh try
         return None
 
-    def _index_touch(self, node_id: str):
-        if self._node_index is not None:
-            self._node_index.touch(node_id)
-
-    def _index_rebuild(self):
-        if self._node_index is not None:
-            self._node_index.rebuild()
-
     def _charge_avail(self, charge: tuple) -> ResourceSet:
         """Lock held. Resolve a charge tuple to its current availability."""
         if charge[0] == "pg":
@@ -4473,7 +4367,7 @@ class ControlServer:
         else:
             node = self.nodes[charge[1]]
             node.available = node.available.subtract(need)
-            self._index_touch(charge[1])
+            self._node_index.touch(charge[1])
 
     def _schedule_once(self):
         self._reap_unregistered_workers()

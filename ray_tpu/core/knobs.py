@@ -77,8 +77,6 @@ KNOBS: List[Knob] = [
          "1 skips GCE metadata-server queries during TPU detection."),
     Knob("RAY_TPU_PALLAS_INTERPRET", "", "flag", "user",
          "Run Pallas kernels in interpret mode (CPU-only testing)."),
-    Knob("RAY_TPU_PREFILL_DENSE", "", "flag", "user",
-         "1 forces the dense prefill path in models/decoding."),
     Knob("RAY_TPU_PA_SB", "", "int", "bench",
          "Paged-attention sub-batch override (perf experiments only)."),
     Knob("RAY_TPU_NATIVE_SANITIZE", "", "str", "user",
@@ -87,8 +85,6 @@ KNOBS: List[Knob] = [
          "0 disables the C++ shared-memory object-store fast path."),
 
     # -- rpc / wire ------------------------------------------------------
-    Knob("RAY_TPU_RPC_NO_BATCH", "", "flag", "user",
-         "1 disables control-plane frame coalescing (legacy protocol)."),
     Knob("RAY_TPU_RPC_BATCH_MAX_MSGS", "512", "int", "user",
          "Max sub-messages per coalesced control-plane batch frame."),
     Knob("RAY_TPU_RPC_BATCH_MAX_BYTES", "4194304", "int", "user",
@@ -143,27 +139,9 @@ KNOBS: List[Knob] = [
          "percentiles (serve_slo / /api/serve_slo)."),
 
     # -- scheduling / placement -----------------------------------------
-    Knob("RAY_TPU_NO_LOCALITY", "", "flag", "user",
-         "Truthy disables locality-aware task placement on the head."),
-    Knob("RAY_TPU_GCS_SHARDS", "8", "int", "user",
-         "Owner-keyed submit-ingress shards on the head (0 = legacy "
-         "single-lock ingress)."),
-    Knob("RAY_TPU_NODE_INDEX", "1", "bool", "user",
-         "0 disables the utilization-bucketed node index and falls back "
-         "to full node-table scans in _pick_node/placement."),
     Knob("RAY_TPU_SCHED_IDLE_WAIT_S", "30.0", "float", "user",
          "Scheduler wakeup ceiling when no time-based work is pending "
          "(timer-wheel deadlines cover lease expiry below this)."),
-    Knob("RAY_TPU_ZEROCOPY_MIN_BYTES", "524288", "int", "user",
-         "Payloads at/above this ride the scatter-gather wire path "
-         "(no header+payload concat copy); 0 disables."),
-    Knob("RAY_TPU_NM_PULL", "1", "bool", "user",
-         "0 disables node-manager-level single-flight object pulls; "
-         "workers pull remote objects directly."),
-    Knob("RAY_TPU_GIL_SWITCH_S", "0", "float", "user",
-         "sys.setswitchinterval applied at process start (0 = keep the "
-         "interpreter default, 5ms); opt-in tuning for hosts running "
-         "many ray_tpu processes per core."),
     Knob("RAY_TPU_DISABLE_ZYGOTE", "0", "bool", "user",
          "1 disables the zygote prefork path; workers spawn directly."),
     Knob("RAY_TPU_WHEEL_DIR", "", "str", "user",
@@ -258,18 +236,9 @@ KNOBS: List[Knob] = [
          "1 copies deserialized buffers out of shm instead of zero-copy "
          "views."),
 
-    # -- benchmarks (scripts/) -------------------------------------------
+    # -- ray_tpu/scripts/microbenchmark.py -------------------------------
     Knob("RAY_TPU_BENCH_SCALE", "1.0", "float", "bench",
          "Scales microbenchmark workload sizes."),
-    Knob("RAY_TPU_BENCH_HARVEST", "1", "bool", "bench",
-         "0 disables span harvest during bench_profiling runs."),
-    Knob("RAY_TPU_BENCH_SAMPLER", "1", "bool", "bench",
-         "0 disables the profile sampler during bench_profiling runs."),
-    Knob("RAY_TPU_BENCH_LATENCY_MS", "15", "float", "bench",
-         "Simulated cross-node link latency in bench_object_plane."),
-    Knob("RAY_TPU_BENCH_PG_NODES", "2000", "int", "bench",
-         "Simulated-cluster node count for bench_head_scale's "
-         "placement-group section."),
 
     # -- test harness (tests/conftest.py) --------------------------------
     Knob("RAY_TPU_TEST_WATCHDOG", "420", "int", "test",
@@ -352,27 +321,6 @@ _CONFIG_DOCS: Dict[str, str] = {
         "restart.",
     "log_dir": "Per-session log directory ('' = session default).",
 }
-
-
-def apply_interpreter_tuning() -> None:
-    """Per-process interpreter tuning, called from every bootstrap path
-    (driver init, worker main, node-manager main).
-
-    RAY_TPU_GIL_SWITCH_S shortens the GIL switch interval: an op on the
-    hot path crosses several processes (owner -> head -> worker ->
-    owner), and on an oversubscribed host each hop's recv-thread wakeup
-    can wait out the full default 5 ms interval before the bytecode
-    holder yields — a latency tax that bounds end-to-end throughput
-    even when every process profiles as idle."""
-    import os
-    import sys
-
-    try:
-        si = float(os.environ.get("RAY_TPU_GIL_SWITCH_S", "0") or 0)
-    except ValueError:
-        si = 0.0
-    if si > 0:
-        sys.setswitchinterval(si)
 
 
 def config_knobs() -> List[Knob]:

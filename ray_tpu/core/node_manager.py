@@ -649,9 +649,6 @@ class NodeManager:
 def main(argv=None) -> int:
     import argparse
 
-    from ray_tpu.core import knobs
-
-    knobs.apply_interpreter_tuning()
     p = argparse.ArgumentParser("ray_tpu.core.node_manager")
     p.add_argument("--address", required=True, help="head control address")
     p.add_argument("--num-cpus", type=float, default=None)

@@ -26,8 +26,7 @@ receiver unpacks and dispatches sub-messages in order; semantics are
 identical to having received each sub-frame individually.  Batches are
 never nested, and the server only emits pickle batches to peers that
 have themselves spoken pickle — JSON-only peers (the C++ client) keep
-getting plain frames.  Set RAY_TPU_RPC_NO_BATCH=1 to disable coalescing
-entirely and restore the one-frame-per-message protocol byte for byte.
+getting plain frames.
 
 Server: thread per connection, handler invoked per message; handler may
 return a value (sent back as response) or None for one-way messages.
@@ -77,28 +76,9 @@ KIND_OOB = 7
 
 _OOB_INDEX = struct.Struct("<BII")
 
-_TRUTHY = ("1", "true", "yes", "on")
-
-
-_ZC_MIN: int | None = None
-
-
-def _zerocopy_min() -> int:
-    """Payload size from which frames switch to scatter-gather writes
-    and pickle5 buffers go out-of-band.  <= 0 disables the path
-    (RAY_TPU_ZEROCOPY_MIN_BYTES; cached after first read)."""
-    global _ZC_MIN
-    v = _ZC_MIN
-    if v is None:
-        try:
-            v = int(os.environ.get(
-                "RAY_TPU_ZEROCOPY_MIN_BYTES", str(512 << 10)))
-        except ValueError:
-            v = 512 << 10
-        if v <= 0:
-            v = 1 << 62
-        _ZC_MIN = v
-    return v
+# Payload size from which frames switch to scatter-gather writes and
+# pickle5 buffers go out-of-band.
+_ZEROCOPY_MIN_BYTES = 512 << 10
 
 
 def _sendmsg_all(sock: socket.socket, parts) -> None:
@@ -163,7 +143,7 @@ def _encode_payload(msg) -> tuple[int | None, "bytes | tuple"]:
     buffer crossed the zero-copy threshold — the parts are
     (index, pickle_stream, buf0, ...) and the caller's frame kind is
     folded into the index as inner_kind at send time."""
-    zc = _zerocopy_min()
+    zc = _ZEROCOPY_MIN_BYTES
     bufs: list[memoryview] = []
 
     def _cb(pb):
@@ -211,14 +191,6 @@ def _decode_oob(payload) -> tuple[int, Any]:
         bufs.append(bytes(mv[off:off + n]))
         off += n
     return inner_kind, pickle.loads(pkl, buffers=bufs)
-
-
-def batching_enabled() -> bool:
-    """Master switch for wire-level coalescing.  Checked at Client /
-    Connection construction (not per send) so a process-wide
-    RAY_TPU_RPC_NO_BATCH=1 restores the legacy protocol exactly."""
-    return os.environ.get(
-        "RAY_TPU_RPC_NO_BATCH", "").strip().lower() not in _TRUTHY
 
 
 def _batch_caps() -> tuple[int, int]:
@@ -355,7 +327,7 @@ def _send_frame(sock: socket.socket, kind: int, req_id: int, payload):
     header = _FRAME.pack(kind, req_id, n)
     if isinstance(payload, tuple):
         _sendmsg_all(sock, (header, *payload))
-    elif n >= _zerocopy_min():
+    elif n >= _ZEROCOPY_MIN_BYTES:
         WIRE.on_zerocopy(n)
         _sendmsg_all(sock, (header, payload))
     else:
@@ -668,7 +640,7 @@ class _CoalescingSender:
                 header = _FRAME.pack(kind, req_id, plen)
                 if isinstance(payload, tuple):
                     frames.append((header, *payload))
-                elif plen >= _zerocopy_min():
+                elif plen >= _ZEROCOPY_MIN_BYTES:
                     WIRE.on_zerocopy(plen)
                     frames.append((header, payload))
                 else:
@@ -698,11 +670,10 @@ class Connection:
         # peers that speak pickle can decode KIND_BATCH, so pushes and
         # responses to JSON-only peers (the C++ client) stay plain.
         self.peer_pickle = False
-        self._sender = (_CoalescingSender(sock, self.send_lock)
-                        if batching_enabled() else None)
+        self._sender = _CoalescingSender(sock, self.send_lock)
 
     def _post(self, kind: int, req_id: int, payload: bytes):
-        if self._sender is not None and self.peer_pickle:
+        if self.peer_pickle:
             self._sender.send(kind, req_id, payload)
         else:
             with self.send_lock:
@@ -731,16 +702,14 @@ class Connection:
 
     def flush_sends(self):
         """Fence: block until buffered pushes/responses hit the socket."""
-        if self._sender is not None:
-            self._sender.flush()
+        self._sender.flush()
 
     def close(self):
         self.alive = False
-        if self._sender is not None:
-            try:
-                self._sender.flush()
-            except (RpcError, OSError):
-                pass
+        try:
+            self._sender.flush()
+        except (RpcError, OSError):
+            pass
         try:
             self.sock.shutdown(socket.SHUT_RDWR)
         except OSError:
@@ -1055,11 +1024,7 @@ class Client:
         # Wire coalescing (KIND_BATCH): requests AND one-ways share one
         # FIFO buffer so total send order is preserved — the runtime
         # relies on a call() observing every send() issued before it.
-        self._sender = (_CoalescingSender(self._sock, self._send_lock)
-                        if batching_enabled() else None)
-        # Legacy-path counters so frames_sent stays meaningful (and the
-        # burst-regression test stays expressible) under NO_BATCH.
-        self._plain_frames = 0
+        self._sender = _CoalescingSender(self._sock, self._send_lock)
         self._pending: dict[int, threading.Event] = {}
         self._results: dict[int, Any] = {}
         self._next_id = 1
@@ -1125,35 +1090,22 @@ class Client:
 
     def _post(self, kind: int, req_id: int, payload: bytes,
               wait: bool = False):
-        if self._sender is not None:
-            self._sender.send(kind, req_id, payload, wait=wait)
-        else:
-            with self._send_lock:
-                _send_frame(self._sock, kind, req_id, payload)
-                self._plain_frames += 1
+        self._sender.send(kind, req_id, payload, wait=wait)
 
     @property
     def frames_sent(self) -> int:
         """Control-plane frames written to this socket (telemetry for
-        the burst-submission regression test and the RPC bench probe)."""
-        s = self._sender
-        return self._plain_frames if s is None else s.frames_sent
+        the burst-submission regression test)."""
+        return self._sender.frames_sent
 
     @property
     def msgs_sent(self) -> int:
-        s = self._sender
-        return self._plain_frames if s is None else s.msgs_sent
-
-    @property
-    def batches_sent(self) -> int:
-        s = self._sender
-        return 0 if s is None else s.batches_sent
+        return self._sender.msgs_sent
 
     def flush_sends(self):
         """Fence: block until every previously enqueued frame is on the
-        socket.  No-op without coalescing (sends are then synchronous)."""
-        if self._sender is not None:
-            self._sender.flush()
+        socket."""
+        self._sender.flush()
 
     def call_async(self, msg: Any) -> _PendingCall:
         """Post a request and return a handle without waiting for the
@@ -1194,14 +1146,12 @@ class Client:
 
     def close(self):
         self._closed = True
-        # Drain buffered frames before tearing the socket down: the
-        # legacy (synchronous-send) protocol never lost tail messages
-        # on a clean close, and final decref/task_done traffic matters.
-        if self._sender is not None:
-            try:
-                self._sender.flush()
-            except (RpcError, OSError):
-                pass
+        # Drain buffered frames before tearing the socket down: final
+        # decref/task_done traffic must not be lost on a clean close.
+        try:
+            self._sender.flush()
+        except (RpcError, OSError):
+            pass
         try:
             self._sock.shutdown(socket.SHUT_RDWR)
         except OSError:

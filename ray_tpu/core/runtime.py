@@ -1412,14 +1412,12 @@ class CoreClient:
         never pull the same object over the wire twice — the bytes land
         once in the shared arena and both read it via attach().
         Returns the payload view on success, None to fall back to the
-        direct per-process pull (driver processes, RAY_TPU_NM_PULL=0,
-        arena-full degradation, NM errors)."""
+        direct per-process pull (driver processes, arena-full
+        degradation, NM errors)."""
         if self.store is None:
             return None
         nm_addr = os.environ.get("RAY_TPU_LOCAL_NM", "")
-        if not nm_addr or os.environ.get(
-                "RAY_TPU_NM_PULL", "1").strip().lower() in (
-                "0", "false", "no", "off"):
+        if not nm_addr:
             return None
         try:
             nm = self._node_conn(nm_addr)
@@ -2179,24 +2177,21 @@ class CoreClient:
     def _head_frames(items):
         """Yield (end_index, frame_msg) for queued head messages,
         preserving enqueue order: runs of consecutive submits collapse
-        into submit_task_batch frames, runs of increfs into
-        incref_batch frames.  When wire batching is on, adjacent
-        incref/decref runs additionally collapse into ONE refcount_delta
-        vector of net per-object counts — no other message can land
-        between entries of one run, so netting inside it is order-safe
-        (a transient +1/-1 pair can never drive a live object to zero
-        mid-run on the head)."""
-        merge_refs = rpc.batching_enabled()
+        into submit_task_batch frames, and adjacent incref/decref runs
+        into ONE refcount_delta vector of net per-object counts — no
+        other message can land between entries of one run, so netting
+        inside it is order-safe (a transient +1/-1 pair can never drive
+        a live object to zero mid-run on the head)."""
         i, n = 0, len(items)
         while i < n:
             kind = items[i][0]
             is_ref = kind in ("incref", "decref")
             j = i
             while j < n and (items[j][0] == kind or
-                             (merge_refs and is_ref and
+                             (is_ref and
                               items[j][0] in ("incref", "decref"))):
                 j += 1
-            if is_ref and merge_refs and j - i > 1:
+            if is_ref and j - i > 1:
                 deltas: Dict[str, int] = {}
                 for k, obj_hex in items[i:j]:
                     deltas[obj_hex] = deltas.get(obj_hex, 0) + (
@@ -2239,14 +2234,8 @@ class CoreClient:
             elif kind == "put":
                 msg = run[0] if len(run) == 1 else \
                     {"op": "put_object_batch", "items": run}
-            elif kind == "incref":
-                msg = {"op": "incref", "obj": run[0]} \
-                    if len(run) == 1 else \
-                    {"op": "incref_batch", "objs": run}
-            else:  # decref (ref deletions ride the same ordered queue)
-                msg = {"op": "decref", "obj": run[0]} \
-                    if len(run) == 1 else \
-                    {"op": "decref_batch", "objs": run}
+            else:  # a lone incref or decref (longer runs netted above)
+                msg = {"op": kind, "obj": run[0]}
             yield j, msg
             i = j
 

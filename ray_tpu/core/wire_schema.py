@@ -105,11 +105,10 @@ SCHEMA: Dict[str, Dict[str, str]] = {
     # (runtime._head_frames collapses a run to the newest sample).
     "profile_report": {"sample": "dict"},
     "get_profile": {"samples": "bool?"},
-    # Client→head: retune/toggle every worker's sampler at runtime
-    # (bench_profiling.py's A/B switch).
+    # Client→head: retune/toggle every worker's sampler at runtime.
     "set_profile_config": {"enabled": "bool?", "interval_s": "float?"},
     # One-way announce that a PullManager leader started pulling an
-    # object to this node (locality tie-break credit in gcs._pick_node).
+    # object to this node (locality credit in gcs._pick_node_indexed).
     "object_pull_started": {"obj": "str"},
     # -- functions -----------------------------------------------------
     "put_func": {"func_id": "str", "blob": "bytes"},

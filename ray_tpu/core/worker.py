@@ -1046,9 +1046,6 @@ def main():
     import faulthandler
 
     faulthandler.enable()  # native-crash stacks land in the worker .err log
-    from ray_tpu.core import knobs
-
-    knobs.apply_interpreter_tuning()
     from ray_tpu.core.logging_config import apply_from_env
 
     apply_from_env()  # session LoggingConfig (TEXT/JSON), if the driver set one

@@ -305,7 +305,10 @@ class Dataset:
 
     def _combined_block(self):
         mat = self if self._materialized is not None else self.materialize()
-        blocks = [b for bundle in (mat._materialized or [])
+        # Bundles arrive in the order their tasks finished; rows are cut
+        # in source order (the key zip orders by too).
+        blocks = [b for bundle in sorted(mat._materialized or [],
+                                         key=lambda bundle: bundle.seq)
                   for b in ray_tpu.get(bundle.blocks_ref)]
         return concat_blocks(blocks) if blocks else pa.table({})
 

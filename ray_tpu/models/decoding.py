@@ -54,10 +54,6 @@ def _use_flash_prefill(seq: int, head_dim: int) -> bool:
     outputs are never read (last-valid-position selection).  The same
     argument covers fully-pad bucket rows, which only attend
     themselves."""
-    import os
-
-    if os.environ.get("RAY_TPU_PREFILL_DENSE", "") == "1":
-        return False
     if not (dispatch.platform() == "tpu" or dispatch.interpret_mode()):
         return False
     # At short segments (<= 128) the dense per-segment scores are small

@@ -440,10 +440,3 @@ def num_params(config: TransformerConfig) -> int:
                  + 2 * c.hidden_size)
     return (c.vocab_size * c.hidden_size + c.num_layers * per_layer
             + c.hidden_size)
-
-
-def flops_per_token(config: TransformerConfig, seq_len: int) -> float:
-    """Approximate forward+backward FLOPs/token (6ND + attention)."""
-    n = num_params(config) - config.vocab_size * config.hidden_size
-    attn = 12 * config.num_layers * config.hidden_size * seq_len
-    return 6 * n + attn

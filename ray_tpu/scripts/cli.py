@@ -27,8 +27,11 @@ import signal
 import sys
 import time
 
-_ADDRESS_FILE = "/tmp/ray_tpu/cluster_address"
-_DASHBOARD_FILE = "/tmp/ray_tpu/dashboard_url"
+from ray_tpu.core.api import _address_file, _state_dir
+
+
+def _dashboard_file() -> str:
+    return os.path.join(_state_dir(), "dashboard_url")
 
 
 def _client(addr: str = None):
@@ -37,7 +40,7 @@ def _client(addr: str = None):
 
     if not addr:
         try:
-            with open(_ADDRESS_FILE) as f:
+            with open(_address_file()) as f:
                 addr = f.read().strip()
         except FileNotFoundError:
             print("no running cluster (did you `ray-tpu start --head`?)",
@@ -48,7 +51,7 @@ def _client(addr: str = None):
     except OSError:
         print(f"cluster address file points at {addr} but nothing is "
               "listening; removing stale file", file=sys.stderr)
-        os.unlink(_ADDRESS_FILE)
+        os.unlink(_address_file())
         sys.exit(1)
 
 
@@ -62,8 +65,8 @@ def cmd_start(args):
             return 1
         return _start_worker_node(args)
     rt = ray_tpu.init(num_cpus=args.num_cpus, num_tpus=args.num_tpus)
-    os.makedirs(os.path.dirname(_ADDRESS_FILE), exist_ok=True)
-    with open(_ADDRESS_FILE, "w") as f:
+    os.makedirs(_state_dir(), exist_ok=True)
+    with open(_address_file(), "w") as f:
         f.write(rt.address)
     print(f"ray_tpu head started at {rt.address}")
     print(f"connect with ray_tpu.init(address='auto') or "
@@ -72,15 +75,17 @@ def cmd_start(args):
         from ray_tpu.dashboard import Dashboard
 
         dash = Dashboard(rt, port=args.dashboard_port)
-        with open(_DASHBOARD_FILE, "w") as f:
+        with open(_dashboard_file(), "w") as f:
             f.write(dash.url)
         print(f"dashboard at {dash.url}")
     if args.block:
         stop = []
         signal.signal(signal.SIGTERM, lambda *a: stop.append(1))
         signal.signal(signal.SIGINT, lambda *a: stop.append(1))
-        while not stop:
-            time.sleep(0.2)
+        # Until a signal, or until `ray-tpu stop` (shutdown_cluster) has
+        # stopped the control server under this process.
+        while not stop and not rt.control._stopped.wait(0.2):
+            pass
         ray_tpu.shutdown()
     else:
         print("running in background of this process; use --block to wait "
@@ -99,7 +104,7 @@ def _start_worker_node(args):
 
     address = args.address
     if address == "auto":
-        with open(_ADDRESS_FILE) as f:
+        with open(_address_file()) as f:
             address = f.read().strip()
     if getattr(args, "detach", False):
         import subprocess
@@ -114,9 +119,9 @@ def _start_worker_node(args):
             argv += ["--num-tpus", f"{args.num_tpus:g}"]
         for kv in (args.label or []):
             argv += ["--label", kv]
-        log = open(f"/tmp/ray_tpu/node-{args.node_id or 'worker'}.log",
-                   "ab") if os.path.isdir("/tmp/ray_tpu") else \
-            subprocess.DEVNULL
+        log = open(os.path.join(
+            _state_dir(), f"node-{args.node_id or 'worker'}.log"), "ab") \
+            if os.path.isdir(_state_dir()) else subprocess.DEVNULL
         proc = subprocess.Popen(argv, start_new_session=True,
                                 stdout=log, stderr=subprocess.STDOUT)
         # Confirm the daemon survives its startup window.
@@ -150,7 +155,7 @@ def cmd_stop(args):
         client.call({"op": "shutdown_cluster"}, timeout=5)
     except Exception:
         pass  # server exits mid-reply
-    for path in (_ADDRESS_FILE, _DASHBOARD_FILE):
+    for path in (_address_file(), _dashboard_file()):
         try:
             os.unlink(path)
         except FileNotFoundError:

@@ -26,8 +26,7 @@ move bytes directly peer-to-peer:
   - send/recv: direct push into the peer's inbox.
 
 Receives block on a condition variable (no sleep-polling in the op
-path).  The legacy KV-rendezvous transport survives as backend="kv" for
-comparison benchmarks.
+path).
 """
 
 from __future__ import annotations
@@ -42,7 +41,6 @@ import numpy as np
 from ray_tpu.core import rpc
 from ray_tpu.core.config import get_config
 from ray_tpu.core.ids import ObjectID
-from ray_tpu.core.object_ref import ObjectRef
 from ray_tpu.core.runtime import get_runtime
 from ray_tpu.experimental import internal_kv
 
@@ -398,141 +396,6 @@ class HostCollectiveGroup:
             pass
 
 
-class KvHostCollectiveGroup:
-    """Legacy KV-rendezvous transport (payloads via the head's object
-    store, polling for readiness).  Kept as backend="kv" so the p2p ring
-    can be benchmarked against it; not used by default."""
-
-    def __init__(self, group_name: str, world_size: int, rank: int,
-                 timeout_s: float = _DEFAULT_TIMEOUT_S):
-        if not (0 <= rank < world_size):
-            raise ValueError(f"rank {rank} outside world_size {world_size}")
-        self.group_name = group_name
-        self.world_size = world_size
-        self.rank = rank
-        self.timeout_s = timeout_s
-        self._seq: Dict[str, int] = {}
-        self._lock = threading.Lock()
-
-    def _next_seq(self, kind: str) -> int:
-        with self._lock:
-            n = self._seq.get(kind, 0)
-            self._seq[kind] = n + 1
-        return n
-
-    def _key(self, kind: str, seq: int, *suffix) -> str:
-        parts = (["col", self.group_name, kind, str(seq)]
-                 + [str(s) for s in suffix])
-        return "/".join(parts)
-
-    def _publish(self, key: str, value: np.ndarray):
-        ref = get_runtime().put(np.asarray(value))
-        internal_kv.kv_put(key, (ref.hex(), ref.owner))
-        return ref  # caller must keep it alive until the op's ack barrier
-
-    def _fetch(self, key: str) -> np.ndarray:
-        deadline = time.monotonic() + self.timeout_s
-        while True:
-            entry = internal_kv.kv_get(key)
-            if entry is not None:
-                break
-            if time.monotonic() > deadline:
-                raise CollectiveGroupError(
-                    f"collective op timed out waiting for {key} "
-                    f"(group={self.group_name}, rank={self.rank})")
-            time.sleep(_POLL_S)
-        obj_hex, owner = entry
-        rt = get_runtime()
-        rt.core.client.send({"op": "incref", "obj": obj_hex})
-        ref = ObjectRef(ObjectID.from_hex(obj_hex), owner=owner)
-        return rt.get([ref])[0]
-
-    def _ack_barrier(self, kind: str, seq: int):
-        internal_kv.kv_put(self._key(kind, seq, "ack", self.rank), 1)
-        deadline = time.monotonic() + self.timeout_s
-        for r in range(self.world_size):
-            key = self._key(kind, seq, "ack", r)
-            while not internal_kv.kv_exists(key):
-                if time.monotonic() > deadline:
-                    raise CollectiveGroupError(
-                        f"barrier timed out waiting for rank {r} "
-                        f"(group={self.group_name})")
-                time.sleep(_POLL_S)
-        if self.rank == 0 and seq >= 2:
-            stale = self._key(kind, seq - 2)
-            for k in internal_kv.kv_keys(stale + "/") + (
-                    [stale] if internal_kv.kv_exists(stale) else []):
-                internal_kv.kv_del(k)
-
-    def barrier(self):
-        self._ack_barrier("barrier", self._next_seq("barrier"))
-
-    def allgather(self, array) -> List[np.ndarray]:
-        seq = self._next_seq("allgather")
-        local = np.array(array)
-        ref = self._publish(self._key("allgather", seq, self.rank), local)
-        out = [local if r == self.rank
-               else self._fetch(self._key("allgather", seq, r))
-               for r in range(self.world_size)]
-        self._ack_barrier("allgather", seq)
-        del ref
-        return out
-
-    def allreduce(self, array, op: ReduceOp = ReduceOp.SUM) -> np.ndarray:
-        parts = self.allgather(array)
-        return _REDUCERS[op](np.stack([np.asarray(p) for p in parts]))
-
-    def reducescatter(self, array, op: ReduceOp = ReduceOp.SUM) -> np.ndarray:
-        reduced = self.allreduce(array, op)
-        n = reduced.shape[0]
-        if n % self.world_size != 0:
-            raise ValueError(
-                f"leading dim {n} not divisible by world_size "
-                f"{self.world_size}")
-        shard = n // self.world_size
-        return reduced[self.rank * shard:(self.rank + 1) * shard]
-
-    def broadcast(self, array, src_rank: int = 0) -> np.ndarray:
-        seq = self._next_seq("broadcast")
-        key = self._key("broadcast", seq, src_rank)
-        ref = None
-        if self.rank == src_rank:
-            ref = self._publish(key, array)
-            out = np.asarray(array)
-        else:
-            out = self._fetch(key)
-        self._ack_barrier("broadcast", seq)
-        del ref
-        return out
-
-    def send(self, array, dst_rank: int):
-        if dst_rank == self.rank:
-            raise ValueError("cannot send to self")
-        seq = self._next_seq(f"p2p-{self.rank}-{dst_rank}")
-        key = self._key(f"p2p-{self.rank}-{dst_rank}", seq)
-        ref = self._publish(key, array)  # noqa: F841 — held until ack
-        ack = key + "/recv-ack"
-        deadline = time.monotonic() + self.timeout_s
-        while not internal_kv.kv_exists(ack):
-            if time.monotonic() > deadline:
-                raise CollectiveGroupError(f"send not acked: {key}")
-            time.sleep(_POLL_S)
-        internal_kv.kv_del(key)
-        internal_kv.kv_del(ack)
-
-    def recv(self, src_rank: int) -> np.ndarray:
-        if src_rank == self.rank:
-            raise ValueError("cannot recv from self")
-        seq = self._next_seq(f"p2p-{src_rank}-{self.rank}")
-        key = self._key(f"p2p-{src_rank}-{self.rank}", seq)
-        out = self._fetch(key)
-        internal_kv.kv_put(key + "/recv-ack", 1)
-        return out
-
-    def close(self):
-        pass
-
-
 class GroupManager:
     """Per-process registry of collective groups (reference
     collective.py:40)."""
@@ -542,16 +405,14 @@ class GroupManager:
         self._lock = threading.Lock()
 
     def create(self, group_name: str, world_size: int, rank: int,
-               timeout_s: float = _DEFAULT_TIMEOUT_S,
-               backend: str = "host"):
-        cls = KvHostCollectiveGroup if backend == "kv" \
-            else HostCollectiveGroup
+               timeout_s: float = _DEFAULT_TIMEOUT_S):
         with self._lock:
             if group_name in self._groups:
                 raise CollectiveGroupError(
                     f"group {group_name!r} already initialized in this "
                     "process")
-            g = cls(group_name, world_size, rank, timeout_s)
+            g = HostCollectiveGroup(group_name, world_size, rank,
+                                    timeout_s)
             self._groups[group_name] = g
             return g
 
@@ -568,8 +429,7 @@ class GroupManager:
         me = _self_actor_hex()
         if me and me in decl["actor_ranks"]:
             return self.create(group_name, decl["world_size"],
-                               decl["actor_ranks"][me],
-                               backend=decl.get("backend", "host"))
+                               decl["actor_ranks"][me])
         return None
 
     def destroy(self, group_name: str):
@@ -591,6 +451,11 @@ def _self_actor_hex() -> str:
 
 # -- module-level API (reference collective.py signatures) ---------------
 
+def _check_backend(backend: str) -> None:
+    if backend not in ("host", "nccl", "gloo"):
+        raise ValueError(f"unknown collective backend {backend!r}")
+
+
 def init_collective_group(world_size: int, rank: int,
                           backend: str = "host",
                           group_name: str = "default") -> None:
@@ -599,12 +464,9 @@ def init_collective_group(world_size: int, rank: int,
     ``backend``: "host" (the p2p ring implemented here; "nccl"/"gloo"
     are accepted as aliases for reference compatibility — on TPU the
     accelerator tier lives inside jitted programs, see module
-    docstring), or "kv" (legacy store-and-poll transport, kept for
-    benchmarks)."""
-    if backend not in ("host", "nccl", "gloo", "kv"):
-        raise ValueError(f"unknown collective backend {backend!r}")
-    _manager.create(group_name, world_size, rank,
-                    backend="kv" if backend == "kv" else "host")
+    docstring)."""
+    _check_backend(backend)
+    _manager.create(group_name, world_size, rank)
 
 
 def create_collective_group(actors: Sequence, world_size: int,
@@ -613,13 +475,13 @@ def create_collective_group(actors: Sequence, world_size: int,
                             group_name: str = "default") -> None:
     """Declarative setup from the driver (reference collective.py:151):
     record the group membership; each actor joins lazily on first use."""
+    _check_backend(backend)
     if len(actors) != len(ranks) or len(actors) != world_size:
         raise ValueError("actors/ranks must both have world_size entries")
     actor_ranks = {a._actor_hex: r for a, r in zip(actors, ranks)}
     internal_kv.kv_put(
         f"col-decl/{group_name}",
-        {"world_size": world_size, "actor_ranks": actor_ranks,
-         "backend": backend})
+        {"world_size": world_size, "actor_ranks": actor_ranks})
 
 
 def is_group_initialized(group_name: str = "default") -> bool:
@@ -634,9 +496,8 @@ def destroy_collective_group(group_name: str = "default") -> None:
     _manager.destroy(group_name)
     try:
         internal_kv.kv_del(f"col-decl/{group_name}")
-        for prefix in (f"col/{group_name}/", f"colp2p/{group_name}/"):
-            for k in internal_kv.kv_keys(prefix):
-                internal_kv.kv_del(k)
+        for k in internal_kv.kv_keys(f"colp2p/{group_name}/"):
+            internal_kv.kv_del(k)
     except Exception:
         pass  # best effort: runtime may already be shut down
 

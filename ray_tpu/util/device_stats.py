@@ -153,8 +153,7 @@ def memory_stats() -> Optional[Dict[str, Any]]:
 
 # Peak rates of one chip by jax `device_kind`: (HBM bytes/s, dense bf16
 # FLOP/s).  Source: Google Cloud TPU documentation, system-architecture
-# pages for v4, v5e ("TPU v5 lite"), v5p and v6e ("TPU v6 lite").  THE
-# one table: bench.py and scripts/bench_decode.py read it too.  A kind
+# pages for v4, v5e ("TPU v5 lite"), v5p and v6e ("TPU v6 lite").  A kind
 # that is not here has no assumed peak — RAY_TPU_DEVICE_HBM_GBPS /
 # RAY_TPU_DEVICE_PEAK_TFLOPS supply one explicitly (CPU hosts, tests).
 PEAK_SPECS = {

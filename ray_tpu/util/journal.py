@@ -32,9 +32,7 @@ timestamp.  Retention never deletes another pid's newest segment (it
 may still be open for append).
 
 The journal is off by default — ``stream(name)`` returns None unless
-``RAY_TPU_OPS_JOURNAL_DIR`` is set — so the live path stays zero-cost
-(see scripts/bench_opsplane.py / OPSPLANE_BENCH.json for the measured
-on-cost, budget <5%).
+``RAY_TPU_OPS_JOURNAL_DIR`` is set — so the live path stays zero-cost.
 """
 
 from __future__ import annotations

@@ -371,9 +371,8 @@ def clock_offset() -> float:
 def serve_trace_enabled() -> bool:
     """Request-journey tracing gate for the serve data plane
     (RAY_TPU_SERVE_TRACE, default on).  Read per request — an env read
-    is nanoseconds next to a model step — so the paired overhead bench
-    (scripts/bench_serve.py tracing phase) can flip it between arms
-    without rebuilding the serving stack."""
+    is nanoseconds next to a model step — so it can be flipped without
+    rebuilding the serving stack."""
     return os.environ.get("RAY_TPU_SERVE_TRACE", "1").strip().lower() \
         not in ("0", "false", "no", "off")
 

@@ -1,7 +1,7 @@
 """Control-plane scale probe: locate the head's ceiling on one host.
 
-VERDICT r2 weak #8: the biggest cluster any test exercised was 3 logical
-nodes; BASELINE.md's envelope rows are 2,000 nodes / 40k actors / 1M
+The biggest cluster any other test exercises is 3 logical nodes;
+BASELINE.md's envelope rows are 2,000 nodes / 40k actors / 1M
 queued tasks / 1k PGs (on 64-core cloud hosts).  This probe drives the
 same four dimensions as far as one host allows and records the rates:
 
@@ -12,8 +12,8 @@ same four dimensions as far as one host allows and records the rates:
     head; the probe records both the rate and that attribution)
   - placement groups created+removed (default 100)
 
-Writes SCALE_r03.json at the repo root.
-Usage: python scripts/scale_probe.py [--nodes N] [--tasks N]
+Writes its readings to --out.
+Usage: python scripts/scale_probe.py --out FILE [--nodes N] [--tasks N]
        [--actors N] [--pgs N]
 """
 
@@ -44,7 +44,7 @@ def main(argv=None) -> int:
                     help="also put+get one N-GiB object through the shm "
                          "arena (BASELINE.md 'max ray.get numpy object' "
                          "row); sizes the arena to fit")
-    ap.add_argument("--out", default="SCALE_r03.json")
+    ap.add_argument("--out", required=True)
     args = ap.parse_args(argv)
 
     import ray_tpu

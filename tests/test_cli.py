@@ -14,36 +14,41 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(args, **kw):
+def _env():
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env.setdefault("JAX_PLATFORMS", "cpu")
     env.setdefault("RAY_TPU_CHIPS", "none")
+    return env
+
+
+def _run(args, **kw):
     return subprocess.run(
         [sys.executable, "-m", "ray_tpu.scripts.cli"] + args,
         capture_output=True, text=True, timeout=kw.pop("timeout", 60),
-        env=env, **kw)
+        env=_env(), **kw)
 
 
 @pytest.fixture
-def cluster_head():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    env.setdefault("RAY_TPU_CHIPS", "none")
+def cluster_head(tmp_path, monkeypatch):
+    # The address file lies under the temp dir; five other test files
+    # start heads of their own, and under xdist they run beside this
+    # one.  A temp dir of its own keeps this head's file this head's.
+    monkeypatch.setenv("TMPDIR", str(tmp_path))
+    address_file = tmp_path / "ray_tpu" / "cluster_address"
+    env = _env()
     proc = subprocess.Popen(
         [sys.executable, "-m", "ray_tpu.scripts.cli", "start", "--head",
          "--num-cpus", "2", "--block", "--no-dashboard"],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
         env=env)
     try:
-        deadline = time.monotonic() + 30
-        while not os.path.exists("/tmp/ray_tpu/cluster_address"):
+        deadline = time.monotonic() + 60
+        while not (address_file.exists() and address_file.read_text()):
             if time.monotonic() > deadline or proc.poll() is not None:
                 out = proc.stdout.read() if proc.stdout else ""
                 raise RuntimeError(f"head did not start: {out}")
             time.sleep(0.1)
-        time.sleep(0.3)
     except BaseException:
         # The pre-yield error path must not leak a --block head: each
         # leaked head idles forever and skews every later timing

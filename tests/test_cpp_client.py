@@ -82,6 +82,27 @@ def test_named_function_python_roundtrip(cluster):
                            "args": []})
 
 
+def test_poll_that_outruns_the_scheduler_sees_pending(cluster):
+    """A named task's return entry is made when its spec drains from
+    the head's submit ingress.  A client that polls before the
+    scheduler has drained it must read "pending", never "object not
+    found" (which the C++ client takes as the task's failure).  The
+    head's lock is held here, so the scheduler cannot drain between
+    the two calls."""
+    ray_tpu.register_named_function("inc", lambda a: a + 1)
+    head = cluster.control
+
+    class _Conn:
+        meta = {}
+
+    with head.lock:
+        obj = head._op_submit_named_task(
+            _Conn(), {"name": "inc", "args": [1]})
+        st = head._op_get_object_json(_Conn(), {"obj": obj})
+    assert st == {"status": "pending"}
+    assert _poll(cluster, obj) == {"status": "ready", "value": 2}
+
+
 def test_non_jsonable_result_reports_clearly(cluster):
     import numpy as np
 

@@ -640,6 +640,14 @@ def test_split_at_indices_and_proportionately():
     with pytest.raises(ValueError, match="sorted"):
         ds.split_at_indices([7, 3])
 
+    # Read tasks finish in any order on a busy host: the cut is made in
+    # source order whatever order the bundles arrived in.
+    mat = rd.range(10).materialize()
+    assert len(mat._materialized) > 1
+    mat._materialized.reverse()
+    a, b, c = mat.split_at_indices([3, 7])
+    assert sorted(r["id"] for r in b.take_all()) == [3, 4, 5, 6]
+
     x, y, z = rd.range(20).split_proportionately([0.25, 0.5])
     assert [d.count() for d in (x, y, z)] == [5, 10, 5]
     with pytest.raises(ValueError, match="less than 1"):

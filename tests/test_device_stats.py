@@ -205,9 +205,14 @@ def test_engine_step_sampler_device_fields(monkeypatch):
     # A CPU has no recorded peaks: fractions need them given explicitly.
     monkeypatch.setenv("RAY_TPU_DEVICE_HBM_GBPS", "100")
     monkeypatch.setenv("RAY_TPU_DEVICE_PEAK_TFLOPS", "1")
+    import jax
+
     from ray_tpu.models import transformer as tfm
     from ray_tpu.serve.llm_engine import LLMEngine
 
+    # Another test file may have compiled these shapes in this process
+    # already; the compile counts asserted below need a cold jit cache.
+    jax.clear_caches()
     c = tfm.TransformerConfig.tiny()
     eng = LLMEngine(c, page_size=4, num_pages=64, max_batch=4,
                     multi_step=1)
@@ -423,67 +428,3 @@ def test_device_watchdog_and_api_device(monkeypatch):
             dash.stop()
     finally:
         ray_tpu.shutdown()
-
-
-# ---------------------------------------------------------------------------
-# Bench trajectory index (satellite)
-# ---------------------------------------------------------------------------
-
-def test_bench_index_every_known_file_parses():
-    bench_index = _load_script("bench_index")
-    files = bench_index.bench_files(_REPO)
-    assert files, "no bench JSONs found at the repo root"
-    index = bench_index.build_index(_REPO)  # raises if any fails json
-    assert index["file_count"] == len(files)
-    per_source = {}
-    for row in index["rows"]:
-        for key in ("metric", "value", "source"):
-            assert key in row, row
-        assert isinstance(row["value"], (int, float)), row
-        per_source.setdefault(row["source"], 0)
-        per_source[row["source"]] += 1
-    # Every known bench file contributes at least one headline row.
-    for path in files:
-        name = os.path.basename(path)
-        assert per_source.get(name, 0) > 0, f"{name} extracted 0 rows"
-    # Known headline metrics survive extraction.
-    metrics = {r["metric"] for r in index["rows"]}
-    for want in ("decode_tokens_per_sec",
-                 "serve_tokens_per_sec",
-                 "multi_client_tasks_async.overhead"):
-        assert want in metrics, sorted(metrics)
-
-
-def test_bench_trajectory_committed_and_fresh():
-    path = os.path.join(_REPO, "BENCH_TRAJECTORY.json")
-    assert os.path.exists(path), \
-        "BENCH_TRAJECTORY.json missing: run scripts/bench_index.py"
-    with open(path) as f:
-        doc = json.load(f)
-    assert doc["rows"] and doc["file_count"] == len(doc["files"])
-    bench_index = _load_script("bench_index")
-    live = {os.path.basename(p)
-            for p in bench_index.bench_files(_REPO)}
-    assert set(doc["files"]) == live, \
-        "BENCH_TRAJECTORY.json is stale: rerun scripts/bench_index.py"
-
-
-# ---------------------------------------------------------------------------
-# Device-telemetry overhead budget (satellite)
-# ---------------------------------------------------------------------------
-
-def test_device_telemetry_overhead_budget():
-    bench = os.path.join(_REPO, "PROF_BENCH.json")
-    if not os.path.exists(bench):
-        pytest.skip("PROF_BENCH.json not generated")
-    with open(bench) as f:
-        doc = json.load(f)
-    row = doc.get("engine_device_telemetry")
-    assert row is not None, \
-        "PROF_BENCH.json predates the device-telemetry phase: rerun " \
-        "scripts/bench_profiling.py"
-    assert row["off_steps_s"] > 0 and row["on_steps_s"] > 0
-    assert row["overhead"] < 0.05, (
-        f"device telemetry overhead {row['overhead']:.1%} exceeds the "
-        f"5% budget ({row['on_steps_s']:.0f} vs "
-        f"{row['off_steps_s']:.0f} steps/s)")

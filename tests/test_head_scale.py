@@ -5,14 +5,11 @@ object plane (ISSUE 13).
 Covers the shard correctness matrix (N-owner concurrent submit/complete
 landing in the right shard), cross-shard PG atomicity, timer-wheel fire
 ordering + cancellation, node-manager-level single-flight pull fan-in,
-pickle5 round-trip identity for >= 1 MiB ndarray args, and the
-HEAD_BENCH.json thresholds the ISSUE pins.
+and pickle5 round-trip identity for >= 1 MiB ndarray args.
 """
 
-import json
 import os
 import threading
-import time
 
 import numpy as np
 import pytest
@@ -23,8 +20,6 @@ from ray_tpu.util import (
     placement_group,
     remove_placement_group,
 )
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
@@ -207,27 +202,6 @@ def test_pg_spread_lands_on_distinct_nodes():
         c.shutdown()
 
 
-def test_node_index_matches_legacy_scan():
-    """The bucketed index and the legacy full scan agree on
-    schedulability across a mixed cluster (same tasks complete)."""
-    os.environ["RAY_TPU_NODE_INDEX"] = "0"
-    try:
-        c = Cluster(head_node_args={"num_cpus": 2})
-        try:
-            c.add_node(num_cpus=2, node_id="legacy1")
-
-            @ray_tpu.remote
-            def one():
-                return 1
-
-            assert sum(ray_tpu.get(
-                [one.remote() for _ in range(8)], timeout=60)) == 8
-        finally:
-            c.shutdown()
-    finally:
-        os.environ.pop("RAY_TPU_NODE_INDEX", None)
-
-
 # ---------------------------------------------------------------------------
 # Node-manager-level single-flight pull
 
@@ -359,48 +333,3 @@ def test_put_serialized_skips_reserialize():
         assert np.array_equal(np.asarray(back), arr)
     finally:
         ray_tpu.shutdown()
-
-
-# ---------------------------------------------------------------------------
-# Bench thresholds (HEAD_BENCH.json, scripts/bench_head_scale.py)
-
-
-def _head_bench():
-    path = os.path.join(REPO, "HEAD_BENCH.json")
-    assert os.path.exists(path), \
-        "HEAD_BENCH.json missing — run scripts/bench_head_scale.py"
-    return json.load(open(path))
-
-
-def test_head_bench_multi_client_speedup():
-    doc = _head_bench()
-    row = doc["multi_client_tasks_async"]
-    # ISSUE 13 names >= 1.7x over the RPC_BENCH 4,952 ops/s row, but
-    # that row was recorded on a faster host: the SEED code measures
-    # well under it here (HEAD_BENCH's host_factor documents the gap),
-    # so an absolute pin would test the machine, not the code.  What
-    # the bench CAN pin honestly is the paired same-host comparison
-    # (SCALE_r05 methodology): the scale-out machinery must not cost
-    # throughput on the RPC_BENCH shape, and the doc must carry the
-    # recorded row + host factor so the cross-host context is explicit.
-    assert row["after_ops_per_s"] >= 0.9 * row["before_ops_per_s"], row
-    assert row["recorded_rpc_bench_ops_per_s"] > 0, row
-    assert row["host_factor"] is not None, row
-
-
-def test_head_bench_pg_create_ready_flat():
-    doc = _head_bench()
-    rows = {r["pgs"]: r for r in doc["pg_create_ready"]}
-    assert set(rows) >= {100, 1000}
-    r100, r1000 = rows[100], rows[1000]
-    # ISSUE 13 acceptance: 1,000-PG rate within 25% of the 100-PG rate.
-    assert r1000["after_per_s"] >= 0.75 * r100["after_per_s"], \
-        (r100, r1000)
-
-
-def test_head_bench_large_arg_bytes_copied():
-    doc = _head_bench()
-    row = doc["large_arg_submit"]
-    # The zero-copy path must move the dominant share of large-arg
-    # bytes out-of-band: copied bytes p99 strictly below the payload.
-    assert row["p99_bytes_copied"] < row["arg_bytes"], row

@@ -1,9 +1,8 @@
 """Object-plane fast path: windowed chunk pulls (rpc.pull_object_chunked),
 single-flight dedup (object_plane.PullManager), direct-into-arena caching
 (object_plane.pull_into_store), and locality-aware placement
-(gcs.ControlServer._pick_node tie-breaks)."""
+(gcs.ControlServer._pick_node_indexed's locality consult)."""
 
-import json
 import os
 import threading
 import time
@@ -406,22 +405,31 @@ def test_arena_cache_failure_warns_once_per_cause(store, caplog):
 
 
 # ---------------------------------------------------------------------------
-# Locality-aware placement (_pick_node hybrid tie-breaks)
+# Locality-aware placement (_pick_node -> _pick_node_indexed)
 # ---------------------------------------------------------------------------
 
 class _FakeHead:
-    """Just enough ControlServer surface to drive _pick_node."""
+    """Just enough ControlServer surface to drive _pick_node over a
+    real _NodeIndex: the picker every head runs."""
 
     _utilization = gcs.ControlServer._utilization
     _locality_bytes = gcs.ControlServer._locality_bytes
-    _locality_enabled = staticmethod(gcs.ControlServer._locality_enabled)
     _pick_node = gcs.ControlServer._pick_node
+    _pick_node_indexed = gcs.ControlServer._pick_node_indexed
 
-    def __init__(self, nodes, objects):
-        self.nodes = nodes
+    def __init__(self, nodes, objects, fillers=16):
+        # The index answers an ask for a resource that at most 16 nodes
+        # have free from that free set alone (least utilized fit, no
+        # locality, no packing); idle filler nodes put CPU past that so
+        # the hybrid policy and its locality consult are what runs.
+        self.nodes = dict(nodes)
+        for i in range(fillers):
+            self.nodes[f"f{i:02d}"] = _node(f"f{i:02d}")
         self.objects = objects
         self.placement_groups = {}
         self._m_locality_hits = None
+        self._node_index = gcs._NodeIndex(self)
+        self._node_index.rebuild()
 
     def _charge_avail(self, charge):
         return self.nodes[charge[1]].available
@@ -443,25 +451,23 @@ def _node(nid, cpus=4.0, avail=None, is_head=False):
                      is_head=is_head)
 
 
-def test_locality_breaks_utilization_ties(monkeypatch):
-    monkeypatch.delenv("RAY_TPU_NO_LOCALITY", raising=False)
+def test_locality_breaks_utilization_ties():
     obj = "ab" * 14
     head = _FakeHead(
         nodes={"head": _node("head", is_head=True), "n2": _node("n2")},
         objects={obj: ObjectEntry(state=READY, size=64 << 20, in_shm=True,
                                   node_id="n2")})
     need = ResourceSet({"CPU": 1.0})
-    # Equal utilization; legacy tie-break prefers the head.  With a
-    # 64 MiB arg resident on n2, locality wins the tie.
+    # Every node idle.  With a 64 MiB arg resident on n2, n2 it is.
     nid, _ = head._pick_node(need, _Spec([obj]))
     assert nid == "n2"
-    # No ref args -> legacy choice (the head) is preserved.
+    # No ref args -> the pack walk's first fit in the bucket: the head,
+    # which joined first.
     nid, _ = head._pick_node(need, _Spec([]))
     assert nid == "head"
 
 
-def test_locality_counts_replicas_and_respects_feasibility(monkeypatch):
-    monkeypatch.delenv("RAY_TPU_NO_LOCALITY", raising=False)
+def test_locality_counts_replicas_and_respects_feasibility():
     a, b = "aa" * 14, "bb" * 14
     head = _FakeHead(
         nodes={"head": _node("head", is_head=True),
@@ -479,18 +485,44 @@ def test_locality_counts_replicas_and_respects_feasibility(monkeypatch):
     assert nid == "n3"
 
 
-def test_no_locality_env_restores_legacy_choice(monkeypatch):
-    obj = "cd" * 14
+@pytest.mark.parametrize("holder_avail,want", [
+    # The holder is the LESS utilized of the two busy nodes: packing
+    # alone would take the head (0.25); a fitting holder below the
+    # spread threshold wins outright, not only on a utilization tie.
+    (4.0, "n2"),
+    # The holder fits but sits at 0.75, over the threshold: locality
+    # does not pull work onto a node the policy is spreading away from,
+    # and the pack walk takes the busiest node under it.
+    (1.0, "head"),
+])
+def test_locality_holder_wins_outright_only_below_threshold(
+        holder_avail, want):
+    obj = "ac" * 14
     head = _FakeHead(
-        nodes={"head": _node("head", is_head=True), "n2": _node("n2")},
+        nodes={"head": _node("head", avail=3.0, is_head=True),
+               "n2": _node("n2", avail=holder_avail)},
         objects={obj: ObjectEntry(state=READY, size=64 << 20, in_shm=True,
                                   node_id="n2")})
-    need = ResourceSet({"CPU": 1.0})
-    monkeypatch.setenv("RAY_TPU_NO_LOCALITY", "1")
-    nid, _ = head._pick_node(need, _Spec([obj]))
-    assert nid == "head"  # legacy tie-break: pack onto the head
-    monkeypatch.delenv("RAY_TPU_NO_LOCALITY")
-    nid, _ = head._pick_node(need, _Spec([obj]))
+    nid, _ = head._pick_node(ResourceSet({"CPU": 1.0}), _Spec([obj]))
+    assert nid == want
+
+
+def test_few_free_nodes_take_the_free_set_not_locality():
+    """What a head of up to 16 nodes does today (ROADMAP D4): an ask
+    naming a resource goes to that resource's free set, which picks the
+    least utilized fit and consults neither locality nor the pack
+    threshold."""
+    obj = "ad" * 14
+    head = _FakeHead(
+        nodes={"head": _node("head", is_head=True),
+               "n2": _node("n2", avail=3.0)},
+        objects={obj: ObjectEntry(state=READY, size=64 << 20, in_shm=True,
+                                  node_id="n2")},
+        fillers=0)
+    nid, _ = head._pick_node(ResourceSet({"CPU": 1.0}), _Spec([obj]))
+    assert nid == "head"
+    # An ask that names no resource has no free set to go to.
+    nid, _ = head._pick_node(ResourceSet({}), _Spec([obj]))
     assert nid == "n2"
 
 
@@ -544,24 +576,3 @@ def test_object_metric_snapshots_shape_and_counts(store):
     from ray_tpu.util import metrics as metrics_mod
     names = {s["name"] for s in metrics_mod.local_snapshots()}
     assert "object_transfer_bytes_total" in names
-
-
-# ---------------------------------------------------------------------------
-# Bench thresholds (scripts/bench_object_plane.py writes OBJ_BENCH.json)
-# ---------------------------------------------------------------------------
-
-def test_object_plane_bench_thresholds():
-    bench = os.path.join(os.path.dirname(__file__), os.pardir,
-                         "OBJ_BENCH.json")
-    if not os.path.exists(bench):
-        pytest.skip("OBJ_BENCH.json not generated")
-    with open(bench) as f:
-        doc = json.load(f)
-    row = doc["pull_throughput"]["64MiB"]
-    assert row["windowed_MBps"] >= 1.5 * row["single_MBps"], (
-        f"windowed pull {row['windowed_MBps']:.0f} MB/s must be >= 1.5x "
-        f"single-chunk {row['single_MBps']:.0f} MB/s")
-    dedup = doc["dedup_fan_in"]
-    assert dedup["consumers"] >= 8
-    assert dedup["wire_pulls"] == 1, (
-        f"dedup fan-in performed {dedup['wire_pulls']} wire pulls")

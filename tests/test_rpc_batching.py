@@ -257,21 +257,6 @@ def test_flush_fence_skips_linger(monkeypatch):
     assert len(sock.frames) == 1
 
 
-def test_no_batch_env_disables_coalescing(monkeypatch, echo_server):
-    srv, handler = echo_server
-    monkeypatch.setenv("RAY_TPU_RPC_NO_BATCH", "1")
-    assert not rpc.batching_enabled()
-    cli = rpc.Client(srv.address)
-    assert cli._sender is None  # legacy synchronous path
-    for i in range(10):
-        cli.send({"op": "note", "i": 100 + i})
-    assert cli.call({"op": "echo", "x": "done"}) == "done"
-    assert cli.batches_sent == 0
-    assert cli.frames_sent == cli.msgs_sent == 11
-    assert [m["i"] for m in handler.got] == list(range(100, 110))
-    cli.close()
-
-
 # ---------------------------------------------------------------------------
 # Ref-count delta vectors
 # ---------------------------------------------------------------------------

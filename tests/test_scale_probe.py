@@ -1,8 +1,7 @@
-"""Head scale-envelope smoke (VERDICT r2 weak #8).
+"""Head scale-envelope smoke.
 
 The full probe (scripts/scale_probe.py: 50 nodes / 10k queued tasks /
-1k actors / 100 PGs) runs out-of-band and records SCALE_r03.json; this
-keeps the machinery exercised in the suite at CI-sized numbers —
+1k actors / 100 PGs) runs out-of-band; this keeps the machinery exercised in the suite at CI-sized numbers —
 many logical nodes, a queued-task burst bigger than the worker pool,
 a batch of actors, and PG create/remove, all asserting completion.
 """

@@ -1,15 +1,12 @@
 """Serve data plane under load: engine admission control (bounded queue,
 deadline shedding, abort reclamation, per-step prefill budget),
-load-feedback P2C routing with staleness fallback, the multiplex model
-cache's concurrency guarantees, and the SERVE_BENCH.json artifact
-thresholds (scripts/bench_serve.py).
+load-feedback P2C routing with staleness fallback, and the multiplex
+model cache's concurrency guarantees.
 
 These are unit tests — no cluster; the engine runs the tiny CPU config
 and the router is exercised directly against an injected replica set.
 """
 
-import json
-import os
 import threading
 import time
 
@@ -312,31 +309,3 @@ def test_serve_metrics_and_flight_recorder_lane():
     events = [(e["category"], e["event"])
               for e in flight_recorder.dump(last=50)]
     assert ("serve", "queue_full") in events
-
-
-def test_serve_bench_artifact_thresholds():
-    bench = os.path.join(os.path.dirname(__file__), os.pardir,
-                         "SERVE_BENCH.json")
-    if not os.path.exists(bench):
-        pytest.skip("SERVE_BENCH.json not generated")
-    with open(bench) as f:
-        doc = json.load(f)
-    assert doc["concurrent_clients"] >= 1024
-    sus = doc["sustained_load"]
-    assert sus["tokens_per_sec"] > 0
-    assert 0 < sus["ttft_p50_s"] <= sus["ttft_p99_s"]
-    assert 0 < sus["tpot_p50_ms"] <= sus["tpot_p99_ms"]
-    burst = doc["burst_shed"]
-    # Backpressure fired: the 4x-cap burst was shed, not queued forever.
-    assert burst["queue_full_rejects"] > 0
-    assert burst["shed_rate"] > 0
-    assert burst["completed"] + burst["deadline_sheds"] \
-        + burst["queue_full_rejects"] == burst["burst_clients"]
-    pi = doc["prefill_interference"]
-    assert pi["decode_tpot_p99_ms_alone"] > 0
-    assert pi["prefill_requests_injected"] > 0
-    if doc.get("on_tpu"):
-        # TPU acceptance bars (CPU runs are dispatch-bound, so the
-        # roofline fraction and the TPOT isolation bar only bind there).
-        assert doc["roofline_fraction"] > 0.378
-        assert pi["tpot_ratio"] <= 1.2

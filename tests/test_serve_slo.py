@@ -281,28 +281,6 @@ def test_opsdump_serve_request_lanes(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Committed tracing-overhead budget (scripts/bench_serve.py)
-# ---------------------------------------------------------------------------
-
-
-def test_tracing_overhead_budget():
-    bench = os.path.join(REPO, "SERVE_BENCH.json")
-    if not os.path.exists(bench):
-        pytest.skip("SERVE_BENCH.json not generated")
-    with open(bench) as f:
-        doc = json.load(f)
-    row = doc.get("tracing_overhead")
-    if row is None:
-        pytest.skip("tracing_overhead rows not generated")
-    assert row["overhead_pct"] < 5.0, (
-        f"request-journey tracing costs {row['overhead_pct']:.2f}% "
-        f"tok/s — over the 5% observability budget")
-    assert row["tokens_per_sec_traced"] > 0
-    assert row["tokens_per_sec_untraced"] > 0
-    assert row["spans_per_run"] > 0  # the traced arm actually traced
-
-
-# ---------------------------------------------------------------------------
 # Cluster: connected trace over HTTP + /api/serve_slo + partial timeline
 # ---------------------------------------------------------------------------
 

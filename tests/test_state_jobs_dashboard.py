@@ -237,6 +237,13 @@ def test_dashboard_full_surface_three_node_cluster(tmp_path):
 
         ray_tpu.get([work.remote(i) for i in range(6)], timeout=60)
 
+        # The workers report a task's states through their coalescing
+        # flushers: the results can be back before the last event is.
+        deadline = _time.time() + 30
+        while len(_get_json(f"{base}/api/tasks?state=FINISHED")) < 6:
+            assert _time.time() < deadline, "task events never arrived"
+            _time.sleep(0.1)
+
         # Table controls: filter + sort + pagination on the tasks table.
         all_tasks = _get_json(f"{base}/api/tasks")
         assert len(all_tasks) >= 6

@@ -21,7 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops import attention, paged_attention
 
-# 1.1 B GQA serving widths (scripts/bench_decode.py, chip_smoke.py).
+# 1.1 B GQA serving widths (chip_smoke.py).
 B, H, KVH, D = 128, 16, 4, 128
 PAGE, NUM_PAGES = 128, 320
 
