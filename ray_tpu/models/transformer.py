@@ -197,11 +197,9 @@ def rope_freqs(head_dim: int, max_len: int, theta: float):
 
 def apply_rope(x, cos, sin, positions):
     # x: [b, s, heads, hd]; cos/sin: [max_len, hd//2]; positions: [b, s]
-    c = cos[positions][:, :, None, :]  # [b, s, 1, hd//2]
-    s = sin[positions][:, :, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-    return out.astype(x.dtype)
+    from ray_tpu.ops.attention import rope_reference
+
+    return rope_reference(x, cos[positions], sin[positions])
 
 
 def _use_ring(config: TransformerConfig) -> bool:
@@ -217,8 +215,17 @@ def _use_ring(config: TransformerConfig) -> bool:
     return False
 
 
-def _attention(q, k, v, mask, config: TransformerConfig):
-    """q:[b,s,h,hd] k,v:[b,s,kv,hd] causal attention with GQA."""
+def _ropes_in_flash(config: TransformerConfig) -> bool:
+    """True when `_attention` will call flash_attention, whose kernels rope
+    q and k themselves (`rope=`): `_block` then hands them as projected."""
+    return config.use_flash and not _use_ring(config)
+
+
+def _attention(q, k, v, mask, config: TransformerConfig, rope=None):
+    """q:[b,s,h,hd] k,v:[b,s,kv,hd] causal attention with GQA.  rope: the
+    tables at the rows' positions when q and k are not roped yet (only
+    where `_ropes_in_flash`); rope after GQA's repeat gives the same
+    values."""
     b, s, h, hd = q.shape
     kv = k.shape[2]
     if kv != h:
@@ -232,7 +239,7 @@ def _attention(q, k, v, mask, config: TransformerConfig):
     if config.use_flash:
         from ray_tpu.ops.attention import flash_attention
 
-        return flash_attention(q, k, v, causal=True)
+        return flash_attention(q, k, v, causal=True, rope=rope)
     scale = 1.0 / math.sqrt(hd)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
@@ -240,7 +247,9 @@ def _attention(q, k, v, mask, config: TransformerConfig):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _block(x, bp, cos, sin, positions, mask, config: TransformerConfig):
+def _block(x, bp, rope, mask, config: TransformerConfig):
+    """rope: (cos, sin) gathered at the rows' positions, [b, s, hd//2],
+    once for all layers by the caller."""
     c = config
     hd = c.head_dim_
     b, s, h = x.shape
@@ -252,9 +261,11 @@ def _block(x, bp, cos, sin, positions, mask, config: TransformerConfig):
     v = (y @ bp["wv"].astype(c.dtype)).reshape(b, s, c.num_kv_heads, hd)
     q = with_logical_constraint(q, ("batch", "seq", "heads", None))
     k = with_logical_constraint(k, ("batch", "seq", "heads", None))
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
-    attn = _attention(q, k, v, mask, c)
+    if not _ropes_in_flash(c):
+        from ray_tpu.ops.attention import rope_reference
+
+        q, k, rope = rope_reference(q, *rope), rope_reference(k, *rope), None
+    attn = _attention(q, k, v, mask, c, rope)
     attn = attn.reshape(b, s, c.num_heads * hd)
     attn_proj = checkpoint_name(
         attn @ bp["wo"].astype(c.dtype), "attn_proj")
@@ -306,8 +317,8 @@ def forward_hidden(params: Dict[str, Any], tokens,
     mask = jnp.tril(jnp.ones((s, s), dtype=bool))[None, None, :, :]
 
     block_fn = _maybe_remat(
-        partial(_block, cos=cos, sin=sin, positions=positions,
-                mask=mask, config=c), c)
+        partial(_block, rope=(cos[positions], sin[positions]), mask=mask,
+                config=c), c)
 
     aux_total = jnp.zeros((), jnp.float32)
     if c.scan_layers:
@@ -379,7 +390,7 @@ def forward_pipelined(params: Dict[str, Any], tokens,
         positions = jnp.broadcast_to(
             jnp.arange(s, dtype=jnp.int32), (mb, s))
         block = _maybe_remat(
-            partial(_block, cos=cos, sin=sin, positions=positions,
+            partial(_block, rope=(cos[positions], sin[positions]),
                     mask=mask, config=c), c)
 
         def scan_body(carry, layer_params):

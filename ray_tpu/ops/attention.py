@@ -28,6 +28,12 @@ machinery is absent in-tree); on TPU this is a core op.  Design:
     tile, which is exact; tile and block sizes come from `default_blocks`
     unless passed.  The comment above `_scale_is_exact` has the reasons,
     `dispatch.taken()["flash_attention.plan"]` what ran.
+  - `rope=(cos, sin)`: the kernels apply the rotary embedding themselves,
+    to the q and k tiles as they load them and its transpose to the
+    float32 sums of dq and dk before their one rounding, so q and k go
+    from their projections to the call un-roped and no float32 copy of
+    either crosses HBM (`flash_attention`'s docstring; the XLA paths rope
+    with `rope_reference`).
   - CPU / odd-shape fallback: `attention_reference` with identical
     semantics — the numerical ground truth in tests (which compare both
     paths in interpret mode, values and grads).
@@ -256,6 +262,48 @@ def _scaled(x, sm_scale: float):
     return (x.astype(jnp.float32) * sm_scale).astype(x.dtype)
 
 
+def rope_reference(x, cos, sin):
+    """Rotary embedding in XLA.  x: [b, s, heads, d]; cos, sin: [b, s, d/2]
+    float32, gathered at the rows' positions.  Float32 arithmetic, rounded
+    once to x's dtype."""
+    c, s = cos[:, :, None, :], sin[:, :, None, :]
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    return out.astype(x.dtype)
+
+
+def _widen_rope(rope):
+    """(cos, sin) [b, s, d/2] -> [b, s, d] float32 as the kernels take
+    them: cos twice, and the sine with rotate_half's sign folded in, so
+    that rope(x) = x * cos + swap_halves(x) * sin."""
+    def twice(t, signs):      # a broadcast, where a concatenate would pad
+        t = t.astype(jnp.float32)[:, :, None, :] * jnp.asarray(
+            signs, jnp.float32)[:, None]
+        return t.reshape(*t.shape[:2], -1)
+
+    cos, sin = rope
+    return twice(cos, (1.0, 1.0)), twice(sin, (-1.0, 1.0))
+
+
+def _swap_halves(x):
+    """[rows, d] with the two halves of d exchanged (rotate_half without its
+    sign, which `_widen_rope` folds into the sine), in VMEM: two lane
+    slices and a concatenate, exact in any dtype.  Of the forms Mosaic
+    takes on the v5e this one timed fastest (PERF.md, PR 33: a matmul with
+    the d x d permutation needs the MXU's full-precision passes on the
+    float32 sums and added 0.93 ms to a backward call at 160 x 2048 x 64,
+    this 0.40; a lane roll of bfloat16 is not implemented)."""
+    half = x.shape[-1] // 2
+    return jnp.concatenate([x[:, half:], x[:, :half]], axis=1)
+
+
+def _roped(x, cos, sin):
+    """The rotary embedding of a [rows, d] block, float32; cos, sin: the
+    widened tables' rows.  With -sin it is the transpose, for a gradient."""
+    return (x.astype(jnp.float32) * cos
+            + _swap_halves(x).astype(jnp.float32) * sin)
+
+
 def _put(old, at: int, new):
     """`old` [.., queries] with its queries from `at` on (static) replaced."""
     return jnp.concatenate([old[:, :at], new], axis=1) if at else new
@@ -314,15 +362,40 @@ def _walk_blocks(step, carry, causal: bool, tile_min, tile: int, inner: int,
 # Pallas forward kernel (offset-aware, emits logsumexp)
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, *refs,
                 causal: bool, block_q: int, block_k: int, seq_k: int,
-                sm_scale: float, fold_scale: bool, windowed: bool = False):
+                sm_scale: float, fold_scale: bool, windowed: bool = False,
+                rope_q0: Optional[int] = None):
+    """rope_q0 (None: no rope, the kernel without): the row of the tables,
+    which hold the KEYS' positions, at which the queries' begin.  Then refs
+    holds the two tables before the outputs and, after them, a scratch for
+    the head's roped keys."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     window = offs_ref[2] if windowed else None
     q = q_ref[0]  # [block_q, d]
     d = q.shape[-1]
+    if rope_q0 is None:
+        o_ref, lse_ref = refs
+    else:
+        cos_ref, sin_ref, o_ref, lse_ref, roped_k_ref = refs
+
+        # A head's keys are roped once, at its first query tile, and stay
+        # in scratch for the others (the axis runs in order, as the
+        # backward's sum of dq needs it to).
+        plain_k_ref, k_ref = k_ref, roped_k_ref
+
+        @pl.when(qi == 0)
+        def _():
+            k_ref[0] = _roped(plain_k_ref[0], cos_ref[0],
+                              sin_ref[0]).astype(k_ref.dtype)
+
+        rows = pl.ds(pl.multiple_of(rope_q0 + qi * block_q,
+                                    math.gcd(rope_q0, block_q)), block_q)
+        # rounded to the operand's dtype before the scale and any matmul,
+        # as rope in XLA rounds it
+        q = _roped(q, cos_ref[0, rows, :], sin_ref[0, rows, :]).astype(q.dtype)
     if fold_scale:
         q = _scaled(q, sm_scale)
     query_minus_key = _query_minus_key(block_k, block_q) if causal else None
@@ -383,12 +456,26 @@ def _compiler_params(vmem_mib: int = 32):
     return pltpu.CompilerParams(vmem_limit_bytes=vmem_mib << 20)
 
 
+def _rope_operands(rope, heads: int, seq_k: int, d: int):
+    """(operands, their BlockSpecs) of the widened tables [b, seq_k, d]: a
+    row's whole table, whose block index does not move across the row's
+    heads or tiles, so the pipeline loads it once a row."""
+    from jax.experimental import pallas as pl
+
+    if rope is None:
+        return (), []
+    spec = pl.BlockSpec((1, seq_k, d), lambda g, i, offs: (g // heads, 0, 0))
+    return tuple(rope), [spec, spec]
+
+
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
                block_q: int, block_k: int,
                fold_scale: Optional[bool] = None,
-               windowed: bool = False):
-    """fold_scale is for the tests alone (None: fold when exact).
+               windowed: bool = False, rope=None):
+    """fold_scale is for the tests alone (None: fold when exact).  rope:
+    None or the widened tables (`_widen_rope`); the kernel then ropes q
+    and k as it loads them.
 
     Jitted so that one trace serves both of a train step's calls (the
     primal under jax.checkpoint and the custom VJP's forward rule) and one
@@ -410,7 +497,9 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
     kernel = functools.partial(
         _fwd_kernel, causal=causal, block_q=block_q, block_k=block_k,
         seq_k=sk, sm_scale=sm_scale, fold_scale=fold_scale,
-        **({"windowed": True} if windowed else {}))
+        **({"windowed": True} if windowed else {}),
+        **({} if rope is None else {"rope_q0": sk - sq}))
+    tables, table_specs = _rope_operands(rope, h, sk, d)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -420,11 +509,14 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
                 pl.BlockSpec((1, block_q, d), lambda bh, i, offs: (bh, i, 0)),
                 pl.BlockSpec((1, sk, d), lambda bh, i, offs: (bh, 0, 0)),
                 pl.BlockSpec((1, sk, d), lambda bh, i, offs: (bh, 0, 0)),
+                *table_specs,
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, d), lambda bh, i, offs: (bh, i, 0)),
                 pl.BlockSpec((1, 8, block_q), lambda bh, i, offs: (bh, 0, i)),
             ],
+            scratch_shapes=([] if rope is None
+                            else [pltpu.VMEM((1, sk, d), k.dtype)]),
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
@@ -433,7 +525,7 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
         compiler_params=_compiler_params(),
         interpret=dispatch.interpret_mode(),
         name="flash_fwd",
-    )(offs, qf, kf, vf)
+    )(offs, qf, kf, vf, *tables)
     out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     return out, lse[:, 0, :]  # lse: [bh, sq]
 
@@ -466,9 +558,15 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
 # ---------------------------------------------------------------------------
 
 def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
-                dq_ref, dk_ref, dv_ref, kt_ref, dqt_ref, *, causal: bool,
+                *refs, causal: bool,
                 block_q: int, block_k: int, seq_q: int, sm_scale: float,
-                fold_scale: bool, windowed: bool = False):
+                fold_scale: bool, windowed: bool = False,
+                roped: bool = False):
+    """roped: refs holds the two tables (the KEYS' positions; the queries'
+    are their last seq_q rows) before the outputs and, after the scratch,
+    one more for the head's roped q.  dq and dk are then the gradients of
+    the UN-roped q and k: rope's transpose goes on the float32 sums, before
+    their one rounding."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
@@ -476,6 +574,15 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
     k = k_ref[0]                              # [block_k, d] native dtype
     v = v_ref[0]
     d = k.shape[-1]
+    if roped:
+        cos_ref, sin_ref, dq_ref, dk_ref, dv_ref, kt_ref, dqt_ref, \
+            roped_q_ref = refs
+        k_rows = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+        q_rows = slice(cos_ref.shape[1] - seq_q, cos_ref.shape[1])
+        k = _roped(k, cos_ref[0, k_rows, :],
+                   sin_ref[0, k_rows, :]).astype(k.dtype)
+    else:
+        dq_ref, dk_ref, dv_ref, kt_ref, dqt_ref = refs
     k_s = _scaled(k, sm_scale) if fold_scale else k
     # k^T for dq, turned once a program into scratch: the steps' matmuls
     # read it as a plain operand (a transpose that feeds the MXU directly,
@@ -490,6 +597,12 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
     @pl.when(ki == 0)
     def _():
         dqt_ref[...] = jnp.zeros(dqt_ref.shape, dqt_ref.dtype)
+        if roped:       # the head's q, roped once for all its key tiles
+            roped_q_ref[0] = _roped(
+                q_ref[0], cos_ref[0, q_rows, :],
+                sin_ref[0, q_rows, :]).astype(roped_q_ref.dtype)
+
+    q_ref = roped_q_ref if roped else q_ref     # what the steps read
 
     def step(start, first, carry, hi: int):
         """The query block at `start` against keys [0, hi) of the tile (the
@@ -548,12 +661,17 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
     dk, dv = carry
     if fold_scale:
         dk = dk * sm_scale
+    if roped:
+        dk = _roped(dk, cos_ref[0, k_rows, :], -sin_ref[0, k_rows, :])
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
     @pl.when(ki == pl.num_programs(1) - 1)
     def _():
-        dq_ref[0] = dqt_ref[...].T.astype(dq_ref.dtype)
+        dq = dqt_ref[...].T
+        if roped:
+            dq = _roped(dq, cos_ref[0, q_rows, :], -sin_ref[0, q_rows, :])
+        dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _lse8(x, bh, s):
@@ -562,7 +680,7 @@ def _lse8(x, bh, s):
 
 
 def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
-               blocks, windowed=False):
+               blocks, windowed=False, rope=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -584,28 +702,38 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
     seq_spec = pl.BlockSpec((1, 8, sq), lambda g, i, offs: (g, 0, 0))
     full_q = pl.BlockSpec((1, sq, d), lambda g, i, offs: (g, 0, 0))
     k_tile = pl.BlockSpec((1, block_k, d), lambda g, i, offs: (g, i, 0))
+    tables, table_specs = _rope_operands(rope, h, sk, d)
+    # A float32 table block as VMEM holds it (128 lanes): two tables, each
+    # double-buffered, the roped q and the float32 dq being roped come to
+    # under six of them (58.0 MiB needed at 8192 x 128; at 2048 x 64 the
+    # 48 hold).
+    table_mib = -(-sk * max(d, 128) * 4 // 2 ** 20)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, causal=causal, block_q=block_q,
                           block_k=block_k, seq_q=sq, sm_scale=sm_scale,
                           fold_scale=_scale_is_exact(sm_scale),
-                          **({"windowed": True} if windowed else {})),
+                          **({"windowed": True} if windowed else {}),
+                          **({} if rope is None else {"roped": True})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, sk // block_k),
-            in_specs=[full_q, k_tile, k_tile, full_q, seq_spec, seq_spec],
+            in_specs=[full_q, k_tile, k_tile, full_q, seq_spec, seq_spec,
+                      *table_specs],
             out_specs=[full_q, k_tile, k_tile],
             scratch_shapes=[pltpu.VMEM((d, block_k), k.dtype),
-                            pltpu.VMEM((d, sq), jnp.float32)],
+                            pltpu.VMEM((d, sq), jnp.float32)]
+            + ([] if rope is None else [pltpu.VMEM((1, sq, d), q.dtype)]),
         ),
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
-        compiler_params=_compiler_params(48),
+        compiler_params=_compiler_params(
+            48 if rope is None else max(48, 40 + 6 * table_mib)),
         interpret=dispatch.interpret_mode(),
         name="flash_bwd",
-    )(offs, qf, kf, vf, dof, lse8, corr8)
+    )(offs, qf, kf, vf, dof, lse8, corr8, *tables)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     dk = dk.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
@@ -618,15 +746,15 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
 # forward and of the backward, in that order, then the window (or None).
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash_lse(q, k, v, offs, causal, sm_scale, blocks):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_lse(q, k, v, offs, rope, causal, sm_scale, blocks):
     return _flash_fwd(q, k, v, offs, causal, sm_scale, *blocks[0], None,
-                      blocks[2] is not None)
+                      blocks[2] is not None, rope)
 
 
-def _flash_lse_fwd(q, k, v, offs, causal, sm_scale, blocks):
+def _flash_lse_fwd(q, k, v, offs, rope, causal, sm_scale, blocks):
     out, lse = _flash_fwd(q, k, v, offs, causal, sm_scale, *blocks[0], None,
-                          blocks[2] is not None)
+                          blocks[2] is not None, rope)
     # Named residuals: under jax.checkpoint with
     # save_only_these_names("attn_out", "attn_lse") (the transformer's
     # "save_attn" remat policy) the kernel outputs are kept from the
@@ -639,16 +767,17 @@ def _flash_lse_fwd(q, k, v, offs, causal, sm_scale, blocks):
     v_r = checkpoint_name(v, "attn_v")
     out_r = checkpoint_name(out, "attn_out")
     lse_r = checkpoint_name(lse, "attn_lse")
-    return (out, lse), (q_r, k_r, v_r, out_r, lse_r, offs)
+    return (out, lse), (q_r, k_r, v_r, out_r, lse_r, offs, rope)
 
 
 def _flash_lse_bwd(causal, sm_scale, blocks, res, cts):
-    q, k, v, out, lse, offs = res
+    q, k, v, out, lse, offs, rope = res
     dout, dlse = cts
     dq, dk, dv = _flash_bwd(q, k, v, out, lse, offs, dout, dlse,
                             causal, sm_scale, blocks[1],
-                            blocks[2] is not None)
-    return dq, dk, dv, None  # offs (int positions) has no gradient
+                            blocks[2] is not None, rope)
+    # offs (int positions) has no gradient, the tables are constants
+    return dq, dk, dv, None, None
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -659,14 +788,15 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 # ---------------------------------------------------------------------------
 
 def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
-                 seq_q: int, seq_k: int, blocks) -> None:
+                 seq_q: int, seq_k: int, blocks, roped: bool) -> None:
     """Say in `dispatch.taken()` what the kernels were built to do: each
     kernel's (block_q x block_k), that dq comes out of the backward's one
     pass and over how many key tiles it is summed there, whether the scale
     left the score tile, and what share of each kernel's computed scores
     no query may see (known here only when the offsets are static; a
     traced offset decides it at run time); under a window also the window
-    and the share of the forward's (tile, block) pairs that it visits."""
+    and the share of the forward's (tile, block) pairs that it visits;
+    `rope_in_kernel` when the kernels rope q and k themselves."""
     (fq, fk), (kv_q, kv_k), window = blocks
     static = isinstance(q_off, int) and isinstance(kv_off, int)
     if not causal:
@@ -692,21 +822,29 @@ def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
                                       window)
             plan += ",visited%.1f%%" % (
                 100.0 * visited / ((seq_q // fq) * (seq_k // fk)))
+    if roped:
+        plan += ",rope_in_kernel"
     dispatch.record("flash_attention.plan", plan)
 
 
-def _chunk(q, k, v, q_off, kv_off, causal, sm_scale, blocks, window=None):
+def _chunk(q, k, v, q_off, kv_off, causal, sm_scale, blocks, window=None,
+           rope=None):
+    """rope: None, or (cos, sin) [b, seq_k, d/2] at the KEYS' positions (the
+    queries' are the last seq_q rows): attention over rope(q), rope(k), the
+    kernels roping the tiles they load."""
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
     blocks = (*blocks, window)
     _record_plan(q_off, kv_off, causal, sm_scale, q.shape[1], k.shape[1],
-                 blocks)
+                 blocks, rope is not None)
+    if rope is not None:
+        rope = _widen_rope(rope)
     # Under a window the scalars are [q_off, kv_off, window]: the kernels
     # read the window there, and a windowed call shows in a trace by its
     # first operand, s32[3] (the benchmark's swa reader finds it so).
     offs = jnp.stack([jnp.asarray(x, jnp.int32) for x in
                       (q_off, kv_off) + (() if window is None else (window,))])
-    return _flash_lse(q, k, v, offs, causal, sm_scale, blocks)
+    return _flash_lse(q, k, v, offs, rope, causal, sm_scale, blocks)
 
 
 def flash_attention_chunk(q, k, v, q_off, kv_off, causal: bool = True,
@@ -728,8 +866,24 @@ def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, rope=None):
     """Tiled attention. q:[b,s,h,d], k/v:[b,t,h,d] -> [b,s,h,d].
+
+    rope=(cos, sin): attention over rope(q), rope(k), for q and k as they
+    come from their projections.  The tables are float32 [b, t, d/2],
+    gathered at the KEYS' positions (`cos[positions]`); the queries take
+    their last s rows (ends aligned, as the causal mask is).  The Pallas
+    kernels, on the TPU and interpreted, rope the q and k tiles as they
+    load them (float32, rounded to the operands' dtype before the scale
+    and any matmul, which is where rope in XLA rounds) and apply rope's
+    transpose to their float32 sums of dq and dk before those are rounded,
+    so that q and k cross HBM once, un-roped, and the gradients with
+    respect to them come out of the one call; the plan then says
+    `rope_in_kernel`.  The XLA fallback, and a call whose queries begin at
+    a row of the tables that is no multiple of 8, rope with
+    `rope_reference` first and go on as without.  rope=None (a model
+    without rotary positions, or one that ropes elsewhere) builds exactly
+    the kernels without.
 
     window (causal only): query t sees keys s with 0 <= t - s < window.
     The kernels do not visit the blocks wholly behind a tile's window
@@ -766,19 +920,28 @@ def flash_attention(q, k, v, causal: bool = True,
         blocks = default_blocks(d, sq, sk, q.dtype, window)
     else:
         blocks = ((min(block_q or 512, sq), min(block_k or 512, sk)),) * 2
-    if not all(_can_use_pallas(sq, sk, d, bq, bk) for bq, bk in blocks):
+    pallas = all(_can_use_pallas(sq, sk, d, bq, bk) for bq, bk in blocks)
+    # The kernels read the queries' rows of the tables at (sk - sq) on:
+    # float32 rows come in sublanes of 8.
+    if rope is not None and not (pallas and (sk - sq) % 8 == 0):
+        cos, sin = rope
+        q = rope_reference(q, cos[:, sk - sq:], sin[:, sk - sq:])
+        k = rope_reference(k, cos, sin)
+        rope = None
+    if not pallas:
         dispatch.record("flash_attention", "xla")
         return attention_reference(q, k, v, causal, sm_scale, window)
     dispatch.record("flash_attention", "interpret"
                     if dispatch.interpret_mode() else "pallas")
+    tables = () if rope is None else tuple(rope)
 
-    def kernel(q, k, v):
+    def kernel(q, k, v, *tables):
         return _chunk(q, k, v, sk - sq, 0, causal, sm_scale, blocks,
-                      window)[0]
+                      window, tables or None)[0]
 
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or mesh.size == 1:
-        return kernel(q, k, v)
+        return kernel(q, k, v, *tables)
     from jax.sharding import PartitionSpec as P
 
     # An axis shards a dim only where it divides it; otherwise that dim
@@ -790,5 +953,8 @@ def flash_attention(q, k, v, causal: bool = True,
     heads = "tensor" if ("tensor" in sizes
                          and q.shape[2] % sizes["tensor"] == 0) else None
     spec = P(batch or None, None, heads, None)
-    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
+    table_spec = P(batch or None, None, None)
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(spec, spec, spec) + (table_spec,) * len(tables),
+        out_specs=spec, check_vma=False)(q, k, v, *tables)

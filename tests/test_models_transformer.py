@@ -127,3 +127,100 @@ def test_llama2_7b_compiles_at_shape():
         lambda p, b: jax.grad(lambda q: tfm.loss_fn(q, b, config))(p),
         param_shapes, batch)
     assert grad_shapes["tok_embed"].shape == (32000, 4096)
+
+
+# ---------------------------------------------------------------------------
+# Rope inside the flash kernels: `_block` hands q and k as projected where
+# flash_attention will run, and ropes them itself everywhere else
+# ---------------------------------------------------------------------------
+
+def _flash_and_plain(**kw):
+    """The tiny model twice: through flash_attention (rope in the kernels)
+    and with use_flash=False (rope in XLA, plain attention)."""
+    base = dict(dtype=jnp.float32, max_seq_len=256, head_dim=32, **kw)
+    return (tfm.TransformerConfig.tiny(use_flash=True, **base),
+            tfm.TransformerConfig.tiny(use_flash=False, **base))
+
+
+def _new_flash_plans(before):
+    from ray_tpu.ops import dispatch
+
+    return [p for p, n in dispatch.taken().get(
+        "flash_attention.plan", {}).items()
+        if n > before.get("flash_attention.plan", {}).get(p, 0)]
+
+
+@pytest.mark.parametrize("remat_policy", ["full", "save_attn"])
+@pytest.mark.parametrize("kv_heads", [4, 2])      # without GQA's repeat, with
+def test_loss_and_gradients_with_rope_in_the_kernel_match_the_plain_path(
+        monkeypatch, kv_heads, remat_policy):
+    from ray_tpu.ops import dispatch
+
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    flash, plain = _flash_and_plain(num_kv_heads=kv_heads,
+                                    remat_policy=remat_policy)
+    params = tfm.init_params(flash, jax.random.PRNGKey(3))
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(4), (2, 257), 0,
+                                          flash.vocab_size)}
+    before = dispatch.taken()
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: tfm.loss_fn(p, batch, flash)))(params)
+    plans = _new_flash_plans(before)
+    assert plans and all(p.endswith(",rope_in_kernel") for p in plans), plans
+    before = dispatch.taken()
+    loss_p, grads_p = jax.jit(jax.value_and_grad(
+        lambda p: tfm.loss_fn(p, batch, plain)))(params)
+    assert not _new_flash_plans(before)
+    np.testing.assert_allclose(float(loss), float(loss_p), rtol=1e-5)
+    for (path, g), g_p in zip(jax.tree_util.tree_leaves_with_path(grads),
+                              jax.tree.leaves(grads_p)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(g_p), atol=2e-5,
+                                   rtol=2e-3, err_msg=str(path))
+
+
+def test_positions_reach_the_kernels_tables(monkeypatch):
+    """forward() at positions that differ by row, do not start at 0 and, in
+    one row, step by 2 (rope is relative: a row moved as a whole attends as
+    before): the kernels' tables are gathered at them, as rope in XLA's."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    flash, plain = _flash_and_plain()
+    params = tfm.init_params(flash, jax.random.PRNGKey(5))
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (2, 128), 0,
+                                flash.vocab_size)
+    positions = jnp.stack([2 * jnp.arange(128) + 1, jnp.arange(128) + 100])
+    got = tfm.forward(params, tokens, flash, positions=positions)
+    want = tfm.forward(params, tokens, plain, positions=positions)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4,
+                               rtol=2e-4)
+    moved = tfm.forward(params, tokens, flash)
+    assert np.abs(np.asarray(moved) - np.asarray(want)).max() > 1e-2
+
+
+def test_pipelined_forward_and_eval_step_run_the_same_block(monkeypatch):
+    """forward_pipelined (its stage function calls `_block`) and
+    ShardedTrainStep.eval_step, each through the roped kernels, against
+    the plain path."""
+    from ray_tpu.ops import dispatch
+    from ray_tpu.train.train_state import ShardedTrainStep
+
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    flash, plain = _flash_and_plain()
+    params = tfm.init_params(flash, jax.random.PRNGKey(7))
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(8), (4, 129), 0,
+                                          flash.vocab_size)}
+    mesh = mesh_lib.build_mesh(axes={"stage": 2},
+                               devices=jax.devices()[:2])
+    before = dispatch.taken()
+    with jax.sharding.set_mesh(mesh):
+        piped = jax.jit(lambda p: tfm.loss_fn_pipelined(
+            p, batch, flash, 2, mesh=mesh))(params)
+    assert all(p.endswith(",rope_in_kernel")
+               for p in _new_flash_plans(before))
+    want = float(tfm.loss_fn(params, batch, plain))
+    np.testing.assert_allclose(float(piped), want, rtol=1e-5)
+
+    one = mesh_lib.build_mesh(axes={"data": 1}, devices=jax.devices()[:1])
+    before = dispatch.taken()
+    got = ShardedTrainStep(flash, one).eval_step(params, batch)
+    assert _new_flash_plans(before)
+    np.testing.assert_allclose(float(got), want, rtol=1e-5)

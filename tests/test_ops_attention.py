@@ -351,15 +351,9 @@ def test_plan_is_recorded_beside_the_path():
     q, k, v = _rand_qkv(8, 1, 2048, 1, 64)
     before = dispatch.taken()
     jax.make_jaxpr(lambda q, k, v: attn.flash_attention(q, k, v))(q, k, v)
-    after = dispatch.taken()
-
-    def new(op):
-        return {p: n - before.get(op, {}).get(p, 0)
-                for p, n in after.get(op, {}).items()
-                if n - before.get(op, {}).get(p, 0)}
-
-    assert new("flash_attention") == {"interpret": 1}
-    assert new("flash_attention.plan") == {
+    new = _new_plans(before)
+    assert new["flash_attention"] == {"interpret": 1}
+    assert new["flash_attention.plan"] == {
         "fwd2048x512,bwd512x2048,dq_in_pass,scale_folded,dead20/20%": 1}
     # a traced offset (ring attention) and head size 128
     q, k, v = _rand_qkv(9, 1, 128, 1, 128)
@@ -648,3 +642,274 @@ def test_one_backward_kernel_gives_dq_dk_dv_in_the_operands_dtype(key_tiles):
     for out, seq in zip(backward[0].outvars, (sq, sk, sk)):
         assert out.aval.dtype == jnp.bfloat16
         assert out.aval.shape == (2, seq, 64)
+
+
+# ---------------------------------------------------------------------------
+# Rope inside the kernels (rope=): q and k as projected, roped where the
+# tiles are loaded; against rope in XLA before the same kernels
+# ---------------------------------------------------------------------------
+
+def _rope_tables(b, sk, d, starts=(3, 500)):
+    """(cos, sin) [b, sk, d/2] float32 at positions that differ by row and
+    do not start at 0, each value cut to the eight bits a bfloat16 holds:
+    a bfloat16 operand times such a value is exact in float32, so x * cos
+    + y * sin is rounded once whether or not the CPU's compiler fuses the
+    multiply into the add (it does in one program and not in the other,
+    which moves one rounding in 2 ** 16 of bfloat16 values; the TPU's vector
+    unit has no such fused form to choose).  The bit-for-bit tests below
+    test the kernels, not the host's code generator."""
+    inv = 1.0 / (10000.0 ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    pos = (jnp.asarray(starts[:b], jnp.float32)[:, None]
+           + jnp.arange(sk, dtype=jnp.float32)[None, :])
+    freqs = pos[:, :, None] * inv
+    return tuple(t.astype(jnp.bfloat16).astype(jnp.float32)
+                 for t in (jnp.cos(freqs), jnp.sin(freqs)))
+
+
+def _rope_outside(rope, sq):
+    """q, k -> rope in XLA, the queries on the tables' last sq rows."""
+    cos, sin = rope
+    sk = cos.shape[1]
+    return (lambda q: attn.rope_reference(q, cos[:, sk - sq:],
+                                          sin[:, sk - sq:]),
+            lambda k: attn.rope_reference(k, cos, sin))
+
+
+# id: (sq, sk, d, block_q, block_k, window, causal)
+_ROPES = {
+    "d64": (256, 256, 64, 128, 128, None, True),
+    "d128": (256, 256, 128, 128, 128, None, True),
+    "fewer_queries_than_keys": (128, 384, 64, 128, 128, None, True),
+    "fewer_queries_d128_two_query_tiles": (256, 512, 128, 128, 256, None,
+                                           True),
+    "several_key_tiles_narrow_forward": (512, 512, 64, 256, 128, None, True),
+    "narrow_backward": (512, 512, 64, 128, 512, None, True),
+    "window": (512, 512, 64, 128, 128, 200, True),
+    "window_fewer_queries": (256, 768, 128, 128, 128, 200, True),
+    "default_blocks": (1024, 1024, 64, None, None, None, True),
+    "not_causal": (256, 512, 64, 128, 128, None, False),
+}
+
+
+def _rope_case(name, dtype):
+    sq, sk, d, bq, bk, window, causal = _ROPES[name]
+    b, h = 2, 2
+    ks = jax.random.split(jax.random.PRNGKey(len(name) + d), 4)
+    q, w = (jax.random.normal(x, (b, sq, h, d), jnp.float32).astype(dtype)
+            for x in ks[:2])
+    k, v = (jax.random.normal(x, (b, sk, h, d), jnp.float32).astype(dtype)
+            for x in ks[2:])
+    kw = dict(causal=causal, block_q=bq, block_k=bk, window=window)
+    return q, k, v, w, _rope_tables(b, sk, d), kw
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("name", sorted(_ROPES))
+def test_rope_in_kernel_forward_is_bit_for_bit_rope_in_xla(name, dtype):
+    """out AND lse: the kernels rope in float32 and round to the operand's
+    dtype before the scale and the first matmul, which is where rope in
+    XLA rounds."""
+    q, k, v, _, rope, kw = _rope_case(name, dtype)
+    sq, sk, d = q.shape[1], k.shape[1], q.shape[-1]
+    window = kw["window"]
+    blocks = ((kw["block_q"], kw["block_k"]),) * 2 if kw["block_q"] \
+        else attn.default_blocks(d, sq, sk, dtype, window)
+    rope_q, rope_k = _rope_outside(rope, sq)
+
+    def chunk(q, k, rope):
+        return attn._chunk(q, k, v, sk - sq, 0, kw["causal"], d ** -0.5,
+                           blocks, window, rope)
+
+    out, lse = chunk(q, k, rope)
+    out_x, lse_x = chunk(rope_q(q), rope_k(k), None)
+    assert out.dtype == dtype
+    if dtype == jnp.float32:
+        # float32 operands: the products are not exact, and the host's
+        # fused multiply-adds move the last bit (see _rope_tables)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(out_x),
+                                   atol=2e-6, rtol=2e-6)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_x),
+                                   atol=2e-6, rtol=2e-6)
+    else:
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(out_x, np.float32))
+        np.testing.assert_array_equal(np.asarray(lse), np.asarray(lse_x))
+    # and the public call gives that out
+    np.testing.assert_array_equal(
+        np.asarray(attn.flash_attention(q, k, v, rope=rope, **kw),
+                   np.float32), np.asarray(out, np.float32))
+
+
+@pytest.mark.parametrize("name", sorted(_ROPES))
+def test_rope_in_kernel_gradients_lose_a_rounding_not_gain_one(name):
+    """bfloat16 operands, gradients with respect to the UN-roped q and k.
+    Rope in XLA rounds the kernel's dq and dk to bfloat16, turns them back
+    through rope in float32 and rounds again; the kernel turns its float32
+    sums and rounds once.  So against the float32 reference's gradients
+    the kernel's are no further off than today's, and the two differ by a
+    bfloat16 rounding of the largest value at most.  dv does not meet
+    rope: bit for bit."""
+    q, k, v, w, rope, kw = _rope_case(name, jnp.bfloat16)
+    sq, d = q.shape[1], q.shape[-1]
+    rope_q, rope_k = _rope_outside(rope, sq)
+    w32 = w.astype(jnp.float32)
+
+    def grads(fn, *xs):
+        return jax.grad(lambda q, k, v: jnp.sum(
+            fn(q, k, v).astype(jnp.float32) * w32), argnums=(0, 1, 2))(*xs)
+
+    inside = grads(lambda q, k, v: attn.flash_attention(
+        q, k, v, rope=rope, **kw), q, k, v)
+    outside = grads(lambda q, k, v: attn.flash_attention(
+        rope_q(q), rope_k(k), v, **kw), q, k, v)
+    masked = _masked_reference(sq, k.shape[1], d, kw["causal"], kw["window"])
+    exact = grads(lambda q, k, v: masked(rope_q(q), rope_k(k), v),
+                  *(x.astype(jnp.float32) for x in (q, k, v)))
+    np.testing.assert_array_equal(np.asarray(inside[2], np.float32),
+                                  np.asarray(outside[2], np.float32))
+    for got, today, ref in zip(inside[:2], outside[:2], exact[:2]):
+        assert got.dtype == jnp.bfloat16
+        got, today, ref = (np.asarray(x, np.float32)
+                           for x in (got, today, ref))
+        top = np.abs(ref).max()
+        assert np.abs(got - today).max() <= 2.0 ** -7 * top
+        assert np.abs(got - ref).max() <= 2.0 ** -5 * top
+
+        def rms(x):
+            return float(np.sqrt(np.mean(x * x)))
+
+        assert rms(got - ref) <= 1.01 * rms(today - ref), (
+            rms(got - ref), rms(today - ref))
+
+
+@pytest.mark.parametrize("name", ["d64", "d128", "fewer_queries_than_keys",
+                                  "window"])
+def test_rope_in_kernel_float32_gradients_match_the_reference(name):
+    q, k, v, w, rope, kw = _rope_case(name, jnp.float32)
+    sq, d = q.shape[1], q.shape[-1]
+    rope_q, rope_k = _rope_outside(rope, sq)
+    masked = _masked_reference(sq, k.shape[1], d, kw["causal"], kw["window"])
+    out, g = _grads_and_value(lambda q, k, v: attn.flash_attention(
+        q, k, v, rope=rope, **kw), q, k, v, w)
+    ref, g_ref = _grads_and_value(
+        lambda q, k, v: masked(rope_q(q), rope_k(k), v), q, k, v, w)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    for a, r in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   atol=5e-4, rtol=5e-4)
+
+
+def _new_plans(before):
+    from ray_tpu.ops import dispatch
+
+    after = dispatch.taken()
+    return {op: {p: n - before.get(op, {}).get(p, 0)
+                 for p, n in after.get(op, {}).items()
+                 if n - before.get(op, {}).get(p, 0)}
+            for op in ("flash_attention", "flash_attention.plan")}
+
+
+@pytest.mark.parametrize("shape,blocks,path", [
+    ((1, 100, 2, 32), {"block_k": 64}, "xla"),  # a block that does not
+    ((1, 256, 2, 64), {"block_q": 96}, "xla"),  # divide the sequence
+    ((1, 132, 2, 64), {}, "interpret")])        # queries begin at row 4
+def test_rope_outside_the_kernels_where_they_cannot_take_it(shape, blocks,
+                                                            path):
+    """The XLA fallback ropes with rope_reference and goes on as without;
+    so does a kernel call whose queries begin at a row of the tables that
+    is no multiple of 8 (128 queries against 132 keys).  Neither plan says
+    rope_in_kernel."""
+    from ray_tpu.ops import dispatch
+
+    b, sk, h, d = shape
+    sq = 128 if sk == 132 else sk
+    ks = jax.random.split(jax.random.PRNGKey(sk), 3)
+    q = jax.random.normal(ks[0], (b, sq, h, d), jnp.float32)
+    k, v = (jax.random.normal(x, shape, jnp.float32) for x in ks[1:])
+    rope = _rope_tables(b, sk, d)
+    rope_q, rope_k = _rope_outside(rope, sq)
+    before = dispatch.taken()
+    out = attn.flash_attention(q, k, v, rope=rope, **blocks)
+    new = _new_plans(before)
+    assert new["flash_attention"] == {path: 1}
+    assert not any("rope_in_kernel" in p for p in new["flash_attention.plan"])
+    ref = attn.attention_reference(rope_q(q), rope_k(k), v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("roped", [False, True])
+def test_rope_is_seen_in_the_input_plan_and_operands(roped):
+    """rope=None builds exactly the kernels without: four and seven
+    operands, two scratch buffers in the backward, none in the forward, a
+    plan without the token.  rope=(cos, sin): the two float32 tables
+    [b, sk, d] come LAST (a trace's face of the call, result and first
+    operand, does not move), their block index is the row's for every head
+    and tile of it, one scratch more in each kernel, and the plan says
+    rope_in_kernel."""
+    from ray_tpu.ops import dispatch
+
+    b, s, h, d = 2, 512, 4, 64
+    x = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+    t = jax.ShapeDtypeStruct((b, s, d // 2), jnp.float32)
+
+    def loss(q, k, v, cos, sin):
+        return attn.flash_attention(
+            q, k, v, block_q=128, block_k=256,
+            rope=(cos, sin) if roped else None).astype(jnp.float32).sum()
+
+    before = dispatch.taken()
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x, t, t)
+    plans = _new_plans(before)["flash_attention.plan"]
+    token = ",rope_in_kernel" if roped else ""
+    assert list(plans) == [
+        "fwd128x256,bwd128x256,dq_in_pass,dq_over2tiles,scale_folded,"
+        "dead33/20%" + token]
+    fwd, bwd = sorted(_pallas_calls(jaxpr.jaxpr),
+                      key=lambda c: len(c.outvars))
+    extra = 2 if roped else 0
+    assert len(fwd.invars) == 4 + extra and len(bwd.invars) == 7 + extra
+    for call, scratch in ((fwd, 0), (bwd, 2)):
+        mapping = call.params["grid_mapping"]
+        assert mapping.num_scratch_operands == scratch + (1 if roped else 0)
+        assert call.invars[0].aval.shape == (2,)            # offs first
+        assert call.invars[1].aval.shape == (b * h, s, d)   # then q
+        if not roped:
+            continue
+        for table, block in zip(call.invars[-2:],
+                                mapping.block_mappings[-2 - len(
+                                    call.outvars):][:2]):
+            assert table.aval.shape == (b, s, d)
+            assert table.aval.dtype == jnp.float32
+            index = block.index_map_jaxpr
+            offs = jnp.zeros((2,), jnp.int32)
+            for g in range(b * h):
+                for i in range(2):
+                    at = jax.core.eval_jaxpr(index.jaxpr, index.consts,
+                                             jnp.int32(g), jnp.int32(i),
+                                             offs)
+                    assert [int(x) for x in at] == [g // h, 0, 0]
+
+
+def test_rope_under_a_batch_sharded_mesh_is_the_one_device_call():
+    """flash_attention's shard_map hands each shard its rows of the tables
+    with its rows of q, k and v (the fsdp cell's path)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    b, s, h, d = 4, 256, 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q, k, v = (jax.random.normal(x, (b, s, h, d), jnp.float32) for x in ks)
+    rope = _rope_tables(b, s, d, starts=(3, 500, 40, 77))
+
+    def call(q, k, v, cos, sin):
+        return attn.flash_attention(q, k, v, rope=(cos, sin), block_q=128,
+                                    block_k=128)
+
+    one = call(q, k, v, *rope)
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("fsdp",))
+    rows = NamedSharding(mesh, P("fsdp"))
+    with jax.sharding.set_mesh(mesh):
+        four = jax.jit(call)(*(jax.device_put(x, rows)
+                               for x in (q, k, v, *rope)))
+    np.testing.assert_array_equal(np.asarray(four), np.asarray(one))

@@ -174,25 +174,30 @@ def _roofline_kernel_pattern():
     return _reader("flash_fwd_roofline.train").KERNEL
 
 
-def _cell_calls(topo, monkeypatch, cell, fn):
+def _cell_calls(topo, monkeypatch, cell, fn, roped=False):
     """The kernel calls of fn(q, k, v) compiled at a cell's own attention
     shapes: on one chip or under the fsdp=4 mesh, through the public
-    flash_attention."""
+    flash_attention.  roped: fn(q, k, v, cos, sin), the tables as `_block`
+    gathers them, float32 [rows, 2048, 32]."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     monkeypatch.setattr(attention.dispatch, "platform", lambda: "tpu")
     monkeypatch.setattr(attention.dispatch, "interpret_mode", lambda: False)
     shape = (CELL_ROWS[cell], 2048, 32, 64)
+
+    def args(sharding):
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+        t = jax.ShapeDtypeStruct((shape[0], 2048, 32), jnp.float32,
+                                 sharding=sharding)
+        return (x, x, x) + ((t, t) if roped else ())
+
     if cell == "train-d12":
-        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
-                                 sharding=SingleDeviceSharding(
-                                     topo.devices[0]))
-        return _custom_calls_as_traced(fn, x, x, x)
+        return _custom_calls_as_traced(
+            fn, *args(SingleDeviceSharding(topo.devices[0])))
     mesh = Mesh(topo.devices, ("fsdp",))
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
-                             sharding=NamedSharding(mesh, P("fsdp")))
     with jax.sharding.set_mesh(mesh):
-        return _custom_calls_as_traced(fn, x, x, x)
+        return _custom_calls_as_traced(
+            fn, *args(NamedSharding(mesh, P("fsdp"))))
 
 
 @pytest.mark.parametrize("cell", sorted(CELL_ROWS))
@@ -213,6 +218,40 @@ def test_cell_flash_forward_keeps_the_face_the_roofline_reader_finds(
     bh = 5 * 32 if cell == "train-d12" else 10 * 32   # a chip's share
     assert f"(bf16[{bh},2048,64]" in found.group(0)
     assert f"f32[{bh},8,2048])" in found.group(0)
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_ROWS))
+def test_cell_roped_flash_keeps_the_faces_and_takes_the_tables_last(
+        topo, monkeypatch, cell):
+    """The dense cells' call since PR 33, rope=(cos, sin): forward and ONE
+    backward call compile for the v5e (one chip, and a chip's share under
+    the fsdp=4 shard_map).  The forward's face, result and first operand,
+    is the one the roofline reader finds; the backward's is three results
+    behind s32[2], which no forward reader matches; the widened tables,
+    float32 [rows, 2048, 64], are the last two operands of each."""
+    import re
+
+    def loss(q, k, v, cos, sin):
+        return attention.flash_attention(
+            q, k, v, rope=(cos, sin)).astype(jnp.float32).sum()
+
+    calls = _cell_calls(topo, monkeypatch, cell,
+                        jax.grad(loss, argnums=(0, 1, 2)), roped=True)
+    pattern = _roofline_kernel_pattern()
+    forward = [l for l in calls if re.search(pattern, l)]
+    backward = [l for l in calls if not re.search(pattern, l)]
+    assert len(forward) == 1 and len(backward) == 1, calls
+    rows = CELL_ROWS[cell] if cell == "train-d12" else CELL_ROWS[cell] // 4
+    bh = rows * 32
+    found = re.search(pattern, forward[0]).group(0)
+    assert f"(bf16[{bh},2048,64]" in found and f"f32[{bh},8,2048])" in found
+    grad = f"bf16[{bh},2048,64]"
+    assert f"= ({grad}, {grad}, {grad}) custom-call(s32[2] " in backward[0]
+    table = rf"f32\[{rows},2048,64\] [^,()]+"
+    for line in calls:
+        operands = line.split("custom-call(", 1)[1].split(")", 1)[0]
+        assert re.search(rf", {table}, {table}$", operands), operands
+        assert len(re.findall(r"f32\[\d+,2048,64\]", operands)) == 2
 
 
 @pytest.mark.parametrize("cell", sorted(CELL_ROWS))
@@ -450,3 +489,164 @@ def test_cell_hybrid_step_program_fits_a_v5e(topo, monkeypatch):
     # layer the same (3).  So the pair's body 6, the lone mamba 3, the full
     # layer 3, the cross layer 3.
     assert text.count("tpu_custom_call") == 6 + 3 + 3 + 3
+
+
+# ---------------------------------------------------------------------------
+# What the dense step does AROUND the flash kernels (PR 33): the arrays XLA
+# moves between the projections' matmul fusions and the custom calls
+# ---------------------------------------------------------------------------
+
+_DTYPE_BYTES = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1}
+
+
+def _hlo_bytes(shapes: str) -> int:
+    import re
+
+    total = 0
+    for dtype, dims in re.findall(r"\b(f32|bf16|s32|u32|pred)\[([0-9,]*)\]",
+                                  shapes):
+        n = 1
+        for x in filter(None, dims.split(",")):
+            n *= int(x)
+        total += n * _DTYPE_BYTES[dtype]
+    return total
+
+
+# The ops that are a pass over their operands in the step's own stream.
+# Not counted: bitcasts and tuple plumbing, which move nothing, and the
+# asynchronous prefetches the scheduler wraps around a kernel's operands
+# (slice-start / copy-start and their custom-call joins), which both the
+# tree with rope in XLA and the one without have alike.
+_PASSES = ("copy", "convert", "transpose", "fusion", "broadcast", "reduce",
+           "pad", "concatenate", "slice", "dynamic-slice")
+
+
+def _glue_between_matmuls_and_kernels(text: str):
+    """{instruction name: (opcode, result shape, bytes read + written)} of
+    every materialised op that lies between a Mosaic custom call and the
+    nearest matmul fusions, walking from the calls' operands back and from
+    their results on through anything that is neither (copies, converts,
+    loop and reduce fusions, broadcasts), in every computation that holds
+    a call (the scanned layer's forward body, and its remat + backward
+    body).  Fused computations' insides are not materialised and are
+    skipped."""
+    import re
+
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            inst = re.match(
+                r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$", line)
+            if inst:
+                cur.append(inst.groups())
+    with_dot = {name for name, insts in comps.items()
+                if any(op in ("dot", "convolution") for _, _, op, _ in insts)}
+    glue = {}
+    for name, insts in comps.items():
+        if "fused_computation" in name or name.startswith("fused_"):
+            continue
+        by_name = {i[0]: i for i in insts}
+
+        def operands(i):
+            return [o for o in re.findall(r"%([\w.\-]+)",
+                                          i[3].split("), ")[0])
+                    if o in by_name]
+
+        def kernel(i):
+            return i[2] == "custom-call" and "tpu_custom_call" in i[3]
+
+        def matmul(i):
+            called = re.search(r"calls=%?([\w.\-]+)", i[3])
+            return i[2] in ("dot", "convolution") or (
+                i[2] == "fusion" and called and called.group(1) in with_dot)
+
+        users = {}
+        for i in insts:
+            for o in operands(i):
+                users.setdefault(o, []).append(i[0])
+        kernels = [i for i in insts if kernel(i)]
+        seen = set()
+        for start, step in (
+                ([o for i in kernels for o in operands(i)],
+                 lambda i: operands(i)),
+                ([u for i in kernels for u in users.get(i[0], [])],
+                 lambda i: users.get(i[0], []))):
+            stack = list(start)
+            while stack:
+                i = by_name[stack.pop()]
+                if i[0] in seen or kernel(i) or matmul(i) or i[2] in (
+                        "parameter", "constant", "while", "tuple"):
+                    continue
+                seen.add(i[0])
+                stack.extend(step(i))
+        for n in seen:
+            _, shape, op, _ = by_name[n]
+            if op not in _PASSES:
+                continue
+            moved = _hlo_bytes(shape) + sum(
+                _hlo_bytes(by_name[o][1]) for o in operands(by_name[n]))
+            glue[f"{name}/{n}"] = (op, re.sub(r"\{[^}]*\}", "", shape), moved)
+    return comps, glue
+
+
+def test_dense_layer_moves_q_and_k_to_the_kernels_once_and_unroped(
+        one_chip, monkeypatch):
+    """One remat'd dense layer of the cells' widths at train-d12's 5 x 2048
+    rows, forward and backward (two scanned layers' grad: the scan body is
+    compiled once).  With rope in XLA (PR 32) the float32 round trip of dq
+    and dk, rope's split-and-pad fusions and its own passes made 2.57 GB a
+    layer move between the matmul fusions and the custom calls, as this
+    walk counts them; with rope in the kernels 1.11 GB is left: the
+    relayout copies of 42 MB (eleven on the walk, one more behind the
+    scan's carry), delta, the lse broadcasts and the tables.  A later
+    change that puts ONE pass of a [5, 2048, 32, 64] array back (84 MB)
+    fails here, on the CPU."""
+    import re
+
+    from ray_tpu.models import transformer as tfm
+
+    _on_tpu(monkeypatch, attention)
+    monkeypatch.setattr(attention.dispatch, "_taken", {})
+    config = tfm.TransformerConfig(
+        vocab_size=256, hidden_size=2048, intermediate_size=8192,
+        num_layers=2, num_heads=32, num_kv_heads=32, head_dim=64,
+        max_seq_len=2048, rope_theta=130000.0, remat_policy="full",
+        dtype=jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: tfm.init_params(config, jax.random.key(0))))
+    tokens = jax.ShapeDtypeStruct((5, 2048), jnp.int32, sharding=one_chip)
+
+    def loss(p, t):
+        return tfm.forward_hidden(p, t, config)[0].astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss), params, tokens)
+    assert all(p.endswith(",rope_in_kernel") for p in
+               attention.dispatch.taken()["flash_attention.plan"])
+    assert text.count("tpu_custom_call") == 3   # forward, remat's, backward
+    comps, glue = _glue_between_matmuls_and_kernels(text)
+    # no float32 copy of a q- or k-sized array is materialised anywhere
+    materialised = [
+        (name, i[0], i[1]) for name, insts in comps.items()
+        if "fused_computation" not in name for i in insts
+        if re.match(r"f32\[(5,32,2048,64|5,2048,32,64|160,2048,64)\]", i[1])]
+    assert not materialised, materialised
+    # no split-and-concatenate of a 64-wide last axis (it compiles to a pad
+    # and a maximum in one fusion)
+    for name, insts in comps.items():
+        ops = {i[2] for i in insts}
+        padded = [i[1] for i in insts
+                  if re.match(r"(bf16|f32)\[5,(2048,32|32,2048),", i[1])]
+        assert not ({"pad", "maximum"} <= ops and padded), (name, padded)
+    moved = sum(b for _, _, b in glue.values())
+    copies = [g for g in glue.values() if g[0] == "copy"
+              and re.match(r"bf16\[5,(2048,32|32,2048),64\]", g[1])]
+    assert 10 <= len(copies) <= 12, sorted(glue.values(),
+                                           key=lambda g: -g[2])[:20]
+    assert 0.9e9 < moved < 1.15e9, (
+        moved, sorted(glue.values(), key=lambda g: -g[2])[:20])
