@@ -1,0 +1,457 @@
+"""Latent-attention, routed-expert decoder (the DeepSeek-V3 form, as
+Kanana-2-30B-A3B publishes it) for training through `ShardedTrainStep`: the
+same entry points as models/transformer.py and models/hybrid.py
+(`init_params`, `logical_axes`, `num_params`, `loss_fn`, `token_nll`);
+embedding, fused cross-entropy, SwiGLU and the remat wrapper are
+models/common.py's, the routed experts models/moe.py's dropless layer.
+
+Layer equations (h the layer's input, T tokens; every matrix [in, out], no
+bias anywhere):
+
+  block      x = x + Attn(RMSNorm(x)); x = x + FFN(RMSNorm(x)); eps
+             `rms_norm_eps`; a final RMSNorm; logits through an UNTIED head.
+  attention  q = h W_q -> [T, heads, nope + rope] = [q_nope | q_pe];
+             c = h W_kva -> [T, kv_lora_rank + rope]; c_kv = RMSNorm(c[:rank])
+             with a weight, k_pe = c[rank:] (ONE head, shared by all);
+             c_kv W_kvb -> [T, heads, nope + v] = [k_nope | v];
+             rope (theta `rope_theta`, the interleaved pairing (2i, 2i + 1))
+             on q_pe and k_pe only; k = [k_nope | k_pe];
+             causal softmax(q k^T / sqrt(nope + rope)) v -> [T, heads, v];
+             W_o: heads x v -> hidden.  Keys are nope + rope wide (192),
+             values v wide (128): ONE flash_attention call, nothing padded.
+  dense FFN  the first `first_k_dense_replace` layers: SwiGLU of
+             `intermediate_size`.
+  expert FFN s = sigmoid(h W_r) in float32 over `router_width` experts; a
+             token's `num_experts_per_tok` experts are the top of s + b
+             (b: the selection bias, `noaux_tc`; one group, no group limit);
+             gates g = s[sel] / sum(s[sel]) x `routed_scaling_factor` (the
+             bias is not in the gate); y = sum_e g_e (silu(h Wg_e) * (h
+             Wu_e)) Wd_e + Shared(h), experts `moe_intermediate_size` wide,
+             Shared ONE SwiGLU of `n_shared_experts` x that width.  No
+             auxiliary loss.
+
+One chip's share.  `n_routed_experts` is how many experts THIS program
+holds (experts `first_held_expert` on), `router_width` how many the model
+routes over.  The router and the top-k run over all of them; the layer
+computes the held experts' terms and the shared expert; what the absent
+experts would add is left out and that partial result goes on to the next
+layer (expert parallelism without its exchange: the other chips' terms are
+the other chips').  With router_width == n_routed_experts it is the whole
+layer.
+
+The selection bias `router_bias` is a leaf of the parameters that is not
+trained (`not_trained`): it enters the selection only, gets no gradient,
+and the step leaves it as it is (its published update rule is a
+load-balancing controller outside the optimiser, and its rate is not
+published).
+
+The program.  The dense layers are one segment, the expert layers another,
+each with its parameters stacked on a leading repeats axis and scanned:
+`params["layers"][segNN]["0"][leaf][repeat]`, the layout models/hybrid.py
+has.  `loss_and_metrics` also gives the LAST expert layer's routing counts
+(`moe_rows_held`, `moe_load_max`, `moe_load_mean`, `moe_rows_bound`) and
+the rows all the expert layers held together (`moe_rows_held_all_layers`)
+as device scalars, which `ShardedTrainStep` carries in the step's metrics.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, Dict, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models import common, moe
+from ray_tpu.models.transformer import rms_norm
+from ray_tpu.parallel.sharding import with_logical_constraint
+
+F32 = jnp.float32
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoEConfig:
+    """The published config.json's key names, the chip's share
+    (`router_width`, `first_held_expert`) and the train switches the other
+    models have."""
+    vocab_size: int = 128256
+    hidden_size: int = 2048
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 768
+    num_hidden_layers: int = 48
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 32
+    kv_lora_rank: int = 512
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_routed_experts: int = 128         # held HERE
+    router_width: Optional[int] = None  # routed over; None: all are held
+    first_held_expert: int = 0
+    num_experts_per_tok: int = 6
+    n_shared_experts: int = 2
+    routed_scaling_factor: float = 2.448
+    norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"
+    topk_method: str = "noaux_tc"
+    n_group: int = 1
+    topk_group: int = 1
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1e6
+    rope_interleave: bool = True
+    rope_scaling: Optional[dict] = None
+    tie_word_embeddings: bool = False
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    remat: bool = True
+    remat_policy: str = "full"
+    fused_ce: bool = False
+
+    def __post_init__(self):
+        if self.router_width is None:
+            object.__setattr__(self, "router_width", self.n_routed_experts)
+        unsupported = {
+            "q_lora_rank": self.q_lora_rank is not None,
+            "scoring_func": self.scoring_func != "sigmoid",
+            "topk_method": self.topk_method != "noaux_tc",
+            "n_group / topk_group": (self.n_group, self.topk_group) != (1, 1),
+            "norm_topk_prob": not self.norm_topk_prob,
+            "rope_interleave": not self.rope_interleave,
+            "rope_scaling": self.rope_scaling is not None,
+            "tie_word_embeddings": self.tie_word_embeddings,
+        }
+        bad = sorted(k for k, v in unsupported.items() if v)
+        if bad:
+            raise ValueError(f"not written down here, so not computed: {bad}")
+        if not 0 < self.first_k_dense_replace <= self.num_hidden_layers:
+            raise ValueError("first_k_dense_replace must lie in 1 .. "
+                             "num_hidden_layers")
+        if self.first_held_expert + self.n_routed_experts > self.router_width:
+            raise ValueError("the held experts lie outside the router's")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("rope pairs dimensions: qk_rope_head_dim is odd")
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def experts_held(self) -> Tuple[int, int]:
+        return self.first_held_expert, self.n_routed_experts
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        dense = self.first_k_dense_replace
+        return ("dense",) * dense + ("moe",) * (self.num_hidden_layers - dense)
+
+    @classmethod
+    def tiny(cls, **kw) -> "LatentMoEConfig":
+        """Test-sized: both kinds of layer, a share of the experts."""
+        return cls(**{**dict(
+            vocab_size=256, hidden_size=64, intermediate_size=128,
+            moe_intermediate_size=32, num_hidden_layers=3,
+            num_attention_heads=2, kv_lora_rank=32, qk_nope_head_dim=32,
+            qk_rope_head_dim=16, v_head_dim=32, n_routed_experts=4,
+            router_width=16, num_experts_per_tok=3), **kw})
+
+
+def segments(config: LatentMoEConfig) -> List[Tuple[str, int, int]]:
+    """(kind, first layer, repeats): the dense layers, then the expert
+    layers."""
+    dense = config.first_k_dense_replace
+    out = [("dense", 0, dense)]
+    if config.num_hidden_layers > dense:
+        out.append(("moe", dense, config.num_hidden_layers - dense))
+    return out
+
+
+def _segment_name(i: int) -> str:
+    return f"seg{i:02d}"
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+# ---------------------------------------------------------------------------
+
+def _layer_shapes(kind: str, c: LatentMoEConfig) -> Dict[str, Tuple]:
+    """name -> (shape, logical axes, init): init is a matrix's fan-in, or
+    "ones" or "select_bias"."""
+    h, heads = c.hidden_size, c.num_attention_heads
+    rank, rope = c.kv_lora_rank, c.qk_rope_head_dim
+    shapes = {
+        "ln1_w": ((h,), (None,), "ones"),
+        "wq": ((h, heads * c.qk_head_dim), ("embed", "heads"), h),
+        "wkv_a": ((h, rank + rope), ("embed", None), h),
+        "kv_norm_w": ((rank,), (None,), "ones"),
+        "wkv_b": ((rank, heads * (c.qk_nope_head_dim + c.v_head_dim)),
+                  (None, "heads"), rank),
+        "wo": ((heads * c.v_head_dim, h), ("heads", "embed"),
+               heads * c.v_head_dim),
+        "ln2_w": ((h,), (None,), "ones"),
+    }
+    if kind == "dense":
+        m = c.intermediate_size
+        shapes.update({
+            "w_gate": ((h, m), ("embed", "mlp"), h),
+            "w_up": ((h, m), ("embed", "mlp"), h),
+            "w_down": ((m, h), ("mlp", "embed"), m)})
+        return shapes
+    m, held = c.moe_intermediate_size, c.n_routed_experts
+    shared = c.n_shared_experts * m
+    shapes.update({
+        "router_w": ((h, c.router_width), ("embed", None), h),
+        "router_bias": ((c.router_width,), (None,), "select_bias"),
+        "experts_gate": ((held, h, m), ("expert", "embed", "mlp"), h),
+        "experts_up": ((held, h, m), ("expert", "embed", "mlp"), h),
+        "experts_down": ((held, m, h), ("expert", "mlp", "embed"), m),
+        "shared_gate": ((h, shared), ("embed", "mlp"), h),
+        "shared_up": ((h, shared), ("embed", "mlp"), h),
+        "shared_down": ((shared, h), ("mlp", "embed"), shared)})
+    return shapes
+
+
+def _normal(key, shape, dtype, std):
+    return (jax.random.normal(key, shape) * std).astype(dtype)
+
+
+def _init_layer(key, kind: str, c: LatentMoEConfig) -> Dict[str, Any]:
+    shapes = _layer_shapes(kind, c)
+    out = {}
+    for k, (name, (shape, _, init)) in zip(
+            jax.random.split(key, len(shapes)), shapes.items()):
+        if init == "ones":
+            out[name] = jnp.ones(shape, c.param_dtype)
+        elif init == "select_bias":
+            out[name] = _normal(k, shape, c.param_dtype, 0.01)
+        else:
+            out[name] = _normal(k, shape, c.param_dtype,
+                                1.0 / math.sqrt(init))
+    return out
+
+
+def init_params(config: LatentMoEConfig, key) -> Dict[str, Any]:
+    """{"tok_embed", "layers": {segNN: {"0": layer parameters stacked on a
+    leading repeats axis}}, "final_norm_w", "lm_head" [vocab, hidden]}."""
+    c = config
+    k_embed, k_head, k_layers = jax.random.split(key, 3)
+    std = 1.0 / math.sqrt(c.hidden_size)
+    layers = {}
+    for si, (kind, first, repeats) in enumerate(segments(c)):
+        each = [_init_layer(jax.random.fold_in(k_layers, first + rep), kind, c)
+                for rep in range(repeats)]
+        layers[_segment_name(si)] = {
+            "0": jax.tree.map(lambda *a: jnp.stack(a), *each)}
+    return {
+        "tok_embed": _normal(k_embed, (c.vocab_size, c.hidden_size),
+                             c.param_dtype, std),
+        "layers": layers,
+        "final_norm_w": jnp.ones((c.hidden_size,), c.param_dtype),
+        "lm_head": _normal(k_head, (c.vocab_size, c.hidden_size),
+                           c.param_dtype, std),
+    }
+
+
+def _leaf_tree(config: LatentMoEConfig, leaf, top):
+    """The parameters' tree with leaf(name, (shape, axes, init)) at every
+    layer leaf and top(name) at the others."""
+    layers = {
+        _segment_name(si): {"0": {
+            name: leaf(name, spec)
+            for name, spec in _layer_shapes(kind, config).items()}}
+        for si, (kind, _, _) in enumerate(segments(config))}
+    return {"tok_embed": top("tok_embed"), "layers": layers,
+            "final_norm_w": top("final_norm_w"), "lm_head": top("lm_head")}
+
+
+def logical_axes(config: LatentMoEConfig) -> Dict[str, Any]:
+    """Logical-axis tree matching init_params, for parallel.sharding."""
+    tops = {"tok_embed": ("vocab", "embed"), "final_norm_w": (None,),
+            "lm_head": ("vocab", "embed")}
+    return _leaf_tree(config, lambda name, spec: ("layers",) + spec[1],
+                      tops.__getitem__)
+
+
+def not_trained(config: LatentMoEConfig) -> Dict[str, Any]:
+    """True at the leaves a train step leaves as they are: the router's
+    selection bias."""
+    return _leaf_tree(config, lambda name, spec: name == "router_bias",
+                      lambda name: False)
+
+
+def num_params(config: LatentMoEConfig) -> int:
+    per_layer = sum(math.prod(shape) for kind in config.layer_kinds
+                    for shape, _, _ in _layer_shapes(kind, config).values())
+    return (2 * config.vocab_size * config.hidden_size + per_layer
+            + config.hidden_size)
+
+
+# ---------------------------------------------------------------------------
+# Forward
+# ---------------------------------------------------------------------------
+
+def _matmul(x, w, c: LatentMoEConfig):
+    """bf16 operands, fp32 accumulation, the result in the compute dtype."""
+    return jnp.einsum("bsi,io->bso", x.astype(c.dtype), w.astype(c.dtype),
+                      preferred_element_type=c.dtype)
+
+
+def rope_tables(seq: int, c: LatentMoEConfig):
+    """(cos, sin) [seq, rope / 2] float32 at positions 0 .. seq - 1."""
+    half = c.qk_rope_head_dim // 2
+    inv_freq = 1.0 / (c.rope_theta ** (jnp.arange(half, dtype=F32) / half))
+    angle = jnp.arange(seq, dtype=F32)[:, None] * inv_freq[None, :]
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def rope_interleaved(x, cos, sin):
+    """x [b, s, heads, rope]: the pairs (2i, 2i + 1) turned by the angle of
+    frequency i; float32 arithmetic, rounded once to x's dtype."""
+    pairs = x.astype(F32).reshape(*x.shape[:-1], -1, 2)
+    even, odd = pairs[..., 0], pairs[..., 1]
+    c, s = cos[None, :, None, :], sin[None, :, None, :]
+    out = jnp.stack([even * c - odd * s, odd * c + even * s], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _attention(u, lp, cos, sin, c: LatentMoEConfig):
+    from ray_tpu.ops.attention import flash_attention
+
+    b, s, _ = u.shape
+    heads, nope, rope = (c.num_attention_heads, c.qk_nope_head_dim,
+                         c.qk_rope_head_dim)
+    rank, dv = c.kv_lora_rank, c.v_head_dim
+    with jax.named_scope("mla.project"):
+        q = _matmul(u, lp["wq"], c).reshape(b, s, heads, nope + rope)
+        latent = _matmul(u, lp["wkv_a"], c)
+        c_kv = rms_norm(latent[..., :rank], lp["kv_norm_w"], c.rms_norm_eps)
+        kv = _matmul(c_kv, lp["wkv_b"], c).reshape(b, s, heads, nope + dv)
+        k_pe = rope_interleaved(latent[..., None, rank:], cos, sin)
+        q = jnp.concatenate(
+            [q[..., :nope], rope_interleaved(q[..., nope:], cos, sin)],
+            axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_pe, (b, s, heads, rope))],
+            axis=-1)
+        q = with_logical_constraint(q, ("batch", "seq", "heads", None))
+    a = flash_attention(q, k, kv[..., nope:], causal=True,
+                        sm_scale=1.0 / math.sqrt(nope + rope))
+    return _matmul(a.reshape(b, s, heads * dv), lp["wo"], c)
+
+
+def _routed_part(flat, router_w, router_bias, w_gate, w_up, w_down,
+                 c: LatentMoEConfig):
+    """The router and models/moe.py's dropless layer for this chip's share:
+    flat [T, hidden] -> (the held experts' sum, the routing counts).  The
+    usual buffer holds twice the rows even routing sends here; a step that
+    sends more takes the full bound's."""
+    with jax.named_scope("moe.route"):
+        idx, gates = moe.sigmoid_route(
+            flat, router_w, router_bias,
+            num_experts_per_token=c.num_experts_per_tok,
+            scale=c.routed_scaling_factor)
+    even = -(-flat.shape[0] * c.num_experts_per_tok * c.n_routed_experts
+             // c.router_width)
+    return moe.routed_experts(
+        flat, idx, gates, w_gate, w_up, w_down, experts_held=c.experts_held,
+        dtype=c.dtype, usual_rows=2 * even)
+
+
+def routed_experts(h, router_w, router_bias, w_gate, w_up, w_down,
+                   config: LatentMoEConfig):
+    """The routed part of an expert layer ALONE, on its operands as the
+    layer makes them (h [.., hidden]: the normed input; the router's weight
+    and selection bias; the held experts' weights) -> the held experts' sum,
+    like h: the router, the dropless dispatch and the grouped matmuls, no
+    shared expert."""
+    y, _ = _routed_part(h.reshape(-1, h.shape[-1]), router_w, router_bias,
+                        w_gate, w_up, w_down, config)
+    return y.reshape(h.shape)
+
+
+def _layer(x, lp, cos, sin, *, kind: str, c: LatentMoEConfig):
+    """One layer -> (x, the routing counts of an expert layer or None)."""
+    u = rms_norm(x, lp["ln1_w"], c.rms_norm_eps)
+    u = with_logical_constraint(u, ("batch", "seq", "embed"))
+    x = with_logical_constraint(x + _attention(u, lp, cos, sin, c),
+                                ("batch", "seq", "embed"))
+    y = rms_norm(x, lp["ln2_w"], c.rms_norm_eps)
+    stats = None
+    if kind == "dense":
+        ffn = common.swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"],
+                            c.dtype)
+    else:
+        routed, stats = _routed_part(
+            y.reshape(-1, y.shape[-1]), lp["router_w"], lp["router_bias"],
+            lp["experts_gate"], lp["experts_up"], lp["experts_down"], c)
+        ffn = routed.reshape(y.shape) + common.swiglu(
+            y, lp["shared_gate"], lp["shared_up"], lp["shared_down"],
+            c.dtype)
+    return with_logical_constraint(x + ffn, ("batch", "seq", "embed")), stats
+
+
+@functools.cache
+def _layer_fn(kind: str, c: LatentMoEConfig):
+    return common.maybe_remat(functools.partial(_layer, kind=kind, c=c),
+                              c.remat, c.remat_policy)
+
+
+def forward_hidden(params: Dict[str, Any], tokens, config: LatentMoEConfig):
+    """Embedding + layers + final RMSNorm: [b, s] -> ([b, s, hidden], the
+    LAST expert layer's routing counts and the rows all the expert layers
+    held together, or None without one)."""
+    c = config
+    x = common.embed_tokens(params["tok_embed"], tokens, c.dtype)
+    cos, sin = rope_tables(tokens.shape[1], c)
+    stats = None
+    for si, (kind, _, _) in enumerate(segments(c)):
+        fn = _layer_fn(kind, c)
+
+        def body(x, lp, fn=fn):
+            return fn(x, lp, cos, sin)
+
+        x, per_layer = jax.lax.scan(
+            body, x, params["layers"][_segment_name(si)]["0"])
+        if per_layer is not None:
+            stats = jax.tree.map(lambda a: a[-1], per_layer)
+            stats["rows_held_all_layers"] = jnp.sum(per_layer["rows_held"])
+    return rms_norm(x, params["final_norm_w"], c.rms_norm_eps), stats
+
+
+def forward(params: Dict[str, Any], tokens, config: LatentMoEConfig):
+    """tokens [b, s] int32 -> logits [b, s, vocab] (fp32)."""
+    x, _ = forward_hidden(params, tokens, config)
+    return common.tied_logits(x, params["lm_head"], config.dtype)
+
+
+def _nll_and_stats(params, batch, config: LatentMoEConfig):
+    tokens = batch["tokens"]
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    x, stats = forward_hidden(params, inputs, config)
+    if config.fused_ce:
+        return common.fused_nll(x, params["lm_head"], targets), stats
+    logits = common.tied_logits(x, params["lm_head"], config.dtype)
+    return common.logits_nll(logits, targets), stats
+
+
+def token_nll(params, batch, config: LatentMoEConfig):
+    """-log p(tokens[t+1] | tokens[:t+1]) for every position: [b, s] fp32.
+    batch: {"tokens": [b, s+1] int32}."""
+    return _nll_and_stats(params, batch, config)[0]
+
+
+def loss_and_metrics(params, batch, config: LatentMoEConfig):
+    """(next-token cross-entropy, the LAST expert layer's routing counts
+    as `moe_*` device scalars; none without an expert layer)."""
+    nll, stats = _nll_and_stats(params, batch, config)
+    mask = batch.get("mask")
+    loss = common.masked_mean(nll, None if mask is None else mask[:, 1:])
+    return loss, {f"moe_{k}": v for k, v in (stats or {}).items()}
+
+
+def loss_fn(params, batch, config: LatentMoEConfig):
+    """Next-token cross-entropy: the mean of `token_nll`, over the
+    positions batch["mask"] keeps if there is one."""
+    return loss_and_metrics(params, batch, config)[0]

@@ -1,4 +1,6 @@
-"""Mixture-of-experts FFN with GSPMD expert parallelism.
+"""Mixture-of-experts FFN: the GSPMD capacity layer (`moe_ffn`, the dense
+block's and decoding's) and the dropless layer that holds one chip's share of
+the experts (`sigmoid_route`, `routed_experts`: latent_moe.py's).
 
 Greenfield capability (SURVEY.md §2.4 — expert parallelism is absent from
 the reference; the TPU-native target is an expert mesh axis + all_to_all).
@@ -15,7 +17,7 @@ Aux load-balancing loss per Switch Transformers (Fedus et al.):
 from __future__ import annotations
 
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -124,3 +126,189 @@ def moe_ffn_gather(x, router_w, w_gate, w_up, w_down, *,
     out = jnp.einsum("tkm,tkmh->tkh", g * u, wd)
     out = jnp.einsum("tkh,tk->th", out, gate_vals.astype(dtype))
     return out.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# The dropless layer: one chip's share of the experts
+# ---------------------------------------------------------------------------
+# What expert parallelism asks of a chip: route every token over ALL the
+# experts, compute the terms of the experts that live here, hand the partial
+# sum on.  No capacity factor: a token routed to a held expert is computed
+# whatever the routing, and the static shapes come from a bound the routing
+# cannot exceed (a token sends at most min(k, held) rows here).  The rows
+# are sorted by expert into ops/grouped_matmul.py's layout and the three
+# expert matmuls are grouped matmuls, which cost by the rows that are there.
+#
+# Rows move by GATHER in both directions (an XLA scatter of a hundred
+# thousand rows is a serial loop on the TPU): placing rows in expert order
+# reads x at `src`, its transpose reads the sorted gradient at `pos`; the
+# weighted sum back reads the experts' output at `pos`, its transpose reads
+# dy at `src`.  `pos` [T, k] is where an assignment's row lies, `src` [rows]
+# which assignment a row holds: one is the other's inverse, the first from a
+# running count an expert, the second from a stable sort by expert.
+
+def _sum_rows_at(table, pos, held, weights=None):
+    """sum over a token's k assignments of (weights x) table [rows, h] at
+    pos [T, k], where held -> [T, h] float32.  A select, not a product:
+    rows nobody placed hold nothing defined.  A gather a slot: one gather
+    of [T, k] rows would be re-laid for its k-long axis before the sum."""
+    total = 0.0
+    for slot in range(pos.shape[1]):
+        picked = table[jnp.minimum(pos[:, slot], table.shape[0] - 1)]
+        picked = picked.astype(jnp.float32)
+        if weights is not None:
+            picked = picked * weights[:, slot, None]
+        total = total + jnp.where(held[:, slot, None], picked, 0.0)
+    return total
+
+
+@jax.custom_vjp
+def _place(x, src_token, row_valid, pos, held):
+    """x [T, h] -> the rows in expert order [rows, h]; padding rows zero."""
+    return jnp.where(row_valid[:, None], x[src_token],
+                     jnp.zeros((), x.dtype))
+
+
+def _place_fwd(x, src_token, row_valid, pos, held):
+    return _place(x, src_token, row_valid, pos, held), (pos, held)
+
+
+def _place_bwd(res, d_rows):
+    pos, held = res
+    return (_sum_rows_at(d_rows, pos, held).astype(d_rows.dtype),
+            None, None, None, None)
+
+
+_place.defvjp(_place_fwd, _place_bwd)
+
+
+@jax.custom_vjp
+def _combine(out, gates, pos, held, src, row_valid):
+    """sum over a token's held assignments of gate x its row of `out`
+    [rows, h] -> [T, h] in out's dtype, summed in float32."""
+    return _sum_rows_at(out, pos, held, gates).astype(out.dtype)
+
+
+def _combine_fwd(out, gates, pos, held, src, row_valid):
+    return (_combine(out, gates, pos, held, src, row_valid),
+            (out, gates, pos, held, src, row_valid))
+
+
+def _combine_bwd(res, dy):
+    out, gates, pos, held, src, row_valid = res
+    k = gates.shape[1]
+    # dy at every row's token, read once: times the row's gate it is the
+    # row's cotangent, times the row itself the gate's
+    dy_rows = dy[src // k].astype(jnp.float32)
+    d_out = jnp.where(row_valid[:, None],
+                      dy_rows * gates.reshape(-1)[src][:, None],
+                      0.0).astype(out.dtype)
+    d_gate_rows = jnp.sum(dy_rows * out.astype(jnp.float32), axis=-1)
+    d_gates = jnp.where(held, d_gate_rows[jnp.minimum(pos, out.shape[0] - 1)],
+                        0.0)
+    return d_out, d_gates, None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def sigmoid_route(x, router_w, select_bias, *, num_experts_per_token: int,
+                  scale: float):
+    """The bias-corrected sigmoid router: s = sigmoid(x W_r) in float32 at
+    full matmul precision (a bfloat16 pass flips near-ties); a token's
+    experts are the top k of s + bias; its gates s[sel] / sum(s[sel]) x
+    scale.  The bias enters the selection only and gets no gradient.
+    -> (expert index [T, k] int32, gates [T, k] float32)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)),
+        num_experts_per_token)
+    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
+    return idx.astype(jnp.int32), gates
+
+
+def routed_experts(x, idx, gates, w_gate, w_up, w_down, *,
+                   experts_held: Tuple[int, int], dtype=jnp.bfloat16,
+                   tile_m=None, usual_rows: Optional[int] = None):
+    """The held experts' part of a routed-expert layer, dropless.
+
+    x [T, h]; idx, gates [T, k]: every token's experts (indices over ALL
+    the model's experts) and gates, from the router; experts_held = (first,
+    count): this chip holds experts first .. first + count - 1, whose
+    SwiGLU weights are w_gate, w_up [count, h, m] and w_down [count, m, h].
+    -> (y [T, h] = sum over a token's HELD experts e of
+        gate_e (silu(x Wg_e) * (x Wu_e)) Wd_e,
+    stats).  What the other experts would add is not here: it is the other
+    chips'.  stats: `rows_held` (assignments that landed here), `load_max`
+    and `load_mean` over the held experts, `rows_bound` (the buffer's
+    bound, static), int32 / float32 scalars.
+
+    The buffer of rows in expert order is as long as the bound, tokens x
+    min(k, count), which the routing cannot exceed; XLA's gathers, the
+    index arithmetic and the SwiGLU between the grouped matmuls cost by the
+    buffer, not by the rows in it.  `usual_rows`, where given and under the
+    bound: a step whose rows fit that many takes a buffer of that length
+    instead (`lax.cond` on the count: the same computation at two static
+    sizes, every row computed in either).
+    """
+    from ray_tpu.ops import grouped_matmul as gm
+
+    first, count = experts_held
+    if w_gate.shape[0] != count:
+        raise ValueError(f"{w_gate.shape[0]} experts' weights, {count} held")
+    tokens, k = idx.shape
+    tile_m = tile_m or gm.TILE_M
+    bound = tokens * min(k, count)
+
+    with jax.named_scope("moe.dispatch"):
+        local = idx - first
+        held = (local >= 0) & (local < count)
+        key = jnp.where(held, local, count).reshape(-1)     # [T * k]
+        onehot = (key[:, None] == jnp.arange(count, dtype=jnp.int32)[None, :]
+                  ).astype(jnp.int32)
+        sizes = jnp.sum(onehot, axis=0)
+        # an assignment's rank among its expert's, in token order
+        rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot,
+                       axis=1)
+        # the inverse: a stable sort by expert lists the assignments as the
+        # buffer holds them, less the padding between groups
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+
+    def through_the_experts(rows_bound: int):
+        """The layer over a buffer that holds `rows_bound` rows."""
+        rows = gm.layout_rows(rows_bound, count, tile_m)
+        with jax.named_scope("moe.dispatch"):
+            layout = gm.group_layout(sizes, rows, tile_m)
+            pos = (layout.starts[jnp.minimum(key, count - 1)] + rank
+                   ).reshape(tokens, k)
+            row_group, row_valid = gm.row_groups(layout)
+            packed = (jnp.cumsum(sizes) - sizes)[row_group] + (
+                jnp.arange(rows, dtype=jnp.int32) - layout.starts[row_group])
+            src = order[jnp.clip(packed, 0, tokens * k - 1)]
+            rows_in = _place(x.astype(dtype), src // k, row_valid, pos, held)
+        with jax.named_scope("moe.experts"):
+            gate_h = gm.grouped_matmul(rows_in, w_gate.astype(dtype), layout)
+            up_h = gm.grouped_matmul(rows_in, w_up.astype(dtype), layout)
+            out = gm.grouped_matmul(jax.nn.silu(gate_h) * up_h,
+                                    w_down.astype(dtype), layout)
+        with jax.named_scope("moe.combine"):
+            return _combine(out, gates, pos, held, src, row_valid)
+
+    rows_held = jnp.sum(sizes)
+    if usual_rows is None or usual_rows >= bound:
+        y = through_the_experts(bound)
+    else:
+        # each side under its own checkpoint: a cond's backward keeps BOTH
+        # sides' residuals as outputs, and the buffers are the large ones
+        y = jax.lax.cond(
+            rows_held <= usual_rows,
+            jax.checkpoint(lambda: through_the_experts(usual_rows)),
+            jax.checkpoint(lambda: through_the_experts(bound)))
+
+    stats = {"rows_held": rows_held, "load_max": jnp.max(sizes),
+             "load_mean": jnp.mean(sizes.astype(jnp.float32)),
+             "rows_bound": jnp.asarray(bound, jnp.int32)}
+    return y.astype(x.dtype), stats

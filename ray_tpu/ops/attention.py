@@ -38,8 +38,15 @@ machinery is absent in-tree); on TPU this is a core op.  Design:
     semantics — the numerical ground truth in tests (which compare both
     paths in interpret mode, values and grads).
 
-Layout convention: q, k, v are [batch, seq, heads, head_dim] (the models/
-convention); kernels internally fold batch×heads.
+  - Values of another width than the keys (latent attention in training:
+    keys 192 wide, values 128): the same two kernels, whose output,
+    accumulator, do and dv take the values' width and whose scores contract
+    over the keys'.  Nothing is padded; equal widths build the kernels as
+    they were.
+
+Layout convention: q, k are [batch, seq, heads, head_dim], v is [batch, seq,
+heads, value_dim] (the models/ convention); kernels internally fold
+batch×heads.
 """
 
 from __future__ import annotations
@@ -57,7 +64,8 @@ _NEG_INF = float(-1e30)
 
 
 def _can_use_pallas(seq_q: int, seq_k: int, head_dim: int,
-                    block_q: int, block_k: int) -> bool:
+                    block_q: int, block_k: int,
+                    value_dim: Optional[int] = None) -> bool:
     if dispatch.interpret_mode():
         return seq_q % block_q == 0 and seq_k % block_k == 0
     return (
@@ -65,6 +73,7 @@ def _can_use_pallas(seq_q: int, seq_k: int, head_dim: int,
         and seq_q % block_q == 0
         and seq_k % block_k == 0
         and head_dim % 64 == 0
+        and (value_dim or head_dim) % 64 == 0
     )
 
 
@@ -375,7 +384,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, *refs,
     qi = pl.program_id(1)
     window = offs_ref[2] if windowed else None
     q = q_ref[0]  # [block_q, d]
-    d = q.shape[-1]
+    d = v_ref.shape[-1]     # the accumulator's rows: the values' width
     if rope_q0 is None:
         o_ref, lse_ref = refs
     else:
@@ -485,13 +494,13 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, d_v = k.shape[1], v.shape[-1]
     if fold_scale is None:
         fold_scale = _scale_is_exact(sm_scale)
     # fold batch*heads, put seq in the middle: [bh, s, d]
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d_v)
 
     grid = (b * h, sq // block_q)
     kernel = functools.partial(
@@ -508,25 +517,25 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda bh, i, offs: (bh, i, 0)),
                 pl.BlockSpec((1, sk, d), lambda bh, i, offs: (bh, 0, 0)),
-                pl.BlockSpec((1, sk, d), lambda bh, i, offs: (bh, 0, 0)),
+                pl.BlockSpec((1, sk, d_v), lambda bh, i, offs: (bh, 0, 0)),
                 *table_specs,
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda bh, i, offs: (bh, i, 0)),
+                pl.BlockSpec((1, block_q, d_v), lambda bh, i, offs: (bh, i, 0)),
                 pl.BlockSpec((1, 8, block_q), lambda bh, i, offs: (bh, 0, i)),
             ],
             scratch_shapes=([] if rope is None
                             else [pltpu.VMEM((1, sk, d), k.dtype)]),
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, sq, d_v), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, sq), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=dispatch.interpret_mode(),
         name="flash_fwd",
     )(offs, qf, kf, vf, *tables)
-    out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    out = out.reshape(b, h, sq, d_v).transpose(0, 2, 1, 3)
     return out, lse[:, 0, :]  # lse: [bh, sq]
 
 
@@ -639,7 +648,7 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
         return step(j * block_q, tile_min - j * block_q, carry, block_k)
 
     carry = (jnp.zeros((block_k, d), jnp.float32),
-             jnp.zeros((block_k, d), jnp.float32))
+             jnp.zeros((block_k, v.shape[-1]), jnp.float32))
     if causal:
         # First query block whose last row sees this tile's first key,
         # then one block for each further `block_q` keys of the tile.
@@ -685,15 +694,15 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, d_v = k.shape[1], v.shape[-1]
     bh = b * h
     block_q, block_k = blocks
     qf = q.transpose(0, 2, 1, 3).reshape(bh, sq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(bh, sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(bh, sk, d)
-    dof = dout.transpose(0, 2, 1, 3).reshape(bh, sq, d)
+    vf = v.transpose(0, 2, 1, 3).reshape(bh, sk, d_v)
+    dof = dout.transpose(0, 2, 1, 3).reshape(bh, sq, d_v)
     delta = jnp.sum(dof.astype(jnp.float32)
-                    * out.transpose(0, 2, 1, 3).reshape(bh, sq, d)
+                    * out.transpose(0, 2, 1, 3).reshape(bh, sq, d_v)
                     .astype(jnp.float32), axis=-1)      # [bh, sq]
     lse8 = _lse8(lse, bh, sq)
     # (delta + (-dlse)) enters every key of a query uniformly: one term.
@@ -702,12 +711,22 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
     seq_spec = pl.BlockSpec((1, 8, sq), lambda g, i, offs: (g, 0, 0))
     full_q = pl.BlockSpec((1, sq, d), lambda g, i, offs: (g, 0, 0))
     k_tile = pl.BlockSpec((1, block_k, d), lambda g, i, offs: (g, i, 0))
+    # do and v (and dv) in the values' width: the same specs at equal widths
+    full_do = full_q if d_v == d else pl.BlockSpec(
+        (1, sq, d_v), lambda g, i, offs: (g, 0, 0))
+    v_tile = k_tile if d_v == d else pl.BlockSpec(
+        (1, block_k, d_v), lambda g, i, offs: (g, i, 0))
     tables, table_specs = _rope_operands(rope, h, sk, d)
     # A float32 table block as VMEM holds it (128 lanes): two tables, each
     # double-buffered, the roped q and the float32 dq being roped come to
     # under six of them (58.0 MiB needed at 8192 x 128; at 2048 x 64 the
     # 48 hold).
     table_mib = -(-sk * max(d, 128) * 4 // 2 ** 20)
+    # What a program holds of a head WHOLE, as VMEM holds it (lanes of
+    # 128): q and dq double-buffered, do double-buffered, dq's float32 sum.
+    # 16 MiB at 8192 x 128, inside the 48; keys of 192 need 26.
+    lanes, v_lanes = -(-d // 128) * 128, -(-d_v // 128) * 128
+    head_mib = -(-sq * (8 * lanes + 4 * v_lanes + 4 * d) // 2 ** 20)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, causal=causal, block_q=block_q,
                           block_k=block_k, seq_q=sq, sm_scale=sm_scale,
@@ -717,9 +736,9 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, sk // block_k),
-            in_specs=[full_q, k_tile, k_tile, full_q, seq_spec, seq_spec,
+            in_specs=[full_q, k_tile, v_tile, full_do, seq_spec, seq_spec,
                       *table_specs],
-            out_specs=[full_q, k_tile, k_tile],
+            out_specs=[full_q, k_tile, v_tile],
             scratch_shapes=[pltpu.VMEM((d, block_k), k.dtype),
                             pltpu.VMEM((d, sq), jnp.float32)]
             + ([] if rope is None else [pltpu.VMEM((1, sq, d), q.dtype)]),
@@ -727,17 +746,18 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, sk, d_v), v.dtype),
         ],
         compiler_params=_compiler_params(
-            48 if rope is None else max(48, 40 + 6 * table_mib)),
+            max(48, 32 + head_mib) if rope is None
+            else max(48, 40 + 6 * table_mib)),
         interpret=dispatch.interpret_mode(),
         name="flash_bwd",
     )(offs, qf, kf, vf, dof, lse8, corr8, *tables)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     dk = dk.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
-    dv = dv.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
+    dv = dv.reshape(b, h, sk, d_v).transpose(0, 2, 1, 3)
     return dq, dk, dv
 
 
@@ -788,7 +808,8 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 # ---------------------------------------------------------------------------
 
 def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
-                 seq_q: int, seq_k: int, blocks, roped: bool) -> None:
+                 seq_q: int, seq_k: int, blocks, roped: bool,
+                 widths=None) -> None:
     """Say in `dispatch.taken()` what the kernels were built to do: each
     kernel's (block_q x block_k), that dq comes out of the backward's one
     pass and over how many key tiles it is summed there, whether the scale
@@ -796,7 +817,8 @@ def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
     no query may see (known here only when the offsets are static; a
     traced offset decides it at run time); under a window also the window
     and the share of the forward's (tile, block) pairs that it visits;
-    `rope_in_kernel` when the kernels rope q and k themselves."""
+    `rope_in_kernel` when the kernels rope q and k themselves; `widths`
+    (keys', values') where the two differ, as `dqk192,dv128`."""
     (fq, fk), (kv_q, kv_k), window = blocks
     static = isinstance(q_off, int) and isinstance(kv_off, int)
     if not causal:
@@ -824,6 +846,8 @@ def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
                 100.0 * visited / ((seq_q // fq) * (seq_k // fk)))
     if roped:
         plan += ",rope_in_kernel"
+    if widths is not None:
+        plan += ",dqk%d,dv%d" % widths
     dispatch.record("flash_attention.plan", plan)
 
 
@@ -836,7 +860,9 @@ def _chunk(q, k, v, q_off, kv_off, causal, sm_scale, blocks, window=None,
         raise ValueError("a window needs causal=True")
     blocks = (*blocks, window)
     _record_plan(q_off, kv_off, causal, sm_scale, q.shape[1], k.shape[1],
-                 blocks, rope is not None)
+                 blocks, rope is not None,
+                 None if v.shape[-1] == q.shape[-1]
+                 else (q.shape[-1], v.shape[-1]))
     if rope is not None:
         rope = _widen_rope(rope)
     # Under a window the scalars are [q_off, kv_off, window]: the kernels
@@ -867,7 +893,11 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     window: Optional[int] = None, rope=None):
-    """Tiled attention. q:[b,s,h,d], k/v:[b,t,h,d] -> [b,s,h,d].
+    """Tiled attention. q:[b,s,h,d], k:[b,t,h,d], v:[b,t,h,e] -> [b,s,h,e].
+    The values may have another width than the keys (e != d: latent
+    attention's 192 / 128): the kernels then contract the scores over d and
+    carry e through the output, do and dv, with nothing padded, and the
+    plan says `dqk<d>,dv<e>`; e == d builds exactly the kernels without.
 
     rope=(cos, sin): attention over rope(q), rope(k), for q and k as they
     come from their projections.  The tables are float32 [b, t, d/2],
@@ -920,7 +950,8 @@ def flash_attention(q, k, v, causal: bool = True,
         blocks = default_blocks(d, sq, sk, q.dtype, window)
     else:
         blocks = ((min(block_q or 512, sq), min(block_k or 512, sk)),) * 2
-    pallas = all(_can_use_pallas(sq, sk, d, bq, bk) for bq, bk in blocks)
+    pallas = all(_can_use_pallas(sq, sk, d, bq, bk, v.shape[-1])
+                 for bq, bk in blocks)
     # The kernels read the queries' rows of the tables at (sk - sq) on:
     # float32 rows come in sublanes of 8.
     if rope is not None and not (pallas and (sk - sq) % 8 == 0):

@@ -9,8 +9,13 @@ learners (rl/) and the bench harness all reuse it.
 
 The model is the configuration's: a config dataclass lives in its model's
 module (models/transformer.py's TransformerConfig, models/hybrid.py's
-HybridConfig), and that module offers `init_params(config, key)`,
-`logical_axes(config)` and `loss_fn(params, batch, config)`.
+HybridConfig, models/latent_moe.py's LatentMoEConfig), and that module
+offers `init_params(config, key)`, `logical_axes(config)` and
+`loss_fn(params, batch, config)`.  A module may also offer
+`loss_and_metrics(params, batch, config) -> (loss, {name: device scalar})`,
+whose scalars then ride in the step's metrics (an expert layer's routing
+counts), and `not_trained(config)`, a tree of bools like the parameters:
+True where a step must leave the leaf as it is.
 """
 
 from __future__ import annotations
@@ -30,6 +35,10 @@ from ray_tpu.parallel.sharding import (
     tree_shardings,
 )
 from ray_tpu.util import device_stats, tracing
+
+
+# what every model's step reports; a model's own metrics ride beside them
+_STEP_METRICS = ("loss", "grad_norm", "step")
 
 
 def default_optimizer(learning_rate: float = 3e-4,
@@ -126,6 +135,14 @@ class ShardedTrainStep:
                 mesh=mesh)
         else:
             self.loss_fn = lambda p, b: model.loss_fn(p, b, config)
+        # the model's own step metrics, where it has some and the loss is its
+        self._loss_and_metrics = None
+        if loss_fn is None and self.num_stages == 1 \
+                and hasattr(model, "loss_and_metrics"):
+            self._loss_and_metrics = lambda p, b: model.loss_and_metrics(
+                p, b, config)
+        self._not_trained = (model.not_trained(config)
+                             if hasattr(model, "not_trained") else None)
         self.param_logical = model.logical_axes(config)
         self.param_shardings = tree_shardings(
             mesh, self.param_logical, rules)
@@ -139,14 +156,16 @@ class ShardedTrainStep:
         self._spanned: set = set()
         self._steps = 0
 
-    def _span(self, name: str, **attrs):
+    def _span(self, name: str, attrs: Optional[Dict[str, Any]] = None,
+              force: bool = False):
         """Host time to place the inputs and enqueue one program.  The
         FIRST call of each program holds its compile or cache load and is
         recorded whatever the tracing flag says (the start-up timeline
-        reads it); later ones follow the flag, and a running profile."""
+        reads it), as is one the caller forces; later ones follow the
+        flag, and a running profile."""
         first = name not in self._spanned
         self._spanned.add(name)
-        return tracing.trace_span(name, attrs or None, force=first)
+        return tracing.trace_span(name, attrs, force=first or force)
 
     # -- init ---------------------------------------------------------------
     def _init_fn(self, rng):
@@ -172,14 +191,22 @@ class ShardedTrainStep:
 
     # -- step ---------------------------------------------------------------
     def _step_fn(self, state, batch):
-        def loss(p):
-            return self.loss_fn(p, batch)
-
-        loss_val, grads = jax.value_and_grad(loss)(state["params"])
+        model_metrics = {}
+        if self._loss_and_metrics is None:
+            loss_val, grads = jax.value_and_grad(
+                lambda p: self.loss_fn(p, batch))(state["params"])
+        else:
+            (loss_val, model_metrics), grads = jax.value_and_grad(
+                lambda p: self._loss_and_metrics(p, batch),
+                has_aux=True)(state["params"])
         grads = jax.tree.map(
             jax.lax.with_sharding_constraint, grads, self.param_shardings)
         updates, opt_state = self.optimizer.update(
             grads, state["opt_state"], state["params"])
+        if self._not_trained is not None:   # weight decay moves them too
+            updates = jax.tree.map(
+                lambda u, frozen: jnp.zeros_like(u) if frozen else u,
+                updates, self._not_trained)
         params = optax.apply_updates(state["params"], updates)
         params = jax.tree.map(
             jax.lax.with_sharding_constraint, params, self.param_shardings)
@@ -187,16 +214,30 @@ class ShardedTrainStep:
             "loss": loss_val.astype(jnp.float32),
             "grad_norm": optax.global_norm(grads).astype(jnp.float32),
             "step": state["step"] + 1,
+            **model_metrics,
         }
         return {"params": params, "opt_state": opt_state,
                 "step": state["step"] + 1}, metrics
 
     def step(self, state, batch):
         self._steps += 1
-        with self._span("train.step", step=self._steps):
+        attrs = {"step": self._steps}   # kept by identity: see trace_span
+        # A model's own metrics ride on the spans of steps 1, 2, 4, 8, ...,
+        # which are recorded whatever the tracing flag says, so that
+        # timeline.json holds them through a run and not for its first step
+        # alone (a routing count drifts as the weights move).  Reading them
+        # waits for that step: a few scalars, a logarithm of the run's
+        # steps times.
+        counted = (self._loss_and_metrics is not None
+                   and self._steps & (self._steps - 1) == 0)
+        with self._span("train.step", attrs, force=counted):
             batch = jax.device_put(batch, self.batch_sharding)
             with self._mesh_scope():
-                return self._step(state, batch)
+                state, metrics = self._step(state, batch)
+            if counted:
+                attrs.update({k: float(v) for k, v in metrics.items()
+                              if k not in _STEP_METRICS})
+            return state, metrics
 
     # -- eval ----------------------------------------------------------------
     @functools.cached_property

@@ -1,0 +1,313 @@
+"""models/latent_moe.py (latent attention + dropless routed experts) at tiny
+widths, kernels interpreted on the CPU, against the benchmark's plain
+reference (benchmark/reference/deepseek_v3_mla_moe.py) on seeded weights."""
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark.reference import deepseek_v3_mla_moe as ref
+from ray_tpu.models import common, latent_moe as lm, moe
+from ray_tpu.ops import grouped_matmul as gm
+
+
+@pytest.fixture(autouse=True)
+def _interpret_mode(monkeypatch):
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+
+
+def _f32(**kw):
+    return lm.LatentMoEConfig.tiny(dtype=jnp.float32, remat=False, **kw)
+
+
+def _dims(config):
+    return ref.dims_from_config(
+        {f.name: getattr(config, f.name) for f in dataclasses.fields(config)})
+
+
+def _tokens(rows=2, seq=128, vocab=256, seed=1):
+    return np.asarray(jax.random.randint(jax.random.PRNGKey(seed),
+                                         (rows, seq + 1), 0, vocab))
+
+
+@pytest.mark.parametrize("fused_ce", [False, True])
+def test_token_nll_matches_the_reference(fused_ce):
+    config = _f32(fused_ce=fused_ce)
+    params = lm.init_params(config, jax.random.PRNGKey(3))
+    tokens = _tokens()
+    got = lm.token_nll(params, {"tokens": jnp.asarray(tokens)}, config)
+    want = ref.batch_token_nll(params, tokens, _dims(config))
+    # the fused cross-entropy multiplies in bfloat16 whatever the model's
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=3e-2 if fused_ce else 2e-4)
+    assert abs(float(got.mean()) - np.log(256)) < 1.0
+
+
+def test_gradient_matches_the_reference_layer_by_layer():
+    """jax.grad of the program's loss (the grouped kernels' and the flash
+    kernels' VJPs, the gathers' custom VJPs) against the reference's
+    gradient walked back a layer at a time; the selection bias gets none."""
+    config = _f32()
+    params = lm.init_params(config, jax.random.PRNGKey(4))
+    tokens = _tokens()
+    got = jax.grad(lambda p: lm.loss_fn(p, {"tokens": jnp.asarray(tokens)},
+                                        config))(params)
+    want = {}
+    for row in tokens:
+        run = ref.Pass(params, row[:-1], _dims(config), for_grads=True)
+        for path, grad in run.grads(row[1:]):
+            for name, g in (grad.items() if isinstance(grad, dict)
+                            else [(None, grad)]):
+                want[path, name] = want.get((path, name), 0) \
+                    + np.asarray(g) / len(tokens)
+    seen = 0
+    for (path, name), w in want.items():
+        g = got[path[0]] if name is None \
+            else got["layers"][path[1]][path[2]][name][path[3]]
+        g, norm = np.asarray(g), np.linalg.norm(w)
+        if name == "router_bias":
+            assert norm == 0 and not g.any()
+            continue
+        assert np.linalg.norm(g - w) <= 2e-4 * norm, (path, name)
+        seen += 1
+    # three at the top, a dense layer's ten, two expert layers' fifteen each
+    assert len(want) == 3 + 10 + 2 * 15 and seen == len(want) - 2
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Eight chips, two of sixteen experts each: the routed parts that the
+    program's layer gives for the eight shares, plus the shared expert
+    counted ONCE, are what the uncut sixteen-expert reference layer gives."""
+    config = _f32(n_routed_experts=16, router_width=16)
+    lp = jax.tree.map(
+        lambda a: a[0], lm.init_params(config, jax.random.PRNGKey(5))
+        ["layers"]["seg01"]["0"])
+    h = jax.random.normal(jax.random.PRNGKey(6), (96, config.hidden_size))
+    idx, gates = moe.sigmoid_route(
+        h, lp["router_w"], lp["router_bias"],
+        num_experts_per_token=config.num_experts_per_tok,
+        scale=config.routed_scaling_factor)
+    total, rows = 0.0, 0
+    for share in range(8):
+        held = slice(2 * share, 2 * share + 2)
+        part, stats = moe.routed_experts(
+            h, idx, gates, lp["experts_gate"][held], lp["experts_up"][held],
+            lp["experts_down"][held], experts_held=(2 * share, 2),
+            dtype=jnp.float32, tile_m=16)
+        total, rows = total + part, rows + int(stats["rows_held"])
+    assert rows == 96 * config.num_experts_per_tok      # every assignment
+    total = total + common.swiglu(h, lp["shared_gate"], lp["shared_up"],
+                                  lp["shared_down"], jnp.float32)
+    d = _dims(config)
+    with jax.default_matmul_precision("highest"):
+        want = ref.whole_layer_ffn(h, lp, d, (0, 16)) + ref._swiglu(
+            h, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want),
+                               atol=2e-5, rtol=1e-5)
+    # and one share is what the reference gives for that share alone
+    one = moe.routed_experts(
+        h, idx, gates, lp["experts_gate"][4:8], lp["experts_up"][4:8],
+        lp["experts_down"][4:8], experts_held=(4, 4), dtype=jnp.float32,
+        tile_m=16)[0]
+    np.testing.assert_allclose(
+        np.asarray(one),
+        np.asarray(ref.whole_layer_ffn(h, jax.tree.map(
+            lambda a: a[4:8] if a.shape[:1] == (16,) and a.ndim == 3 else a,
+            lp), d, (4, 4))), atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["every_token_chooses_held_experts",
+                                  "every_token_chooses_one_held_expert"])
+def test_no_row_is_dropped_whatever_the_routing(case):
+    """No capacity factor: with every token's choices inside the held
+    experts the row bound is REACHED and every row is computed; with every
+    token also choosing one and the same expert that expert holds a row of
+    every token."""
+    config = _f32()
+    first, count = 4, 4
+    k, tokens = config.num_experts_per_tok, 64
+    lp = jax.tree.map(
+        lambda a: a[0], lm.init_params(config, jax.random.PRNGKey(7))
+        ["layers"]["seg01"]["0"])
+    bias = jnp.zeros(16).at[first:first + count].set(5.0)
+    if case == "every_token_chooses_one_held_expert":
+        bias = bias.at[first + 1].set(9.0)
+    h = jax.random.normal(jax.random.PRNGKey(8), (tokens, config.hidden_size))
+    idx, gates = moe.sigmoid_route(h, lp["router_w"], bias,
+                                   num_experts_per_token=k,
+                                   scale=config.routed_scaling_factor)
+    assert bool(jnp.all((idx >= first) & (idx < first + count)))
+    y, stats = moe.routed_experts(
+        h, idx, gates, lp["experts_gate"], lp["experts_up"],
+        lp["experts_down"], experts_held=(first, count), dtype=jnp.float32,
+        tile_m=16)
+    assert int(stats["rows_held"]) == tokens * k == int(stats["rows_bound"])
+    if case == "every_token_chooses_one_held_expert":
+        assert int(stats["load_max"]) == tokens
+    want = ref.whole_layer_ffn(
+        h, {**lp, "router_bias": bias},
+        {**_dims(config), "router_width": 16}, (first, count))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5,
+                               rtol=1e-5)
+
+
+@pytest.mark.parametrize("usual_rows,side", [(None, "the bound's buffer"),
+                                             (64, "the usual buffer"),
+                                             (8, "over the usual: the bound's")])
+def test_both_buffer_sizes_compute_every_row(usual_rows, side):
+    """`usual_rows`: a step whose rows fit takes the shorter buffer, one
+    whose rows do not takes the bound's; values and gradients are the same
+    either way, and equal the reference's."""
+    config = _f32()
+    lp = jax.tree.map(
+        lambda a: a[0], lm.init_params(config, jax.random.PRNGKey(13))
+        ["layers"]["seg01"]["0"])
+    h = jax.random.normal(jax.random.PRNGKey(14), (48, config.hidden_size))
+    idx, gates = moe.sigmoid_route(
+        h, lp["router_w"], lp["router_bias"],
+        num_experts_per_token=config.num_experts_per_tok,
+        scale=config.routed_scaling_factor)
+    weights = (lp["experts_gate"], lp["experts_up"], lp["experts_down"])
+
+    def layer(h, gates, weights, usual_rows):
+        return moe.routed_experts(
+            h, idx, gates, *weights, experts_held=(0, 4), dtype=jnp.float32,
+            tile_m=16, usual_rows=usual_rows)
+
+    y, stats = layer(h, gates, weights, usual_rows)
+    held = int(stats["rows_held"])
+    assert 8 < held <= 64 < int(stats["rows_bound"]) == 48 * 3
+    want = ref.whole_layer_ffn(h, lp, _dims(config), (0, 4))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5,
+                               rtol=1e-5)
+    w = jax.random.normal(jax.random.PRNGKey(15), y.shape)
+    grads = [jax.grad(lambda h, g, ws: jnp.sum(layer(h, g, ws, u)[0] * w),
+                      argnums=(0, 1, 2))(h, gates, weights)
+             for u in (usual_rows, None)]
+    for got, base in zip(jax.tree.leaves(grads[0]),
+                         jax.tree.leaves(grads[1])):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(base),
+                                   atol=1e-5, rtol=1e-5)
+
+
+def test_the_bias_selects_and_is_not_in_the_gate():
+    config = _f32()
+    w = jax.random.normal(jax.random.PRNGKey(9), (config.hidden_size, 16))
+    h = jax.random.normal(jax.random.PRNGKey(10), (32, config.hidden_size))
+    bias = jnp.zeros(16).at[3].set(10.0)
+    idx, gates = moe.sigmoid_route(h, w, bias, num_experts_per_token=3,
+                                   scale=2.448)
+    assert bool(jnp.all(jnp.any(idx == 3, axis=-1)))
+    np.testing.assert_allclose(np.asarray(gates.sum(-1)), 2.448, rtol=1e-6)
+    scores = jax.nn.sigmoid(h @ w)
+    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    np.testing.assert_allclose(
+        np.asarray(gates),
+        np.asarray(picked / picked.sum(-1, keepdims=True) * 2.448), rtol=1e-5)
+    grad = jax.grad(lambda b: moe.sigmoid_route(
+        h, w, b, num_experts_per_token=3, scale=2.448)[1].sum())(bias)
+    assert not np.asarray(grad).any()
+
+
+def test_the_probe_runs_the_routed_layer_alone_on_the_references_operands():
+    config = _f32()
+    params = lm.init_params(config, jax.random.PRNGKey(11))
+    run = ref.Pass(params, _tokens()[0, :-1], _dims(config))
+    operands, want = run.routed_experts()
+    got = lm.routed_experts(*operands, config=config)
+    assert got.shape == want.shape == (1, 128, config.hidden_size)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    # in the model's own dtype the rows are bfloat16: close, not equal
+    low = lm.routed_experts(*operands, config=lm.LatentMoEConfig.tiny())
+    err = float(jnp.linalg.norm(low - want) / jnp.linalg.norm(want))
+    assert 1e-4 < err < 2e-2
+
+
+def test_train_step_carries_the_counts_and_leaves_the_bias(monkeypatch):
+    """Through ShardedTrainStep: the step's metrics hold the last expert
+    layer's routing counts, its first span holds them as attributes (what
+    timeline.json keeps), AdamW's weight decay does not move the selection
+    bias, and every other leaf moves."""
+    from ray_tpu.parallel.mesh import build_mesh
+    from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
+    from ray_tpu.util import tracing
+
+    config = lm.LatentMoEConfig.tiny(fused_ce=True)
+    mesh = build_mesh(axes={"fsdp": 1}, devices=jax.devices()[:1])
+    ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
+        warmup_steps=1, total_steps=10, mu_dtype=jnp.bfloat16,
+        nu_dtype=jnp.bfloat16))
+    state = ts.init(jax.random.PRNGKey(0))
+    before = jax.tree.map(np.asarray, state["params"])
+    batch = {"tokens": jnp.asarray(_tokens())}
+    for _ in range(5):
+        state, metrics = ts.step(state, batch)
+    tokens = 2 * 128
+    assert int(metrics["moe_rows_bound"]) == tokens * 3
+    assert 0 < int(metrics["moe_rows_held"]) <= tokens * 3
+    assert float(metrics["moe_load_mean"]) == int(metrics["moe_rows_held"]) / 4
+    assert int(metrics["moe_load_max"]) >= float(metrics["moe_load_mean"])
+    assert int(metrics["moe_rows_held_all_layers"]) >= int(
+        metrics["moe_rows_held"])       # two expert layers' against the last's
+    # steps 1, 2 and 4 are recorded whatever the tracing flag says, with
+    # the counts; 3 and 5 are not
+    spans = [s for s in tracing.get_spans(("train.",))
+             if s["name"] == "train.step"][-3:]
+    assert [s["attributes"]["step"] for s in spans] == [1, 2, 4]
+    for s in spans:
+        assert s["attributes"]["moe_rows_bound"] == tokens * 3
+        assert {"moe_load_max", "moe_load_mean", "moe_rows_held",
+                "moe_rows_held_all_layers"} <= set(s["attributes"])
+    after = jax.tree.map(np.asarray, state["params"])
+    frozen = lm.not_trained(config)
+    moved = jax.tree.map(lambda a, b: bool((a != b).any()), before, after)
+    for (path, is_frozen), (_, has_moved) in zip(
+            jax.tree_util.tree_flatten_with_path(frozen)[0],
+            jax.tree_util.tree_flatten_with_path(moved)[0]):
+        assert has_moved != is_frozen, path
+    assert sum(jax.tree.leaves(frozen)) == 1    # one stacked leaf: the bias
+
+
+def test_layout_names_and_count():
+    config = lm.LatentMoEConfig.tiny()
+    params = jax.eval_shape(lambda: lm.init_params(config,
+                                                   jax.random.PRNGKey(0)))
+    assert sorted(params) == ["final_norm_w", "layers", "lm_head",
+                              "tok_embed"]
+    assert sorted(params["layers"]) == ["seg00", "seg01"]
+    assert params["layers"]["seg00"]["0"]["w_gate"].shape == (1, 64, 128)
+    assert params["layers"]["seg01"]["0"]["experts_gate"].shape == (
+        2, 4, 64, 32)
+    assert params["layers"]["seg01"]["0"]["router_w"].shape == (2, 64, 16)
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)) \
+        == lm.num_params(config)
+    axes = lm.logical_axes(config)
+    assert jax.tree.structure(axes, is_leaf=lambda x: isinstance(x, tuple)) \
+        == jax.tree.structure(params)
+    assert lm.LatentMoEConfig().qk_head_dim == 192
+
+
+@pytest.mark.parametrize("bad", [
+    {"q_lora_rank": 1536}, {"scoring_func": "softmax"}, {"n_group": 8},
+    {"tie_word_embeddings": True}, {"rope_interleave": False},
+    {"first_held_expert": 14}, {"rope_scaling": {"type": "yarn"}}])
+def test_what_is_not_written_down_is_refused(bad):
+    with pytest.raises(ValueError):
+        lm.LatentMoEConfig.tiny(**bad)
+
+
+def test_rope_turns_interleaved_pairs():
+    config = lm.LatentMoEConfig.tiny()
+    cos, sin = lm.rope_tables(8, config)
+    x = jax.random.normal(jax.random.PRNGKey(12), (1, 8, 2, 16))
+    got = lm.rope_interleaved(x, cos, sin)
+    want = ref._rope(x[0], config.rope_theta)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got[0, 0]), np.asarray(x[0, 0]),
+                               atol=1e-6)     # position 0 is not turned
+    assert gm.TILE_M % 16 == 0

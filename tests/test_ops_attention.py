@@ -913,3 +913,88 @@ def test_rope_under_a_batch_sharded_mesh_is_the_one_device_call():
         four = jax.jit(call)(*(jax.device_put(x, rows)
                                for x in (q, k, v, *rope)))
     np.testing.assert_array_equal(np.asarray(four), np.asarray(one))
+
+
+# ---------------------------------------------------------------------------
+# Values of another width than the keys (PR 34: latent attention in training,
+# keys 192 wide, values 128): the same two kernels, nothing padded.
+# ---------------------------------------------------------------------------
+
+# (d, e, sq, sk, block_q, block_k, causal, window)
+_WIDTHS = [
+    (192, 128, 256, 256, 128, 128, True, None),     # the cell's widths
+    (192, 128, 512, 512, None, None, True, None),   # default_blocks' plan
+    (192, 128, 512, 512, 256, 128, True, None),     # narrow forward steps
+    (192, 128, 512, 512, 128, 256, True, None),     # narrow backward steps
+    (192, 128, 128, 384, 128, 128, True, None),     # fewer queries than keys
+    (192, 128, 256, 256, 128, 128, False, None),
+    (192, 128, 512, 512, 128, 128, True, 192),      # under a window
+    (64, 128, 256, 256, 128, 128, True, None),      # values the wider; folded
+    (128, 64, 256, 512, 128, 256, True, None),
+]
+
+
+@pytest.mark.parametrize("d,e,sq,sk,bq,bk,causal,window", _WIDTHS)
+def test_value_width_differs_values_and_grads_match_reference(
+        d, e, sq, sk, bq, bk, causal, window):
+    """out, dq, dk and dv of the Pallas kernels against
+    `attention_reference` where v is e wide and q, k are d wide; dv comes
+    out e wide, dq and dk d wide."""
+    ks = jax.random.split(jax.random.PRNGKey(d + e + sq + sk), 4)
+    q = jax.random.normal(ks[0], (1, sq, 2, d), jnp.float32)
+    k = jax.random.normal(ks[1], (1, sk, 2, d), jnp.float32)
+    v = jax.random.normal(ks[2], (1, sk, 2, e), jnp.float32)
+    w = jax.random.normal(ks[3], (1, sq, 2, e), jnp.float32)
+    out, grads = _grads_and_value(
+        lambda q, k, v: attn.flash_attention(
+            q, k, v, causal=causal, block_q=bq, block_k=bk, window=window),
+        q, k, v, w)
+    ref, ref_grads = _grads_and_value(
+        lambda q, k, v: attn.attention_reference(q, k, v, causal=causal,
+                                                 window=window), q, k, v, w)
+    assert out.shape == (1, sq, 2, e)
+    assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    for g, r in zip(grads, ref_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=5e-4, rtol=5e-4)
+
+
+def test_value_width_is_seen_in_the_input_and_said_in_the_plan(monkeypatch):
+    """Keys 192 / values 128 take the kernels (never the XLA path, never a
+    padded v) and the plan says both widths; equal widths record the plan
+    they always did, with no word about widths."""
+    monkeypatch.setattr(attn.dispatch, "_taken", {})
+    x = jnp.ones((1, 256, 2, 192), jnp.bfloat16)
+    out = attn.flash_attention(x, x, x[..., :128], block_q=128, block_k=128)
+    assert out.shape == (1, 256, 2, 128) and out.dtype == jnp.bfloat16
+    taken = attn.dispatch.taken()
+    assert taken["flash_attention"] == {"interpret": 1}
+    assert list(taken["flash_attention.plan"]) == [
+        "fwd128x128,bwd128x128,dq_in_pass,dq_over2tiles,scale_per_score,"
+        "dead33/33%,dqk192,dv128"]
+    monkeypatch.setattr(attn.dispatch, "_taken", {})
+    attn.flash_attention(x, x, x, block_q=128, block_k=128)
+    assert list(attn.dispatch.taken()["flash_attention.plan"]) == [
+        "fwd128x128,bwd128x128,dq_in_pass,dq_over2tiles,scale_per_score,"
+        "dead33/33%"]
+
+
+def test_value_width_bfloat16_backward_gives_each_gradient_its_own_width():
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (2, 256, 2, 192), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (2, 256, 2, 192), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (2, 256, 2, 128), jnp.bfloat16)
+
+    def loss(fn):
+        return lambda q, k, v: fn(q, k, v).astype(jnp.float32).sum()
+
+    got = jax.grad(loss(lambda q, k, v: attn.flash_attention(
+        q, k, v, block_q=128, block_k=128)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(attn.attention_reference), argnums=(0, 1, 2))(
+        q, k, v)
+    for g, r, x in zip(got, want, (q, k, v)):
+        assert g.shape == x.shape and g.dtype == jnp.bfloat16
+        err = jnp.linalg.norm((g - r).astype(jnp.float32))
+        assert float(err / jnp.linalg.norm(r.astype(jnp.float32))) < 0.02

@@ -650,3 +650,163 @@ def test_dense_layer_moves_q_and_k_to_the_kernels_once_and_unroped(
                                            key=lambda g: -g[2])[:20]
     assert 0.9e9 < moved < 1.15e9, (
         moved, sorted(glue.values(), key=lambda g: -g[2])[:20])
+
+
+# ---------------------------------------------------------------------------
+# train-moe-mla-d6 (PR 34): latent attention's 192 / 128 flash calls, the
+# grouped-matmul kernels and the cell's whole step program
+# ---------------------------------------------------------------------------
+
+MOE_ROWS, MOE_SEQ, MOE_HEADS = 2, 8192, 32
+MOE_TOKENS, MOE_HELD, MOE_TOP_K = MOE_ROWS * MOE_SEQ, 16, 6
+
+
+def _moe_faces():
+    from benchmark import moe_faces
+
+    return moe_faces
+
+
+def test_cell_latent_flash_compiles_and_keeps_the_face_its_reader_finds(
+        one_chip, monkeypatch):
+    """Keys 192 wide, values 128, at the cell's shapes: ONE forward call
+    whose results are 128 wide and whose q is 192 wide (what
+    mla_fwd_roofline.moe matches; the dense and hybrid forward readers'
+    pattern matches it too, and they never see this cell), one backward
+    call with dq, dk 192 wide and dv 128; nothing padded; the plan says
+    both widths."""
+    import re
+
+    _on_tpu(monkeypatch, attention)
+    monkeypatch.setattr(attention.dispatch, "_taken", {})
+    q = jax.ShapeDtypeStruct((MOE_ROWS, MOE_SEQ, MOE_HEADS, 192),
+                             jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((MOE_ROWS, MOE_SEQ, MOE_HEADS, 128),
+                             jnp.bfloat16, sharding=one_chip)
+    face = _moe_faces().MLA_FORWARD
+    assert _reader("mla_fwd_roofline.moe").KERNEL == face
+    sm_scale = 192 ** -0.5
+
+    def attend(q, k, v):
+        return attention.flash_attention(q, k, v, sm_scale=sm_scale)
+
+    calls = _custom_calls_as_traced(attend, q, q, v)
+    assert len(calls) == 1 and re.search(face, calls[0]), calls
+    assert "(bf16[64,8192,128], f32[64,8,8192])" in calls[0]
+    calls = _custom_calls_as_traced(
+        jax.grad(lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+                 argnums=(0, 1, 2)), q, q, v)
+    backward = [l for l in calls if not re.search(face, l)]
+    assert len(calls) == 2 and len(backward) == 1, calls
+    assert ("= (bf16[64,8192,192], bf16[64,8192,192], bf16[64,8192,128]) "
+            "custom-call(s32[2] ") in backward[0]
+    # an equal-width call is not mistaken for it
+    x = jax.ShapeDtypeStruct((1, MOE_SEQ, 40, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    calls = _custom_calls_as_traced(
+        lambda q, k, v: attention.flash_attention(q, k, v), x, x, x)
+    assert len(calls) == 1 and not re.search(face, calls[0])
+    plans = list(attention.dispatch.taken()["flash_attention.plan"])
+    assert ("fwd2048x512,bwd512x2048,dq_in_pass,dq_over4tiles,"
+            "scale_per_score,dead6/6%,dqk192,dv128") in plans
+    assert ("fwd2048x512,bwd512x2048,dq_in_pass,dq_over4tiles,"
+            "scale_per_score,dead6/6%") in plans
+
+
+def test_cell_grouped_matmul_kernels_compile_and_keep_their_faces(
+        one_chip, monkeypatch):
+    """Forward, transposed (dx) and dw at the cell's widths (2048 <-> 768,
+    16 groups, the bound of 6 x 16,384 rows): each custom-call is found by
+    exactly one of benchmark/moe_faces.py's patterns, which the grouped
+    readers share."""
+    import re
+
+    from ray_tpu.ops import grouped_matmul as gm
+
+    _on_tpu(monkeypatch, gm)
+    monkeypatch.setattr(gm.dispatch, "_taken", {})
+    faces = _moe_faces()
+    patterns = {"forward": faces.GROUPED_FORWARD,
+                "transposed": faces.GROUPED_TRANSPOSED,
+                "dw": faces.GROUPED_DW}
+    assert _reader("grouped_matmul_roofline.moe").KERNEL == patterns["forward"]
+    assert _reader("grouped_matmul_share.moe").KERNELS == tuple(
+        patterns.values())
+    rows = gm.layout_rows(MOE_TOKENS * MOE_TOP_K, MOE_HELD)
+    assert rows == 102_400
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def kinds(calls):
+        found = [[k for k, p in patterns.items() if re.search(p, l)]
+                 for l in calls]
+        assert all(len(f) == 1 for f in found), (calls, found)
+        return sorted(f[0] for f in found)
+
+    for k, n in ((2048, 768), (768, 2048)):
+        def product(x, w, sizes):
+            return gm.grouped_matmul(x, w, gm.group_layout(sizes, rows))
+
+        shapes = (sds((rows, k)), sds((MOE_HELD, k, n)),
+                  sds((MOE_HELD,), jnp.int32))
+        calls = _custom_calls_as_traced(product, *shapes)
+        assert kinds(calls) == ["forward"], calls
+        assert f"= bf16[{rows},{n}] custom-call(s32[400] " in calls[0]
+        calls = _custom_calls_as_traced(
+            jax.grad(lambda *a: product(*a).astype(jnp.float32).sum(),
+                     argnums=(0, 1)), *shapes)
+        assert kinds(calls) == ["dw", "transposed"], calls
+    taken = gm.dispatch.taken()
+    assert set(taken["grouped_matmul"]) == {"pallas"}
+    assert sorted(taken["grouped_matmul.plan"]) == [
+        "tile256x2048,rows102400,groups16", "tile256x768,rows102400,groups16"]
+
+
+def test_cell_latent_moe_step_program_fits_a_v5e(topo, monkeypatch):
+    """The cell's whole step program (a dense and five expert layers, 16 of
+    128 experts, an eighth of the vocabulary, 2 x 8192 tokens, full remat,
+    fused CE, bfloat16 moments) by AOT memory_analysis: under 15.75 GiB at
+    the configuration's rows."""
+    import json
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmark.drivers import train_model
+    from ray_tpu.ops import grouped_matmul as gm
+    from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
+
+    _on_tpu(monkeypatch, attention)
+    _on_tpu(monkeypatch, gm)
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmark", "configs",
+                        "kanana-2-30b-a3b-train-d6e16.json")
+    doc = json.load(open(path))
+    tr = doc["train"]
+    assert tr["batch_rows"] == MOE_ROWS and tr["sequence_length"] == MOE_SEQ
+    config = train_model.build_config(doc["program"], doc["model"], tr)
+    mesh = Mesh(topo.devices[:1], ("fsdp",))
+    whole = NamedSharding(mesh, P())
+    ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
+        warmup_steps=tr["lr_warmup_steps"], total_steps=tr["lr_total_steps"],
+        mu_dtype=jnp.bfloat16, nu_dtype=jnp.bfloat16))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    with jax.sharding.set_mesh(mesh):
+        state = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=whole),
+            jax.eval_shape(ts._init_fn, key))
+        batch = {"tokens": jax.ShapeDtypeStruct(
+            (tr["batch_rows"], tr["sequence_length"] + 1), jnp.int32,
+            sharding=whole)}
+        compiled = jax.jit(ts._step_fn, donate_argnums=(0,)).lower(
+            state, batch).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 13.0 * 2 ** 30 < total < 15.75 * 2 ** 30, total / 2 ** 30
+    # The dense layer: flash forward, forward again under remat, backward
+    # (3).  The five expert layers are ONE scanned body: those three and
+    # the grouped kernels, three forward, three again for the backward,
+    # three transposed and three dw (12), at each of the layer's two
+    # buffer sizes (the usual and the full bound: a cond's two sides).
+    assert compiled.as_text().count("tpu_custom_call") == 3 + 3 + 2 * 12
