@@ -18,7 +18,9 @@ bias anywhere):
              on q_pe and k_pe only; k = [k_nope | k_pe];
              causal softmax(q k^T / sqrt(nope + rope)) v -> [T, heads, v];
              W_o: heads x v -> hidden.  Keys are nope + rope wide (192),
-             values v wide (128): ONE flash_attention call, nothing padded.
+             values v wide (128): ONE call of the flash kernels, which take
+             q, [k_nope | v] and k_pe as the projections lay them and rope
+             and put the keys together themselves (`_attention`).
   dense FFN  the first `first_k_dense_replace` layers: SwiGLU of
              `intermediate_size`.
   expert FFN s = sigmoid(h W_r) in float32 over `router_width` experts; a
@@ -63,6 +65,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models import common, moe
 from ray_tpu.models.transformer import rms_norm
@@ -308,7 +311,10 @@ def rope_tables(seq: int, c: LatentMoEConfig):
 
 def rope_interleaved(x, cos, sin):
     """x [b, s, heads, rope]: the pairs (2i, 2i + 1) turned by the angle of
-    frequency i; float32 arithmetic, rounded once to x's dtype."""
+    frequency i; float32 arithmetic, rounded once to x's dtype.  The
+    published pairing, written down plainly: the program ropes inside the
+    flash kernels (`_attention`, `_pairs_halved`), and the tests hold it
+    to this."""
     pairs = x.astype(F32).reshape(*x.shape[:-1], -1, 2)
     even, odd = pairs[..., 0], pairs[..., 1]
     c, s = cos[None, :, None, :], sin[None, :, None, :]
@@ -316,28 +322,49 @@ def rope_interleaved(x, cos, sin):
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def _pairs_halved(x, width: int, rope: int):
+    """x [.., n x width], whose last `rope` columns of every `width` hold
+    the rotary pairs (2i, 2i + 1): the same with those columns in the order
+    [evens | odds], pair i now (i, i + rope / 2), the pairing the flash
+    kernels' rope turns (`ops.attention.rope_reference`).  q_pe . k_pe does
+    not see ONE reordering of both, so attention is what the published
+    pairing gives.  Done where it is cheap, on W_q (25 MB a layer, where q
+    is 201) and on the one rotary key, as a matmul with a constant 0 / 1
+    matrix: exact in any dtype, and the gradient comes back through its
+    transpose, so the parameters keep their published column order."""
+    first = width - rope
+    order = np.arange(width)
+    order[first:] = first + np.concatenate([np.arange(0, rope, 2),
+                                            np.arange(1, rope, 2)])
+    reorder = np.zeros((width, width), np.float32)
+    reorder[order, np.arange(width)] = 1.0
+    out = jnp.einsum("...gw,wv->...gv", x.reshape(*x.shape[:-1], -1, width),
+                     jnp.asarray(reorder, x.dtype),
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=x.dtype)
+    return out.reshape(x.shape)
+
+
 def _attention(u, lp, cos, sin, c: LatentMoEConfig):
-    from ray_tpu.ops.attention import flash_attention
+    """q, [k_nope | v] and the one rotary key go to the kernels as their
+    projections lay them, un-roped (`latent_flash_attention`)."""
+    from ray_tpu.ops.attention import latent_flash_attention
 
     b, s, _ = u.shape
     heads, nope, rope = (c.num_attention_heads, c.qk_nope_head_dim,
                          c.qk_rope_head_dim)
     rank, dv = c.kv_lora_rank, c.v_head_dim
     with jax.named_scope("mla.project"):
-        q = _matmul(u, lp["wq"], c).reshape(b, s, heads, nope + rope)
+        wq = _pairs_halved(lp["wq"].astype(c.dtype), nope + rope, rope)
+        q = _matmul(u, wq, c).reshape(b, s, heads, nope + rope)
+        q = with_logical_constraint(q, ("batch", "seq", "heads", None))
         latent = _matmul(u, lp["wkv_a"], c)
         c_kv = rms_norm(latent[..., :rank], lp["kv_norm_w"], c.rms_norm_eps)
         kv = _matmul(c_kv, lp["wkv_b"], c).reshape(b, s, heads, nope + dv)
-        k_pe = rope_interleaved(latent[..., None, rank:], cos, sin)
-        q = jnp.concatenate(
-            [q[..., :nope], rope_interleaved(q[..., nope:], cos, sin)],
-            axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(k_pe, (b, s, heads, rope))],
-            axis=-1)
-        q = with_logical_constraint(q, ("batch", "seq", "heads", None))
-    a = flash_attention(q, k, kv[..., nope:], causal=True,
-                        sm_scale=1.0 / math.sqrt(nope + rope))
+        k_pe = _pairs_halved(latent[..., rank:], rope, rope)
+    tables = tuple(jnp.broadcast_to(t, (b, *t.shape)) for t in (cos, sin))
+    a = latent_flash_attention(q, kv, k_pe, tables,
+                               sm_scale=1.0 / math.sqrt(nope + rope))
     return _matmul(a.reshape(b, s, heads * dv), lp["wo"], c)
 
 

@@ -43,6 +43,13 @@ machinery is absent in-tree); on TPU this is a core op.  Design:
     accumulator, do and dv take the values' width and whose scores contract
     over the keys'.  Nothing is padded; equal widths build the kernels as
     they were.
+  - Latent attention IN PARTS (`latent_flash_attention`): q un-roped, a
+    head's [k_nope | v] as its projection lays it, ONE rotary key shared
+    by all heads.  The kernels read a head's block of the projection's
+    output by lane-block index, rope the last columns of q and the one
+    rotary key in VMEM and put the keys together there, so the
+    concatenate, split, broadcast and rope passes never cross HBM; the
+    backward writes [dk_nope | dv] as the projection's gradient reads it.
 
 Layout convention: q, k are [batch, seq, heads, head_dim], v is [batch, seq,
 heads, value_dim] (the models/ convention); kernels internally fold
@@ -313,6 +320,28 @@ def _roped(x, cos, sin):
             + _swap_halves(x).astype(jnp.float32) * sin)
 
 
+def _roped_from(nope: int, x, cos, sin):
+    """[rows, nope + r] with its columns from nope on (lane-aligned) roped
+    and rounded to x's dtype, the others as they are: latent attention's
+    q, whose rotary part is its last r columns.  With -sin and a float32
+    sum, that sum's gradient turned back, still float32."""
+    return jnp.concatenate(
+        [x[:, :nope], _roped(x[:, nope:], cos, sin).astype(x.dtype)], axis=1)
+
+
+def _for_row_blocks(body, length: int, block: int) -> None:
+    """body(rows) for each `block` rows of `length`, in a loop: one
+    statement over a head's whole rows would hold them whole in VMEM,
+    float32, between its load and its store."""
+    from jax.experimental import pallas as pl
+
+    def one(i, _):
+        body(pl.ds(pl.multiple_of(i * block, block), block))
+        return 0
+
+    jax.lax.fori_loop(0, length // block, one, 0)
+
+
 def _put(old, at: int, new):
     """`old` [.., queries] with its queries from `at` on (static) replaced."""
     return jnp.concatenate([old[:, :at], new], axis=1) if at else new
@@ -374,20 +403,33 @@ def _walk_blocks(step, carry, causal: bool, tile_min, tile: int, inner: int,
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, *refs,
                 causal: bool, block_q: int, block_k: int, seq_k: int,
                 sm_scale: float, fold_scale: bool, windowed: bool = False,
-                rope_q0: Optional[int] = None):
+                rope_q0: Optional[int] = None, parts=None):
     """rope_q0 (None: no rope, the kernel without): the row of the tables,
     which hold the KEYS' positions, at which the queries' begin.  Then refs
     holds the two tables before the outputs and, after them, a scratch for
-    the head's roped keys."""
+    the head's roped keys.
+
+    parts (with rope_q0; None: whole operands, the kernel without): (nope,
+    heads) of latent attention.  k_ref is then a head's [k_nope | v] as the
+    projection lays it and v_ref the row's ONE rotary key, un-roped; the
+    tables are as wide as that key, q is roped from column nope on, and a
+    second scratch holds the row's roped rotary key, made once for all its
+    heads."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     window = offs_ref[2] if windowed else None
     q = q_ref[0]  # [block_q, d]
     d = v_ref.shape[-1]     # the accumulator's rows: the values' width
+    v_cols = slice(None)
+
+    def q_table_rows():
+        return pl.ds(pl.multiple_of(rope_q0 + qi * block_q,
+                                    math.gcd(rope_q0, block_q)), block_q)
+
     if rope_q0 is None:
         o_ref, lse_ref = refs
-    else:
+    elif parts is None:
         cos_ref, sin_ref, o_ref, lse_ref, roped_k_ref = refs
 
         # A head's keys are roped once, at its first query tile, and stay
@@ -400,11 +442,39 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, *refs,
             k_ref[0] = _roped(plain_k_ref[0], cos_ref[0],
                               sin_ref[0]).astype(k_ref.dtype)
 
-        rows = pl.ds(pl.multiple_of(rope_q0 + qi * block_q,
-                                    math.gcd(rope_q0, block_q)), block_q)
+        rows = q_table_rows()
         # rounded to the operand's dtype before the scale and any matmul,
         # as rope in XLA rounds it
         q = _roped(q, cos_ref[0, rows, :], sin_ref[0, rows, :]).astype(q.dtype)
+    else:
+        nope, heads = parts
+        cos_ref, sin_ref, o_ref, lse_ref, roped_k_ref, roped_pe_ref = refs
+        # v is the lane-aligned end of the head's [k_nope | v]; the head's
+        # keys, [k_nope | rope(k_pe)], are put together in scratch at its
+        # first query tile, as the whole keys are roped there above
+        plain_pe_ref, v_ref, k_ref = v_ref, k_ref, roped_k_ref
+        d, v_cols = v_ref.shape[-1] - nope, slice(nope, None)
+
+        def rope_pe(at):
+            roped_pe_ref[at, :] = _roped(
+                plain_pe_ref[0, at, :], cos_ref[0, at, :],
+                sin_ref[0, at, :]).astype(roped_pe_ref.dtype)
+
+        def put_keys(at):
+            k_ref[0, at, :nope] = v_ref[0, at, :nope]
+            k_ref[0, at, nope:] = roped_pe_ref[at, :]
+
+        # the row's ONE rotary key is roped at the first of its heads
+        @pl.when(jnp.logical_and(qi == 0, pl.program_id(0) % heads == 0))
+        def _():
+            _for_row_blocks(rope_pe, seq_k, block_k)
+
+        @pl.when(qi == 0)
+        def _():
+            _for_row_blocks(put_keys, seq_k, block_k)
+
+        rows = q_table_rows()
+        q = _roped_from(nope, q, cos_ref[0, rows, :], sin_ref[0, rows, :])
     if fold_scale:
         q = _scaled(q, sm_scale)
     query_minus_key = _query_minus_key(block_k, block_q) if causal else None
@@ -416,7 +486,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, *refs,
         m, l, acc = (x[:, lo:] for x in carry)
         start = pl.multiple_of(start, block_k)
         k_blk = k_ref[0, pl.ds(start, block_k), :]
-        v_blk = v_ref[0, pl.ds(start, block_k), :]
+        v_blk = v_ref[0, pl.ds(start, block_k), v_cols]
         s = _dot(k_blk, q[lo:], 1, 1)              # [block_k, block_q - lo]
         if not fold_scale:
             s = s * sm_scale
@@ -477,6 +547,40 @@ def _rope_operands(rope, heads: int, seq_k: int, d: int):
     return tuple(rope), [spec, spec]
 
 
+def _lanes(width: int) -> int:
+    """A row of `width` as VMEM holds it: whole lanes of 128."""
+    return -(-width // 128) * 128
+
+
+def _latent_parts(q, kv, k_pe):
+    """None for whole q, k, v.  Latent attention's parts are told by their
+    ranks: where the values would be, [b, t, h, e], stands ONE rotary key
+    for all heads, [b, t, r]; kv is then [b, t, h, nope + e] = [k_nope | v]
+    and q [b, s, h, nope + r].  -> (nope, e, r)."""
+    if k_pe.ndim != 3:
+        return None
+    r = k_pe.shape[-1]
+    nope = q.shape[-1] - r
+    return nope, kv.shape[-1] - nope, r
+
+
+def _parts_operands(kv, k_pe, heads: int, rows: int):
+    """(kv as its projection lays it, [b, t, h x (nope + e)], and k_pe;
+    the BlockSpecs that read `rows` of them for program (g, i)): a head's
+    columns of kv by lane-block index, no split and no transpose, and the
+    row's one rotary key whatever the head.  rows=None: all t, whatever
+    the tile (the forward, which holds a head's keys whole)."""
+    from jax.experimental import pallas as pl
+
+    b, t, _, width = kv.shape
+    where = (lambda i: 0) if rows is None else (lambda i: i)
+    return (kv.reshape(b, t, heads * width), k_pe), [
+        pl.BlockSpec((1, rows or t, width),
+                     lambda g, i, offs: (g // heads, where(i), g % heads)),
+        pl.BlockSpec((1, rows or t, k_pe.shape[-1]),
+                     lambda g, i, offs: (g // heads, where(i), 0))]
+
+
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
                block_q: int, block_k: int,
@@ -499,16 +603,38 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
         fold_scale = _scale_is_exact(sm_scale)
     # fold batch*heads, put seq in the middle: [bh, s, d]
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d_v)
+    parts = _latent_parts(q, k, v)
+    if parts is None:
+        kv_operands = (k.transpose(0, 2, 1, 3).reshape(b * h, sk, d),
+                       v.transpose(0, 2, 1, 3).reshape(b * h, sk, d_v))
+        kv_specs = [
+            pl.BlockSpec((1, sk, d), lambda bh, i, offs: (bh, 0, 0)),
+            pl.BlockSpec((1, sk, d_v), lambda bh, i, offs: (bh, 0, 0))]
+        scratch = [] if rope is None else [pltpu.VMEM((1, sk, d), k.dtype)]
+        table_width, vmem_mib = d, 32
+    else:
+        # q keeps its [bh, s, d] face (the benchmark's reader finds the
+        # call by it) and out comes back [bh, s, e]; k_nope, v and the
+        # rotary key are read where their projections left them.
+        nope, d_v, table_width = parts
+        kv_operands, kv_specs = _parts_operands(k, v, h, None)
+        scratch = [pltpu.VMEM((1, sk, d), k.dtype),
+                   pltpu.VMEM((sk, table_width), k.dtype)]
+        # What a program holds of the keys' rows, as VMEM holds them (lanes
+        # of 128), beside the whole-operand call's 32 MiB: a head's
+        # [k_nope | v], the rotary key and the two float32 tables, each
+        # double-buffered, and the two scratches.  34 MiB at 8192 rows.
+        vmem_mib = 32 + -(-sk * (4 * _lanes(nope + d_v) + 2 * _lanes(d)
+                                 + 22 * _lanes(table_width)) // 2 ** 20)
 
     grid = (b * h, sq // block_q)
     kernel = functools.partial(
         _fwd_kernel, causal=causal, block_q=block_q, block_k=block_k,
         seq_k=sk, sm_scale=sm_scale, fold_scale=fold_scale,
         **({"windowed": True} if windowed else {}),
-        **({} if rope is None else {"rope_q0": sk - sq}))
-    tables, table_specs = _rope_operands(rope, h, sk, d)
+        **({} if rope is None else {"rope_q0": sk - sq}),
+        **({} if parts is None else {"parts": (nope, h)}))
+    tables, table_specs = _rope_operands(rope, h, sk, table_width)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -516,25 +642,23 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda bh, i, offs: (bh, i, 0)),
-                pl.BlockSpec((1, sk, d), lambda bh, i, offs: (bh, 0, 0)),
-                pl.BlockSpec((1, sk, d_v), lambda bh, i, offs: (bh, 0, 0)),
+                *kv_specs,
                 *table_specs,
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, d_v), lambda bh, i, offs: (bh, i, 0)),
                 pl.BlockSpec((1, 8, block_q), lambda bh, i, offs: (bh, 0, i)),
             ],
-            scratch_shapes=([] if rope is None
-                            else [pltpu.VMEM((1, sk, d), k.dtype)]),
+            scratch_shapes=scratch,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, d_v), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, sq), jnp.float32),
         ],
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(vmem_mib),
         interpret=dispatch.interpret_mode(),
         name="flash_fwd",
-    )(offs, qf, kf, vf, *tables)
+    )(offs, qf, *kv_operands, *tables)
     out = out.reshape(b, h, sq, d_v).transpose(0, 2, 1, 3)
     return out, lse[:, 0, :]  # lse: [bh, sq]
 
@@ -570,12 +694,23 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
                 *refs, causal: bool,
                 block_q: int, block_k: int, seq_q: int, sm_scale: float,
                 fold_scale: bool, windowed: bool = False,
-                roped: bool = False):
+                roped: bool = False, nope: Optional[int] = None):
     """roped: refs holds the two tables (the KEYS' positions; the queries'
     are their last seq_q rows) before the outputs and, after the scratch,
     one more for the head's roped q.  dq and dk are then the gradients of
     the UN-roped q and k: rope's transpose goes on the float32 sums, before
-    their one rounding."""
+    their one rounding.
+
+    nope (with roped; None: whole operands, the kernel without): latent
+    attention's parts.  k_ref is the tile of a head's [k_nope | v] as the
+    projection lays it, v_ref that of the row's one rotary key, un-roped,
+    do_ref a head's columns of the output's gradient; q is roped from
+    column nope on.  corr_ref holds -dlse alone: the head's output is one
+    more input, behind the tables, and delta = rowsum(do * out) is made
+    and added here, at the head's first key tile, into one more scratch
+    (in XLA it drew two relayouts of do after it).  The outputs are dq,
+    [dk_nope | dv] laid as k_ref is, and this head's share of the rotary
+    key's gradient."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
@@ -583,15 +718,27 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
     k = k_ref[0]                              # [block_k, d] native dtype
     v = v_ref[0]
     d = k.shape[-1]
-    if roped:
+    if nope is not None:
+        minus_dlse_ref = corr_ref
+        cos_ref, sin_ref, out_ref, dq_ref, dk_ref, dv_ref, kt_ref, dqt_ref, \
+            roped_q_ref, corr_ref = refs
+    elif roped:
         cos_ref, sin_ref, dq_ref, dk_ref, dv_ref, kt_ref, dqt_ref, \
             roped_q_ref = refs
+    if roped:
         k_rows = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
         q_rows = slice(cos_ref.shape[1] - seq_q, cos_ref.shape[1])
-        k = _roped(k, cos_ref[0, k_rows, :],
-                   sin_ref[0, k_rows, :]).astype(k.dtype)
     else:
         dq_ref, dk_ref, dv_ref, kt_ref, dqt_ref = refs
+    if nope is not None:
+        # the tile's keys, [k_nope | rope(k_pe)], and its values
+        k, v = jnp.concatenate(
+            [k[:, :nope], _roped(v, cos_ref[0, k_rows, :], sin_ref[
+                0, k_rows, :]).astype(k.dtype)], axis=1), k[:, nope:]
+        d = k.shape[-1]
+    elif roped:
+        k = _roped(k, cos_ref[0, k_rows, :],
+                   sin_ref[0, k_rows, :]).astype(k.dtype)
     k_s = _scaled(k, sm_scale) if fold_scale else k
     # k^T for dq, turned once a program into scratch: the steps' matmuls
     # read it as a plain operand (a transpose that feeds the MXU directly,
@@ -603,10 +750,29 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
     # this tile's first key less the queries' first position
     tile_min = offs_ref[1] - offs_ref[0] + ki * block_k
 
+    def table_rows(at, sign: float):
+        """(cos, sign * sin) at the rows `at` of q: latent attention's q
+        is roped, and dq turned back, a block of rows at a time."""
+        at = pl.ds(pl.multiple_of(at.start + q_rows.start, math.gcd(
+            q_rows.start, block_q)), block_q)
+        return cos_ref[0, at, :], sign * sin_ref[0, at, :]
+
     @pl.when(ki == 0)
     def _():
         dqt_ref[...] = jnp.zeros(dqt_ref.shape, dqt_ref.dtype)
-        if roped:       # the head's q, roped once for all its key tiles
+        if nope is not None:
+            def rope_q_and_make_corr(at):
+                roped_q_ref[0, at, :] = _roped_from(
+                    nope, q_ref[0, at, :], *table_rows(at, 1.0))
+                # delta - dlse, delta turned so that its rows lie along
+                # the lanes as lse's do
+                do_out = (do_ref[0, at, :].astype(jnp.float32)
+                          * out_ref[0, at, :].astype(jnp.float32))
+                corr_ref[0, :, at] = jnp.sum(
+                    do_out.T, axis=0, keepdims=True) + minus_dlse_ref[0, :, at]
+
+            _for_row_blocks(rope_q_and_make_corr, seq_q, block_q)
+        elif roped:     # the head's q, roped once for all its key tiles
             roped_q_ref[0] = _roped(
                 q_ref[0], cos_ref[0, q_rows, :],
                 sin_ref[0, q_rows, :]).astype(roped_q_ref.dtype)
@@ -670,13 +836,26 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
     dk, dv = carry
     if fold_scale:
         dk = dk * sm_scale
-    if roped:
+    if nope is not None:
+        # dk_ref: [dk_nope | dv] beside each other, as the projection's
+        # gradient reads them; dv_ref: this head's gradient of the one
+        # rotary key, rope turned back on the float32 sum
+        dk, dv = jnp.concatenate([dk[:, :nope], dv], axis=1), _roped(
+            dk[:, nope:], cos_ref[0, k_rows, :], -sin_ref[0, k_rows, :])
+    elif roped:
         dk = _roped(dk, cos_ref[0, k_rows, :], -sin_ref[0, k_rows, :])
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
     @pl.when(ki == pl.num_programs(1) - 1)
     def _():
+        if nope is not None:
+            def turn_dq(at):
+                dq_ref[0, at, :] = _roped_from(
+                    nope, dqt_ref[:, at].T, *table_rows(at, -1.0)).astype(
+                        dq_ref.dtype)
+
+            return _for_row_blocks(turn_dq, seq_q, block_q)
         dq = dqt_ref[...].T
         if roped:
             dq = _roped(dq, cos_ref[0, q_rows, :], -sin_ref[0, q_rows, :])
@@ -697,26 +876,56 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
     sk, d_v = k.shape[1], v.shape[-1]
     bh = b * h
     block_q, block_k = blocks
+    parts = _latent_parts(q, k, v)
     qf = q.transpose(0, 2, 1, 3).reshape(bh, sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(bh, sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(bh, sk, d_v)
-    dof = dout.transpose(0, 2, 1, 3).reshape(bh, sq, d_v)
-    delta = jnp.sum(dof.astype(jnp.float32)
-                    * out.transpose(0, 2, 1, 3).reshape(bh, sq, d_v)
-                    .astype(jnp.float32), axis=-1)      # [bh, sq]
+    full_q = pl.BlockSpec((1, sq, d), lambda g, i, offs: (g, 0, 0))
+    k_tile = pl.BlockSpec((1, block_k, d), lambda g, i, offs: (g, i, 0))
+    if parts is None:
+        kf = k.transpose(0, 2, 1, 3).reshape(bh, sk, d)
+        vf = v.transpose(0, 2, 1, 3).reshape(bh, sk, d_v)
+        dof = dout.transpose(0, 2, 1, 3).reshape(bh, sq, d_v)
+        delta = jnp.sum(dof.astype(jnp.float32)
+                        * out.transpose(0, 2, 1, 3).reshape(bh, sq, d_v)
+                        .astype(jnp.float32), axis=-1)      # [bh, sq]
+        # do and v (and dv) in the values' width: the same specs at equal
+        # widths
+        full_do = full_q if d_v == d else pl.BlockSpec(
+            (1, sq, d_v), lambda g, i, offs: (g, 0, 0))
+        v_tile = k_tile if d_v == d else pl.BlockSpec(
+            (1, block_k, d_v), lambda g, i, offs: (g, i, 0))
+        table_width = d
+        grads = [jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
+                 jax.ShapeDtypeStruct((bh, sk, d_v), v.dtype)]
+        grad_specs = [k_tile, v_tile]
+    else:
+        # Everything but q and dq where XLA lays it: a head's [k_nope | v]
+        # and [dk_nope | dv] in the projection's columns, do in the output
+        # projection's, the one rotary key's gradient a share a head.
+        nope, d_v, table_width = parts
+        (kf, vf), (k_tile, v_tile) = _parts_operands(k, v, h, block_k)
+        # delta is made in the kernel and added to the -dlse that goes in:
+        # from do as the output projection's gradient lays it, and out as
+        # the forward gave it (turning back what `_flash_fwd` turned: XLA
+        # folds the two, where a reshape of the turned out to [b, s, h x e]
+        # is a pass of its own, the tiles of the two not being the same)
+        dof = dout.reshape(b, sq, h * d_v)
+        outf = out.transpose(0, 2, 1, 3).reshape(bh, sq, d_v)
+        delta = 0.0
+        full_do = pl.BlockSpec((1, sq, d_v),
+                               lambda g, i, offs: (g // h, 0, g % h))
+        full_out = pl.BlockSpec((1, sq, d_v), lambda g, i, offs: (g, 0, 0))
+        grads = [jax.ShapeDtypeStruct(kf.shape, k.dtype),
+                 jax.ShapeDtypeStruct((bh, sk, table_width), v.dtype)]
+        grad_specs = [k_tile, pl.BlockSpec((1, block_k, table_width),
+                                           lambda g, i, offs: (g, i, 0))]
     lse8 = _lse8(lse, bh, sq)
     # (delta + (-dlse)) enters every key of a query uniformly: one term.
     corr8 = _lse8(delta - dlse.astype(jnp.float32), bh, sq)
 
     seq_spec = pl.BlockSpec((1, 8, sq), lambda g, i, offs: (g, 0, 0))
-    full_q = pl.BlockSpec((1, sq, d), lambda g, i, offs: (g, 0, 0))
-    k_tile = pl.BlockSpec((1, block_k, d), lambda g, i, offs: (g, i, 0))
-    # do and v (and dv) in the values' width: the same specs at equal widths
-    full_do = full_q if d_v == d else pl.BlockSpec(
-        (1, sq, d_v), lambda g, i, offs: (g, 0, 0))
-    v_tile = k_tile if d_v == d else pl.BlockSpec(
-        (1, block_k, d_v), lambda g, i, offs: (g, i, 0))
-    tables, table_specs = _rope_operands(rope, h, sk, d)
+    tables, table_specs = _rope_operands(rope, h, sk, table_width)
+    if parts is not None:
+        tables, table_specs = (*tables, outf), [*table_specs, full_out]
     # A float32 table block as VMEM holds it (128 lanes): two tables, each
     # double-buffered, the roped q and the float32 dq being roped come to
     # under six of them (58.0 MiB needed at 8192 x 128; at 2048 x 64 the
@@ -725,37 +934,51 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
     # What a program holds of a head WHOLE, as VMEM holds it (lanes of
     # 128): q and dq double-buffered, do double-buffered, dq's float32 sum.
     # 16 MiB at 8192 x 128, inside the 48; keys of 192 need 26.
-    lanes, v_lanes = -(-d // 128) * 128, -(-d_v // 128) * 128
+    lanes, v_lanes = _lanes(d), _lanes(d_v)
     head_mib = -(-sq * (8 * lanes + 4 * v_lanes + 4 * d) // 2 ** 20)
+    if parts is not None:
+        # beside the head's whole rows its roped q, its output (double-
+        # buffered) and the two tables' four buffers (lanes of 128): 90
+        # MiB at 8192 rows, where the cell's step program compiled at 80
+        # and not at 70 before the output came in
+        vmem_mib = 40 + head_mib + -(-(sq * (2 * lanes + 4 * v_lanes)
+                                       + 16 * sk * _lanes(table_width))
+                                     // 2 ** 20)
+    elif rope is None:
+        vmem_mib = max(48, 32 + head_mib)
+    else:
+        vmem_mib = max(48, 40 + 6 * table_mib)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, causal=causal, block_q=block_q,
                           block_k=block_k, seq_q=sq, sm_scale=sm_scale,
                           fold_scale=_scale_is_exact(sm_scale),
                           **({"windowed": True} if windowed else {}),
-                          **({} if rope is None else {"roped": True})),
+                          **({} if rope is None else {"roped": True}),
+                          **({} if parts is None else {"nope": nope})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, sk // block_k),
             in_specs=[full_q, k_tile, v_tile, full_do, seq_spec, seq_spec,
                       *table_specs],
-            out_specs=[full_q, k_tile, v_tile],
+            out_specs=[full_q, *grad_specs],
             scratch_shapes=[pltpu.VMEM((d, block_k), k.dtype),
                             pltpu.VMEM((d, sq), jnp.float32)]
-            + ([] if rope is None else [pltpu.VMEM((1, sq, d), q.dtype)]),
+            + ([] if rope is None else [pltpu.VMEM((1, sq, d), q.dtype)])
+            + ([] if parts is None else [pltpu.VMEM((1, 8, sq),
+                                                    jnp.float32)]),
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d_v), v.dtype),
-        ],
-        compiler_params=_compiler_params(
-            max(48, 32 + head_mib) if rope is None
-            else max(48, 40 + 6 * table_mib)),
+        out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype), *grads],
+        compiler_params=_compiler_params(vmem_mib),
         interpret=dispatch.interpret_mode(),
         name="flash_bwd",
     )(offs, qf, kf, vf, dof, lse8, corr8, *tables)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    if parts is not None:
+        # dv here is the rotary key's gradient, a share a head
+        return dq, dk.reshape(k.shape), jnp.sum(
+            dv.reshape(b, h, sk, table_width), axis=1,
+            dtype=jnp.float32).astype(v.dtype)
     dk = dk.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
     dv = dv.reshape(b, h, sk, d_v).transpose(0, 2, 1, 3)
     return dq, dk, dv
@@ -809,7 +1032,7 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
                  seq_q: int, seq_k: int, blocks, roped: bool,
-                 widths=None) -> None:
+                 widths=None, rope_width: Optional[int] = None) -> None:
     """Say in `dispatch.taken()` what the kernels were built to do: each
     kernel's (block_q x block_k), that dq comes out of the backward's one
     pass and over how many key tiles it is summed there, whether the scale
@@ -818,7 +1041,9 @@ def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
     traced offset decides it at run time); under a window also the window
     and the share of the forward's (tile, block) pairs that it visits;
     `rope_in_kernel` when the kernels rope q and k themselves; `widths`
-    (keys', values') where the two differ, as `dqk192,dv128`."""
+    (keys', values') where the two differ, as `dqk192,dv128`; `rope_width`
+    where the operands are latent attention's parts, whose last columns
+    the kernels rope, as `latent_parts,rope_in_kernel64of192`."""
     (fq, fk), (kv_q, kv_k), window = blocks
     static = isinstance(q_off, int) and isinstance(kv_off, int)
     if not causal:
@@ -848,6 +1073,8 @@ def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
         plan += ",rope_in_kernel"
     if widths is not None:
         plan += ",dqk%d,dv%d" % widths
+    if rope_width is not None:
+        plan += ",latent_parts,rope_in_kernel%dof%d" % (rope_width, widths[0])
     dispatch.record("flash_attention.plan", plan)
 
 
@@ -855,14 +1082,18 @@ def _chunk(q, k, v, q_off, kv_off, causal, sm_scale, blocks, window=None,
            rope=None):
     """rope: None, or (cos, sin) [b, seq_k, d/2] at the KEYS' positions (the
     queries' are the last seq_q rows): attention over rope(q), rope(k), the
-    kernels roping the tiles they load."""
+    kernels roping the tiles they load.  Or latent attention's parts
+    (`_latent_parts`): k = [k_nope | v] by head, v = the one rotary key,
+    the tables as wide as that key."""
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
     blocks = (*blocks, window)
+    parts = _latent_parts(q, k, v)
+    d, e = q.shape[-1], v.shape[-1] if parts is None else parts[1]
     _record_plan(q_off, kv_off, causal, sm_scale, q.shape[1], k.shape[1],
-                 blocks, rope is not None,
-                 None if v.shape[-1] == q.shape[-1]
-                 else (q.shape[-1], v.shape[-1]))
+                 blocks, rope is not None and parts is None,
+                 None if e == d else (d, e),
+                 None if parts is None else parts[2])
     if rope is not None:
         rope = _widen_rope(rope)
     # Under a window the scalars are [q_off, kv_off, window]: the kernels
@@ -886,6 +1117,14 @@ def flash_attention_chunk(q, k, v, q_off, kv_off, causal: bool = True,
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     return _chunk(q, k, v, q_off, kv_off, causal, sm_scale,
                   ((block_q, block_k),) * 2)
+
+
+def _blocks(d: int, sq: int, sk: int, dtype, block_q, block_k, window=None):
+    """`default_blocks`' plan unless the caller passes a block size, which
+    then holds for both kernels."""
+    if block_q is None and block_k is None:
+        return default_blocks(d, sq, sk, dtype, window)
+    return ((min(block_q or 512, sq), min(block_k or 512, sk)),) * 2
 
 
 def flash_attention(q, k, v, causal: bool = True,
@@ -946,10 +1185,7 @@ def flash_attention(q, k, v, causal: bool = True,
     sq, sk, d = q.shape[1], k.shape[1], q.shape[-1]
     if window is not None and window >= sk:
         window = None          # every key a query may see is in its window
-    if block_q is None and block_k is None:
-        blocks = default_blocks(d, sq, sk, q.dtype, window)
-    else:
-        blocks = ((min(block_q or 512, sq), min(block_k or 512, sk)),) * 2
+    blocks = _blocks(d, sq, sk, q.dtype, block_q, block_k, window)
     pallas = all(_can_use_pallas(sq, sk, d, bq, bk, v.shape[-1])
                  for bq, bk in blocks)
     # The kernels read the queries' rows of the tables at (sk - sq) on:
@@ -970,13 +1206,23 @@ def flash_attention(q, k, v, causal: bool = True,
         return _chunk(q, k, v, sk - sq, 0, causal, sm_scale, blocks,
                       window, tables or None)[0]
 
+    return _per_shard(kernel, q, k, v, *tables)
+
+
+def _per_shard(kernel, *operands):
+    """kernel(*operands), under an ambient multi-device mesh per shard
+    inside a shard_map: batch over data / fsdp and heads, the third axis of
+    the 4-D operands (the first is q), over tensor; the 3-D ones (rope's
+    tables, latent attention's one rotary key) have no heads and are whole
+    over tensor."""
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or mesh.size == 1:
-        return kernel(q, k, v, *tables)
+        return kernel(*operands)
     from jax.sharding import PartitionSpec as P
 
     # An axis shards a dim only where it divides it; otherwise that dim
     # is computed replicated (small eval batches on a wide mesh).
+    q = operands[0]
     sizes = dict(mesh.shape)
     batch = tuple(a for a in ("data", "fsdp") if a in sizes)
     if q.shape[0] % math.prod(sizes[a] for a in batch):
@@ -987,5 +1233,72 @@ def flash_attention(q, k, v, causal: bool = True,
     table_spec = P(batch or None, None, None)
     return jax.shard_map(
         kernel, mesh=mesh,
-        in_specs=(spec, spec, spec) + (table_spec,) * len(tables),
-        out_specs=spec, check_vma=False)(q, k, v, *tables)
+        in_specs=tuple(spec if x.ndim == 4 else table_spec
+                       for x in operands),
+        out_specs=spec, check_vma=False)(*operands)
+
+
+def latent_flash_attention(q, kv, k_pe, rope, causal: bool = True,
+                           sm_scale: Optional[float] = None,
+                           block_q: Optional[int] = None,
+                           block_k: Optional[int] = None):
+    """Latent attention from its PARTS, as their projections lay them:
+
+      q     [b, s, h, nope + r]  un-roped, its last r columns rotary;
+      kv    [b, t, h, nope + e]  a head's [k_nope | v];
+      k_pe  [b, t, r]            ONE rotary key, shared by all heads,
+                                 un-roped;
+      rope  (cos, sin) float32 [b, t, r/2] at the KEYS' positions, the
+            queries' their last s rows: `flash_attention`'s, r wide.
+
+    -> [b, s, h, e]: `flash_attention` over q' = [q_nope | rope(q_pe)],
+    k' = [k_nope | rope(k_pe)] for every head, and v, at sm_scale
+    (default (nope + r)^-0.5), rope pairing column i of the r with column i
+    + r/2 (`rope_reference`; a model that pairs otherwise reorders the
+    rotary columns of its two projections at use: q_pe . k_pe does not see
+    one permutation of both).
+
+    The Pallas kernels, on the TPU and interpreted, never see q', k' or v
+    in HBM: they read a head's [k_nope | v] from kv by lane-block index
+    (nope and e multiples of 128 on the TPU), the one rotary key whatever
+    the head, rope it and the q tile's last r columns in VMEM (float32,
+    rounded to the operands' dtype before any matmul, as rope in XLA
+    rounds) and put the head's keys together there.  The backward writes
+    [dk_nope | dv] laid as kv is, dq with rope turned back on its float32
+    sum, and the rotary key's gradient a share a head, which XLA sums;
+    `do` is read from [b, s, h x e] as the output projection's gradient
+    lays it.  q goes in as [b x h, s, nope + r] and the output comes back
+    [b x h, s, e] (the forward's face, which the benchmark's reader finds:
+    one relayout each in XLA), as does dq.  The plan says
+    `dqk<nope + r>,dv<e>,latent_parts,rope_in_kernel<r>of<nope + r>`.
+
+    Where the kernels cannot run so (off the TPU, widths off the lanes,
+    queries that begin at a row of the tables that is no multiple of 8),
+    q', k' and v are put together here and `flash_attention` takes them."""
+    b, sq, h, d = q.shape
+    sk, r = k_pe.shape[1], k_pe.shape[-1]
+    nope = d - r
+    e = kv.shape[-1] - nope
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    blocks = _blocks(d, sq, sk, q.dtype, block_q, block_k)
+    on_lanes = dispatch.interpret_mode() or (nope % 128 == 0 and e % 128 == 0)
+    if not (on_lanes and (sk - sq) % 8 == 0
+            and all(_can_use_pallas(sq, sk, d, bq, bk, e)
+                    for bq, bk in blocks)):
+        cos, sin = rope
+        q = jnp.concatenate([q[..., :nope], rope_reference(
+            q[..., nope:], cos[:, sk - sq:], sin[:, sk - sq:])], axis=-1)
+        k_pe = rope_reference(k_pe[:, :, None, :], cos, sin)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_pe, (b, sk, h, r))], axis=-1)
+        return flash_attention(q, k, kv[..., nope:], causal, sm_scale,
+                               block_q, block_k)
+    dispatch.record("flash_attention", "interpret"
+                    if dispatch.interpret_mode() else "pallas")
+
+    def kernel(q, kv, k_pe, *tables):
+        return _chunk(q, kv, k_pe, sk - sq, 0, causal, sm_scale, blocks,
+                      None, tables)[0]
+
+    return _per_shard(kernel, q, kv, k_pe, *rope)

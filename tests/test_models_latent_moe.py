@@ -311,3 +311,74 @@ def test_rope_turns_interleaved_pairs():
     np.testing.assert_allclose(np.asarray(got[0, 0]), np.asarray(x[0, 0]),
                                atol=1e-6)     # position 0 is not turned
     assert gm.TILE_M % 16 == 0
+
+
+def _attention_roped_in_xla(u, lp, cos, sin, c):
+    """Latent attention as the model computed it before its operands went to
+    the kernels in parts: the published pairing's rope (`rope_interleaved`)
+    on q_pe and k_pe in XLA, k_pe broadcast to the heads, q and k
+    concatenated, [k_nope | v] split, then the whole-operand kernels."""
+    from ray_tpu.ops.attention import flash_attention
+
+    b, s, _ = u.shape
+    heads, nope, rope = (c.num_attention_heads, c.qk_nope_head_dim,
+                         c.qk_rope_head_dim)
+    rank, dv = c.kv_lora_rank, c.v_head_dim
+    q = lm._matmul(u, lp["wq"], c).reshape(b, s, heads, nope + rope)
+    latent = lm._matmul(u, lp["wkv_a"], c)
+    c_kv = lm.rms_norm(latent[..., :rank], lp["kv_norm_w"], c.rms_norm_eps)
+    kv = lm._matmul(c_kv, lp["wkv_b"], c).reshape(b, s, heads, nope + dv)
+    k_pe = lm.rope_interleaved(latent[..., None, rank:], cos, sin)
+    q = jnp.concatenate(
+        [q[..., :nope], lm.rope_interleaved(q[..., nope:], cos, sin)],
+        axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (b, s, heads, rope))],
+        axis=-1)
+    a = flash_attention(q, k, kv[..., nope:], causal=True,
+                        sm_scale=1.0 / (nope + rope) ** 0.5)
+    return lm._matmul(a.reshape(b, s, heads * dv), lp["wo"], c)
+
+
+def test_parts_path_is_rope_in_xla_on_the_published_columns(monkeypatch):
+    """A parameter tree from `init_params`, columns as published, gives the
+    same loss and the same gradients (W_q's and W_kva's, whose rotary
+    columns the program reorders at use, first) through the kernels that
+    take the parts as through rope in XLA with the interleaved pairing: the
+    reordering, its transpose on the gradients and the kernels' half-split
+    rope are the published rope."""
+    from ray_tpu.ops import dispatch
+
+    config = _f32()
+    params = lm.init_params(config, jax.random.PRNGKey(8))
+    batch = {"tokens": jnp.asarray(_tokens(seed=9))}
+
+    def loss_and_grads():
+        return jax.value_and_grad(
+            lambda p: lm.loss_fn(p, batch, config))(params)
+
+    monkeypatch.setattr(dispatch, "_taken", {})
+    loss, grads = loss_and_grads()
+    plans = list(dispatch.taken()["flash_attention.plan"])
+    assert plans and all(p.endswith(
+        ",dqk48,dv32,latent_parts,rope_in_kernel16of48") for p in plans)
+    monkeypatch.setattr(lm, "_attention", _attention_roped_in_xla)
+    lm._layer_fn.cache_clear()      # a traced layer is cached by its function
+    monkeypatch.setattr(dispatch, "_taken", {})
+    want_loss, want = loss_and_grads()
+    lm._layer_fn.cache_clear()
+    assert not any("latent_parts" in p
+                   for p in dispatch.taken()["flash_attention.plan"])
+    assert abs(float(loss) - float(want_loss)) < 1e-5
+    for seg in ("seg00", "seg01"):
+        for name, w in want["layers"][seg]["0"].items():
+            g = np.asarray(grads["layers"][seg]["0"][name])
+            w = np.asarray(w)
+            assert np.linalg.norm(g - w) <= 2e-4 * max(
+                np.linalg.norm(w), 1e-12), (seg, name)
+    # the rotary columns' gradients are not all alike: a reordering that
+    # came back wrong would show
+    rotary = np.asarray(want["layers"]["seg00"]["0"]["wkv_a"])[
+        0, :, config.kv_lora_rank:]
+    assert np.linalg.norm(rotary[:, 0::2] - rotary[:, 1::2]) \
+        > 0.1 * np.linalg.norm(rotary)

@@ -713,6 +713,83 @@ def test_cell_latent_flash_compiles_and_keeps_the_face_its_reader_finds(
             "scale_per_score,dead6/6%") in plans
 
 
+def test_cell_latent_parts_compile_and_keep_the_face_its_reader_finds(
+        one_chip, monkeypatch):
+    """The cell's call since PR 35, `latent_flash_attention` at its shapes:
+    q [2, 8192, 32, 192] un-roped, kv [2, 8192, 32, 256] as W_kvb lays it,
+    ONE rotary key [2, 8192, 64].  Forward and ONE backward call compile
+    for the v5e.  The forward keeps the face mla_fwd_roofline.moe finds (q
+    bf16[64, 8192, 192] second, results 128 wide); kv, the rotary key and
+    the tables go in as XLA lays them.  The backward, which no reader
+    finds, gives dq [64, 8192, 192], [dk_nope | dv] laid as kv, and the
+    rotary key's gradient a share a head.  The plan holds the parts' word
+    behind the widths; the dense, hybrid and whole-operand plans do not."""
+    import re
+
+    _on_tpu(monkeypatch, attention)
+    monkeypatch.setattr(attention.dispatch, "_taken", {})
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = (sds((MOE_ROWS, MOE_SEQ, MOE_HEADS, 192)),
+              sds((MOE_ROWS, MOE_SEQ, MOE_HEADS, 256)),
+              sds((MOE_ROWS, MOE_SEQ, 64)),
+              sds((MOE_ROWS, MOE_SEQ, 32), jnp.float32),
+              sds((MOE_ROWS, MOE_SEQ, 32), jnp.float32))
+    face = _moe_faces().MLA_FORWARD
+
+    def attend(q, kv, k_pe, cos, sin):
+        return attention.latent_flash_attention(q, kv, k_pe, (cos, sin),
+                                                sm_scale=192 ** -0.5)
+
+    calls = _custom_calls_as_traced(attend, *shapes)
+    assert len(calls) == 1 and re.search(face, calls[0]), calls
+    assert "(bf16[64,8192,128], f32[64,8,8192]) custom-call(s32[2] " \
+        in calls[0]
+    operands = calls[0].split("custom-call(", 1)[1]
+    assert re.match(
+        r"s32\[2\] [^,]+, bf16\[64,8192,192\] [^,]+, bf16\[2,8192,8192\] "
+        r"[^,]+, bf16\[2,8192,64\] [^,]+, f32\[2,8192,64\] [^,]+, "
+        r"f32\[2,8192,64\] ", operands), operands
+    calls = _custom_calls_as_traced(
+        jax.grad(lambda *a: attend(*a).astype(jnp.float32).sum(),
+                 argnums=(0, 1, 2)), *shapes)
+    backward = [l for l in calls if not re.search(face, l)]
+    assert len(calls) == 2 and len(backward) == 1, calls
+    assert ("= (bf16[64,8192,192], bf16[2,8192,8192], bf16[64,8192,64]) "
+            "custom-call(s32[2] ") in backward[0]
+    assert "bf16[2,8192,4096] " in backward[0]      # do, as W_o's side has it
+    parts_plan = ("fwd2048x512,bwd512x2048,dq_in_pass,dq_over4tiles,"
+                  "scale_per_score,dead6/6%,dqk192,dv128,latent_parts,"
+                  "rope_in_kernel64of192")
+    assert list(attention.dispatch.taken()["flash_attention.plan"]) == [
+        parts_plan]
+    # the other cells' calls and the whole-operand 192 / 128 call: no word
+    monkeypatch.setattr(attention.dispatch, "_taken", {})
+    x = sds((5, 2048, 32, 64))
+    t = sds((5, 2048, 32), jnp.float32)
+    jax.jit(lambda q, c, s: attention.flash_attention(
+        q, q, q, rope=(c, s))).lower(x, t, t)
+    x = sds((1, MOE_SEQ, 40, 128))
+    jax.jit(lambda q: attention.flash_attention(
+        q, q, q, sm_scale=0.125)).lower(x)
+    jax.jit(lambda q: attention.flash_attention(
+        q, q, q, sm_scale=0.125, window=512)).lower(x)
+    jax.jit(lambda q, v: attention.flash_attention(
+        q, q, v, sm_scale=192 ** -0.5)).lower(shapes[0], sds(
+            (MOE_ROWS, MOE_SEQ, MOE_HEADS, 128)))
+    assert sorted(attention.dispatch.taken()["flash_attention.plan"]) == [
+        "fwd2048x512,bwd512x2048,dq_in_pass,dq_over4tiles,scale_folded,"
+        "dead6/6%",
+        "fwd2048x512,bwd512x2048,dq_in_pass,dq_over4tiles,scale_per_score,"
+        "dead6/6%,dqk192,dv128",
+        "fwd2048x512,bwd512x2048,dq_in_pass,scale_folded,dead20/20%,"
+        "rope_in_kernel",
+        "fwd512x512,bwd512x512,dq_in_pass,dq_over16tiles,scale_folded,"
+        "dead50/50%,window512,visited12.1%"]
+
+
 def test_cell_grouped_matmul_kernels_compile_and_keep_their_faces(
         one_chip, monkeypatch):
     """Forward, transposed (dx) and dw at the cell's widths (2048 <-> 768,
@@ -778,6 +855,7 @@ def test_cell_latent_moe_step_program_fits_a_v5e(topo, monkeypatch):
 
     _on_tpu(monkeypatch, attention)
     _on_tpu(monkeypatch, gm)
+    monkeypatch.setattr(attention.dispatch, "_taken", {})
     path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
                         "benchmark", "configs",
                         "kanana-2-30b-a3b-train-d6e16.json")
@@ -810,3 +888,6 @@ def test_cell_latent_moe_step_program_fits_a_v5e(topo, monkeypatch):
     # three transposed and three dw (12), at each of the layer's two
     # buffer sizes (the usual and the full bound: a cond's two sides).
     assert compiled.as_text().count("tpu_custom_call") == 3 + 3 + 2 * 12
+    # and the attention calls are the ones that take latent attention's parts
+    assert all(p.endswith(",dqk192,dv128,latent_parts,rope_in_kernel64of192")
+               for p in attention.dispatch.taken()["flash_attention.plan"])
