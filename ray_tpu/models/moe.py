@@ -1,6 +1,7 @@
 """Mixture-of-experts FFN: the GSPMD capacity layer (`moe_ffn`, the dense
 block's and decoding's) and the dropless layer that holds one chip's share of
-the experts (`sigmoid_route`, `routed_experts`: latent_moe.py's).
+the experts (`sigmoid_route`, `softmax_route`, `routed_experts`:
+latent_moe.py's and swa_moe.py's).
 
 Greenfield capability (SURVEY.md §2.4 — expert parallelism is absent from
 the reference; the TPU-native target is an expert mesh axis + all_to_all).
@@ -226,6 +227,20 @@ def sigmoid_route(x, router_w, select_bias, *, num_experts_per_token: int,
         scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)),
         num_experts_per_token)
     picked = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
+    return idx.astype(jnp.int32), gates
+
+
+def softmax_route(x, router_w, *, num_experts_per_token: int, scale: float):
+    """The softmax router: p = softmax(x W_r) over all the experts in
+    float32 at full matmul precision (as `sigmoid_route`, and for its
+    reason); a token's experts are the top k of p; its gates p[sel] /
+    sum(p[sel]) x scale.  No selection bias.
+    -> (expert index [T, k] int32, gates [T, k] float32)."""
+    probs = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    picked, idx = jax.lax.top_k(probs, num_experts_per_token)
     gates = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
     return idx.astype(jnp.int32), gates
 

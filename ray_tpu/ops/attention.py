@@ -611,7 +611,12 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
             pl.BlockSpec((1, sk, d), lambda bh, i, offs: (bh, 0, 0)),
             pl.BlockSpec((1, sk, d_v), lambda bh, i, offs: (bh, 0, 0))]
         scratch = [] if rope is None else [pltpu.VMEM((1, sk, d), k.dtype)]
-        table_width, vmem_mib = d, 32
+        # The two float32 tables, a row's whole and double-buffered, are
+        # four blocks of sk x 128 lanes beside the resident k and v and
+        # the roped keys' scratch: 33.9 MiB needed at 8192 x 128, where
+        # the 32 hold every shorter or rope-less call.
+        table_mib = 0 if rope is None else -(-sk * _lanes(d) * 4 // 2 ** 20)
+        table_width, vmem_mib = d, max(32, 24 + 4 * table_mib)
     else:
         # q keeps its [bh, s, d] face (the benchmark's reader finds the
         # call by it) and out comes back [bh, s, e]; k_nope, v and the

@@ -1262,3 +1262,121 @@ def test_latent_parts_are_seen_in_the_operands_and_read_where_they_lie():
             assert block_index(bwd, 9, g, i) == [g, 0, 0]             # dq
             assert block_index(bwd, 10, g, i) == [g // h, i, g % h]   # dkv
             assert block_index(bwd, 11, g, i) == [g, i, 0]            # dk_pe
+
+
+# ---------------------------------------------------------------------------
+# A window WITH rope at head size 128, and a rope over half the head
+# (models/swa_moe.py's two calls: the sliding layers', the full layers')
+# ---------------------------------------------------------------------------
+
+def _half_rope_tables(b, sk, d):
+    """A partial rope's tables as a model hands them to the kernels: the
+    first d/4 pairs turn (factor 1.5 in cos and sin, as yarn's attention
+    factor sits there), the other d/4 pass through on cos 1 and sin 0."""
+    inv = 1.0 / (5e5 ** (jnp.arange(0, d // 2, 2, dtype=jnp.float32)
+                         / (d // 2)))
+    angle = (jnp.arange(sk, dtype=jnp.float32)[None, :, None] + 7.0) * inv
+    angle = jnp.broadcast_to(angle, (b, sk, d // 4))
+    tail = jnp.ones((b, sk, d // 4), jnp.float32)
+    return (jnp.concatenate([1.5 * jnp.cos(angle), tail], axis=-1),
+            jnp.concatenate([1.5 * jnp.sin(angle), 0.0 * tail], axis=-1))
+
+
+def _half_roped(x, cos, sin):
+    """The published partial rope, written out: the first d/2 columns
+    turned, pair (i, i + d/4), the others as they are.  cos, sin [b, s,
+    d/4]."""
+    d = x.shape[-1]
+    a, b_, rest = x[..., :d // 4], x[..., d // 4:d // 2], x[..., d // 2:]
+    c, s = cos[:, :, None, :], sin[:, :, None, :]
+    return jnp.concatenate([a * c - b_ * s, b_ * c + a * s, rest], axis=-1)
+
+
+def _rotary_halves_first(x):
+    """[rot_a | rot_b | pass_a | pass_b] -> [rot_a | pass_a | rot_b |
+    pass_b]: the one reordering of q's and k's columns under which the
+    kernels' whole-head pairing (i, i + d/2) is the partial rope's."""
+    d = x.shape[-1]
+    return x.reshape(*x.shape[:-1], 2, 2, d // 4).swapaxes(-2, -3).reshape(
+        x.shape)
+
+
+# id: (sq, sk, window, block); None: `default_blocks`' plan
+_WINDOWED_ROPES = {
+    "window_under_a_block": (512, 512, 100, 128),
+    "window_is_a_block": (512, 512, 128, 128),
+    "window_over_the_sequence": (256, 256, 1024, 128),
+    "default_blocks_window_is_a_block": (1024, 1024, 512, None),
+    "window_fewer_queries_than_keys": (256, 512, 128, 128),
+    "half_rope_full": (256, 256, None, 128),
+    "half_rope_default_blocks": (1024, 1024, None, None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_WINDOWED_ROPES))
+def test_window_with_rope_at_head_128_matches_reference(name):
+    """flash_attention(window=, rope=) at head size 128, kernels
+    interpreted, float32: the output and dq, dk, dv with respect to the
+    UN-roped operands against `attention_reference` on operands roped in
+    XLA.  The half-rope cases hand the kernels reordered columns and
+    tables with an identity tail, and are held to the published partial
+    rope on the columns as published."""
+    sq, sk, window, block = _WINDOWED_ROPES[name]
+    b, h, d = 2, 2, 128
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 4)
+    q, w = (jax.random.normal(x, (b, sq, h, d), jnp.float32) for x in ks[:2])
+    k, v = (jax.random.normal(x, (b, sk, h, d), jnp.float32) for x in ks[2:])
+    half = name.startswith("half_rope")
+    if half:
+        rope = _half_rope_tables(b, sk, d)
+        turning = tuple(t[..., :d // 4] for t in rope)
+
+        def rope_q(x):
+            return _half_roped(x, *(t[:, sk - sq:] for t in turning))
+
+        def rope_k(x):
+            return _half_roped(x, *turning)
+
+        to_kernel = _rotary_halves_first
+    else:
+        rope = _rope_tables(b, sk, d)
+        rope_q, rope_k = _rope_outside(rope, sq)
+
+        def to_kernel(x):
+            return x
+
+    out, g = _grads_and_value(lambda q, k, v: attn.flash_attention(
+        to_kernel(q), to_kernel(k), v, rope=rope, window=window,
+        block_q=block, block_k=block), q, k, v, w)
+    ref, g_ref = _grads_and_value(
+        lambda q, k, v: attn.attention_reference(
+            rope_q(q), rope_k(k), v, window=window), q, k, v, w)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5,
+                               rtol=3e-5)
+    for got, want, what in zip(g, g_ref, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-4, rtol=2e-4, err_msg=what)
+    plan = list(attn.dispatch.taken()["flash_attention.plan"])
+    assert any(p.endswith("rope_in_kernel") and ("window" in p) == (
+        window is not None and window < sk) for p in plan), plan
+
+
+def test_long_roped_forward_asks_more_vmem_and_the_others_what_they_did():
+    """The forward's VMEM ask follows what the call can see: 32 MiB for
+    every call without rope and for a roped one whose tables are short (the
+    dense cells' 2048 x 64), more where the two float32 tables of a long
+    row would not fit beside k and v (8192 x 128: 40)."""
+    import re
+
+    def ask(sk, d, roped):
+        x = jax.ShapeDtypeStruct((1, sk, 2, d), jnp.bfloat16)
+        rope = tuple(jax.ShapeDtypeStruct((1, sk, d // 2), jnp.float32)
+                     for _ in range(2)) if roped else None
+        text = str(jax.make_jaxpr(lambda q, k, v, rope: attn.flash_attention(
+            q, k, v, rope=rope))(x, x, x, rope))
+        return sorted({int(m) >> 20 for m in
+                       re.findall(r"vmem_limit_bytes=(\d+)", text)})
+
+    assert ask(2048, 64, True) == ask(2048, 64, False) == [32]
+    assert ask(8192, 128, False) == [32]
+    assert ask(8192, 128, True) == [40]

@@ -891,3 +891,166 @@ def test_cell_latent_moe_step_program_fits_a_v5e(topo, monkeypatch):
     # and the attention calls are the ones that take latent attention's parts
     assert all(p.endswith(",dqk192,dv128,latent_parts,rope_in_kernel64of192")
                for p in attention.dispatch.taken()["flash_attention.plan"])
+
+
+# ---------------------------------------------------------------------------
+# The windowed / full GQA, routed-expert cell (train-swa-moe-d5): a window
+# WITH rope at head size 128, 72 and 48 heads, 8 experts of 3072 <-> 1024
+# ---------------------------------------------------------------------------
+
+SWA_SEQ, SWA_HELD, SWA_TOP_K = 8192, 8, 10
+
+
+def test_cell_swa_moe_flash_calls_compile_and_keep_the_faces_readers_find(
+        one_chip, monkeypatch):
+    """The sliding layers' call (72 heads, window 512, rope over the head)
+    and the full layers' (48 heads, the triangle, the half rope as tables
+    with an identity tail) at 1 x 8192 x 128: forward and backward compile
+    (the roped forward asks 40 MiB of VMEM at this length), the windowed
+    forward is found by swa_fwd_roofline.swamoe alone, the full one by
+    flash_fwd_roofline.swamoe alone, the one-call backward by neither and
+    by attention_share.swamoe's third pattern; the plans say how each call
+    ropes."""
+    import re
+
+    from benchmark import swa_moe_faces as faces
+
+    _on_tpu(monkeypatch, attention)
+    monkeypatch.setattr(attention.dispatch, "_taken", {})
+    swa = _reader("swa_fwd_roofline.swamoe").KERNEL
+    full = _reader("flash_fwd_roofline.swamoe").KERNEL
+    assert (swa, full) == (faces.FORWARD_WINDOWED, faces.FORWARD_FULL)
+    assert _reader("attention_share.swamoe").KERNELS == (
+        faces.FORWARD_WINDOWED, faces.FORWARD_FULL, faces.BACKWARD)
+    table = jax.ShapeDtypeStruct((1, SWA_SEQ, 64), jnp.float32,
+                                 sharding=one_chip)
+    for heads, window, mine, other in ((72, 512, swa, full),
+                                       (48, None, full, swa)):
+        x = jax.ShapeDtypeStruct((1, SWA_SEQ, heads, 128), jnp.bfloat16,
+                                 sharding=one_chip)
+
+        def attend(q, k, v, cos, sin, window=window):
+            return attention.flash_attention(q, k, v, window=window,
+                                             rope=(cos, sin))
+
+        def loss(q, k, v, cos, sin):
+            return attend(q, k, v, cos, sin).astype(jnp.float32).sum()
+
+        calls = _custom_calls_as_traced(attend, x, x, x, table, table)
+        assert len(calls) == 1 and re.search(mine, calls[0]), calls
+        assert not re.search(other, calls[0])
+        assert not re.search(faces.BACKWARD, calls[0])
+        assert f"(bf16[{heads},8192,128], f32[{heads},8,8192])" in calls[0]
+        calls = _custom_calls_as_traced(jax.grad(loss, argnums=(0, 1, 2)),
+                                        x, x, x, table, table)
+        assert len(calls) == 2          # forward, backward
+        assert sum(bool(re.search(mine, l)) for l in calls) == 1
+        assert sum(bool(re.search(faces.BACKWARD, l)) for l in calls) == 1
+        assert not any(re.search(other, l) for l in calls)
+    assert sorted(attention.dispatch.taken()["flash_attention.plan"]) == [
+        "fwd2048x512,bwd512x2048,dq_in_pass,dq_over4tiles,scale_per_score,"
+        "dead6/6%,rope_in_kernel",
+        "fwd512x512,bwd512x512,dq_in_pass,dq_over16tiles,scale_per_score,"
+        "dead50/50%,window512,visited12.1%,rope_in_kernel"]
+
+
+def test_cell_swa_moe_grouped_matmul_kernels_keep_their_faces(
+        one_chip, monkeypatch):
+    """Forward, transposed (dx) and dw at this cell's widths (3072 <-> 1024,
+    8 groups) and both of its buffer sizes (the usual 4 x 2,560 rows and
+    the bound of 8 x 8,192): each custom-call is found by exactly one of
+    benchmark/moe_faces.py's patterns, which the `.swamoe` grouped readers
+    share with the `.moe` ones."""
+    import re
+
+    from ray_tpu.ops import grouped_matmul as gm
+
+    _on_tpu(monkeypatch, gm)
+    faces = _moe_faces()
+    patterns = {"forward": faces.GROUPED_FORWARD,
+                "transposed": faces.GROUPED_TRANSPOSED,
+                "dw": faces.GROUPED_DW}
+    assert _reader("grouped_matmul_roofline.swamoe").KERNEL \
+        == patterns["forward"]
+    assert _reader("grouped_matmul_share.swamoe").KERNELS == tuple(
+        patterns.values())
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def kinds(calls):
+        found = [[k for k, p in patterns.items() if re.search(p, l)]
+                 for l in calls]
+        assert all(len(f) == 1 for f in found), (calls, found)
+        return sorted(f[0] for f in found)
+
+    even = SWA_SEQ * SWA_TOP_K * SWA_HELD // 256
+    for buffer in (4 * even, SWA_SEQ * min(SWA_TOP_K, SWA_HELD)):
+        rows = gm.layout_rows(buffer, SWA_HELD)
+        for k, n in ((3072, 1024), (1024, 3072)):
+            def product(x, w, sizes, rows=rows):
+                return gm.grouped_matmul(x, w, gm.group_layout(sizes, rows))
+
+            shapes = (sds((rows, k)), sds((SWA_HELD, k, n)),
+                      sds((SWA_HELD,), jnp.int32))
+            assert kinds(_custom_calls_as_traced(product, *shapes)) \
+                == ["forward"]
+            calls = _custom_calls_as_traced(
+                jax.grad(lambda *a: product(*a).astype(jnp.float32).sum(),
+                         argnums=(0, 1)), *shapes)
+            assert kinds(calls) == ["dw", "transposed"], calls
+
+
+def test_cell_swa_moe_step_program_fits_a_v5e(topo, monkeypatch):
+    """The cell's whole step program (a full + dense layer, three sliding
+    and one full expert layer, 8 of 256 experts, an eighth of the
+    vocabulary, 1 x 8192 tokens, full remat, fused CE, bfloat16 moments) by
+    AOT memory_analysis: under 15.75 GiB at the configuration's rows."""
+    import json
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmark.drivers import train_model
+    from ray_tpu.ops import grouped_matmul as gm
+    from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
+
+    _on_tpu(monkeypatch, attention)
+    _on_tpu(monkeypatch, gm)
+    monkeypatch.setattr(attention.dispatch, "_taken", {})
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmark", "configs",
+                        "laguna-s-2.1-train-d5e8.json")
+    doc = json.load(open(path))
+    tr = doc["train"]
+    assert tr["batch_rows"] == 1 and tr["sequence_length"] == SWA_SEQ
+    config = train_model.build_config(doc["program"], doc["model"], tr)
+    mesh = Mesh(topo.devices[:1], ("fsdp",))
+    whole = NamedSharding(mesh, P())
+    ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
+        warmup_steps=tr["lr_warmup_steps"], total_steps=tr["lr_total_steps"],
+        mu_dtype=jnp.bfloat16, nu_dtype=jnp.bfloat16))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    with jax.sharding.set_mesh(mesh):
+        state = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=whole),
+            jax.eval_shape(ts._init_fn, key))
+        batch = {"tokens": jax.ShapeDtypeStruct(
+            (tr["batch_rows"], tr["sequence_length"] + 1), jnp.int32,
+            sharding=whole)}
+        compiled = jax.jit(ts._step_fn, donate_argnums=(0,)).lower(
+            state, batch).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 13.0 * 2 ** 30 < total < 15.75 * 2 ** 30, total / 2 ** 30
+    # Three segments, each flash forward, forward again under remat and
+    # backward (3); the two with experts also the grouped kernels, twelve
+    # at each of the layer's two buffer sizes (a cond's two sides).
+    assert compiled.as_text().count("tpu_custom_call") == 3 * 3 + 2 * 2 * 12
+    taken = attention.dispatch.taken()
+    assert sorted(p.split(",dead")[1] for p in
+                  taken["flash_attention.plan"]) == [
+        "50/50%,window512,visited12.1%,rope_in_kernel", "6/6%,rope_in_kernel"]
+    assert list(taken["swa_moe.rope"]) == [
+        "full_attention:in_kernel64of128_columns_reordered_at_use_identity_"
+        "tail,sliding_attention:in_kernel128of128"]
