@@ -36,6 +36,58 @@ def swiglu(y, w_gate, w_up, w_down, dtype):
     return checkpoint_name(ffn @ w_down.astype(dtype), "mlp_out")
 
 
+# Heads beside each other on the last axis, [b, s, heads x d], is how a
+# projection's matmul writes q, k and v, how the flash kernels take them
+# and how W_o reads the output (ops/attention.py).  On the TPU such an
+# array lies in tiles of 8 rows x 128 columns, and a [b, s, heads, d] view
+# of it is ANOTHER arrangement (its tiles run over heads x d), which XLA
+# reaches by a copy of the whole array.  So what a model does to single
+# heads between the projections and the kernels it does to whole tiles,
+# [b, s / 8, heads, 8, d]: for d a multiple of 128 the two transposes
+# below move no byte, and a repeat compiles to one broadcast (PERF.md,
+# PR 38; 8 is the tile's rows for float32 and bfloat16 alike: with 16 or
+# 32 the copies come back).  Any other d or s is as right, and as XLA
+# lays it.
+_TILE_ROWS = 8
+
+
+def by_tiles(x, heads: int):
+    """[b, s, heads x w] -> [b, s / 8, heads, 8, w] (s / 1 and 1 where 8
+    does not divide s)."""
+    b, s, _ = x.shape
+    rows = _TILE_ROWS if s % _TILE_ROWS == 0 else 1
+    return x.reshape(b, s // rows, rows, heads, -1).transpose(0, 1, 3, 2, 4)
+
+
+def from_tiles(x):
+    """[b, s / 8, heads, 8, w] -> [b, s, heads x w]."""
+    b, blocks, _, rows, _ = x.shape
+    return x.transpose(0, 1, 3, 2, 4).reshape(b, blocks * rows, -1)
+
+
+def repeat_heads(x, heads: int, group: int):
+    """GQA's repeat on a projection's output: x [b, s, heads x d] -> [b, s,
+    heads x group x d], head j of the result the head j // group of x
+    (`jnp.repeat(.., group, axis=2)` of the [b, s, heads, d] view)."""
+    if group == 1:
+        return x
+    t = by_tiles(x, heads)
+    t = jnp.broadcast_to(t[:, :, :, None],
+                         (*t.shape[:3], group, *t.shape[3:]))
+    return from_tiles(t.reshape(*t.shape[:2], heads * group, *t.shape[4:]))
+
+
+def scale_heads(x, scale):
+    """x [b, s, heads x d] with head j's d columns times scale[.., j]
+    ([b, s, heads], float32): the product in float32, rounded to x's
+    dtype."""
+    heads = scale.shape[-1]
+    t = by_tiles(scale, heads)                          # [.., heads, 8, 1]
+    wide = from_tiles(jnp.broadcast_to(
+        t, (*t.shape[:4], x.shape[-1] // heads)))
+    return (x.astype(jnp.float32) * wide).astype(x.dtype)
+
+
 def masked_mean(nll, mask):
     if mask is None:
         return jnp.mean(nll)

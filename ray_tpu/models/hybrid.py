@@ -370,58 +370,64 @@ def _mamba_mixer(u, lp, c: HybridConfig):
 
 def _differential_attention(q, k, v, lp, lam_init, c: HybridConfig,
                             window: Optional[int]):
-    """q: [b, s, heads, d]; k, v: [b, s, kv_heads, d] -> [b, s, heads * d].
-    One flash_attention call: see the module's header."""
+    """q: [b, s, heads x d]; k, v: [b, s, kv_heads x d], as their
+    projections lay them -> [b, s, heads x d].  One flash_attention call:
+    see the module's header.
+
+    A pair's two heads are the halves of ONE 2d-wide block of columns, so
+    the operands are made there, on [b, s, pairs x 2d] and by whole tiles
+    (`common.repeat_heads`), where the kernels take them; a [b, s, heads,
+    d] view of any of them is a copy of it on the TPU."""
     from ray_tpu.ops.attention import flash_attention
 
-    b, s, heads, d = q.shape
-    kv = k.shape[2]
-    rep = heads // kv
+    b, s, _ = q.shape
+    heads, kv, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    pairs, rep = heads // 2, heads // kv
 
-    def pairs(x, first):    # the first or second head of every pair
-        return x[:, :, first::2]
+    def first_and_second(x):
+        """[.., n x 2d], the pairs (x1 | x2) -> (x1 | 0) and (x2 | 0) of
+        every pair: queries and keys zero-padded from d to 2d."""
+        first = (jnp.arange(x.shape[-1]) % (2 * d)) < d
+        second = jnp.pad(x[..., d:], ((0, 0), (0, 0), (0, d)))  # d to the left
+        return jnp.where(first, x, 0), jnp.where(first, second, 0)
 
     def for_queries(x):     # one KV pair for each of its `rep` query pairs
-        return jnp.repeat(x, rep, axis=2)
+        return common.repeat_heads(x, kv // 2, rep)
 
-    def widen(x):           # zero-pad the head size d -> 2d
-        return jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, d)))
-
-    values = for_queries(v.reshape(b, s, kv // 2, 2 * d))
+    values = for_queries(v)
     out = flash_attention(
-        widen(jnp.concatenate([pairs(q, 0), pairs(q, 1)], axis=2)),
-        widen(jnp.concatenate([for_queries(pairs(k, 0)),
-                               for_queries(pairs(k, 1))], axis=2)),
-        jnp.concatenate([values, values], axis=2),
+        *(jnp.concatenate(x, axis=-1).reshape(b, s, heads, 2 * d) for x in (
+            first_and_second(q),
+            [for_queries(x) for x in first_and_second(k)],
+            [values, values])),
         causal=True, sm_scale=1.0 / math.sqrt(d), window=window)
+    out = out.reshape(b, s, heads * 2 * d)
     lam = (jnp.exp(jnp.sum(lp["lq1"].astype(F32) * lp["lk1"].astype(F32)))
            - jnp.exp(jnp.sum(lp["lq2"].astype(F32) * lp["lk2"].astype(F32)))
            + lam_init)
-    a = (out[:, :, :heads // 2].astype(F32)
-         - lam * out[:, :, heads // 2:].astype(F32))        # [b, s, pairs, 2d]
+    a = common.by_tiles(out[..., :pairs * 2 * d].astype(F32)
+                        - lam * out[..., pairs * 2 * d:].astype(F32),
+                        pairs)                          # [.., pairs, 8, 2d]
     a = a * jax.lax.rsqrt(jnp.mean(jnp.square(a), axis=-1, keepdims=True)
                           + c.layer_norm_eps)
     a = a * lp["subln"].astype(F32) * (1.0 - lam_init)
-    return a.astype(c.dtype).reshape(b, s, heads * d)
+    return common.from_tiles(a.astype(c.dtype))
 
 
 def _attention_mixer(u, lp, lam_init, c: HybridConfig, window):
-    """-> (the mixer's output, (k, v) as projected)."""
-    b, s, _ = u.shape
+    """-> (the mixer's output, (k, v) as projected, [b, s, kv_heads x d])."""
     heads, kv, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     qkv = _matmul(u, lp["wqkv"], c) + lp["bqkv"].astype(c.dtype)
-    q = qkv[..., :heads * d].reshape(b, s, heads, d)
-    k = qkv[..., heads * d:(heads + kv) * d].reshape(b, s, kv, d)
-    v = qkv[..., (heads + kv) * d:].reshape(b, s, kv, d)
-    q = with_logical_constraint(q, ("batch", "seq", "heads", None))
+    q = qkv[..., :heads * d]
+    k = qkv[..., heads * d:(heads + kv) * d]
+    v = qkv[..., (heads + kv) * d:]
+    q = with_logical_constraint(q, ("batch", "seq", "heads"))
     a = _differential_attention(q, k, v, lp, lam_init, c, window)
     return _matmul(a, lp["wo"], c) + lp["bo"].astype(c.dtype), (k, v)
 
 
 def _cross_mixer(u, lp, lam_init, shared_kv, c: HybridConfig):
-    b, s, _ = u.shape
-    q = (_matmul(u, lp["wq"], c) + lp["bq"].astype(c.dtype)).reshape(
-        b, s, c.num_attention_heads, c.head_dim)
+    q = _matmul(u, lp["wq"], c) + lp["bq"].astype(c.dtype)
     a = _differential_attention(q, *shared_kv, lp, lam_init, c, None)
     return _matmul(a, lp["wo"], c) + lp["bo"].astype(c.dtype)
 

@@ -228,12 +228,13 @@ class SwaMoEConfig:
     @classmethod
     def tiny(cls, **kw) -> "SwaMoEConfig":
         """Test-sized: both kinds of layer in the published order, two head
-        counts (groups of 2 and 3), a window shorter than a test's
-        sequence, 4 of 16 experts held."""
+        counts (groups of 2 and 3; heads of 64, two a lane block, so that
+        the flash kernels work a pair a program and pad nothing), a window
+        shorter than a test's sequence, 4 of 16 experts held."""
         return cls(**{**dict(
             vocab_size=256, hidden_size=64, intermediate_size=128,
             num_hidden_layers=5, num_attention_heads=4,
-            num_key_value_heads=2, head_dim=16,
+            num_key_value_heads=2, head_dim=64,
             num_attention_heads_per_layer=(4, 6, 6, 6, 4), sliding_window=32,
             num_experts=4, router_width=16, num_experts_per_tok=3,
             moe_intermediate_size=32, shared_expert_intermediate_size=32),
@@ -441,21 +442,23 @@ def _attention(u, lp, tables, *, kind: str, heads: int, c: SwaMoEConfig):
     with jax.named_scope("attn.full" if kind == FULL else "attn.sliding"):
         wq = _rotary_first_halves(lp["wq"].astype(c.dtype), heads, c, kind)
         wk = _rotary_first_halves(lp["wk"].astype(c.dtype), kv, c, kind)
-        q = _matmul(u, wq, c).reshape(b, s, heads, d)
-        q = with_logical_constraint(q, ("batch", "seq", "heads", None))
-        k = _matmul(u, wk, c).reshape(b, s, kv, d)
-        v = _matmul(u, lp["wv"], c).reshape(b, s, kv, d)
-        # the kernels take expanded heads: query head j reads KV head
-        # j // group
-        k, v = (jnp.repeat(x, heads // kv, axis=2) for x in (k, v))
+        q = with_logical_constraint(_matmul(u, wq, c),
+                                    ("batch", "seq", "heads"))
+        q = q.reshape(b, s, heads, d)
+        # the kernels take expanded heads, query head j reading KV head
+        # j // group, and take them as the projections lay them, [b, s,
+        # heads x d]: the repeat and the gate work on that, by whole tiles
+        # (`common.repeat_heads`), and the [b, s, heads, d] views fold away
+        k, v = (common.repeat_heads(_matmul(u, w, c), kv, heads // kv)
+                .reshape(b, s, heads, d) for w in (wk, lp["wv"]))
         rope = tuple(jnp.broadcast_to(t, (b, *t.shape)) for t in tables)
         a = flash_attention(
             q, k, v, causal=True, sm_scale=1.0 / math.sqrt(d), rope=rope,
             window=c.sliding_window if kind == SLIDING else None)
     with jax.named_scope("attn.gate"):
         gate = jax.nn.sigmoid(_matmul(u, lp["wg"], c, F32))
-        a = (a.astype(F32) * gate[..., None]).astype(c.dtype)
-    return _matmul(a.reshape(b, s, heads * d), lp["wo"], c)
+        a = common.scale_heads(a.reshape(b, s, heads * d), gate)
+    return _matmul(a, lp["wo"], c)
 
 
 def _routed_part(flat, router_w, w_gate, w_up, w_down, c: SwaMoEConfig):

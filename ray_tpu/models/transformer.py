@@ -256,11 +256,17 @@ def _block(x, bp, rope, mask, config: TransformerConfig):
 
     y = rms_norm(x, bp["attn_norm"], c.rms_eps)
     y = with_logical_constraint(y, ("batch", "seq", "embed"))
-    q = (y @ bp["wq"].astype(c.dtype)).reshape(b, s, c.num_heads, hd)
-    k = (y @ bp["wk"].astype(c.dtype)).reshape(b, s, c.num_kv_heads, hd)
-    v = (y @ bp["wv"].astype(c.dtype)).reshape(b, s, c.num_kv_heads, hd)
-    q = with_logical_constraint(q, ("batch", "seq", "heads", None))
-    k = with_logical_constraint(k, ("batch", "seq", "heads", None))
+    # The heads' constraint goes on the projections' own [b, s, heads x hd]
+    # (whole heads a shard, as on the 4-D view): behind it the reshape to
+    # heads folds against the flash kernels' own back, and q and k reach
+    # them as the matmuls wrote them; on the 4-D view it stood between the
+    # two and XLA laid that view out, sequence-minor, and copied it.
+    q, k, v = (with_logical_constraint(y @ bp[w].astype(c.dtype),
+                                       ("batch", "seq", "heads"))
+               for w in ("wq", "wk", "wv"))
+    q = q.reshape(b, s, c.num_heads, hd)
+    k = k.reshape(b, s, c.num_kv_heads, hd)
+    v = v.reshape(b, s, c.num_kv_heads, hd)
     if not _ropes_in_flash(c):
         from ray_tpu.ops.attention import rope_reference
 

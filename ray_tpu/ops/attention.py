@@ -4,8 +4,9 @@ The reference framework ships no attention kernels (SURVEY.md §5 — long-conte
 machinery is absent in-tree); on TPU this is a core op.  Design:
 
   - `flash_attention(q, k, v, causal=..., window=...)`: online-softmax
-    tiled kernel (Pallas, grid over (batch*heads, q-tiles), K/V of one head
-    resident in VMEM) so the s×s score matrix never materializes in HBM.
+    tiled kernel (Pallas, grid over (batch x groups of heads, q-tiles), the
+    K/V of a program's heads resident in VMEM) so the s×s score matrix
+    never materializes in HBM.
     Causal, or causal under a sliding window (query t sees keys s with
     0 <= t - s < window): the blocks wholly behind a tile's window are not
     visited, the trailing edge is masked in forward and backward.
@@ -52,8 +53,22 @@ machinery is absent in-tree); on TPU this is a core op.  Design:
     backward writes [dk_nope | dv] as the projection's gradient reads it.
 
 Layout convention: q, k are [batch, seq, heads, head_dim], v is [batch, seq,
-heads, value_dim] (the models/ convention); kernels internally fold
-batch×heads.
+heads, value_dim] (the models/ convention).  The whole-operand calls hand
+the kernels q, k, v (and do) as [batch, seq, heads x head_dim], which is how
+the projections' matmuls write them and W_o and the weight gradients read
+out, dq, dk and dv: a reshape that XLA folds against the model's own, no
+transpose and no 64-wide last axis (half a lane block, which XLA pads to
+twice its bytes and lays sequence-minor).  The grid's first axis selects
+LANE blocks through the BlockSpecs' index maps: one head of 128 is one lane
+block, two heads of 64 share one, lcm(d, 128) / d in general
+(`_heads_a_program`).  A program works its heads together: loads and stores
+are whole lane blocks; each head's scores come from a contraction over all
+the block's lanes against an operand in which the other heads' lanes are
+zero (`_head_alone`: the MXU passes of the d-deep contraction, no lane
+shift); softmax's state, the output's accumulator and dq's sum are a
+head's own rows, dk's and dv's sums a head's own array, joined by lane at
+the end.  The plan says `operands_bshd,heads2x64`.  lse, and the -dlse the
+backward takes, stay a row a head, [batch x heads, 8, seq].
 """
 
 from __future__ import annotations
@@ -288,36 +303,80 @@ def rope_reference(x, cos, sin):
     return out.astype(x.dtype)
 
 
-def _widen_rope(rope):
-    """(cos, sin) [b, s, d/2] -> [b, s, d] float32 as the kernels take
-    them: cos twice, and the sine with rotate_half's sign folded in, so
-    that rope(x) = x * cos + swap_halves(x) * sin."""
+def _widen_rope(rope, heads: int = 1):
+    """(cos, sin) [b, s, d/2] -> [b, s, heads x d] float32 as the kernels
+    take them: for each of the heads a program works, cos twice and the
+    sine with rotate_half's sign folded in, so that rope(x) = x * cos +
+    swap_halves(x) * sin."""
     def twice(t, signs):      # a broadcast, where a concatenate would pad
         t = t.astype(jnp.float32)[:, :, None, :] * jnp.asarray(
-            signs, jnp.float32)[:, None]
+            signs * heads, jnp.float32)[:, None]
         return t.reshape(*t.shape[:2], -1)
 
     cos, sin = rope
     return twice(cos, (1.0, 1.0)), twice(sin, (-1.0, 1.0))
 
 
-def _swap_halves(x):
-    """[rows, d] with the two halves of d exchanged (rotate_half without its
-    sign, which `_widen_rope` folds into the sine), in VMEM: two lane
-    slices and a concatenate, exact in any dtype.  Of the forms Mosaic
-    takes on the v5e this one timed fastest (PERF.md, PR 33: a matmul with
-    the d x d permutation needs the MXU's full-precision passes on the
-    float32 sums and added 0.93 ms to a backward call at 160 x 2048 x 64,
-    this 0.40; a lane roll of bfloat16 is not implemented)."""
-    half = x.shape[-1] // 2
-    return jnp.concatenate([x[:, half:], x[:, :half]], axis=1)
+def _swap_halves(x, heads: int = 1):
+    """[rows, heads x d] with the two halves of each head's d exchanged
+    (rotate_half without its sign, which `_widen_rope` folds into the
+    sine), in VMEM: two lane slices a head and a concatenate, exact in any
+    dtype.  Of the forms Mosaic takes on the v5e this one timed fastest
+    (PERF.md, PR 33: a matmul with the d x d permutation needs the MXU's
+    full-precision passes on the float32 sums and added 0.93 ms to a
+    backward call at 160 x 2048 x 64, this 0.40; a lane roll of bfloat16
+    is not implemented)."""
+    half = x.shape[-1] // (2 * heads)
+    return jnp.concatenate(
+        [x[:, (i ^ 1) * half:((i ^ 1) + 1) * half]
+         for i in range(2 * heads)], axis=1)
 
 
-def _roped(x, cos, sin):
-    """The rotary embedding of a [rows, d] block, float32; cos, sin: the
-    widened tables' rows.  With -sin it is the transpose, for a gradient."""
+def _roped(x, cos, sin, heads: int = 1):
+    """The rotary embedding of a [rows, heads x d] block, each head's d by
+    itself, float32; cos, sin: the widened tables' rows.  With -sin it is
+    the transpose, for a gradient."""
     return (x.astype(jnp.float32) * cos
-            + _swap_halves(x).astype(jnp.float32) * sin)
+            + _swap_halves(x, heads).astype(jnp.float32) * sin)
+
+
+def _heads_a_program(d: int, d_v: int) -> int:
+    """How many heads one program works: the operands cross HBM as their
+    projections lay them, [b, s, heads x d], and a program's block of
+    them is whole lane blocks of 128, so lcm(d, 128) / d heads (two of 64,
+    one of 128), for the keys' width and the values' both."""
+    return max(128 // math.gcd(d, 128), 128 // math.gcd(d_v, 128))
+
+
+def _head_lanes(shape, j: int, heads: int):
+    """[shape] bool: the lanes (last axis) of head j of `heads`."""
+    d = shape[-1] // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return jnp.logical_and(lane >= j * d, lane < (j + 1) * d)
+
+
+def _head_alone(x, j: int, heads: int, scale: float = 1.0):
+    """[rows, heads x d] with head j's lanes times `scale` and every other
+    lane zero, in x's dtype: against it a contraction over ALL the lanes
+    is head j's over its own d, at the MXU passes the d-deep one costs (a
+    contraction 64 deep half fills the array as it is) and with no lane
+    shift.  The product is float32 rounded back: exact for 1 and for a
+    power of two, `_scaled`'s rounding for another scale."""
+    row = (1, x.shape[-1])
+    factor = _keep(_head_lanes(row, j, heads),
+                   jnp.full(row, scale, jnp.float32), 0.0)
+    return (x.astype(jnp.float32) * factor).astype(x.dtype)
+
+
+def _join_heads(per_head):
+    """One [rows, heads x d] from an array a head, each right in its own
+    head's lanes (what p_j . do or ds_j . q gives) and to be dropped in
+    the others'."""
+    out = per_head[-1]
+    for j in range(len(per_head) - 2, -1, -1):
+        out = jax.lax.select(_head_lanes(out.shape, j, len(per_head)),
+                             per_head[j], out)
+    return out
 
 
 def _roped_from(nope: int, x, cos, sin):
@@ -403,11 +462,23 @@ def _walk_blocks(step, carry, causal: bool, tile_min, tile: int, inner: int,
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, *refs,
                 causal: bool, block_q: int, block_k: int, seq_k: int,
                 sm_scale: float, fold_scale: bool, windowed: bool = False,
-                rope_q0: Optional[int] = None, parts=None):
-    """rope_q0 (None: no rope, the kernel without): the row of the tables,
+                rope_q0: Optional[int] = None, parts=None, heads: int = 1):
+    """heads: how many heads' lanes the blocks hold beside each other
+    (`_heads_a_program`; 1 builds the kernel of one): k and v are loaded
+    and the output stored for all of them at once, every lane, and each
+    head keeps its own scores, softmax state and accumulator.
+
+    Several heads' refs end with one more scratch, [heads x d, keys]: their
+    values turned once, at the heads' first query tile, so that a head's p .
+    v reads its own ROWS of them as a plain operand.  (v^T . p on the
+    loaded block gives all the heads' rows for each head's p: measured on
+    the v5e at 5 x 2048 x 32 x 64, 1.84 ms a forward call against 1.62 so,
+    PERF.md, PR 38.)
+
+    rope_q0 (None: no rope, the kernel without): the row of the tables,
     which hold the KEYS' positions, at which the queries' begin.  Then refs
     holds the two tables before the outputs and, after them, a scratch for
-    the head's roped keys.
+    the heads' roped keys.
 
     parts (with rope_q0; None: whole operands, the kernel without): (nope,
     heads) of latent attention.  k_ref is then a head's [k_nope | v] as the
@@ -419,13 +490,23 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, *refs,
 
     qi = pl.program_id(1)
     window = offs_ref[2] if windowed else None
-    q = q_ref[0]  # [block_q, d]
-    d = v_ref.shape[-1]     # the accumulator's rows: the values' width
+    q = q_ref[0]  # [block_q, heads x d]
+    d = v_ref.shape[-1] // heads  # the accumulator's rows: the values' width
     v_cols = slice(None)
 
     def q_table_rows():
         return pl.ds(pl.multiple_of(rope_q0 + qi * block_q,
                                     math.gcd(rope_q0, block_q)), block_q)
+
+    if heads > 1:
+        *refs, vt_ref = refs
+
+        def turn_values(at):
+            vt_ref[:, at] = v_ref[0, at, :].T
+
+        @pl.when(qi == 0)
+        def _():
+            _for_row_blocks(turn_values, seq_k, block_k)
 
     if rope_q0 is None:
         o_ref, lse_ref = refs
@@ -439,15 +520,16 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, *refs,
 
         @pl.when(qi == 0)
         def _():
-            k_ref[0] = _roped(plain_k_ref[0], cos_ref[0],
-                              sin_ref[0]).astype(k_ref.dtype)
+            k_ref[0] = _roped(plain_k_ref[0], cos_ref[0], sin_ref[0],
+                              heads).astype(k_ref.dtype)
 
         rows = q_table_rows()
         # rounded to the operand's dtype before the scale and any matmul,
         # as rope in XLA rounds it
-        q = _roped(q, cos_ref[0, rows, :], sin_ref[0, rows, :]).astype(q.dtype)
+        q = _roped(q, cos_ref[0, rows, :], sin_ref[0, rows, :],
+                   heads).astype(q.dtype)
     else:
-        nope, heads = parts
+        nope, row_heads = parts
         cos_ref, sin_ref, o_ref, lse_ref, roped_k_ref, roped_pe_ref = refs
         # v is the lane-aligned end of the head's [k_nope | v]; the head's
         # keys, [k_nope | rope(k_pe)], are put together in scratch at its
@@ -465,7 +547,8 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, *refs,
             k_ref[0, at, nope:] = roped_pe_ref[at, :]
 
         # the row's ONE rotary key is roped at the first of its heads
-        @pl.when(jnp.logical_and(qi == 0, pl.program_id(0) % heads == 0))
+        @pl.when(jnp.logical_and(qi == 0,
+                                 pl.program_id(0) % row_heads == 0))
         def _():
             _for_row_blocks(rope_pe, seq_k, block_k)
 
@@ -475,52 +558,76 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, *refs,
 
         rows = q_table_rows()
         q = _roped_from(nope, q, cos_ref[0, rows, :], sin_ref[0, rows, :])
-    if fold_scale:
-        q = _scaled(q, sm_scale)
+    if heads > 1:       # a head's q alone in its lanes, the scale with it
+        qs = [_head_alone(q, j, heads, sm_scale if fold_scale else 1.0)
+              for j in range(heads)]
+    else:
+        qs = [_scaled(q, sm_scale) if fold_scale else q]
     query_minus_key = _query_minus_key(block_k, block_q) if causal else None
 
     def step(start, first, carry, lo: int):
         """The key block at `start` against queries [lo, block_q) of the
-        tile.  Scores, statistics and the accumulator are held [keys | d,
-        queries]."""
-        m, l, acc = (x[:, lo:] for x in carry)
-        start = pl.multiple_of(start, block_k)
-        k_blk = k_ref[0, pl.ds(start, block_k), :]
-        v_blk = v_ref[0, pl.ds(start, block_k), v_cols]
-        s = _dot(k_blk, q[lo:], 1, 1)              # [block_k, block_q - lo]
-        if not fold_scale:
-            s = s * sm_scale
-        if causal:
-            s = _keep(_visible(query_minus_key[:, lo:], first, window),
-                      s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=0, keepdims=True)
-        # p in v's dtype for the second MXU matmul (f32 accumulation
-        # preserved by preferred_element_type) — same as every
-        # production flash kernel; probabilities are in [0, 1] so bf16
-        # rounding here is benign relative to the softmax itself.
-        acc_new = acc * alpha + _dot(v_blk, p.astype(v_blk.dtype), 0, 0)
-        return tuple(_put(old, lo, new) for old, new in
-                     zip(carry, (m_new, l_new, acc_new)))
+        tile, a head after the other.  Scores, statistics and the
+        accumulator are held [keys | d, queries]; carry: (m, l, acc) of
+        each head in turn."""
+        visible, new = None, []
+        for j, q in enumerate(qs):
+            m, l, acc = (x[:, lo:] for x in carry[3 * j:3 * j + 3])
+            if j == 0:      # one load of the block for all its heads
+                start = pl.multiple_of(start, block_k)
+                k_blk = k_ref[0, pl.ds(start, block_k), :]
+                if heads == 1:
+                    v_blk = v_ref[0, pl.ds(start, block_k), v_cols]
+            s = _dot(k_blk, q[lo:], 1, 1)          # [block_k, block_q - lo]
+            if not fold_scale:
+                s = s * sm_scale
+            if causal:
+                if visible is None:     # and one mask
+                    visible = _visible(query_minus_key[:, lo:], first, window)
+                s = _keep(visible, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_new = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+            # p in v's dtype for the second MXU matmul (f32 accumulation
+            # preserved by preferred_element_type) — same as every
+            # production flash kernel; probabilities are in [0, 1] so bf16
+            # rounding here is benign relative to the softmax itself.
+            acc = acc * alpha
+            if heads > 1:       # this head's rows of the values, turned
+                pv = _dot(vt_ref[j * d:(j + 1) * d, pl.ds(start, block_k)],
+                          p.astype(vt_ref.dtype), 1, 0)
+            else:
+                pv = _dot(v_blk, p.astype(v_blk.dtype), 0, 0)
+            new += [m_new, l_new, acc + pv]
+        return tuple(_put(old, lo, x) for old, x in zip(carry, new))
 
     carry = (jnp.full((1, block_q), _NEG_INF, jnp.float32),
              jnp.zeros((1, block_q), jnp.float32),
-             jnp.zeros((d, block_q), jnp.float32))
-    m, l, acc = _walk_blocks(
+             jnp.zeros((d, block_q), jnp.float32)) * heads
+    carry = _walk_blocks(
         step, carry, causal, offs_ref[0] - offs_ref[1] + qi * block_q,
         block_q, block_k, seq_k // block_k, window)
-    l_safe = jnp.maximum(l, 1e-30)
-    # Queries with no visible keys (possible in ring chunks "from the
-    # future"): m stayed at -inf, so p accumulated exp(0)=1 garbage —
-    # zero the output and mark lse = -inf ("no weight" for the merge).
-    valid = m > _NEG_INF / 2
-    o_ref[0] = jnp.where(valid, acc / l_safe, 0.0).T.astype(o_ref.dtype)
-    lse = jnp.where(valid & (l > 0), m + jnp.log(l_safe), _NEG_INF)
-    # lse is logically [block_q]; stored broadcast over an 8-sublane axis so
-    # the block shape ends in (8, block_q) per Mosaic's tiling constraint.
-    lse_ref[0] = jnp.broadcast_to(lse, (8, block_q))
+    outs, safe = [], []
+    for m, l, acc in zip(carry[0::3], carry[1::3], carry[2::3]):
+        l_safe = jnp.maximum(l, 1e-30)
+        # Queries with no visible keys (possible in ring chunks "from the
+        # future"): m stayed at -inf, so p accumulated exp(0)=1 garbage —
+        # zero the output and mark lse = -inf ("no weight" for the merge).
+        valid = m > _NEG_INF / 2
+        outs.append(jnp.where(valid, acc / l_safe, 0.0))
+        safe.append((valid, l_safe))
+    # the heads' [d, queries] under each other, turned once: every lane of
+    # the output's block is stored
+    out = outs[0] if heads == 1 else jnp.concatenate(outs, axis=0)
+    o_ref[0] = out.T.astype(o_ref.dtype)
+    for j, (valid, l_safe) in enumerate(safe):
+        m, l = carry[3 * j:3 * j + 2]
+        lse = jnp.where(valid & (l > 0), m + jnp.log(l_safe), _NEG_INF)
+        # lse is logically [block_q]; stored broadcast over an 8-sublane
+        # axis so the block shape ends in (8, block_q) per Mosaic's tiling
+        # constraint.
+        lse_ref[j] = jnp.broadcast_to(lse, (8, block_q))
 
 
 def _compiler_params(vmem_mib: int = 32):
@@ -535,21 +642,46 @@ def _compiler_params(vmem_mib: int = 32):
     return pltpu.CompilerParams(vmem_limit_bytes=vmem_mib << 20)
 
 
-def _rope_operands(rope, heads: int, seq_k: int, d: int):
+def _rope_operands(rope, programs: int, seq_k: int, d: int):
     """(operands, their BlockSpecs) of the widened tables [b, seq_k, d]: a
     row's whole table, whose block index does not move across the row's
-    heads or tiles, so the pipeline loads it once a row."""
+    `programs` (its heads, or its groups of them) or their tiles, so the
+    pipeline loads it once a row."""
     from jax.experimental import pallas as pl
 
     if rope is None:
         return (), []
-    spec = pl.BlockSpec((1, seq_k, d), lambda g, i, offs: (g // heads, 0, 0))
+    spec = pl.BlockSpec((1, seq_k, d),
+                        lambda g, i, offs: (g // programs, 0, 0))
     return tuple(rope), [spec, spec]
 
 
 def _lanes(width: int) -> int:
     """A row of `width` as VMEM holds it: whole lanes of 128."""
     return -(-width // 128) * 128
+
+
+def _head_spec(rows: int, width: int, groups: int, whole: bool = False):
+    """The BlockSpec that reads `rows` x `width` of a [b, t, h x w] array
+    for program (g, i), g = row x groups + group: the group's lanes by
+    lane-block index, rows i x rows on (whole: all t, whatever i)."""
+    from jax.experimental import pallas as pl
+
+    where = (lambda i: 0) if whole else (lambda i: i)
+    return pl.BlockSpec(
+        (1, rows, width),
+        lambda g, i, offs: (g // groups, where(i), g % groups))
+
+
+def _head_blocks(x, heads: int, groups: int, rows: Optional[int]):
+    """(x [b, t, h, w] as its projection lays it, [b, t, h x w]: a reshape
+    that XLA folds against the model's own; the BlockSpec that reads `rows`
+    of it for program (g, i)): the lanes of the `heads` heads that program
+    g works, no transpose and nothing padded.  rows=None: all t, whatever
+    the tile."""
+    b, t, h, w = x.shape
+    return x.reshape(b, t, h * w), _head_spec(rows or t, heads * w, groups,
+                                              rows is None)
 
 
 def _latent_parts(q, kv, k_pe):
@@ -601,27 +733,48 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
     sk, d_v = k.shape[1], v.shape[-1]
     if fold_scale is None:
         fold_scale = _scale_is_exact(sm_scale)
-    # fold batch*heads, put seq in the middle: [bh, s, d]
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     parts = _latent_parts(q, k, v)
     if parts is None:
-        kv_operands = (k.transpose(0, 2, 1, 3).reshape(b * h, sk, d),
-                       v.transpose(0, 2, 1, 3).reshape(b * h, sk, d_v))
-        kv_specs = [
-            pl.BlockSpec((1, sk, d), lambda bh, i, offs: (bh, 0, 0)),
-            pl.BlockSpec((1, sk, d_v), lambda bh, i, offs: (bh, 0, 0))]
-        scratch = [] if rope is None else [pltpu.VMEM((1, sk, d), k.dtype)]
+        # q, k, v and out cross HBM where the projections lay and read
+        # them, [b, s, h x d]; a program works the `heads` heads of one
+        # lane block (h is a multiple: `_chunk`), the grid's first axis
+        # their groups within each row.
+        heads = _heads_a_program(d, d_v)
+        programs = h // heads
+        qf, q_spec = _head_blocks(q, heads, programs, block_q)
+        kv_operands, kv_specs = zip(_head_blocks(k, heads, programs, None),
+                                    _head_blocks(v, heads, programs, None))
+        out_shape = jax.ShapeDtypeStruct((b, sq, h * d_v), q.dtype)
+        out_spec = _head_spec(block_q, heads * d_v, programs)
+        table_width = heads * d
+        scratch = [] if rope is None else [
+            pltpu.VMEM((1, sk, table_width), k.dtype)]
         # The two float32 tables, a row's whole and double-buffered, are
         # four blocks of sk x 128 lanes beside the resident k and v and
         # the roped keys' scratch: 33.9 MiB needed at 8192 x 128, where
         # the 32 hold every shorter or rope-less call.
-        table_mib = 0 if rope is None else -(-sk * _lanes(d) * 4 // 2 ** 20)
-        table_width, vmem_mib = d, max(32, 24 + 4 * table_mib)
+        table_mib = 0 if rope is None else -(
+            -sk * _lanes(table_width) * 4 // 2 ** 20)
+        # and whatever the heads' resident k and v, double-buffered, hold
+        # beyond one head of 128 at 8192 rows (8 MiB): 12 more for two
+        # heads of 192 / 128 there
+        kv_mib = -(-sk * 4 * (_lanes(heads * d) + _lanes(heads * d_v))
+                   // 2 ** 20)
+        if heads > 1:       # the heads' values, turned
+            scratch = scratch + [pltpu.VMEM((heads * d_v, sk), v.dtype)]
+            kv_mib += -(-sk * 2 * _lanes(heads * d_v) // 2 ** 20)
+        vmem_mib = max(32, 24 + 4 * table_mib) + max(kv_mib - 8, 0)
     else:
         # q keeps its [bh, s, d] face (the benchmark's reader finds the
         # call by it) and out comes back [bh, s, e]; k_nope, v and the
         # rotary key are read where their projections left them.
         nope, d_v, table_width = parts
+        heads, programs = 1, h
+        qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
+        q_spec = pl.BlockSpec((1, block_q, d), lambda bh, i, offs: (bh, i, 0))
+        out_shape = jax.ShapeDtypeStruct((b * h, sq, d_v), q.dtype)
+        out_spec = pl.BlockSpec((1, block_q, d_v),
+                                lambda bh, i, offs: (bh, i, 0))
         kv_operands, kv_specs = _parts_operands(k, v, h, None)
         scratch = [pltpu.VMEM((1, sk, d), k.dtype),
                    pltpu.VMEM((sk, table_width), k.dtype)]
@@ -632,39 +785,42 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
         vmem_mib = 32 + -(-sk * (4 * _lanes(nope + d_v) + 2 * _lanes(d)
                                  + 22 * _lanes(table_width)) // 2 ** 20)
 
-    grid = (b * h, sq // block_q)
+    grid = (b * programs, sq // block_q)
     kernel = functools.partial(
         _fwd_kernel, causal=causal, block_q=block_q, block_k=block_k,
         seq_k=sk, sm_scale=sm_scale, fold_scale=fold_scale,
         **({"windowed": True} if windowed else {}),
         **({} if rope is None else {"rope_q0": sk - sq}),
-        **({} if parts is None else {"parts": (nope, h)}))
-    tables, table_specs = _rope_operands(rope, h, sk, table_width)
+        **({} if parts is None else {"parts": (nope, h)}),
+        **({} if heads == 1 else {"heads": heads}))
+    tables, table_specs = _rope_operands(rope, programs, sk, table_width)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda bh, i, offs: (bh, i, 0)),
-                *kv_specs,
-                *table_specs,
-            ],
+            in_specs=[q_spec, *kv_specs, *table_specs],
             out_specs=[
-                pl.BlockSpec((1, block_q, d_v), lambda bh, i, offs: (bh, i, 0)),
-                pl.BlockSpec((1, 8, block_q), lambda bh, i, offs: (bh, 0, i)),
+                out_spec,
+                # lse stays a row a head, [b x h, 8, sq]: a program's
+                # heads are neighbours there
+                pl.BlockSpec((heads, 8, block_q),
+                             lambda g, i, offs: (g, 0, i)),
             ],
             scratch_shapes=scratch,
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d_v), q.dtype),
+            out_shape,
             jax.ShapeDtypeStruct((b * h, 8, sq), jnp.float32),
         ],
         compiler_params=_compiler_params(vmem_mib),
         interpret=dispatch.interpret_mode(),
         name="flash_fwd",
     )(offs, qf, *kv_operands, *tables)
-    out = out.reshape(b, h, sq, d_v).transpose(0, 2, 1, 3)
+    if parts is None:
+        out = out.reshape(b, sq, h, d_v)
+    else:
+        out = out.reshape(b, h, sq, d_v).transpose(0, 2, 1, 3)
     return out, lse[:, 0, :]  # lse: [bh, sq]
 
 
@@ -672,12 +828,14 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
 # Pallas backward kernel: ONE pass recomputes the scores by block from the
 # saved logsumexp and gives dq, dk and dv (the public flash-attention
 # backward, with its two passes folded into the one that tiles the keys).
-# corr = delta - dlse is precomputed outside: delta = rowsum(do * out), and
-# dlse is the cotangent of the lse OUTPUT (zero for plain flash_attention,
-# nonzero under ring attention's merge).
+# corr = delta - dlse: -dlse comes in, the cotangent of the lse OUTPUT (zero
+# for plain flash_attention, nonzero under ring attention's merge), and
+# delta = rowsum(do * out) is made here from the output, one more input, at
+# the first key tile.
 #
 # A program holds one key tile (k, v: [block_k, d]) and a head's WHOLE q,
-# do, lse and corr in VMEM, and meets the query blocks that can see the
+# do, out, lse and corr in VMEM (of the heads that share its lane block:
+# `_heads_a_program`), and meets the query blocks that can see the
 # tile: the forward's plan with the roles turned (the diagonal cuts the
 # tile's END off in straight-line steps, then the loop; under a window the
 # loop ends early).  A step makes s, p, dp and ds once, [keys, queries],
@@ -695,46 +853,55 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
 # unscaled per score and the f32 accumulator of dk is scaled at the end.
 # ---------------------------------------------------------------------------
 
-def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
-                *refs, causal: bool,
+def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                minus_dlse_ref, *refs, causal: bool,
                 block_q: int, block_k: int, seq_q: int, sm_scale: float,
                 fold_scale: bool, windowed: bool = False,
-                roped: bool = False, nope: Optional[int] = None):
-    """roped: refs holds the two tables (the KEYS' positions; the queries'
-    are their last seq_q rows) before the outputs and, after the scratch,
-    one more for the head's roped q.  dq and dk are then the gradients of
-    the UN-roped q and k: rope's transpose goes on the float32 sums, before
-    their one rounding.
+                roped: bool = False, nope: Optional[int] = None,
+                heads: int = 1):
+    """heads: how many heads' lanes the blocks hold beside each other (1
+    builds the kernel of one): q, k, v and do are loaded and dq, dk, dv
+    stored for all of them at once; each head has its own scores, its own
+    float32 sums of dk and dv (right in its own lanes, joined at the end)
+    and its own rows of dq's sum.
+
+    refs begins with the heads' output, one more input: delta = rowsum(do *
+    out) is made here, a head's d at a time, at the heads' first key tile,
+    and added to the -dlse that comes in, into the last scratch (in XLA it
+    drew a float32 relayout of do * out after it: 168 MB a call at 5 x 2048
+    x 32 x 64).
+
+    roped: refs then holds the two tables (the KEYS' positions; the
+    queries' are their last seq_q rows) before the outputs and, after the
+    scratch, one more for the heads' roped q.  dq and dk are then the
+    gradients of the UN-roped q and k: rope's transpose goes on the float32
+    sums, before their one rounding.
 
     nope (with roped; None: whole operands, the kernel without): latent
     attention's parts.  k_ref is the tile of a head's [k_nope | v] as the
     projection lays it, v_ref that of the row's one rotary key, un-roped,
     do_ref a head's columns of the output's gradient; q is roped from
-    column nope on.  corr_ref holds -dlse alone: the head's output is one
-    more input, behind the tables, and delta = rowsum(do * out) is made
-    and added here, at the head's first key tile, into one more scratch
-    (in XLA it drew two relayouts of do after it).  The outputs are dq,
-    [dk_nope | dv] laid as k_ref is, and this head's share of the rotary
-    key's gradient."""
+    column nope on; the head's output stands BEHIND the tables.  The
+    outputs are dq, [dk_nope | dv] laid as k_ref is, and this head's share
+    of the rotary key's gradient."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
     window = offs_ref[2] if windowed else None
-    k = k_ref[0]                              # [block_k, d] native dtype
+    k = k_ref[0]                      # [block_k, heads x d] native dtype
     v = v_ref[0]
-    d = k.shape[-1]
+    d = k.shape[-1] // heads
     if nope is not None:
-        minus_dlse_ref = corr_ref
         cos_ref, sin_ref, out_ref, dq_ref, dk_ref, dv_ref, kt_ref, dqt_ref, \
             roped_q_ref, corr_ref = refs
     elif roped:
-        cos_ref, sin_ref, dq_ref, dk_ref, dv_ref, kt_ref, dqt_ref, \
-            roped_q_ref = refs
+        out_ref, cos_ref, sin_ref, dq_ref, dk_ref, dv_ref, kt_ref, dqt_ref, \
+            roped_q_ref, corr_ref = refs
     if roped:
         k_rows = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
         q_rows = slice(cos_ref.shape[1] - seq_q, cos_ref.shape[1])
     else:
-        dq_ref, dk_ref, dv_ref, kt_ref, dqt_ref = refs
+        out_ref, dq_ref, dk_ref, dv_ref, kt_ref, dqt_ref, corr_ref = refs
     if nope is not None:
         # the tile's keys, [k_nope | rope(k_pe)], and its values
         k, v = jnp.concatenate(
@@ -742,14 +909,23 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
                 0, k_rows, :]).astype(k.dtype)], axis=1), k[:, nope:]
         d = k.shape[-1]
     elif roped:
-        k = _roped(k, cos_ref[0, k_rows, :],
-                   sin_ref[0, k_rows, :]).astype(k.dtype)
+        k = _roped(k, cos_ref[0, k_rows, :], sin_ref[0, k_rows, :],
+                   heads).astype(k.dtype)
     k_s = _scaled(k, sm_scale) if fold_scale else k
     # k^T for dq, turned once a program into scratch: the steps' matmuls
     # read it as a plain operand (a transpose that feeds the MXU directly,
     # or a transposed-left matmul in the step, fails a check of the
     # compiler at a key tile of 2048).
     kt_ref[...] = k_s.T
+    if heads > 1:
+        # a head's k (the scale with it) and v alone in their lanes, for
+        # its scores and dp; its rows of k^T and of dq's sum
+        ks = [_head_alone(k, j, heads, sm_scale if fold_scale else 1.0)
+              for j in range(heads)]
+        vs = [_head_alone(v, j, heads) for j in range(heads)]
+        head_rows = [slice(j * d, (j + 1) * d) for j in range(heads)]
+    else:
+        ks, vs, head_rows = [k_s], [v], [slice(None)]
     num_q = seq_q // block_q
     query_minus_key = _query_minus_key(block_k, block_q) if causal else None
     # this tile's first key less the queries' first position
@@ -765,61 +941,77 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
     @pl.when(ki == 0)
     def _():
         dqt_ref[...] = jnp.zeros(dqt_ref.shape, dqt_ref.dtype)
+
+        def make_corr(at):
+            # delta - dlse, delta turned so that its rows lie along the
+            # lanes as lse's do
+            do_out = (do_ref[0, at, :].astype(jnp.float32)
+                      * out_ref[0, at, :].astype(jnp.float32)).T
+            e = do_out.shape[0] // heads
+            for j in range(heads):
+                own = do_out if heads == 1 else do_out[j * e:(j + 1) * e]
+                corr_ref[j, :, at] = jnp.sum(
+                    own, axis=0, keepdims=True) + minus_dlse_ref[j, :, at]
+
         if nope is not None:
             def rope_q_and_make_corr(at):
                 roped_q_ref[0, at, :] = _roped_from(
                     nope, q_ref[0, at, :], *table_rows(at, 1.0))
-                # delta - dlse, delta turned so that its rows lie along
-                # the lanes as lse's do
-                do_out = (do_ref[0, at, :].astype(jnp.float32)
-                          * out_ref[0, at, :].astype(jnp.float32))
-                corr_ref[0, :, at] = jnp.sum(
-                    do_out.T, axis=0, keepdims=True) + minus_dlse_ref[0, :, at]
+                make_corr(at)
 
-            _for_row_blocks(rope_q_and_make_corr, seq_q, block_q)
-        elif roped:     # the head's q, roped once for all its key tiles
+            return _for_row_blocks(rope_q_and_make_corr, seq_q, block_q)
+        if roped:       # the heads' q, roped once for all their key tiles
             roped_q_ref[0] = _roped(
-                q_ref[0], cos_ref[0, q_rows, :],
-                sin_ref[0, q_rows, :]).astype(roped_q_ref.dtype)
+                q_ref[0], cos_ref[0, q_rows, :], sin_ref[0, q_rows, :],
+                heads).astype(roped_q_ref.dtype)
+        _for_row_blocks(make_corr, seq_q, block_q)
 
     q_ref = roped_q_ref if roped else q_ref     # what the steps read
 
     def step(start, first, carry, hi: int):
         """The query block at `start` against keys [0, hi) of the tile (the
-        roles turn: the diagonal cuts the tile's END off).  Scores are
-        held [keys, queries], as in the forward."""
-        dk, dv = carry
-        start = pl.multiple_of(start, block_q)
-        rows = pl.ds(start, block_q)
-        q_blk = q_ref[0, rows, :]
-        do_blk = do_ref[0, rows, :]
-        lse_blk = lse_ref[0, 0:1, rows]                     # [1, block_q]
-        corr = corr_ref[0, 0:1, rows]
-        s = _dot(k_s[:hi], q_blk, 1, 1)                     # [hi, block_q]
-        if not fold_scale:
-            s = s * sm_scale
-        p = jnp.exp(s - lse_blk)
-        if causal:
-            p = _keep(_visible(query_minus_key[:hi], first, window), p, 0.0)
-        dv_new = dv[:hi] + _dot(p.astype(do_blk.dtype), do_blk, 1, 0)
-        ds = p * (_dot(v[:hi], do_blk, 1, 1) - corr)        # dp^T = v · do^T
-        if not fold_scale:
-            ds = ds * sm_scale
-        ds = ds.astype(q_blk.dtype)
-        dk_new = dk[:hi] + _dot(ds, q_blk, 1, 0)
-        # dq^T += k^T · ds, [d, block_q] (a step whose block lies outside
-        # the operand reads an inside block and adds exact zeros to it).
-        dqt_ref[:, rows] += _dot(kt_ref[:, :hi], ds, 1, 0)
-        if hi < block_k:
-            dk_new = jnp.concatenate([dk_new, dk[hi:]], axis=0)
-            dv_new = jnp.concatenate([dv_new, dv[hi:]], axis=0)
-        return dk_new, dv_new
+        roles turn: the diagonal cuts the tile's END off), a head after
+        the other.  Scores are held [keys, queries], as in the forward;
+        carry: (dk, dv) of each head in turn."""
+        visible, new = None, []
+        for j, (k_j, v_j, at) in enumerate(zip(ks, vs, head_rows)):
+            dk, dv = carry[2 * j:2 * j + 2]
+            if j == 0:      # one load of the blocks for all their heads
+                start = pl.multiple_of(start, block_q)
+                rows = pl.ds(start, block_q)
+                q_blk = q_ref[0, rows, :]
+                do_blk = do_ref[0, rows, :]
+            lse_blk = lse_ref[j, 0:1, rows]                 # [1, block_q]
+            corr = corr_ref[j, 0:1, rows]
+            s = _dot(k_j[:hi], q_blk, 1, 1)                 # [hi, block_q]
+            if not fold_scale:
+                s = s * sm_scale
+            p = jnp.exp(s - lse_blk)
+            if causal:
+                if visible is None:     # and one mask
+                    visible = _visible(query_minus_key[:hi], first, window)
+                p = _keep(visible, p, 0.0)
+            dv_new = dv[:hi] + _dot(p.astype(do_blk.dtype), do_blk, 1, 0)
+            ds = p * (_dot(v_j[:hi], do_blk, 1, 1) - corr)  # dp^T = v · do^T
+            if not fold_scale:
+                ds = ds * sm_scale
+            ds = ds.astype(q_blk.dtype)
+            dk_new = dk[:hi] + _dot(ds, q_blk, 1, 0)
+            # dq^T += k^T · ds, [d, block_q] (a step whose block lies
+            # outside the operand reads an inside block and adds exact
+            # zeros to it).
+            dqt_ref[at, rows] += _dot(kt_ref[at, :hi], ds, 1, 0)
+            if hi < block_k:
+                dk_new = jnp.concatenate([dk_new, dk[hi:]], axis=0)
+                dv_new = jnp.concatenate([dv_new, dv[hi:]], axis=0)
+            new += [dk_new, dv_new]
+        return tuple(new)
 
     def whole(j, carry):
         return step(j * block_q, tile_min - j * block_q, carry, block_k)
 
-    carry = (jnp.zeros((block_k, d), jnp.float32),
-             jnp.zeros((block_k, v.shape[-1]), jnp.float32))
+    carry = (jnp.zeros((block_k, k.shape[-1]), jnp.float32),
+             jnp.zeros((block_k, v.shape[-1]), jnp.float32)) * heads
     if causal:
         # First query block whose last row sees this tile's first key,
         # then one block for each further `block_q` keys of the tile.
@@ -838,7 +1030,8 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
             jnp.clip(first_blk + narrow, 0, num_q), end, whole, carry)
     else:
         carry = jax.lax.fori_loop(0, num_q, whole, carry)
-    dk, dv = carry
+    # each head's sums are right in its own lanes: all lanes are stored
+    dk, dv = _join_heads(carry[0::2]), _join_heads(carry[1::2])
     if fold_scale:
         dk = dk * sm_scale
     if nope is not None:
@@ -848,7 +1041,7 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
         dk, dv = jnp.concatenate([dk[:, :nope], dv], axis=1), _roped(
             dk[:, nope:], cos_ref[0, k_rows, :], -sin_ref[0, k_rows, :])
     elif roped:
-        dk = _roped(dk, cos_ref[0, k_rows, :], -sin_ref[0, k_rows, :])
+        dk = _roped(dk, cos_ref[0, k_rows, :], -sin_ref[0, k_rows, :], heads)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -863,7 +1056,8 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, corr_ref,
             return _for_row_blocks(turn_dq, seq_q, block_q)
         dq = dqt_ref[...].T
         if roped:
-            dq = _roped(dq, cos_ref[0, q_rows, :], -sin_ref[0, q_rows, :])
+            dq = _roped(dq, cos_ref[0, q_rows, :], -sin_ref[0, q_rows, :],
+                        heads)
         dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
@@ -882,40 +1076,38 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
     bh = b * h
     block_q, block_k = blocks
     parts = _latent_parts(q, k, v)
-    qf = q.transpose(0, 2, 1, 3).reshape(bh, sq, d)
-    full_q = pl.BlockSpec((1, sq, d), lambda g, i, offs: (g, 0, 0))
-    k_tile = pl.BlockSpec((1, block_k, d), lambda g, i, offs: (g, i, 0))
     if parts is None:
-        kf = k.transpose(0, 2, 1, 3).reshape(bh, sk, d)
-        vf = v.transpose(0, 2, 1, 3).reshape(bh, sk, d_v)
-        dof = dout.transpose(0, 2, 1, 3).reshape(bh, sq, d_v)
-        delta = jnp.sum(dof.astype(jnp.float32)
-                        * out.transpose(0, 2, 1, 3).reshape(bh, sq, d_v)
-                        .astype(jnp.float32), axis=-1)      # [bh, sq]
-        # do and v (and dv) in the values' width: the same specs at equal
-        # widths
-        full_do = full_q if d_v == d else pl.BlockSpec(
-            (1, sq, d_v), lambda g, i, offs: (g, 0, 0))
-        v_tile = k_tile if d_v == d else pl.BlockSpec(
-            (1, block_k, d_v), lambda g, i, offs: (g, i, 0))
-        table_width = d
-        grads = [jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                 jax.ShapeDtypeStruct((bh, sk, d_v), v.dtype)]
+        # q, k, v, do and dq, dk, dv where the projections lay and their
+        # gradients read them, [b, s, h x d], `heads` heads a program
+        # (`_flash_fwd`); do and v (and dv) in the values' width.
+        heads = _heads_a_program(d, d_v)
+        programs = h // heads
+        qf, full_q = _head_blocks(q, heads, programs, None)
+        kf, k_tile = _head_blocks(k, heads, programs, block_k)
+        vf, v_tile = _head_blocks(v, heads, programs, block_k)
+        dof, full_do = _head_blocks(dout, heads, programs, None)
+        outf, full_out = _head_blocks(out, heads, programs, None)
+        table_width = heads * d
+        dq_shape = jax.ShapeDtypeStruct(qf.shape, q.dtype)
+        grads = [jax.ShapeDtypeStruct(kf.shape, k.dtype),
+                 jax.ShapeDtypeStruct(vf.shape, v.dtype)]
         grad_specs = [k_tile, v_tile]
     else:
         # Everything but q and dq where XLA lays it: a head's [k_nope | v]
         # and [dk_nope | dv] in the projection's columns, do in the output
         # projection's, the one rotary key's gradient a share a head.
         nope, d_v, table_width = parts
+        heads, programs = 1, h
+        qf = q.transpose(0, 2, 1, 3).reshape(bh, sq, d)
+        full_q = pl.BlockSpec((1, sq, d), lambda g, i, offs: (g, 0, 0))
+        dq_shape = jax.ShapeDtypeStruct((bh, sq, d), q.dtype)
         (kf, vf), (k_tile, v_tile) = _parts_operands(k, v, h, block_k)
-        # delta is made in the kernel and added to the -dlse that goes in:
-        # from do as the output projection's gradient lays it, and out as
-        # the forward gave it (turning back what `_flash_fwd` turned: XLA
+        # do as the output projection's gradient lays it, and out as the
+        # forward gave it (turning back what `_flash_fwd` turned: XLA
         # folds the two, where a reshape of the turned out to [b, s, h x e]
         # is a pass of its own, the tiles of the two not being the same)
         dof = dout.reshape(b, sq, h * d_v)
         outf = out.transpose(0, 2, 1, 3).reshape(bh, sq, d_v)
-        delta = 0.0
         full_do = pl.BlockSpec((1, sq, d_v),
                                lambda g, i, offs: (g // h, 0, g % h))
         full_out = pl.BlockSpec((1, sq, d_v), lambda g, i, offs: (g, 0, 0))
@@ -924,23 +1116,26 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
         grad_specs = [k_tile, pl.BlockSpec((1, block_k, table_width),
                                            lambda g, i, offs: (g, i, 0))]
     lse8 = _lse8(lse, bh, sq)
-    # (delta + (-dlse)) enters every key of a query uniformly: one term.
-    corr8 = _lse8(delta - dlse.astype(jnp.float32), bh, sq)
+    # (delta + (-dlse)) enters every key of a query uniformly, one term:
+    # delta = rowsum(do * out) is made in the kernel and added to this
+    minus_dlse8 = _lse8(0.0 - dlse.astype(jnp.float32), bh, sq)
 
-    seq_spec = pl.BlockSpec((1, 8, sq), lambda g, i, offs: (g, 0, 0))
-    tables, table_specs = _rope_operands(rope, h, sk, table_width)
-    if parts is not None:
+    seq_spec = pl.BlockSpec((heads, 8, sq), lambda g, i, offs: (g, 0, 0))
+    tables, table_specs = _rope_operands(rope, programs, sk, table_width)
+    if parts is None:       # the tables come last, the parts' output
+        tables, table_specs = (outf, *tables), [full_out, *table_specs]
+    else:
         tables, table_specs = (*tables, outf), [*table_specs, full_out]
     # A float32 table block as VMEM holds it (128 lanes): two tables, each
     # double-buffered, the roped q and the float32 dq being roped come to
     # under six of them (58.0 MiB needed at 8192 x 128; at 2048 x 64 the
     # 48 hold).
-    table_mib = -(-sk * max(d, 128) * 4 // 2 ** 20)
-    # What a program holds of a head WHOLE, as VMEM holds it (lanes of
+    table_mib = -(-sk * max(heads * d, 128) * 4 // 2 ** 20)
+    # What a program holds of its heads WHOLE, as VMEM holds it (lanes of
     # 128): q and dq double-buffered, do double-buffered, dq's float32 sum.
     # 16 MiB at 8192 x 128, inside the 48; keys of 192 need 26.
-    lanes, v_lanes = _lanes(d), _lanes(d_v)
-    head_mib = -(-sq * (8 * lanes + 4 * v_lanes + 4 * d) // 2 ** 20)
+    lanes, v_lanes = _lanes(heads * d), _lanes(heads * d_v)
+    head_mib = -(-sq * (8 * lanes + 4 * v_lanes + 4 * heads * d) // 2 ** 20)
     if parts is not None:
         # beside the head's whole rows its roped q, its output (double-
         # buffered) and the two tables' four buffers (lanes of 128): 90
@@ -949,44 +1144,52 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
         vmem_mib = 40 + head_mib + -(-(sq * (2 * lanes + 4 * v_lanes)
                                        + 16 * sk * _lanes(table_width))
                                      // 2 ** 20)
-    elif rope is None:
-        vmem_mib = max(48, 32 + head_mib)
     else:
-        vmem_mib = max(48, 40 + 6 * table_mib)
+        # the heads' output, double-buffered, beside what stood before it
+        # came in: 4 MiB at 8192 x 128
+        out_mib = -(-sq * 4 * v_lanes // 2 ** 20)
+        # and what a program holds a HEAD of as wide as all its lanes:
+        # the float32 sums of dk and dv, twice across a step, and the k
+        # and v with the other heads' lanes zero; beyond the one head of
+        # 128 that always fitted, 6 MiB more for two of 64 at a key tile
+        # of 2048 and 24 for two of 192 (103.7 MiB needed at 8192 rows)
+        sums_mib = -(-heads * block_k * 12 * (lanes + v_lanes) // 2 ** 20) - 6
+        vmem_mib = out_mib + max(sums_mib, 0) + (
+            32 + head_mib if rope is None else 40 + 6 * table_mib)
+        vmem_mib = max(48, vmem_mib)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, causal=causal, block_q=block_q,
                           block_k=block_k, seq_q=sq, sm_scale=sm_scale,
                           fold_scale=_scale_is_exact(sm_scale),
                           **({"windowed": True} if windowed else {}),
                           **({} if rope is None else {"roped": True}),
-                          **({} if parts is None else {"nope": nope})),
+                          **({} if parts is None else {"nope": nope}),
+                          **({} if heads == 1 else {"heads": heads})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, sk // block_k),
+            grid=(b * programs, sk // block_k),
             in_specs=[full_q, k_tile, v_tile, full_do, seq_spec, seq_spec,
                       *table_specs],
             out_specs=[full_q, *grad_specs],
-            scratch_shapes=[pltpu.VMEM((d, block_k), k.dtype),
-                            pltpu.VMEM((d, sq), jnp.float32)]
-            + ([] if rope is None else [pltpu.VMEM((1, sq, d), q.dtype)])
-            + ([] if parts is None else [pltpu.VMEM((1, 8, sq),
-                                                    jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((heads * d, block_k), k.dtype),
+                            pltpu.VMEM((heads * d, sq), jnp.float32)]
+            + ([] if rope is None else [pltpu.VMEM((1, sq, heads * d),
+                                                   q.dtype)])
+            + [pltpu.VMEM((heads, 8, sq), jnp.float32)],
         ),
-        out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype), *grads],
+        out_shape=[dq_shape, *grads],
         compiler_params=_compiler_params(vmem_mib),
         interpret=dispatch.interpret_mode(),
         name="flash_bwd",
-    )(offs, qf, kf, vf, dof, lse8, corr8, *tables)
+    )(offs, qf, kf, vf, dof, lse8, minus_dlse8, *tables)
 
+    if parts is None:
+        return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    # dv here is the rotary key's gradient, a share a head
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    if parts is not None:
-        # dv here is the rotary key's gradient, a share a head
-        return dq, dk.reshape(k.shape), jnp.sum(
-            dv.reshape(b, h, sk, table_width), axis=1,
-            dtype=jnp.float32).astype(v.dtype)
-    dk = dk.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
-    dv = dv.reshape(b, h, sk, d_v).transpose(0, 2, 1, 3)
-    return dq, dk, dv
+    return dq, dk.reshape(k.shape), jnp.sum(
+        dv.reshape(b, h, sk, table_width), axis=1,
+        dtype=jnp.float32).astype(v.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -1037,7 +1240,8 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
                  seq_q: int, seq_k: int, blocks, roped: bool,
-                 widths=None, rope_width: Optional[int] = None) -> None:
+                 widths=None, rope_width: Optional[int] = None,
+                 heads=None) -> None:
     """Say in `dispatch.taken()` what the kernels were built to do: each
     kernel's (block_q x block_k), that dq comes out of the backward's one
     pass and over how many key tiles it is summed there, whether the scale
@@ -1048,7 +1252,10 @@ def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
     `rope_in_kernel` when the kernels rope q and k themselves; `widths`
     (keys', values') where the two differ, as `dqk192,dv128`; `rope_width`
     where the operands are latent attention's parts, whose last columns
-    the kernels rope, as `latent_parts,rope_in_kernel64of192`."""
+    the kernels rope, as `latent_parts,rope_in_kernel64of192`; `heads` =
+    (heads a program works, the keys' width) where the operands are whole
+    q, k, v, taken as their projections lay them, as
+    `operands_bshd,heads2x64`."""
     (fq, fk), (kv_q, kv_k), window = blocks
     static = isinstance(q_off, int) and isinstance(kv_off, int)
     if not causal:
@@ -1080,6 +1287,8 @@ def _record_plan(q_off, kv_off, causal: bool, sm_scale: float,
         plan += ",dqk%d,dv%d" % widths
     if rope_width is not None:
         plan += ",latent_parts,rope_in_kernel%dof%d" % (rope_width, widths[0])
+    if heads is not None:
+        plan += ",operands_bshd,heads%dx%d" % heads
     dispatch.record("flash_attention.plan", plan)
 
 
@@ -1095,18 +1304,31 @@ def _chunk(q, k, v, q_off, kv_off, causal, sm_scale, blocks, window=None,
     blocks = (*blocks, window)
     parts = _latent_parts(q, k, v)
     d, e = q.shape[-1], v.shape[-1] if parts is None else parts[1]
+    heads = _heads_a_program(d, e) if parts is None else 1
     _record_plan(q_off, kv_off, causal, sm_scale, q.shape[1], k.shape[1],
                  blocks, rope is not None and parts is None,
                  None if e == d else (d, e),
-                 None if parts is None else parts[2])
+                 None if parts is None else parts[2],
+                 (heads, d) if parts is None else None)
     if rope is not None:
-        rope = _widen_rope(rope)
+        rope = _widen_rope(rope, heads)
     # Under a window the scalars are [q_off, kv_off, window]: the kernels
     # read the window there, and a windowed call shows in a trace by its
     # first operand, s32[3] (the benchmark's swa reader finds it so).
     offs = jnp.stack([jnp.asarray(x, jnp.int32) for x in
                       (q_off, kv_off) + (() if window is None else (window,))])
-    return _flash_lse(q, k, v, offs, rope, causal, sm_scale, blocks)
+    b, sq, h = q.shape[:3]
+    spare = -h % heads
+    if not spare:
+        return _flash_lse(q, k, v, offs, rope, causal, sm_scale, blocks)
+    # A head count that does not fill its last lane block (25 heads of 64)
+    # goes in with zero heads behind it, whose output and gradients are
+    # dropped: a copy in XLA, and the one layout still.
+    out, lse = _flash_lse(
+        *(jnp.pad(x, ((0, 0), (0, 0), (0, spare), (0, 0))) for x in (q, k, v)),
+        offs, rope, causal, sm_scale, blocks)
+    return out[:, :, :h], lse.reshape(b, h + spare, sq)[:, :h].reshape(
+        b * h, sq)
 
 
 def flash_attention_chunk(q, k, v, q_off, kv_off, causal: bool = True,

@@ -53,8 +53,8 @@ def test_the_tiny_size_has_what_the_cell_has():
     assert [(first, repeats) for _, first, repeats in sm.segments(config)] \
         == [(0, 1), (1, 3), (4, 1)]
     assert config.sliding_window < 128 and config.experts_held == (0, 4)
-    assert config.rotary_width(sm.FULL) == 8 \
-        and config.rotary_width(sm.SLIDING) == 16
+    assert config.rotary_width(sm.FULL) == 32 \
+        and config.rotary_width(sm.SLIDING) == 64
     # the published pattern, whole: a full layer, (3 sliding + 1 full) x 11,
     # 3 sliding; 48 / 72 heads by kind come from the file, not from here
     whole = sm.SwaMoEConfig()
@@ -324,13 +324,13 @@ def test_train_step_carries_the_counts_of_every_expert_segment():
         lambda a, b: bool((a != b).any()), before, after)))
     taken = dispatch.taken()
     plans = list(taken["flash_attention.plan"])
-    assert any(p.endswith(",window32,visited100.0%,rope_in_kernel")
+    assert any(",window32,visited100.0%,rope_in_kernel,operands_bshd," in p
                for p in plans), plans
-    assert any("window" not in p and p.endswith(",rope_in_kernel")
+    assert any("window" not in p and ",rope_in_kernel,operands_bshd," in p
                for p in plans), plans
-    assert any(p.startswith("full_attention:in_kernel8of16_columns_reordered"
+    assert any(p.startswith("full_attention:in_kernel32of64_columns_reordered"
                             "_at_use_identity_tail,sliding_attention:"
-                            "in_kernel16of16") for p in taken["swa_moe.rope"])
+                            "in_kernel64of64") for p in taken["swa_moe.rope"])
 
 
 def test_layout_names_and_count():
@@ -342,12 +342,12 @@ def test_layout_names_and_count():
     assert sorted(params["layers"]) == ["seg00", "seg01", "seg02"]
     seg = {k: v["0"] for k, v in params["layers"].items()}
     assert seg["seg00"]["w_gate"].shape == (1, 64, 128)
-    assert seg["seg00"]["wq"].shape == (1, 64, 4 * 16)
-    assert seg["seg01"]["wq"].shape == (3, 64, 6 * 16)      # by the heads
-    assert seg["seg01"]["wo"].shape == (3, 6 * 16, 64)
+    assert seg["seg00"]["wq"].shape == (1, 64, 4 * 64)
+    assert seg["seg01"]["wq"].shape == (3, 64, 6 * 64)      # by the heads
+    assert seg["seg01"]["wo"].shape == (3, 6 * 64, 64)
     assert seg["seg01"]["wg"].shape == (3, 64, 6)
-    assert seg["seg01"]["wk"].shape == (3, 64, 2 * 16)      # by the KV heads
-    assert seg["seg02"]["wv"].shape == (1, 64, 2 * 16)
+    assert seg["seg01"]["wk"].shape == (3, 64, 2 * 64)      # by the KV heads
+    assert seg["seg02"]["wv"].shape == (1, 64, 2 * 64)
     assert seg["seg02"]["experts_gate"].shape == (1, 4, 64, 32)
     assert seg["seg02"]["router_w"].shape == (1, 64, 16)
     assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)) \

@@ -89,13 +89,17 @@ def test_ring_attention_flash_chunk_path(monkeypatch, causal):
     and gradients must still match the dense reference."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     rng = np.random.default_rng(7)
-    b, s, h, d = 1, 1024, 2, 16  # s_local = 1024/8 = 128 -> flash path
+    # s_local = 512/4 = 128 -> flash path; two heads of 64 are one lane
+    # block, one program (of 16 a program works eight, and interprets so);
+    # a ring of four: the chunks before, on and "from the future" of the
+    # diagonal all occur
+    b, s, h, d = 1, 512, 2, 64
     q = jnp.asarray(rng.normal(size=(b, s, h, d)).astype(np.float32) * 0.3)
     k = jnp.asarray(rng.normal(size=(b, s, h, d)).astype(np.float32) * 0.3)
     v = jnp.asarray(rng.normal(size=(b, s, h, d)).astype(np.float32))
     ref = attention_reference(q, k, v, causal=causal)
 
-    mesh = build_mesh(axes={"seq": 8})
+    mesh = build_mesh(axes={"seq": 4}, devices=jax.devices()[:4])
     with mesh:
         out = jax.jit(lambda q, k, v: ring_attention(
             q, k, v, mesh=mesh, causal=causal))(q, k, v)
