@@ -12,28 +12,58 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.parallel.sharding import with_logical_constraint
 
+# The step program's parts, named once: every model file and the train
+# step put their code under these with `jax.named_scope`, so that the
+# compiled program's `op_name` says which part an instruction belongs to
+# (the backward and remat's second forward keep the name inside
+# `transpose(jvp(..))` / `checkpoint/..`).  A scope is metadata: it moves
+# no fusion.  `device_stats.program_report` hands the names to whoever
+# joins a device trace to the program (`scripts/opsdump.py --parts`, the
+# benchmark's `part_ms.*` readers), and the innermost name decides.
+ATTN_FULL = "attn.full"          # causal attention over the whole triangle:
+ATTN_SLIDING = "attn.sliding"    # .. or a window; the pre-norm, the
+ATTN_CROSS = "attn.cross"        # projections, the kernels, W_o
+ATTN_GATE = "attn.gate"          # a per-head output gate, inside one of them
+MLA_PROJECT = "mla.project"      # latent attention's projections, likewise
+SSM = "ssm"                      # a state-space mixer: projections, conv, scan
+GMU = "gmu"                      # a gated memory unit that is its own layer
+MLP = "mlp"                      # dense and SHARED feed-forward, its pre-norm
+MOE_ROUTE = "moe.route"
+MOE_DISPATCH = "moe.dispatch"
+MOE_EXPERTS = "moe.experts"
+MOE_COMBINE = "moe.combine"
+EMBED = "embed"
+LOSS = "loss"                    # final norm, head, cross-entropy
+OPTIMIZER = "optimizer"          # everything of the step behind the gradient
+SCOPES = (ATTN_FULL, ATTN_SLIDING, ATTN_CROSS, ATTN_GATE, MLA_PROJECT, SSM,
+          GMU, MLP, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE, EMBED,
+          LOSS, OPTIMIZER)
+
 
 def embed_tokens(tok_embed, tokens, dtype):
-    x = tok_embed.astype(dtype)[tokens]
-    return with_logical_constraint(x, ("batch", "seq", "embed"))
+    with jax.named_scope(EMBED):
+        x = tok_embed.astype(dtype)[tokens]
+        return with_logical_constraint(x, ("batch", "seq", "embed"))
 
 
 def tied_logits(x, tok_embed, dtype):
     """Logits of the weight-tied head from normed hidden states (bf16
     operands, fp32 accumulation: the MXU's native mode — an fp32xfp32
     einsum here ran at half rate for ~10% of the model's FLOPs)."""
-    logits = jnp.einsum(
-        "bsh,vh->bsv", x.astype(dtype), tok_embed.astype(dtype),
-        preferred_element_type=jnp.float32)
-    return with_logical_constraint(logits, ("batch", "seq", "vocab"))
+    with jax.named_scope(LOSS):
+        logits = jnp.einsum(
+            "bsh,vh->bsv", x.astype(dtype), tok_embed.astype(dtype),
+            preferred_element_type=jnp.float32)
+        return with_logical_constraint(logits, ("batch", "seq", "vocab"))
 
 
 def swiglu(y, w_gate, w_up, w_down, dtype):
     """(silu(y Wg) * (y Wu)) Wd, no bias."""
-    gate = jax.nn.silu(y @ w_gate.astype(dtype))
-    up = y @ w_up.astype(dtype)
-    ffn = with_logical_constraint(gate * up, ("batch", "seq", "mlp"))
-    return checkpoint_name(ffn @ w_down.astype(dtype), "mlp_out")
+    with jax.named_scope(MLP):
+        gate = jax.nn.silu(y @ w_gate.astype(dtype))
+        up = y @ w_up.astype(dtype)
+        ffn = with_logical_constraint(gate * up, ("batch", "seq", "mlp"))
+        return checkpoint_name(ffn @ w_down.astype(dtype), "mlp_out")
 
 
 # Heads beside each other on the last axis, [b, s, heads x d], is how a
@@ -89,9 +119,10 @@ def scale_heads(x, scale):
 
 
 def masked_mean(nll, mask):
-    if mask is None:
-        return jnp.mean(nll)
-    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
+    with jax.named_scope(LOSS):
+        if mask is None:
+            return jnp.mean(nll)
+        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
 
 
 def _fused_nll_flat(hidden, tok_embed, targets):
@@ -100,8 +131,9 @@ def _fused_nll_flat(hidden, tok_embed, targets):
     from ray_tpu.ops.fused_ce import fused_ce_nll
 
     b, s = targets.shape
-    return fused_ce_nll(hidden.reshape(b * s, -1), tok_embed,
-                        targets.reshape(-1))
+    with jax.named_scope(LOSS):
+        return fused_ce_nll(hidden.reshape(b * s, -1), tok_embed,
+                            targets.reshape(-1))
 
 
 def fused_ce(hidden, tok_embed, targets, mask):
@@ -118,8 +150,10 @@ def logits_ce(logits, targets, mask):
 
 def logits_nll(logits, targets):
     """Per-token next-token NLL [b, s] from fp32 logits [b, s, vocab]."""
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    with jax.named_scope(LOSS):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(logp, targets[..., None],
+                                    axis=-1)[..., 0]
 
 
 def fused_nll(hidden, tok_embed, targets):

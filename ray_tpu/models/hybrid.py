@@ -432,26 +432,34 @@ def _cross_mixer(u, lp, lam_init, shared_kv, c: HybridConfig):
     return _matmul(a, lp["wo"], c) + lp["bo"].astype(c.dtype)
 
 
+# a kind of layer's mixer, with its pre-norm -> the part of the step it is
+_MIXER_SCOPE = {"mamba": common.SSM, "window": common.ATTN_SLIDING,
+                "full": common.ATTN_FULL, "gmu": common.GMU,
+                "cross": common.ATTN_CROSS}
+
+
 def _layer(x, lp, lam_init, memory, shared_kv, *, kind: str,
            c: HybridConfig):
     """One layer -> (x, what it hands on: y for mamba, (k, v) for full,
     else None)."""
-    u = layer_norm(x, lp["ln1_w"], lp["ln1_b"], c.layer_norm_eps)
-    u = with_logical_constraint(u, ("batch", "seq", "embed"))
     handed = None
-    if kind == "mamba":
-        mixed, handed = _mamba_mixer(u, lp, c)
-    elif kind == "window":
-        mixed, _ = _attention_mixer(u, lp, lam_init, c, c.sliding_window)
-    elif kind == "full":
-        mixed, handed = _attention_mixer(u, lp, lam_init, c, None)
-    elif kind == "gmu":
-        mixed = _matmul(memory * jax.nn.silu(_matmul(u, lp["w1"], c)),
-                        lp["w2"], c)
-    else:
-        mixed = _cross_mixer(u, lp, lam_init, shared_kv, c)
+    with jax.named_scope(_MIXER_SCOPE[kind]):
+        u = layer_norm(x, lp["ln1_w"], lp["ln1_b"], c.layer_norm_eps)
+        u = with_logical_constraint(u, ("batch", "seq", "embed"))
+        if kind == "mamba":
+            mixed, handed = _mamba_mixer(u, lp, c)
+        elif kind == "window":
+            mixed, _ = _attention_mixer(u, lp, lam_init, c, c.sliding_window)
+        elif kind == "full":
+            mixed, handed = _attention_mixer(u, lp, lam_init, c, None)
+        elif kind == "gmu":
+            mixed = _matmul(memory * jax.nn.silu(_matmul(u, lp["w1"], c)),
+                            lp["w2"], c)
+        else:
+            mixed = _cross_mixer(u, lp, lam_init, shared_kv, c)
     x = with_logical_constraint(x + mixed, ("batch", "seq", "embed"))
-    y = layer_norm(x, lp["ln2_w"], lp["ln2_b"], c.layer_norm_eps)
+    with jax.named_scope(common.MLP):
+        y = layer_norm(x, lp["ln2_w"], lp["ln2_b"], c.layer_norm_eps)
     x = x + common.swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"], c.dtype)
     return with_logical_constraint(x, ("batch", "seq", "embed")), handed
 
@@ -493,8 +501,9 @@ def forward_hidden(params: Dict[str, Any], tokens, config: HybridConfig):
             return x, None
 
         x, _ = jax.lax.scan(body, x, (seg, lam))
-    return layer_norm(x, params["final_norm_w"], params["final_norm_b"],
-                      c.layer_norm_eps)
+    with jax.named_scope(common.LOSS):
+        return layer_norm(x, params["final_norm_w"], params["final_norm_b"],
+                          c.layer_norm_eps)
 
 
 def forward(params: Dict[str, Any], tokens, config: HybridConfig):

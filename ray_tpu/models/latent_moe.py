@@ -347,14 +347,16 @@ def _pairs_halved(x, width: int, rope: int):
 
 def _attention(u, lp, cos, sin, c: LatentMoEConfig):
     """q, [k_nope | v] and the one rotary key go to the kernels as their
-    projections lay them, un-roped (`latent_flash_attention`)."""
+    projections lay them, un-roped (`latent_flash_attention`).  The caller's
+    scope (`_layer`) names it: the kernels and W_o; the latent projections
+    have their own inside it."""
     from ray_tpu.ops.attention import latent_flash_attention
 
     b, s, _ = u.shape
     heads, nope, rope = (c.num_attention_heads, c.qk_nope_head_dim,
                          c.qk_rope_head_dim)
     rank, dv = c.kv_lora_rank, c.v_head_dim
-    with jax.named_scope("mla.project"):
+    with jax.named_scope(common.MLA_PROJECT):
         wq = _pairs_halved(lp["wq"].astype(c.dtype), nope + rope, rope)
         q = _matmul(u, wq, c).reshape(b, s, heads, nope + rope)
         q = with_logical_constraint(q, ("batch", "seq", "heads", None))
@@ -374,7 +376,7 @@ def _routed_part(flat, router_w, router_bias, w_gate, w_up, w_down,
     flat [T, hidden] -> (the held experts' sum, the routing counts).  The
     usual buffer holds twice the rows even routing sends here; a step that
     sends more takes the full bound's."""
-    with jax.named_scope("moe.route"):
+    with jax.named_scope(common.MOE_ROUTE):
         idx, gates = moe.sigmoid_route(
             flat, router_w, router_bias,
             num_experts_per_token=c.num_experts_per_tok,
@@ -400,11 +402,13 @@ def routed_experts(h, router_w, router_bias, w_gate, w_up, w_down,
 
 def _layer(x, lp, cos, sin, *, kind: str, c: LatentMoEConfig):
     """One layer -> (x, the routing counts of an expert layer or None)."""
-    u = rms_norm(x, lp["ln1_w"], c.rms_norm_eps)
-    u = with_logical_constraint(u, ("batch", "seq", "embed"))
-    x = with_logical_constraint(x + _attention(u, lp, cos, sin, c),
-                                ("batch", "seq", "embed"))
-    y = rms_norm(x, lp["ln2_w"], c.rms_norm_eps)
+    with jax.named_scope(common.ATTN_FULL):
+        u = rms_norm(x, lp["ln1_w"], c.rms_norm_eps)
+        u = with_logical_constraint(u, ("batch", "seq", "embed"))
+        mixed = _attention(u, lp, cos, sin, c)
+    x = with_logical_constraint(x + mixed, ("batch", "seq", "embed"))
+    with jax.named_scope(common.MLP):
+        y = rms_norm(x, lp["ln2_w"], c.rms_norm_eps)
     stats = None
     if kind == "dense":
         ffn = common.swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"],
@@ -431,7 +435,8 @@ def forward_hidden(params: Dict[str, Any], tokens, config: LatentMoEConfig):
     held together, or None without one)."""
     c = config
     x = common.embed_tokens(params["tok_embed"], tokens, c.dtype)
-    cos, sin = rope_tables(tokens.shape[1], c)
+    with jax.named_scope(common.ATTN_FULL):     # the tables are attention's
+        cos, sin = rope_tables(tokens.shape[1], c)
     stats = None
     for si, (kind, _, _) in enumerate(segments(c)):
         fn = _layer_fn(kind, c)
@@ -444,7 +449,8 @@ def forward_hidden(params: Dict[str, Any], tokens, config: LatentMoEConfig):
         if per_layer is not None:
             stats = jax.tree.map(lambda a: a[-1], per_layer)
             stats["rows_held_all_layers"] = jnp.sum(per_layer["rows_held"])
-    return rms_norm(x, params["final_norm_w"], c.rms_norm_eps), stats
+    with jax.named_scope(common.LOSS):
+        return rms_norm(x, params["final_norm_w"], c.rms_norm_eps), stats
 
 
 def forward(params: Dict[str, Any], tokens, config: LatentMoEConfig):

@@ -23,6 +23,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import common
 from ray_tpu.parallel.sharding import with_logical_constraint
 
 
@@ -278,7 +279,7 @@ def routed_experts(x, idx, gates, w_gate, w_up, w_down, *,
     tile_m = tile_m or gm.TILE_M
     bound = tokens * min(k, count)
 
-    with jax.named_scope("moe.dispatch"):
+    with jax.named_scope(common.MOE_DISPATCH):
         local = idx - first
         held = (local >= 0) & (local < count)
         key = jnp.where(held, local, count).reshape(-1)     # [T * k]
@@ -295,7 +296,7 @@ def routed_experts(x, idx, gates, w_gate, w_up, w_down, *,
     def through_the_experts(rows_bound: int):
         """The layer over a buffer that holds `rows_bound` rows."""
         rows = gm.layout_rows(rows_bound, count, tile_m)
-        with jax.named_scope("moe.dispatch"):
+        with jax.named_scope(common.MOE_DISPATCH):
             layout = gm.group_layout(sizes, rows, tile_m)
             pos = (layout.starts[jnp.minimum(key, count - 1)] + rank
                    ).reshape(tokens, k)
@@ -304,12 +305,12 @@ def routed_experts(x, idx, gates, w_gate, w_up, w_down, *,
                 jnp.arange(rows, dtype=jnp.int32) - layout.starts[row_group])
             src = order[jnp.clip(packed, 0, tokens * k - 1)]
             rows_in = _place(x.astype(dtype), src // k, row_valid, pos, held)
-        with jax.named_scope("moe.experts"):
+        with jax.named_scope(common.MOE_EXPERTS):
             gate_h = gm.grouped_matmul(rows_in, w_gate.astype(dtype), layout)
             up_h = gm.grouped_matmul(rows_in, w_up.astype(dtype), layout)
             out = gm.grouped_matmul(jax.nn.silu(gate_h) * up_h,
                                     w_down.astype(dtype), layout)
-        with jax.named_scope("moe.combine"):
+        with jax.named_scope(common.MOE_COMBINE):
             return _combine(out, gates, pos, held, src, row_valid)
 
     rows_held = jnp.sum(sizes)
@@ -317,11 +318,14 @@ def routed_experts(x, idx, gates, w_gate, w_up, w_down, *,
         y = through_the_experts(bound)
     else:
         # each side under its own checkpoint: a cond's backward keeps BOTH
-        # sides' residuals as outputs, and the buffers are the large ones
-        y = jax.lax.cond(
-            rows_held <= usual_rows,
-            jax.checkpoint(lambda: through_the_experts(usual_rows)),
-            jax.checkpoint(lambda: through_the_experts(bound)))
+        # sides' residuals as outputs, and the buffers are the large ones.
+        # The conditional itself (its branch, what it copies in and out)
+        # counts as dispatch: choosing the buffer is placing the rows.
+        with jax.named_scope(common.MOE_DISPATCH):
+            y = jax.lax.cond(
+                rows_held <= usual_rows,
+                jax.checkpoint(lambda: through_the_experts(usual_rows)),
+                jax.checkpoint(lambda: through_the_experts(bound)))
 
     stats = {"rows_held": rows_held, "load_max": jnp.max(sizes),
              "load_mean": jnp.mean(sizes.astype(jnp.float32)),

@@ -434,28 +434,28 @@ def _matmul(x, w, c: SwaMoEConfig, out_dtype=None):
 
 
 def _attention(u, lp, tables, *, kind: str, heads: int, c: SwaMoEConfig):
-    """u [b, s, hidden], the normed input -> the mixer's output."""
+    """u [b, s, hidden], the normed input -> the mixer's output.  The
+    caller's scope (`_layer`) names it: projections, kernels and W_o."""
     from ray_tpu.ops.attention import flash_attention
 
     b, s, _ = u.shape
     kv, d = c.num_key_value_heads, c.head_dim
-    with jax.named_scope("attn.full" if kind == FULL else "attn.sliding"):
-        wq = _rotary_first_halves(lp["wq"].astype(c.dtype), heads, c, kind)
-        wk = _rotary_first_halves(lp["wk"].astype(c.dtype), kv, c, kind)
-        q = with_logical_constraint(_matmul(u, wq, c),
-                                    ("batch", "seq", "heads"))
-        q = q.reshape(b, s, heads, d)
-        # the kernels take expanded heads, query head j reading KV head
-        # j // group, and take them as the projections lay them, [b, s,
-        # heads x d]: the repeat and the gate work on that, by whole tiles
-        # (`common.repeat_heads`), and the [b, s, heads, d] views fold away
-        k, v = (common.repeat_heads(_matmul(u, w, c), kv, heads // kv)
-                .reshape(b, s, heads, d) for w in (wk, lp["wv"]))
-        rope = tuple(jnp.broadcast_to(t, (b, *t.shape)) for t in tables)
-        a = flash_attention(
-            q, k, v, causal=True, sm_scale=1.0 / math.sqrt(d), rope=rope,
-            window=c.sliding_window if kind == SLIDING else None)
-    with jax.named_scope("attn.gate"):
+    wq = _rotary_first_halves(lp["wq"].astype(c.dtype), heads, c, kind)
+    wk = _rotary_first_halves(lp["wk"].astype(c.dtype), kv, c, kind)
+    q = with_logical_constraint(_matmul(u, wq, c),
+                                ("batch", "seq", "heads"))
+    q = q.reshape(b, s, heads, d)
+    # the kernels take expanded heads, query head j reading KV head
+    # j // group, and take them as the projections lay them, [b, s,
+    # heads x d]: the repeat and the gate work on that, by whole tiles
+    # (`common.repeat_heads`), and the [b, s, heads, d] views fold away
+    k, v = (common.repeat_heads(_matmul(u, w, c), kv, heads // kv)
+            .reshape(b, s, heads, d) for w in (wk, lp["wv"]))
+    rope = tuple(jnp.broadcast_to(t, (b, *t.shape)) for t in tables)
+    a = flash_attention(
+        q, k, v, causal=True, sm_scale=1.0 / math.sqrt(d), rope=rope,
+        window=c.sliding_window if kind == SLIDING else None)
+    with jax.named_scope(common.ATTN_GATE):
         gate = jax.nn.sigmoid(_matmul(u, lp["wg"], c, F32))
         a = common.scale_heads(a.reshape(b, s, heads * d), gate)
     return _matmul(a, lp["wo"], c)
@@ -466,7 +466,7 @@ def _routed_part(flat, router_w, w_gate, w_up, w_down, c: SwaMoEConfig):
     flat [T, hidden] -> (the held experts' sum, the routing counts).  The
     usual buffer holds `USUAL_LOAD` times the rows even routing sends here;
     a step that sends more takes the full bound's."""
-    with jax.named_scope("moe.route"):
+    with jax.named_scope(common.MOE_ROUTE):
         idx, gates = moe.softmax_route(
             flat, router_w, num_experts_per_token=c.num_experts_per_tok,
             scale=c.moe_routed_scaling_factor)
@@ -491,12 +491,14 @@ def routed_experts(h, router_w, w_gate, w_up, w_down, config: SwaMoEConfig):
 def _layer(x, lp, tables, *, kind: Tuple[str, int, str], c: SwaMoEConfig):
     """One layer -> (x, the routing counts of an expert layer or None)."""
     attn_kind, heads, ffn_kind = kind
-    u = rms_norm(x, lp["ln1_w"], c.rms_norm_eps)
-    u = with_logical_constraint(u, ("batch", "seq", "embed"))
-    x = with_logical_constraint(
-        x + _attention(u, lp, tables, kind=attn_kind, heads=heads, c=c),
-        ("batch", "seq", "embed"))
-    y = rms_norm(x, lp["ln2_w"], c.rms_norm_eps)
+    with jax.named_scope(common.ATTN_FULL if attn_kind == FULL
+                         else common.ATTN_SLIDING):
+        u = rms_norm(x, lp["ln1_w"], c.rms_norm_eps)
+        u = with_logical_constraint(u, ("batch", "seq", "embed"))
+        mixed = _attention(u, lp, tables, kind=attn_kind, heads=heads, c=c)
+    x = with_logical_constraint(x + mixed, ("batch", "seq", "embed"))
+    with jax.named_scope(common.MLP):
+        y = rms_norm(x, lp["ln2_w"], c.rms_norm_eps)
     stats = None
     if ffn_kind == DENSE:
         ffn = common.swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"],
@@ -523,8 +525,11 @@ def forward_hidden(params: Dict[str, Any], tokens, config: SwaMoEConfig):
     held together, or None without one)."""
     c = config
     x = common.embed_tokens(params["tok_embed"], tokens, c.dtype)
-    tables = {kind: kernel_tables(tokens.shape[1], kind, c)
-              for kind in sorted(set(c.layer_types))}
+    tables = {}
+    for kind in sorted(set(c.layer_types)):     # the tables are attention's
+        with jax.named_scope(common.ATTN_FULL if kind == FULL
+                             else common.ATTN_SLIDING):
+            tables[kind] = kernel_tables(tokens.shape[1], kind, c)
     dispatch.record("swa_moe.rope", ",".join(
         f"{kind}:in_kernel{c.rotary_width(kind)}of{c.head_dim}"
         + ("" if c.rotary_width(kind) == c.head_dim
@@ -544,7 +549,8 @@ def forward_hidden(params: Dict[str, Any], tokens, config: SwaMoEConfig):
             rows_held = rows_held + jnp.sum(per_layer["rows_held"])
     if stats is not None:
         stats["rows_held_all_layers"] = rows_held
-    return rms_norm(x, params["final_norm_w"], c.rms_norm_eps), stats
+    with jax.named_scope(common.LOSS):
+        return rms_norm(x, params["final_norm_w"], c.rms_norm_eps), stats
 
 
 def _nll_and_stats(params, batch, config: SwaMoEConfig):

@@ -254,39 +254,43 @@ def _block(x, bp, rope, mask, config: TransformerConfig):
     hd = c.head_dim_
     b, s, h = x.shape
 
-    y = rms_norm(x, bp["attn_norm"], c.rms_eps)
-    y = with_logical_constraint(y, ("batch", "seq", "embed"))
-    # The heads' constraint goes on the projections' own [b, s, heads x hd]
-    # (whole heads a shard, as on the 4-D view): behind it the reshape to
-    # heads folds against the flash kernels' own back, and q and k reach
-    # them as the matmuls wrote them; on the 4-D view it stood between the
-    # two and XLA laid that view out, sequence-minor, and copied it.
-    q, k, v = (with_logical_constraint(y @ bp[w].astype(c.dtype),
-                                       ("batch", "seq", "heads"))
-               for w in ("wq", "wk", "wv"))
-    q = q.reshape(b, s, c.num_heads, hd)
-    k = k.reshape(b, s, c.num_kv_heads, hd)
-    v = v.reshape(b, s, c.num_kv_heads, hd)
-    if not _ropes_in_flash(c):
-        from ray_tpu.ops.attention import rope_reference
+    with jax.named_scope(common.ATTN_FULL):
+        y = rms_norm(x, bp["attn_norm"], c.rms_eps)
+        y = with_logical_constraint(y, ("batch", "seq", "embed"))
+        # The heads' constraint goes on the projections' own [b, s, heads x hd]
+        # (whole heads a shard, as on the 4-D view): behind it the reshape to
+        # heads folds against the flash kernels' own back, and q and k reach
+        # them as the matmuls wrote them; on the 4-D view it stood between the
+        # two and XLA laid that view out, sequence-minor, and copied it.
+        q, k, v = (with_logical_constraint(y @ bp[w].astype(c.dtype),
+                                           ("batch", "seq", "heads"))
+                   for w in ("wq", "wk", "wv"))
+        q = q.reshape(b, s, c.num_heads, hd)
+        k = k.reshape(b, s, c.num_kv_heads, hd)
+        v = v.reshape(b, s, c.num_kv_heads, hd)
+        if not _ropes_in_flash(c):
+            from ray_tpu.ops.attention import rope_reference
 
-        q, k, rope = rope_reference(q, *rope), rope_reference(k, *rope), None
-    attn = _attention(q, k, v, mask, c, rope)
-    attn = attn.reshape(b, s, c.num_heads * hd)
-    attn_proj = checkpoint_name(
-        attn @ bp["wo"].astype(c.dtype), "attn_proj")
+            q, k = rope_reference(q, *rope), rope_reference(k, *rope)
+            rope = None
+        attn = _attention(q, k, v, mask, c, rope)
+        attn = attn.reshape(b, s, c.num_heads * hd)
+        attn_proj = checkpoint_name(
+            attn @ bp["wo"].astype(c.dtype), "attn_proj")
     x = x + attn_proj
     x = with_logical_constraint(x, ("batch", "seq", "embed"))
 
-    y = rms_norm(x, bp["mlp_norm"], c.rms_eps)
+    with jax.named_scope(common.MLP):
+        y = rms_norm(x, bp["mlp_norm"], c.rms_eps)
     if c.num_experts > 0:
         from ray_tpu.models.moe import moe_ffn
 
-        out2d, aux = moe_ffn(
-            y.reshape(b * s, h), bp["router"], bp["we_gate"],
-            bp["we_up"], bp["we_down"],
-            num_experts_per_token=c.num_experts_per_token,
-            capacity_factor=c.capacity_factor, dtype=c.dtype)
+        with jax.named_scope(common.MOE_EXPERTS):
+            out2d, aux = moe_ffn(
+                y.reshape(b * s, h), bp["router"], bp["we_gate"],
+                bp["we_up"], bp["we_down"],
+                num_experts_per_token=c.num_experts_per_token,
+                capacity_factor=c.capacity_factor, dtype=c.dtype)
         x = x + out2d.reshape(b, s, h)
     else:
         aux = jnp.zeros((), jnp.float32)
@@ -301,7 +305,8 @@ def _embed_tokens(params, tokens, c: TransformerConfig):
 
 def _lm_head(params, x, c: TransformerConfig):
     """Final norm + weight-tied head."""
-    x = rms_norm(x, params["final_norm"], c.rms_eps)
+    with jax.named_scope(common.LOSS):
+        x = rms_norm(x, params["final_norm"], c.rms_eps)
     return common.tied_logits(x, params["tok_embed"], c.dtype)
 
 
@@ -319,12 +324,13 @@ def forward_hidden(params: Dict[str, Any], tokens,
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     x = _embed_tokens(params, tokens, c)
-    cos, sin = rope_freqs(c.head_dim_, c.max_seq_len, c.rope_theta)
+    with jax.named_scope(common.ATTN_FULL):     # the tables are attention's
+        cos, sin = rope_freqs(c.head_dim_, c.max_seq_len, c.rope_theta)
+        rope = (cos[positions], sin[positions])
     mask = jnp.tril(jnp.ones((s, s), dtype=bool))[None, None, :, :]
 
     block_fn = _maybe_remat(
-        partial(_block, rope=(cos[positions], sin[positions]), mask=mask,
-                config=c), c)
+        partial(_block, rope=rope, mask=mask, config=c), c)
 
     aux_total = jnp.zeros((), jnp.float32)
     if c.scan_layers:
@@ -336,7 +342,8 @@ def forward_hidden(params: Dict[str, Any], tokens,
             scan_body, (x, aux_total), params["blocks"])
     else:
         x, aux_total = block_fn(x, params["blocks"])
-    return rms_norm(x, params["final_norm"], c.rms_eps), aux_total
+    with jax.named_scope(common.LOSS):
+        return rms_norm(x, params["final_norm"], c.rms_eps), aux_total
 
 
 def forward(params: Dict[str, Any], tokens, config: TransformerConfig,

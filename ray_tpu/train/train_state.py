@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ray_tpu.models.common import OPTIMIZER
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
     Rules,
@@ -199,20 +200,27 @@ class ShardedTrainStep:
             (loss_val, model_metrics), grads = jax.value_and_grad(
                 lambda p: self._loss_and_metrics(p, batch),
                 has_aux=True)(state["params"])
-        grads = jax.tree.map(
-            jax.lax.with_sharding_constraint, grads, self.param_shardings)
-        updates, opt_state = self.optimizer.update(
-            grads, state["opt_state"], state["params"])
-        if self._not_trained is not None:   # weight decay moves them too
-            updates = jax.tree.map(
-                lambda u, frozen: jnp.zeros_like(u) if frozen else u,
-                updates, self._not_trained)
-        params = optax.apply_updates(state["params"], updates)
-        params = jax.tree.map(
-            jax.lax.with_sharding_constraint, params, self.param_shardings)
+        # everything behind the gradient is one part of the step
+        # (models/common.py's vocabulary): constraint and norm, clip, AdamW,
+        # apply_updates and the parameters' casts
+        with jax.named_scope(OPTIMIZER):
+            grads = jax.tree.map(
+                jax.lax.with_sharding_constraint, grads,
+                self.param_shardings)
+            updates, opt_state = self.optimizer.update(
+                grads, state["opt_state"], state["params"])
+            if self._not_trained is not None:   # weight decay moves them too
+                updates = jax.tree.map(
+                    lambda u, frozen: jnp.zeros_like(u) if frozen else u,
+                    updates, self._not_trained)
+            params = optax.apply_updates(state["params"], updates)
+            params = jax.tree.map(
+                jax.lax.with_sharding_constraint, params,
+                self.param_shardings)
+            grad_norm = optax.global_norm(grads).astype(jnp.float32)
         metrics = {
             "loss": loss_val.astype(jnp.float32),
-            "grad_norm": optax.global_norm(grads).astype(jnp.float32),
+            "grad_norm": grad_norm,
             "step": state["step"] + 1,
             **model_metrics,
         }

@@ -255,14 +255,17 @@ def _write_timeline(group, run_dir: str, since: float
     runtime's, and this attempt's: those begun at or after `since`) merged
     with every worker's (asked once, after the loops have ended), as
     span dicts (`tracing.span_row_to_dict`'s keys) with `worker` and
-    `pid`; every `xla.compile` span apart; each process's `compile_totals`.  Written to
+    `pid`; every `xla.compile` span apart; each process's `compile_totals`;
+    after a traced run, rank 0's `programs` (`TrainWorker.timeline`: that
+    worker compiles the step program's report then, from the cache, which
+    is what the longer wait is for).  Written to
     <run_dir>/timeline.json beside the loggers' result.json and returned
     for `Result.timeline`.  Never fails a finished run."""
     from ray_tpu.train.worker_group import timeline_spans
 
     try:
         parts = ray_tpu.get([w.timeline.remote() for w in group.workers],
-                            timeout=60)
+                            timeout=300)
         spans = [s for s in timeline_spans("driver")
                  if s["start"] >= since
                  or s["name"] in tracing.RUNTIME_STARTUP_SPANS]
@@ -273,6 +276,10 @@ def _write_timeline(group, run_dir: str, since: float
         doc = {"spans": [s for s in spans if s["name"] != "xla.compile"],
                "compiles": [s for s in spans if s["name"] == "xla.compile"],
                "compile_totals": totals}
+        programs = next((p["programs"] for p in parts if "programs" in p),
+                        None)
+        if programs:
+            doc["programs"] = programs
         # Through JSON, so that Result.timeline is what the file holds.
         text = json.dumps(doc, default=str)
         os.makedirs(run_dir, exist_ok=True)

@@ -9,6 +9,7 @@ through a polled queue.
 
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import time
@@ -26,6 +27,11 @@ from ray_tpu.util import device_stats, tracing
 # tracing enabled every step is in the ring.
 TIMELINE_PREFIXES = ("startup.", "train.", "xla.compile")
 TIMELINE_MAX_ROWS = 4096
+# The program a traced run's timeline reports on: `ShardedTrainStep`'s
+# name for its step (`device_stats.count_compiles`).
+STEP_PROGRAM = "train.step"
+
+logger = logging.getLogger(__name__)
 
 
 def timeline_spans(worker: str) -> List[Dict[str, Any]]:
@@ -139,10 +145,25 @@ class TrainWorker:
     def timeline(self) -> Dict[str, Any]:
         """This worker's part of the run's timeline (`JaxTrainer.fit`
         asks once, after the loop has ended): its start-up, train and
-        compile spans, and `device_stats.compile_totals()`."""
-        return {"rank": self.rank,
+        compile spans, and `device_stats.compile_totals()`.  Rank 0 of a
+        TRACED run (tracing enabled here, or a profile ran in this
+        process) adds `programs`: the step program's report, what a
+        device trace's operations are joined to
+        (`device_stats.program_report`).  An untraced run lowers
+        nothing."""
+        part = {"rank": self.rank,
                 "spans": timeline_spans(f"rank{self.rank}"),
                 "compile_totals": device_stats.compile_totals()}
+        if self.rank == 0 and (tracing.is_tracing_enabled()
+                               or tracing.profile_seen()):
+            try:
+                report = device_stats.program_report(STEP_PROGRAM)
+            except Exception:  # noqa: BLE001 — a record of the run, not the run
+                logger.warning("no report of %s", STEP_PROGRAM, exc_info=True)
+                report = None
+            if report is not None:
+                part["programs"] = {STEP_PROGRAM: report}
+        return part
 
     def shutdown(self) -> bool:
         return True

@@ -21,14 +21,23 @@ Design rules:
     listener (``install_compile_listener``) turns every trace,
     lowering, backend-compile and persistent-cache event of the process
     into an ``xla.compile`` span and the ``compile_totals()``.
+  - What a program IS comes from the program: the wrapper
+    ``count_compiles`` returns keeps the abstract signature of its
+    first call, and ``program_report(name)`` lowers and compiles that
+    again (a hit in the persistent cache) for the compiled module's
+    instructions, their scopes and its memory.  Nobody calls it on a
+    hot path; ``TrainWorker.timeline()`` does, after a traced run.
 """
 
+import contextlib
 import logging
 import os
+import re
 import sys
 import threading
 import time
-from typing import Any, Dict, Optional, Tuple
+import weakref
+from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu.core.log_once import warn_once
 
@@ -91,6 +100,7 @@ def reset() -> None:
     global _watermark_bytes, _watermark_fraction, _last_step
     with _lock:
         _compiles.clear()
+        _first_calls.clear()
         _components.clear()
         _totals.update(_ZERO_TOTALS)
         _watermark_bytes = 0
@@ -287,6 +297,9 @@ class _CompileTracked:
         self._fn = fn
         self._name = name
         self._seen_sigs = None  # fallback when _cache_size is absent
+        # (args, kwargs, mesh) of the FIRST call, abstract: what
+        # `program_report` lowers again.  Kept once, never per step.
+        self._signature = None
         self.__wrapped__ = fn
 
     def _cache_size(self) -> int:
@@ -298,6 +311,9 @@ class _CompileTracked:
     def __call__(self, *args, **kwargs):
         if not _enabled:
             return self._fn(*args, **kwargs)
+        if self._signature is None:     # before the call: it may donate
+            self._signature = _abstract_signature(args, kwargs)
+            _first_calls[self._name] = self
         before = self._cache_size()
         if before < 0:
             # No tracing-cache introspection: fall back to tracking
@@ -347,6 +363,154 @@ def recompiles_after_warmup() -> Dict[str, int]:
     with _lock:
         return {k: v["after_warmup"] for k, v in _compiles.items()
                 if v["after_warmup"]}
+
+
+# ---------------------------------------------------------------------------
+# program report: a compiled program's instructions, scopes and memory
+
+# name -> the wrapper of that name whose first call came last
+_first_calls: "weakref.WeakValueDictionary[str, _CompileTracked]" = \
+    weakref.WeakValueDictionary()
+
+
+def _abstract_signature(args: tuple, kwargs: dict):
+    """(args, kwargs, mesh) of a call with every array replaced by its
+    shape, dtype and sharding, and the mesh `jax.sharding.set_mesh` had
+    set around it (None where none was)."""
+    jax = _jax()
+
+    def abstract(a):
+        if isinstance(a, jax.Array):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+        return a
+
+    mesh = jax.sharding.get_mesh()
+    return (jax.tree.map(abstract, args), jax.tree.map(abstract, kwargs),
+            None if mesh.empty else mesh)
+
+
+# Instructions a trace never shows as an operation with time of its own.
+_NO_EVENT = frozenset(("parameter", "constant", "tuple", "get-tuple-element",
+                       "bitcast"))
+_MATMULS = frozenset(("convolution", "dot"))
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_OPCODE = re.compile(r"\s*([\w\-]+)\(")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_TARGET = re.compile(r'custom_call_target="([^"]*)"')
+_FUSED = re.compile(r"\bcalls=%?([\w.\-]+)")
+# what an instruction runs as operations of the same stream
+_BODIES = re.compile(r"\b(?:condition|body|true_computation|"
+                     r"false_computation|to_apply)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+
+
+def _after_type(rest: str) -> str:
+    """An instruction's text behind its result type: a type has no blank
+    outside its brackets (`bf16[8,128]{1,0:T(8,128)(2,1)}`, `(f32[8], ..)`)."""
+    depth = 0
+    for i, ch in enumerate(rest):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        elif ch == " " and depth == 0:
+            return rest[i:]
+    return ""
+
+
+def hlo_instructions(text: str) -> Tuple[str, Dict[str, list]]:
+    """(module name, {instruction: [opcode, op_name, holds_matmul,
+    custom_call_target]}) of a compiled module's text, for the
+    instructions a device trace can show: those of the entry computation
+    and of what `while`, `conditional` and `call` run, not the inside of
+    fusions.  `op_name` is the metadata's (the named scopes are in it),
+    `holds_matmul` whether the instruction is a matmul or a fusion that
+    holds one."""
+    module = text.split(None, 2)[1].rstrip(",") if text.startswith(
+        "HloModule ") else ""
+    comps: Dict[str, List[tuple]] = {}
+    entry, current = None, None
+    for line in text.splitlines():
+        if current is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                current = comps.setdefault(m.group(1), [])
+                if line.startswith("ENTRY "):
+                    entry = m.group(1)
+            continue
+        if line == "}":
+            current = None
+            continue
+        m = _INSTRUCTION.match(line)
+        if m:
+            tail = _after_type(m.group(2))
+            op = _OPCODE.match(tail)
+            current.append((m.group(1), op.group(1) if op else "", tail))
+    matmul_in = {name: any(op in _MATMULS for _, op, _ in insts)
+                 for name, insts in comps.items()}
+    out: Dict[str, list] = {}
+    todo, seen = [entry], set()
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        for name, op, tail in comps[comp]:
+            if op in ("while", "conditional", "call"):
+                todo += _BODIES.findall(tail)
+                for group in _BRANCHES.findall(tail):
+                    todo += [b.strip().lstrip("%") for b in group.split(",")]
+            if op in _NO_EVENT:
+                continue
+            fused = _FUSED.search(tail) if op == "fusion" else None
+            op_name = _OP_NAME.search(tail)
+            target = _TARGET.search(tail)
+            out[name] = [
+                op, op_name.group(1) if op_name else "",
+                op in _MATMULS
+                or bool(fused and matmul_in.get(fused.group(1))),
+                target.group(1) if target else ""]
+    return module, out
+
+
+def program_report(name: str) -> Optional[Dict[str, Any]]:
+    """What the program counted as `name` (``count_compiles``) compiled
+    to: its first call's abstract signature lowered and compiled again,
+    which the persistent cache serves where the run filled it.  ->
+    {"module": the module's name as a trace prints it, "instructions":
+    `hlo_instructions`' table, "memory": `memory_analysis()` in bytes a
+    device (arguments, outputs, temporaries, aliased, code, and `total`,
+    what a chip must hold: arguments + outputs - aliased + temporaries),
+    "bytes_limit": the device's (None where the backend reports none),
+    "seconds": what making the report took}; None for a program that was
+    never called here.  Lowers and compiles: for after a run, not in one."""
+    tracked = _first_calls.get(name)
+    jax = _jax()
+    if tracked is None or jax is None:
+        return None
+    t0 = time.perf_counter()
+    args, kwargs, mesh = tracked._signature
+    with (jax.sharding.set_mesh(mesh) if mesh is not None
+          else contextlib.nullcontext()):
+        compiled = tracked._fn.lower(*args, **kwargs).compile()
+    module, instructions = hlo_instructions(compiled.as_text())
+    m = compiled.memory_analysis()
+    memory = None
+    if m is not None:
+        memory = {"argument_bytes": int(m.argument_size_in_bytes),
+                  "output_bytes": int(m.output_size_in_bytes),
+                  "temp_bytes": int(m.temp_size_in_bytes),
+                  "alias_bytes": int(m.alias_size_in_bytes),
+                  "generated_code_bytes": int(
+                      m.generated_code_size_in_bytes)}
+        memory["total_bytes"] = (
+            memory["argument_bytes"] + memory["output_bytes"]
+            - memory["alias_bytes"] + memory["temp_bytes"])
+    return {"module": module, "instructions": instructions,
+            "memory": memory,
+            "bytes_limit": (memory_stats() or {}).get("bytes_limit"),
+            "seconds": time.perf_counter() - t0}
 
 
 # ---------------------------------------------------------------------------

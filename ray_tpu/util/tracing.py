@@ -120,6 +120,15 @@ def current_span_name() -> Optional[str]:
 
 ANNOTATION_PREFIX = "ray_tpu:"
 _jax_seen = False
+# a `trace_span` of this process has run under a running profile
+_profile_seen = False
+
+
+def profile_seen() -> bool:
+    """True once a span of this process entered a RUNNING profile: what
+    "a profile ran here" means to code that acts after the run (the train
+    worker's program report)."""
+    return _profile_seen
 
 
 def _annotation_cls():
@@ -258,6 +267,7 @@ def trace_span(name: str, attributes: Optional[Dict[str, Any]] = None,
     time the span began where that was before the block (process start,
     a caller's entry).  In a process that has loaded JAX the block also
     runs under a "ray_tpu:<name>" profiler annotation (module docstring)."""
+    global _profile_seen
     ann_cls = _annotation_cls()
     record = _enabled or force
     if not record and ann_cls is None:
@@ -274,7 +284,10 @@ def trace_span(name: str, attributes: Optional[Dict[str, Any]] = None,
     ann = None
     if ann_cls is not None:
         # TraceMe encodes its keyword metadata only while a profile runs.
-        meta = _scalars(attributes) if ann_cls.is_enabled() else {}
+        meta = {}
+        if ann_cls.is_enabled():
+            _profile_seen = True
+            meta = _scalars(attributes)
         ann = ann_cls(ANNOTATION_PREFIX + name,
                       **{**meta, "t_epoch": t0, "span_id": span_id or ""})
         ann.__enter__()

@@ -34,6 +34,17 @@ journal:
     python scripts/opsdump.py --xplane t.xplane.pb \
         --timeline run/timeline.json --out trace.json
     python scripts/opsdump.py --xplane t.xplane.pb --stats  # the offset
+
+`--parts` prints, from the same two files of a TRACED train run, the step
+program's time by the model's parts: every operation the device ran
+inside the step module, joined by instruction name to the
+`programs["train.step"]` report in timeline.json (its `op_name` holds the
+model files' named scopes) and summed by part, with what is left of the
+module as `idle_in_program` and the compiled program's memory
+(benchmark/part_lib.py holds the rules; PERF.md section 5 is made so):
+
+    python scripts/opsdump.py --xplane t.xplane.pb \
+        --timeline run/timeline.json --parts
 """
 
 from __future__ import annotations
@@ -304,6 +315,68 @@ def timeline_events(path: str) -> List[Dict[str, Any]]:
     return events
 
 
+def parts_table(xplane: str, timeline: str, top: int = 8) -> str:
+    """The step program's time by part, as text: ms a step, share of the
+    step module, operations a step, by bucket and under it by scope; the
+    heaviest operations under no scope; the compiled program's memory.
+    `xplane`: an .xplane.pb, or a trace saved by `TraceView.to_json`."""
+    from benchmark import part_lib, trace_reduce
+
+    with open(timeline) as f:
+        report = (json.load(f).get("programs") or {}).get(
+            part_lib.STEP_PROGRAM)
+    if not report:
+        raise SystemExit(f"{timeline} holds no programs[\"train.step\"]: "
+                         "only a traced run writes the program's report")
+    trace = (trace_reduce.from_json(xplane)
+             if xplane.endswith((".json", ".json.gz"))
+             else trace_reduce.load_xplane(xplane))
+    tiled = part_lib.tile(trace, report)
+    if tiled is None:
+        raise SystemExit(f"{xplane} shows no run of {report['module']}")
+    step, scopes = tiled["step_ms"], tiled["by_scope"]
+    lines = [f"{part_lib.STEP_PROGRAM}: module {report['module']}, "
+             f"{tiled['steps']:g} steps a device on "
+             f"{len(trace.device_planes())} devices, {step:.2f} ms a step",
+             f"{'part':<28}{'ms a step':>11}{'share':>9}{'calls':>9}"]
+
+    def row(label, ms, calls=None):
+        lines.append(f"{label:<28}{ms:>11.2f}{100 * ms / step:>8.1f}%"
+                     + ("" if calls is None else f"{calls:>9.0f}"))
+
+    for bucket in part_lib.BUCKETS:
+        if not tiled["calls"][bucket]:
+            continue
+        row(bucket, tiled["parts"][bucket], tiled["calls"][bucket])
+        under = sorted(((sc, v) for (b, sc), v in scopes.items()
+                        if b == bucket and sc), key=lambda kv: -kv[1]["ms"])
+        if len(under) > 1 or (under and under[0][0] != bucket):
+            for sc, v in under:
+                row("  " + sc, v["ms"], v["calls"])
+    row(part_lib.IDLE, tiled[part_lib.IDLE])
+    row("sum", sum(tiled["parts"].values()) + tiled[part_lib.IDLE])
+    lines.append(f"bare copies, transposes and converts (inside the parts): "
+                 f"{tiled['bare_copy_ms']:.2f} ms a step; operations the "
+                 f"report has no row for: {tiled['unjoined_ms']:.2f}")
+    for sig, v in sorted(tiled["unscoped_ops"].items(),
+                         key=lambda kv: -kv[1]["ms"])[:top]:
+        lines.append(f"  unscoped: {v['ms']:8.2f} ms  {v['calls']:5.0f} x  "
+                     f"{sig}")
+    mem, limit = report.get("memory"), report.get("bytes_limit")
+    if mem:
+        gib = 2.0 ** 30
+        lines.append(
+            f"memory a chip: {mem['total_bytes'] / gib:.3f} GiB"
+            + (f" of {limit / gib:.2f} ({100 * mem['total_bytes'] / limit:.1f}"
+               f" %)" if limit else "")
+            + f" = arguments {mem['argument_bytes'] / gib:.3f} + outputs "
+            f"{mem['output_bytes'] / gib:.3f} - aliased "
+            f"{mem['alias_bytes'] / gib:.3f} + temporaries "
+            f"{mem['temp_bytes'] / gib:.3f}; the report took "
+            f"{report.get('seconds', 0.0):.1f} s")
+    return "\n".join(lines)
+
+
 def dump_stats(directory: str) -> Dict[str, Any]:
     out: Dict[str, Any] = {"dir": directory}
     for stream in STREAMS:
@@ -368,7 +441,16 @@ def main(argv=None) -> int:
                          "the spans, on the epoch clock")
     ap.add_argument("--timeline", default="",
                     help="a train run's timeline.json to add")
+    ap.add_argument("--parts", action="store_true",
+                    help="print the step program's time by the model's "
+                         "parts (needs --xplane and --timeline of a "
+                         "traced run) instead of a trace")
     args = ap.parse_args(argv)
+    if args.parts:
+        if not (args.xplane and args.timeline):
+            ap.error("--parts needs --xplane and --timeline")
+        print(parts_table(args.xplane, args.timeline))
+        return 0
     if not (args.dir or args.xplane or args.timeline):
         ap.error("--dir required (or set RAY_TPU_OPS_JOURNAL_DIR), "
                  "unless --xplane or --timeline is given")

@@ -371,6 +371,15 @@ def test_fit_leaves_a_timeline_with_every_phase(fit_result):
         for s in doc["compiles"])
 
 
+def test_an_untraced_fit_writes_no_program_report(fit_result):
+    """`programs` (the step program's report, PR 39) is a traced run's:
+    this fit ran no profile and enabled no tracing, so its worker lowered
+    nothing after the loop and the file has no such key."""
+    assert "programs" not in fit_result.timeline
+    with open(os.path.join(fit_result.path, "timeline.json")) as f:
+        assert "programs" not in json.load(f)
+
+
 def test_timeline_phases_nest_and_tile(fit_result):
     spans = fit_result.timeline["spans"]
     by_id = {s["span_id"]: s for s in spans}

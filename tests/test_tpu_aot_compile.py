@@ -369,6 +369,73 @@ def _on_tpu(monkeypatch, module):
     monkeypatch.setattr(module.dispatch, "interpret_mode", lambda: False)
 
 
+# cell -> its configuration under benchmark/configs/
+STEP_CONFIGS = {"train-hybrid-d8": "phi4-mini-flash-train-d8.json",
+                "train-moe-mla-d6": "kanana-2-30b-a3b-train-d6e16.json",
+                "train-swa-moe-d5": "laguna-s-2.1-train-d5e8.json"}
+
+
+@pytest.fixture(scope="module")
+def step_program(topo):
+    """cell -> (the cell's whole step program as `ShardedTrainStep` jits
+    it, compiled for one chip of the described v5e; what its trace left
+    in `dispatch.taken()`; the configuration's train group).  Compiled
+    when first asked for, once a module: a whole step takes a minute or
+    two, and every test of a cell's step shares the one compile."""
+    import copy
+    import json
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmark.drivers import train_model
+    from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
+
+    built = {}
+
+    def build(cell):
+        path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                            "benchmark", "configs", STEP_CONFIGS[cell])
+        with open(path) as f:
+            doc = json.load(f)
+        tr = doc["train"]
+        config = train_model.build_config(doc["program"], doc["model"], tr)
+        mesh = Mesh(topo.devices[:1], ("fsdp",))
+        whole = NamedSharding(mesh, P())
+        ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
+            warmup_steps=tr["lr_warmup_steps"],
+            total_steps=tr["lr_total_steps"],
+            mu_dtype=jnp.bfloat16, nu_dtype=jnp.bfloat16))
+        key = jax.eval_shape(lambda: jax.random.key(0))
+        with pytest.MonkeyPatch.context() as mp:
+            _on_tpu(mp, attention)      # the one dispatch module of all ops
+            mp.setattr(attention.dispatch, "_taken", {})
+            with jax.sharding.set_mesh(mesh):
+                state = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                   sharding=whole),
+                    jax.eval_shape(ts._init_fn, key))
+                batch = {"tokens": jax.ShapeDtypeStruct(
+                    (tr["batch_rows"], tr["sequence_length"] + 1), jnp.int32,
+                    sharding=whole)}
+                compiled = jax.jit(ts._step_fn, donate_argnums=(0,)).lower(
+                    state, batch).compile()
+            taken = copy.deepcopy(attention.dispatch.taken())
+        return compiled, taken, tr
+
+    def get(cell):
+        if cell not in built:
+            built[cell] = build(cell)
+        return built[cell]
+
+    return get
+
+
+def _chip_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
 def _scan_shapes(one_chip):
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -449,44 +516,12 @@ def test_cell_windowed_flash_compiles_and_is_told_from_the_full_call(
     assert any("window" not in p for p in plans)
 
 
-def test_cell_hybrid_step_program_fits_a_v5e(topo, monkeypatch):
+def test_cell_hybrid_step_program_fits_a_v5e(step_program):
     """The cell's whole step program (eight layers of five kinds, an
     eighth of the vocabulary, 1 x 8192 tokens, full remat, fused CE,
     bfloat16 moments) by AOT memory_analysis: under 15.75 GiB."""
-    import json
-
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from benchmark.drivers import train_model
-    from ray_tpu.ops import selective_scan as ss
-    from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
-
-    _on_tpu(monkeypatch, attention)
-    _on_tpu(monkeypatch, ss)
-    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                        "benchmark", "configs",
-                        "phi4-mini-flash-train-d8.json")
-    doc = json.load(open(path))
-    tr = doc["train"]
-    config = train_model.build_config(doc["program"], doc["model"], tr)
-    mesh = Mesh(topo.devices[:1], ("fsdp",))
-    whole = NamedSharding(mesh, P())
-    ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
-        warmup_steps=tr["lr_warmup_steps"], total_steps=tr["lr_total_steps"],
-        mu_dtype=jnp.bfloat16, nu_dtype=jnp.bfloat16))
-    key = jax.eval_shape(lambda: jax.random.key(0))
-    with jax.sharding.set_mesh(mesh):
-        state = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=whole),
-            jax.eval_shape(ts._init_fn, key))
-        batch = {"tokens": jax.ShapeDtypeStruct(
-            (tr["batch_rows"], tr["sequence_length"] + 1), jnp.int32,
-            sharding=whole)}
-        compiled = jax.jit(ts._step_fn, donate_argnums=(0,)).lower(
-            state, batch).compile()
-    m = compiled.memory_analysis()
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    compiled, _, _ = step_program("train-hybrid-d8")
+    total = _chip_bytes(compiled)
     assert 13.0 * 2 ** 30 < total < 15.75 * 2 ** 30, total / 2 ** 30
     text = compiled.as_text()
     # The two (mamba, window) pairs are ONE scanned body: a scan layer is
@@ -911,47 +946,14 @@ def test_cell_grouped_matmul_kernels_compile_and_keep_their_faces(
         "tile256x2048,rows102400,groups16", "tile256x768,rows102400,groups16"]
 
 
-def test_cell_latent_moe_step_program_fits_a_v5e(topo, monkeypatch):
+def test_cell_latent_moe_step_program_fits_a_v5e(step_program):
     """The cell's whole step program (a dense and five expert layers, 16 of
     128 experts, an eighth of the vocabulary, 2 x 8192 tokens, full remat,
     fused CE, bfloat16 moments) by AOT memory_analysis: under 15.75 GiB at
     the configuration's rows."""
-    import json
-
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from benchmark.drivers import train_model
-    from ray_tpu.ops import grouped_matmul as gm
-    from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
-
-    _on_tpu(monkeypatch, attention)
-    _on_tpu(monkeypatch, gm)
-    monkeypatch.setattr(attention.dispatch, "_taken", {})
-    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                        "benchmark", "configs",
-                        "kanana-2-30b-a3b-train-d6e16.json")
-    doc = json.load(open(path))
-    tr = doc["train"]
+    compiled, taken, tr = step_program("train-moe-mla-d6")
     assert tr["batch_rows"] == MOE_ROWS and tr["sequence_length"] == MOE_SEQ
-    config = train_model.build_config(doc["program"], doc["model"], tr)
-    mesh = Mesh(topo.devices[:1], ("fsdp",))
-    whole = NamedSharding(mesh, P())
-    ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
-        warmup_steps=tr["lr_warmup_steps"], total_steps=tr["lr_total_steps"],
-        mu_dtype=jnp.bfloat16, nu_dtype=jnp.bfloat16))
-    key = jax.eval_shape(lambda: jax.random.key(0))
-    with jax.sharding.set_mesh(mesh):
-        state = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=whole),
-            jax.eval_shape(ts._init_fn, key))
-        batch = {"tokens": jax.ShapeDtypeStruct(
-            (tr["batch_rows"], tr["sequence_length"] + 1), jnp.int32,
-            sharding=whole)}
-        compiled = jax.jit(ts._step_fn, donate_argnums=(0,)).lower(
-            state, batch).compile()
-    m = compiled.memory_analysis()
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    total = _chip_bytes(compiled)
     assert 13.0 * 2 ** 30 < total < 15.75 * 2 ** 30, total / 2 ** 30
     # The dense layer: flash forward, forward again under remat, backward
     # (3).  The five expert layers are ONE scanned body: those three and
@@ -961,7 +963,7 @@ def test_cell_latent_moe_step_program_fits_a_v5e(topo, monkeypatch):
     assert compiled.as_text().count("tpu_custom_call") == 3 + 3 + 2 * 12
     # and the attention calls are the ones that take latent attention's parts
     assert all(p.endswith(",dqk192,dv128,latent_parts,rope_in_kernel64of192")
-               for p in attention.dispatch.taken()["flash_attention.plan"])
+               for p in taken["flash_attention.plan"])
 
 
 # ---------------------------------------------------------------------------
@@ -1074,53 +1076,19 @@ def test_cell_swa_moe_grouped_matmul_kernels_keep_their_faces(
             assert kinds(calls) == ["dw", "transposed"], calls
 
 
-def test_cell_swa_moe_step_program_fits_a_v5e(topo, monkeypatch):
+def test_cell_swa_moe_step_program_fits_a_v5e(step_program):
     """The cell's whole step program (a full + dense layer, three sliding
     and one full expert layer, 8 of 256 experts, an eighth of the
     vocabulary, 1 x 8192 tokens, full remat, fused CE, bfloat16 moments) by
     AOT memory_analysis: under 15.75 GiB at the configuration's rows."""
-    import json
-
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from benchmark.drivers import train_model
-    from ray_tpu.ops import grouped_matmul as gm
-    from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
-
-    _on_tpu(monkeypatch, attention)
-    _on_tpu(monkeypatch, gm)
-    monkeypatch.setattr(attention.dispatch, "_taken", {})
-    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                        "benchmark", "configs",
-                        "laguna-s-2.1-train-d5e8.json")
-    doc = json.load(open(path))
-    tr = doc["train"]
+    compiled, taken, tr = step_program("train-swa-moe-d5")
     assert tr["batch_rows"] == 1 and tr["sequence_length"] == SWA_SEQ
-    config = train_model.build_config(doc["program"], doc["model"], tr)
-    mesh = Mesh(topo.devices[:1], ("fsdp",))
-    whole = NamedSharding(mesh, P())
-    ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
-        warmup_steps=tr["lr_warmup_steps"], total_steps=tr["lr_total_steps"],
-        mu_dtype=jnp.bfloat16, nu_dtype=jnp.bfloat16))
-    key = jax.eval_shape(lambda: jax.random.key(0))
-    with jax.sharding.set_mesh(mesh):
-        state = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=whole),
-            jax.eval_shape(ts._init_fn, key))
-        batch = {"tokens": jax.ShapeDtypeStruct(
-            (tr["batch_rows"], tr["sequence_length"] + 1), jnp.int32,
-            sharding=whole)}
-        compiled = jax.jit(ts._step_fn, donate_argnums=(0,)).lower(
-            state, batch).compile()
-    m = compiled.memory_analysis()
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    total = _chip_bytes(compiled)
     assert 13.0 * 2 ** 30 < total < 15.75 * 2 ** 30, total / 2 ** 30
     # Three segments, each flash forward, forward again under remat and
     # backward (3); the two with experts also the grouped kernels, twelve
     # at each of the layer's two buffer sizes (a cond's two sides).
     assert compiled.as_text().count("tpu_custom_call") == 3 * 3 + 2 * 2 * 12
-    taken = attention.dispatch.taken()
     assert sorted(p.split(",dead")[1] for p in
                   taken["flash_attention.plan"]) == [
         "50/50%,window512,visited12.1%,rope_in_kernel,operands_bshd,"
@@ -1128,3 +1096,122 @@ def test_cell_swa_moe_step_program_fits_a_v5e(topo, monkeypatch):
     assert list(taken["swa_moe.rope"]) == [
         "full_attention:in_kernel64of128_columns_reordered_at_use_identity_"
         "tail,sliding_attention:in_kernel128of128"]
+
+
+# ---------------------------------------------------------------------------
+# The scope vocabulary (PR 39, models/common.py): the model's parts named
+# in the compiled program's metadata, and in nothing else of it
+# ---------------------------------------------------------------------------
+
+# sha256 of each program's optimised HLO, `_metadata_stripped`, as the
+# tree BEFORE the scopes compiled it (PR 38's, 9d83a62: this file's
+# helpers run on an archive of that commit).  A scope is metadata: it may move no fusion, no schedule
+# and no byte of a kernel.  A change that means to move the program
+# replaces its digest here and says so.
+PARENT_HLO_SHA256 = {
+    "train-hybrid-d8":
+        "3d480d458ec2cf6d978d269f5cdda6a3c7f2dcedd415d84a8be116758340a32b",
+    "train-moe-mla-d6":
+        "50d1412f6e37aec18ba66b5bff087340d6e5a6177015296695b19afb2efbc0c9",
+    "train-swa-moe-d5":
+        "f8c28ef818489e042a75400d908dc21307792cd49e8e9194b6a0841f8c6e7aa4",
+    "dense-layer.one_chip":
+        "f8ed670122d29fe6c37a4e2abeab135595d735282e71449a49a0e737920e6abf",
+    "dense-layer.fsdp4":
+        "18846b18d5b9aa4f38d225887a3735f29dea7670610c009b067599dd2824fbd8",
+}
+
+
+def _kernel_without_locations(body: str) -> str:
+    """sha256 of a Mosaic kernel (a custom call's `body`: base64 of MLIR
+    bytecode) printed without its debug locations, which hold the CALL
+    SITE's file, function and line in the model files."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+            enable_debug_info=False)
+    return hashlib.sha256(asm.encode()).hexdigest()
+
+
+def _metadata_stripped(text: str) -> str:
+    """A compiled module's text less what names its source: every
+    instruction's `metadata={...}`, the tables of files, functions,
+    locations and stack frames between the header and the first
+    computation, the locations inside each Mosaic kernel, and the
+    instructions' own names."""
+    import re
+
+    lines = text.splitlines()
+    if "FileNames" in lines:
+        first = lines.index("FileNames")
+        del lines[first:next(i for i in range(first, len(lines))
+                             if lines[i].startswith(("%", "ENTRY ")))]
+    text = re.sub(r',? ?metadata=\{(?:[^{}"]|"(?:[^"\\]|\\.)*")*\}', "",
+                  "\n".join(lines))
+    text = re.sub(
+        r'"body":"([A-Za-z0-9+/=]+)"',
+        lambda m: f'"body":"{_kernel_without_locations(m.group(1))}"', text)
+    # An instruction's NAME comes from its source too (`%jit__scan_fwd_.26`
+    # is the call's, the number whatever made the name unique): each name
+    # becomes its rank by first appearance, which keeps who feeds whom.
+    rank = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: rank.setdefault(m.group(0), f"%{len(rank)}"),
+                  text)
+
+
+def _program_text(program, step_program, topo, monkeypatch) -> str:
+    if program in STEP_CONFIGS:
+        return step_program(program)[0].as_text()
+    return _dense_layer_program(topo, monkeypatch,
+                                program.split(".", 1)[1])[0]
+
+
+@pytest.mark.parametrize("program", sorted(PARENT_HLO_SHA256))
+def test_the_scopes_left_the_optimised_hlo_as_the_parent_compiled_it(
+        program, step_program, topo, monkeypatch):
+    import hashlib
+
+    text = _metadata_stripped(
+        _program_text(program, step_program, topo, monkeypatch))
+    assert "op_name" not in text and "source_file" not in text \
+        and ".py" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == PARENT_HLO_SHA256[program]
+
+
+@pytest.mark.parametrize("program", sorted(PARENT_HLO_SHA256))
+def test_every_matmul_and_every_kernel_carries_a_scope_of_the_vocabulary(
+        program, step_program, topo, monkeypatch):
+    """What the `part_ms.*` readers rest on: in the compiled program every
+    instruction a trace can show that is a Pallas kernel or holds a matmul
+    (a fusion's root gives it its `op_name`) names one of
+    `models/common.py`'s scopes, forward, remat's second forward and
+    backward alike."""
+    import re
+
+    from ray_tpu.models import common
+    from ray_tpu.util.device_stats import hlo_instructions
+
+    scope = re.compile(r"(?<![\w.])(" + "|".join(
+        re.escape(s) for s in common.SCOPES) + r")(?![\w.])")
+    module, rows = hlo_instructions(
+        _program_text(program, step_program, topo, monkeypatch))
+    assert module.startswith("jit_")
+    heavy = {name: row for name, row in rows.items()
+             if row[2] or row[3] == "tpu_custom_call"}
+    assert len(heavy) >= 10, sorted(heavy)
+    bare = {name: row[1] for name, row in heavy.items()
+            if not scope.search(row[1])}
+    assert not bare, bare
+    if program in STEP_CONFIGS:     # the whole step: both ends and the rest
+        found = {s for row in rows.values() for s in scope.findall(row[1])}
+        assert {common.EMBED, common.LOSS, common.OPTIMIZER,
+                common.MLP} <= found, found
