@@ -23,6 +23,7 @@ import argparse
 import http.client
 import json
 import os
+import re
 import signal
 import sys
 import threading
@@ -317,6 +318,13 @@ def train_loop(cfg):
             t0 = time.perf_counter()
             state, metrics = ts.step(state, {"tokens": jnp.asarray(tokens)})
             loss = float(metrics["loss"])
+            calls = None
+            if step == cfg["steps"] - 1:    # the program that ran, once
+                rows = device_stats.program_report("train.step")[
+                    "instructions"].values()
+                calls = [sum(r[3] == "tpu_custom_call" and f"/{k}/" in r[1]
+                             for r in rows)
+                         for k in ("flash_fwd", "flash_bwd")]
             train.report({
                 "mesh": n, "step": step, "loss": loss,
                 "grad_norm": float(metrics["grad_norm"]),
@@ -326,6 +334,7 @@ def train_loop(cfg):
                 "count": len(all_devices),
                 "placement": placement,
                 "kernels": dispatch.taken(),
+                "flash_calls": calls,
                 "hbm_peak_bytes": int((device_stats.memory_stats() or {})
                                       .get("peak_bytes_in_use", 0)),
             })
@@ -395,6 +404,23 @@ def train_phase(args, rehearse: bool, meshes: list) -> dict:
         check(last["platform"] == "tpu",
               f"the train worker runs on {last['platform']!r}, not the TPU")
         check_kernels(last["kernels"], ("flash_attention",))
+        # What each mesh's first step decided of remat ("full": what fits
+        # is kept), in the order of the meshes, and the flash calls of the
+        # program that ran: one that had room for the kept out and lse
+        # runs each layer's forward kernel once, not twice.
+        records = [r for r, times in last["kernels"]["train.remat"].items()
+                   for _ in range(times)]
+        say("train.remat", records=records,
+            flash_calls={n: by_mesh[n][-1]["flash_calls"] for n in meshes})
+        for n, record in zip(meshes, records):
+            # a program the compiler refused gives no bytes to read: no room
+            read = re.search(r"program(\d+)of(\d+),beside(\d+)$", record)
+            room = bool(read) and (
+                int(read[1]) + int(read[3]) <= int(read[2]))
+            fwd, bwd = by_mesh[n][-1]["flash_calls"]
+            check(fwd == (bwd if room else 2 * bwd) > 0,
+                  f"mesh {n}: {record}, yet {fwd} forward flash calls "
+                  f"for {bwd} backward")
     return {"platform": last["platform"], "kind": last["kind"],
             "count": last["count"]}
 

@@ -162,7 +162,18 @@ def fused_nll(hidden, tok_embed, targets):
     return _fused_nll_flat(hidden, tok_embed, targets).reshape(targets.shape)
 
 
+# what "save_attn" keeps of a layer: the flash kernels' outputs, named in
+# ops/attention._flash_fwd as the kernel wrote them
+SAVE_ATTN_NAMES = ("attn_out", "attn_lse")
+
+
 def maybe_remat(block_fn, remat: bool, remat_policy: str):
+    """`block_fn` under `jax.checkpoint`, keeping across it what the policy
+    names: "full" nothing but the layer's input, "save_attn" also the
+    flash kernels' out and lse, "dots" / "dots_no_mlp" matmul outputs.
+    `ShardedTrainStep` reads "full" as "keep what fits": it builds the
+    step under "save_attn" first and falls back to "full" where the
+    compiled program does not fit the device (train/train_state.py)."""
     if not remat:
         return block_fn
     if remat_policy == "dots":
@@ -172,13 +183,13 @@ def maybe_remat(block_fn, remat: bool, remat_policy: str):
     if remat_policy == "save_attn":
         # Middle ground between "full" (recompute everything, min HBM)
         # and "dots" (save every matmul, OOMs at billion scale): keep
-        # only the flash kernel's outputs (out + lse, named in
-        # ops/attention.py _flash_lse_fwd) so the backward re-derives
-        # the cheap projections but never re-runs the attention kernel.
+        # only the flash kernel's outputs (out + lse) so the backward
+        # re-derives the cheap projections but never re-runs the
+        # attention kernel.
         return jax.checkpoint(
             block_fn,
             policy=jax.checkpoint_policies.save_only_these_names(
-                "attn_out", "attn_lse"))
+                *SAVE_ATTN_NAMES))
     if remat_policy == "dots_no_mlp":
         # "dots" minus its biggest buffers: save every matmul output
         # EXCEPT the gate/up MLP intermediates ([b, s, intermediate] —

@@ -79,6 +79,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops import dispatch
 
@@ -817,11 +818,18 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
         interpret=dispatch.interpret_mode(),
         name="flash_fwd",
     )(offs, qf, *kv_operands, *tables)
+    # Named as the kernel wrote them, before the views below: what a
+    # layer's remat keeps under save_only_these_names("attn_out",
+    # "attn_lse") (models/common.maybe_remat) is then these two arrays,
+    # and its backward holds no forward kernel.  A [b, s, h, 64] view kept
+    # in their place would lie in half-filled lane blocks, twice the bytes.
+    out = checkpoint_name(out, "attn_out")
+    lse = checkpoint_name(lse[:, 0, :], "attn_lse")     # [bh, sq]
     if parts is None:
         out = out.reshape(b, sq, h, d_v)
     else:
         out = out.reshape(b, h, sq, d_v).transpose(0, 2, 1, 3)
-    return out, lse[:, 0, :]  # lse: [bh, sq]
+    return out, lse
 
 
 # ---------------------------------------------------------------------------
@@ -1206,19 +1214,13 @@ def _flash_lse(q, k, v, offs, rope, causal, sm_scale, blocks):
 def _flash_lse_fwd(q, k, v, offs, rope, causal, sm_scale, blocks):
     out, lse = _flash_fwd(q, k, v, offs, causal, sm_scale, *blocks[0], None,
                           blocks[2] is not None, rope)
-    # Named residuals: under jax.checkpoint with
-    # save_only_these_names("attn_out", "attn_lse") (the transformer's
-    # "save_attn" remat policy) the kernel outputs are kept from the
-    # primal pass, so the backward never re-runs the forward kernel —
-    # q/k/v residuals are cheap projections the remat re-derives.
-    from jax.ad_checkpoint import checkpoint_name
-
+    # Named residuals, for a remat policy that keeps them ("dots_no_mlp");
+    # out and lse carry their names from `_flash_fwd`, and are the primal
+    # outputs too: W_o's gradient reads the kept out, not a second forward.
     q_r = checkpoint_name(q, "attn_q")
     k_r = checkpoint_name(k, "attn_k")
     v_r = checkpoint_name(v, "attn_v")
-    out_r = checkpoint_name(out, "attn_out")
-    lse_r = checkpoint_name(lse, "attn_lse")
-    return (out, lse), (q_r, k_r, v_r, out_r, lse_r, offs, rope)
+    return (out, lse), (q_r, k_r, v_r, out, lse, offs, rope)
 
 
 def _flash_lse_bwd(causal, sm_scale, blocks, res, cts):

@@ -20,15 +20,18 @@ True where a step must leave the leaf as it is.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import importlib
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 
-from ray_tpu.models.common import OPTIMIZER
+from ray_tpu.models.common import OPTIMIZER, SAVE_ATTN_NAMES
+from ray_tpu.ops import dispatch
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
     Rules,
@@ -108,6 +111,31 @@ class ShardedTrainStep:
         ts = ShardedTrainStep(config, mesh)
         state = ts.init(jax.random.key(0))
         state, metrics = ts.step(state, batch)   # batch: {"tokens": [b, s+1]}
+
+    What a layer's remat keeps under `remat_policy="full"` is decided here,
+    by what fits (the other policies do as `models/common.maybe_remat`
+    says).  The first `step` lowers and compiles the program that keeps
+    each layer's flash out and lse (`SAVE_ATTN_NAMES`: the backward runs no
+    attention forward a second time) and reads its `memory_analysis()`,
+    arguments + outputs - aliased + temporaries, against the device's
+    `bytes_limit` less what the device holds beside the program's own
+    arguments (`bytes_in_use` at that moment, the state and the batch taken
+    off).  That program is the step if it compiles and fits; where the
+    compiler refuses it (RESOURCE_EXHAUSTED) or it does not fit, the step
+    is the bare `jax.checkpoint` program.  The reading is the least limit
+    and the most in use over this process's devices of the mesh, and the
+    decision is ONE for the mesh's processes: all keep, or none does.  A
+    backend with no `bytes_limit` (the CPU) takes the first program:
+    nothing there can refuse it.  A caller who holds other arrays on the
+    device (a reference copy, an EMA) puts them there BEFORE the first
+    step, so that the decision sees them: what comes later has the room
+    the chosen program leaves.  A `loss_fn` of the caller's is not the
+    model's to rebuild: it runs as it is given.  Nothing is remembered between
+    starts: a first program that compiled is found in the persistent
+    compilation cache, one the compiler refuses is refused again.  What was
+    decided is in `dispatch.taken()["train.remat"]` and on the first
+    `train.step` span; `device_stats.program_report("train.step")` reports
+    the program that runs.
     """
 
     def __init__(self, config, mesh,
@@ -128,20 +156,18 @@ class ShardedTrainStep:
         # layer dim so each device already holds its stage's run.
         self.num_stages = int(dict(mesh.shape).get("stage", 1))
         self.num_microbatches = num_microbatches
-        if loss_fn is not None:
-            self.loss_fn = loss_fn
-        elif self.num_stages > 1:
-            self.loss_fn = lambda p, b: model.loss_fn_pipelined(
-                p, b, config, self.num_stages, self.num_microbatches,
-                mesh=mesh)
-        else:
-            self.loss_fn = lambda p, b: model.loss_fn(p, b, config)
-        # the model's own step metrics, where it has some and the loss is its
-        self._loss_and_metrics = None
-        if loss_fn is None and self.num_stages == 1 \
-                and hasattr(model, "loss_and_metrics"):
-            self._loss_and_metrics = lambda p, b: model.loss_and_metrics(
-                p, b, config)
+        # the model's own step metrics ride where it has some and the
+        # loss is its
+        self.loss_fn, self._loss_and_metrics = (
+            (loss_fn, None) if loss_fn is not None
+            else self._model_losses(config))
+        # the ladder's first rung, where the ladder applies; which rung
+        # runs (None: the first step decides)
+        self._kept_config = None
+        if loss_fn is None and config.remat and config.remat_policy == "full":
+            self._kept_config = dataclasses.replace(
+                config, remat_policy="save_attn")
+        self._keep: Optional[bool] = None if self._kept_config else False
         self._not_trained = (model.not_trained(config)
                              if hasattr(model, "not_trained") else None)
         self.param_logical = model.logical_axes(config)
@@ -153,9 +179,22 @@ class ShardedTrainStep:
         self._init = device_stats.count_compiles(
             jax.jit(self._init_fn), "train.init")
         self._step = device_stats.count_compiles(
-            jax.jit(self._step_fn, donate_argnums=(0,)), "train.step")
+            jax.jit(self._step_fn, donate_argnums=(0,),
+                    static_argnames=("keep",)), "train.step")
         self._spanned: set = set()
         self._steps = 0
+
+    def _model_losses(self, config):
+        """(loss, loss with the model's own metrics or None) of the model
+        under `config`, each a function of (params, batch)."""
+        model = self.model
+        if self.num_stages > 1:
+            return (lambda p, b: model.loss_fn_pipelined(
+                p, b, config, self.num_stages, self.num_microbatches,
+                mesh=self.mesh)), None
+        return (lambda p, b: model.loss_fn(p, b, config)), (
+            (lambda p, b: model.loss_and_metrics(p, b, config))
+            if hasattr(model, "loss_and_metrics") else None)
 
     def _span(self, name: str, attrs: Optional[Dict[str, Any]] = None,
               force: bool = False):
@@ -191,14 +230,20 @@ class ShardedTrainStep:
             return self._init(rng)
 
     # -- step ---------------------------------------------------------------
-    def _step_fn(self, state, batch):
+    def _step_fn(self, state, batch, keep: bool = False):
+        """keep: the ladder's first rung, each layer's flash out and lse
+        held across its checkpoint (static: one jitted function, two
+        programs)."""
+        loss_fn, loss_and_metrics = (
+            self._model_losses(self._kept_config) if keep
+            else (self.loss_fn, self._loss_and_metrics))
         model_metrics = {}
-        if self._loss_and_metrics is None:
+        if loss_and_metrics is None:
             loss_val, grads = jax.value_and_grad(
-                lambda p: self.loss_fn(p, batch))(state["params"])
+                lambda p: loss_fn(p, batch))(state["params"])
         else:
             (loss_val, model_metrics), grads = jax.value_and_grad(
-                lambda p: self._loss_and_metrics(p, batch),
+                lambda p: loss_and_metrics(p, batch),
                 has_aux=True)(state["params"])
         # everything behind the gradient is one part of the step
         # (models/common.py's vocabulary): constraint and norm, clip, AdamW,
@@ -227,6 +272,56 @@ class ShardedTrainStep:
         return {"params": params, "opt_state": opt_state,
                 "step": state["step"] + 1}, metrics
 
+    def _choose_rung(self, state, batch, attrs: Dict[str, Any]) -> bool:
+        """Whether the step keeps out and lse (the class docstring's ladder),
+        decided inside the first step's span, whose attributes get the
+        record.  The compile made here is the one the call that follows
+        finds, so a first rung that fits costs the one compile, or the one
+        load from the persistent cache, that a first step always cost."""
+        # of this process's devices of the mesh: the least limit, and the
+        # most in use now, before the program is loaded
+        stats = [s for s in map(device_stats.memory_stats,
+                                self.mesh.local_devices) if s]
+        limit = min((s["bytes_limit"] for s in stats if "bytes_limit" in s),
+                    default=None)
+        in_use = max((s.get("bytes_in_use", 0) for s in stats), default=0)
+        total = beside = None
+        try:
+            memory = self._step.lower(
+                state, batch, keep=True).compile().memory_analysis()
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            keep = False
+        else:
+            total = device_stats.program_bytes(memory)
+            if total is not None:
+                # what the chip holds beside the program's own arguments
+                # (the state and the batch are on it already)
+                beside = max(0, in_use - int(memory.argument_size_in_bytes))
+            keep = limit is None or total is None or total + beside <= limit
+        keep = self._everywhere(keep)
+        kept = "kept:" + (",".join(SAVE_ATTN_NAMES) if keep else "none")
+        # the bytes are the FIRST rung's: what was held against the limit
+        attrs.update(remat=kept, remat_program_bytes=total,
+                     remat_beside_bytes=beside, bytes_limit=limit)
+        dispatch.record("train.remat",
+                        f"{kept},program{total}of{limit},beside{beside}")
+        return keep
+
+    def _everywhere(self, keep: bool) -> bool:
+        """One decision for the whole job: the mesh's processes run ONE
+        program, so all keep or none does (limits differ between chips,
+        and what else a chip holds between processes)."""
+        if len({d.process_index for d in self.mesh.devices.flat}) == 1:
+            return keep
+        along = jax.sharding.PartitionSpec(self.mesh.axis_names)
+        flags = jax.make_array_from_callback(
+            (self.mesh.size,), jax.sharding.NamedSharding(self.mesh, along),
+            lambda index: np.full((1,), keep))
+        return bool(jax.jit(jnp.all, out_shardings=jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec()))(flags))
+
     def step(self, state, batch):
         self._steps += 1
         attrs = {"step": self._steps}   # kept by identity: see trace_span
@@ -241,7 +336,9 @@ class ShardedTrainStep:
         with self._span("train.step", attrs, force=counted):
             batch = jax.device_put(batch, self.batch_sharding)
             with self._mesh_scope():
-                state, metrics = self._step(state, batch)
+                if self._keep is None:
+                    self._keep = self._choose_rung(state, batch, attrs)
+                state, metrics = self._step(state, batch, keep=self._keep)
             if counted:
                 attrs.update({k: float(v) for k, v in metrics.items()
                               if k not in _STEP_METRICS})

@@ -148,14 +148,15 @@ def has_accelerator() -> bool:
         "cpu", "none", "unloaded", "")
 
 
-def memory_stats() -> Optional[Dict[str, Any]]:
-    """device.memory_stats() for device 0, or None (CPU backends and
-    older runtimes return None or raise — both degrade to None)."""
+def memory_stats(device=None) -> Optional[Dict[str, Any]]:
+    """device.memory_stats() of `device` (device 0 where none is given),
+    or None (CPU backends and older runtimes return None or raise, as
+    does a device of another process — all degrade to None)."""
     jax = _jax()
     if jax is None:
         return None
     try:
-        stats = jax.devices()[0].memory_stats()
+        stats = (device or jax.devices()[0]).memory_stats()
         return dict(stats) if stats else None
     except Exception:  # raylint: allow-swallow(cpu/older runtimes raise here; None is the documented fallback)
         return None
@@ -474,6 +475,16 @@ def hlo_instructions(text: str) -> Tuple[str, Dict[str, list]]:
     return module, out
 
 
+def program_bytes(memory) -> Optional[int]:
+    """What a chip must hold to run a compiled program, from its
+    `memory_analysis()`: arguments + outputs - aliased + temporaries
+    (None where the backend gives no analysis)."""
+    if memory is None:
+        return None
+    return int(memory.argument_size_in_bytes + memory.output_size_in_bytes
+               - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+
+
 def program_report(name: str) -> Optional[Dict[str, Any]]:
     """What the program counted as `name` (``count_compiles``) compiled
     to: its first call's abstract signature lowered and compiled again,
@@ -503,10 +514,8 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
                   "temp_bytes": int(m.temp_size_in_bytes),
                   "alias_bytes": int(m.alias_size_in_bytes),
                   "generated_code_bytes": int(
-                      m.generated_code_size_in_bytes)}
-        memory["total_bytes"] = (
-            memory["argument_bytes"] + memory["output_bytes"]
-            - memory["alias_bytes"] + memory["temp_bytes"])
+                      m.generated_code_size_in_bytes),
+                  "total_bytes": program_bytes(m)}
     return {"module": module, "instructions": instructions,
             "memory": memory,
             "bytes_limit": (memory_stats() or {}).get("bytes_limit"),

@@ -66,3 +66,22 @@ def pytest_runtest_protocol(item, nextitem):
     yield
     if _watchdog_file is not None:
         faulthandler.cancel_dump_traceback_later()
+
+
+@pytest.fixture
+def time_limit():
+    """`time_limit(seconds)`: the test fails once it has run that long (at
+    the next return to Python: a compile is not cut), where the watchdog
+    above would kill the whole run.  For tests that compile."""
+    import signal
+
+    def arm(seconds: int):
+        def on_alarm(signum, frame):
+            raise TimeoutError(f"the test ran over its {seconds} s")
+
+        signal.signal(signal.SIGALRM, on_alarm)
+        signal.alarm(seconds)
+
+    yield arm
+    signal.alarm(0)
+    signal.signal(signal.SIGALRM, signal.SIG_DFL)

@@ -293,7 +293,13 @@ def test_train_step_programs_are_in_compile_counts():
              if s["name"].startswith("train.")]
     assert sorted(names) == ["train.eval", "train.init", "train.step"]
     (first,) = [s for s in tracing.get_spans() if s["name"] == "train.step"]
-    assert first["attributes"] == {"step": 1}
+    # .. and carries what the first step decided of remat (PR 40): on
+    # the CPU, which reports no limit, out and lse are kept
+    kept = first["attributes"]
+    assert kept == {"step": 1, "remat": "kept:attn_out,attn_lse",
+                    "remat_program_bytes": kept["remat_program_bytes"],
+                    "remat_beside_bytes": 0, "bytes_limit": None}
+    assert kept["remat_program_bytes"] > 0
     programs = {s["attributes"]["program"] for s in _compile_spans()
                 if s["attributes"]["event"] == "backend_compile"}
     assert {"train.init", "train.step", "train.eval"} <= programs

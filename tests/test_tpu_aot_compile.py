@@ -431,9 +431,9 @@ def step_program(topo):
 
 
 def _chip_bytes(compiled) -> int:
-    m = compiled.memory_analysis()
-    return (m.argument_size_in_bytes + m.output_size_in_bytes
-            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    from ray_tpu.util.device_stats import program_bytes
+
+    return program_bytes(compiled.memory_analysis())
 
 
 def _scan_shapes(one_chip):
@@ -634,10 +634,11 @@ def _glue_between_matmuls_and_kernels(text: str):
     return comps, glue
 
 
-_DENSE_LAYER = {}       # mesh -> (compiled text, its kernel calls as traced)
+_DENSE_LAYER = {}       # (mesh, remat policy) -> (compiled text, its kernel
+                        # calls as traced)
 
 
-def _dense_layer_program(topo, monkeypatch, mesh_name):
+def _dense_layer_program(topo, monkeypatch, mesh_name, policy="full"):
     """One remat'd dense layer of the cells' widths, forward and backward
     (two scanned layers' grad: the scan body is compiled once), compiled
     once a module for `one_chip` (train-d12's 5 x 2048 rows) and for `fsdp4`
@@ -652,12 +653,12 @@ def _dense_layer_program(topo, monkeypatch, mesh_name):
 
     _on_tpu(monkeypatch, attention)
     monkeypatch.setattr(attention.dispatch, "_taken", {})
-    if mesh_name in _DENSE_LAYER:
-        return _DENSE_LAYER[mesh_name]
+    if (mesh_name, policy) in _DENSE_LAYER:
+        return _DENSE_LAYER[mesh_name, policy]
     config = tfm.TransformerConfig(
         vocab_size=256, hidden_size=2048, intermediate_size=8192,
         num_layers=2, num_heads=32, num_kv_heads=32, head_dim=64,
-        max_seq_len=2048, rope_theta=130000.0, remat_policy="full",
+        max_seq_len=2048, rope_theta=130000.0, remat_policy=policy,
         dtype=jnp.bfloat16)
     shapes = jax.eval_shape(lambda: tfm.init_params(config, jax.random.key(0)))
     if mesh_name == "one_chip":
@@ -682,9 +683,9 @@ def _dense_layer_program(topo, monkeypatch, mesh_name):
     opts.include_layout_in_shapes = False
     opts.print_backend_config = False
     traced = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
-    _DENSE_LAYER[mesh_name] = (compiled.as_text(), [
+    _DENSE_LAYER[mesh_name, policy] = (compiled.as_text(), [
         l for l in traced.splitlines() if "tpu_custom_call" in l])
-    return _DENSE_LAYER[mesh_name]
+    return _DENSE_LAYER[mesh_name, policy]
 
 
 def test_dense_layer_moves_q_and_k_to_the_kernels_once_and_unroped(
@@ -1096,6 +1097,131 @@ def test_cell_swa_moe_step_program_fits_a_v5e(step_program):
     assert list(taken["swa_moe.rope"]) == [
         "full_attention:in_kernel64of128_columns_reordered_at_use_identity_"
         "tail,sliding_attention:in_kernel128of128"]
+
+
+# ---------------------------------------------------------------------------
+# What a layer's remat keeps (PR 40): under the ladder's first rung
+# (`ShardedTrainStep`, "save_attn" in `models/common.maybe_remat`) the
+# compiled gradient of one remat'd layer holds ONE forward flash call and
+# one backward; under the second ("full": bare jax.checkpoint) two and one
+# ---------------------------------------------------------------------------
+
+
+def _flash_calls(text: str):
+    """(forward, backward) flash custom calls of a compiled module, told
+    apart by the kernels' names in `op_name`."""
+    calls = [l for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    return (sum("/flash_fwd/" in l for l in calls),
+            sum("/flash_bwd/" in l for l in calls))
+
+
+@pytest.mark.parametrize("mesh_name,policy,forwards", [
+    ("fsdp4", "save_attn", 1), ("one_chip", "full", 2), ("fsdp4", "full", 2)])
+def test_dense_layer_runs_the_flash_forward_once_where_out_and_lse_are_kept(
+        topo, monkeypatch, time_limit, mesh_name, policy, forwards):
+    """The scanned dense layer of the cells' widths under the fsdp=4 mesh,
+    where the custom VJP sits INSIDE the `shard_map` and the kept out and
+    lse cross it (the policy sees the names in there), and the bare layer
+    on both meshes (programs other tests compiled).
+    What is kept is the kernel's own [rows, 2048, 32 x 64] and [rows x 32,
+    2048] float32, a layer: not the [rows, 2048, 32, 64] view, which would
+    lie in half-filled lane blocks at twice the bytes."""
+    import re
+
+    time_limit(240)
+    text, calls = _dense_layer_program(topo, monkeypatch, mesh_name, policy)
+    assert _flash_calls(text) == (forwards, 1), calls
+    rows = 5 if mesh_name == "one_chip" else 10
+    stacked = set(re.findall(r"(?:bf16|f32)\[2,[\d,]+\]", text))
+    assert f"bf16[2,{rows},2048,2048]" in stacked       # the layers' inputs
+    assert (f"f32[2,{rows * 32},2048]" in stacked) == (policy == "save_attn")
+    assert f"bf16[2,{rows},2048,32,64]" not in stacked
+
+
+def _remat_layer_calls(layer, policy, *shapes):
+    """(forward, backward) flash calls in the compiled value and gradient,
+    by every operand, of `layer` under `maybe_remat(.., policy)` (the value
+    too, as a step wants the loss: the layer's first forward is not dead)."""
+    from ray_tpu.models import common
+
+    block = common.maybe_remat(layer, True, policy)
+
+    def loss(*operands):
+        return block(*operands).astype(jnp.float32).sum()
+
+    return _flash_calls(_compiled_text(
+        jax.value_and_grad(loss, argnums=tuple(range(len(shapes)))),
+        *shapes))
+
+
+def _sds(one_chip, *shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("kind,policy,forwards", [
+    ("dense_rope_64", "save_attn", 1), ("latent", "save_attn", 1),
+    ("windowed_rope_128", "save_attn", 1), ("cross", "save_attn", 1),
+    ("cross", "full", 2)])
+def test_a_remat_layer_of_every_attention_entry_keeps_out_and_lse(
+        one_chip, monkeypatch, time_limit, kind, policy, forwards):
+    """Projections, the attention entry and W_o as one remat'd layer, at
+    the cells' widths: latent attention's parts (train-moe-mla-d6: 32
+    heads, keys 192, values 128), the dense cells' roped call on one chip
+    (train-d12: 5 x 2048, 32 heads of 64), a window WITH rope at head 128
+    (train-swa-moe-d5's sliding layer, 72 heads) and the hybrid's cross
+    layer, whose keys and values come from another layer (operands of the
+    layer, 40 heads at value width 128): the backward of the kept layer
+    holds no forward kernel, that of the bare one holds it again."""
+    time_limit(240)
+    _on_tpu(monkeypatch, attention)
+    monkeypatch.setattr(attention.dispatch, "_taken", {})
+    if kind == "latent":
+        b, s, h, hidden = MOE_ROWS, MOE_SEQ, MOE_HEADS, 2048
+
+        def layer(x, wq, wkv, wr, wo, cos, sin):
+            q = (x @ wq).reshape(b, s, h, 192)
+            kv = (x @ wkv).reshape(b, s, h, 256)
+            out = attention.latent_flash_attention(q, kv, x @ wr, (cos, sin))
+            return out.reshape(b, s, h * 128) @ wo
+
+        shapes = [_sds(one_chip, b, s, hidden),
+                  _sds(one_chip, hidden, h * 192),
+                  _sds(one_chip, hidden, h * 256), _sds(one_chip, hidden, 64),
+                  _sds(one_chip, h * 128, hidden)] + [
+            _sds(one_chip, b, s, 32, dtype=jnp.float32)] * 2
+    elif kind in ("windowed_rope_128", "dense_rope_64"):
+        b, s, h, d, hidden, window = ((1, SWA_SEQ, 72, 128, 3072, 512)
+                                      if kind == "windowed_rope_128"
+                                      else (5, 2048, 32, 64, 2048, None))
+
+        def layer(x, wq, wk, wv, wo, cos, sin):
+            q, k, v = ((x @ w).reshape(b, s, h, d) for w in (wq, wk, wv))
+            out = attention.flash_attention(q, k, v, window=window,
+                                            rope=(cos, sin))
+            return out.reshape(b, s, h * d) @ wo
+
+        shapes = [_sds(one_chip, b, s, hidden)] + [
+            _sds(one_chip, hidden, h * d)] * 3 + [
+            _sds(one_chip, h * d, hidden)] + [
+            _sds(one_chip, b, s, d // 2, dtype=jnp.float32)] * 2
+    else:
+        b, s, h, hidden = HYBRID_ROWS, HYBRID_SEQ, 40, 2560
+
+        def layer(x, k, v, wq, wo):
+            q = (x @ wq).reshape(b, s, h, 128)
+            out = attention.flash_attention(q, k, v, sm_scale=0.125)
+            return out.reshape(b, s, h * 128) @ wo
+
+        shapes = [_sds(one_chip, b, s, hidden)] + [
+            _sds(one_chip, b, s, h, 128)] * 2 + [
+            _sds(one_chip, hidden, h * 128), _sds(one_chip, h * 128, hidden)]
+    assert _remat_layer_calls(layer, policy, *shapes) == (forwards, 1)
+    assert attention.dispatch.taken()["flash_attention"] == {"pallas": 1}
+    (plan,) = attention.dispatch.taken()["flash_attention.plan"]
+    assert {"latent": "latent_parts", "windowed_rope_128": "window512",
+            "dense_rope_64": "rope_in_kernel,operands_bshd,heads2x64",
+            "cross": "dead"}[kind] in plan, plan
 
 
 # ---------------------------------------------------------------------------

@@ -210,6 +210,58 @@ def test_multiprocess_jax_distributed_collective(ray4):
     assert res.metrics["total"] == 4 * 1.0 + 4 * 2.0
 
 
+@pytest.mark.parametrize("short_rank", [0, 1])
+def test_the_processes_of_one_mesh_take_one_remat_rung(ray4, time_limit,
+                                                       short_rank):
+    """PR 40: what `remat_policy="full"` keeps is ONE decision for the
+    processes of a mesh.  Two processes, one mesh over both; one of them
+    reads a limit its program does not fit (chips differ), the other a
+    limit it fits: alone they would build two different programs and the
+    job would hang.  Both take the second rung, whichever rank is short."""
+    time_limit(240)
+    out = tempfile.mkdtemp(prefix="remat_ranks_")
+
+    def loop(config):
+        import jax
+
+        from ray_tpu.models import transformer as tfm
+        from ray_tpu.ops import dispatch
+        from ray_tpu.train import get_mesh
+        from ray_tpu.train.train_state import ShardedTrainStep
+        from ray_tpu.util import device_stats
+
+        rank = jax.process_index()
+        limit = 1000 if rank == config["short_rank"] else 1 << 40
+        device_stats.memory_stats = lambda device=None: {
+            "bytes_limit": limit, "bytes_in_use": 0}
+        cfg = tfm.TransformerConfig.tiny(num_layers=2, max_seq_len=64)
+        ts = ShardedTrainStep(cfg, get_mesh({"fsdp": -1}))
+        state = ts.init(jax.random.key(0))
+        tokens = np.random.default_rng(0).integers(
+            0, cfg.vocab_size, (4, 65), dtype=np.int32)
+        for _ in range(2):
+            state, metrics = ts.step(state, {"tokens": tokens})
+        record, = dispatch.taken()["train.remat"]
+        with open(os.path.join(config["out"], f"rank{rank}"), "w") as f:
+            f.write(f"{ts._keep} {record.split(',program')[0]} "
+                    f"{record.rsplit('of', 1)[1]}")
+        train.report({"loss": float(metrics["loss"])})
+
+    res = JaxTrainer(
+        loop, train_loop_config={"out": out, "short_rank": short_rank},
+        scaling_config=ScalingConfig(num_workers=2),
+        run_config=RunConfig(storage_path=_run_dir(), name="remat_ranks"),
+        backend_config=train.JaxBackendConfig(
+            distributed_init=True, platform="cpu", host_device_count=2),
+    ).fit()
+    assert np.isfinite(res.metrics["loss"])
+    said = {f: open(os.path.join(out, f)).read() for f in os.listdir(out)}
+    # each rank read its own limit, and both run the bare program
+    assert said == {
+        f"rank{short_rank}": "False kept:none 1000,beside0",
+        f"rank{1 - short_rank}": f"False kept:none {1 << 40},beside0"}
+
+
 def test_checkpoint_numbering_survives_restart_and_num_to_keep(ray4):
     """Restarted attempts continue checkpoint numbering (no overwrite) and
     num_to_keep GC runs on the persisting worker."""
