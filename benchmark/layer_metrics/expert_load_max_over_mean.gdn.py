@@ -1,0 +1,17 @@
+"""Routed experts: the fullest held expert's rows over the held experts'
+mean, in the last layer at the recorded step nearest the traced window
+(`moe_load_max` / `moe_load_mean`, device scalars of the step's metrics that
+the forced `train.step` spans of steps 1, 2, 4, 8, ... carry into
+timeline.json).  1 is even routing; the grouped matmul costs by the rows, so
+imbalance shows here before it shows in the step."""
+from benchmark import moe_lib
+
+NAME, UNIT, SOURCE = "expert_load_max_over_mean.gdn", "ratio", \
+    "program_counter"
+LAYER, MOVES, WORKLOADS = "routed experts", "train_tokens_per_s", ["train-gdn-moe-d4"]
+
+
+def read(spans, trace, counters, cell):
+    counts = moe_lib.step_counts(cell, trace)
+    most, mean = counts.get("moe_load_max"), counts.get("moe_load_mean")
+    return None if not most or not mean else most / mean

@@ -25,7 +25,8 @@ ATTN_SLIDING = "attn.sliding"    # .. or a window; the pre-norm, the
 ATTN_CROSS = "attn.cross"        # projections, the kernels, W_o
 ATTN_GATE = "attn.gate"          # a per-head output gate, inside one of them
 MLA_PROJECT = "mla.project"      # latent attention's projections, likewise
-SSM = "ssm"                      # a state-space mixer: projections, conv, scan
+SSM = "ssm"                      # a recurrent mixer: state-space or linear
+                                 # attention; projections, conv, the kernels
 GMU = "gmu"                      # a gated memory unit that is its own layer
 MLP = "mlp"                      # dense and SHARED feed-forward, its pre-norm
 MOE_ROUTE = "moe.route"
@@ -64,6 +65,18 @@ def swiglu(y, w_gate, w_up, w_down, dtype):
         up = y @ w_up.astype(dtype)
         ffn = with_logical_constraint(gate * up, ("batch", "seq", "mlp"))
         return checkpoint_name(ffn @ w_down.astype(dtype), "mlp_out")
+
+
+def causal_depthwise_conv(x, w, b=None):
+    """y_t = b + sum_j w[j] x_{t - (taps - 1) + j}: `taps` shifted adds
+    over the time axis, nothing before position 0.  x: [b, s, channels];
+    w: [taps, channels]; b: [channels] or None."""
+    taps, s = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    y = 0.0 if b is None else b.astype(x.dtype)
+    for j in range(taps):
+        y = y + padded[:, j:j + s] * w[j].astype(x.dtype)
+    return y
 
 
 # Heads beside each other on the last axis, [b, s, heads x d], is how a

@@ -329,17 +329,6 @@ def _matmul(x, w, c: HybridConfig, out_dtype=None):
                       preferred_element_type=out_dtype or c.dtype)
 
 
-def causal_depthwise_conv(x, w, b):
-    """y_t = b + sum_j w[j] x_{t - (taps - 1) + j}: `taps` shifted adds
-    over the time axis, nothing before position 0.  x: [b, s, channels]."""
-    taps, s = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    y = b.astype(x.dtype)
-    for j in range(taps):
-        y = y + padded[:, j:j + s] * w[j].astype(x.dtype)
-    return y
-
-
 def recurrence(x, dt, a_log, b_t, c_t, d, config: HybridConfig):
     """The mamba mixer's recurrence on its operands as the mixer makes them
     (x [b, s, d_inner]; dt like x, b_t and c_t [b, s, state], float32;
@@ -357,7 +346,8 @@ def _mamba_mixer(u, lp, c: HybridConfig):
     n, r = c.mamba_d_state, c.mamba_dt_rank
     x, z = jnp.split(_matmul(u, lp["in_proj"], c), 2, axis=-1)
     x = with_logical_constraint(x, ("batch", "seq", "ssm_inner"))
-    x = jax.nn.silu(causal_depthwise_conv(x, lp["conv_w"], lp["conv_b"]))
+    x = jax.nn.silu(common.causal_depthwise_conv(x, lp["conv_w"],
+                                                 lp["conv_b"]))
     # the step, B and C feed the float32 recurrence: fp32 out of the MXU
     proj = _matmul(x, lp["x_proj"], c, F32)
     rank, b_t, c_t = proj[..., :r], proj[..., r:r + n], proj[..., r + n:]

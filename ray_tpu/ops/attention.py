@@ -197,11 +197,17 @@ def default_blocks(head_dim: int, seq_q: int, seq_k: int, dtype,
     block of its window with all its queries, most of which cannot see
     it: tile and block are both 512 then, so a tile visits the block on
     its diagonal and the one behind it."""
-    del head_dim, dtype        # the sweep gave one answer for those it ran
+    del dtype                  # the sweep gave one answer for those it ran
     if window is not None and window < seq_k:
         short = (min(512, seq_q), min(512, seq_k))
         return short, short
     kv_tile, q_inner = _tile_and_inner(seq_k, seq_q)
+    if _one_wide_head(_heads_a_program(head_dim, head_dim), head_dim):
+        # the backward's k, v, dk and dv tiles, its two float32 sums and the
+        # four [keys, queries] tiles of a step are 40 MiB at a key tile of
+        # 2048 x 256 beside the head's whole q, do, out and dq, and the chip
+        # has 128 (PERF.md, PR 42)
+        kv_tile = min(kv_tile, 1024)
     return _tile_and_inner(seq_q, seq_k), (q_inner, kv_tile)
 
 
@@ -643,18 +649,29 @@ def _compiler_params(vmem_mib: int = 32):
     return pltpu.CompilerParams(vmem_limit_bytes=vmem_mib << 20)
 
 
-def _rope_operands(rope, programs: int, seq_k: int, d: int):
+def _rope_operands(rope, programs: int, seq_k: int, d: int,
+                   one_buffer: bool = False):
     """(operands, their BlockSpecs) of the widened tables [b, seq_k, d]: a
     row's whole table, whose block index does not move across the row's
     `programs` (its heads, or its groups of them) or their tiles, so the
-    pipeline loads it once a row."""
+    pipeline loads it once a row.  one_buffer: the table keeps ONE buffer
+    (`_one_wide_head`: its second, which only a row's change would use, is
+    8 MiB at 8192 x 256, 16 for the pair)."""
     from jax.experimental import pallas as pl
 
     if rope is None:
         return (), []
-    spec = pl.BlockSpec((1, seq_k, d),
-                        lambda g, i, offs: (g // programs, 0, 0))
+    spec = pl.BlockSpec(
+        (1, seq_k, d), lambda g, i, offs: (g // programs, 0, 0),
+        **({"pipeline_mode": pl.Buffered(1)} if one_buffer else {}))
     return tuple(rope), [spec, spec]
+
+
+def _one_wide_head(heads: int, d: int) -> bool:
+    """A program works ONE head wider than a lane block (a head of 256):
+    what it holds whole is twice a head of 128's, and the chip's VMEM is
+    what it was."""
+    return heads == 1 and d > 128
 
 
 def _lanes(width: int) -> int:
@@ -765,12 +782,24 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
             scratch = scratch + [pltpu.VMEM((heads * d_v, sk), v.dtype)]
             kv_mib += -(-sk * 2 * _lanes(heads * d_v) // 2 ** 20)
         vmem_mib = max(32, 24 + 4 * table_mib) + max(kv_mib - 8, 0)
+        wide = _one_wide_head(heads, d)
+        if wide:
+            # beside its tables and its resident k and v: the roped keys'
+            # scratch, the q and out tiles and the [d, queries] accumulator,
+            # by the lanes beyond 128.  XLA counts against a kernel's scoped
+            # VMEM what it has itself placed there of the call's operands:
+            # the call at 8192 x 256 needed 65.4 MiB inside a step program
+            # of two rows and 77.1 inside the same program of one row (the
+            # lse result and a table in VMEM) while its tables kept two
+            # buffers; with one (`_rope_operands`) 16 less, of the 70 asked
+            vmem_mib += -(-(2 * sk + 16 * block_q) * (_lanes(d) - 128)
+                          // 2 ** 20)
     else:
         # q keeps its [bh, s, d] face (the benchmark's reader finds the
         # call by it) and out comes back [bh, s, e]; k_nope, v and the
         # rotary key are read where their projections left them.
         nope, d_v, table_width = parts
-        heads, programs = 1, h
+        heads, programs, wide = 1, h, False
         qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
         q_spec = pl.BlockSpec((1, block_q, d), lambda bh, i, offs: (bh, i, 0))
         out_shape = jax.ShapeDtypeStruct((b * h, sq, d_v), q.dtype)
@@ -794,7 +823,8 @@ def _flash_fwd(q, k, v, offs, causal: bool, sm_scale: float,
         **({} if rope is None else {"rope_q0": sk - sq}),
         **({} if parts is None else {"parts": (nope, h)}),
         **({} if heads == 1 else {"heads": heads}))
-    tables, table_specs = _rope_operands(rope, programs, sk, table_width)
+    tables, table_specs = _rope_operands(rope, programs, sk, table_width,
+                                         wide)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1129,7 +1159,9 @@ def _flash_bwd(q, k, v, out, lse, offs, dout, dlse, causal, sm_scale,
     minus_dlse8 = _lse8(0.0 - dlse.astype(jnp.float32), bh, sq)
 
     seq_spec = pl.BlockSpec((heads, 8, sq), lambda g, i, offs: (g, 0, 0))
-    tables, table_specs = _rope_operands(rope, programs, sk, table_width)
+    tables, table_specs = _rope_operands(
+        rope, programs, sk, table_width,
+        parts is None and _one_wide_head(heads, d))
     if parts is None:       # the tables come last, the parts' output
         tables, table_specs = (outf, *tables), [full_out, *table_specs]
     else:

@@ -1121,3 +1121,45 @@ def test_long_roped_forward_asks_more_vmem_and_the_others_what_they_did():
     assert ask(2048, 64, True) == ask(2048, 64, False) == [32]
     assert ask(8192, 128, False) == [32]
     assert ask(8192, 128, True) == [40]
+
+
+# ---------------------------------------------------------------------------
+# A head of 256 (two lane blocks, one head a program) with a rotary QUARTER:
+# the tables hold cos 1 and sin 0 for the pairs that pass through, so the
+# kernels' whole-head turn is the quarter turn (models/gdn_moe.py)
+# ---------------------------------------------------------------------------
+
+def _quarter_tables(b, s, d, theta=1e7):
+    r = d // 4
+    inv_freq = theta ** (-2.0 * jnp.arange(r // 2, dtype=jnp.float32) / r)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    passing = (d - r) // 2
+    cos = jnp.concatenate([jnp.cos(angle), jnp.ones((s, passing))], axis=1)
+    sin = jnp.concatenate([jnp.sin(angle), jnp.zeros((s, passing))], axis=1)
+    return tuple(jnp.broadcast_to(t, (b, s, d // 2)) for t in (cos, sin))
+
+
+def test_head_256_with_a_rotary_quarter_values_and_grads():
+    b, s, h, d = 1, 256, 2, 256
+    q, k, v = _rand_qkv(7, b, s, h, d)
+    w = jax.random.normal(jax.random.PRNGKey(8), (b, s, h, d))
+    rope = _quarter_tables(b, s, d)
+    kw = dict(causal=True, sm_scale=1.0 / 16, block_q=128, block_k=128)
+
+    def roped_reference(q, k, v):
+        return attn.attention_reference(
+            attn.rope_reference(q, *rope), attn.rope_reference(k, *rope), v,
+            causal=True, sm_scale=1.0 / 16)
+
+    (out, grads), (out_ref, grads_ref) = (
+        _grads_and_value(f, q, k, v, w) for f in (
+            lambda q, k, v: attn.flash_attention(q, k, v, rope=rope, **kw),
+            roped_reference))
+    for name, a, e in zip(("out", "dq", "dk", "dv"), (out, *grads),
+                          (out_ref, *grads_ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(e), atol=3e-5,
+                                   rtol=3e-5, err_msg=name)
+    assert bool((rope[0][0, :, d // 8:] == 1).all())
+    plans = attn.dispatch.taken()["flash_attention.plan"]
+    assert any(p.endswith("rope_in_kernel,operands_bshd,heads1x256")
+               for p in plans), plans

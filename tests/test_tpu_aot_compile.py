@@ -372,7 +372,8 @@ def _on_tpu(monkeypatch, module):
 # cell -> its configuration under benchmark/configs/
 STEP_CONFIGS = {"train-hybrid-d8": "phi4-mini-flash-train-d8.json",
                 "train-moe-mla-d6": "kanana-2-30b-a3b-train-d6e16.json",
-                "train-swa-moe-d5": "laguna-s-2.1-train-d5e8.json"}
+                "train-swa-moe-d5": "laguna-s-2.1-train-d5e8.json",
+                "train-gdn-moe-d4": "qwen3-next-80b-a3b-train-d4e32.json"}
 
 
 @pytest.fixture(scope="module")
@@ -1097,6 +1098,127 @@ def test_cell_swa_moe_step_program_fits_a_v5e(step_program):
     assert list(taken["swa_moe.rope"]) == [
         "full_attention:in_kernel64of128_columns_reordered_at_use_identity_"
         "tail,sliding_attention:in_kernel128of128"]
+
+
+# ---------------------------------------------------------------------------
+# train-gdn-moe-d4 (PR 42): the gated-delta-rule kernels and the head-256
+# flash call at the cell's size, and its step program
+# ---------------------------------------------------------------------------
+GDN_ROWS, GDN_SEQ = 3, 8192
+
+
+def test_cell_gated_delta_kernels_compile_and_keep_the_faces_readers_find(
+        one_chip, monkeypatch):
+    """Forward alone, forward with the blocks' first states and backward at
+    the cell's size (3 x 8192, 32 value heads over 16 key heads of 128,
+    bfloat16 operands); each custom-call is found by exactly the pattern
+    benchmark/gdn_faces.py gives the rule's readers for it, and by none of
+    the flash or grouped-matmul patterns the cell's other readers use."""
+    import re
+
+    from benchmark import gdn_faces, moe_faces
+    from ray_tpu.ops import gated_delta as gd
+
+    _on_tpu(monkeypatch, gd)
+    monkeypatch.setattr(gd.dispatch, "_taken", {})
+    forward, backward = _reader("gated_delta_share.gdn").KERNELS
+    assert (forward, backward) == (gdn_faces.RULE_FORWARD,
+                                   gdn_faces.RULE_BACKWARD)
+    assert _reader("gated_delta_fwd_roofline.gdn").KERNEL == forward
+    others = (gdn_faces.FLASH_FORWARD, moe_faces.GROUPED_FORWARD,
+              moe_faces.GROUPED_TRANSPOSED, moe_faces.GROUPED_DW)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, t = GDN_ROWS, GDN_SEQ
+    shapes = (sds((b, t, 16, 128)), sds((b, t, 16, 128)),
+              sds((b, t, 32, 128)), sds((b, t, 32), jnp.float32),
+              sds((b, t, 32), jnp.float32))
+    calls = _custom_calls_as_traced(gd.gated_delta_rule, *shapes)
+    assert len(calls) == 1 and re.search(forward, calls[0]), calls
+    assert not re.search(backward, calls[0])
+    assert "= bf16[3,8192,4096] custom-call(bf16[3,8192,2048] " in calls[0]
+
+    def loss(*a):
+        return gd.gated_delta_rule(*a).astype(jnp.float32).sum()
+
+    calls = _custom_calls_as_traced(
+        jax.grad(loss, argnums=tuple(range(5))), *shapes)
+    assert len(calls) == 2, calls       # forward with states, backward
+    assert sorted((bool(re.search(forward, l)), bool(re.search(backward, l)))
+                  for l in calls) == [(False, True), (True, False)]
+    # every block of 8 chunks' first state, a head: [3 x 32, 16, 128, 128]
+    assert any("f32[96,16,128,128]" in l for l in calls)
+    assert not any(re.search(o, l) for o in others for l in calls)
+    taken = gd.dispatch.taken()
+    assert taken["gated_delta_rule"] == {"pallas": 2}
+    assert list(taken["gated_delta_rule.plan"]) == [
+        "chunk64,heads32over16,dk128,dv128,state_f32,bwd_pallas"]
+
+
+def test_cell_head_256_flash_compiles_and_keeps_the_face_its_reader_finds(
+        one_chip, monkeypatch):
+    """The full layer's call at 3 x 8192, 16 heads of 256, the quarter rope
+    as tables with an identity tail: forward and backward compile (they ask
+    70 and 96 MiB of VMEM at this width and length, the tables in ONE
+    buffer each and the backward's key tile 1024); the forward is found by
+    flash_fwd_roofline.gdn, the backward is not; the plan says one head a
+    program."""
+    import re
+
+    from benchmark import gdn_faces
+
+    _on_tpu(monkeypatch, attention)
+    monkeypatch.setattr(attention.dispatch, "_taken", {})
+    face = _reader("flash_fwd_roofline.gdn").KERNEL
+    assert face == gdn_faces.FLASH_FORWARD
+    x = jax.ShapeDtypeStruct((GDN_ROWS, GDN_SEQ, 16, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    table = jax.ShapeDtypeStruct((GDN_ROWS, GDN_SEQ, 128), jnp.float32,
+                                 sharding=one_chip)
+
+    def attend(q, k, v, cos, sin):
+        return attention.flash_attention(q, k, v, sm_scale=1.0 / 16,
+                                         rope=(cos, sin))
+
+    def loss(*a):
+        return attend(*a).astype(jnp.float32).sum()
+
+    calls = _custom_calls_as_traced(jax.grad(loss, argnums=(0, 1, 2)),
+                                    x, x, x, table, table)
+    assert len(calls) == 2          # forward, backward
+    assert sum(bool(re.search(face, l)) for l in calls) == 1
+    assert any("(bf16[3,8192,4096], f32[48,8,8192])" in l for l in calls)
+    assert not any(re.search(gdn_faces.RULE_FORWARD, l)
+                   or re.search(gdn_faces.RULE_BACKWARD, l) for l in calls)
+    assert list(attention.dispatch.taken()["flash_attention.plan"]) == [
+        "fwd2048x512,bwd512x1024,dq_in_pass,dq_over8tiles,scale_folded,"
+        "dead6/6%,rope_in_kernel,operands_bshd,heads1x256"]
+
+
+def test_cell_gdn_moe_step_program_fits_a_v5e(step_program):
+    """The cell's whole step program (three gated-delta-rule layers and one
+    gated full layer, 32 of 512 experts and a gated shared expert in each,
+    an eighth of the vocabulary, 3 x 8192 tokens, full remat, fused CE,
+    bfloat16 moments) by AOT memory_analysis: under 15.75 GiB at the
+    configuration's rows."""
+    compiled, taken, tr = step_program("train-gdn-moe-d4")
+    assert tr["batch_rows"] == GDN_ROWS and tr["sequence_length"] == GDN_SEQ
+    total = _chip_bytes(compiled)
+    assert 13.0 * 2 ** 30 < total < 15.75 * 2 ** 30, total / 2 ** 30
+    # The linear segment: the rule's forward, its forward again under remat
+    # and its backward (3); the full segment the flash three; each segment
+    # the grouped kernels, twelve at each of the layer's two buffer sizes.
+    assert compiled.as_text().count("tpu_custom_call") == 2 * 3 + 2 * 2 * 12
+    assert list(taken["gated_delta_rule.plan"]) == [
+        "chunk64,heads32over16,dk128,dv128,state_f32,bwd_pallas"]
+    assert [p.split(",dead")[1] for p in taken["flash_attention.plan"]] == [
+        "6/6%,rope_in_kernel,operands_bshd,heads1x256"]
+    assert list(taken["gdn_moe.rope"]) == [
+        "full_attention:in_kernel64of256_columns_reordered_at_use_identity_"
+        "tail"]
+    assert all(",groups32" in p for p in taken["grouped_matmul.plan"])
 
 
 # ---------------------------------------------------------------------------
