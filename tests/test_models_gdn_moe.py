@@ -223,8 +223,9 @@ def test_train_step_carries_the_counts_and_the_plans():
     assert all(jax.tree.leaves(jax.tree.map(
         lambda a, b: bool((a != b).any()), before, after)))
     taken = dispatch.taken()
-    assert "chunk64,heads4over2,dk32,dv32,state_f32,bwd_pallas" \
-        in taken["gated_delta_rule.plan"]
+    assert any(p.startswith("chunk64,heads4over2,dk32,dv32,state_f32,"
+                            "bwd_pallas,passes")
+               for p in taken["gated_delta_rule.plan"])
     assert set(taken["gated_delta_rule"]) == {"interpret"}
     assert any(",rope_in_kernel,operands_bshd,heads2x64" in p
                for p in taken["flash_attention.plan"])

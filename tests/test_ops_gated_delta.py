@@ -3,7 +3,9 @@ the CPU) against `gated_delta_reference`, the recurrence one step at a time:
 forward and all five gradients, 2 key / 4 value heads, strong and weak
 decay, beta at 0 and at 1, chunk edges inside the sequence, a sequence that
 is no multiple of the chunk and one longer than a block of chunks (the state
-and dL/dS carried from grid step to grid step); and the exact inverse."""
+and dL/dS carried from grid step to grid step); one case at the cell's chunk
+and widths; the exact inverse; and the MXU passes a chunk of the kernels'
+traced bodies, which the plan reports."""
 
 import numpy as np
 import pytest
@@ -65,6 +67,69 @@ def test_forward_and_gradients_match_the_recurrence(path, t, weak,
         assert _rel(mine, ref) < 5e-5, name
 
 
+def test_the_cell_s_chunk_and_widths_match_the_recurrence(monkeypatch):
+    """Chunk 64, one key head over its two value heads of 128 (one program
+    works both), bfloat16-valued float32 operands as the probe hands them:
+    the row stacks of 128 form, and at 2 x 512 + 64 steps dL/dS and what
+    the backward keeps in VMEM (T, D, K S, M, the decays) cross a block's
+    edge and a padded tail."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    args = _inputs(2 * 512 + 64, True, hk=1, hv=2, dk=128, dv=128)
+    want = gd.gated_delta_reference(*args)
+    got = gd.gated_delta_rule(*args)
+    assert _rel(got, want) < 3e-5
+    w = jax.random.normal(jax.random.key(9), want.shape)
+    grads = [jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
+                      argnums=(0, 1, 2, 3, 4))(*args)
+             for f in (gd.gated_delta_rule, gd.gated_delta_reference)]
+    for name, mine, ref in zip("q k v g beta".split(), *grads):
+        assert _rel(mine, ref) < 5e-5, name
+    assert any(p.startswith("chunk64,heads2over1,dk128,dv128,state_f32,"
+                            "bwd_pallas,passes")
+               for p in dispatch.taken()["gated_delta_rule.plan"])
+
+
+def _dots(f, *args):
+    return gd._count_dots(jax.make_jaxpr(f)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("kernel,ceiling,parent", [
+    ("forward", 32, 44), ("backward", 70, 118)])
+def test_a_chunk_s_mxu_passes_at_the_cell_s_shapes(kernel, ceiling, parent,
+                                                   monkeypatch):
+    """The dot_generals of a kernel's traced body a chunk a value head at
+    chunk 64, d_k = d_v = 128, bfloat16 q / k / v, two value heads a
+    program (the parent's: 44 and 118), counted here from the lines the
+    kernel runs; the plan string says the same numbers."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    sds = jax.ShapeDtypeStruct
+    qk, v, row, S = (sds((64, 128), bf16), sds((64, 128), bf16),
+                     sds((1, 64), f32), sds((128, 128), f32))
+
+    def walk(q):        # both heads of a program, K K^T (and Q K^T) once
+        def f(q_, k, v, rows, S):
+            return [(c.get("o"), S) for c, S in gd._heads_walk(
+                q_ if q else None, k, [v, v], rows, rows, [S, S])]
+        return _dots(f, qk, qk, v, sds((2, 1, 64), f32), S) / 2
+
+    if kernel == "forward":
+        counted = walk(True)
+    else:               # its walk forward (no o), then its walk back
+        kept = gd._abstract_chunk(64, 128, 128, bf16, bf16)[1]
+        counted = walk(False) + _dots(gd._chunk_backward, qk, qk, v, row, row,
+                                      S, kept, v, S)
+    assert counted <= ceiling < parent
+    passes = gd.mxu_passes(64, 128, 128, jnp.dtype(bf16), jnp.dtype(bf16), 2)
+    assert dict(zip(("forward", "backward"), passes))[kernel] == counted
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(dispatch, "_taken", {})
+    shapes = [sds((1, 64, 1, 128), bf16)] * 2 + [
+        sds((1, 64, 2, 128), bf16)] + [sds((1, 64, 2), f32)] * 2
+    jax.eval_shape(lambda *a: gd.gated_delta_rule(*a), *shapes)
+    plan, = dispatch.taken()["gated_delta_rule.plan"]
+    assert plan.endswith(f",bwd_pallas,passes{passes[0]:g}+{passes[1]:g}")
+
+
 def test_the_decay_and_beta_are_felt():
     """What the comparison above would not see if they were not: without
     the decay, or with beta = 1, the output is another."""
@@ -89,13 +154,15 @@ def test_bfloat16_operands_keep_a_float32_state(monkeypatch):
     sound = _rel(got.astype(jnp.float32), want)
     assert sound < 4e-3
     plans = dispatch.taken()["gated_delta_rule.plan"]
-    assert f"chunk{CHUNK},heads4over2,dk16,dv32,state_f32,bwd_pallas" in plans
+    assert any(p.startswith(f"chunk{CHUNK},heads4over2,dk16,dv32,state_f32,"
+                            "bwd_pallas,passes") for p in plans)
 
 
 def test_the_nilpotent_product_is_the_inverse():
     """(I + A)^-1 of a strictly lower triangular A by the product (I - A)
     (I + A^2)(I + A^4) .. against a triangular solve: exact to rounding,
-    also where A's entries are as large as the rule's can be."""
+    also where A's entries are as large as the rule's can be; at n 64 six
+    float32 products, each three MXU passes (the parent's: ten)."""
     for n, scale in ((16, 1.0), (64, 0.1), (64, 0.3)):
         a = jnp.tril(scale * jax.random.normal(jax.random.key(n), (n, n)),
                      -1)
@@ -104,3 +171,5 @@ def test_the_nilpotent_product_is_the_inverse():
         got = gd._unit_lower_inverse(a)
         assert _rel(got, want) < 1e-4, (n, scale)
         assert float(jnp.abs(jnp.triu(got, 1)).max()) == 0.0
+    a = jax.ShapeDtypeStruct((64, 64), jnp.float32)
+    assert _dots(gd._unit_lower_inverse, a) == 6 * 3

@@ -1154,7 +1154,8 @@ def test_cell_gated_delta_kernels_compile_and_keep_the_faces_readers_find(
     taken = gd.dispatch.taken()
     assert taken["gated_delta_rule"] == {"pallas": 2}
     assert list(taken["gated_delta_rule.plan"]) == [
-        "chunk64,heads32over16,dk128,dv128,state_f32,bwd_pallas"]
+        "chunk64,heads32over16,dk128,dv128,state_f32,bwd_pallas,"
+        "passes28.5+50.5"]
 
 
 def test_cell_head_256_flash_compiles_and_keeps_the_face_its_reader_finds(
@@ -1212,7 +1213,8 @@ def test_cell_gdn_moe_step_program_fits_a_v5e(step_program):
     # the grouped kernels, twelve at each of the layer's two buffer sizes.
     assert compiled.as_text().count("tpu_custom_call") == 2 * 3 + 2 * 2 * 12
     assert list(taken["gated_delta_rule.plan"]) == [
-        "chunk64,heads32over16,dk128,dv128,state_f32,bwd_pallas"]
+        "chunk64,heads32over16,dk128,dv128,state_f32,bwd_pallas,"
+        "passes28.5+50.5"]
     assert [p.split(",dead")[1] for p in taken["flash_attention.plan"]] == [
         "6/6%,rope_in_kernel,operands_bshd,heads1x256"]
     assert list(taken["gdn_moe.rope"]) == [
