@@ -39,6 +39,13 @@ OPTIMIZER = "optimizer"          # everything of the step behind the gradient
 SCOPES = (ATTN_FULL, ATTN_SLIDING, ATTN_CROSS, ATTN_GATE, MLA_PROJECT, SSM,
           GMU, MLP, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE, EMBED,
           LOSS, OPTIMIZER)
+# A name INSIDE one of the scopes above, which decides no part: what
+# compressed convolutional attention does between its projections and the
+# kernels (the shifted value half, both convolutions, the q-k mean, the l2
+# norm and temperature).  Its operations stay `attn.full`'s for whoever
+# tiles the step by SCOPES; a reader that wants them alone finds the name
+# in `op_name`.
+ATTN_MIX = "attn.mix"
 
 
 def embed_tokens(tok_embed, tokens, dtype):

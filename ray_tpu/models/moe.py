@@ -1,7 +1,8 @@
 """Mixture-of-experts FFN: the GSPMD capacity layer (`moe_ffn`, the dense
 block's and decoding's) and the dropless layer that holds one chip's share of
-the experts (`sigmoid_route`, `softmax_route`, `routed_experts`:
-latent_moe.py's and swa_moe.py's).
+the experts (`sigmoid_route`, `softmax_route` and the selection they share
+with cca_moe.py's router, `select_experts`; `routed_experts`: the four
+expert model files').
 
 Greenfield capability (SURVEY.md §2.4 — expert parallelism is absent from
 the reference; the TPU-native target is an expert mesh axis + all_to_all).
@@ -214,22 +215,47 @@ def _combine_bwd(res, dy):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def select_experts(scores, select_bias, *, num_experts_per_token: int,
+                   gate_rule: str, scale: float = 1.0):
+    """The selection every router here ends in: scores [T, experts] float32
+    (a sigmoid's or a softmax's, whatever made them) -> a token's experts,
+    the top k of scores + bias (the bias, where there is one, enters the
+    selection only and gets no gradient; with none the top k of the scores
+    themselves), and its gates by the stated rule: "renormalised",
+    scores[sel] / sum(scores[sel]) x scale, or "raw", scores[sel] as they
+    are (at k = 1 a renormalised gate is the constant `scale` and the
+    router behind it gets no gradient).
+    -> (expert index [T, k] int32, gates [T, k] float32)."""
+    if select_bias is None:
+        picked, idx = jax.lax.top_k(scores, num_experts_per_token)
+    else:
+        _, idx = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)),
+            num_experts_per_token)
+        picked = jnp.take_along_axis(scores, idx, axis=-1)
+    if gate_rule == "renormalised":
+        gates = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
+    elif gate_rule == "raw":
+        gates = picked
+    else:
+        raise ValueError(f"unknown gate_rule {gate_rule!r}; expected "
+                         "'renormalised' or 'raw'")
+    return idx.astype(jnp.int32), gates
+
+
 def sigmoid_route(x, router_w, select_bias, *, num_experts_per_token: int,
                   scale: float):
     """The bias-corrected sigmoid router: s = sigmoid(x W_r) in float32 at
     full matmul precision (a bfloat16 pass flips near-ties); a token's
     experts are the top k of s + bias; its gates s[sel] / sum(s[sel]) x
-    scale.  The bias enters the selection only and gets no gradient.
+    scale (`select_experts`).
     -> (expert index [T, k] int32, gates [T, k] float32)."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(
-        scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)),
-        num_experts_per_token)
-    picked = jnp.take_along_axis(scores, idx, axis=-1)
-    gates = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
-    return idx.astype(jnp.int32), gates
+    return select_experts(
+        scores, select_bias, num_experts_per_token=num_experts_per_token,
+        gate_rule="renormalised", scale=scale)
 
 
 def softmax_route(x, router_w, *, num_experts_per_token: int, scale: float):
@@ -241,9 +267,9 @@ def softmax_route(x, router_w, *, num_experts_per_token: int, scale: float):
     probs = jax.nn.softmax(jnp.dot(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST), axis=-1)
-    picked, idx = jax.lax.top_k(probs, num_experts_per_token)
-    gates = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
-    return idx.astype(jnp.int32), gates
+    return select_experts(
+        probs, None, num_experts_per_token=num_experts_per_token,
+        gate_rule="renormalised", scale=scale)
 
 
 def routed_experts(x, idx, gates, w_gate, w_up, w_down, *,
