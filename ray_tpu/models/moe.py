@@ -19,12 +19,13 @@ Aux load-balancing loss per Switch Transformers (Fedus et al.):
 from __future__ import annotations
 
 import math
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import common
+from ray_tpu.ops import dispatch, row_gather
 from ray_tpu.parallel.sharding import with_logical_constraint
 
 
@@ -143,73 +144,121 @@ def moe_ffn_gather(x, router_w, w_gate, w_up, w_down, *,
 # expert matmuls are grouped matmuls, which cost by the rows that are there.
 #
 # Rows move by GATHER in both directions (an XLA scatter of a hundred
-# thousand rows is a serial loop on the TPU): placing rows in expert order
-# reads x at `src`, its transpose reads the sorted gradient at `pos`; the
-# weighted sum back reads the experts' output at `pos`, its transpose reads
-# dy at `src`.  `pos` [T, k] is where an assignment's row lies, `src` [rows]
-# which assignment a row holds: one is the other's inverse, the first from a
-# running count an expert, the second from a stable sort by expert.
+# thousand rows is a serial loop on the TPU), and all four movers are one
+# function, ops/row_gather.py's `gather_sum`: an out row names the table's
+# rows it takes.  Placing rows in expert order reads x by the BUFFER (a row
+# takes its token, one slot a row), and so does its transpose's transpose,
+# dy at every row's token; the weighted sum back reads the experts' output
+# by the TOKEN (its held assignments' rows, k slots a token of which this
+# chip's experts fill a few), and so does placing's transpose.  By the
+# token, XLA's k gathers cost by the slots; the kernel costs by the rows
+# that are there (`gather_sum` takes it where an out row has slots to
+# spare, k > 1; at one slot a row XLA's one gather stays).  `pos` [T, k] is
+# where an assignment's row lies, `src` [rows] which assignment a row
+# holds: one is the other's inverse, the first from a running count an
+# expert, the second from a stable sort by expert.
 
-def _sum_rows_at(table, pos, held, weights=None):
-    """sum over a token's k assignments of (weights x) table [rows, h] at
-    pos [T, k], where held -> [T, h] float32.  A select, not a product:
-    rows nobody placed hold nothing defined.  A gather a slot: one gather
-    of [T, k] rows would be re-laid for its k-long axis before the sum."""
-    total = 0.0
-    for slot in range(pos.shape[1]):
-        picked = table[jnp.minimum(pos[:, slot], table.shape[0] - 1)]
-        picked = picked.astype(jnp.float32)
-        if weights is not None:
-            picked = picked * weights[:, slot, None]
-        total = total + jnp.where(held[:, slot, None], picked, 0.0)
-    return total
+class _Lists(NamedTuple):
+    """How the rows lie, read both ways (every leaf integer or bool)."""
+    src: jax.Array          # [rows] the assignment (token * k + slot) a row
+    #                         of the buffer holds
+    row_valid: jax.Array    # [rows] bool: a row some assignment fills
+    pos: jax.Array          # [T, k] the buffer row of an assignment
+    held: jax.Array         # [T, k] bool: an assignment this chip computes
+    gap: jax.Array          # [T * k] the slots NOT held before it, in its token
+    held_rows: jax.Array    # [T * k] pos, a token's held assignments first
+    held_count: jax.Array   # [T] how many a token has
+
+
+def _shifted(a, n: int):
+    """a [m] -> b [m], b[i] = a[i + n]; zero where a has no such entry."""
+    if n == 0:
+        return a
+    fill = jnp.zeros((abs(n),), a.dtype)
+    return jnp.concatenate([a[n:], fill] if n > 0 else [fill, a[:n]])
+
+
+def _slots_before(flags, k: int):
+    """flags [T * k] bool, a token's k slots together -> how many of the
+    slots BEFORE it in its token are set, int32.  Shifts of the flat list:
+    a [T, k] array would lie k to a tile of 128 lanes."""
+    slot = jnp.arange(flags.shape[0], dtype=jnp.int32) % k
+    flags = flags.astype(jnp.int32)
+    return sum(jnp.where(slot >= back, _shifted(flags, -back), 0)
+               for back in range(1, k)) + jnp.zeros_like(flags)
+
+
+def _held_first(values, held, gap, k: int):
+    """values [T * k] -> the same, each token's held slots moved to the
+    front of its k in their order (what lies behind them is not defined):
+    a held slot moves down by the slots not held before it."""
+    out = jnp.zeros_like(values)
+    for down in range(k):
+        out = jnp.where(_shifted(held & (gap == down), down),
+                        _shifted(values, down), out)
+    return out
+
+
+def _rows_at_tokens(table, lists: _Lists):
+    """table [T, h] -> [rows, h]: every buffer row its token's row of the
+    table, padding rows zero."""
+    k = lists.pos.shape[1]
+    return row_gather.gather_sum(table, lists.src // k,
+                                 lists.row_valid.astype(jnp.int32))
+
+
+def _sum_held_rows(table, lists: _Lists, gates=None):
+    """table [rows, h] -> [T, h] in its dtype: the sum over a token's held
+    assignments, in slot order and in float32, of (gate x) its row."""
+    k = lists.pos.shape[1]
+    weights = None if gates is None else _held_first(
+        gates.reshape(-1).astype(jnp.float32), lists.held.reshape(-1),
+        lists.gap, k)
+    return row_gather.gather_sum(table, lists.held_rows, lists.held_count,
+                                 weights)
 
 
 @jax.custom_vjp
-def _place(x, src_token, row_valid, pos, held):
+def _place(x, lists: _Lists):
     """x [T, h] -> the rows in expert order [rows, h]; padding rows zero."""
-    return jnp.where(row_valid[:, None], x[src_token],
-                     jnp.zeros((), x.dtype))
+    return _rows_at_tokens(x, lists)
 
 
-def _place_fwd(x, src_token, row_valid, pos, held):
-    return _place(x, src_token, row_valid, pos, held), (pos, held)
+def _place_fwd(x, lists):
+    return _place(x, lists), lists
 
 
-def _place_bwd(res, d_rows):
-    pos, held = res
-    return (_sum_rows_at(d_rows, pos, held).astype(d_rows.dtype),
-            None, None, None, None)
+def _place_bwd(lists, d_rows):
+    return _sum_held_rows(d_rows, lists), None
 
 
 _place.defvjp(_place_fwd, _place_bwd)
 
 
 @jax.custom_vjp
-def _combine(out, gates, pos, held, src, row_valid):
+def _combine(out, gates, lists: _Lists):
     """sum over a token's held assignments of gate x its row of `out`
     [rows, h] -> [T, h] in out's dtype, summed in float32."""
-    return _sum_rows_at(out, pos, held, gates).astype(out.dtype)
+    return _sum_held_rows(out, lists, gates)
 
 
-def _combine_fwd(out, gates, pos, held, src, row_valid):
-    return (_combine(out, gates, pos, held, src, row_valid),
-            (out, gates, pos, held, src, row_valid))
+def _combine_fwd(out, gates, lists):
+    return _combine(out, gates, lists), (out, gates, lists)
 
 
 def _combine_bwd(res, dy):
-    out, gates, pos, held, src, row_valid = res
-    k = gates.shape[1]
+    out, gates, lists = res
     # dy at every row's token, read once: times the row's gate it is the
     # row's cotangent, times the row itself the gate's
-    dy_rows = dy[src // k].astype(jnp.float32)
-    d_out = jnp.where(row_valid[:, None],
-                      dy_rows * gates.reshape(-1)[src][:, None],
+    dy_rows = _rows_at_tokens(dy, lists).astype(jnp.float32)
+    d_out = jnp.where(lists.row_valid[:, None],
+                      dy_rows * gates.reshape(-1)[lists.src][:, None],
                       0.0).astype(out.dtype)
     d_gate_rows = jnp.sum(dy_rows * out.astype(jnp.float32), axis=-1)
-    d_gates = jnp.where(held, d_gate_rows[jnp.minimum(pos, out.shape[0] - 1)],
-                        0.0)
-    return d_out, d_gates, None, None, None, None
+    d_gates = jnp.where(
+        lists.held,
+        d_gate_rows[jnp.minimum(lists.pos, out.shape[0] - 1)], 0.0)
+    return d_out, d_gates, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -289,9 +338,14 @@ def routed_experts(x, idx, gates, w_gate, w_up, w_down, *,
     bound, static), int32 / float32 scalars.
 
     The buffer of rows in expert order is as long as the bound, tokens x
-    min(k, count), which the routing cannot exceed; XLA's gathers, the
-    index arithmetic and the SwiGLU between the grouped matmuls cost by the
-    buffer, not by the rows in it.  `usual_rows`, where given and under the
+    min(k, count), which the routing cannot exceed.  The movers by the token
+    (the sum back, placing's transpose) cost by the rows that are there
+    where k > 1 (`ops/row_gather.py`; `dispatch.taken()["routed_experts"]`
+    says which way they went, `["routed_experts.plan"]` the slots, the
+    buffer and the bound of each static size traced); the movers by the
+    buffer (XLA's one gather), the index arithmetic and the SwiGLU between
+    the grouped matmuls cost by the buffer, not by the rows in it.
+    `usual_rows`, where given and under the
     bound: a step whose rows fit that many takes a buffer of that length
     instead (`lax.cond` on the count: the same computation at two static
     sizes, every row computed in either).
@@ -319,25 +373,38 @@ def routed_experts(x, idx, gates, w_gate, w_up, w_down, *,
         # buffer holds them, less the padding between groups
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
 
+        # a token's held assignments first among its k slots: the lists
+        # the movers by the token walk
+        gap = _slots_before(~held.reshape(-1), k)
+        held_count = jnp.sum(held, axis=1, dtype=jnp.int32)
+
     def through_the_experts(rows_bound: int):
         """The layer over a buffer that holds `rows_bound` rows."""
         rows = gm.layout_rows(rows_bound, count, tile_m)
+        way = row_gather.path(x.shape[1], k, dtype)
+        dispatch.record("routed_experts", way)
+        dispatch.record(
+            "routed_experts.plan",
+            f"{'rows_by_gather' if way == 'xla' else 'rows_by_index'},"
+            f"slots{tokens * k},buffer{rows},entries<={rows_bound}")
         with jax.named_scope(common.MOE_DISPATCH):
             layout = gm.group_layout(sizes, rows, tile_m)
-            pos = (layout.starts[jnp.minimum(key, count - 1)] + rank
-                   ).reshape(tokens, k)
+            pos = layout.starts[jnp.minimum(key, count - 1)] + rank
             row_group, row_valid = gm.row_groups(layout)
             packed = (jnp.cumsum(sizes) - sizes)[row_group] + (
                 jnp.arange(rows, dtype=jnp.int32) - layout.starts[row_group])
             src = order[jnp.clip(packed, 0, tokens * k - 1)]
-            rows_in = _place(x.astype(dtype), src // k, row_valid, pos, held)
+            lists = _Lists(src, row_valid, pos.reshape(tokens, k), held, gap,
+                           _held_first(pos, held.reshape(-1), gap, k),
+                           held_count)
+            rows_in = _place(x.astype(dtype), lists)
         with jax.named_scope(common.MOE_EXPERTS):
             gate_h = gm.grouped_matmul(rows_in, w_gate.astype(dtype), layout)
             up_h = gm.grouped_matmul(rows_in, w_up.astype(dtype), layout)
             out = gm.grouped_matmul(jax.nn.silu(gate_h) * up_h,
                                     w_down.astype(dtype), layout)
         with jax.named_scope(common.MOE_COMBINE):
-            return _combine(out, gates, pos, held, src, row_valid)
+            return _combine(out, gates, lists)
 
     rows_held = jnp.sum(sizes)
     if usual_rows is None or usual_rows >= bound:

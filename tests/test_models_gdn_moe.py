@@ -144,6 +144,71 @@ def test_the_shares_add_up_to_the_uncut_layer():
         > 1e-3
 
 
+@pytest.mark.parametrize("usual_rows,side", [(48, "the usual buffer"),
+                                             (4, "the bound's buffer")])
+def test_the_softmax_routed_share_by_index_equals_the_gathers(
+        usual_rows, side, monkeypatch):
+    """The cell's routing (softmax, renormalised top k) over a share of the
+    experts, bfloat16 rows of a width the kernel takes: value and gradients
+    with the rows moved by ops/row_gather.py's kernel (interpreted) equal
+    those by XLA's gathers to bfloat16's rounding, on the named side of the
+    `lax.cond`; a token whose k experts are all held, an expert with no row."""
+    from ray_tpu.models import moe
+    from ray_tpu.ops import row_gather
+
+    tokens, width, inner, k = 32, 128, 64, 2
+    ks = jax.random.split(jax.random.PRNGKey(31), 6)
+    h = jax.random.normal(ks[0], (tokens, width), jnp.bfloat16)
+    # eight experts, 2..4 held; expert 4 is never chosen, token 0 chooses 2, 3
+    router_w = jax.random.normal(ks[1], (width, 8)) / 8
+    router_w = router_w.at[:, 4].set(0.0)
+    h = h.at[0].set((40 * (router_w[:, 2] + router_w[:, 3])
+                     ).astype(jnp.bfloat16))
+    weights = (jax.random.normal(ks[2], (3, width, inner), jnp.bfloat16) / 8,
+               jax.random.normal(ks[3], (3, width, inner), jnp.bfloat16) / 8,
+               jax.random.normal(ks[4], (3, inner, width), jnp.bfloat16) / 8)
+    mix = jax.random.normal(ks[5], h.shape, jnp.float32)
+    def route(h, router_w):
+        probs = jax.nn.softmax(jnp.dot(
+            h.astype(jnp.float32), router_w,
+            precision=jax.lax.Precision.HIGHEST)
+            - 1e3 * (jnp.arange(8) == 4), axis=-1)
+        return moe.select_experts(probs, None, num_experts_per_token=k,
+                                  gate_rule="renormalised")
+
+    idx, _ = route(h, router_w)
+    assert sorted(np.asarray(idx[0])) == [2, 3] and not bool(
+        jnp.any(idx == 4))
+
+    def layer(h, router_w, weights):
+        _, gates = route(h, router_w)
+        y, stats = moe.routed_experts(
+            h, idx, gates, *weights, experts_held=(2, 3),
+            dtype=jnp.bfloat16, tile_m=16, usual_rows=usual_rows)
+        return jnp.sum(y.astype(jnp.float32) * mix), (y, stats)
+
+    def run():
+        (_, (y, stats)), grads = jax.value_and_grad(
+            layer, argnums=(0, 1, 2), has_aux=True)(h, router_w, weights)
+        return y, stats, grads
+
+    y, stats, grads = run()
+    held = int(stats["rows_held"])
+    assert 4 < held <= 48
+    assert (held <= usual_rows) == (side == "the usual buffer")
+    assert row_gather.path(width, k) == "interpret"
+    monkeypatch.setattr(row_gather, "_use_pallas", lambda *a: False)
+    y_x, _, grads_x = run()
+    for got, want in zip(jax.tree.leaves((y, grads)),
+                         jax.tree.leaves((y_x, grads_x))):
+        np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                                   np.asarray(want.astype(jnp.float32)),
+                                   atol=2 ** -6, rtol=2 ** -6)
+    # the expert nobody chose gets no dw; the router feels the held gates
+    assert not np.asarray(grads[2][0].astype(jnp.float32))[2].any()
+    assert np.asarray(grads[1]).any()
+
+
 def test_the_quarter_rope_reaches_the_kernels_by_columns_and_a_tail():
     """`_rotary_first` on a head's published columns and `kernel_tables`:
     the kernels' whole-head turn (pair i with i + d/2) of the reordered

@@ -194,6 +194,77 @@ def test_both_buffer_sizes_compute_every_row(usual_rows, side):
                                    atol=1e-5, rtol=1e-5)
 
 
+def _held_share_case(dtype, tokens=40, width=128, inner=64, k=3):
+    """16 experts, this chip holds 4..7: token 0 has ALL its k experts held,
+    expert 7 holds no row, some tokens have none held.  A width the kernel
+    takes (a whole lane tile)."""
+    rng = np.random.default_rng(21)
+    idx = np.stack([rng.permutation(16)[:k] for _ in range(tokens)])
+    idx[idx == 7] = 12
+    idx[0] = [6, 4, 5]
+    idx[1] = [0, 1, 2]
+    ks = jax.random.split(jax.random.PRNGKey(22), 5)
+    h = jax.random.normal(ks[0], (tokens, width), dtype)
+    gates = jax.random.uniform(ks[1], (tokens, k), jnp.float32, 0.1, 1.0)
+    weights = (jax.random.normal(ks[2], (4, width, inner), dtype) / 8,
+               jax.random.normal(ks[3], (4, width, inner), dtype) / 8,
+               jax.random.normal(ks[4], (4, inner, width), dtype) / 8)
+    return h, jnp.asarray(idx, jnp.int32), gates, weights
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("usual_rows,side", [(64, "the usual buffer"),
+                                             (8, "the bound's buffer")])
+def test_the_movers_by_index_equal_the_gathers(usual_rows, side, dtype,
+                                               monkeypatch):
+    """`routed_experts`' value, dx, dw and d_gates with the rows moved by
+    ops/row_gather.py's kernel (interpreted) equal those with XLA's gathers,
+    to the dtype's rounding, on both sides of the `lax.cond`."""
+    from ray_tpu.ops import row_gather
+
+    h, idx, gates, weights = _held_share_case(dtype)
+    mix = jax.random.normal(jax.random.PRNGKey(23), h.shape, jnp.float32)
+
+    def layer(h, gates, weights):
+        y, stats = moe.routed_experts(
+            h, idx, gates, *weights, experts_held=(4, 4), dtype=dtype,
+            tile_m=16, usual_rows=usual_rows)
+        return jnp.sum(y.astype(jnp.float32) * mix), (y, stats)
+
+    def run():
+        monkeypatch.setattr(moe.dispatch, "_taken", {})
+        (_, (y, stats)), grads = jax.value_and_grad(
+            layer, argnums=(0, 1, 2), has_aux=True)(h, gates, weights)
+        return y, stats, grads, moe.dispatch.taken()
+
+    y, stats, grads, taken = run()
+    assert set(taken["routed_experts"]) == {"interpret"}
+    assert set(taken["routed_experts.plan"]) == {
+        "rows_by_index,slots120,buffer192,entries<=120",
+        f"rows_by_index,slots120,buffer{(-(-usual_rows // 16) + 4) * 16},"
+        f"entries<={usual_rows}"}
+    held = int(stats["rows_held"])
+    assert (held <= usual_rows) == (side == "the usual buffer"), held
+    monkeypatch.setattr(row_gather, "_use_pallas", lambda *a: False)
+    y_x, _, grads_x, taken = run()
+    assert set(taken["routed_experts"]) == {"xla"}
+    assert all(p.startswith("rows_by_gather,") for p in
+               taken["routed_experts.plan"])
+    tol = dict(atol=1e-5, rtol=1e-5) if dtype == jnp.float32 else dict(
+        atol=2 ** -6, rtol=2 ** -6)
+    for got, want in zip(jax.tree.leaves((y, grads)),
+                         jax.tree.leaves((y_x, grads_x))):
+        np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                                   np.asarray(want.astype(jnp.float32)),
+                                   **tol)
+    # the gate of an assignment held elsewhere gets nothing; token 0's three
+    # all get something; the expert with no row gets no dw
+    d_gates = np.asarray(grads[1])
+    here = (np.asarray(idx) >= 4) & (np.asarray(idx) < 8)
+    assert not d_gates[~here].any() and d_gates[0].all()
+    assert not np.asarray(grads[2][0].astype(jnp.float32))[3].any()
+
+
 def test_the_bias_selects_and_is_not_in_the_gate():
     config = _f32()
     w = jax.random.normal(jax.random.PRNGKey(9), (config.hidden_size, 16))

@@ -54,8 +54,8 @@ def _compiled_text(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-def _custom_calls_as_traced(fn, *shapes):
-    """The compiled module's Mosaic custom-call lines, printed the way
+def _custom_calls_of(compiled):
+    """A compiled module's Mosaic custom-call lines, printed the way
     the profiler names an operation in a trace: result and operand
     shapes, no layouts."""
     from jax._src.lib import _jax
@@ -64,9 +64,12 @@ def _custom_calls_as_traced(fn, *shapes):
     opts.print_operand_shape = True
     opts.include_layout_in_shapes = False
     opts.print_backend_config = False
-    compiled = jax.jit(fn).lower(*shapes).compile()
     text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
     return [l for l in text.splitlines() if "tpu_custom_call" in l]
+
+
+def _custom_calls_as_traced(fn, *shapes):
+    return _custom_calls_of(jax.jit(fn).lower(*shapes).compile())
 
 
 @pytest.mark.parametrize("table_width", [2, 16])
@@ -948,6 +951,70 @@ def test_cell_grouped_matmul_kernels_compile_and_keep_their_faces(
         "tile256x2048,rows102400,groups16", "tile256x768,rows102400,groups16"]
 
 
+def _every_face():
+    """name -> pattern: every face a reader of the expert cells looks for."""
+    from benchmark import gdn_faces, moe_faces, swa_moe_faces
+
+    return {f"{m.__name__}.{n}": p for m in (moe_faces, gdn_faces,
+                                             swa_moe_faces)
+            for n, p in vars(m).items()
+            if n.isupper() and n[0] != "_" and isinstance(p, str)}
+
+
+def _grouped_calls(calls):
+    """How many of a program's custom calls the grouped readers find."""
+    import re
+
+    faces = _moe_faces()
+    return sum(bool(re.search(p, l)) for l in calls
+               for p in (faces.GROUPED_FORWARD, faces.GROUPED_TRANSPOSED,
+                         faces.GROUPED_DW))
+
+
+# cell -> tokens, k, width, the usual buffer's rows and the bound's
+ROW_GATHER_CELLS = {
+    "train-gdn-moe-d4": (24_576, 10, 2048, 69_632, 253_952),
+    "train-moe-mla-d6": (16_384, 6, 2048, 28_672, 102_400),
+    "train-swa-moe-d5": (8_192, 10, 3072, 12_288, 67_584),
+    "train-cca-moe-d4": (8_192, 1, 2048, 12_288, 12_288),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ROW_GATHER_CELLS))
+def test_cell_row_gather_kernel_compiles_and_wears_no_readers_face(
+        cell, one_chip, monkeypatch):
+    """ops/row_gather.py at each expert cell's sizes: the sum back (a token's
+    k slots, weighted, from either buffer) and placing's transpose (the
+    same, unweighted) compile for a v5e where k > 1, ONE custom call each
+    that none of the readers' patterns finds; at k = 1 (a slot is a row) no
+    kernel is made: XLA's gather stays."""
+    import re
+
+    from ray_tpu.ops import row_gather as rg
+
+    _on_tpu(monkeypatch, rg)
+    tokens, k, h, usual, bound = ROW_GATHER_CELLS[cell]
+    assert {"train-gdn-moe-d4": 245_760, "train-moe-mla-d6": 98_304,
+            "train-swa-moe-d5": 81_920}.get(cell, tokens) == tokens * k
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    faces = _every_face()
+    assert len(faces) == 4 + 3 + 3
+    for buffer in sorted({usual, bound}):
+        lists = (sds((buffer, h), jnp.bfloat16),
+                 sds((tokens * k,), jnp.int32), sds((tokens,), jnp.int32))
+        for weights in ((sds((tokens * k,), jnp.float32),), ()):
+            calls = _custom_calls_as_traced(rg.gather_sum, *lists, *weights)
+            assert len(calls) == (k > 1), calls
+            for line in calls:
+                assert f"= bf16[{tokens},{h}] custom-call(s32[" in line
+                assert not [n for n, p in faces.items()
+                            if re.search(p, line)], line
+    assert rg.path(h, k) == ("pallas" if k > 1 else "xla")
+
+
 def test_cell_latent_moe_step_program_fits_a_v5e(step_program):
     """The cell's whole step program (a dense and five expert layers, 16 of
     128 experts, an eighth of the vocabulary, 2 x 8192 tokens, full remat,
@@ -961,8 +1028,15 @@ def test_cell_latent_moe_step_program_fits_a_v5e(step_program):
     # (3).  The five expert layers are ONE scanned body: those three and
     # the grouped kernels, three forward, three again for the backward,
     # three transposed and three dw (12), at each of the layer's two
-    # buffer sizes (the usual and the full bound: a cond's two sides).
-    assert compiled.as_text().count("tpu_custom_call") == 3 + 3 + 2 * 12
+    # buffer sizes (the usual and the full bound: a cond's two sides), and
+    # the two movers by the token (PR 45: the weighted sum back, placing's
+    # transpose; remat's second sum back feeds nothing and is not compiled).
+    assert compiled.as_text().count("tpu_custom_call") == 3 + 3 + 2 * (12 + 2)
+    assert _grouped_calls(_custom_calls_of(compiled)) == 2 * 12
+    assert set(taken["routed_experts"]) == {"pallas"}
+    assert sorted(taken["routed_experts.plan"]) == [
+        "rows_by_index,slots98304,buffer102400,entries<=98304",
+        "rows_by_index,slots98304,buffer28672,entries<=24576"]
     # and the attention calls are the ones that take latent attention's parts
     assert all(p.endswith(",dqk192,dv128,latent_parts,rope_in_kernel64of192")
                for p in taken["flash_attention.plan"])
@@ -1089,8 +1163,14 @@ def test_cell_swa_moe_step_program_fits_a_v5e(step_program):
     assert 13.0 * 2 ** 30 < total < 15.75 * 2 ** 30, total / 2 ** 30
     # Three segments, each flash forward, forward again under remat and
     # backward (3); the two with experts also the grouped kernels, twelve
-    # at each of the layer's two buffer sizes (a cond's two sides).
-    assert compiled.as_text().count("tpu_custom_call") == 3 * 3 + 2 * 2 * 12
+    # at each of the layer's two buffer sizes (a cond's two sides) and the
+    # two movers by the token beside them (PR 45).
+    assert compiled.as_text().count("tpu_custom_call") == (
+        3 * 3 + 2 * 2 * (12 + 2))
+    assert _grouped_calls(_custom_calls_of(compiled)) == 2 * 2 * 12
+    assert set(taken["routed_experts"]) == {"pallas"}
+    assert all(p.startswith("rows_by_index,slots81920,buffer")
+               for p in taken["routed_experts.plan"])
     assert sorted(p.split(",dead")[1] for p in
                   taken["flash_attention.plan"]) == [
         "50/50%,window512,visited12.1%,rope_in_kernel,operands_bshd,"
@@ -1210,8 +1290,15 @@ def test_cell_gdn_moe_step_program_fits_a_v5e(step_program):
     assert 13.0 * 2 ** 30 < total < 15.75 * 2 ** 30, total / 2 ** 30
     # The linear segment: the rule's forward, its forward again under remat
     # and its backward (3); the full segment the flash three; each segment
-    # the grouped kernels, twelve at each of the layer's two buffer sizes.
-    assert compiled.as_text().count("tpu_custom_call") == 2 * 3 + 2 * 2 * 12
+    # the grouped kernels, twelve at each of the layer's two buffer sizes,
+    # and the two movers by the token beside them (PR 45).
+    assert compiled.as_text().count("tpu_custom_call") == (
+        2 * 3 + 2 * 2 * (12 + 2))
+    assert _grouped_calls(_custom_calls_of(compiled)) == 2 * 2 * 12
+    assert set(taken["routed_experts"]) == {"pallas"}
+    assert sorted(taken["routed_experts.plan"]) == [
+        "rows_by_index,slots245760,buffer253952,entries<=245760",
+        "rows_by_index,slots245760,buffer69632,entries<=61440"]
     assert list(taken["gated_delta_rule.plan"]) == [
         "chunk64,heads32over16,dk128,dv128,state_f32,bwd_pallas,"
         "passes28.5+50.5"]
@@ -1357,14 +1444,16 @@ def test_a_remat_layer_of_every_attention_entry_keeps_out_and_lse(
 # tree BEFORE the scopes compiled it (PR 38's, 9d83a62: this file's
 # helpers run on an archive of that commit).  A scope is metadata: it may move no fusion, no schedule
 # and no byte of a kernel.  A change that means to move the program
-# replaces its digest here and says so.
+# replaces its digest here and says so.  PR 45 MEANT TO: the two expert
+# cells' digests are its tree's (the movers by the token are a kernel,
+# ops/row_gather.py); the hybrid's and both dense layers' stand.
 PARENT_HLO_SHA256 = {
     "train-hybrid-d8":
         "3d480d458ec2cf6d978d269f5cdda6a3c7f2dcedd415d84a8be116758340a32b",
     "train-moe-mla-d6":
-        "50d1412f6e37aec18ba66b5bff087340d6e5a6177015296695b19afb2efbc0c9",
+        "a601fb239f5eb1614c9db8af0ab5e12073e0d19356ff06fc13a98fde2bfec779",
     "train-swa-moe-d5":
-        "f8c28ef818489e042a75400d908dc21307792cd49e8e9194b6a0841f8c6e7aa4",
+        "9846286bb91a94b6fbf231d92a1b4299b36e1116472519b01770a01a46a5f0f4",
     "dense-layer.one_chip":
         "f8ed670122d29fe6c37a4e2abeab135595d735282e71449a49a0e737920e6abf",
     "dense-layer.fsdp4":
