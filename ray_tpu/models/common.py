@@ -46,6 +46,10 @@ SCOPES = (ATTN_FULL, ATTN_SLIDING, ATTN_CROSS, ATTN_GATE, MLA_PROJECT, SSM,
 # tiles the step by SCOPES; a reader that wants them alone finds the name
 # in `op_name`.
 ATTN_MIX = "attn.mix"
+# Likewise inside `ssm`: a linear mixer's chain between W_qkv's product and
+# the rule's kernels (ops/mixer_chain.py: conv, SiLU, the l2 norms, the cut
+# into q, k, v) and, in the backward, the sum over a key head's value heads.
+SSM_CHAIN = "ssm.chain"
 
 
 def embed_tokens(tok_embed, tokens, dtype):

@@ -71,11 +71,11 @@ import jax.numpy as jnp
 
 from ray_tpu.models import common, moe
 from ray_tpu.ops import dispatch
+from ray_tpu.ops.mixer_chain import L2_EPS, conv_silu_l2norm
 from ray_tpu.parallel.sharding import with_logical_constraint
 
 F32 = jnp.float32
 FULL, LINEAR = "full_attention", "linear_attention"
-L2_EPS = 1e-6
 # The usual buffer of an expert layer, in rows even routing would send to
 # the held experts (models/swa_moe.py has the reason and its readings).
 USUAL_LOAD = 4
@@ -333,11 +333,6 @@ def _per_head(x, heads: int, fn):
                              .astype(x.dtype))
 
 
-def _l2_normalised(x, heads: int, scale: float = 1.0):
-    return _per_head(x, heads, lambda t: t * (scale * jax.lax.rsqrt(
-        jnp.sum(t * t, axis=-1, keepdims=True) + L2_EPS)))
-
-
 def gated_delta_rule(q, k, v, g, beta, config: GdnMoEConfig):
     """The linear mixer's recurrence ALONE, on its operands as the mixer
     makes them (q, k [b, s, key heads, d_k], normalised; v [b, s, value
@@ -350,28 +345,54 @@ def gated_delta_rule(q, k, v, g, beta, config: GdnMoEConfig):
     return rule(q, k, v, g, beta)
 
 
-def _linear_mixer(u, lp, c: GdnMoEConfig):
-    """u [b, s, hidden], the normed input -> the mixer's output."""
-    b, s, _ = u.shape
+def _linear_mixer(x, lp, c: GdnMoEConfig):
+    """x [b, s, hidden], the layer's input -> the mixer's output."""
+    b, s, _ = x.shape
     hk, dk = c.linear_num_key_heads, c.linear_key_head_dim
     hv, dv = c.linear_num_value_heads, c.linear_value_head_dim
     wide = c.conv_channels
+
+    def normed(x):
+        return with_logical_constraint(
+            zero_centred_norm(x, lp["ln1_w"], c.rms_norm_eps),
+            ("batch", "seq", "embed"))
+
+    def rule_of(x, w_qkv, conv_w, g, beta):
+        qkv = with_logical_constraint(_matmul(normed(x), w_qkv, c),
+                                      ("batch", "seq", "heads"))
+        with jax.named_scope(common.SSM_CHAIN):
+            q, k, v = conv_silu_l2norm(qkv, conv_w, hk, dk,
+                                       1.0 / math.sqrt(dk), L2_EPS)
+        # [b, s, heads x d] on the way out, as the kernel wrote it: a [b, s,
+        # heads, d] view that crosses the checkpoint is re-laid
+        return gated_delta_rule(
+            q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk),
+            v.reshape(b, s, hv, dv), g, beta, c).reshape(b, s, hv * dv)
+
+    if c.remat:
+        # Under the layer's remat qkv, q, k and v are made AGAIN from the
+        # layer's input for the rule's backward, and do not lie on the chip
+        # while the layer's experts work; the rule's o and states are kept,
+        # so its forward kernel is not run again.  XLA's own
+        # rematerialisation did this to the chain while the chain was its
+        # fusions, and cannot do it to a kernel's results: without it the
+        # step program reads 0.7 GiB more and keeps no flash out and lse
+        # (PERF.md, PR 46).
+        from ray_tpu.ops.gated_delta import KEPT_NAMES
+
+        rule_of = jax.checkpoint(
+            rule_of,
+            policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
+    u = normed(x)
     w = lp["w_qkvz"].astype(c.dtype)
-    qkv = with_logical_constraint(_matmul(u, w[:, :wide], c),
-                                  ("batch", "seq", "heads"))
     z = _matmul(u, w[:, wide:], c)
     ba = _matmul(u, lp["w_ba"], c, F32)
-    qkv = jax.nn.silu(common.causal_depthwise_conv(qkv, lp["conv_w"]))
-    q = _l2_normalised(qkv[..., :hk * dk], hk, 1.0 / math.sqrt(dk))
-    k = _l2_normalised(qkv[..., hk * dk:2 * hk * dk], hk)
-    v = qkv[..., 2 * hk * dk:]
     beta = jax.nn.sigmoid(ba[..., :hv])
     g = -jnp.exp(lp["A_log"].astype(F32)) * jax.nn.softplus(
         ba[..., hv:] + lp["dt_bias"].astype(F32))
-    o = gated_delta_rule(q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk),
-                         v.reshape(b, s, hv, dv), g, beta, c)
+    o = rule_of(x, w[:, :wide], lp["conv_w"], g, beta)
     gn_w = lp["gn_w"].astype(F32)
-    y = _per_head(o.reshape(b, s, hv * dv), hv, lambda t: t * jax.lax.rsqrt(
+    y = _per_head(o, hv, lambda t: t * jax.lax.rsqrt(
         jnp.mean(t * t, axis=-1, keepdims=True) + c.rms_norm_eps) * gn_w)
     y = (y.astype(F32) * jax.nn.silu(z.astype(F32))).astype(c.dtype)
     return _matmul(y, lp["wo"], c)
@@ -460,11 +481,14 @@ def _routed_part(flat, router_w, w_gate, w_up, w_down, c: GdnMoEConfig):
 
 def _layer(x, lp, tables, *, kind: str, c: GdnMoEConfig):
     """One layer -> (x, the expert layer's routing counts)."""
-    with jax.named_scope(common.ATTN_FULL if kind == FULL else common.SSM):
-        u = zero_centred_norm(x, lp["ln1_w"], c.rms_norm_eps)
-        u = with_logical_constraint(u, ("batch", "seq", "embed"))
-        mixed = _full_attention(u, lp, tables, c) if kind == FULL \
-            else _linear_mixer(u, lp, c)
+    if kind == FULL:
+        with jax.named_scope(common.ATTN_FULL):
+            u = zero_centred_norm(x, lp["ln1_w"], c.rms_norm_eps)
+            u = with_logical_constraint(u, ("batch", "seq", "embed"))
+            mixed = _full_attention(u, lp, tables, c)
+    else:
+        with jax.named_scope(common.SSM):
+            mixed = _linear_mixer(x, lp, c)
     x = with_logical_constraint(x + mixed, ("batch", "seq", "embed"))
     with jax.named_scope(common.MLP):
         y = zero_centred_norm(x, lp["ln2_w"], c.rms_norm_eps)

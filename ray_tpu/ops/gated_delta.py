@@ -104,6 +104,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops import dispatch
 
@@ -744,23 +745,40 @@ def _rule(q3, k3, v3, G, beta, shapes, chunk):
     return _rule_fwd(q3, k3, v3, G, beta, shapes, chunk, False)[0]
 
 
+# What a caller's `jax.checkpoint` may keep of the rule (`save_only_these_
+# names`): with o and the blocks' first states kept, its backward runs no
+# forward kernel again, only whatever makes q, k and v.
+KEPT_NAMES = ("gated_delta_out", "gated_delta_states")
+
+
 def _rule_vjp_fwd(q3, k3, v3, G, beta, shapes, chunk):
     o, first = _rule_fwd(q3, k3, v3, G, beta, shapes, chunk, True)
+    o, first = (checkpoint_name(a, n) for a, n in zip((o, first), KEPT_NAMES))
     return o, (q3, k3, v3, G, beta, first)
+
+
+def over_group(d, value_heads: int, key_heads: int):
+    """d [b, t, value heads x d_k], a cotangent a VALUE head -> [b, t, key
+    heads x d_k]: a key head's value heads summed in float32, BY WHOLE
+    TILES (`common.by_tiles`: the group a major axis, so the sum is tile
+    adds; the [b, t, key heads, group, d_k] view is re-laid before a sum)."""
+    from ray_tpu.models import common
+
+    tiles = common.by_tiles(d, value_heads)     # [b, t / 8, hv, 8, d_k]
+    b, blocks, _, rows, width = tiles.shape
+    with jax.named_scope(common.SSM_CHAIN):
+        summed = jnp.sum(
+            tiles.reshape(b, blocks, key_heads, value_heads // key_heads,
+                          rows, width), axis=3, dtype=F32)
+        return common.from_tiles(summed.astype(d.dtype))
 
 
 def _rule_vjp_bwd(shapes, chunk, res, do3):
     q3, k3, v3, G, beta, first = res
-    hv, hk, dk, _ = shapes
-    dq, dk_, dv, dG, dbeta = _rule_bwd(q3, k3, v3, G, beta, do3, first,
-                                       shapes, chunk)
-
-    def over_group(d):      # a key head's value heads summed, in float32
-        b, t, _ = d.shape
-        return jnp.sum(d.reshape(b, t, hk, hv // hk, dk), axis=3,
-                       dtype=F32).reshape(b, t, hk * dk).astype(d.dtype)
-
-    return over_group(dq), over_group(dk_), dv, dG, dbeta
+    hv, hk = shapes[:2]
+    dq, dk, dv, dG, dbeta = _rule_bwd(q3, k3, v3, G, beta, do3, first,
+                                      shapes, chunk)
+    return over_group(dq, hv, hk), over_group(dk, hv, hk), dv, dG, dbeta
 
 
 _rule.defvjp(_rule_vjp_fwd, _rule_vjp_bwd)

@@ -251,6 +251,94 @@ def test_the_probe_runs_the_rule_alone_on_the_references_operands():
     assert 1e-4 < err < 2e-2
 
 
+def _the_parents_linear_mixer(u, lp, c):
+    """`gdn_moe._linear_mixer` as PR 45 had it: the chain between W_qkvz and
+    the rule written out in XLA (the conv's shifted adds and SiLU in the
+    compute dtype, the l2 norms by whole tiles, v a slice), the group summed
+    over the view behind the rule.  Kept here as the plain formulation."""
+    import math
+
+    b, s, _ = u.shape
+    hk, dk = c.linear_num_key_heads, c.linear_key_head_dim
+    hv, dv = c.linear_num_value_heads, c.linear_value_head_dim
+    wide = c.conv_channels
+    w = lp["w_qkvz"].astype(c.dtype)
+    qkv = gm._matmul(u, w[:, :wide], c)
+    z = gm._matmul(u, w[:, wide:], c)
+    ba = gm._matmul(u, lp["w_ba"], c, jnp.float32)
+    qkv = jax.nn.silu(common.causal_depthwise_conv(qkv, lp["conv_w"]))
+
+    def l2_normalised(x, scale=1.0):
+        return gm._per_head(x, hk, lambda t: t * (scale * jax.lax.rsqrt(
+            jnp.sum(t * t, axis=-1, keepdims=True) + gm.L2_EPS)))
+
+    q = l2_normalised(qkv[..., :hk * dk], 1.0 / math.sqrt(dk))
+    k = l2_normalised(qkv[..., hk * dk:2 * hk * dk])
+    v = qkv[..., 2 * hk * dk:]
+    beta = jax.nn.sigmoid(ba[..., :hv])
+    g = -jnp.exp(lp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+        ba[..., hv:] + lp["dt_bias"].astype(jnp.float32))
+    o = gm.gated_delta_rule(q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk),
+                            v.reshape(b, s, hv, dv), g, beta, c)
+    gn_w = lp["gn_w"].astype(jnp.float32)
+    y = gm._per_head(o.reshape(b, s, hv * dv), hv,
+                     lambda t: t * jax.lax.rsqrt(jnp.mean(
+                         t * t, axis=-1, keepdims=True) + c.rms_norm_eps)
+                     * gn_w)
+    y = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))).astype(
+        c.dtype)
+    return gm._matmul(y, lp["wo"], c)
+
+
+@pytest.mark.parametrize("width,path,remat", [
+    (128, "interpret", False), (128, "interpret", True), (32, "xla", False)])
+def test_the_linear_mixer_through_the_op_equals_the_parents_lines(
+        width, path, remat, monkeypatch):
+    """`_linear_mixer` through ops/mixer_chain.py (heads of 128: the kernels,
+    interpreted, over two row tiles; heads of 32: XLA; under `remat` with
+    q, k, v made again for the rule's backward) against the parent's lines
+    behind the parent's norm, float32: the value and every leaf's gradient,
+    the input's too."""
+    from ray_tpu.ops import dispatch, gated_delta as gd
+
+    config = gm.GdnMoEConfig.tiny(
+        dtype=jnp.float32, remat=remat, linear_key_head_dim=width,
+        linear_value_head_dim=width)
+    params = gm.init_params(config, jax.random.PRNGKey(5))
+    lp = jax.tree.map(lambda a: a[0], params["layers"]["seg00"]["0"])
+    u = jax.random.normal(jax.random.PRNGKey(6), (2, 32, config.hidden_size))
+    weight = jax.random.normal(jax.random.PRNGKey(7), u.shape)
+
+    def the_parents(x, lp, c):
+        return _the_parents_linear_mixer(gm.zero_centred_norm(
+            x, lp["ln1_w"], c.rms_norm_eps), lp, c)
+
+    def loss(mixer):
+        return lambda u, lp: jnp.sum(mixer(u, lp, config) * weight)
+
+    monkeypatch.setattr(dispatch, "_taken", {})
+    got = gm._linear_mixer(u, lp, config)
+    got_g = jax.grad(loss(gm._linear_mixer), argnums=(0, 1))(u, lp)
+    assert set(dispatch.taken()["mixer_chain"]) == {path}
+    # the parent's sum over the group's view, too
+    monkeypatch.setattr(gd, "over_group", lambda d, hv, hk: jnp.sum(
+        d.reshape(*d.shape[:2], hk, hv // hk, -1), axis=3).reshape(
+            *d.shape[:2], -1))
+    want = the_parents(u, lp, config)
+    want_g = jax.grad(loss(the_parents), argnums=(0, 1))(u, lp)
+    assert set(got_g[1]) == set(lp) == set(gm._layer_shapes(gm.LINEAR,
+                                                            config))
+    for name, g, w in [("the value", got, want), ("u", got_g[0], want_g[0])
+                       ] + [(n, got_g[1][n], want_g[1][n]) for n in lp]:
+        # the mixer's eight leaves; the layer's second norm and its experts
+        # lie outside it
+        assert (float(jnp.linalg.norm(w)) > 0) == (name in (
+            "the value", "u", "ln1_w", "w_qkvz", "w_ba", "conv_w", "A_log",
+            "dt_bias", "gn_w", "wo")), name
+        assert float(jnp.linalg.norm(g - w)) <= 1e-4 * float(
+            jnp.linalg.norm(w)), name
+
+
 def test_train_step_carries_the_counts_and_the_plans():
     """Through ShardedTrainStep: the loss falls, the step's metrics hold the
     LAST layer's routing counts and the rows of all four layers, its forced
@@ -292,6 +380,8 @@ def test_train_step_carries_the_counts_and_the_plans():
                             "bwd_pallas,passes")
                for p in taken["gated_delta_rule.plan"])
     assert set(taken["gated_delta_rule"]) == {"interpret"}
+    # heads of 32: the chain between W_qkvz and the rule stays XLA's
+    assert set(taken["mixer_chain"]) == {"xla"}
     assert any(",rope_in_kernel,operands_bshd,heads2x64" in p
                for p in taken["flash_attention.plan"])
     assert "full_attention:in_kernel16of64_columns_reordered_at_use_" \

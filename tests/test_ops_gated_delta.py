@@ -89,6 +89,51 @@ def test_the_cell_s_chunk_and_widths_match_the_recurrence(monkeypatch):
                for p in dispatch.taken()["gated_delta_rule.plan"])
 
 
+def _group_sum_of_the_view(d, value_heads, key_heads):
+    """The sum as PR 42 wrote it: over the group axis of the [b, t, key
+    heads, group, d_k] view."""
+    b, t, wide = d.shape
+    return jnp.sum(d.reshape(b, t, key_heads, value_heads // key_heads, -1),
+                   axis=3, dtype=jnp.float32).reshape(
+        b, t, wide * key_heads // value_heads).astype(d.dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("t,hv,hk,dk", [
+    (64, 4, 2, 128),        # whole tiles of 8 rows x 128 columns
+    (24, 8, 2, 16),         # a group of four, heads narrower than a tile
+    (13, 6, 3, 32),         # rows that fill no tile: tiles of one row
+    (16, 2, 2, 128),        # a group of one: nothing to sum
+])
+def test_the_group_sum_by_tiles_is_the_sum_of_the_view(t, hv, hk, dk, dtype):
+    d = jax.random.normal(jax.random.key(t + hv), (2, t, hv * dk)).astype(
+        dtype)
+    got = gd.over_group(d, hv, hk)
+    want = _group_sum_of_the_view(d, hv, hk)
+    assert got.shape == (2, t, hk * dk) and got.dtype == dtype
+    # float32 sums in the group's order on both sides, one rounding
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+
+
+def test_the_rule_s_gradients_of_q_and_k_are_the_view_s(monkeypatch):
+    """dq and dk of the kernels' path with the group summed by tiles equal,
+    to the bit, what the sum over the view gives."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    args = _inputs(80, True)
+    w = jax.random.normal(jax.random.key(9), args[2].shape)
+
+    def grads():
+        return jax.grad(lambda *a: jnp.sum(
+            gd.gated_delta_rule(*a, chunk=CHUNK) * w), argnums=(0, 1))(*args)
+
+    by_tiles = grads()
+    monkeypatch.setattr(gd, "over_group", _group_sum_of_the_view)
+    for mine, theirs in zip(by_tiles, grads()):
+        assert float(jnp.abs(theirs).max()) > 0
+        np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs))
+
+
 def _dots(f, *args):
     return gd._count_dots(jax.make_jaxpr(f)(*args).jaxpr)
 

@@ -1278,6 +1278,105 @@ def test_cell_head_256_flash_compiles_and_keeps_the_face_its_reader_finds(
         "dead6/6%,rope_in_kernel,operands_bshd,heads1x256"]
 
 
+def test_cell_mixer_chain_kernels_compile_and_wear_no_readers_face(
+        one_chip, monkeypatch):
+    """ops/mixer_chain.py at the cell's size (3 x 8192, 16 key and 32 value
+    heads of 128, four taps): the forward and the backward each compile for
+    a v5e as ONE custom call.  Both begin with a bf16 3-D operand, as the
+    rule's kernels do; the rule's patterns read on to the fifth operand
+    (forward) and the five results (backward), which keeps them apart: none
+    of the readers' patterns finds either."""
+    import re
+
+    from ray_tpu.ops import mixer_chain as mc
+
+    _on_tpu(monkeypatch, mc)
+    monkeypatch.setattr(mc.dispatch, "_taken", {})
+    b, t = GDN_ROWS, GDN_SEQ
+    qkv = jax.ShapeDtypeStruct((b, t, 8192), jnp.bfloat16, sharding=one_chip)
+    conv_w = jax.ShapeDtypeStruct((4, 8192), jnp.float32, sharding=one_chip)
+
+    def chain(qkv, conv_w):
+        return mc.conv_silu_l2norm(qkv, conv_w, 16, 128, 128 ** -0.5)
+
+    def loss(qkv, conv_w):
+        q, k, v = chain(qkv, conv_w)
+        return sum(jnp.sum(jnp.square(a.astype(jnp.float32)))
+                   for a in (q, k, v))
+
+    faces = _every_face()
+    assert len(faces) == 4 + 3 + 3
+    forward = _custom_calls_as_traced(chain, qkv, conv_w)
+    assert len(forward) == 1
+    assert ("= (bf16[3,8192,2048], bf16[3,8192,2048], bf16[3,8192,4096]) "
+            "custom-call(bf16[3,8192,8192] ") in forward[0]
+    both = _custom_calls_as_traced(jax.grad(loss, argnums=(0, 1)), qkv,
+                                   conv_w)
+    assert len(both) == 2
+    backward = [l for l in both
+                if "= (bf16[3,8192,8192], f32[32,8192]) custom-call("
+                "bf16[3,8192,8192] " in l]
+    assert len(backward) == 1
+    for line in forward + both:
+        assert not [n for n, p in faces.items() if re.search(p, line)], line
+    assert mc.dispatch.taken()["mixer_chain"] == {"pallas": 2}
+    assert mc._plan(qkv, 16, 128) == (512, 256, 8, 16, 4096)
+    assert mc._plan(qkv, 16, 128, mc.BACKWARD_HEADS)[:2] == (512, 128)
+
+
+def test_cell_linear_mixer_gradient_moves_the_chain_once(one_chip,
+                                                         monkeypatch):
+    """The compiled gradient of ONE linear mixer at the cell's size: five
+    kernels (the chain's forward, the rule's forward with its states, and
+    for the backward the chain's forward AGAIN, from the layer's input,
+    the rule's backward and the chain's; the rule's forward is not run
+    again: its o and states are kept); between W_qkvz's product and them
+    no copy of v out of qkv (PR 45's program held `slice` bf16[3, 8192,
+    4096]) and, behind the rule's backward, no [b, t, key heads, group,
+    d_k] view of dq and dk, which the compiler tiled T(2,128) and re-laid
+    twice."""
+    import json
+    import re
+
+    from benchmark.drivers import train_model
+    from ray_tpu.models import gdn_moe as gm
+
+    _on_tpu(monkeypatch, attention)
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmark", "configs",
+                        STEP_CONFIGS["train-gdn-moe-d4"])
+    with open(path) as f:
+        doc = json.load(f)
+    config = train_model.build_config(doc["program"], doc["model"],
+                                      doc["train"])
+    assert config.conv_channels == 8192
+    lp = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+          for name, (shape, _, _) in gm._layer_shapes(gm.LINEAR,
+                                                      config).items()}
+    x = jax.ShapeDtypeStruct((GDN_ROWS, GDN_SEQ, config.hidden_size),
+                             jnp.bfloat16, sharding=one_chip)
+    assert config.remat
+
+    def loss(x, lp):
+        return jnp.sum(gm._linear_mixer(x, lp, config).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        x, lp).compile().as_text()
+    assert text.count("tpu_custom_call") == 5
+    assert text.count("gated_delta_fwd") and len(
+        [l for l in text.splitlines() if "tpu_custom_call" in l
+         and "gated_delta_fwd" in l]) == 1
+    lines = text.splitlines()
+    assert not [l for l in lines if "[3,8192,16,2,128]" in l]
+    assert not [l for l in lines if "T(2,128)" in l and " reshape(" in l
+                and "[3,8192," in l]
+    assert not [l for l in lines
+                if re.search(r"= bf16\[3,8192,4096\]\S* slice\(", l)]
+    chain = [l for l in lines if "tpu_custom_call" in l
+             and "ssm.chain" in l]
+    assert len(chain) == 3, chain
+
+
 def test_cell_gdn_moe_step_program_fits_a_v5e(step_program):
     """The cell's whole step program (three gated-delta-rule layers and one
     gated full layer, 32 of 512 experts and a gated shared expert in each,
@@ -1289,11 +1388,18 @@ def test_cell_gdn_moe_step_program_fits_a_v5e(step_program):
     total = _chip_bytes(compiled)
     assert 13.0 * 2 ** 30 < total < 15.75 * 2 ** 30, total / 2 ** 30
     # The linear segment: the rule's forward, its forward again under remat
-    # and its backward (3); the full segment the flash three; each segment
-    # the grouped kernels, twelve at each of the layer's two buffer sizes,
-    # and the two movers by the token beside them (PR 45).
-    assert compiled.as_text().count("tpu_custom_call") == (
-        2 * 3 + 2 * 2 * (12 + 2))
+    # and its backward (3), and the chain in front of it likewise and once
+    # more for the rule's backward (4, PR 46: `gdn_moe._linear_mixer`); the
+    # full segment the flash three; each segment the grouped kernels,
+    # twelve at each of the layer's two buffer sizes, and the two movers by
+    # the token beside them (PR 45).
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3 + 4 + 3 + 2 * 2 * (12 + 2)
+    chain = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and "ssm.chain" in l]
+    assert len(chain) == 4 and all("/ssm/" in l for l in chain), chain
+    assert "[3,8192,16,2,128]" not in text      # the group's view is gone
+    assert set(taken["mixer_chain"]) == {"pallas"}
     assert _grouped_calls(_custom_calls_of(compiled)) == 2 * 2 * 12
     assert set(taken["routed_experts"]) == {"pallas"}
     assert sorted(taken["routed_experts.plan"]) == [
