@@ -1553,6 +1553,9 @@ def test_a_remat_layer_of_every_attention_entry_keeps_out_and_lse(
 # replaces its digest here and says so.  PR 45 MEANT TO: the two expert
 # cells' digests are its tree's (the movers by the token are a kernel,
 # ops/row_gather.py); the hybrid's and both dense layers' stand.
+# `train-gdn-moe-d4` is PR 46's tree (40fa1e4), written down before PR 47
+# moved the model files' shared stack into models/stack.py, as is
+# `train-cca-moe-d4` in tests/test_tpu_aot_compile_cca.py.
 PARENT_HLO_SHA256 = {
     "train-hybrid-d8":
         "3d480d458ec2cf6d978d269f5cdda6a3c7f2dcedd415d84a8be116758340a32b",
@@ -1560,6 +1563,8 @@ PARENT_HLO_SHA256 = {
         "a601fb239f5eb1614c9db8af0ab5e12073e0d19356ff06fc13a98fde2bfec779",
     "train-swa-moe-d5":
         "9846286bb91a94b6fbf231d92a1b4299b36e1116472519b01770a01a46a5f0f4",
+    "train-gdn-moe-d4":
+        "7cc704672c11a8ef5a0320fc562c5b5036cc622507febc75399d827196dff252",
     "dense-layer.one_chip":
         "f8ed670122d29fe6c37a4e2abeab135595d735282e71449a49a0e737920e6abf",
     "dense-layer.fsdp4":

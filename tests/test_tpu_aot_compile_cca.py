@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.ops import attention
+from test_tpu_aot_compile import _metadata_stripped
 
 CONFIG = os.path.join(os.path.dirname(os.path.dirname(__file__)),
                       "benchmark", "configs", "zaya1-8b-train-d4.json")
@@ -82,6 +83,24 @@ def step_program(topo):
             ).lower(state, batch, keep=True).compile()
         taken = copy.deepcopy(attention.dispatch.taken())
     return compiled, taken, tr
+
+
+# sha256 of the step program's optimised HLO, `_metadata_stripped`, as PR
+# 46's tree (40fa1e4) compiled it: tests/test_tpu_aot_compile.py's
+# `PARENT_HLO_SHA256` has the rule (a change that means to move the program
+# replaces the digest and says so) and the other cells'.
+PARENT_HLO_SHA256 = (
+    "2d09fc2a1ab5f7a3f17ac1c40ba7c553b46de1a5965a01c6df22d1b1e296fae1")
+
+
+def test_cell_cca_moe_optimised_hlo_is_as_the_parent_compiled_it(
+        step_program):
+    import hashlib
+
+    text = _metadata_stripped(step_program[0].as_text())
+    assert "op_name" not in text and "source_file" not in text \
+        and ".py" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_HLO_SHA256
 
 
 def _calls_as_traced(compiled):
