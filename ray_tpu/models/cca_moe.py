@@ -1,10 +1,8 @@
 """Compressed convolutional attention beside a top-1 expert layer whose
 router is an MLP that carries its state from layer to layer (the `zaya`
-form, as ZAYA1-8B publishes it) for training through `ShardedTrainStep`:
-the same entry points as the other model files (`init_params`,
-`logical_axes`, `num_params`, `not_trained`, `loss_fn`, `token_nll`,
-`loss_and_metrics`) and the probe `cca_mix`; embedding, fused cross-entropy,
-the depthwise convolution, the tiles and the remat wrapper are
+form, as ZAYA1-8B publishes it) for training through `ShardedTrainStep`, on
+models/stack.py's layer stack, with the probe `cca_mix`; embedding,
+cross-entropy, the depthwise convolution and the tiles are
 models/common.py's, the selection and the routed experts models/moe.py's
 dropless layer, attention ops/attention.py's flash kernels.
 
@@ -49,11 +47,9 @@ rms(x) w, eps `rms_norm_eps`):
   model      tied embedding (no input scale), the layers with (x, r) as
              the carry, a final norm, logits x W_emb^T.
 
-One chip's share: `num_experts` is how many experts THIS program holds
-(experts `first_held_expert` on), `router_width` how many the model routes
-over: models/swa_moe.py's convention and its parameter tree
-(`params["layers"][segNN]["0"][leaf][repeat]`; every layer is of one kind,
-so there is one segment, stacked and scanned).
+One chip's share (`stack.routed_part`): `num_experts` experts held HERE,
+from `first_held_expert` on, of `router_width` routed over.  Every layer is
+of one kind, so there is one segment.
 
 Precision.  The matmuls take their operands in the dtype the WEIGHTS come
 in and accumulate in float32: the layer hands `_mix` its matrices in the
@@ -63,13 +59,11 @@ the projections and the kernels (`attn.mix`: the shift, both convolutions'
 sums, the mean, the l2 norm) is float32 either way and is rounded once, to
 the compute dtype, where the kernels take Q, K and V.
 
-How the half rope reaches the kernels.  `flash_attention(.., rope=)` turns
-column i with column i + d/2 over the WHOLE head.  Q and K are no
-projection's output here, so it is their columns, not a weight's, that are
-reordered once where the kernels take them (`_rotary_first_halves`: a head's
-[rot_a | rot_b | pass_a | pass_b] becomes [rot_a | pass_a | rot_b | pass_b];
-q . k does not see one permutation of both), with tables that hold cos 1 and
-sin 0 for the columns that pass through (`dispatch.taken()["cca_moe.rope"]`).
+How the half rope reaches the kernels, whose rope turns the WHOLE head: Q
+and K are no projection's output here, so it is their columns, not a
+weight's, that are reordered once where the kernels take them
+(`stack.rotary_first`), with tables that have an identity tail
+(`stack.kernel_tables`; `dispatch.taken()["cca_moe.rope"]`).
 """
 
 from __future__ import annotations
@@ -82,8 +76,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import common, moe
-from ray_tpu.models.swa_moe import _frozen
+from ray_tpu.models import common, moe, stack
+from ray_tpu.models.swa_moe import USUAL_LOAD, _frozen
 from ray_tpu.models.transformer import rms_norm
 from ray_tpu.ops import dispatch
 from ray_tpu.parallel.sharding import with_logical_constraint
@@ -98,10 +92,6 @@ PUBLISHED_ROPE = {
                        "rope_type": "default"},
     "rope_type": "default",
 }
-# The usual buffer of an expert layer, in rows even routing would send to
-# the held experts (models/swa_moe.py has the reason and its readings).
-# With every expert held the bound is the step's tokens and nothing is chosen.
-USUAL_LOAD = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -213,9 +203,6 @@ def segments(config: CcaMoEConfig) -> List[Tuple[str, int, int]]:
     return [(HYBRID, 0, config.num_hidden_layers)]
 
 
-_SEGMENT = "seg00"
-
-
 # ---------------------------------------------------------------------------
 # Parameters
 # ---------------------------------------------------------------------------
@@ -231,7 +218,6 @@ def _layer_shapes(c: CcaMoEConfig) -> Dict[str, Tuple]:
     heads, kv = c.num_attention_heads, c.num_key_value_heads
     lq, lkv = c.latents
     r, width = c.router_hidden_size, c.router_width
-    m, held = c.moe_intermediate_size, c.num_experts
 
     def residual(prefix):
         return {f"{prefix}_sr": ((h,), (None,), "near_one"),
@@ -265,78 +251,45 @@ def _layer_shapes(c: CcaMoEConfig) -> Dict[str, Tuple]:
         "router_b2": ((r,), (None,), "small"),
         "router_w3": ((r, width), (None, None), r),
         "router_bias": ((width,), (None,), "select_bias"),
-        "experts_gate": ((held, h, m), ("expert", "embed", "mlp"), h),
-        "experts_up": ((held, h, m), ("expert", "embed", "mlp"), h),
-        "experts_down": ((held, m, h), ("expert", "mlp", "embed"), m),
+        **stack.swiglu_shapes("experts", h, c.moe_intermediate_size,
+                              c.num_experts),
     }
 
 
-def _draw(key, shape, init, dtype):
-    x = jax.random.normal(key, shape)
-    if init == "near_one":
-        x = 1.0 + 0.1 * x
-    elif init == "small":
-        x = 0.1 * x
-    elif init == "residual_bias":
-        x = 0.01 * x
-    elif init == "select_bias":
-        x = 0.01 * x
-    else:
-        x = x / math.sqrt(init)
-    return x.astype(dtype)
+def _normal_times(scale: float):
+    return lambda key, shape: scale * jax.random.normal(key, shape)
 
 
-def _init_layer(key, c: CcaMoEConfig) -> Dict[str, Any]:
-    shapes = _layer_shapes(c)
-    return {name: _draw(k, shape, init, c.param_dtype)
-            for k, (name, (shape, _, init)) in zip(
-                jax.random.split(key, len(shapes)), shapes.items())}
+def _top_shapes(c: CcaMoEConfig) -> Dict[str, Tuple]:
+    return {"tok_embed": ((c.vocab_size, c.hidden_size), ("vocab", "embed"),
+                          c.hidden_size),
+            "final_norm_w": ((c.hidden_size,), (None,), "near_one")}
+
+
+_PARAMS = stack.Params(
+    stack.one_kind(segments), lambda kind, c: _layer_shapes(c),
+    _top_shapes, {
+        "near_one": lambda key, shape: (
+            1.0 + 0.1 * jax.random.normal(key, shape)),
+        "small": _normal_times(0.1), "residual_bias": _normal_times(0.01),
+        "select_bias": _normal_times(0.01),
+        "fan_in": lambda key, shape, fan_in: (
+            jax.random.normal(key, shape) / math.sqrt(fan_in))})
+logical_axes, num_params = _PARAMS.logical_axes, _PARAMS.num_params
 
 
 def init_params(config: CcaMoEConfig, key) -> Dict[str, Any]:
     """{"tok_embed" (the head too), "layers": {"seg00": {"0": layer
     parameters stacked on a leading repeats axis}}, "final_norm_w"}."""
-    c = config
     k_embed, k_norm, k_layers = jax.random.split(key, 3)
-    each = [_init_layer(jax.random.fold_in(k_layers, i), c)
-            for i in range(c.num_hidden_layers)]
-    return {
-        "tok_embed": _draw(k_embed, (c.vocab_size, c.hidden_size),
-                           c.hidden_size, c.param_dtype),
-        "layers": {_SEGMENT: {
-            "0": jax.tree.map(lambda *a: jnp.stack(a), *each)}},
-        "final_norm_w": _draw(k_norm, (c.hidden_size,), "near_one",
-                              c.param_dtype),
-    }
-
-
-def _leaf_tree(config: CcaMoEConfig, layer_leaf, top_leaf) -> Dict[str, Any]:
-    return {"tok_embed": top_leaf("tok_embed"),
-            "layers": {_SEGMENT: {"0": {
-                name: layer_leaf(name, spec)
-                for name, spec in _layer_shapes(config).items()}}},
-            "final_norm_w": top_leaf("final_norm_w")}
-
-
-def logical_axes(config: CcaMoEConfig) -> Dict[str, Any]:
-    """Logical-axis tree matching init_params, for parallel.sharding."""
-    tops = {"tok_embed": ("vocab", "embed"), "final_norm_w": (None,)}
-    return _leaf_tree(config, lambda name, spec: ("layers",) + spec[1],
-                      tops.__getitem__)
+    return _PARAMS.init(config, {"tok_embed": k_embed, "final_norm_w": k_norm,
+                                 "layers": k_layers})
 
 
 def not_trained(config: CcaMoEConfig) -> Dict[str, Any]:
     """True at the leaves a train step leaves as they are: the router's
     selection bias (its update rule is a trainer's, not the layer's)."""
-    return _leaf_tree(config, lambda name, spec: name == "router_bias",
-                      lambda name: False)
-
-
-def num_params(config: CcaMoEConfig) -> int:
-    per_layer = sum(math.prod(shape)
-                    for shape, _, _ in _layer_shapes(config).values())
-    return (config.vocab_size * config.hidden_size
-            + config.num_hidden_layers * per_layer + config.hidden_size)
+    return _PARAMS.tree(config, lambda name, spec: name == "router_bias")
 
 
 # ---------------------------------------------------------------------------
@@ -359,10 +312,7 @@ def _shifted(x, back: int):
     return jnp.pad(x[:, :x.shape[1] - back], ((0, 0), (back, 0), (0, 0)))
 
 
-def _per_head(x, heads: int, fn):
-    """fn over every head's columns of x [b, s, heads x w] float32, by
-    whole tiles (`common.by_tiles`): fn sees [b, s / 8, heads, 8, w]."""
-    return common.from_tiles(fn(common.by_tiles(x, heads)))
+_per_head = stack.per_head
 
 
 def _two_convs(x, heads: int, w0, b0, w1, b1):
@@ -442,31 +392,17 @@ def cca_mix(u, wq, wk, wv1, wv2, conv0_w, conv0_b, conv1_w, conv1_b, tau,
 
 
 def _rotary_first_halves(x, heads: int, c: CcaMoEConfig):
-    """The last axis, heads x d with a head's columns as published, [rot_a
-    | rot_b | pass_a | pass_b], the rotary pair i being (rot_a[i],
-    rot_b[i]) -> every head's columns as [rot_a | pass_a | rot_b | pass_b]:
-    pair i is then (i, i + d/2) of the whole head, which the flash kernels'
-    rope turns.  A permutation, exact; its gradient is the permutation
-    back."""
-    lead, quarter = x.shape[:-1], c.head_dim // 4
-    x = x.reshape(*lead, heads, 2, 2, quarter)
-    return jnp.swapaxes(x, -3, -2).reshape(*lead, heads * 4 * quarter)
+    """The last axis, heads x d with a head's columns as published -> as
+    the flash kernels' rope pairs them (`stack.rotary_first`)."""
+    return stack.rotary_first(x, heads, c.rotary_width)
 
 
 def kernel_tables(seq: int, c: CcaMoEConfig):
     """(cos, sin) [seq, head_dim / 2] float32 as the flash kernels take
-    them for a head ordered by `_rotary_first_halves`: the rotary pairs'
-    cos and sin at positions 0 .. seq - 1, then cos 1 and sin 0 for the
-    pairs that pass through."""
-    r = c.rotary_width
-    inv_freq = 1.0 / c.rope_theta ** (
-        2.0 * jnp.arange(r // 2, dtype=F32) / r)
-    angle = jnp.arange(seq, dtype=F32)[:, None] * inv_freq[None, :]
-    passing = (c.head_dim - r) // 2
-    return (jnp.concatenate([jnp.cos(angle), jnp.ones((seq, passing), F32)],
-                            axis=1),
-            jnp.concatenate([jnp.sin(angle), jnp.zeros((seq, passing), F32)],
-                            axis=1))
+    them for a head ordered by `_rotary_first_halves`
+    (`stack.kernel_tables`)."""
+    return stack.kernel_tables(
+        *stack.rope_tables(seq, c.rotary_width, c.rope_theta), c.head_dim)
 
 
 def _attention(u, lp, tables, c: CcaMoEConfig):
@@ -524,17 +460,13 @@ def route(h, r_prev, lp, c: CcaMoEConfig):
 
 
 def _routed_part(flat, r_prev, lp, c: CcaMoEConfig):
-    """The router and models/moe.py's dropless layer for this chip's share:
-    flat [T, E] -> (the held experts' sum, the router's state, the routing
-    counts)."""
-    with jax.named_scope(common.MOE_ROUTE):
-        idx, gates, r = route(flat, r_prev, lp, c)
-    even = -(-flat.shape[0] * c.num_experts_per_tok * c.num_experts
-             // c.router_width)
-    y, stats = moe.routed_experts(
-        flat, idx, gates, lp["experts_gate"], lp["experts_up"],
-        lp["experts_down"], experts_held=c.experts_held, dtype=c.dtype,
-        usual_rows=USUAL_LOAD * even)
+    """`stack.routed_part` behind `route`: flat [T, E] -> (the held
+    experts' sum, the router's state, the routing counts).  `USUAL_LOAD`
+    matters to a share alone: with every expert held, as in the cell, the
+    bound is the step's tokens."""
+    y, stats, r = stack.routed_part(
+        flat, lambda: route(flat, r_prev, lp, c), lp["experts_gate"],
+        lp["experts_up"], lp["experts_down"], c, USUAL_LOAD)
     return y, r, stats
 
 
@@ -546,10 +478,11 @@ def _residual(x, f, lp, prefix: str):
             + (s_h * f.astype(F32) + b_h)).astype(x.dtype)
 
 
-def _layer(carry, lp, tables, *, c: CcaMoEConfig):
+def _layer(carry, lp, tables, *, kind: str, c: CcaMoEConfig):
     """One layer: (x, the router's state) -> ((x, the router's state), the
     expert layer's routing counts).  The residual scaling lies under its
     sublayer's scope: `attn.full`, and `mlp` beside the experts' pre-norm."""
+    del kind                                    # there is the one
     x, r_prev = carry
     with jax.named_scope(common.ATTN_FULL):
         u = rms_norm(x, lp["ln1_w"], c.rms_norm_eps)
@@ -564,12 +497,6 @@ def _layer(carry, lp, tables, *, c: CcaMoEConfig):
     return (with_logical_constraint(x, ("batch", "seq", "embed")), r), stats
 
 
-@functools.cache
-def _layer_fn(c: CcaMoEConfig):
-    return common.maybe_remat(functools.partial(_layer, c=c), c.remat,
-                              c.remat_policy)
-
-
 def forward_hidden(params: Dict[str, Any], tokens, config: CcaMoEConfig):
     """Embedding + layers + the final norm: [b, s] -> ([b, s, E], the LAST
     layer's routing counts and the rows all the layers held together)."""
@@ -582,46 +509,16 @@ def forward_hidden(params: Dict[str, Any], tokens, config: CcaMoEConfig):
     dispatch.record("cca_moe.mix", (
         f"taps{c.cca_time0}+{c.cca_time1},heads{heads}over{kv},"
         f"latent{c.latents[0]}+{c.latents[1]},vshift,l2tau,xla"))
-    dispatch.record("cca_moe.rope", (
-        f"{HYBRID}:in_kernel{c.rotary_width}of{c.head_dim}"
-        "_columns_reordered_at_use_identity_tail"))
-    fn = _layer_fn(c)
-    (x, _), per_layer = jax.lax.scan(
-        lambda carry, lp: fn(carry, lp, tables),
+    dispatch.record("cca_moe.rope",
+                    stack.rope_word(HYBRID, c.rotary_width, c.head_dim))
+    (x, _), stats = stack.walk(
+        _layer, c, segments(c), params["layers"],
         (x, jnp.zeros((b * s, c.router_hidden_size), F32)),
-        params["layers"][_SEGMENT]["0"])
-    stats = jax.tree.map(lambda a: a[-1], per_layer)
-    stats["rows_held_all_layers"] = jnp.sum(per_layer["rows_held"])
+        lambda kind: tables)
     with jax.named_scope(common.LOSS):
         return rms_norm(x, params["final_norm_w"], c.rms_norm_eps), stats
 
 
-def _nll_and_stats(params, batch, config: CcaMoEConfig):
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    x, stats = forward_hidden(params, inputs, config)
-    if config.fused_ce:
-        return common.fused_nll(x, params["tok_embed"], targets), stats
-    logits = common.tied_logits(x, params["tok_embed"], config.dtype)
-    return common.logits_nll(logits, targets), stats
-
-
-def token_nll(params, batch, config: CcaMoEConfig):
-    """-log p(tokens[t+1] | tokens[:t+1]) for every position: [b, s] fp32.
-    batch: {"tokens": [b, s+1] int32}."""
-    return _nll_and_stats(params, batch, config)[0]
-
-
-def loss_and_metrics(params, batch, config: CcaMoEConfig):
-    """(next-token cross-entropy, the LAST layer's routing counts as `moe_*`
-    device scalars)."""
-    nll, stats = _nll_and_stats(params, batch, config)
-    mask = batch.get("mask")
-    loss = common.masked_mean(nll, None if mask is None else mask[:, 1:])
-    return loss, {f"moe_{k}": v for k, v in stats.items()}
-
-
-def loss_fn(params, batch, config: CcaMoEConfig):
-    """Next-token cross-entropy: the mean of `token_nll`, over the
-    positions batch["mask"] keeps if there is one."""
-    return loss_and_metrics(params, batch, config)[0]
+_TAIL = stack.LossTail(forward_hidden, head="tok_embed")
+token_nll, loss_and_metrics = _TAIL.token_nll, _TAIL.loss_and_metrics
+loss_fn = _TAIL.loss_fn

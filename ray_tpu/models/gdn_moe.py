@@ -1,12 +1,11 @@
 """Gated-delta-rule linear attention beside gated full attention, with a
 softmax-routed expert layer and a gated shared expert in every layer (the
 `qwen3_next` form, as Qwen3-Next-80B-A3B publishes it) for training through
-`ShardedTrainStep`: the same entry points as the other model files
-(`init_params`, `logical_axes`, `num_params`, `loss_fn`, `token_nll`,
-`loss_and_metrics`); embedding, fused cross-entropy, SwiGLU, the causal
-convolution and the remat wrapper are models/common.py's, the routed experts
+`ShardedTrainStep`, on models/stack.py's layer stack; embedding,
+cross-entropy and SwiGLU are models/common.py's, the routed experts
 models/moe.py's dropless layer, the recurrence ops/gated_delta.py's kernels,
-attention ops/attention.py's flash kernels.
+the chain before it ops/mixer_chain.py's, attention ops/attention.py's flash
+kernels.
 
 Layer equations (x the layer's input [s, hidden]; every matrix [in, out], no
 bias anywhere; every norm an RMSNorm with eps `rms_norm_eps`; Z(x; w) = x /
@@ -39,24 +38,18 @@ rms(x) (1 + w) the family's ZERO-CENTRED norm):
              the chosen experts HELD HERE of gate_e SwiGLU_e(y) + sigmoid(y
              w_sg) SwiGLU_shared(y).  No auxiliary loss.
 
-One chip's share: `num_experts` is how many experts THIS program holds
-(experts `first_held_expert` on), `router_width` how many the model routes
-over: models/swa_moe.py's convention, and its parameter tree
-(`params["layers"][segNN]["0"][leaf][repeat]`: maximal runs of layers of one
-kind, stacked and scanned).  The published pattern is two segments a period:
-three linear layers, one full.
+One chip's share (`stack.routed_part`): `num_experts` experts held HERE,
+from `first_held_expert` on, of `router_width` routed over.  The segments
+are maximal runs of layers of one kind: the published pattern is two a
+period, three linear layers and one full.
 
-How the full layer's quarter rope reaches the kernels.  `flash_attention(..,
-rope=)` turns column i with column i + d/2 over the WHOLE head.  The
-published head is [rot_a | rot_b | pass] (r/2, r/2, d - r columns, the
-rotary pair i being (rot_a[i], rot_b[i])); W_q's and W_k's columns and the q
-/ k norms' weights are reordered AT USE to [rot_a | pass' | rot_b | pass'']
-(the pass-through columns cut in two), and the tables hold cos 1 and sin 0
-for them, so the kernel's whole-head turn is the published quarter turn and
-the identity on the rest (`dispatch.taken()["gdn_moe.rope"]`).  The norm over
-a head and q . k do not see one permutation of both.  The same reordering of
-W_q splits a head's query columns from its gate columns, so the activations
-are never sliced by head.
+How the full layer's quarter rope reaches the kernels, whose rope turns the
+WHOLE head: W_q's and W_k's columns and the q / k norms' weights are
+reordered AT USE (`stack.rotary_first`; the norm over a head and q . k do not
+see one permutation of both) and the tables have an identity tail
+(`stack.kernel_tables`; `dispatch.taken()["gdn_moe.rope"]`).  The same
+reordering of W_q splits a head's query columns from its gate columns, so the
+activations are never sliced by head.
 """
 
 from __future__ import annotations
@@ -69,16 +62,14 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import common, moe
+from ray_tpu.models import common, moe, stack
+from ray_tpu.models.swa_moe import USUAL_LOAD
 from ray_tpu.ops import dispatch
 from ray_tpu.ops.mixer_chain import L2_EPS, conv_silu_l2norm
 from ray_tpu.parallel.sharding import with_logical_constraint
 
 F32 = jnp.float32
 FULL, LINEAR = "full_attention", "linear_attention"
-# The usual buffer of an expert layer, in rows even routing would send to
-# the held experts (models/swa_moe.py has the reason and its readings).
-USUAL_LOAD = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,17 +174,7 @@ class GdnMoEConfig:
 
 def segments(config: GdnMoEConfig) -> List[Tuple[str, int, int]]:
     """(kind, first layer, repeats): maximal runs of layers of one kind."""
-    out: List[Tuple[str, int, int]] = []
-    for i, kind in enumerate(config.layer_types):
-        if out and out[-1][0] == kind:
-            out[-1] = (kind, out[-1][1], out[-1][2] + 1)
-        else:
-            out.append((kind, i, 1))
-    return out
-
-
-def _segment_name(i: int) -> str:
-    return f"seg{i:02d}"
+    return stack.runs(config.layer_types)
 
 
 # ---------------------------------------------------------------------------
@@ -231,82 +212,50 @@ def _layer_shapes(kind: str, c: GdnMoEConfig) -> Dict[str, Tuple]:
             "k_norm_w": ((d,), (None,), "zero_centred"),
             "wo": ((heads * d, h), ("heads", "embed"), heads * d),
         }
-    m, held = c.moe_intermediate_size, c.num_experts
-    shared = c.shared_expert_intermediate_size
     return {
         "ln1_w": ((h,), (None,), "zero_centred"), **mixer,
         "ln2_w": ((h,), (None,), "zero_centred"),
         "router_w": ((h, c.router_width), ("embed", None), h),
-        "experts_gate": ((held, h, m), ("expert", "embed", "mlp"), h),
-        "experts_up": ((held, h, m), ("expert", "embed", "mlp"), h),
-        "experts_down": ((held, m, h), ("expert", "mlp", "embed"), m),
-        "shared_gate": ((h, shared), ("embed", "mlp"), h),
-        "shared_up": ((h, shared), ("embed", "mlp"), h),
-        "shared_down": ((shared, h), ("mlp", "embed"), shared),
+        **stack.swiglu_shapes("experts", h, c.moe_intermediate_size,
+                              c.num_experts),
+        **stack.swiglu_shapes("shared", h,
+                              c.shared_expert_intermediate_size),
         "shared_expert_gate": ((h, 1), ("embed", None), h),
     }
 
 
-def _draw(key, shape, init, dtype):
-    if init == "zero_centred":
-        x = 0.1 * jax.random.normal(key, shape)
-    elif init == "near_one":
-        x = 1.0 + 0.1 * jax.random.normal(key, shape)
-    elif init == "a_log":
-        x = jnp.log(jax.random.uniform(key, shape, minval=1e-2, maxval=16.0))
-    elif init == "dt_bias":
-        dt = jnp.exp(jax.random.uniform(key, shape, minval=math.log(1e-3),
-                                        maxval=math.log(1e-1)))
-        x = dt + jnp.log(-jnp.expm1(-dt))       # softplus(x) = dt
-    else:
-        x = jax.random.normal(key, shape) / math.sqrt(init)
-    return x.astype(dtype)
+def _dt_bias(key, shape):
+    dt = jnp.exp(jax.random.uniform(key, shape, minval=math.log(1e-3),
+                                    maxval=math.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))        # softplus(x) = dt
 
 
-def _init_layer(key, kind: str, c: GdnMoEConfig) -> Dict[str, Any]:
-    shapes = _layer_shapes(kind, c)
-    return {name: _draw(k, shape, init, c.param_dtype)
-            for k, (name, (shape, _, init)) in zip(
-                jax.random.split(key, len(shapes)), shapes.items())}
+def _top_shapes(c: GdnMoEConfig) -> Dict[str, Tuple]:
+    table = ((c.vocab_size, c.hidden_size), ("vocab", "embed"), c.hidden_size)
+    return {"tok_embed": table, "lm_head": table,
+            "final_norm_w": ((c.hidden_size,), (None,), "zero_centred")}
+
+
+_PARAMS = stack.Params(
+    stack.one_kind(segments), _layer_shapes, _top_shapes, {
+        "zero_centred": lambda key, shape: (
+            0.1 * jax.random.normal(key, shape)),
+        "near_one": lambda key, shape: (
+            1.0 + 0.1 * jax.random.normal(key, shape)),
+        "a_log": lambda key, shape: jnp.log(
+            jax.random.uniform(key, shape, minval=1e-2, maxval=16.0)),
+        "dt_bias": _dt_bias,
+        "fan_in": lambda key, shape, fan_in: (
+            jax.random.normal(key, shape) / math.sqrt(fan_in))})
+logical_axes, num_params = _PARAMS.logical_axes, _PARAMS.num_params
 
 
 def init_params(config: GdnMoEConfig, key) -> Dict[str, Any]:
     """{"tok_embed", "layers": {segNN: {"0": layer parameters stacked on a
     leading repeats axis}}, "final_norm_w", "lm_head" [vocab, hidden]}."""
-    c = config
     k_embed, k_head, k_norm, k_layers = jax.random.split(key, 4)
-    layers = {}
-    for si, (kind, first, repeats) in enumerate(segments(c)):
-        each = [_init_layer(jax.random.fold_in(k_layers, first + rep), kind, c)
-                for rep in range(repeats)]
-        layers[_segment_name(si)] = {
-            "0": jax.tree.map(lambda *a: jnp.stack(a), *each)}
-    table = (c.vocab_size, c.hidden_size)
-    return {
-        "tok_embed": _draw(k_embed, table, c.hidden_size, c.param_dtype),
-        "layers": layers,
-        "final_norm_w": _draw(k_norm, (c.hidden_size,), "zero_centred",
-                              c.param_dtype),
-        "lm_head": _draw(k_head, table, c.hidden_size, c.param_dtype),
-    }
-
-
-def logical_axes(config: GdnMoEConfig) -> Dict[str, Any]:
-    """Logical-axis tree matching init_params, for parallel.sharding."""
-    layers = {
-        _segment_name(si): {"0": {
-            name: ("layers",) + axes
-            for name, (_, axes, _) in _layer_shapes(kind, config).items()}}
-        for si, (kind, _, _) in enumerate(segments(config))}
-    return {"tok_embed": ("vocab", "embed"), "layers": layers,
-            "final_norm_w": (None,), "lm_head": ("vocab", "embed")}
-
-
-def num_params(config: GdnMoEConfig) -> int:
-    per_layer = sum(math.prod(shape) for kind in config.layer_types
-                    for shape, _, _ in _layer_shapes(kind, config).values())
-    return (2 * config.vocab_size * config.hidden_size + per_layer
-            + config.hidden_size)
+    return _PARAMS.init(config, {"tok_embed": k_embed, "lm_head": k_head,
+                                 "final_norm_w": k_norm, "layers": k_layers})
 
 
 # ---------------------------------------------------------------------------
@@ -320,17 +269,7 @@ def zero_centred_norm(x, w, eps):
     return (xf * scale * (1.0 + w.astype(F32))).astype(x.dtype)
 
 
-def _matmul(x, w, c: GdnMoEConfig, out_dtype=None):
-    """bf16 operands, fp32 accumulation, the result in the compute dtype."""
-    return jnp.einsum("bsi,io->bso", x.astype(c.dtype), w.astype(c.dtype),
-                      preferred_element_type=out_dtype or c.dtype)
-
-
-def _per_head(x, heads: int, fn):
-    """fn over every head's columns of x [b, s, heads x w], by whole tiles
-    (`common.by_tiles`): fn sees [.., w] float32 and gives the like."""
-    return common.from_tiles(fn(common.by_tiles(x, heads).astype(F32))
-                             .astype(x.dtype))
+_matmul, _per_head = stack.matmul, stack.per_head
 
 
 def gated_delta_rule(q, k, v, g, beta, config: GdnMoEConfig):
@@ -399,32 +338,16 @@ def _linear_mixer(x, lp, c: GdnMoEConfig):
 
 
 def _rotary_first(x, c: GdnMoEConfig):
-    """The last axis, a head's columns as published, [rot_a | rot_b | pass]
-    -> [rot_a | pass' | rot_b | pass'']: pair i is then (i, i + d/2) of the
-    whole head, which the flash kernels' rope turns.  Four slices, exact;
-    the gradient puts them back."""
-    d, r = c.head_dim, c.rotary_width
-    if r == d:
-        return x
-    cut = r + (d - r) // 2
-    return jnp.concatenate([x[..., :r // 2], x[..., r:cut],
-                            x[..., r // 2:r], x[..., cut:]], axis=-1)
+    """The last axis, ONE head's columns as published -> as the flash
+    kernels' rope pairs them (`stack.rotary_first`)."""
+    return stack.rotary_first(x, 1, c.rotary_width)
 
 
 def kernel_tables(seq: int, c: GdnMoEConfig):
     """(cos, sin) [seq, head_dim / 2] float32 as the flash kernels take
-    them for a head ordered by `_rotary_first`: the rotary pairs' cos and
-    sin at positions 0 .. seq - 1, then cos 1 and sin 0 for the pairs that
-    pass through."""
-    r = c.rotary_width
-    inv_freq = 1.0 / float(c.rope_theta) ** (
-        2.0 * jnp.arange(r // 2, dtype=F32) / r)
-    angle = jnp.arange(seq, dtype=F32)[:, None] * inv_freq[None, :]
-    passing = (c.head_dim - r) // 2
-    return (jnp.concatenate([jnp.cos(angle), jnp.ones((seq, passing), F32)],
-                            axis=1),
-            jnp.concatenate([jnp.sin(angle), jnp.zeros((seq, passing), F32)],
-                            axis=1))
+    them for a head ordered by `_rotary_first` (`stack.kernel_tables`)."""
+    return stack.kernel_tables(
+        *stack.rope_tables(seq, c.rotary_width, c.rope_theta), c.head_dim)
 
 
 def _full_attention(u, lp, tables, c: GdnMoEConfig):
@@ -464,19 +387,13 @@ def _full_attention(u, lp, tables, c: GdnMoEConfig):
 
 
 def _routed_part(flat, router_w, w_gate, w_up, w_down, c: GdnMoEConfig):
-    """The router and models/moe.py's dropless layer for this chip's share:
-    flat [T, hidden] -> (the held experts' sum, the routing counts).  The
-    usual buffer holds `USUAL_LOAD` times the rows even routing sends here;
-    a step that sends more takes the full bound's."""
-    with jax.named_scope(common.MOE_ROUTE):
-        idx, gates = moe.softmax_route(
+    """`stack.routed_part` behind the softmax router: flat [T, hidden] ->
+    (the held experts' sum, the routing counts)."""
+    return stack.routed_part(
+        flat, lambda: moe.softmax_route(
             flat, router_w, num_experts_per_token=c.num_experts_per_tok,
-            scale=1.0)
-    even = -(-flat.shape[0] * c.num_experts_per_tok * c.num_experts
-             // c.router_width)
-    return moe.routed_experts(
-        flat, idx, gates, w_gate, w_up, w_down, experts_held=c.experts_held,
-        dtype=c.dtype, usual_rows=USUAL_LOAD * even)
+            scale=1.0),
+        w_gate, w_up, w_down, c, USUAL_LOAD)
 
 
 def _layer(x, lp, tables, *, kind: str, c: GdnMoEConfig):
@@ -504,12 +421,6 @@ def _layer(x, lp, tables, *, kind: str, c: GdnMoEConfig):
     return with_logical_constraint(x + ffn, ("batch", "seq", "embed")), stats
 
 
-@functools.cache
-def _layer_fn(kind: str, c: GdnMoEConfig):
-    return common.maybe_remat(functools.partial(_layer, kind=kind, c=c),
-                              c.remat, c.remat_policy)
-
-
 def forward_hidden(params: Dict[str, Any], tokens, config: GdnMoEConfig):
     """Embedding + layers + the final norm: [b, s] -> ([b, s, hidden], the
     LAST layer's routing counts and the rows all the expert layers held
@@ -520,53 +431,15 @@ def forward_hidden(params: Dict[str, Any], tokens, config: GdnMoEConfig):
     if FULL in c.layer_types:
         with jax.named_scope(common.ATTN_FULL):     # the tables are its own
             tables = kernel_tables(tokens.shape[1], c)
-        dispatch.record("gdn_moe.rope", (
-            f"{FULL}:in_kernel{c.rotary_width}of{c.head_dim}"
-            + ("" if c.rotary_width == c.head_dim
-               else "_columns_reordered_at_use_identity_tail")))
-    stats, rows_held = None, 0
-    for si, (kind, _, _) in enumerate(segments(c)):
-        fn = _layer_fn(kind, c)
-
-        def body(x, lp, fn=fn):
-            return fn(x, lp, tables)
-
-        x, per_layer = jax.lax.scan(
-            body, x, params["layers"][_segment_name(si)]["0"])
-        stats = jax.tree.map(lambda a: a[-1], per_layer)
-        rows_held = rows_held + jnp.sum(per_layer["rows_held"])
-    stats["rows_held_all_layers"] = rows_held
+        dispatch.record("gdn_moe.rope",
+                        stack.rope_word(FULL, c.rotary_width, c.head_dim))
+    x, stats = stack.walk(_layer, c, segments(c), params["layers"], x,
+                          lambda kind: tables)
     with jax.named_scope(common.LOSS):
         return zero_centred_norm(x, params["final_norm_w"],
                                  c.rms_norm_eps), stats
 
 
-def _nll_and_stats(params, batch, config: GdnMoEConfig):
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    x, stats = forward_hidden(params, inputs, config)
-    if config.fused_ce:
-        return common.fused_nll(x, params["lm_head"], targets), stats
-    logits = common.tied_logits(x, params["lm_head"], config.dtype)
-    return common.logits_nll(logits, targets), stats
-
-
-def token_nll(params, batch, config: GdnMoEConfig):
-    """-log p(tokens[t+1] | tokens[:t+1]) for every position: [b, s] fp32.
-    batch: {"tokens": [b, s+1] int32}."""
-    return _nll_and_stats(params, batch, config)[0]
-
-
-def loss_and_metrics(params, batch, config: GdnMoEConfig):
-    """(next-token cross-entropy, the LAST layer's routing counts as `moe_*`
-    device scalars)."""
-    nll, stats = _nll_and_stats(params, batch, config)
-    mask = batch.get("mask")
-    loss = common.masked_mean(nll, None if mask is None else mask[:, 1:])
-    return loss, {f"moe_{k}": v for k, v in stats.items()}
-
-
-def loss_fn(params, batch, config: GdnMoEConfig):
-    """Next-token cross-entropy: the mean of `token_nll`, over the
-    positions batch["mask"] keeps if there is one."""
-    return loss_and_metrics(params, batch, config)[0]
+_TAIL = stack.LossTail(forward_hidden, head="lm_head")
+token_nll, loss_and_metrics = _TAIL.token_nll, _TAIL.loss_and_metrics
+loss_fn = _TAIL.loss_fn

@@ -4,7 +4,8 @@ in one model, for training through `ShardedTrainStep`.
 
 The same entry points as models/transformer.py (`init_params`,
 `logical_axes`, `forward`, `loss_fn`); embedding, tied head, fused
-cross-entropy, SwiGLU and the remat wrapper are models/common.py's.
+cross-entropy and SwiGLU are models/common.py's, the parameter tree, the
+layer function's remat and the loss tail models/stack.py's.
 
 Layer equations.  Every layer: h = x + Mixer(LN1(x)), out = h + MLP(LN2(h)).
 LN is LayerNorm with weight and bias (eps `layer_norm_eps`);
@@ -59,14 +60,13 @@ them from layer 0.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import common
+from ray_tpu.models import common, stack
 from ray_tpu.parallel.sharding import with_logical_constraint
 
 KINDS = ("mamba", "window", "full", "gmu", "cross")
@@ -181,10 +181,6 @@ def lambda_init(layer: int) -> float:
 # Parameters
 # ---------------------------------------------------------------------------
 
-def _normal(key, shape, dtype, std):
-    return (jax.random.normal(key, shape) * std).astype(dtype)
-
-
 def _mixer_shapes(kind: str, c: HybridConfig) -> Dict[str, Tuple]:
     """name -> (shape, logical axes, init): init is a fan-in for a matrix,
     or one of "zeros", "ones", "bias", "lambda", "a_log", "dt_bias"."""
@@ -225,89 +221,43 @@ def _mixer_shapes(kind: str, c: HybridConfig) -> Dict[str, Tuple]:
 
 
 def _layer_shapes(kind: str, c: HybridConfig) -> Dict[str, Tuple]:
-    h, m = c.hidden_size, c.intermediate_size
+    h = c.hidden_size
     return {
         "ln1_w": ((h,), (None,), "ones"), "ln1_b": ((h,), (None,), "zeros"),
         **_mixer_shapes(kind, c),
         "ln2_w": ((h,), (None,), "ones"), "ln2_b": ((h,), (None,), "zeros"),
-        "w_gate": ((h, m), ("embed", "mlp"), h),
-        "w_up": ((h, m), ("embed", "mlp"), h),
-        "w_down": ((m, h), ("mlp", "embed"), m),
+        **stack.swiglu_shapes("w", h, c.intermediate_size),
     }
 
 
-def _init_leaf(key, shape, init, c: HybridConfig):
-    pd = c.param_dtype
-    if init == "zeros":
-        return jnp.zeros(shape, pd)
-    if init == "ones":
-        return jnp.ones(shape, pd)
-    if init == "bias":
-        return _normal(key, shape, pd, 0.02)
-    if init == "lambda":
-        return _normal(key, shape, pd, 0.1)
-    if init == "a_log":         # A = -(1 .. state) for every channel
-        return jnp.broadcast_to(
-            jnp.log(jnp.arange(1, shape[1] + 1, dtype=F32)), shape).astype(pd)
-    if init == "dt_bias":       # softplus(dt_b) log-uniform in [1e-3, 1e-1]
-        dt = jnp.exp(jax.random.uniform(key, shape) * math.log(100.0)
-                     + math.log(1e-3))
-        return (dt + jnp.log(-jnp.expm1(-dt))).astype(pd)
-    return _normal(key, shape, pd, 1.0 / math.sqrt(init))
+def _dt_bias(key, shape):    # softplus(dt_b) log-uniform in [1e-3, 1e-1]
+    dt = jnp.exp(jax.random.uniform(key, shape) * math.log(100.0)
+                 + math.log(1e-3))
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
-def _init_layer(key, kind: str, c: HybridConfig) -> Dict[str, Any]:
-    shapes = _layer_shapes(kind, c)
-    keys = jax.random.split(key, len(shapes))
-    return {name: _init_leaf(k, shape, init, c)
-            for k, (name, (shape, _, init)) in zip(keys, shapes.items())}
+def _top_shapes(c: HybridConfig) -> Dict[str, Tuple]:
+    h = c.hidden_size
+    return {"tok_embed": ((c.vocab_size, h), ("vocab", "embed"), h),
+            "final_norm_w": ((h,), (None,), "ones"),
+            "final_norm_b": ((h,), (None,), "zeros")}
 
 
-def _segment_name(i: int) -> str:
-    return f"seg{i:02d}"
+_PARAMS = stack.Params(segments, _layer_shapes, _top_shapes, {
+    "bias": lambda key, shape: jax.random.normal(key, shape) * 0.02,
+    "lambda": lambda key, shape: jax.random.normal(key, shape) * 0.1,
+    # A = -(1 .. state) for every channel
+    "a_log": lambda key, shape: jnp.broadcast_to(
+        jnp.log(jnp.arange(1, shape[1] + 1, dtype=F32)), shape),
+    "dt_bias": _dt_bias})
+logical_axes, num_params = _PARAMS.logical_axes, _PARAMS.num_params
 
 
 def init_params(config: HybridConfig, key) -> Dict[str, Any]:
     """{"tok_embed", "layers": {segNN: {position in the pattern: layer
     parameters stacked on a leading repeats axis}}, "final_norm_w/_b"}."""
-    c = config
     k_embed, k_layers = jax.random.split(key)
-    layers = {}
-    for si, (pattern, first, repeats) in enumerate(segments(c)):
-        seg = {}
-        for pos, kind in enumerate(pattern):
-            each = [_init_layer(jax.random.fold_in(
-                k_layers, first + rep * len(pattern) + pos), kind, c)
-                for rep in range(repeats)]
-            seg[str(pos)] = jax.tree.map(lambda *a: jnp.stack(a), *each)
-        layers[_segment_name(si)] = seg
-    return {
-        "tok_embed": _normal(k_embed, (c.vocab_size, c.hidden_size),
-                             c.param_dtype, 1.0 / math.sqrt(c.hidden_size)),
-        "layers": layers,
-        "final_norm_w": jnp.ones((c.hidden_size,), c.param_dtype),
-        "final_norm_b": jnp.zeros((c.hidden_size,), c.param_dtype),
-    }
-
-
-def logical_axes(config: HybridConfig) -> Dict[str, Any]:
-    """Logical-axis tree matching init_params, for parallel.sharding."""
-    layers = {}
-    for si, (pattern, _, _) in enumerate(segments(config)):
-        layers[_segment_name(si)] = {
-            str(pos): {name: ("layers",) + axes for name, (_, axes, _)
-                       in _layer_shapes(kind, config).items()}
-            for pos, kind in enumerate(pattern)}
-    return {"tok_embed": ("vocab", "embed"), "layers": layers,
-            "final_norm_w": (None,), "final_norm_b": (None,)}
-
-
-def num_params(config: HybridConfig) -> int:
-    per_layer = sum(
-        math.prod(shape) for kind in config.layer_kinds
-        for shape, _, _ in _layer_shapes(kind, config).values())
-    return (config.vocab_size * config.hidden_size + per_layer
-            + 2 * config.hidden_size)
+    return _PARAMS.init(config, {"tok_embed": k_embed, "layers": k_layers})
 
 
 # ---------------------------------------------------------------------------
@@ -323,10 +273,7 @@ def layer_norm(x, w, b, eps):
             + b.astype(F32)).astype(dtype)
 
 
-def _matmul(x, w, c: HybridConfig, out_dtype=None):
-    """bf16 operands, fp32 accumulation; the result in `out_dtype`."""
-    return jnp.einsum("bsi,io->bso", x.astype(c.dtype), w.astype(c.dtype),
-                      preferred_element_type=out_dtype or c.dtype)
+_matmul = stack.matmul
 
 
 def recurrence(x, dt, a_log, b_t, c_t, d, config: HybridConfig):
@@ -454,22 +401,16 @@ def _layer(x, lp, lam_init, memory, shared_kv, *, kind: str,
     return with_logical_constraint(x, ("batch", "seq", "embed")), handed
 
 
-@functools.cache
-def _layer_fn(kind: str, c: HybridConfig):
-    """One function object a kind and config: JAX then traces a kind of
-    layer once for every segment that holds it, not once a segment."""
-    return common.maybe_remat(functools.partial(_layer, kind=kind, c=c),
-                              c.remat, c.remat_policy)
-
-
 def forward_hidden(params: Dict[str, Any], tokens, config: HybridConfig):
-    """Embedding + layers + final LayerNorm: [b, s] -> [b, s, hidden]."""
+    """Embedding + layers + final LayerNorm: [b, s] -> ([b, s, hidden],
+    None: no layer routes).  The walk is this file's own: unrolled single
+    layers hand a memory and a shared KV on to the scanned pairs."""
     c = config
     x = common.embed_tokens(params["tok_embed"], tokens, c.dtype)
     memory = shared_kv = None
     for si, (pattern, first, repeats) in enumerate(segments(c)):
-        seg = params["layers"][_segment_name(si)]
-        fns = [_layer_fn(kind, c) for kind in pattern]
+        seg = params["layers"][stack.segment_name(si)]
+        fns = [stack.layer_fn(_layer, kind, c) for kind in pattern]
         lam = jnp.asarray(
             [[lambda_init(first + rep * len(pattern) + pos)
               for pos in range(len(pattern))] for rep in range(repeats)], F32)
@@ -493,29 +434,8 @@ def forward_hidden(params: Dict[str, Any], tokens, config: HybridConfig):
         x, _ = jax.lax.scan(body, x, (seg, lam))
     with jax.named_scope(common.LOSS):
         return layer_norm(x, params["final_norm_w"], params["final_norm_b"],
-                          c.layer_norm_eps)
+                          c.layer_norm_eps), None
 
 
-def forward(params: Dict[str, Any], tokens, config: HybridConfig):
-    """tokens [b, s] int32 -> logits [b, s, vocab] (fp32)."""
-    x = forward_hidden(params, tokens, config)
-    return common.tied_logits(x, params["tok_embed"], config.dtype)
-
-
-def token_nll(params, batch, config: HybridConfig):
-    """-log p(tokens[t+1] | tokens[:t+1]) for every position: [b, s] fp32.
-    batch: {"tokens": [b, s+1] int32}."""
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    if config.fused_ce:
-        return common.fused_nll(forward_hidden(params, inputs, config),
-                                params["tok_embed"], targets)
-    return common.logits_nll(forward(params, inputs, config), targets)
-
-
-def loss_fn(params, batch, config: HybridConfig):
-    """Next-token cross-entropy: the mean of `token_nll`, over the
-    positions batch["mask"] keeps if there is one."""
-    mask = batch.get("mask")
-    return common.masked_mean(token_nll(params, batch, config),
-                              None if mask is None else mask[:, 1:])
+_TAIL = stack.LossTail(forward_hidden, head="tok_embed")
+forward, token_nll, loss_fn = _TAIL.forward, _TAIL.token_nll, _TAIL.loss_fn

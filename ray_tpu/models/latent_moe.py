@@ -1,8 +1,6 @@
 """Latent-attention, routed-expert decoder (the DeepSeek-V3 form, as
-Kanana-2-30B-A3B publishes it) for training through `ShardedTrainStep`: the
-same entry points as models/transformer.py and models/hybrid.py
-(`init_params`, `logical_axes`, `num_params`, `loss_fn`, `token_nll`);
-embedding, fused cross-entropy, SwiGLU and the remat wrapper are
+Kanana-2-30B-A3B publishes it) for training through `ShardedTrainStep`, on
+models/stack.py's layer stack; embedding, cross-entropy and SwiGLU are
 models/common.py's, the routed experts models/moe.py's dropless layer.
 
 Layer equations (h the layer's input, T tokens; every matrix [in, out], no
@@ -32,14 +30,10 @@ bias anywhere):
              Shared ONE SwiGLU of `n_shared_experts` x that width.  No
              auxiliary loss.
 
-One chip's share.  `n_routed_experts` is how many experts THIS program
-holds (experts `first_held_expert` on), `router_width` how many the model
-routes over.  The router and the top-k run over all of them; the layer
-computes the held experts' terms and the shared expert; what the absent
-experts would add is left out and that partial result goes on to the next
-layer (expert parallelism without its exchange: the other chips' terms are
-the other chips').  With router_width == n_routed_experts it is the whole
-layer.
+One chip's share (`stack.routed_part`).  `n_routed_experts` is how many
+experts THIS program holds (experts `first_held_expert` on), `router_width`
+how many the model routes over; the shared expert is computed whole.  With
+router_width == n_routed_experts it is the whole layer.
 
 The selection bias `router_bias` is a leaf of the parameters that is not
 trained (`not_trained`): it enters the selection only, gets no gradient,
@@ -47,19 +41,15 @@ and the step leaves it as it is (its published update rule is a
 load-balancing controller outside the optimiser, and its rate is not
 published).
 
-The program.  The dense layers are one segment, the expert layers another,
-each with its parameters stacked on a leading repeats axis and scanned:
-`params["layers"][segNN]["0"][leaf][repeat]`, the layout models/hybrid.py
-has.  `loss_and_metrics` also gives the LAST expert layer's routing counts
-(`moe_rows_held`, `moe_load_max`, `moe_load_mean`, `moe_rows_bound`) and
-the rows all the expert layers held together (`moe_rows_held_all_layers`)
-as device scalars, which `ShardedTrainStep` carries in the step's metrics.
+The program.  The dense layers are one segment, the expert layers another.
+`loss_and_metrics` also gives the LAST expert layer's routing counts
+(`moe_rows_held`, `moe_load_max`, `moe_load_mean`, `moe_rows_bound`) and the
+rows all the expert layers held together (`moe_rows_held_all_layers`).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -67,7 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import common, moe
+from ray_tpu.models import common, moe, stack
 from ray_tpu.models.transformer import rms_norm
 from ray_tpu.parallel.sharding import with_logical_constraint
 
@@ -145,11 +135,6 @@ class LatentMoEConfig:
     def experts_held(self) -> Tuple[int, int]:
         return self.first_held_expert, self.n_routed_experts
 
-    @property
-    def layer_kinds(self) -> Tuple[str, ...]:
-        dense = self.first_k_dense_replace
-        return ("dense",) * dense + ("moe",) * (self.num_hidden_layers - dense)
-
     @classmethod
     def tiny(cls, **kw) -> "LatentMoEConfig":
         """Test-sized: both kinds of layer, a share of the experts."""
@@ -169,10 +154,6 @@ def segments(config: LatentMoEConfig) -> List[Tuple[str, int, int]]:
     if config.num_hidden_layers > dense:
         out.append(("moe", dense, config.num_hidden_layers - dense))
     return out
-
-
-def _segment_name(i: int) -> str:
-    return f"seg{i:02d}"
 
 
 # ---------------------------------------------------------------------------
@@ -196,109 +177,47 @@ def _layer_shapes(kind: str, c: LatentMoEConfig) -> Dict[str, Tuple]:
         "ln2_w": ((h,), (None,), "ones"),
     }
     if kind == "dense":
-        m = c.intermediate_size
-        shapes.update({
-            "w_gate": ((h, m), ("embed", "mlp"), h),
-            "w_up": ((h, m), ("embed", "mlp"), h),
-            "w_down": ((m, h), ("mlp", "embed"), m)})
-        return shapes
-    m, held = c.moe_intermediate_size, c.n_routed_experts
-    shared = c.n_shared_experts * m
-    shapes.update({
+        return {**shapes, **stack.swiglu_shapes("w", h, c.intermediate_size)}
+    m = c.moe_intermediate_size
+    return {
+        **shapes,
         "router_w": ((h, c.router_width), ("embed", None), h),
         "router_bias": ((c.router_width,), (None,), "select_bias"),
-        "experts_gate": ((held, h, m), ("expert", "embed", "mlp"), h),
-        "experts_up": ((held, h, m), ("expert", "embed", "mlp"), h),
-        "experts_down": ((held, m, h), ("expert", "mlp", "embed"), m),
-        "shared_gate": ((h, shared), ("embed", "mlp"), h),
-        "shared_up": ((h, shared), ("embed", "mlp"), h),
-        "shared_down": ((shared, h), ("mlp", "embed"), shared)})
-    return shapes
+        **stack.swiglu_shapes("experts", h, m, c.n_routed_experts),
+        **stack.swiglu_shapes("shared", h, c.n_shared_experts * m)}
 
 
-def _normal(key, shape, dtype, std):
-    return (jax.random.normal(key, shape) * std).astype(dtype)
+def _top_shapes(c: LatentMoEConfig) -> Dict[str, Tuple]:
+    table = ((c.vocab_size, c.hidden_size), ("vocab", "embed"), c.hidden_size)
+    return {"tok_embed": table, "lm_head": table,
+            "final_norm_w": ((c.hidden_size,), (None,), "ones")}
 
 
-def _init_layer(key, kind: str, c: LatentMoEConfig) -> Dict[str, Any]:
-    shapes = _layer_shapes(kind, c)
-    out = {}
-    for k, (name, (shape, _, init)) in zip(
-            jax.random.split(key, len(shapes)), shapes.items()):
-        if init == "ones":
-            out[name] = jnp.ones(shape, c.param_dtype)
-        elif init == "select_bias":
-            out[name] = _normal(k, shape, c.param_dtype, 0.01)
-        else:
-            out[name] = _normal(k, shape, c.param_dtype,
-                                1.0 / math.sqrt(init))
-    return out
+_PARAMS = stack.Params(
+    stack.one_kind(segments), _layer_shapes, _top_shapes,
+    {"select_bias": lambda key, shape: jax.random.normal(key, shape) * 0.01})
+logical_axes, num_params = _PARAMS.logical_axes, _PARAMS.num_params
 
 
 def init_params(config: LatentMoEConfig, key) -> Dict[str, Any]:
     """{"tok_embed", "layers": {segNN: {"0": layer parameters stacked on a
     leading repeats axis}}, "final_norm_w", "lm_head" [vocab, hidden]}."""
-    c = config
     k_embed, k_head, k_layers = jax.random.split(key, 3)
-    std = 1.0 / math.sqrt(c.hidden_size)
-    layers = {}
-    for si, (kind, first, repeats) in enumerate(segments(c)):
-        each = [_init_layer(jax.random.fold_in(k_layers, first + rep), kind, c)
-                for rep in range(repeats)]
-        layers[_segment_name(si)] = {
-            "0": jax.tree.map(lambda *a: jnp.stack(a), *each)}
-    return {
-        "tok_embed": _normal(k_embed, (c.vocab_size, c.hidden_size),
-                             c.param_dtype, std),
-        "layers": layers,
-        "final_norm_w": jnp.ones((c.hidden_size,), c.param_dtype),
-        "lm_head": _normal(k_head, (c.vocab_size, c.hidden_size),
-                           c.param_dtype, std),
-    }
-
-
-def _leaf_tree(config: LatentMoEConfig, leaf, top):
-    """The parameters' tree with leaf(name, (shape, axes, init)) at every
-    layer leaf and top(name) at the others."""
-    layers = {
-        _segment_name(si): {"0": {
-            name: leaf(name, spec)
-            for name, spec in _layer_shapes(kind, config).items()}}
-        for si, (kind, _, _) in enumerate(segments(config))}
-    return {"tok_embed": top("tok_embed"), "layers": layers,
-            "final_norm_w": top("final_norm_w"), "lm_head": top("lm_head")}
-
-
-def logical_axes(config: LatentMoEConfig) -> Dict[str, Any]:
-    """Logical-axis tree matching init_params, for parallel.sharding."""
-    tops = {"tok_embed": ("vocab", "embed"), "final_norm_w": (None,),
-            "lm_head": ("vocab", "embed")}
-    return _leaf_tree(config, lambda name, spec: ("layers",) + spec[1],
-                      tops.__getitem__)
+    return _PARAMS.init(config, {"tok_embed": k_embed, "lm_head": k_head,
+                                 "layers": k_layers})
 
 
 def not_trained(config: LatentMoEConfig) -> Dict[str, Any]:
     """True at the leaves a train step leaves as they are: the router's
     selection bias."""
-    return _leaf_tree(config, lambda name, spec: name == "router_bias",
-                      lambda name: False)
-
-
-def num_params(config: LatentMoEConfig) -> int:
-    per_layer = sum(math.prod(shape) for kind in config.layer_kinds
-                    for shape, _, _ in _layer_shapes(kind, config).values())
-    return (2 * config.vocab_size * config.hidden_size + per_layer
-            + config.hidden_size)
+    return _PARAMS.tree(config, lambda name, spec: name == "router_bias")
 
 
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
-def _matmul(x, w, c: LatentMoEConfig):
-    """bf16 operands, fp32 accumulation, the result in the compute dtype."""
-    return jnp.einsum("bsi,io->bso", x.astype(c.dtype), w.astype(c.dtype),
-                      preferred_element_type=c.dtype)
+_matmul = stack.matmul
 
 
 def rope_tables(seq: int, c: LatentMoEConfig):
@@ -372,20 +291,17 @@ def _attention(u, lp, cos, sin, c: LatentMoEConfig):
 
 def _routed_part(flat, router_w, router_bias, w_gate, w_up, w_down,
                  c: LatentMoEConfig):
-    """The router and models/moe.py's dropless layer for this chip's share:
-    flat [T, hidden] -> (the held experts' sum, the routing counts).  The
-    usual buffer holds twice the rows even routing sends here; a step that
-    sends more takes the full bound's."""
-    with jax.named_scope(common.MOE_ROUTE):
-        idx, gates = moe.sigmoid_route(
+    """`stack.routed_part` behind the sigmoid router: flat [T, hidden] ->
+    (the held experts' sum, the routing counts).  The usual buffer holds
+    TWICE the rows even routing sends here: this cell holds an eighth of
+    the experts (models/swa_moe.py's `USUAL_LOAD` has the readings that
+    made a thirty-second's share 4)."""
+    return stack.routed_part(
+        flat, lambda: moe.sigmoid_route(
             flat, router_w, router_bias,
             num_experts_per_token=c.num_experts_per_tok,
-            scale=c.routed_scaling_factor)
-    even = -(-flat.shape[0] * c.num_experts_per_tok * c.n_routed_experts
-             // c.router_width)
-    return moe.routed_experts(
-        flat, idx, gates, w_gate, w_up, w_down, experts_held=c.experts_held,
-        dtype=c.dtype, usual_rows=2 * even)
+            scale=c.routed_scaling_factor),
+        w_gate, w_up, w_down, c, 2)
 
 
 def routed_experts(h, router_w, router_bias, w_gate, w_up, w_down,
@@ -400,12 +316,13 @@ def routed_experts(h, router_w, router_bias, w_gate, w_up, w_down,
     return y.reshape(h.shape)
 
 
-def _layer(x, lp, cos, sin, *, kind: str, c: LatentMoEConfig):
-    """One layer -> (x, the routing counts of an expert layer or None)."""
+def _layer(x, lp, tables, *, kind: str, c: LatentMoEConfig):
+    """One layer, tables its (cos, sin) -> (x, the routing counts of an
+    expert layer or None)."""
     with jax.named_scope(common.ATTN_FULL):
         u = rms_norm(x, lp["ln1_w"], c.rms_norm_eps)
         u = with_logical_constraint(u, ("batch", "seq", "embed"))
-        mixed = _attention(u, lp, cos, sin, c)
+        mixed = _attention(u, lp, *tables, c)
     x = with_logical_constraint(x + mixed, ("batch", "seq", "embed"))
     with jax.named_scope(common.MLP):
         y = rms_norm(x, lp["ln2_w"], c.rms_norm_eps)
@@ -423,12 +340,6 @@ def _layer(x, lp, cos, sin, *, kind: str, c: LatentMoEConfig):
     return with_logical_constraint(x + ffn, ("batch", "seq", "embed")), stats
 
 
-@functools.cache
-def _layer_fn(kind: str, c: LatentMoEConfig):
-    return common.maybe_remat(functools.partial(_layer, kind=kind, c=c),
-                              c.remat, c.remat_policy)
-
-
 def forward_hidden(params: Dict[str, Any], tokens, config: LatentMoEConfig):
     """Embedding + layers + final RMSNorm: [b, s] -> ([b, s, hidden], the
     LAST expert layer's routing counts and the rows all the expert layers
@@ -436,55 +347,13 @@ def forward_hidden(params: Dict[str, Any], tokens, config: LatentMoEConfig):
     c = config
     x = common.embed_tokens(params["tok_embed"], tokens, c.dtype)
     with jax.named_scope(common.ATTN_FULL):     # the tables are attention's
-        cos, sin = rope_tables(tokens.shape[1], c)
-    stats = None
-    for si, (kind, _, _) in enumerate(segments(c)):
-        fn = _layer_fn(kind, c)
-
-        def body(x, lp, fn=fn):
-            return fn(x, lp, cos, sin)
-
-        x, per_layer = jax.lax.scan(
-            body, x, params["layers"][_segment_name(si)]["0"])
-        if per_layer is not None:
-            stats = jax.tree.map(lambda a: a[-1], per_layer)
-            stats["rows_held_all_layers"] = jnp.sum(per_layer["rows_held"])
+        tables = rope_tables(tokens.shape[1], c)
+    x, stats = stack.walk(_layer, c, segments(c), params["layers"], x,
+                          lambda kind: tables)
     with jax.named_scope(common.LOSS):
         return rms_norm(x, params["final_norm_w"], c.rms_norm_eps), stats
 
 
-def forward(params: Dict[str, Any], tokens, config: LatentMoEConfig):
-    """tokens [b, s] int32 -> logits [b, s, vocab] (fp32)."""
-    x, _ = forward_hidden(params, tokens, config)
-    return common.tied_logits(x, params["lm_head"], config.dtype)
-
-
-def _nll_and_stats(params, batch, config: LatentMoEConfig):
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    x, stats = forward_hidden(params, inputs, config)
-    if config.fused_ce:
-        return common.fused_nll(x, params["lm_head"], targets), stats
-    logits = common.tied_logits(x, params["lm_head"], config.dtype)
-    return common.logits_nll(logits, targets), stats
-
-
-def token_nll(params, batch, config: LatentMoEConfig):
-    """-log p(tokens[t+1] | tokens[:t+1]) for every position: [b, s] fp32.
-    batch: {"tokens": [b, s+1] int32}."""
-    return _nll_and_stats(params, batch, config)[0]
-
-
-def loss_and_metrics(params, batch, config: LatentMoEConfig):
-    """(next-token cross-entropy, the LAST expert layer's routing counts
-    as `moe_*` device scalars; none without an expert layer)."""
-    nll, stats = _nll_and_stats(params, batch, config)
-    mask = batch.get("mask")
-    loss = common.masked_mean(nll, None if mask is None else mask[:, 1:])
-    return loss, {f"moe_{k}": v for k, v in (stats or {}).items()}
-
-
-def loss_fn(params, batch, config: LatentMoEConfig):
-    """Next-token cross-entropy: the mean of `token_nll`, over the
-    positions batch["mask"] keeps if there is one."""
-    return loss_and_metrics(params, batch, config)[0]
+_TAIL = stack.LossTail(forward_hidden, head="lm_head")
+forward, token_nll = _TAIL.forward, _TAIL.token_nll
+loss_and_metrics, loss_fn = _TAIL.loss_and_metrics, _TAIL.loss_fn

@@ -1,10 +1,9 @@
 """Windowed / full grouped-query attention with per-head output gates and a
 softmax-routed expert layer (the `laguna` form, as Laguna-S-2.1 publishes
-it) for training through `ShardedTrainStep`: the same entry points as the
-other model files (`init_params`, `logical_axes`, `num_params`, `loss_fn`,
-`token_nll`, `loss_and_metrics`); embedding, fused cross-entropy, SwiGLU and
-the remat wrapper are models/common.py's, the routed experts models/moe.py's
-dropless layer, attention ops/attention.py's flash kernels.
+it) for training through `ShardedTrainStep`, on models/stack.py's layer
+stack; embedding, cross-entropy and SwiGLU are models/common.py's, the routed
+experts models/moe.py's dropless layer, attention ops/attention.py's flash
+kernels.
 
 Layer equations (x the layer's input [s, hidden]; every matrix [in, out], no
 bias anywhere; layer l has H_l query heads, `num_attention_heads_per_layer`,
@@ -32,33 +31,24 @@ over `num_key_value_heads` KV heads of `head_dim`, group g_l = H_l / KV):
              `moe_intermediate_size` wide, Shared ONE ungated SwiGLU of
              `shared_expert_intermediate_size`.  No auxiliary loss.
 
-One chip's share.  `num_experts` is how many experts THIS program holds
-(experts `first_held_expert` on), `router_width` how many the model routes
-over: models/latent_moe.py's convention.  The router and the top-k run over
-all of them; the layer computes the held experts' terms and the shared
-expert; what the absent experts would add is left out and that partial
-result goes on to the next layer.
+One chip's share (`stack.routed_part`): `num_experts` is how many experts
+THIS program holds (experts `first_held_expert` on), `router_width` how many
+the model routes over; the shared expert is computed whole.
 
 The program.  Parameter shapes differ by kind of layer (W_q, W_g and W_o by
 the head count), so the stack is segments: maximal runs of layers of one
-kind (attention kind, FFN kind), each with its parameters stacked on a
-leading repeats axis and scanned: `params["layers"][segNN]["0"][leaf]
-[repeat]`, the layout models/hybrid.py and models/latent_moe.py have.  The
-published pattern's first five layers are three segments: [full + dense],
-[sliding + experts] x 3, [full + experts].
+kind (attention kind, FFN kind).  The published pattern's first five layers
+are three segments: [full + dense], [sliding + experts] x 3, [full +
+experts].
 
 How rope reaches the kernels.  Every layer calls `flash_attention(...,
 rope=(cos, sin))` on un-roped q and k and the kernels rope the tiles they
-load, pairing column i with column i + d/2 over the WHOLE head.  A sliding
-layer's rope is exactly that.  A full layer's partial rope is made so by
-one reordering of W_q's and W_k's columns AT USE (`_rotary_first_halves`: a
-head's [rot_a | rot_b | pass_a | pass_b] becomes [rot_a | pass_a | rot_b |
-pass_b], a transpose, exact; q . k does not see one permutation of both, and
-the parameter tree keeps the published order) and tables whose pass-through
-columns hold cos 1 and sin 0: the kernel's whole-head turn is then the
-published half turn and the identity on the rest, so nothing of rope stays
-in XLA (`dispatch.taken()["swa_moe.rope"]` says so).  GQA's repeat of k and
-v is the model's, as the kernels' contract has it.
+load, over the WHOLE head: a sliding layer's rope is exactly that, a full
+layer's half rope is made so by W_q's and W_k's columns reordered AT USE
+(`stack.rotary_first`; the parameter tree keeps the published order) and
+tables with an identity tail (`stack.kernel_tables`), so nothing of rope
+stays in XLA (`dispatch.taken()["swa_moe.rope"]` says so).  GQA's repeat of
+k and v is the model's, as the kernels' contract has it.
 
 `loss_and_metrics` also gives the LAST expert layer's routing counts
 (`moe_rows_held`, `moe_load_max`, `moe_load_mean`, `moe_rows_bound`) and the
@@ -76,7 +66,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import common, moe
+from ray_tpu.models import common, moe, stack
 from ray_tpu.models.transformer import rms_norm
 from ray_tpu.ops import dispatch
 from ray_tpu.parallel.sharding import with_logical_constraint
@@ -244,17 +234,7 @@ class SwaMoEConfig:
 def segments(config: SwaMoEConfig) -> List[Tuple[Tuple[str, int, str], int,
                                                  int]]:
     """(kind, first layer, repeats): maximal runs of layers of one kind."""
-    out: List[Tuple[Tuple[str, int, str], int, int]] = []
-    for i, kind in enumerate(config.layer_kinds):
-        if out and out[-1][0] == kind:
-            out[-1] = (kind, out[-1][1], out[-1][2] + 1)
-        else:
-            out.append((kind, i, 1))
-    return out
-
-
-def _segment_name(i: int) -> str:
-    return f"seg{i:02d}"
+    return stack.runs(config.layer_kinds)
 
 
 # ---------------------------------------------------------------------------
@@ -277,76 +257,33 @@ def _layer_shapes(kind: Tuple[str, int, str], c: SwaMoEConfig
         "ln2_w": ((h,), (None,), "ones"),
     }
     if ffn == DENSE:
-        m = c.intermediate_size
-        shapes.update({
-            "w_gate": ((h, m), ("embed", "mlp"), h),
-            "w_up": ((h, m), ("embed", "mlp"), h),
-            "w_down": ((m, h), ("mlp", "embed"), m)})
-        return shapes
-    m, held = c.moe_intermediate_size, c.num_experts
-    shared = c.shared_expert_intermediate_size
-    shapes.update({
-        "router_w": ((h, c.router_width), ("embed", None), h),
-        "experts_gate": ((held, h, m), ("expert", "embed", "mlp"), h),
-        "experts_up": ((held, h, m), ("expert", "embed", "mlp"), h),
-        "experts_down": ((held, m, h), ("expert", "mlp", "embed"), m),
-        "shared_gate": ((h, shared), ("embed", "mlp"), h),
-        "shared_up": ((h, shared), ("embed", "mlp"), h),
-        "shared_down": ((shared, h), ("mlp", "embed"), shared)})
-    return shapes
-
-
-def _normal(key, shape, dtype, std):
-    return (jax.random.normal(key, shape) * std).astype(dtype)
-
-
-def _init_layer(key, kind, c: SwaMoEConfig) -> Dict[str, Any]:
-    shapes = _layer_shapes(kind, c)
+        return {**shapes, **stack.swiglu_shapes("w", h, c.intermediate_size)}
     return {
-        name: jnp.ones(shape, c.param_dtype) if init == "ones"
-        else _normal(k, shape, c.param_dtype, 1.0 / math.sqrt(init))
-        for k, (name, (shape, _, init)) in zip(
-            jax.random.split(key, len(shapes)), shapes.items())}
+        **shapes,
+        "router_w": ((h, c.router_width), ("embed", None), h),
+        **stack.swiglu_shapes("experts", h, c.moe_intermediate_size,
+                              c.num_experts),
+        **stack.swiglu_shapes("shared", h,
+                              c.shared_expert_intermediate_size)}
+
+
+def _top_shapes(c: SwaMoEConfig) -> Dict[str, Tuple]:
+    table = ((c.vocab_size, c.hidden_size), ("vocab", "embed"), c.hidden_size)
+    return {"tok_embed": table, "lm_head": table,
+            "final_norm_w": ((c.hidden_size,), (None,), "ones")}
+
+
+_PARAMS = stack.Params(stack.one_kind(segments), _layer_shapes,
+                       _top_shapes)
+logical_axes, num_params = _PARAMS.logical_axes, _PARAMS.num_params
 
 
 def init_params(config: SwaMoEConfig, key) -> Dict[str, Any]:
     """{"tok_embed", "layers": {segNN: {"0": layer parameters stacked on a
     leading repeats axis}}, "final_norm_w", "lm_head" [vocab, hidden]}."""
-    c = config
     k_embed, k_head, k_layers = jax.random.split(key, 3)
-    std = 1.0 / math.sqrt(c.hidden_size)
-    layers = {}
-    for si, (kind, first, repeats) in enumerate(segments(c)):
-        each = [_init_layer(jax.random.fold_in(k_layers, first + rep), kind, c)
-                for rep in range(repeats)]
-        layers[_segment_name(si)] = {
-            "0": jax.tree.map(lambda *a: jnp.stack(a), *each)}
-    return {
-        "tok_embed": _normal(k_embed, (c.vocab_size, c.hidden_size),
-                             c.param_dtype, std),
-        "layers": layers,
-        "final_norm_w": jnp.ones((c.hidden_size,), c.param_dtype),
-        "lm_head": _normal(k_head, (c.vocab_size, c.hidden_size),
-                           c.param_dtype, std),
-    }
-
-
-def logical_axes(config: SwaMoEConfig) -> Dict[str, Any]:
-    """Logical-axis tree matching init_params, for parallel.sharding."""
-    layers = {
-        _segment_name(si): {"0": {
-            name: ("layers",) + axes
-            for name, (_, axes, _) in _layer_shapes(kind, config).items()}}
-        for si, (kind, _, _) in enumerate(segments(config))}
-    return {"tok_embed": ("vocab", "embed"), "layers": layers,
-            "final_norm_w": (None,), "lm_head": ("vocab", "embed")}
-
-
-def num_params(config: SwaMoEConfig) -> int:
-    per_layer = sum(math.prod(shape) for kind in config.layer_kinds
-                    for shape, _, _ in _layer_shapes(kind, config).values())
-    return (2 * config.vocab_size * config.hidden_size + per_layer
-            + config.hidden_size)
+    return _PARAMS.init(config, {"tok_embed": k_embed, "lm_head": k_head,
+                                 "layers": k_layers})
 
 
 # ---------------------------------------------------------------------------
@@ -383,54 +320,33 @@ def rope_tables(seq: int, kind: str, c: SwaMoEConfig):
     both where the kind's rope has one."""
     rope, width = c.rope[kind], c.rotary_width(kind)
     if rope.get("rope_type", "default") == "yarn":
-        inv_freq = yarn_inv_freq(rope, width)
+        angle = jnp.arange(seq, dtype=F32)[:, None] \
+            * yarn_inv_freq(rope, width)[None, :]
+        cos, sin = jnp.cos(angle), jnp.sin(angle)
     else:
-        inv_freq = 1.0 / float(rope["rope_theta"]) ** (
-            2.0 * jnp.arange(width // 2, dtype=F32) / width)
-    angle = jnp.arange(seq, dtype=F32)[:, None] * inv_freq[None, :]
+        cos, sin = stack.rope_tables(seq, width, rope["rope_theta"])
     factor = float(rope.get("attention_factor", 1.0))
-    return jnp.cos(angle) * factor, jnp.sin(angle) * factor
+    return cos * factor, sin * factor
 
 
 def kernel_tables(seq: int, kind: str, c: SwaMoEConfig):
     """The tables as the flash kernels take them for a head whose columns
-    `_rotary_first_halves` has ordered: [seq, head_dim / 2], the rotary
-    pairs' cos and sin, then cos 1 and sin 0 for the pairs that pass
-    through."""
-    cos, sin = rope_tables(seq, kind, c)
-    passing = (c.head_dim - c.rotary_width(kind)) // 2
-    if not passing:
-        return cos, sin
-    return (jnp.concatenate([cos, jnp.ones((seq, passing), F32)], axis=1),
-            jnp.concatenate([sin, jnp.zeros((seq, passing), F32)], axis=1))
+    `_rotary_first_halves` has ordered (`stack.kernel_tables`)."""
+    return stack.kernel_tables(*rope_tables(seq, kind, c), c.head_dim)
 
 
 def _rotary_first_halves(w, heads: int, c: SwaMoEConfig, kind: str):
-    """w [hidden, heads x d], a head's columns as published: [rot_a | rot_b
-    | pass_a | pass_b], the rotary pair i being (rot_a[i], rot_b[i]).  ->
-    the same with every head's columns as [rot_a | pass_a | rot_b |
-    pass_b]: pair i is then (i, i + d/2) of the whole head, which the
-    flash kernels' rope turns.  A transpose of the weight, exact in any
-    dtype; its gradient is the transpose back."""
-    d, r = c.head_dim, c.rotary_width(kind)
-    if r == d:
-        return w
-    if 2 * r != d:
-        raise ValueError("a partial rope reaches the kernels by halves: "
-                         f"the rotary width {r} is not half of {d}")
-    h = w.shape[0]
-    w = w.reshape(h, heads, 2, 2, d // 4)       # [.., rot | pass, a | b, .]
-    return w.transpose(0, 1, 3, 2, 4).reshape(h, heads * d)
+    """w [hidden, heads x d], a head's columns as published -> as the flash
+    kernels' rope pairs them (`stack.rotary_first`); a sliding layer's, all
+    rotary, as they are."""
+    return stack.rotary_first(w, heads, c.rotary_width(kind))
 
 
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
-def _matmul(x, w, c: SwaMoEConfig, out_dtype=None):
-    """bf16 operands, fp32 accumulation, the result in the compute dtype."""
-    return jnp.einsum("bsi,io->bso", x.astype(c.dtype), w.astype(c.dtype),
-                      preferred_element_type=out_dtype or c.dtype)
+_matmul = stack.matmul
 
 
 def _attention(u, lp, tables, *, kind: str, heads: int, c: SwaMoEConfig):
@@ -462,19 +378,13 @@ def _attention(u, lp, tables, *, kind: str, heads: int, c: SwaMoEConfig):
 
 
 def _routed_part(flat, router_w, w_gate, w_up, w_down, c: SwaMoEConfig):
-    """The router and models/moe.py's dropless layer for this chip's share:
-    flat [T, hidden] -> (the held experts' sum, the routing counts).  The
-    usual buffer holds `USUAL_LOAD` times the rows even routing sends here;
-    a step that sends more takes the full bound's."""
-    with jax.named_scope(common.MOE_ROUTE):
-        idx, gates = moe.softmax_route(
+    """`stack.routed_part` behind the softmax router: flat [T, hidden] ->
+    (the held experts' sum, the routing counts)."""
+    return stack.routed_part(
+        flat, lambda: moe.softmax_route(
             flat, router_w, num_experts_per_token=c.num_experts_per_tok,
-            scale=c.moe_routed_scaling_factor)
-    even = -(-flat.shape[0] * c.num_experts_per_tok * c.num_experts
-             // c.router_width)
-    return moe.routed_experts(
-        flat, idx, gates, w_gate, w_up, w_down, experts_held=c.experts_held,
-        dtype=c.dtype, usual_rows=USUAL_LOAD * even)
+            scale=c.moe_routed_scaling_factor),
+        w_gate, w_up, w_down, c, USUAL_LOAD)
 
 
 def routed_experts(h, router_w, w_gate, w_up, w_down, config: SwaMoEConfig):
@@ -513,12 +423,6 @@ def _layer(x, lp, tables, *, kind: Tuple[str, int, str], c: SwaMoEConfig):
     return with_logical_constraint(x + ffn, ("batch", "seq", "embed")), stats
 
 
-@functools.cache
-def _layer_fn(kind: Tuple[str, int, str], c: SwaMoEConfig):
-    return common.maybe_remat(functools.partial(_layer, kind=kind, c=c),
-                              c.remat, c.remat_policy)
-
-
 def forward_hidden(params: Dict[str, Any], tokens, config: SwaMoEConfig):
     """Embedding + layers + final RMSNorm: [b, s] -> ([b, s, hidden], the
     LAST expert layer's routing counts and the rows all the expert layers
@@ -531,54 +435,14 @@ def forward_hidden(params: Dict[str, Any], tokens, config: SwaMoEConfig):
                              else common.ATTN_SLIDING):
             tables[kind] = kernel_tables(tokens.shape[1], kind, c)
     dispatch.record("swa_moe.rope", ",".join(
-        f"{kind}:in_kernel{c.rotary_width(kind)}of{c.head_dim}"
-        + ("" if c.rotary_width(kind) == c.head_dim
-           else "_columns_reordered_at_use_identity_tail")
+        stack.rope_word(kind, c.rotary_width(kind), c.head_dim)
         for kind in tables))
-    stats, rows_held = None, 0
-    for si, (kind, _, _) in enumerate(segments(c)):
-        fn = _layer_fn(kind, c)
-
-        def body(x, lp, fn=fn, tables=tables[kind[0]]):
-            return fn(x, lp, tables)
-
-        x, per_layer = jax.lax.scan(
-            body, x, params["layers"][_segment_name(si)]["0"])
-        if per_layer is not None:
-            stats = jax.tree.map(lambda a: a[-1], per_layer)
-            rows_held = rows_held + jnp.sum(per_layer["rows_held"])
-    if stats is not None:
-        stats["rows_held_all_layers"] = rows_held
+    x, stats = stack.walk(_layer, c, segments(c), params["layers"], x,
+                          lambda kind: tables[kind[0]])
     with jax.named_scope(common.LOSS):
         return rms_norm(x, params["final_norm_w"], c.rms_norm_eps), stats
 
 
-def _nll_and_stats(params, batch, config: SwaMoEConfig):
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    x, stats = forward_hidden(params, inputs, config)
-    if config.fused_ce:
-        return common.fused_nll(x, params["lm_head"], targets), stats
-    logits = common.tied_logits(x, params["lm_head"], config.dtype)
-    return common.logits_nll(logits, targets), stats
-
-
-def token_nll(params, batch, config: SwaMoEConfig):
-    """-log p(tokens[t+1] | tokens[:t+1]) for every position: [b, s] fp32.
-    batch: {"tokens": [b, s+1] int32}."""
-    return _nll_and_stats(params, batch, config)[0]
-
-
-def loss_and_metrics(params, batch, config: SwaMoEConfig):
-    """(next-token cross-entropy, the LAST expert layer's routing counts
-    as `moe_*` device scalars; none without an expert layer)."""
-    nll, stats = _nll_and_stats(params, batch, config)
-    mask = batch.get("mask")
-    loss = common.masked_mean(nll, None if mask is None else mask[:, 1:])
-    return loss, {f"moe_{k}": v for k, v in (stats or {}).items()}
-
-
-def loss_fn(params, batch, config: SwaMoEConfig):
-    """Next-token cross-entropy: the mean of `token_nll`, over the
-    positions batch["mask"] keeps if there is one."""
-    return loss_and_metrics(params, batch, config)[0]
+_TAIL = stack.LossTail(forward_hidden, head="lm_head")
+token_nll, loss_and_metrics = _TAIL.token_nll, _TAIL.loss_and_metrics
+loss_fn = _TAIL.loss_fn

@@ -8,9 +8,8 @@ collectives for TP.  This module owns that step; trainers (train/),
 learners (rl/) and the bench harness all reuse it.
 
 The model is the configuration's: a config dataclass lives in its model's
-module (models/transformer.py's TransformerConfig, models/hybrid.py's
-HybridConfig, models/latent_moe.py's LatentMoEConfig), and that module
-offers `init_params(config, key)`, `logical_axes(config)` and
+module (every file under models/ that defines one), and that module offers
+`init_params(config, key)`, `logical_axes(config)` and
 `loss_fn(params, batch, config)`.  A module may also offer
 `loss_and_metrics(params, batch, config) -> (loss, {name: device scalar})`,
 whose scalars then ride in the step's metrics (an expert layer's routing

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmark.reference import deepseek_v3_mla_moe as ref
-from ray_tpu.models import common, latent_moe as lm, moe
+from ray_tpu.models import common, latent_moe as lm, moe, stack
 from ray_tpu.ops import grouped_matmul as gm
 
 
@@ -434,10 +434,10 @@ def test_parts_path_is_rope_in_xla_on_the_published_columns(monkeypatch):
     assert plans and all(p.endswith(
         ",dqk48,dv32,latent_parts,rope_in_kernel16of48") for p in plans)
     monkeypatch.setattr(lm, "_attention", _attention_roped_in_xla)
-    lm._layer_fn.cache_clear()      # a traced layer is cached by its function
+    stack.layer_fn.cache_clear()    # a traced layer is cached by its function
     monkeypatch.setattr(dispatch, "_taken", {})
     want_loss, want = loss_and_grads()
-    lm._layer_fn.cache_clear()
+    stack.layer_fn.cache_clear()
     assert not any("latent_parts" in p
                    for p in dispatch.taken()["flash_attention.plan"])
     assert abs(float(loss) - float(want_loss)) < 1e-5

@@ -9,14 +9,18 @@ so.  (The compiled step programs have theirs in tests/test_tpu_aot_compile
 .py's `PARENT_HLO_SHA256` and tests/test_tpu_aot_compile_cca.py.)
 """
 
+import dataclasses
 import hashlib
 import importlib
 import json
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+
+from ray_tpu.models import common, stack
 
 # module -> its config class
 MODULES = {"hybrid": "HybridConfig", "latent_moe": "LatentMoEConfig",
@@ -180,3 +184,189 @@ def test_the_tiny_parameters_are_the_parent_s_to_the_bit(name):
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_a_cell_s_parameter_tree_is_the_parent_s(cell):
     assert cell_digests(cell) == PARENT_CELLS[cell]
+
+
+# ---------------------------------------------------------------------------
+# models/stack.py's own cases, on a toy model: the walk, the tree, the tail
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class Toy:
+    segments: tuple = ()
+    hidden: int = 4
+    vocab: int = 11
+    dtype: type = jnp.float32
+    param_dtype: type = jnp.float32
+    remat: bool = True
+    remat_policy: str = "full"
+    fused_ce: bool = False
+
+
+TRACED = []
+
+
+def _toy_layer(carry, lp, beside, *, kind, c):
+    """x <- x w + beside, the second carry counts layers; a "routed" kind
+    reports the counts its leaves hold, any other kind None."""
+    TRACED.append(kind)
+    x, seen = carry
+    stats = None
+    if kind.startswith("routed"):
+        stats = {"rows_held": lp["held"], "load_max": lp["held"] * 10}
+    return (x * lp["w"] + beside, seen + 1), stats
+
+
+def _toy_layers(segments):
+    """segNN -> {"0": {"w": [repeats], "held": [repeats] = the layer's
+    index + 1}}."""
+    return {stack.segment_name(si): {"0": {
+        "w": jnp.full((repeats,), 2.0),
+        "held": jnp.arange(first + 1, first + repeats + 1, dtype=jnp.int32)}}
+        for si, (_, first, repeats) in enumerate(segments)}
+
+
+def _walk(segments, x=1.0):
+    c = Toy(segments=tuple(segments))
+    return stack.walk(_toy_layer, c, segments, _toy_layers(segments),
+                      (jnp.float32(x), jnp.int32(0)),
+                      lambda kind: jnp.float32(0.5))
+
+
+def test_walk_gives_the_last_layer_s_counts_and_the_rows_of_both_segments():
+    """Two expert segments of different kinds with a dense one between:
+    the counts are the LAST expert layer's, `rows_held_all_layers` sums
+    over BOTH segments' layers; a walk with no expert layer gives None."""
+    segments = [("routed_a", 0, 2), ("dense", 2, 1), ("routed_b", 3, 2)]
+    _, stats = _walk(segments)
+    assert int(stats["rows_held"]) == 5 and int(stats["load_max"]) == 50
+    assert int(stats["rows_held_all_layers"]) == 1 + 2 + 4 + 5
+    assert _walk([("dense", 0, 3)])[1] is None
+
+
+def test_walk_hands_a_tuple_carry_and_what_rides_beside_it_through():
+    (x, seen), _ = _walk([("dense", 0, 2), ("routed", 2, 1)], x=1.0)
+    assert int(seen) == 3
+    assert float(x) == ((1.0 * 2 + 0.5) * 2 + 0.5) * 2 + 0.5
+
+
+def test_one_kind_in_two_segments_is_traced_once():
+    """`layer_fn` is one function object a (layer, kind, config): JAX finds
+    the second segment's layer in its cache."""
+    segments = [("routed", 0, 2), ("dense", 2, 1), ("routed", 3, 3)]
+    stack.layer_fn.cache_clear()
+    del TRACED[:]
+    _, stats = jax.jit(lambda: _walk(segments))()
+    assert int(stats["rows_held_all_layers"]) == 1 + 2 + 4 + 5 + 6
+    assert sorted(TRACED) == ["dense", "routed"]
+    c = Toy(segments=tuple(segments))
+    assert stack.layer_fn(_toy_layer, "routed", c) \
+        is stack.layer_fn(_toy_layer, "routed", c)
+
+
+def _toy_shapes(kind, c):
+    h = c.hidden
+    return {"norm_w": ((h,), (None,), "ones"),
+            "w": ((h, 3 * h) if kind == "wide" else (h, h),
+                  ("embed", "mlp"), h),
+            "b": ((h,), ("embed",), "small")}
+
+
+_TOY = stack.Params(
+    lambda c: list(c.segments), _toy_shapes,
+    lambda c: {"embed": ((c.vocab, c.hidden), ("vocab", "embed"), c.hidden),
+               "norm_w": ((c.hidden,), (None,), "ones")},
+    {"small": lambda key, shape: 0.1 * jax.random.normal(key, shape)})
+
+
+def test_a_pattern_of_two_kinds_stacks_under_its_positions_by_layer_index():
+    """models/hybrid.py's order: the layer at position `pos` of repetition
+    `rep` is layer first + rep x len(pattern) + pos, drawn from
+    fold_in(key, that), one split a leaf in the table's order."""
+    c = Toy(segments=((("narrow",), 0, 1), (("narrow", "wide"), 1, 3)))
+    key = jax.random.key(7)
+    params = _TOY.init(c, {"layers": key, "embed": jax.random.key(8)})
+    np.testing.assert_array_equal(
+        params["embed"], jax.random.normal(jax.random.key(8), (11, 4)) * 0.5)
+    np.testing.assert_array_equal(params["norm_w"], np.ones(4))
+    layers = params["layers"]
+    assert sorted(layers) == ["seg00", "seg01"]
+    assert sorted(layers["seg01"]) == ["0", "1"]
+    assert layers["seg01"]["1"]["w"].shape == (3, 4, 12)
+    for pos in (0, 1):
+        for rep in range(3):
+            keys = jax.random.split(
+                jax.random.fold_in(key, 1 + rep * 2 + pos), 3)
+            got = layers["seg01"][str(pos)]
+            shape = got["w"].shape[1:]
+            np.testing.assert_array_equal(
+                got["w"][rep], jax.random.normal(keys[1], shape) * 0.5)
+            np.testing.assert_array_equal(
+                got["b"][rep], 0.1 * jax.random.normal(keys[2], (4,)))
+            np.testing.assert_array_equal(got["norm_w"][rep], np.ones(4))
+    # the tree's three uses
+    axes = _TOY.logical_axes(c)
+    assert axes["embed"] == ("vocab", "embed")
+    assert axes["layers"]["seg01"]["1"]["w"] == ("layers", "embed", "mlp")
+    assert jax.tree.structure(axes, is_leaf=lambda x: isinstance(x, tuple)) \
+        == jax.tree.structure(params)
+    assert _TOY.num_params(c) == sum(a.size for a in jax.tree.leaves(params))
+    flags = _TOY.tree(c, lambda name, spec: name == "b")
+    assert flags["layers"]["seg00"]["0"] == {"norm_w": False, "w": False,
+                                             "b": True}
+    assert flags["embed"] is False
+
+
+@pytest.mark.parametrize("head", ["tok_embed", "lm_head"])
+def test_the_tail_scores_against_the_head_the_model_names(head):
+    """`token_nll` is `logits_nll` of the plain head's logits, tied or
+    untied; the loss is its mean over the mask; the counts get `moe_`."""
+    c = Toy()
+    keys = jax.random.split(jax.random.key(3), 3)
+    params = {"tok_embed": jax.random.normal(keys[0], (c.vocab, c.hidden)),
+              "lm_head": jax.random.normal(keys[1], (c.vocab, c.hidden))}
+    tokens = jax.random.randint(keys[2], (2, 6), 0, c.vocab)
+
+    def forward_hidden(params, tokens, config):
+        return jnp.tanh(params["tok_embed"][tokens]), {
+            "rows_held": jnp.int32(3)}
+
+    tail = stack.LossTail(forward_hidden, head=head)
+    hidden = jnp.tanh(params["tok_embed"][tokens[:, :-1]])
+    want = common.logits_nll(
+        jnp.einsum("bsh,vh->bsv", hidden, params[head]), tokens[:, 1:])
+    batch = {"tokens": tokens}
+    np.testing.assert_allclose(tail.token_nll(params, batch, c), want,
+                               rtol=1e-6)
+    np.testing.assert_allclose(
+        tail.forward(params, tokens[:, :-1], c),
+        jnp.einsum("bsh,vh->bsv", hidden, params[head]), rtol=1e-6)
+    mask = jnp.zeros((2, 6)).at[:, 2:4].set(1.0)
+    loss, metrics = tail.loss_and_metrics(params, {**batch, "mask": mask}, c)
+    np.testing.assert_allclose(loss, jnp.mean(want[:, 1:3]), rtol=1e-6)
+    assert {k: int(v) for k, v in metrics.items()} == {"moe_rows_held": 3}
+    np.testing.assert_allclose(tail.loss_fn(params, batch, c),
+                               jnp.mean(want), rtol=1e-6)
+
+
+@pytest.mark.parametrize("d,r", [(8, 4), (16, 4), (16, 12), (8, 8)])
+def test_rotary_first_puts_pair_i_at_i_and_i_plus_half_a_head(d, r):
+    """Half a head (one transpose), a quarter and three quarters (four
+    slices), the whole (nothing to do): rot_a[i] lands at i, rot_b[i] at
+    d/2 + i, the rest keeps its order, in every head alike; the tables get
+    cos 1 and sin 0 behind the rotary pairs."""
+    heads = 3
+    x = jnp.arange(2 * heads * d).reshape(2, heads * d)
+    got = np.asarray(stack.rotary_first(x, heads, r)).reshape(2, heads, d)
+    a, b = np.arange(r // 2), np.arange(r // 2, r)
+    rest = np.arange(r, d)
+    order = np.concatenate([a, rest[:len(rest) // 2], b,
+                            rest[len(rest) // 2:]])
+    np.testing.assert_array_equal(
+        got, np.asarray(x).reshape(2, heads, d)[..., order])
+    one = np.asarray(stack.rotary_first(jnp.arange(d), 1, r))
+    np.testing.assert_array_equal(one, order)
+    cos, sin = stack.kernel_tables(
+        *stack.rope_tables(5, r, 10000.0), d)
+    assert cos.shape == sin.shape == (5, d // 2)
+    assert bool((cos[:, r // 2:] == 1).all() & (sin[:, r // 2:] == 0).all())
+    np.testing.assert_allclose(cos[:, 0], np.cos(np.arange(5)), rtol=1e-6)
