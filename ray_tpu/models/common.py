@@ -78,6 +78,19 @@ def swiglu(y, w_gate, w_up, w_down, dtype):
         return checkpoint_name(ffn @ w_down.astype(dtype), "mlp_out")
 
 
+def relu2(x):
+    """relu(x)^2, squared in float32 and rounded once to x's dtype."""
+    return jnp.square(jax.nn.relu(x.astype(jnp.float32))).astype(x.dtype)
+
+
+def relu2_mlp(y, w_up, w_down, dtype):
+    """relu(y Wu)^2 Wd, no gate matrix, no bias."""
+    with jax.named_scope(MLP):
+        ffn = with_logical_constraint(relu2(y @ w_up.astype(dtype)),
+                                      ("batch", "seq", "mlp"))
+        return checkpoint_name(ffn @ w_down.astype(dtype), "mlp_out")
+
+
 def causal_depthwise_conv(x, w, b=None):
     """y_t = b + sum_j w[j] x_{t - (taps - 1) + j}: `taps` shifted adds
     over the time axis, nothing before position 0.  x: [b, s, channels];
@@ -210,10 +223,16 @@ def maybe_remat(block_fn, remat: bool, remat_policy: str):
         # only the flash kernel's outputs (out + lse) so the backward
         # re-derives the cheap projections but never re-runs the
         # attention kernel.
+        # .. nor, in a layer that is a state-space-dual mixer, the
+        # recurrence's forward kernel (its y and its blocks' first states;
+        # a layer without one has no such name, and its program does not
+        # move)
+        from ray_tpu.ops.ssd_scan import KEPT_NAMES
+
         return jax.checkpoint(
             block_fn,
             policy=jax.checkpoint_policies.save_only_these_names(
-                *SAVE_ATTN_NAMES))
+                *SAVE_ATTN_NAMES, *KEPT_NAMES))
     if remat_policy == "dots_no_mlp":
         # "dots" minus its biggest buffers: save every matmul output
         # EXCEPT the gate/up MLP intermediates ([b, s, intermediate] —

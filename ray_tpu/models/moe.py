@@ -332,7 +332,9 @@ def routed_experts(x, idx, gates, w_gate, w_up, w_down, *,
     SwiGLU weights are w_gate, w_up [count, h, m] and w_down [count, m, h].
     -> (y [T, h] = sum over a token's HELD experts e of
         gate_e (silu(x Wg_e) * (x Wu_e)) Wd_e,
-    stats).  What the other experts would add is not here: it is the other
+    stats).  w_gate None: the experts are UNGATED, two matrices each, and a
+    term is gate_e relu(x Wu_e)^2 Wd_e (`common.relu2`): two grouped
+    matmuls a layer, not three.  What the other experts would add is not here: it is the other
     chips'.  stats: `rows_held` (assignments that landed here), `load_max`
     and `load_mean` over the held experts, `rows_bound` (the buffer's
     bound, static), int32 / float32 scalars.
@@ -353,8 +355,8 @@ def routed_experts(x, idx, gates, w_gate, w_up, w_down, *,
     from ray_tpu.ops import grouped_matmul as gm
 
     first, count = experts_held
-    if w_gate.shape[0] != count:
-        raise ValueError(f"{w_gate.shape[0]} experts' weights, {count} held")
+    if w_up.shape[0] != count:
+        raise ValueError(f"{w_up.shape[0]} experts' weights, {count} held")
     tokens, k = idx.shape
     tile_m = tile_m or gm.TILE_M
     bound = tokens * min(k, count)
@@ -399,10 +401,15 @@ def routed_experts(x, idx, gates, w_gate, w_up, w_down, *,
                            held_count)
             rows_in = _place(x.astype(dtype), lists)
         with jax.named_scope(common.MOE_EXPERTS):
-            gate_h = gm.grouped_matmul(rows_in, w_gate.astype(dtype), layout)
-            up_h = gm.grouped_matmul(rows_in, w_up.astype(dtype), layout)
-            out = gm.grouped_matmul(jax.nn.silu(gate_h) * up_h,
-                                    w_down.astype(dtype), layout)
+            if w_gate is None:
+                hidden = common.relu2(
+                    gm.grouped_matmul(rows_in, w_up.astype(dtype), layout))
+            else:
+                gate_h = gm.grouped_matmul(rows_in, w_gate.astype(dtype),
+                                           layout)
+                up_h = gm.grouped_matmul(rows_in, w_up.astype(dtype), layout)
+                hidden = jax.nn.silu(gate_h) * up_h
+            out = gm.grouped_matmul(hidden, w_down.astype(dtype), layout)
         with jax.named_scope(common.MOE_COMBINE):
             return _combine(out, gates, lists)
 

@@ -77,6 +77,16 @@ def swiglu_shapes(prefix: str, h: int, m: int, experts=None):
             f"{prefix}_down": (lead + (m, h), axis + ("mlp", "embed"), m)}
 
 
+def relu2_shapes(prefix: str, h: int, m: int, experts=None):
+    """The table's rows of an UNGATED feed-forward's two matrices,
+    `<prefix>_up` [h, m] and `_down` [m, h] (`common.relu2_mlp`, and
+    `routed_part` with no gate matrix); of `experts` of them stacked where
+    given."""
+    shapes = swiglu_shapes(prefix, h, m, experts)
+    del shapes[f"{prefix}_gate"]
+    return shapes
+
+
 class Params:
     """A model's parameter tree from its functions: `patterns(config)` ->
     [(pattern, first, repeats)]; `layer_shapes(kind, config)`, a kind's
@@ -215,7 +225,8 @@ def routed_part(flat, route, w_gate, w_up, w_down, config, usual_load: int):
     hidden] -> (the held experts' sum, the routing counts, and whatever
     `route()` gives after (expert index [T, k], gates [T, k])).  The usual
     buffer holds `usual_load` times the rows even routing sends here; a
-    step that sends more takes the full bound's."""
+    step that sends more takes the full bound's.  `w_gate` None: ungated
+    two-matrix experts (`moe.routed_experts`)."""
     with jax.named_scope(common.MOE_ROUTE):
         idx, gates, *state = route()
     even = -(-flat.shape[0] * config.num_experts_per_tok

@@ -13,7 +13,11 @@ next tile edge are padding.  So every row tile belongs to ONE group, and the
 kernels are plain matmuls whose weight block is chosen by a prefetched
 scalar, `tile_group[i]`.  Tiles behind the last group's (`tiles_used` on)
 hold nothing: their programs compute nothing and re-visit the last used
-tile's blocks, so the pipeline moves nothing for them either.  What they
+tile's blocks, so the pipeline moves nothing for them either (`column` in
+`_pallas_grouped` says what that takes once a tile's columns are cut into
+more than two blocks).  A width that is half a lane tile over a whole
+number of them (1856: `_off_grid`) is taken as it is: no parameter and no
+buffer holds a padded column.  What they
 would have written is NOT defined (whatever the buffer held): a caller reads
 only rows it placed (`models/moe.py` gathers by position and selects).
 Padding rows inside a used tile are computed like any row, from whatever the
@@ -134,11 +138,27 @@ def _xla_dw(x, dy, layout: GroupLayout, groups: int):
 
 def _block_n(k: int, n: int, limit: int) -> int:
     """Columns of a [k, n] block: all n where that fits `limit` bytes,
-    else the largest multiple of 128 dividing n that does."""
-    if k * n <= limit or n % 128:
+    else the largest multiple of 128 dividing n that does.  An n that is no
+    whole number of lane tiles (`_off_grid`) has no such divisor: its blocks
+    are the multiple of 128 that fits and pads the LAST block least (the
+    grid is a ceiling; the columns behind n are read as whatever lies there
+    and what is computed from them is never stored)."""
+    if k * n <= limit or n % 128 and not _off_grid(n):
         return n
+    if n % 128:
+        return min((c for c in range(128, n, 128) if k * c <= limit),
+                   key=lambda c: (-(-n // c) * c, -c), default=128)
     return max([128] + [c for c in range(128, n, 128)
                         if n % c == 0 and k * c <= limit])
+
+
+def _off_grid(width: int) -> bool:
+    """A width the kernels take though it is no whole number of lane tiles:
+    half a tile over (1856 = 14 x 128 + 64).  As a contraction (k) it is a
+    block's WHOLE dimension, which a block may always be, and the compiler
+    masks the half tile; as n it is cut into padded blocks (`_block_n`).
+    No parameter holds a padded column either way."""
+    return width > 128 and width % 128 == 64
 
 
 def _compiler_params():
@@ -179,20 +199,36 @@ def _pallas_grouped(x, w, layout: GroupLayout, transpose_rhs: bool):
     bn = _block_n(k, n, _BLOCK_BYTES // w.dtype.itemsize)
 
     w_block = (1, bn, k) if transpose_rhs else (1, k, bn)
+    blocks = -(-n // bn)
+
+    def column(i, j, used):
+        """The column block program (i, j) works on.  A tile behind the last
+        used one re-visits the last used tile's LAST column block, where the
+        program before it left off, so that nothing moves.  With one block
+        there is nothing to choose, and with two the pipeline's two output
+        buffers line up with the blocks re-visited in turn (each is written
+        back with what it held: the older cells' kernels, kept as they were
+        traced); from three on they no longer do, and a buffer would be
+        written back over ANOTHER block of the last used tile."""
+        return j if blocks <= 2 else jnp.where(i < used[0], j, blocks - 1)
+
     return pl.pallas_call(
         functools.partial(_mm_kernel, transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(rows // tile_m, n // bn),
+            grid=(rows // tile_m, blocks),
             in_specs=[
                 pl.BlockSpec((tile_m, k),
                              lambda i, j, g, u: (_row_tile(i, u), 0)),
-                pl.BlockSpec(w_block, (lambda i, j, g, u: (g[i], j, 0))
+                pl.BlockSpec(w_block,
+                             (lambda i, j, g, u: (g[i], column(i, j, u), 0))
                              if transpose_rhs
-                             else (lambda i, j, g, u: (g[i], 0, j))),
+                             else (lambda i, j, g, u: (g[i], 0,
+                                                       column(i, j, u)))),
             ],
             out_specs=pl.BlockSpec(
-                (tile_m, bn), lambda i, j, g, u: (_row_tile(i, u), j)),
+                (tile_m, bn),
+                lambda i, j, g, u: (_row_tile(i, u), column(i, j, u))),
         ),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         compiler_params=_compiler_params(),
@@ -245,7 +281,7 @@ def _pallas_dw(x, dy, layout: GroupLayout, groups: int):
         _dw_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(n // bn, rows // tile_m),     # a column block, then rows
+            grid=(-(-n // bn), rows // tile_m),  # a column block, then rows
             in_specs=[
                 pl.BlockSpec((tile_m, k),
                              lambda j, i, g, u: (_row_tile(i, u), 0)),
@@ -274,7 +310,7 @@ def _use_pallas(k: int, n: int, tile_m: int) -> bool:
     if dispatch.interpret_mode():
         return True
     return (dispatch.platform() == "tpu" and tile_m % 16 == 0
-            and k % 128 == 0 and n % 128 == 0)
+            and all(w % 128 == 0 or _off_grid(w) for w in (k, n)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -314,8 +350,10 @@ def grouped_matmul(x, w, layout: GroupLayout, transpose_rhs: bool = False):
     pallas = _use_pallas(k, n, tile_m)
     dispatch.record("grouped_matmul", "xla" if not pallas else
                     "interpret" if dispatch.interpret_mode() else "pallas")
+    off = "".join(f",{name}{width}_{how}" for name, width, how in (
+        ("k", k, "whole"), ("n", n, "last_block_padded")) if _off_grid(width))
     dispatch.record(
         "grouped_matmul.plan",
         f"tile{tile_m}x{_block_n(k, n, _BLOCK_BYTES // w.dtype.itemsize)},"
-        f"rows{x.shape[0]},groups{w.shape[0]}")
+        f"rows{x.shape[0]},groups{w.shape[0]}{off}")
     return _grouped(x, w, layout, transpose_rhs, pallas)

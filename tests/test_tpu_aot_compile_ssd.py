@@ -1,0 +1,196 @@
+"""AOT-compile the `train-ssd-moe-d9` cell's step program for a described
+v5e (Nemotron-3-Nano-30B-A3B's widths, its first nine layers, 8 of 128
+experts, an eighth of the vocabulary, 3 x 8192 tokens): its bytes, its
+kernels' plans, and what the cell's readers find it by.
+
+No chip is attached: the TPU compiler installed here compiles for a
+topology that is described (v5e:2x2).  A compile that passes is not a chip
+run.  The topology is described inside a fixture, as in
+tests/test_tpu_aot_compile.py, whose wall time this file stays out of: only
+the xdist worker that is handed this file loads libtpu here, everything
+compiles in the test's own process, with the persistent compile cache off.
+"""
+
+import copy
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.ops import attention
+from test_tpu_aot_compile import _metadata_stripped
+
+CONFIG = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                      "benchmark", "configs",
+                      "nemotron-3-nano-30b-a3b-train-d9e8.json")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def step_program(topo):
+    """(the cell's whole step program as `ShardedTrainStep` jits it on the
+    ladder's FIRST rung, which is the one the chip takes: the flash out and
+    lse and the recurrence's y and first states kept; what its trace left in
+    `dispatch.taken()`; the configuration's train group).  One compile,
+    about a minute and a half."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmark.drivers import train_model
+    from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
+
+    with open(CONFIG) as f:
+        doc = json.load(f)
+    tr = doc["train"]
+    config = train_model.build_config(doc["program"], doc["model"], tr)
+    mesh = Mesh(topo.devices[:1], ("fsdp",))
+    whole = NamedSharding(mesh, P())
+    ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
+        warmup_steps=tr["lr_warmup_steps"], total_steps=tr["lr_total_steps"],
+        mu_dtype=jnp.bfloat16, nu_dtype=jnp.bfloat16))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(attention.dispatch, "platform", lambda: "tpu")
+        mp.setattr(attention.dispatch, "interpret_mode", lambda: False)
+        mp.setattr(attention.dispatch, "_taken", {})
+        with jax.sharding.set_mesh(mesh):
+            state = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=whole),
+                jax.eval_shape(ts._init_fn, key))
+            batch = {"tokens": jax.ShapeDtypeStruct(
+                (tr["batch_rows"], tr["sequence_length"] + 1), jnp.int32,
+                sharding=whole)}
+            compiled = jax.jit(
+                ts._step_fn, donate_argnums=(0,), static_argnames=("keep",)
+            ).lower(state, batch, keep=True).compile()
+        taken = copy.deepcopy(attention.dispatch.taken())
+    return compiled, taken, tr
+
+
+# sha256 of the step program's optimised HLO, `_metadata_stripped`, as PR
+# 48's tree compiled it: tests/test_tpu_aot_compile.py's `PARENT_HLO_SHA256`
+# has the rule (a change that means to move the program replaces the digest
+# and says so) and the other cells'.
+PARENT_HLO_SHA256 = (
+    "7dffeb9394a3b2b6a7131625432281d47f0b4ffcbc11c64a8fdca54fc841861c")
+
+
+def test_cell_ssd_moe_optimised_hlo_is_as_this_pr_compiled_it(step_program):
+    import hashlib
+
+    text = _metadata_stripped(step_program[0].as_text())
+    assert "op_name" not in text and "source_file" not in text \
+        and ".py" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_HLO_SHA256
+
+
+def _kernel_op_names(compiled):
+    return [re.search(r'op_name="([^"]*)"', l).group(1)
+            for l in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in l]
+
+
+def test_cell_ssd_moe_step_program_fits_a_v5e(step_program):
+    """The cell's whole step program (four mamba layers, four expert layers
+    of 8 held experts of 1856, one attention layer, an eighth of the untied
+    vocabulary, 3 x 8192 tokens, fused CE, bfloat16 moments) by AOT
+    memory_analysis: under 15.75 GiB at the configuration's rows with room
+    for what stands beside it, and over 11 (the state is 8.7)."""
+    from ray_tpu.util.device_stats import program_bytes
+
+    compiled, taken, tr = step_program
+    assert tr["batch_rows"] == 3 and tr["sequence_length"] == 8192
+    total = program_bytes(compiled.memory_analysis())
+    assert 11.0 * 2 ** 30 < total < 14.5 * 2 ** 30, total / 2 ** 30
+    assert list(taken["ssd_scan.plan"]) == [
+        "chunk128,heads64over8,p64,n128,state_f32,bwd_pallas,"
+        "passes2.625+5.375"]
+    plan, = taken["flash_attention.plan"]
+    assert plan.endswith(",operands_bshd,heads1x128") \
+        and "rope_in_kernel" not in plan
+    # both sides of the buffer's `lax.cond`, the up face then the down face
+    assert list(taken["grouped_matmul.plan"]) == [
+        "tile256x640,rows38912,groups8,n1856_last_block_padded",
+        "tile256x896,rows38912,groups8,k1856_whole",
+        "tile256x640,rows149504,groups8,n1856_last_block_padded",
+        "tile256x896,rows149504,groups8,k1856_whole"]
+    assert list(taken["ssd_moe.experts"]) == ["relu2,ungated,k6of128,held8"]
+    assert set(taken["flash_attention"]) == set(taken["grouped_matmul"]) \
+        == set(taken["ssd_scan"]) == set(taken["routed_experts"]) \
+        == {"pallas"}
+
+
+def test_cell_ssd_moe_kernels_are_found_by_their_names(step_program):
+    """The `.ssd` readers' patterns (benchmark/ssd_faces.py) find the
+    recurrence's and the grouped kernels by the kernel's name in `op_name`,
+    each its calls and no other's.  A mamba layer: ONE forward (its y and
+    first states kept on this rung: remat runs no second one) and one
+    backward; an expert layer: the two faces forward, again under remat,
+    transposed and dw, on each side of the buffer's conditional."""
+    from benchmark import cca_faces, ssd_faces
+
+    compiled, _, _ = step_program
+    names = _kernel_op_names(compiled)
+    found = {k: [n for n in names if re.search(v, n)] for k, v in (
+        ("ssd_fwd", ssd_faces.SSD_FORWARD), ("ssd_bwd", ssd_faces.SSD_BACKWARD),
+        ("forward", ssd_faces.GROUPED_FORWARD),
+        ("transposed", cca_faces.GROUPED_TRANSPOSED),
+        ("dw", cca_faces.GROUPED_DW))}
+    assert {k: len(v) for k, v in found.items()} == {
+        "ssd_fwd": 4, "ssd_bwd": 4, "forward": 4 * 2 * 2 * 2,
+        "transposed": 4 * 2 * 2, "dw": 4 * 2 * 2}
+    assert all("/ssm/" in n and "ssm.chain" not in n
+               for k in ("ssd_fwd", "ssd_bwd") for n in found[k])
+    assert not any("rematted_computation" in n for n in found["ssd_fwd"])
+    assert all("/moe.experts/" in n
+               for k in ("forward", "transposed", "dw") for n in found[k])
+    assert len({n for v in found.values() for n in v}) \
+        == sum(len(set(v)) for v in found.values())
+    flash = [n for n in names if "/attn.full/" in n]
+    assert sorted(n.rsplit("/", 2)[1] for n in flash) == ["flash_bwd",
+                                                          "flash_fwd"]
+    movers = [n for n in names if "gather" in n.rsplit("/", 2)[1]]
+    assert len(names) == sum(map(len, found.values())) + 2 + len(movers)
+
+
+def test_cell_ssd_moe_scopes_are_where_the_readers_look(step_program):
+    """`mixer_chain_ms.ssd` finds its operations by `op_name`: `ssm.chain`
+    lies INSIDE `ssm` in the forward, remat's forward and the backward, and
+    holds no kernel and no matmul; every kernel and every matmul keeps a
+    scope of the vocabulary."""
+    from ray_tpu.models import common
+
+    compiled, _, _ = step_program
+    names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    chain = [n for n in names if common.SSM_CHAIN in n]
+    outside = [n for n in chain
+               if f"{common.SSM}/{common.SSM_CHAIN}/" not in n]
+    assert chain and not outside, outside[:5]
+    assert any("rematted_computation" in n for n in chain)
+    assert any(n.startswith("jit(_step_fn)/transpose(jvp())") for n in chain)
+    assert not any("dot_general" in n or "pallas_call" in n for n in chain)
+    scope = re.compile(r"(?<![\w.])(" + "|".join(
+        re.escape(s) for s in common.SCOPES) + r")(?![\w.])")
+    assert all(scope.search(n) for n in _kernel_op_names(compiled))
+    assert all(scope.search(n) for n in names if "dot_general" in n)
