@@ -4,8 +4,10 @@ order a pattern string gives (the `nemotron_h` form, as NVIDIA-Nemotron-3-
 Nano-30B-A3B publishes it) for training through `ShardedTrainStep`, on
 models/stack.py's layer stack; embedding and cross-entropy are
 models/common.py's, the routed experts models/moe.py's dropless layer, the
-recurrence ops/ssd_scan.py's kernels, attention ops/attention.py's flash
-kernels.
+recurrence ops/ssd_scan.py's kernels, the chain round it (the convolution
+with its bias, SiLU and the cut into x, B, C in front; the gate and the norm
+by groups behind) ops/mixer_chain.py's two passes, attention
+ops/attention.py's flash kernels.
 
 Layer equations (x the layer's input [s, hidden]; every matrix [in, out];
 every norm a plain RMSNorm, x / rms(x) w, eps `layer_norm_epsilon`, sums in
@@ -57,6 +59,7 @@ from ray_tpu.models import common, moe, stack
 from ray_tpu.models.hybrid import _dt_bias
 from ray_tpu.models.swa_moe import USUAL_LOAD
 from ray_tpu.ops import dispatch
+from ray_tpu.ops.mixer_chain import conv_silu_split, gated_group_norm
 from ray_tpu.parallel.sharding import with_logical_constraint
 
 F32 = jnp.float32
@@ -316,26 +319,19 @@ def _mamba_mixer(u, lp, c: SsdMoEConfig):
                                   ("batch", "seq", "heads"))
     # the step feeds the float32 recurrence: float32 out of the MXU
     dt = _matmul(u, w[:, inner + wide:], c, F32)
+    # both halves of the chain round the recurrence are ops/mixer_chain.py's
+    # passes over row tiles (float32 from the operands as they lie, rounded
+    # once where a result leaves); x, B, C and y cross as [b, s, columns]
     with jax.named_scope(common.SSM_CHAIN):
-        xBC = jax.nn.silu(common.causal_depthwise_conv(
-            xBC.astype(F32), lp["conv_w"].astype(F32),
-            lp["conv_b"].astype(F32))).astype(c.dtype)
+        x, B, C = conv_silu_split(xBC, lp["conv_w"], lp["conv_b"],
+                                  (inner, groups * N, groups * N))
         dt = jax.nn.softplus(dt + lp["dt_bias"].astype(F32))
-    # [b, s, heads x P] on the way in and out, as W_in wrote it and W_out
-    # reads it: a [b, s, heads, P] view that meets another operation is
-    # re-laid
     y = ssd_scan(
-        xBC[..., :inner].reshape(b, s, heads, P), dt,
-        -jnp.exp(lp["A_log"].astype(F32)),
-        xBC[..., inner:inner + groups * N].reshape(b, s, groups, N),
-        xBC[..., inner + groups * N:].reshape(b, s, groups, N),
+        x.reshape(b, s, heads, P), dt, -jnp.exp(lp["A_log"].astype(F32)),
+        B.reshape(b, s, groups, N), C.reshape(b, s, groups, N),
         lp["D"].astype(F32), c).reshape(b, s, inner)
     with jax.named_scope(common.SSM_CHAIN):
-        gn_w = lp["gn_w"].astype(F32).reshape(groups, 1, inner // groups)
-        gated = (y.astype(F32) * jax.nn.silu(z.astype(F32))).astype(c.dtype)
-        y = stack.per_head(gated, groups, lambda t: t * jax.lax.rsqrt(
-            jnp.mean(t * t, axis=-1, keepdims=True) + c.layer_norm_epsilon)
-            * gn_w)
+        y = gated_group_norm(y, z, lp["gn_w"], groups, c.layer_norm_epsilon)
     return _matmul(y, lp["w_out"], c)
 
 

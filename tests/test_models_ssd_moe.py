@@ -264,7 +264,19 @@ def sound():
 
 
 def test_the_sound_reference_is_within_the_limit(sound):
+    """The program through the INTERPRETED passes of ops/mixer_chain.py (the
+    conv with its bias and the cut; the gate and the norm by groups), which
+    the breaks below are held against."""
+    from ray_tpu.ops import dispatch, mixer_chain
+
     config, params, tokens, got = sound
+    assert "interpret" in dispatch.taken()["ssd_chain"]
+    inner, wide = config.d_inner, config.conv_channels
+    assert mixer_chain.split_path(
+        jnp.zeros((1, 64, wide)), jnp.zeros((config.conv_kernel, wide)),
+        (inner, (wide - inner) // 2, (wide - inner) // 2)) == "interpret"
+    assert mixer_chain.norm_path(jnp.zeros((1, 64, inner)),
+                                 config.n_groups) == "interpret"
     want = _reference_nll(params, tokens, _dims(config))
     assert np.abs(got - np.asarray(want)).max() < 3e-4
 
@@ -346,7 +358,7 @@ def test_the_probe_runs_the_scan_alone_on_the_references_operands():
     assert 1e-4 < err < 2e-2
 
 
-def test_train_step_carries_the_counts_and_the_plans():
+def test_train_step_carries_the_counts_and_the_plans(monkeypatch):
     """Through ShardedTrainStep: the loss falls, the step's metrics hold the
     LAST expert layer's routing counts and the rows of both expert layers,
     its forced spans hold them as attributes, the selection bias stays as
@@ -356,6 +368,7 @@ def test_train_step_carries_the_counts_and_the_plans():
     from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
     from ray_tpu.util import tracing
 
+    monkeypatch.setattr(dispatch, "_taken", {})
     config = sm.SsdMoEConfig.tiny(fused_ce=True)
     mesh = build_mesh(axes={"fsdp": 1}, devices=jax.devices()[:1])
     ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
@@ -390,6 +403,8 @@ def test_train_step_carries_the_counts_and_the_plans():
                             "bwd_pallas,passes")
                for p in taken["ssd_scan.plan"])
     assert set(taken["ssd_scan"]) == {"interpret"}
+    # both halves of the chain round it: ops/mixer_chain.py's passes
+    assert set(taken["ssd_chain"]) == {"interpret"}
     assert "relu2,ungated,k3of16,held4" in taken["ssd_moe.experts"]
     assert any(p.endswith(",operands_bshd,heads2x64")
                and "rope_in_kernel" not in p

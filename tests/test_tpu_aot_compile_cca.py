@@ -139,16 +139,30 @@ def test_cell_cca_moe_step_program_fits_a_v5e(step_program):
     # the grouped kernels' three forward, three again under remat, three
     # transposed and three dw
     assert compiled.as_text().count("tpu_custom_call") == 2 + 12
-    assert [p.split(",dead")[1] for p in taken["flash_attention.plan"]] == [
-        "6/6%,rope_in_kernel,operands_bshd,heads1x128"]
-    assert list(taken["grouped_matmul.plan"]) == [
-        "tile256x1024,rows12288,groups16"]
-    assert set(taken["flash_attention"]) == set(taken["grouped_matmul"]) \
-        == {"pallas"}
-    assert list(taken["cca_moe.mix"]) == [
-        "taps2+2,heads8over2,latent1024+256,vshift,l2tau,xla"]
-    assert list(taken["cca_moe.rope"]) == [
-        "hybrid:in_kernel64of128_columns_reordered_at_use_identity_tail"]
+
+
+# what the step program's trace left in `dispatch.taken()`, a case each:
+# pytest-xdist hands files out by their number of cases, largest first, and a
+# file of five cases round a compile of minutes would start last and end the
+# run alone (PERF.md section 7, PR 49)
+TAKEN = {
+    "flash_attention.plan": lambda plans: [
+        p.split(",dead")[1] for p in plans] == [
+            "6/6%,rope_in_kernel,operands_bshd,heads1x128"],
+    "grouped_matmul.plan": lambda plans: list(plans) == [
+        "tile256x1024,rows12288,groups16"],
+    "flash_attention": lambda paths: set(paths) == {"pallas"},
+    "grouped_matmul": lambda paths: set(paths) == {"pallas"},
+    "cca_moe.mix": lambda plans: list(plans) == [
+        "taps2+2,heads8over2,latent1024+256,vshift,l2tau,xla"],
+    "cca_moe.rope": lambda plans: list(plans) == [
+        "hybrid:in_kernel64of128_columns_reordered_at_use_identity_tail"],
+}
+
+
+@pytest.mark.parametrize("key", sorted(TAKEN))
+def test_cell_cca_moe_trace_left_its_plans_and_paths(step_program, key):
+    assert TAKEN[key](step_program[1][key]), step_program[1][key]
 
 
 def test_cell_cca_moe_grouped_kernels_are_found_by_their_names(step_program):

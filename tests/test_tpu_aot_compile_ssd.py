@@ -89,11 +89,13 @@ def step_program(topo):
 
 
 # sha256 of the step program's optimised HLO, `_metadata_stripped`, as PR
-# 48's tree compiled it: tests/test_tpu_aot_compile.py's `PARENT_HLO_SHA256`
+# 49's tree compiled it: tests/test_tpu_aot_compile.py's `PARENT_HLO_SHA256`
 # has the rule (a change that means to move the program replaces the digest
-# and says so) and the other cells'.
+# and says so) and the other cells'.  PR 49 MEANT TO: the chain round the
+# recurrence is ops/mixer_chain.py's four kernels (PR 48's tree read
+# 7dffeb93..).
 PARENT_HLO_SHA256 = (
-    "7dffeb9394a3b2b6a7131625432281d47f0b4ffcbc11c64a8fdca54fc841861c")
+    "ec413a9a62eec4a8957ae0d829e4e0e88569d16ec742059c28a41316ba856c9f")
 
 
 def test_cell_ssd_moe_optimised_hlo_is_as_this_pr_compiled_it(step_program):
@@ -123,48 +125,92 @@ def test_cell_ssd_moe_step_program_fits_a_v5e(step_program):
     assert tr["batch_rows"] == 3 and tr["sequence_length"] == 8192
     total = program_bytes(compiled.memory_analysis())
     assert 11.0 * 2 ** 30 < total < 14.5 * 2 ** 30, total / 2 ** 30
-    assert list(taken["ssd_scan.plan"]) == [
-        "chunk128,heads64over8,p64,n128,state_f32,bwd_pallas,"
-        "passes2.625+5.375"]
     plan, = taken["flash_attention.plan"]
     assert plan.endswith(",operands_bshd,heads1x128") \
         and "rope_in_kernel" not in plan
-    # both sides of the buffer's `lax.cond`, the up face then the down face
-    assert list(taken["grouped_matmul.plan"]) == [
+
+
+# what the step program's trace left in `dispatch.taken()`; the grouped
+# plans are both sides of the buffer's `lax.cond`, the up face then the down
+PLANS = {
+    "ssd_scan.plan": ["chunk128,heads64over8,p64,n128,state_f32,bwd_pallas,"
+                      "passes2.625+5.375"],
+    "grouped_matmul.plan": [
         "tile256x640,rows38912,groups8,n1856_last_block_padded",
         "tile256x896,rows38912,groups8,k1856_whole",
         "tile256x640,rows149504,groups8,n1856_last_block_padded",
-        "tile256x896,rows149504,groups8,k1856_whole"]
-    assert list(taken["ssd_moe.experts"]) == ["relu2,ungated,k6of128,held8"]
-    assert set(taken["flash_attention"]) == set(taken["grouped_matmul"]) \
-        == set(taken["ssd_scan"]) == set(taken["routed_experts"]) \
-        == {"pallas"}
+        "tile256x896,rows149504,groups8,k1856_whole"],
+    "ssd_moe.experts": ["relu2,ungated,k6of128,held8"],
+}
 
 
-def test_cell_ssd_moe_kernels_are_found_by_their_names(step_program):
-    """The `.ssd` readers' patterns (benchmark/ssd_faces.py) find the
-    recurrence's and the grouped kernels by the kernel's name in `op_name`,
-    each its calls and no other's.  A mamba layer: ONE forward (its y and
-    first states kept on this rung: remat runs no second one) and one
-    backward; an expert layer: the two faces forward, again under remat,
-    transposed and dw, on each side of the buffer's conditional."""
+@pytest.mark.parametrize("key", sorted(PLANS))
+def test_cell_ssd_moe_kernels_plans(step_program, key):
+    assert list(step_program[1][key]) == PLANS[key]
+
+
+@pytest.mark.parametrize("op", ["flash_attention", "grouped_matmul",
+                                "routed_experts", "ssd_chain", "ssd_scan"])
+def test_cell_ssd_moe_takes_every_kernel(step_program, op):
+    """Each op of the cell went down its Pallas path; `ssd_chain` is each of
+    ops/mixer_chain.py's two passes round the recurrence (PR 49)."""
+    assert set(step_program[1][op]) == {"pallas"}
+
+
+def _found(compiled):
+    """name -> the kernel calls' `op_name`s that the name's pattern finds:
+    the `.ssd` readers' (benchmark/ssd_faces.py) and the chain's four."""
     from benchmark import cca_faces, ssd_faces
 
-    compiled, _, _ = step_program
     names = _kernel_op_names(compiled)
-    found = {k: [n for n in names if re.search(v, n)] for k, v in (
+    return names, {k: [n for n in names if re.search(v, n)] for k, v in (
         ("ssd_fwd", ssd_faces.SSD_FORWARD), ("ssd_bwd", ssd_faces.SSD_BACKWARD),
         ("forward", ssd_faces.GROUPED_FORWARD),
         ("transposed", cca_faces.GROUPED_TRANSPOSED),
-        ("dw", cca_faces.GROUPED_DW))}
-    assert {k: len(v) for k, v in found.items()} == {
-        "ssd_fwd": 4, "ssd_bwd": 4, "forward": 4 * 2 * 2 * 2,
-        "transposed": 4 * 2 * 2, "dw": 4 * 2 * 2}
-    assert all("/ssm/" in n and "ssm.chain" not in n
-               for k in ("ssd_fwd", "ssd_bwd") for n in found[k])
-    assert not any("rematted_computation" in n for n in found["ssd_fwd"])
-    assert all("/moe.experts/" in n
-               for k in ("forward", "transposed", "dw") for n in found[k])
+        ("dw", cca_faces.GROUPED_DW),
+        *((k, rf"/{k}(?:/|$)") for k in (
+            "ssd_chain_fwd", "ssd_chain_bwd", "gated_norm_fwd",
+            "gated_norm_bwd")))}
+
+
+# name -> (calls a step, of them under remat, the scope they lie in, a scope
+# they must not lie in).  A mamba layer: ONE forward of the recurrence (its
+# y and first states kept on this rung: remat runs no second one) and one
+# backward; the chain's two passes round it (ops/mixer_chain.py, PR 49),
+# each forward once in the pass and once under remat, each backward once,
+# all under `ssm.chain`, where `mixer_chain_ms.ssd` reads them and
+# `ssd_share.ssd`'s patterns do not; an expert layer: the two faces forward,
+# again under remat, transposed and dw, on each side of the buffer's
+# conditional.
+KERNELS = {
+    "ssd_fwd": (4, 0, "/ssm/", "ssm.chain"),
+    "ssd_bwd": (4, 0, "/ssm/", "ssm.chain"),
+    "ssd_chain_fwd": (8, 4, "/ssm/ssm.chain/", None),
+    "ssd_chain_bwd": (4, 0, "/ssm/ssm.chain/", None),
+    "gated_norm_fwd": (8, 4, "/ssm/ssm.chain/", None),
+    "gated_norm_bwd": (4, 0, "/ssm/ssm.chain/", None),
+    "forward": (4 * 2 * 2 * 2, 4 * 2 * 2, "/moe.experts/", None),
+    "transposed": (4 * 2 * 2, 0, "/moe.experts/", None),
+    "dw": (4 * 2 * 2, 0, "/moe.experts/", None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_cell_ssd_moe_kernels_are_found_by_their_names(step_program, name):
+    """A kernel's name in `op_name` finds its calls and no other's."""
+    calls, rematted, scope, outside = KERNELS[name]
+    found = _found(step_program[0])[1][name]
+    assert len(found) == calls
+    assert sum("rematted_computation" in n for n in found) == rematted
+    assert all(scope in n and (outside is None or outside not in n)
+               for n in found)
+
+
+def test_cell_ssd_moe_every_kernel_call_has_one_name(step_program):
+    """No call is found by two names, and beside the named ones the program
+    holds the flash pair and the movers by the token alone."""
+    names, found = _found(step_program[0])
+    assert set(found) == set(KERNELS)
     assert len({n for v in found.values() for n in v}) \
         == sum(len(set(v)) for v in found.values())
     flash = [n for n in names if "/attn.full/" in n]
@@ -177,8 +223,8 @@ def test_cell_ssd_moe_kernels_are_found_by_their_names(step_program):
 def test_cell_ssd_moe_scopes_are_where_the_readers_look(step_program):
     """`mixer_chain_ms.ssd` finds its operations by `op_name`: `ssm.chain`
     lies INSIDE `ssm` in the forward, remat's forward and the backward, and
-    holds no kernel and no matmul; every kernel and every matmul keeps a
-    scope of the vocabulary."""
+    holds exactly the four chain kernels' names and no matmul; every kernel
+    and every matmul keeps a scope of the vocabulary."""
     from ray_tpu.models import common
 
     compiled, _, _ = step_program
@@ -189,7 +235,10 @@ def test_cell_ssd_moe_scopes_are_where_the_readers_look(step_program):
     assert chain and not outside, outside[:5]
     assert any("rematted_computation" in n for n in chain)
     assert any(n.startswith("jit(_step_fn)/transpose(jvp())") for n in chain)
-    assert not any("dot_general" in n or "pallas_call" in n for n in chain)
+    assert not any("dot_general" in n for n in chain)
+    assert {n.rsplit("/", 2)[1] for n in _kernel_op_names(compiled)
+            if common.SSM_CHAIN in n} == {
+        k for k, v in KERNELS.items() if "ssm.chain" in v[2]}
     scope = re.compile(r"(?<![\w.])(" + "|".join(
         re.escape(s) for s in common.SCOPES) + r")(?![\w.])")
     assert all(scope.search(n) for n in _kernel_op_names(compiled))
