@@ -12,16 +12,24 @@ tile `tile_m` and run on from there; the rows between a group's end and the
 next tile edge are padding.  So every row tile belongs to ONE group, and the
 kernels are plain matmuls whose weight block is chosen by a prefetched
 scalar, `tile_group[i]`.  Tiles behind the last group's (`tiles_used` on)
-hold nothing: their programs compute nothing and re-visit the last used
-tile's blocks, so the pipeline moves nothing for them either (`column` in
-`_pallas_grouped` says what that takes once a tile's columns are cut into
-more than two blocks).  A width that is half a lane tile over a whole
-number of them (1856: `_off_grid`) is taken as it is: no parameter and no
-buffer holds a padded column.  What they
-would have written is NOT defined (whatever the buffer held): a caller reads
-only rows it placed (`models/moe.py` gathers by position and selects).
-Padding rows inside a used tile are computed like any row, from whatever the
-caller put there.
+hold nothing, and what their rows come out as is NOT defined (whatever the
+buffer held): a caller reads only rows it placed (`models/moe.py` gathers by
+position and selects).  Padding rows inside a used tile are computed like
+any row, from whatever the caller put there.  A width that is half a lane
+tile over a whole number of them (1856: `_off_grid`) is taken as it is: no
+parameter and no buffer holds a padded column.
+
+The walk (`_walk`; dw's grid goes the same way).  A column block's row tiles
+pass before the next column block's, so the block of its group's matrix that
+a program needs is the one the program before it had, until the group
+changes: a call fetches each (group that holds rows, column block) ONCE,
+however many tiles the group has, and the block is as wide as VMEM lets it
+be (`_BLOCK_BYTES`: the whole matrix at every width traced so far, so the
+rows are read once too).  A program of a tile behind the last used one
+computes nothing and works on the blocks of the program before it, the last
+used tile's at the same column block: no index moves, so the pipeline
+fetches nothing and writes nothing for it, at any number of column blocks.
+What is left of such a program is its fixed cost.
 
   - `grouped_matmul(x, w, layout)`: x [rows, k], w [groups, k, n] ->
     [rows, n]; `transpose_rhs=True` takes w [groups, n, k].
@@ -49,8 +57,13 @@ import jax.numpy as jnp
 from ray_tpu.ops import dispatch
 
 TILE_M = 256        # rows a tile: see PERF.md (PR 34) for the choice
-_BLOCK_BYTES = 4 << 20      # the most one weight block may take in VMEM
-_SUM_BYTES = 8 << 20        # and dw's float32 sum of one
+_VMEM_BYTES = 64 << 20      # what a kernel here asks of VMEM at most
+# The most one weight block may take: the pipeline holds two, half of what
+# the kernel may ask; two row tiles, two output tiles and the float32
+# product share the other half (2.8 + 2 + 2 MB at 256 x 2688 -> 1856, where
+# the two blocks are 20.6).
+_BLOCK_BYTES = _VMEM_BYTES // 4
+_SUM_BYTES = 8 << 20        # dw's float32 sum of one block
 
 
 @functools.partial(jax.tree_util.register_dataclass,
@@ -164,10 +177,12 @@ def _off_grid(width: int) -> bool:
 def _compiler_params():
     from jax.experimental.pallas import tpu as pltpu
 
-    # rows in order: a group's weight block stays while its tiles pass, and
-    # dw's scratch sums along the axis
+    # both axes in order: a column block's row tiles follow one another, so
+    # a group's weight block stays while its tiles pass, a tile behind the
+    # last used one finds every block where the program before it left it,
+    # and dw's scratch sums along the rows
     return pltpu.CompilerParams(
-        vmem_limit_bytes=64 << 20,
+        vmem_limit_bytes=_VMEM_BYTES,
         dimension_semantics=("arbitrary", "arbitrary"))
 
 
@@ -177,11 +192,23 @@ def _row_tile(i, used):
     return jnp.minimum(i, jnp.maximum(used[0] - 1, 0))
 
 
+def _walk(j, i, tile_group, used):
+    """(row tile, group, column block) that program (j, i) of the grid
+    (column blocks, row tiles) works on; `tile_group` [tiles] and `used` [1]
+    are the prefetched scalars (or, in a test, arrays on the host).  A tile
+    behind the last used one gives what the program before it gave:
+    `tile_group` repeats the last used tile's group there (`group_layout`),
+    the row tile is the last used one, and j is the same.  Where NO tile is
+    used every program is behind the first, so the column block stays too."""
+    return (_row_tile(i, used), tile_group[i],
+            jnp.where(used[0] > 0, j, 0))
+
+
 def _mm_kernel(tile_group_ref, used_ref, x_ref, w_ref, o_ref, *,
                transpose_rhs: bool):
     from jax.experimental import pallas as pl
 
-    @pl.when(pl.program_id(0) < used_ref[0])
+    @pl.when(pl.program_id(1) < used_ref[0])
     def _():
         o_ref[...] = jax.lax.dot_general(
             x_ref[...], w_ref[0],
@@ -199,36 +226,26 @@ def _pallas_grouped(x, w, layout: GroupLayout, transpose_rhs: bool):
     bn = _block_n(k, n, _BLOCK_BYTES // w.dtype.itemsize)
 
     w_block = (1, bn, k) if transpose_rhs else (1, k, bn)
-    blocks = -(-n // bn)
 
-    def column(i, j, used):
-        """The column block program (i, j) works on.  A tile behind the last
-        used one re-visits the last used tile's LAST column block, where the
-        program before it left off, so that nothing moves.  With one block
-        there is nothing to choose, and with two the pipeline's two output
-        buffers line up with the blocks re-visited in turn (each is written
-        back with what it held: the older cells' kernels, kept as they were
-        traced); from three on they no longer do, and a buffer would be
-        written back over ANOTHER block of the last used tile."""
-        return j if blocks <= 2 else jnp.where(i < used[0], j, blocks - 1)
+    def x_at(j, i, g, u):
+        return _walk(j, i, g, u)[0], 0
+
+    def w_at(j, i, g, u):
+        _, group, column = _walk(j, i, g, u)
+        return (group, column, 0) if transpose_rhs else (group, 0, column)
+
+    def out_at(j, i, g, u):
+        tile, _, column = _walk(j, i, g, u)
+        return tile, column
 
     return pl.pallas_call(
         functools.partial(_mm_kernel, transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(rows // tile_m, blocks),
-            in_specs=[
-                pl.BlockSpec((tile_m, k),
-                             lambda i, j, g, u: (_row_tile(i, u), 0)),
-                pl.BlockSpec(w_block,
-                             (lambda i, j, g, u: (g[i], column(i, j, u), 0))
-                             if transpose_rhs
-                             else (lambda i, j, g, u: (g[i], 0,
-                                                       column(i, j, u)))),
-            ],
-            out_specs=pl.BlockSpec(
-                (tile_m, bn),
-                lambda i, j, g, u: (_row_tile(i, u), column(i, j, u))),
+            grid=(-(-n // bn), rows // tile_m),  # a column block, then rows
+            in_specs=[pl.BlockSpec((tile_m, k), x_at),
+                      pl.BlockSpec(w_block, w_at)],
+            out_specs=pl.BlockSpec((tile_m, bn), out_at),
         ),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         compiler_params=_compiler_params(),
@@ -350,10 +367,12 @@ def grouped_matmul(x, w, layout: GroupLayout, transpose_rhs: bool = False):
     pallas = _use_pallas(k, n, tile_m)
     dispatch.record("grouped_matmul", "xla" if not pallas else
                     "interpret" if dispatch.interpret_mode() else "pallas")
+    bn = _block_n(k, n, _BLOCK_BYTES // w.dtype.itemsize)
     off = "".join(f",{name}{width}_{how}" for name, width, how in (
-        ("k", k, "whole"), ("n", n, "last_block_padded")) if _off_grid(width))
-    dispatch.record(
-        "grouped_matmul.plan",
-        f"tile{tile_m}x{_block_n(k, n, _BLOCK_BYTES // w.dtype.itemsize)},"
-        f"rows{x.shape[0]},groups{w.shape[0]}{off}")
+        ("k", k, "whole"),
+        ("n", n, "whole" if bn == n else "last_block_padded"))
+        if _off_grid(width))
+    dispatch.record("grouped_matmul.plan",
+                    f"tile{tile_m}x{bn},rows{x.shape[0]},groups{w.shape[0]}"
+                    f"{off}")
     return _grouped(x, w, layout, transpose_rhs, pallas)

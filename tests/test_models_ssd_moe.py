@@ -409,7 +409,7 @@ def test_train_step_carries_the_counts_and_the_plans(monkeypatch):
     assert any(p.endswith(",operands_bshd,heads2x64")
                and "rope_in_kernel" not in p
                for p in taken["flash_attention.plan"])
-    assert any(p.endswith("groups4,n192_last_block_padded")
+    assert any(p.endswith("groups4,n192_whole")   # ONE block: none padded
                for p in taken["grouped_matmul.plan"])
     assert any(p.startswith("kept:") for p in taken["train.remat"])
 

@@ -1,7 +1,10 @@
 """Grouped matmul (ops/grouped_matmul.py): the Pallas kernels, interpreted
 on the CPU, and the XLA formulation, against a plain einsum over each row's
 own group: forward, dx and dw; uneven groups, an empty group, every row in
-one group, no row at all, and the row bound reached."""
+one group, no row at all, and the row bound reached; the same with the
+matrices cut into two and three column blocks; and the walk over the grid
+itself (`_walk`), evaluated on the host: what moves from one program to the
+next."""
 
 import numpy as np
 import pytest
@@ -14,6 +17,7 @@ from ray_tpu.ops import grouped_matmul as gm
 TILE = 16
 GROUPS = 5
 BOUND = 55      # the most rows the five groups may hold together
+NARROW_BYTES = 128 * 128 * 4    # a block of 128 columns of `_case`'s matrices
 
 # group sizes -> what the case is
 _SIZES = {
@@ -29,9 +33,24 @@ _SIZES = {
 
 @pytest.fixture(params=["interpret", "xla"])
 def path(request, monkeypatch):
+    """The path a test takes.  `narrow` is the interpreter with the byte
+    limit cut down to blocks of 128 columns of `_case`'s float32 matrices:
+    the cells' matrices are one block each, so the walk over several column
+    blocks is held here."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET",
-                       "1" if request.param == "interpret" else "")
+                       "" if request.param == "xla" else "1")
+    if request.param == "narrow":
+        monkeypatch.setattr(gm, "_BLOCK_BYTES", NARROW_BYTES)
+        return "interpret"
     return request.param
+
+
+# (group sizes, path, n): every split on both paths at one block, and the
+# splits that leave most tiles empty at two and three
+_ONE_BLOCK = [(name, path, 256) for name in sorted(_SIZES)
+              for path in ("interpret", "xla")]
+_MORE_BLOCKS = [(name, "narrow", n) for n in (256, 384) for name in (
+    "an_empty_group", "every_row_to_one_group", "no_row_at_all")]
 
 
 def _case(sizes, k=128, n=256, seed=0):
@@ -51,9 +70,12 @@ def _einsum_reference(x, w, layout):
                      jnp.einsum("rk,rkn->rn", x, w[group]), 0.0), valid
 
 
-@pytest.mark.parametrize("name", sorted(_SIZES))
-def test_forward_dx_and_dw_match_an_einsum(name, path):
-    layout, x, w, c = _case(_SIZES[name])
+@pytest.mark.parametrize("name,path,n", _ONE_BLOCK + _MORE_BLOCKS,
+                         indirect=["path"])
+def test_forward_dx_and_dw_match_an_einsum(name, path, n):
+    layout, x, w, c = _case(_SIZES[name], n=n)
+    if gm._BLOCK_BYTES == NARROW_BYTES:     # n is two or three blocks
+        assert gm._block_n(128, n, NARROW_BYTES // 4) == 128 < n
     _, valid = gm.row_groups(layout)
     assert int(valid.sum()) == sum(_SIZES[name])      # no row left out
 
@@ -85,15 +107,71 @@ def test_forward_dx_and_dw_match_an_einsum(name, path):
             assert not np.asarray(dw[g]).any()
 
 
-@pytest.mark.parametrize("name", ["uneven", "an_empty_group"])
-def test_transposed_matrices_give_the_same_product(name, path):
-    layout, x, w, _ = _case(_SIZES[name])
+@pytest.mark.parametrize("name,path,n", [
+    (name, path, 256) for name in ("uneven", "an_empty_group")
+    for path in ("interpret", "xla")] + [
+    (name, "narrow", n) for n in (256, 384)
+    for name in ("uneven", "an_empty_group")], indirect=["path"])
+def test_transposed_matrices_give_the_same_product(name, path, n):
+    layout, x, w, _ = _case(_SIZES[name], n=n)
     _, valid = gm.row_groups(layout)
     a = gm.grouped_matmul(x, w, layout)
     b = gm.grouped_matmul(x, w.transpose(0, 2, 1), layout, transpose_rhs=True)
     np.testing.assert_allclose(np.asarray(jnp.where(valid[:, None], a, 0)),
                                np.asarray(jnp.where(valid[:, None], b, 0)),
                                atol=1e-4, rtol=1e-5)
+
+
+# group sizes -> the tiles of the buffer (None: `layout_rows`' nine)
+_WALKS = {
+    "uneven": ([5, 2, 33, 14, 1], None),
+    "an_empty_group_in_the_middle": ([20, 0, 17, 0, 18], None),
+    "an_empty_last_group": ([20, 3, 17, 15, 0], None),
+    "no_used_tile": ([0, 0, 0, 0, 0], None),
+    "a_full_buffer": ([16, 32, 1, 0, 6], 5),
+}
+
+
+@pytest.mark.parametrize("blocks", [1, 2, 3])
+@pytest.mark.parametrize("name", sorted(_WALKS))
+def test_the_walk_fetches_a_block_once_and_moves_nothing_for_an_empty_tile(
+        name, blocks):
+    """`_walk` over the whole grid (column blocks, row tiles), in the order
+    the programs run: the pipeline fetches a block where its index differs
+    from the program's before, and writes an output block back where the
+    next program's differs."""
+    sizes, tiles = _WALKS[name]
+    tiles = tiles or gm.layout_rows(BOUND, GROUPS, TILE) // TILE
+    layout = gm.group_layout(jnp.asarray(sizes, jnp.int32), tiles * TILE, TILE)
+    used = int(layout.tiles_used)
+    assert used == sum(-(-s // TILE) for s in sizes) <= tiles
+    assert (used == tiles) == (name == "a_full_buffer")
+    j, i = (v.reshape(-1) for v in np.meshgrid(
+        np.arange(blocks), np.arange(tiles), indexing="ij"))
+    tile, group, column = (np.broadcast_to(np.asarray(v), i.shape) for v in
+                           gm._walk(j, i, layout.tile_group,
+                                    layout.tiles_used.reshape(1)))
+    x_at = np.stack([tile], 1)
+    w_at = np.stack([group, column], 1)
+    out_at = np.stack([tile, column], 1)
+    # a used tile's program works on its own tile, its group, its column
+    live = i < used
+    tile_group = np.asarray(layout.tile_group)
+    assert (tile[live] == i[live]).all() and (column[live] == j[live]).all()
+    assert (group[live] == tile_group[i[live]]).all()
+    assert all(sizes[g] > 0 for g in group[live])
+    # a program behind the last used tile: nothing moves
+    behind = ~live
+    behind[0] = False                   # the first program has none before
+    for at in (x_at, w_at, out_at):
+        assert (at[behind] == at[np.flatnonzero(behind) - 1]).all()
+    # a weight block is fetched once a (group that holds rows, column block)
+    fetches = 1 + int((w_at[1:] != w_at[:-1]).any(axis=1).sum())
+    held = sum(s > 0 for s in sizes)
+    assert fetches == max(held * blocks, 1)
+    # and an output block is written back once, behind its one program
+    writes = 1 + int((out_at[1:] != out_at[:-1]).any(axis=1).sum())
+    assert writes == max(used * blocks, 1)
 
 
 def test_layout_begins_every_group_at_a_tile_and_fits_any_split():

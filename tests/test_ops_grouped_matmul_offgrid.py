@@ -73,13 +73,18 @@ def test_forward_dx_and_dw_match_the_xla_formulation(split, k, n,
 
 
 def test_the_blocks_of_the_cell_s_faces_and_what_the_plan_says(monkeypatch):
-    """2688 -> 1856 (n off the grid): blocks of 640 columns, three of them,
-    the last padded by 64; 1856 -> 2688 (k off the grid): the whole 1856 a
-    block, 896 columns; dw's float32 sums likewise.  Nothing is stored in a
-    parameter: the widths in the plan are the published ones."""
+    """2688 -> 1856 and back, bfloat16: the whole matrix is one block since
+    PR 50 (9.5 MiB of the 16 a block may take), the off-grid width a block's
+    whole dimension either way.  Where it does not fit (dw's float32 sums of
+    8 MiB; a forward block held to 4 MiB, as before PR 50) n off the grid is
+    cut into blocks of 640 columns, three of them, the last padded by 64,
+    and k off the grid stays whole beside 896 columns.  Nothing is stored in
+    a parameter: the widths in the plan are the published ones."""
     limit = gm._BLOCK_BYTES // 2
-    assert gm._block_n(2688, 1856, limit) == 640
-    assert gm._block_n(1856, 2688, limit) == 896
+    assert gm._block_n(2688, 1856, limit) == 1856
+    assert gm._block_n(1856, 2688, limit) == 2688
+    assert gm._block_n(2688, 1856, (4 << 20) // 2) == 640
+    assert gm._block_n(1856, 2688, (4 << 20) // 2) == 896
     assert gm._block_n(2688, 1856, gm._SUM_BYTES // 4) == 640
     assert gm._block_n(1856, 2688, gm._SUM_BYTES // 4) == 896
     assert gm._off_grid(1856) and not gm._off_grid(1792) \
@@ -94,21 +99,29 @@ def test_the_blocks_of_the_cell_s_faces_and_what_the_plan_says(monkeypatch):
     gm.grouped_matmul(x, w, layout)
     gm.grouped_matmul(x[:, :NARROW], jnp.swapaxes(w, 1, 2), layout)
     assert list(dispatch.taken()["grouped_matmul.plan"]) == [
-        f"tile16x192,rows64,groups2,n192_last_block_padded",
-        f"tile16x256,rows64,groups2,k192_whole"]
+        "tile16x192,rows64,groups2,n192_whole",
+        "tile16x256,rows64,groups2,k192_whole"]
+    # cut into two blocks of 128 columns the plan says which one is padded
+    monkeypatch.setattr(dispatch, "_taken", {})
+    monkeypatch.setattr(gm, "_BLOCK_BYTES", WIDE * 128 * 4)
+    gm.grouped_matmul(x, w, layout)
+    assert list(dispatch.taken()["grouped_matmul.plan"]) == [
+        "tile16x128,rows64,groups2,n192_last_block_padded"]
 
 
 @pytest.mark.parametrize("k,n,block,dw_block", [
     (2048, 768, 768, 768), (768, 2048, 2048, 2048),     # train-moe-mla-d6
-    (3072, 1024, 512, 512), (1024, 3072, 1536, 1536),   # train-swa-moe-d5
+    (3072, 1024, 1024, 512), (1024, 3072, 3072, 1536),  # train-swa-moe-d5
     (2048, 512, 512, 512), (512, 2048, 2048, 2048),     # train-gdn-moe-d4
-    (2048, 2048, 1024, 1024),                           # train-cca-moe-d4
+    (2048, 2048, 2048, 1024),                           # train-cca-moe-d4
     (64, 32, 32, 32), (32, 64, 64, 64)])                # the tiny sizes
 def test_the_older_faces_blocks_are_the_ones_chosen_before(k, n, block,
                                                            dw_block):
-    """`_block_n` as the parent had it, for every face the four older expert
-    cells trace (bfloat16 blocks of 4 MiB, float32 sums of 8) and for a
-    width that is no multiple of 128 and not half a tile over one."""
+    """`_block_n`'s rule as PR 47's tree had it, for every face the four
+    older expert cells trace and for a width that is no multiple of 128 and
+    not half a tile over one.  What moved with PR 50 is the limit of the
+    forward / transposed kernel (bfloat16 blocks of 16 MiB where they were
+    4: every face here is ONE block now; dw's float32 sums of 8 stand)."""
     def parents(k, n, limit):
         if k * n <= limit or n % 128:
             return n

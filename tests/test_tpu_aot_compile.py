@@ -1171,6 +1171,12 @@ def test_cell_swa_moe_step_program_fits_a_v5e(step_program):
     assert set(taken["routed_experts"]) == {"pallas"}
     assert all(p.startswith("rows_by_index,slots81920,buffer")
                for p in taken["routed_experts.plan"])
+    # both faces' matrices are ONE block (PR 50: 6 MiB of the 16 a block
+    # may take; two blocks of 512 and 1536 columns before), at the usual
+    # buffer and at the bound's
+    assert sorted(taken["grouped_matmul.plan"]) == [
+        "tile256x1024,rows12288,groups8", "tile256x1024,rows67584,groups8",
+        "tile256x3072,rows12288,groups8", "tile256x3072,rows67584,groups8"]
     assert sorted(p.split(",dead")[1] for p in
                   taken["flash_attention.plan"]) == [
         "50/50%,window512,visited12.1%,rope_in_kernel,operands_bshd,"
@@ -1555,16 +1561,24 @@ def test_a_remat_layer_of_every_attention_entry_keeps_out_and_lse(
 # ops/row_gather.py); the hybrid's and both dense layers' stand.
 # `train-gdn-moe-d4` is PR 46's tree (40fa1e4), written down before PR 47
 # moved the model files' shared stack into models/stack.py, as is
-# `train-cca-moe-d4` in tests/test_tpu_aot_compile_cca.py.
+# `train-cca-moe-d4` in tests/test_tpu_aot_compile_cca.py.  PR 50 MEANT TO:
+# the three expert cells' digests here (and the two in the `_cca` and `_ssd`
+# files) are its tree's: ops/grouped_matmul.py's forward / transposed grid
+# walks a column block's row tiles before the next column block, and
+# `train-swa-moe-d5`'s matrices are one block where they were two; in
+# `train-moe-mla-d6` and `train-gdn-moe-d4` (one block before and after) the
+# kernel's two grid axes changed places and nothing else.  PR 49's tree read
+# a601fb23.., 9846286b.., 7cc70467..; the hybrid's and both dense layers'
+# stand.
 PARENT_HLO_SHA256 = {
     "train-hybrid-d8":
         "3d480d458ec2cf6d978d269f5cdda6a3c7f2dcedd415d84a8be116758340a32b",
     "train-moe-mla-d6":
-        "a601fb239f5eb1614c9db8af0ab5e12073e0d19356ff06fc13a98fde2bfec779",
+        "3ca4b7c9c283cef8b41c7806c1711cd6c60458da9f93049b76045b9bd299f8dd",
     "train-swa-moe-d5":
-        "9846286bb91a94b6fbf231d92a1b4299b36e1116472519b01770a01a46a5f0f4",
+        "f2e033300b79a9054556a89eb396fc3ad498458c8b1ff89950c36042f0fb56f1",
     "train-gdn-moe-d4":
-        "7cc704672c11a8ef5a0320fc562c5b5036cc622507febc75399d827196dff252",
+        "2ad23ab37fda3cc27b1a3cd8dfebd2e407048da425c9639c2c89d28d2f2004a3",
     "dense-layer.one_chip":
         "f8ed670122d29fe6c37a4e2abeab135595d735282e71449a49a0e737920e6abf",
     "dense-layer.fsdp4":

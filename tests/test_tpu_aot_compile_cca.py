@@ -88,9 +88,12 @@ def step_program(topo):
 # sha256 of the step program's optimised HLO, `_metadata_stripped`, as PR
 # 46's tree (40fa1e4) compiled it: tests/test_tpu_aot_compile.py's
 # `PARENT_HLO_SHA256` has the rule (a change that means to move the program
-# replaces the digest and says so) and the other cells'.
+# replaces the digest and says so) and the other cells'.  PR 50 MEANT TO:
+# the grouped kernels' forward / transposed grid walks a column block's row
+# tiles before the next column block and the 2048 x 2048 matrix is ONE block
+# (PR 46's tree read 2d09fc2a..).
 PARENT_HLO_SHA256 = (
-    "2d09fc2a1ab5f7a3f17ac1c40ba7c553b46de1a5965a01c6df22d1b1e296fae1")
+    "80b748b99e62eb2171c103fe147284e3439f32ea2a6216d5e70600fbff9fe333")
 
 
 def test_cell_cca_moe_optimised_hlo_is_as_the_parent_compiled_it(
@@ -150,7 +153,7 @@ TAKEN = {
         p.split(",dead")[1] for p in plans] == [
             "6/6%,rope_in_kernel,operands_bshd,heads1x128"],
     "grouped_matmul.plan": lambda plans: list(plans) == [
-        "tile256x1024,rows12288,groups16"],
+        "tile256x2048,rows12288,groups16"],
     "flash_attention": lambda paths: set(paths) == {"pallas"},
     "grouped_matmul": lambda paths: set(paths) == {"pallas"},
     "cca_moe.mix": lambda plans: list(plans) == [

@@ -93,9 +93,11 @@ def step_program(topo):
 # has the rule (a change that means to move the program replaces the digest
 # and says so) and the other cells'.  PR 49 MEANT TO: the chain round the
 # recurrence is ops/mixer_chain.py's four kernels (PR 48's tree read
-# 7dffeb93..).
+# 7dffeb93..).  PR 50 MEANT TO: the grouped kernels' forward / transposed
+# grid walks a column block's row tiles before the next column block and
+# both faces' matrices are ONE block (PR 49's tree read ec413a9a..).
 PARENT_HLO_SHA256 = (
-    "ec413a9a62eec4a8957ae0d829e4e0e88569d16ec742059c28a41316ba856c9f")
+    "d67ca952facacb4528e16a1ced8b5b534b4f43c511fe412601d1e5c9ef458aea")
 
 
 def test_cell_ssd_moe_optimised_hlo_is_as_this_pr_compiled_it(step_program):
@@ -136,10 +138,10 @@ PLANS = {
     "ssd_scan.plan": ["chunk128,heads64over8,p64,n128,state_f32,bwd_pallas,"
                       "passes2.625+5.375"],
     "grouped_matmul.plan": [
-        "tile256x640,rows38912,groups8,n1856_last_block_padded",
-        "tile256x896,rows38912,groups8,k1856_whole",
-        "tile256x640,rows149504,groups8,n1856_last_block_padded",
-        "tile256x896,rows149504,groups8,k1856_whole"],
+        "tile256x1856,rows38912,groups8,n1856_whole",
+        "tile256x2688,rows38912,groups8,k1856_whole",
+        "tile256x1856,rows149504,groups8,n1856_whole",
+        "tile256x2688,rows149504,groups8,k1856_whole"],
     "ssd_moe.experts": ["relu2,ungated,k6of128,held8"],
 }
 
