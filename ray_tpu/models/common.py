@@ -39,6 +39,15 @@ OPTIMIZER = "optimizer"          # everything of the step behind the gradient
 SCOPES = (ATTN_FULL, ATTN_SLIDING, ATTN_CROSS, ATTN_GATE, MLA_PROJECT, SSM,
           GMU, MLP, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE, EMBED,
           LOSS, OPTIMIZER)
+# A residual stream of several lanes: the calls round a sublayer that mix
+# them (ops/hyper_connection.py) and the collapse behind the stack, OUTSIDE
+# every sublayer's own scope.  A part of the step of its own, but NOT in
+# SCOPES yet: whoever tiles the step gives every name of SCOPES a bucket
+# (benchmark/part_lib.py, which tests/test_program_report.py holds to this
+# tuple), and until the tiling has one for it these operations are the
+# tiling's `unscoped`; a reader that wants them alone finds the name in
+# `op_name` (`residual_mix_ms`).
+RESID_MIX = "resid.mix"
 # A name INSIDE one of the scopes above, which decides no part: what
 # compressed convolutional attention does between its projections and the
 # kernels (the shifted value half, both convolutions, the q-k mean, the l2

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import common, moe
+from ray_tpu.parallel.sharding import with_logical_constraint
 
 F32 = jnp.float32
 Patterns = List[Tuple[Tuple[Any, ...], int, int]]
@@ -65,7 +66,37 @@ _DRAWS = {
     # name: the two round differently, and the values are the seed's
     "fan_in": lambda key, shape, fan_in: (
         jax.random.normal(key, shape) * (1.0 / math.sqrt(fan_in))),
+    # a sublayer's lane leaves (`hc_shapes`): the three scales of the
+    # dynamic part, and the static part
+    "hc_scale": lambda key, shape: (
+        jnp.asarray(HC_SCALE[:shape[0]])
+        * (1.0 + 0.1 * jax.random.normal(key, shape))),
+    "hc_base": lambda key, shape: hc_base(key, shape),
+    "hc_pre_base": lambda key, shape: jnp.full(
+        shape, -math.log(shape[0] - 1.0)),
 }
+# The lanes' draws.  With w_hc = 0 the lanes do nothing (`residual`): pre
+# sums to 1 (base -ln(n - 1) under the sigmoid), post is 1 (base 0), comb is
+# doubly stochastic whatever its base.  The seeded weights are drawn so that
+# each part of the mixing carries weight in a comparison with a plain
+# reference: w_hc normal / sqrt(n d), so m is about normal(0, 1) a token;
+# scales of 0.5, 0.5 and 1 (x (1 + 0.1 normal)), so the DYNAMIC part moves
+# pre and post by tens of per cent and comb's logits by about 1; the
+# diagonal of base's matrix part 2 x (1 + 0.1 normal), so the STATIC part
+# keeps about 0.7 of a lane in its lane; and rows of such logits are far
+# enough from doubly stochastic that ONE round where twenty are due shows.
+HC_SCALE = (0.5, 0.5, 1.0)
+HC_DIAGONAL = 2.0
+
+
+def hc_base(key, shape):
+    """base [2 n + n^2]: -ln(n - 1) for pre, 0 for post, the matrix part
+    a drawn diagonal."""
+    n = math.isqrt(shape[0] + 1) - 1
+    pre = jnp.full((n,), -math.log(n - 1.0))
+    diagonal = HC_DIAGONAL * (1.0 + 0.1 * jax.random.normal(key, (n,)))
+    return jnp.concatenate([pre, jnp.zeros((n,)),
+                            jnp.diag(diagonal).reshape(-1)])
 
 
 def swiglu_shapes(prefix: str, h: int, m: int, experts=None):
@@ -75,6 +106,24 @@ def swiglu_shapes(prefix: str, h: int, m: int, experts=None):
     return {f"{prefix}_gate": (lead + (h, m), axis + ("embed", "mlp"), h),
             f"{prefix}_up": (lead + (h, m), axis + ("embed", "mlp"), h),
             f"{prefix}_down": (lead + (m, h), axis + ("mlp", "embed"), m)}
+
+
+def hc_shapes(prefix: str, h: int, n: int):
+    """The table's rows of ONE sublayer's lane leaves (ops/
+    hyper_connection.py): `<prefix>_w` [n h, 2 n + n^2], `_scale` [3],
+    `_base` [2 n + n^2], float32 whatever the compute dtype."""
+    width = 2 * n + n * n
+    return {f"{prefix}_w": ((n * h, width), ("embed", None), n * h),
+            f"{prefix}_scale": ((3,), (None,), "hc_scale"),
+            f"{prefix}_base": ((width,), (None,), "hc_base")}
+
+
+def hc_head_shapes(h: int, n: int):
+    """.. and of the collapse behind the last layer, a GROUP of the top
+    table: `w` [n h, n], `scale` [1], `base` [n]."""
+    return {"w": ((n * h, n), ("embed", None), n * h),
+            "scale": ((1,), (None,), "hc_scale"),
+            "base": ((n,), (None,), "hc_pre_base")}
 
 
 def relu2_shapes(prefix: str, h: int, m: int, experts=None):
@@ -112,7 +161,8 @@ class Params:
         one and of "layers", as the MODEL splits its key.  Layer i (first +
         rep x len(pattern) + its position) draws from fold_in(keys["layers"],
         i), one split of that a leaf in the table's order, whether the
-        leaf's draw uses it or not."""
+        leaf's draw uses it or not; a group's leaves likewise from ITS
+        key."""
         def layer(kind, index):
             shapes = self.layer_shapes(kind, config)
             split = jax.random.split(
@@ -125,9 +175,23 @@ class Params:
             config, lambda kind, at, stride, repeats: jax.tree.map(
                 lambda *a: jnp.stack(a),
                 *[layer(kind, at + rep * stride) for rep in range(repeats)])),
-            **{name: self.draw(keys.get(name), shape, init,
-                               config.param_dtype)
-               for name, (shape, _, init) in self.top_shapes(config).items()}}
+            **self._top(config, lambda name, spec, key: self.draw(
+                key, spec[0], spec[2], config.param_dtype), keys)}
+
+    def _top(self, config, leaf, keys: Optional[Mapping[str, Any]] = None):
+        """{name: leaf(name, spec, key)} over the top table, a group a dict
+        of the same; `keys[name]` is a leaf's key, and a group's, of which
+        each of its leaves gets one split."""
+        def entry(name, spec, key):
+            if not isinstance(spec, dict):
+                return leaf(name, spec, key)
+            split = [None] * len(spec) if key is None \
+                else jax.random.split(key, len(spec))
+            return {n: entry(n, s, k)
+                    for k, (n, s) in zip(split, spec.items())}
+
+        return {name: entry(name, spec, (keys or {}).get(name))
+                for name, spec in self.top_shapes(config).items()}
 
     def _layers(self, config, position):
         """{segNN: {position in the pattern: position(kind, its first
@@ -149,8 +213,7 @@ class Params:
                                   init))
                 for name, (shape, axes, init)
                 in self.layer_shapes(kind, config).items()}),
-            **{name: leaf(name, spec)
-               for name, spec in self.top_shapes(config).items()}}
+            **self._top(config, lambda name, spec, key: leaf(name, spec))}
 
     def logical_axes(self, config) -> Dict[str, Any]:
         """Logical-axis tree matching `init`, for parallel.sharding."""
@@ -190,8 +253,10 @@ def layer_fn(layer, kind, config):
                               config.remat, config.remat_policy)
 
 
-def walk(layer, config, segments, layers, carry, beside):
-    """One scan a segment of one kind over `layers` (`params["layers"]`):
+def walk(layer, config, segments, layers, carry, beside, first: int = 0):
+    """One scan a segment of one kind over `layers` (`params["layers"]`;
+    `segments` the model's from its `first` on, where a model walks its
+    stack in two goes):
     `layer(carry, a layer's leaves, beside(kind), kind=, c=) -> (carry, an
     expert layer's routing counts or None)`, the carry of any shape,
     `beside(kind)` what rides beside it (rope tables).  -> (carry, the LAST
@@ -204,8 +269,8 @@ def walk(layer, config, segments, layers, carry, beside):
         def body(carry, lp, fn=fn, extra=extra):
             return fn(carry, lp, extra)
 
-        carry, per_layer = jax.lax.scan(body, carry,
-                                        layers[segment_name(si)]["0"])
+        carry, per_layer = jax.lax.scan(
+            body, carry, layers[segment_name(first + si)]["0"])
         if per_layer is not None:
             stats = jax.tree.map(lambda a: a[-1], per_layer)
             held = jnp.sum(per_layer["rows_held"])
@@ -213,6 +278,56 @@ def walk(layer, config, segments, layers, carry, beside):
     if stats is not None:
         stats["rows_held_all_layers"] = rows_held
     return carry, stats
+
+
+def hyper(config):
+    """The lanes' settings (`ops.hyper_connection.HC`) of a config whose
+    residual stream has several (`hc_mult`), else None."""
+    from ray_tpu.ops.hyper_connection import HC
+
+    if not getattr(config, "hc_mult", None):
+        return None
+    return HC(config.hc_mult, config.hc_sinkhorn_iters, config.hc_eps,
+              config.rms_norm_eps, config.mhc_h_res_clamp_min,
+              config.mhc_h_res_clamp_max)
+
+
+def residual(x, sublayer, lp, prefix: str, config, mixes=None):
+    """A sublayer round the residual stream: x + sublayer(x), `sublayer`
+    norm and all, where the stream is one lane.  Where it is several
+    (`hyper`), the two calls of ops/hyper_connection.py round it with the
+    sublayer's own lane leaves `lp[<prefix>_w | _scale | _base]`, under the
+    scope `resid.mix` and outside the sublayer's: x [b, s, n h], the
+    sublayer sees the lanes' weighted sum [b, s, h]; the call's `mix` is
+    appended to `mixes` where a list is given (`comb_row_err` reads it).
+    With w = 0 and the draws of `hc_shapes` equal lanes stay equal and each
+    is the one-lane stream."""
+    hc = hyper(config)
+    if hc is None:
+        return with_logical_constraint(x + sublayer(x),
+                                       ("batch", "seq", "embed"))
+    from ray_tpu.ops.hyper_connection import hc_post, hc_pre
+
+    with jax.named_scope(common.RESID_MIX):
+        u, mix, x = hc_pre(x, lp[prefix + "_w"], lp[prefix + "_scale"],
+                           lp[prefix + "_base"], hc)
+    if mixes is not None:
+        mixes.append(mix)
+    y = sublayer(u)
+    with jax.named_scope(common.RESID_MIX):
+        return with_logical_constraint(hc_post(x, y, mix, hc),
+                                       ("batch", "seq", "embed"))
+
+
+def comb_row_err(mixes, config):
+    """The worst |row sum - 1| of the lanes' mixing matrix over the tokens
+    of these calls' `mix`: what the Sinkhorn rounds leave (its columns are
+    the last thing they divide)."""
+    from ray_tpu.ops.hyper_connection import mix_parts
+
+    return functools.reduce(jnp.maximum, [
+        jnp.max(jnp.abs(jnp.sum(mix_parts(mix, config.hc_mult)[2], -1) - 1.0))
+        for mix in mixes])
 
 
 def routed_part(flat, route, w_gate, w_up, w_down, config, usual_load: int):
@@ -304,41 +419,74 @@ class LossTail:
     tokens, config) -> (normed hidden states [b, s, hidden], the routing
     counts or None)` and the name of the leaf that is its output head
     ([vocab, hidden]: "lm_head", or "tok_embed" where tied).  A model module
-    binds its public names from one of these."""
+    binds its public names from one of these.  `second(params, tokens,
+    next_tokens, config)`, where a model has a block BEHIND the stack that
+    re-reads the embedding and the head (multi-token prediction at depth 1,
+    DeepSeek-V3's report, section 2.2), gives None for a config without the
+    block, else (what `forward_hidden` gives, the block's normed hidden
+    states from the tokens one position on, the second loss's weight): a
+    position's objective is then nll_main[i] + weight x nll_second[i],
+    nll_second[i] = -log p_block(tokens[i+2] | tokens[:i+2]) and zero at
+    the last position, which has no such token."""
 
-    def __init__(self, forward_hidden, head: str):
+    def __init__(self, forward_hidden, head: str,
+                 second: Optional[Callable] = None):
         self.forward_hidden, self.head = forward_hidden, head
+        self.second = second
 
     def forward(self, params, tokens, config):
         """tokens [b, s] int32 -> logits [b, s, vocab] (fp32)."""
         x, _ = self.forward_hidden(params, tokens, config)
         return common.tied_logits(x, params[self.head], config.dtype)
 
+    def _nll(self, x, params, targets, config):
+        if config.fused_ce:
+            return common.fused_nll(x, params[self.head], targets)
+        logits = common.tied_logits(x, params[self.head], config.dtype)
+        return common.logits_nll(logits, targets)
+
     def _nll_and_stats(self, params, batch, config):
+        """(a position's objective [b, s], the routing counts or None, the
+        second loss's mean or None)."""
         tokens = batch["tokens"]
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
-        x, stats = self.forward_hidden(params, inputs, config)
-        if config.fused_ce:
-            return common.fused_nll(x, params[self.head], targets), stats
-        logits = common.tied_logits(x, params[self.head], config.dtype)
-        return common.logits_nll(logits, targets), stats
+        both = self.second and self.second(params, inputs, targets, config)
+        if not both:
+            x, stats = self.forward_hidden(params, inputs, config)
+            return self._nll(x, params, targets, config), stats, None
+        (x, stats), x2, weight = both
+        nll = self._nll(x, params, targets, config)
+        # the block sees position i's stream and token i + 1 and scores
+        # token i + 2; the last position has none: any id there, masked
+        ahead = jnp.concatenate([targets[:, 1:], targets[:, :1]], axis=1)
+        nll2 = self._nll(x2, params, ahead, config)
+        with jax.named_scope(common.LOSS):
+            s = targets.shape[1]
+            nll2 = jnp.where(jnp.arange(s) < s - 1, nll2, 0.0)
+            return (nll + weight * nll2, stats,
+                    jnp.sum(nll2) / max(nll2.size - nll2.shape[0], 1))
 
     def token_nll(self, params, batch, config):
-        """-log p(tokens[t+1] | tokens[:t+1]) for every position: [b, s]
-        fp32.  batch: {"tokens": [b, s+1] int32}."""
+        """A position's objective: -log p(tokens[t+1] | tokens[:t+1]) (plus
+        the weighted second loss where the config has one) for every
+        position: [b, s] fp32.  batch: {"tokens": [b, s+1] int32}."""
         return self._nll_and_stats(params, batch, config)[0]
 
     def loss_and_metrics(self, params, batch, config):
-        """(next-token cross-entropy, the LAST expert layer's routing counts
+        """(the mean objective, the LAST expert layer's routing counts
         and `rows_held_all_layers` as `moe_*` device scalars, which
-        `ShardedTrainStep` carries in the step's metrics; none without an
-        expert layer)."""
-        nll, stats = self._nll_and_stats(params, batch, config)
+        `ShardedTrainStep` carries in the step's metrics, none without an
+        expert layer; `mtp_nll`, the second loss's mean over the positions
+        that have one)."""
+        nll, stats, second = self._nll_and_stats(params, batch, config)
         mask = batch.get("mask")
         loss = common.masked_mean(nll, None if mask is None else mask[:, 1:])
-        return loss, {f"moe_{k}": v for k, v in (stats or {}).items()}
+        metrics = {f"moe_{k}": v for k, v in (stats or {}).items()}
+        if second is not None:
+            metrics["mtp_nll"] = second
+        return loss, metrics
 
     def loss_fn(self, params, batch, config):
-        """Next-token cross-entropy: the mean of `token_nll`, over the
-        positions batch["mask"] keeps if there is one."""
+        """The mean of `token_nll`, over the positions batch["mask"] keeps
+        if there is one."""
         return self.loss_and_metrics(params, batch, config)[0]

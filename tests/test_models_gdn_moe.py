@@ -339,7 +339,7 @@ def test_the_linear_mixer_through_the_op_equals_the_parents_lines(
             jnp.linalg.norm(w)), name
 
 
-def test_train_step_carries_the_counts_and_the_plans():
+def test_train_step_carries_the_counts_and_the_plans(monkeypatch):
     """Through ShardedTrainStep: the loss falls, the step's metrics hold the
     LAST layer's routing counts and the rows of all four layers, its forced
     spans hold them as attributes, and the plans say what ran."""
@@ -348,6 +348,9 @@ def test_train_step_carries_the_counts_and_the_plans():
     from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
     from ray_tpu.util import tracing
 
+    # what ran HERE: the record is the process's, and `--dist loadfile` puts
+    # other files' interpreted chains in front of this one
+    monkeypatch.setattr(dispatch, "_taken", {})
     config = gm.GdnMoEConfig.tiny(fused_ce=True)
     mesh = build_mesh(axes={"fsdp": 1}, devices=jax.devices()[:1])
     ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(

@@ -257,9 +257,12 @@ def sound():
     config = _f32()
     params = sm.init_params(config, jax.random.PRNGKey(6))
     tokens = _tokens(rows=1, seq=64)
-    import os
-    os.environ["RAY_TPU_PALLAS_INTERPRET"] = "1"
-    got = sm.token_nll(params, {"tokens": jnp.asarray(tokens)}, config)[0]
+    # for this call alone: left in os.environ it outlives the module in its
+    # xdist worker and turns the next file's dispatch to the interpreter
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+        got = sm.token_nll(params, {"tokens": jnp.asarray(tokens)},
+                           config)[0]
     return config, params, tokens[0], np.asarray(got)
 
 
