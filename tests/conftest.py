@@ -39,6 +39,35 @@ def ray_start_shared():
     ray_tpu.shutdown()
 
 
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e:2x2 with no chip attached (tests/aot.py): libtpu is
+    loaded by the xdist worker that first asks, the compile cache is off
+    while the module runs."""
+    import aot
+
+    yield from aot.describe_v5e()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def step_program(request, topo):
+    """`aot.compile_step`'s four (compiled, taken, train group, parameter
+    count) of the whole step program of the cell whose file asks: its `CONFIG`
+    names the configuration, its `STEP_STATIC`, where it has one, the rung.
+    One compile a module, which every test of the step shares."""
+    import aot
+
+    return aot.compile_step(topo, request.module.CONFIG,
+                            **getattr(request.module, "STEP_STATIC", {}))
+
+
 # ---------------------------------------------------------------------------
 # Hang watchdog: any single test exceeding WATCHDOG_S dumps EVERY
 # thread's stack to the real stderr (bypassing capture) and kills the

@@ -81,11 +81,13 @@ def test_7b_param_and_opt_state_fit_v5p_under_fsdp8():
 
 def test_7b_width_truncated_depth_trains_on_virtual_mesh():
     """One REAL sharded train step at full 7B width (hidden 4096,
-    mlp 11008, 32 heads) with depth cut to 2 layers — exercises the
-    exact per-layer partitioning the full model uses, with memory a CPU
-    host can hold."""
+    mlp 11008, 32 heads) with depth cut to one layer and the vocabulary
+    to a quarter (8000 rows shard over fsdp:8 as 32000 do; the embedding
+    and the head were over half of the step's parameters and of its
+    minutes) — exercises the exact per-layer partitioning the full model
+    uses, with memory a CPU host can hold."""
     config = tfm.TransformerConfig.llama2_7b(
-        num_layers=1, max_seq_len=32)
+        num_layers=1, max_seq_len=32, vocab_size=8000)
     devices = jax.devices()[:8]
     mesh = build_mesh(axes={"fsdp": 8}, devices=devices)
     ts = ShardedTrainStep(

@@ -21,6 +21,14 @@ def _interpret_mode(monkeypatch):
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
 
 
+# The program's per-token loss as ONE jitted function for the file: cases
+# with an equal configuration share its compile, where a call dispatched
+# primitive by primitive compiles every layer scan anew.  (The init stays
+# eager: a leaf's draw is cached by its shape across cases and configurations,
+# which one jitted init a configuration is not.)
+_nll = jax.jit(gm.token_nll, static_argnums=2)
+
+
 def _f32(**kw):
     return gm.GdnMoEConfig.tiny(dtype=jnp.float32, remat=False, **kw)
 
@@ -70,7 +78,7 @@ def test_token_nll_matches_the_reference(fused_ce):
     config = _f32(fused_ce=fused_ce)
     params = gm.init_params(config, jax.random.PRNGKey(3))
     tokens = _tokens()
-    got = gm.token_nll(params, {"tokens": jnp.asarray(tokens)}, config)
+    got = _nll(params, {"tokens": jnp.asarray(tokens)}, config)
     want = ref.batch_token_nll(params, tokens, _dims(config))
     # the fused cross-entropy multiplies in bfloat16 whatever the model's
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -88,8 +96,8 @@ def test_gradient_matches_the_reference_layer_by_layer():
     config = _f32()
     params = gm.init_params(config, jax.random.PRNGKey(4))
     tokens = _tokens(rows=1)
-    got = jax.grad(lambda p: gm.loss_fn(p, {"tokens": jnp.asarray(tokens)},
-                                        config))(params)
+    got = jax.jit(jax.grad(lambda p: gm.loss_fn(
+        p, {"tokens": jnp.asarray(tokens)}, config)))(params)
     run = ref.Pass(params, tokens[0, :-1], _dims(config), for_grads=True)
     seen = 0
     for path, grad in run.grads(tokens[0, 1:]):
@@ -318,14 +326,14 @@ def test_the_linear_mixer_through_the_op_equals_the_parents_lines(
 
     monkeypatch.setattr(dispatch, "_taken", {})
     got = gm._linear_mixer(u, lp, config)
-    got_g = jax.grad(loss(gm._linear_mixer), argnums=(0, 1))(u, lp)
+    got_g = jax.jit(jax.grad(loss(gm._linear_mixer), argnums=(0, 1)))(u, lp)
     assert set(dispatch.taken()["mixer_chain"]) == {path}
     # the parent's sum over the group's view, too
     monkeypatch.setattr(gd, "over_group", lambda d, hv, hk: jnp.sum(
         d.reshape(*d.shape[:2], hk, hv // hk, -1), axis=3).reshape(
             *d.shape[:2], -1))
     want = the_parents(u, lp, config)
-    want_g = jax.grad(loss(the_parents), argnums=(0, 1))(u, lp)
+    want_g = jax.jit(jax.grad(loss(the_parents), argnums=(0, 1)))(u, lp)
     assert set(got_g[1]) == set(lp) == set(gm._layer_shapes(gm.LINEAR,
                                                             config))
     for name, g, w in [("the value", got, want), ("u", got_g[0], want_g[0])

@@ -48,13 +48,23 @@ def _dims(c):
 
 def _params(c, seed=0):
     """The program's init, then every leaf moved: with norms at 1, biases
-    near 0 and D at 1 a wrong bias or norm would go unseen."""
+    near 0 and D at 1 a wrong bias or norm would go unseen.  (Eager on
+    purpose: a leaf's draw is cached by its shape across cases, where ONE
+    jitted program of all the draws costs five seconds a configuration.)"""
     params = hy.init_params(c, jax.random.key(seed))
     leaves, treedef = jax.tree.flatten(params)
     keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
     return jax.tree.unflatten(treedef, [
         l + 0.05 * jax.random.normal(k, l.shape)
         for l, k in zip(leaves, keys)])
+
+
+# The program's entry points, each ONE jitted function for the file: cases
+# that call one with an equal configuration share its compile, and a call
+# dispatched primitive by primitive compiles its layer scans anew each time.
+_forward = jax.jit(hy.forward, static_argnums=2)
+_loss = jax.jit(hy.loss_fn, static_argnums=2)
+_nll = jax.jit(hy.token_nll, static_argnums=2)
 
 
 def _tokens(c, rows=2, seq=40, seed=0):
@@ -77,7 +87,7 @@ def test_each_kind_of_layer_matches_the_reference(kind):
     c = _config(_ONE_KIND[kind])
     params, tokens = _params(c, seed=3), _tokens(c, seq=48)
     with jax.default_matmul_precision("highest"):
-        got = hy.forward(params, jnp.asarray(tokens[:, :-1]), c)
+        got = _forward(params, jnp.asarray(tokens[:, :-1]), c)
         want = _ref_logits(params, tokens, c)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
 
@@ -86,9 +96,9 @@ def test_whole_model_logits_and_loss_match_the_reference():
     c = _config()
     params, tokens = _params(c), _tokens(c)
     with jax.default_matmul_precision("highest"):
-        got = hy.forward(params, jnp.asarray(tokens[:, :-1]), c)
+        got = _forward(params, jnp.asarray(tokens[:, :-1]), c)
         want = _ref_logits(params, tokens, c)
-        loss = hy.loss_fn(params, {"tokens": jnp.asarray(tokens)}, c)
+        loss = _loss(params, {"tokens": jnp.asarray(tokens)}, c)
         ref_loss = ref.loss(params, tokens, _dims(c))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
     assert abs(float(loss) - float(ref_loss)) < 2e-4
@@ -106,14 +116,14 @@ def test_token_nll_is_what_the_loss_averages_and_matches_the_reference(
     params, tokens = _params(c, seed=9), _tokens(c, seed=9)
     batch = {"tokens": jnp.asarray(tokens)}
     with jax.default_matmul_precision("highest"):
-        nll = hy.token_nll(params, batch, c)
+        nll = _nll(params, batch, c)
         want = ref.batch_token_nll(params, tokens, _dims(c))
         assert nll.shape == want.shape == (2, 40) and nll.dtype == jnp.float32
         assert float(jnp.mean(nll)) == pytest.approx(
-            float(hy.loss_fn(params, batch, c)), abs=1e-6)
+            float(_loss(params, batch, c)), abs=1e-6)
         mask = jnp.asarray(np.random.default_rng(1).integers(0, 2, (2, 41)),
                            jnp.float32)
-        masked = hy.loss_fn(params, {**batch, "mask": mask}, c)
+        masked = _loss(params, {**batch, "mask": mask}, c)
         assert float(masked) == pytest.approx(
             float(jnp.sum(nll * mask[:, 1:]) / jnp.sum(mask[:, 1:])), abs=1e-6)
         assert float(ref.loss(params, tokens, _dims(c))) == pytest.approx(
@@ -131,9 +141,12 @@ def test_loss_and_gradients_match_the_reference(fused_ce, tol, grad_tol):
     params, tokens = _params(c, seed=5), _tokens(c, seed=5)
     batch = {"tokens": jnp.asarray(tokens)}
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(hy.loss_fn)(params, batch, c)
-        ref_loss, ref_grads = jax.value_and_grad(ref.loss)(
-            params, tokens, _dims(c))
+        # each gradient under ONE jit: dispatched primitive by primitive the
+        # two make 702 compilations
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p: hy.loss_fn(p, batch, c)))(params)
+        ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+            lambda p: ref.loss(p, tokens, _dims(c))))(params)
     assert abs(float(loss) - float(ref_loss)) < tol
     flat, _ = jax.tree_util.tree_flatten_with_path(
         jax.tree.map(lambda g, r: float(jnp.abs(g - r).max()
@@ -171,41 +184,6 @@ def test_recurrence_alone_on_the_references_operands_gives_its_memory():
                           / jnp.mean(want ** 2))) > 2e-3
 
 
-@pytest.mark.parametrize("kinds", [ALL_FIVE, ALL_FIVE + ("gmu", "cross"),
-                                   ("mamba", "window", "mamba")])
-def test_the_references_layer_by_layer_gradient_is_its_jax_grad(
-        kinds, monkeypatch):
-    """`Pass.grads` walks the reference back one layer at a time (so that
-    8192 tokens fit beside a train state); it must give what `jax.grad` of
-    the reference's loss gives, for every parameter, with the memory and
-    the shared KV read by none, one or two layers, and with chunks and
-    blocks shorter than the sequence."""
-    monkeypatch.setattr(ref, "SCAN_CHUNK", 16)
-    monkeypatch.setattr(ref, "QUERY_BLOCK", 16)
-    monkeypatch.setattr(ref, "LOGIT_ROWS", 16)
-    jax.clear_caches()      # the reference's jitted layers read the sizes
-    c = _config(kinds)
-    params, tokens = _params(c, seed=13), _tokens(c, rows=1, seed=13)
-    want = jax.grad(ref.loss)(params, tokens, _dims(c))
-    run = ref.Pass(params, tokens[0, :-1], _dims(c), for_grads=True)
-    seen = 0
-    for path, grad in run.grads(tokens[0, 1:]):
-        if path[0] == "layers":
-            _, seg, pos, rep = path
-            pairs = [(g, want["layers"][seg][pos][name][rep])
-                     for name, g in grad.items()]
-        else:
-            pairs = [(grad, want[path[0]])]
-        for g, w in pairs:
-            seen += 1
-            assert float(jnp.linalg.norm(g - w)) <= 1e-4 * float(
-                jnp.linalg.norm(w)) + 1e-7, path
-    # every parameter of every layer (a stacked leaf holds one a repeat)
-    assert seen == 3 + sum(leaf.shape[0]
-                           for leaf in jax.tree.leaves(want["layers"]))
-    jax.clear_caches()
-
-
 @pytest.mark.parametrize("policy", ["full", "dots"])
 def test_remat_on_and_off_give_the_same_loss_and_gradients(policy):
     """The memory and the shared KV cross per-layer remat as arguments
@@ -214,8 +192,10 @@ def test_remat_on_and_off_give_the_same_loss_and_gradients(policy):
     on = _config(remat=True, remat_policy=policy)
     off = dataclasses.replace(on, remat=False)
     params, batch = _params(on), {"tokens": jnp.asarray(_tokens(on))}
-    l_on, g_on = jax.value_and_grad(hy.loss_fn)(params, batch, on)
-    l_off, g_off = jax.value_and_grad(hy.loss_fn)(params, batch, off)
+    l_on, g_on = jax.jit(jax.value_and_grad(
+        lambda p: hy.loss_fn(p, batch, on)))(params)
+    l_off, g_off = jax.jit(jax.value_and_grad(
+        lambda p: hy.loss_fn(p, batch, off)))(params)
     assert float(l_on) == pytest.approx(float(l_off), abs=1e-6)
     for a, b in zip(jax.tree.leaves(g_on), jax.tree.leaves(g_off)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
@@ -264,7 +244,8 @@ def test_a_reader_before_its_source_is_refused(kinds, why):
 
 def test_logical_axes_match_the_parameters():
     c = _config()
-    params, axes = hy.init_params(c, jax.random.key(0)), hy.logical_axes(c)
+    params = jax.eval_shape(lambda: hy.init_params(c, jax.random.key(0)))
+    axes = hy.logical_axes(c)
     is_axes = lambda v: isinstance(v, tuple)     # noqa: E731
     assert jax.tree.structure(params) == jax.tree.structure(
         axes, is_leaf=is_axes)
@@ -332,7 +313,8 @@ def test_the_benchmarks_driver_reads_the_first_steps_gradient_from_adam(
     state = ts.init(jax.random.key(0))
     tokens = _tokens(c, rows=2, seq=32)
     batch = {"tokens": jnp.asarray(tokens)}
-    want = jax.grad(hy.loss_fn)(state["params"], batch, c)
+    want = jax.jit(jax.grad(lambda p: hy.loss_fn(p, batch, c)))(
+        state["params"])
 
     def as_the_reference_gives_them(grads, halve=None):
         out = {}
@@ -362,3 +344,47 @@ def test_the_benchmarks_driver_reads_the_first_steps_gradient_from_adam(
     assert broken["by_leaf"][worst] == pytest.approx(1.0, rel=0.02)
     assert train_model.worst_parameter(
         broken["by_leaf"], excludes=["A_log"]) != worst
+
+
+# LAST in the file: it clears JAX's caches round itself, and every case behind
+# it would compile its draws and entry points again.  The five kinds once
+# each (the memory and the shared KV have ONE reader), then a second (gmu,
+# cross) pair (two readers, and a scanned pair's second repeat), then no
+# reader at all
+ONE_READER = ("mamba", "window", "full", "gmu", "cross")
+
+
+@pytest.mark.parametrize("kinds", [ONE_READER, ONE_READER + ("gmu", "cross"),
+                                   ("mamba", "window", "mamba")])
+def test_the_references_layer_by_layer_gradient_is_its_jax_grad(
+        kinds, monkeypatch):
+    """`Pass.grads` walks the reference back one layer at a time (so that
+    8192 tokens fit beside a train state); it must give what `jax.grad` of
+    the reference's loss gives, for every parameter, with the memory and
+    the shared KV read by none, one or two layers, and with chunks and
+    blocks shorter than the sequence."""
+    monkeypatch.setattr(ref, "SCAN_CHUNK", 16)
+    monkeypatch.setattr(ref, "QUERY_BLOCK", 16)
+    monkeypatch.setattr(ref, "LOGIT_ROWS", 16)
+    jax.clear_caches()      # the reference's jitted layers read the sizes
+    c = _config(kinds)
+    params, tokens = _params(c, seed=13), _tokens(c, rows=1, seed=13)
+    want = jax.jit(jax.grad(
+        lambda p: ref.loss(p, tokens, _dims(c))))(params)
+    run = ref.Pass(params, tokens[0, :-1], _dims(c), for_grads=True)
+    seen = 0
+    for path, grad in run.grads(tokens[0, 1:]):
+        if path[0] == "layers":
+            _, seg, pos, rep = path
+            pairs = [(g, want["layers"][seg][pos][name][rep])
+                     for name, g in grad.items()]
+        else:
+            pairs = [(grad, want[path[0]])]
+        for g, w in pairs:
+            seen += 1
+            assert float(jnp.linalg.norm(g - w)) <= 1e-4 * float(
+                jnp.linalg.norm(w)) + 1e-7, path
+    # every parameter of every layer (a stacked leaf holds one a repeat)
+    assert seen == 3 + sum(leaf.shape[0]
+                           for leaf in jax.tree.leaves(want["layers"]))
+    jax.clear_caches()

@@ -21,6 +21,14 @@ def _interpret_mode(monkeypatch):
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
 
 
+# The program's per-token loss as ONE jitted function for the file: cases
+# with an equal configuration share its compile, where a call dispatched
+# primitive by primitive compiles every layer scan anew.  (The init stays
+# eager: a leaf's draw is cached by its shape across cases and configurations,
+# which one jitted init a configuration is not.)
+_nll = jax.jit(sm.token_nll, static_argnums=2)
+
+
 def _f32(**kw):
     return sm.SwaMoEConfig.tiny(dtype=jnp.float32, remat=False, **kw)
 
@@ -68,7 +76,7 @@ def test_token_nll_matches_the_reference(fused_ce):
     config = _f32(fused_ce=fused_ce)
     params = sm.init_params(config, jax.random.PRNGKey(3))
     tokens = _tokens()
-    got = sm.token_nll(params, {"tokens": jnp.asarray(tokens)}, config)
+    got = _nll(params, {"tokens": jnp.asarray(tokens)}, config)
     want = ref.batch_token_nll(params, tokens, _dims(config))
     # the fused cross-entropy multiplies in bfloat16 whatever the model's
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -84,8 +92,8 @@ def test_gradient_matches_the_reference_layer_by_layer():
     config = _f32()
     params = sm.init_params(config, jax.random.PRNGKey(4))
     tokens = _tokens()
-    got = jax.grad(lambda p: sm.loss_fn(p, {"tokens": jnp.asarray(tokens)},
-                                        config))(params)
+    got = jax.jit(jax.grad(lambda p: sm.loss_fn(
+        p, {"tokens": jnp.asarray(tokens)}, config)))(params)
     want = {}
     for row in tokens:
         run = ref.Pass(params, row[:-1], _dims(config), for_grads=True)

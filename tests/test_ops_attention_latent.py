@@ -11,13 +11,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import attention as attn
-from test_ops_attention import (_grads_and_value, _new_plans, _pallas_calls,
-                                _rope_tables)
-
-
-@pytest.fixture(autouse=True)
-def _interpret_mode(monkeypatch):
-    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+from attention_cases import (  # noqa: F401 (the fixture is autouse)
+    _grads_and_value, _interpret_mode, _new_plans, _pallas_calls,
+    _rope_tables)
 
 
 # ---------------------------------------------------------------------------
@@ -126,9 +122,10 @@ def test_latent_parts_bfloat16_is_rope_in_xla_before_the_whole_kernels():
                       for fn in (parts, whole))
     assert (out != out_whole).mean() < 1e-3
     np.testing.assert_allclose(out, out_whole, rtol=2.0 ** -7, atol=0)
-    got = jax.grad(loss(parts), argnums=(0, 1, 2))(q, kv, k_pe)
-    want = jax.grad(loss(whole), argnums=(0, 1, 2))(q, kv, k_pe)
-    exact = jax.grad(loss(_latent_assembled(rope, nope)), argnums=(0, 1, 2))(
+    got = jax.jit(jax.grad(loss(parts), argnums=(0, 1, 2)))(q, kv, k_pe)
+    want = jax.jit(jax.grad(loss(whole), argnums=(0, 1, 2)))(q, kv, k_pe)
+    exact = jax.jit(jax.grad(loss(_latent_assembled(rope, nope)),
+                             argnums=(0, 1, 2)))(
         *(x.astype(jnp.float32) for x in (q, kv, k_pe)))
     for g, x, f, arg in zip(got, want, exact, (q, kv, k_pe)):
         assert g.shape == arg.shape and g.dtype == jnp.bfloat16
@@ -162,8 +159,8 @@ def test_latent_parts_gradient_through_the_lse_output():
         return loss(*attn._chunk(*_latent_whole(q, kv, k_pe, rope, nope),
                                  0, 0, True, scale, blocks))
 
-    got = jax.grad(parts, argnums=(0, 1, 2))(q, kv, k_pe)
-    want = jax.grad(whole, argnums=(0, 1, 2))(q, kv, k_pe)
+    got = jax.jit(jax.grad(parts, argnums=(0, 1, 2)))(q, kv, k_pe)
+    want = jax.jit(jax.grad(whole, argnums=(0, 1, 2)))(q, kv, k_pe)
     for g, x in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(x),
                                    atol=5e-4, rtol=5e-4)

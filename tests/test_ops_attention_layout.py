@@ -12,12 +12,8 @@ import jax.numpy as jnp
 
 from ray_tpu.ops import attention as attn
 from ray_tpu.ops import dispatch
-from test_ops_attention import _pallas_calls
-
-
-@pytest.fixture(autouse=True)
-def _interpret_mode(monkeypatch):
-    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+from attention_cases import (  # noqa: F401 (the fixture is autouse)
+    _interpret_mode, _pallas_calls)
 
 
 def _operands(b, sq, sk, h, d, e, seed=0, dtype=jnp.float32):
@@ -48,9 +44,9 @@ def _reference(q, k, v, rope, window):
 
 
 def _value_and_grads(fn, q, k, v, w):
-    return jax.value_and_grad(
+    return jax.jit(jax.value_and_grad(
         lambda q, k, v: (fn(q, k, v).astype(jnp.float32) * w).sum(),
-        argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
 
 
 # (name, b, sq, sk, heads, d, e, rope, window, the plan's word)
@@ -120,8 +116,8 @@ def test_chunk_with_a_gradient_on_lse_matches_the_reference(d, delta):
         lse = jnp.where(rows[None, None], lse, 0.0).reshape(b * h, s)
         return (out * w).sum() + (lse * wl).sum()
 
-    got = jax.value_and_grad(flash, argnums=(0, 1, 2))(q, k, v)
-    want = jax.value_and_grad(reference, argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.value_and_grad(flash, argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.value_and_grad(reference, argnums=(0, 1, 2)))(q, k, v)
     np.testing.assert_allclose(got[0], want[0], rtol=2e-5)
     for g, r in zip(got[1], want[1]):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r),

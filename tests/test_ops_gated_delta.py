@@ -59,8 +59,8 @@ def test_forward_and_gradients_match_the_recurrence(path, t, weak,
     assert got.shape == want.shape and got.dtype == args[2].dtype
     assert _rel(got, want) < 3e-5
     w = jax.random.normal(jax.random.key(9), want.shape)
-    grads = [jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
-                      argnums=(0, 1, 2, 3, 4))(*args)
+    grads = [jax.jit(jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
+                              argnums=(0, 1, 2, 3, 4)))(*args)
              for f in (rule, gd.gated_delta_reference)]
     for name, mine, ref in zip("q k v g beta".split(), *grads):
         assert mine.shape == ref.shape, name
@@ -79,8 +79,8 @@ def test_the_cell_s_chunk_and_widths_match_the_recurrence(monkeypatch):
     got = gd.gated_delta_rule(*args)
     assert _rel(got, want) < 3e-5
     w = jax.random.normal(jax.random.key(9), want.shape)
-    grads = [jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
-                      argnums=(0, 1, 2, 3, 4))(*args)
+    grads = [jax.jit(jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
+                              argnums=(0, 1, 2, 3, 4)))(*args)
              for f in (gd.gated_delta_rule, gd.gated_delta_reference)]
     for name, mine, ref in zip("q k v g beta".split(), *grads):
         assert _rel(mine, ref) < 5e-5, name

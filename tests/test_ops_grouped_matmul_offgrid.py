@@ -61,10 +61,11 @@ def test_forward_dx_and_dw_match_the_xla_formulation(split, k, n,
     def loss(fn):
         return lambda x, w: jnp.sum(jnp.where(used, fn(x, w), 0.0) * cot)
 
-    dx, dw = jax.grad(loss(lambda x, w: gm.grouped_matmul(x, w, layout)),
-                      (0, 1))(x, w)
-    rx, rw = jax.grad(loss(lambda x, w: gm._xla_grouped(x, w, layout, False)),
-                      (0, 1))(x, w)
+    dx, dw = jax.jit(jax.grad(
+        loss(lambda x, w: gm.grouped_matmul(x, w, layout)), (0, 1)))(x, w)
+    rx, rw = jax.jit(jax.grad(
+        loss(lambda x, w: gm._xla_grouped(x, w, layout, False)), (0, 1)))(
+            x, w)
     np.testing.assert_allclose(np.where(used, dx, 0), np.where(used, rx, 0),
                                atol=2e-5)
     np.testing.assert_allclose(dw, rw, atol=2e-4)
@@ -180,7 +181,9 @@ def test_ungated_routed_experts_match_a_loop_over_experts(first, count,
         if w_gate is not None:
             continue
         which = (0, 1, 3, 4)
-        got = jax.grad(lambda *a: jnp.sum(layer(*a) ** 2), which)(*args)
-        want = jax.grad(lambda *a: jnp.sum(loop(*a) ** 2), which)(*args)
+        got = jax.jit(jax.grad(lambda *a: jnp.sum(layer(*a) ** 2), which))(
+            *args)
+        want = jax.jit(jax.grad(lambda *a: jnp.sum(loop(*a) ** 2), which))(
+            *args)
         for g, r in zip(got, want):
             np.testing.assert_allclose(g, r, atol=2e-3, rtol=2e-4)

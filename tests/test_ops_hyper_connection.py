@@ -166,9 +166,10 @@ def test_the_clamp_binds(path):
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(got, want, atol=1e-5)
     assert float(jnp.abs(want - free).max()) > 1e-3
-    g = jax.grad(lambda s: jnp.sum(H.hc_pre(x, w, s, base, HC)[1] ** 2))(scale)
-    r = jax.grad(lambda s: jnp.sum(
-        H.hc_pre_reference(x, w, s, base, HC)[1] ** 2))(scale)
+    g = jax.jit(jax.grad(lambda s: jnp.sum(
+        H.hc_pre(x, w, s, base, HC)[1] ** 2)))(scale)
+    r = jax.jit(jax.grad(lambda s: jnp.sum(
+        H.hc_pre_reference(x, w, s, base, HC)[1] ** 2)))(scale)
     np.testing.assert_allclose(g, r, rtol=2e-3, atol=1e-6)
 
 
@@ -197,8 +198,8 @@ def test_collapse_is_its_reference(path):
     g = jax.random.normal(kx, (1, 256, 128))
 
     def loss(fn):
-        return jax.value_and_grad(lambda x, w, s, b: jnp.sum(
-            fn(x, w, s, b, HC).astype(F32) * g), argnums=(0, 1, 2, 3))
+        return jax.jit(jax.value_and_grad(lambda x, w, s, b: jnp.sum(
+            fn(x, w, s, b, HC).astype(F32) * g), argnums=(0, 1, 2, 3)))
 
     got, g_got = loss(H.hc_collapse)(x, w_head, scale_h, base_h)
     want, g_want = loss(H.hc_collapse_reference)(x, w_head, scale_h, base_h)

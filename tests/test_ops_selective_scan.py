@@ -3,6 +3,8 @@ scan written out here: forward and all six gradients, a sequence that is
 no multiple of the chunk, channels that are no multiple of the channel
 block, and state carried over chunk and channel-block edges."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -64,19 +66,27 @@ def test_forward_matches_the_sequential_scan(b, t, c, n, chunk):
         np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
+@functools.lru_cache(maxsize=None)
+def _all_six_gradients(b, t, c, n, chunk):
+    """(the scan's, the sequential scan's) gradients by all six operands at
+    one shape, each side ONE jitted program: the six cases of a shape differ
+    only in which of the six they read."""
+    args = _inputs(b, t, c, n, seed=7 + t)
+    w = jax.random.normal(jax.random.key(3), args[0].shape)
+
+    def grads(fn):
+        return jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a) * w),
+                                argnums=tuple(range(6))))(*args)
+
+    return (grads(lambda *a: ss.selective_scan(*a, chunk=chunk)),
+            grads(_sequential))
+
+
 @pytest.mark.parametrize("b,t,c,n,chunk", _SHAPES[:3])
 @pytest.mark.parametrize("wrt", range(6), ids=["dx", "ddt", "dA", "dB",
                                                "dC", "dD"])
 def test_each_gradient_matches_the_sequential_scan(wrt, b, t, c, n, chunk):
-    args = _inputs(b, t, c, n, seed=7 + t)
-    w = jax.random.normal(jax.random.key(3), args[0].shape)
-
-    def loss(fn):
-        return lambda *a: jnp.sum(fn(*a) * w)
-
-    got = jax.grad(loss(lambda *a: ss.selective_scan(*a, chunk=chunk)),
-                   argnums=wrt)(*args)
-    want = jax.grad(loss(_sequential), argnums=wrt)(*args)
+    got, want = (g[wrt] for g in _all_six_gradients(b, t, c, n, chunk))
     scale = float(jnp.abs(want).max())
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5 * max(scale, 1.0), rtol=1e-4)

@@ -68,8 +68,9 @@ def test_forward_and_gradients_match_the_recurrence(path, t, heads, groups, P,
 
     every = tuple(range(6))
     for name, g, r in zip(("x", "dt", "a", "B", "C", "D"),
-                          jax.grad(loss(scan), every)(*args),
-                          jax.grad(loss(ss.ssd_scan_reference), every)(*args)):
+                          jax.jit(jax.grad(loss(scan), every))(*args),
+                          jax.jit(jax.grad(loss(ss.ssd_scan_reference),
+                                           every))(*args)):
         assert g.shape == r.shape and _rel(g, r) < 2e-4, name
 
 
@@ -121,7 +122,8 @@ def test_decays_that_underflow_give_zero_and_not_nan(path, monkeypatch):
     got = scan(*args)
     assert bool(jnp.all(jnp.isfinite(got)))
     assert _rel(got, ss.ssd_scan_reference(*args)) < 2e-5
-    grads = jax.grad(lambda *a: jnp.sum(scan(*a) ** 2), (0, 1, 2, 3, 4))(*args)
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(scan(*a) ** 2),
+                             (0, 1, 2, 3, 4)))(*args)
     assert all(bool(jnp.all(jnp.isfinite(g))) for g in grads)
 
 

@@ -4,91 +4,27 @@ its bytes, its kernels' plans, and what the cell's readers find it by.
 
 No chip is attached: the TPU compiler installed here compiles for a
 topology that is described (v5e:2x2).  A compile that passes is not a chip
-run.  The topology is described inside a fixture, as in
-tests/test_tpu_aot_compile.py, whose wall time this file stays out of: only
-the xdist worker that is handed this file loads libtpu here, everything
-compiles in the test's own process, with the persistent compile cache off.
+run.  tests/aot.py says how, and holds what the files of this name share.
 """
 
-import copy
-import json
-import os
 import re
 
-import jax
-import jax.numpy as jnp
 import pytest
 
-from ray_tpu.ops import attention
-from test_tpu_aot_compile import _metadata_stripped
+from aot import (_chip_bytes, _custom_calls_of, _kernel_op_names,
+                 _scope_pattern, hlo_is_as_recorded)
 
-CONFIG = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                      "benchmark", "configs", "zaya1-8b-train-d4.json")
+CONFIG = "zaya1-8b-train-d4.json"
 
-
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        desc = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no TPU compiler here
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+# `_step_fn`'s static arguments: the ladder's FIRST rung, each layer's flash
+# out and lse kept, which is the one the chip takes
+STEP_STATIC = {"keep": True}
 
 
-@pytest.fixture(scope="module")
-def step_program(topo):
-    """(the cell's whole step program as `ShardedTrainStep` jits it on the
-    ladder's FIRST rung, each layer's flash out and lse kept, which is the
-    one the chip takes; what its trace left in `dispatch.taken()`; the
-    configuration's train group).  One compile, about a minute."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from benchmark.drivers import train_model
-    from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
-
-    with open(CONFIG) as f:
-        doc = json.load(f)
-    tr = doc["train"]
-    config = train_model.build_config(doc["program"], doc["model"], tr)
-    mesh = Mesh(topo.devices[:1], ("fsdp",))
-    whole = NamedSharding(mesh, P())
-    ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
-        warmup_steps=tr["lr_warmup_steps"], total_steps=tr["lr_total_steps"],
-        mu_dtype=jnp.bfloat16, nu_dtype=jnp.bfloat16))
-    key = jax.eval_shape(lambda: jax.random.key(0))
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(attention.dispatch, "platform", lambda: "tpu")
-        mp.setattr(attention.dispatch, "interpret_mode", lambda: False)
-        mp.setattr(attention.dispatch, "_taken", {})
-        with jax.sharding.set_mesh(mesh):
-            state = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                               sharding=whole),
-                jax.eval_shape(ts._init_fn, key))
-            batch = {"tokens": jax.ShapeDtypeStruct(
-                (tr["batch_rows"], tr["sequence_length"] + 1), jnp.int32,
-                sharding=whole)}
-            compiled = jax.jit(
-                ts._step_fn, donate_argnums=(0,), static_argnames=("keep",)
-            ).lower(state, batch, keep=True).compile()
-        taken = copy.deepcopy(attention.dispatch.taken())
-    return compiled, taken, tr
-
-
-# sha256 of the step program's optimised HLO, `_metadata_stripped`, as PR
-# 46's tree (40fa1e4) compiled it: tests/test_tpu_aot_compile.py's
-# `PARENT_HLO_SHA256` has the rule (a change that means to move the program
-# replaces the digest and says so) and the other cells'.  PR 50 MEANT TO:
+# sha256 of the step program's optimised HLO, `aot._metadata_stripped`, as PR
+# 46's tree (40fa1e4) compiled it: `aot.hlo_is_as_recorded` has the rule (a
+# change that means to move the program replaces the digest and says so).
+# PR 50 MEANT TO:
 # the grouped kernels' forward / transposed grid walks a column block's row
 # tiles before the next column block and the 2048 x 2048 matrix is ONE block
 # (PR 46's tree read 2d09fc2a..).
@@ -98,32 +34,7 @@ PARENT_HLO_SHA256 = (
 
 def test_cell_cca_moe_optimised_hlo_is_as_the_parent_compiled_it(
         step_program):
-    import hashlib
-
-    text = _metadata_stripped(step_program[0].as_text())
-    assert "op_name" not in text and "source_file" not in text \
-        and ".py" not in text
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_HLO_SHA256
-
-
-def _calls_as_traced(compiled):
-    """The compiled module's Mosaic custom-call lines, printed the way the
-    profiler names an operation in a trace: result and operand shapes, no
-    layouts."""
-    from jax._src.lib import _jax
-
-    opts = _jax.HloPrintOptions.short_parsable()
-    opts.print_operand_shape = True
-    opts.include_layout_in_shapes = False
-    opts.print_backend_config = False
-    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
-    return [l for l in text.splitlines() if "tpu_custom_call" in l]
-
-
-def _kernel_op_names(compiled):
-    return [re.search(r'op_name="([^"]*)"', l).group(1)
-            for l in compiled.as_text().splitlines()
-            if 'custom_call_target="tpu_custom_call"' in l]
+    hlo_is_as_recorded(step_program[0].as_text(), PARENT_HLO_SHA256)
 
 
 def test_cell_cca_moe_step_program_fits_a_v5e(step_program):
@@ -132,11 +43,9 @@ def test_cell_cca_moe_step_program_fits_a_v5e(step_program):
     the tied vocabulary, 1 x 8192 tokens, out and lse kept across remat,
     fused CE, bfloat16 moments) by AOT memory_analysis: under 15.75 GiB at
     the configuration's rows, and over 13 (the state alone is 11.7)."""
-    from ray_tpu.util.device_stats import program_bytes
-
-    compiled, taken, tr = step_program
+    compiled, _, tr, _ = step_program
     assert tr["batch_rows"] == 1 and tr["sequence_length"] == 8192
-    total = program_bytes(compiled.memory_analysis())
+    total = _chip_bytes(compiled)
     assert 13.0 * 2 ** 30 < total < 15.0 * 2 ** 30, total / 2 ** 30
     # one segment: the flash forward (kept: no second one) and backward,
     # the grouped kernels' three forward, three again under remat, three
@@ -175,7 +84,7 @@ def test_cell_cca_moe_grouped_kernels_are_found_by_their_names(step_program):
     name in `op_name`, and each finds its calls and no other's."""
     from benchmark import cca_faces, moe_faces
 
-    compiled, _, _ = step_program
+    compiled = step_program[0]
     names = _kernel_op_names(compiled)
     assert len(names) == 14
     found = {k: [n for n in names if re.search(getattr(cca_faces, k), n)]
@@ -190,7 +99,7 @@ def test_cell_cca_moe_grouped_kernels_are_found_by_their_names(step_program):
     assert sorted(n.rsplit("/", 2)[1] for n in flash) == ["flash_bwd",
                                                           "flash_fwd"]
     # what shapes alone see: forward and transposed are one face here
-    calls = _calls_as_traced(compiled)
+    calls = _custom_calls_of(compiled)
     assert sum(bool(re.search(moe_faces.GROUPED_FORWARD, l))
                for l in calls) == 9
     assert not any(re.search(moe_faces.GROUPED_TRANSPOSED, l) for l in calls)
@@ -203,8 +112,8 @@ def test_cell_cca_moe_flash_forward_keeps_the_face_its_reader_finds(
     of 128 over 8192 positions."""
     from benchmark import swa_moe_faces
 
-    compiled, _, _ = step_program
-    calls = _calls_as_traced(compiled)
+    compiled = step_program[0]
+    calls = _custom_calls_of(compiled)
     forward = [l for l in calls
                if re.search(swa_moe_faces.FORWARD_FULL, l)]
     assert len(forward) == 1
@@ -223,7 +132,7 @@ def test_cell_cca_moe_scopes_are_where_the_readers_look(step_program):
     and every matmul keeps a scope of the vocabulary."""
     from ray_tpu.models import common
 
-    compiled, _, _ = step_program
+    compiled = step_program[0]
     names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
     mix = [n for n in names if common.ATTN_MIX in n]
     outside = [n for n in mix
@@ -234,6 +143,5 @@ def test_cell_cca_moe_scopes_are_where_the_readers_look(step_program):
     route = [n for n in names if f"/{common.MOE_ROUTE}/" in n]
     assert any("dot_general" in n for n in route)
     assert any("erf" in n for n in route)       # the exact gelu
-    scope = re.compile(r"(?<![\w.])(" + "|".join(
-        re.escape(s) for s in common.SCOPES) + r")(?![\w.])")
+    scope = _scope_pattern()
     assert all(scope.search(n) for n in _kernel_op_names(compiled))

@@ -6,106 +6,35 @@ its kernels' plans, and what the cell's readers find it by.
 
 No chip is attached: the TPU compiler installed here compiles for a
 topology that is described (v5e:2x2).  A compile that passes is not a chip
-run.  The topology is described inside a fixture, as in
-tests/test_tpu_aot_compile.py, whose wall time this file stays out of.  Its
-assertions are spelt out as two dozen cases round ONE compile of about a
-minute, so that `--dist loadfile` (files by their number of tests, largest
-first) starts the file early (PERF.md section 7).
+run.  tests/aot.py says how, and holds what the files of this name share.
+Its assertions are spelt out as two dozen cases round ONE compile, so that
+`--dist loadfile` (files by their number of tests, largest first) starts the
+file early (PERF.md section 7).
 """
 
-import copy
-import json
-import os
 import re
 
-import jax
-import jax.numpy as jnp
 import pytest
 
-from ray_tpu.ops import attention
-from test_tpu_aot_compile import _metadata_stripped
+from aot import (_chip_bytes, _kernel_op_names, _scope_pattern, config_doc,
+                 hlo_is_as_recorded)
 
-CONFIG = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                      "benchmark", "configs",
-                      "xing4.0-29b-a4b-train-d5e8.json")
+CONFIG = "xing4.0-29b-a4b-train-d5e8.json"
 
-
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        desc = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no TPU compiler here
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+# `_step_fn`'s static arguments: the ladder's LOWEST rung, nothing kept across
+# a layer's checkpoint (the first rung's program reads 16.44 GiB, over)
+STEP_STATIC = {"keep": False}
 
 
-@pytest.fixture(scope="module")
-def step_program(topo):
-    """(the cell's whole step program as `ShardedTrainStep` jits it on the
-    ladder's LOWEST rung, nothing kept across a layer's checkpoint: the
-    first rung's program reads 16.44 GiB, over; what its trace left in
-    `dispatch.taken()`; the configuration's train group; the number of
-    parameters).  One compile."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from benchmark.drivers import train_model
-    from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
-
-    with open(CONFIG) as f:
-        doc = json.load(f)
-    tr = doc["train"]
-    config = train_model.build_config(doc["program"], doc["model"], tr)
-    mesh = Mesh(topo.devices[:1], ("fsdp",))
-    whole = NamedSharding(mesh, P())
-    ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
-        warmup_steps=tr["lr_warmup_steps"], total_steps=tr["lr_total_steps"],
-        mu_dtype=jnp.bfloat16, nu_dtype=jnp.bfloat16))
-    key = jax.eval_shape(lambda: jax.random.key(0))
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(attention.dispatch, "platform", lambda: "tpu")
-        mp.setattr(attention.dispatch, "interpret_mode", lambda: False)
-        mp.setattr(attention.dispatch, "_taken", {})
-        with jax.sharding.set_mesh(mesh):
-            state = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                               sharding=whole),
-                jax.eval_shape(ts._init_fn, key))
-            batch = {"tokens": jax.ShapeDtypeStruct(
-                (tr["batch_rows"], tr["sequence_length"] + 1), jnp.int32,
-                sharding=whole)}
-            compiled = jax.jit(
-                ts._step_fn, donate_argnums=(0,), static_argnames=("keep",)
-            ).lower(state, batch, keep=False).compile()
-        taken = copy.deepcopy(attention.dispatch.taken())
-    count = sum(a.size for a in jax.tree.leaves(state["params"]))
-    return compiled, taken, tr, count
-
-
-# sha256 of the step program's optimised HLO, `_metadata_stripped`, as THIS
-# PR's tree compiled it: tests/test_tpu_aot_compile.py's `PARENT_HLO_SHA256`
-# has the rule (a change that means to move the program replaces the digest
-# and says so).
+# sha256 of the step program's optimised HLO, `aot._metadata_stripped`, as PR
+# 52's tree compiled it: `aot.hlo_is_as_recorded` has the rule (a change that
+# means to move the program replaces the digest and says so).
 PARENT_HLO_SHA256 = (
     "2955a191fd5b3381f5cf7c59d09625d964ff12b4c45191955f287a6bae6c9eaf")
 
 
 def test_cell_mhc_optimised_hlo_is_as_this_pr_compiled_it(step_program):
-    import hashlib
-
-    text = _metadata_stripped(step_program[0].as_text())
-    assert "op_name" not in text and "source_file" not in text \
-        and ".py" not in text
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_HLO_SHA256
+    hlo_is_as_recorded(step_program[0].as_text(), PARENT_HLO_SHA256)
 
 
 def test_cell_mhc_holds_the_configurations_parameters(step_program):
@@ -113,8 +42,7 @@ def test_cell_mhc_holds_the_configurations_parameters(step_program):
     give."""
     from benchmark import arith_hc_moe
 
-    with open(CONFIG) as f:
-        model = json.load(f)["model"]
+    model = config_doc(CONFIG)["model"]
     assert step_program[3] == arith_hc_moe.param_count(model) == 759_403_795
 
 
@@ -124,11 +52,9 @@ def test_cell_mhc_step_program_fits_a_v5e(step_program):
     lanes, 1 x 8192 tokens, fused CE, bfloat16 moments) by AOT
     memory_analysis: under 15.75 GiB with 0.45 of room for what stands
     beside it on the chip (0.3), and over 13 (the state is 9.9 at 14 B)."""
-    from ray_tpu.util.device_stats import program_bytes
-
     compiled, _, tr, _ = step_program
     assert tr["batch_rows"] == 1 and tr["sequence_length"] == 8192
-    total = program_bytes(compiled.memory_analysis())
+    total = _chip_bytes(compiled)
     assert 13.0 * 2 ** 30 < total < 15.3 * 2 ** 30, total / 2 ** 30
 
 
@@ -157,12 +83,6 @@ def test_cell_mhc_kernels_plans(step_program, key):
 def test_cell_mhc_takes_every_kernel(step_program, op):
     """Each op of the cell went down its Pallas path (`must_take_pallas`)."""
     assert set(step_program[1][op]) == {"pallas"}
-
-
-def _kernel_op_names(compiled):
-    return [re.search(r'op_name="([^"]*)"', l).group(1)
-            for l in compiled.as_text().splitlines()
-            if 'custom_call_target="tpu_custom_call"' in l]
 
 
 def _found(compiled):
@@ -254,8 +174,6 @@ def test_cell_mhc_every_kernel_and_matmul_keeps_a_scope(step_program):
 
     compiled = step_program[0]
     names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
-    scope = re.compile(r"(?<![\w.])(" + "|".join(
-        re.escape(s) for s in (*common.SCOPES, common.RESID_MIX))
-        + r")(?![\w.])")
+    scope = _scope_pattern(common.RESID_MIX)
     assert all(scope.search(n) for n in _kernel_op_names(compiled))
     assert all(scope.search(n) for n in names if "dot_general" in n)

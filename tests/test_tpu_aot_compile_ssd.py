@@ -5,93 +5,27 @@ kernels' plans, and what the cell's readers find it by.
 
 No chip is attached: the TPU compiler installed here compiles for a
 topology that is described (v5e:2x2).  A compile that passes is not a chip
-run.  The topology is described inside a fixture, as in
-tests/test_tpu_aot_compile.py, whose wall time this file stays out of: only
-the xdist worker that is handed this file loads libtpu here, everything
-compiles in the test's own process, with the persistent compile cache off.
+run.  tests/aot.py says how, and holds what the files of this name share.
 """
 
-import copy
-import json
-import os
 import re
 
-import jax
-import jax.numpy as jnp
 import pytest
 
-from ray_tpu.ops import attention
-from test_tpu_aot_compile import _metadata_stripped
+from aot import (_chip_bytes, _kernel_op_names, _scope_pattern,
+                 hlo_is_as_recorded)
 
-CONFIG = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                      "benchmark", "configs",
-                      "nemotron-3-nano-30b-a3b-train-d9e8.json")
+CONFIG = "nemotron-3-nano-30b-a3b-train-d9e8.json"
 
-
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        desc = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no TPU compiler here
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+# `_step_fn`'s static arguments: the ladder's FIRST rung, which is the one the
+# chip takes (the flash out and lse and the recurrence's y and first states
+# kept)
+STEP_STATIC = {"keep": True}
 
 
-@pytest.fixture(scope="module")
-def step_program(topo):
-    """(the cell's whole step program as `ShardedTrainStep` jits it on the
-    ladder's FIRST rung, which is the one the chip takes: the flash out and
-    lse and the recurrence's y and first states kept; what its trace left in
-    `dispatch.taken()`; the configuration's train group).  One compile,
-    about a minute and a half."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from benchmark.drivers import train_model
-    from ray_tpu.train.train_state import ShardedTrainStep, default_optimizer
-
-    with open(CONFIG) as f:
-        doc = json.load(f)
-    tr = doc["train"]
-    config = train_model.build_config(doc["program"], doc["model"], tr)
-    mesh = Mesh(topo.devices[:1], ("fsdp",))
-    whole = NamedSharding(mesh, P())
-    ts = ShardedTrainStep(config, mesh, optimizer=default_optimizer(
-        warmup_steps=tr["lr_warmup_steps"], total_steps=tr["lr_total_steps"],
-        mu_dtype=jnp.bfloat16, nu_dtype=jnp.bfloat16))
-    key = jax.eval_shape(lambda: jax.random.key(0))
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(attention.dispatch, "platform", lambda: "tpu")
-        mp.setattr(attention.dispatch, "interpret_mode", lambda: False)
-        mp.setattr(attention.dispatch, "_taken", {})
-        with jax.sharding.set_mesh(mesh):
-            state = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                               sharding=whole),
-                jax.eval_shape(ts._init_fn, key))
-            batch = {"tokens": jax.ShapeDtypeStruct(
-                (tr["batch_rows"], tr["sequence_length"] + 1), jnp.int32,
-                sharding=whole)}
-            compiled = jax.jit(
-                ts._step_fn, donate_argnums=(0,), static_argnames=("keep",)
-            ).lower(state, batch, keep=True).compile()
-        taken = copy.deepcopy(attention.dispatch.taken())
-    return compiled, taken, tr
-
-
-# sha256 of the step program's optimised HLO, `_metadata_stripped`, as PR
-# 49's tree compiled it: tests/test_tpu_aot_compile.py's `PARENT_HLO_SHA256`
-# has the rule (a change that means to move the program replaces the digest
-# and says so) and the other cells'.  PR 49 MEANT TO: the chain round the
+# sha256 of the step program's optimised HLO, `aot._metadata_stripped`:
+# `aot.hlo_is_as_recorded` has the rule (a change that means to move the
+# program replaces the digest and says so).  PR 49 MEANT TO: the chain round the
 # recurrence is ops/mixer_chain.py's four kernels (PR 48's tree read
 # 7dffeb93..).  PR 50 MEANT TO: the grouped kernels' forward / transposed
 # grid walks a column block's row tiles before the next column block and
@@ -101,18 +35,7 @@ PARENT_HLO_SHA256 = (
 
 
 def test_cell_ssd_moe_optimised_hlo_is_as_this_pr_compiled_it(step_program):
-    import hashlib
-
-    text = _metadata_stripped(step_program[0].as_text())
-    assert "op_name" not in text and "source_file" not in text \
-        and ".py" not in text
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_HLO_SHA256
-
-
-def _kernel_op_names(compiled):
-    return [re.search(r'op_name="([^"]*)"', l).group(1)
-            for l in compiled.as_text().splitlines()
-            if 'custom_call_target="tpu_custom_call"' in l]
+    hlo_is_as_recorded(step_program[0].as_text(), PARENT_HLO_SHA256)
 
 
 def test_cell_ssd_moe_step_program_fits_a_v5e(step_program):
@@ -121,11 +44,9 @@ def test_cell_ssd_moe_step_program_fits_a_v5e(step_program):
     vocabulary, 3 x 8192 tokens, fused CE, bfloat16 moments) by AOT
     memory_analysis: under 15.75 GiB at the configuration's rows with room
     for what stands beside it, and over 11 (the state is 8.7)."""
-    from ray_tpu.util.device_stats import program_bytes
-
-    compiled, taken, tr = step_program
+    compiled, taken, tr, _ = step_program
     assert tr["batch_rows"] == 3 and tr["sequence_length"] == 8192
-    total = program_bytes(compiled.memory_analysis())
+    total = _chip_bytes(compiled)
     assert 11.0 * 2 ** 30 < total < 14.5 * 2 ** 30, total / 2 ** 30
     plan, = taken["flash_attention.plan"]
     assert plan.endswith(",operands_bshd,heads1x128") \
@@ -229,7 +150,7 @@ def test_cell_ssd_moe_scopes_are_where_the_readers_look(step_program):
     and every matmul keeps a scope of the vocabulary."""
     from ray_tpu.models import common
 
-    compiled, _, _ = step_program
+    compiled = step_program[0]
     names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
     chain = [n for n in names if common.SSM_CHAIN in n]
     outside = [n for n in chain
@@ -241,7 +162,6 @@ def test_cell_ssd_moe_scopes_are_where_the_readers_look(step_program):
     assert {n.rsplit("/", 2)[1] for n in _kernel_op_names(compiled)
             if common.SSM_CHAIN in n} == {
         k for k, v in KERNELS.items() if "ssm.chain" in v[2]}
-    scope = re.compile(r"(?<![\w.])(" + "|".join(
-        re.escape(s) for s in common.SCOPES) + r")(?![\w.])")
+    scope = _scope_pattern()
     assert all(scope.search(n) for n in _kernel_op_names(compiled))
     assert all(scope.search(n) for n in names if "dot_general" in n)
