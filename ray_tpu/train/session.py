@@ -7,6 +7,11 @@ loop runs in a daemon thread inside the train-worker actor; `report()` hands
 (metrics, checkpoint) to the actor's result queue with maxsize-1
 backpressure, exactly the reference's result-queue flow (trainer.py:31
 TrainingIterator pulls).
+
+The process's step ledger lives here too (`step_ledger`): one row a call
+of `ShardedTrainStep.step`, on the program's own clock, which `report()`
+adds its time to and `TrainWorker.timeline()` hands to the run's
+timeline.json.
 """
 
 from __future__ import annotations
@@ -15,6 +20,7 @@ import dataclasses
 import queue
 import threading
 import time
+from collections import deque
 from typing import Any, Callable, Dict, Optional
 
 from ray_tpu.train.checkpoint import Checkpoint
@@ -22,6 +28,76 @@ from ray_tpu.util import tracing
 
 _session_lock = threading.Lock()
 _session: Optional["_TrainSession"] = None
+
+# The most rows of one kind a worker hands back for the run's timeline.
+TIMELINE_MAX_ROWS = 4096
+# A ledger row's flags: a profile was running when the step entered; a
+# counted step, whose span waits for the model's metrics; the previous
+# step's loss was ready when this one entered (the host, not the device,
+# set this step's start).
+STEP_PROFILED, STEP_SYNCED, STEP_DEVICE_DRY = 1, 2, 4
+
+
+class StepLedger:
+    """Every step of the process on its own clock, always on.  A row is
+    `[step, t_enter, dispatch_s, report_s, flags]`: the step's number, the
+    epoch time its `train.step` span stamps on its annotation as
+    `t_epoch`, the seconds inside that span's block (placing the inputs
+    and enqueueing the program), the seconds inside the `train.report`
+    blocks entered before the next step, and the flags above.  A step's
+    wall is the next row's `t_enter` less its own; what of it is neither
+    dispatch nor report is the loop's own time.  The ring keeps the newest
+    `TIMELINE_MAX_ROWS` rows and counts the rest as `dropped`; the totals
+    run over every step, so a long run costs what a short one does."""
+
+    def __init__(self, max_rows: int = TIMELINE_MAX_ROWS):
+        self._lock = threading.Lock()   # the loop's thread against a reader
+        self._rows: "deque[list]" = deque(maxlen=max_rows)
+        self._open: Optional[list] = None
+        self._dropped = 0
+        self._totals = {"steps": 0, "wall_s": 0.0, "dispatch_s": 0.0,
+                        "report_s": 0.0, "longest_wall_s": 0.0,
+                        "longest_wall_step": None}
+
+    def enter(self, step: int, t_enter: float, flags: int) -> list:
+        """A step has entered: its row, which closes the one before."""
+        row = [step, t_enter, 0.0, 0.0, flags]
+        totals = self._totals
+        with self._lock:
+            last = self._open
+            if last is not None:
+                wall = t_enter - last[1]
+                totals["wall_s"] += wall
+                if wall > totals["longest_wall_s"]:
+                    totals["longest_wall_s"] = wall
+                    totals["longest_wall_step"] = last[0]
+            if len(self._rows) == self._rows.maxlen:
+                self._dropped += 1
+            self._rows.append(row)
+            self._open = row
+            totals["steps"] += 1
+        return row
+
+    def dispatched(self, row: list, seconds: float) -> None:
+        row[2] = seconds
+        self._totals["dispatch_s"] += seconds
+
+    def reported(self, seconds: float) -> None:
+        """A `train.report` block's seconds go to the step before it; a
+        report with no step before it adds to no row."""
+        row = self._open
+        if row is not None:
+            row[3] += seconds
+            self._totals["report_s"] += seconds
+
+    def snapshot(self) -> Dict[str, Any]:
+        """{"rows", "dropped", "totals"}, as timeline.json holds them."""
+        with self._lock:
+            return {"rows": [list(r) for r in self._rows],
+                    "dropped": self._dropped, "totals": dict(self._totals)}
+
+
+step_ledger = StepLedger()
 
 
 @dataclasses.dataclass
@@ -70,11 +146,13 @@ class _TrainSession:
         # The queue holds one item: a driver that is slow to poll blocks
         # the loop here, and the span shows it.
         with tracing.trace_span("train.report"):
+            t = time.perf_counter()
             self._note_device_step(metrics)
             if checkpoint is not None and self.persist_checkpoint:
                 checkpoint = self.persist_checkpoint(checkpoint, metrics)
             self.result_queue.put({"metrics": dict(metrics),
                                    "checkpoint": checkpoint})
+            step_ledger.reported(time.perf_counter() - t)
 
     def _note_device_step(self, metrics: Dict[str, Any]) -> None:
         """Device-plane step hook (same accounting the serve engine's

@@ -22,6 +22,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import importlib
+import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -37,6 +38,7 @@ from ray_tpu.parallel.sharding import (
     data_sharding,
     tree_shardings,
 )
+from ray_tpu.train import session
 from ray_tpu.util import device_stats, tracing
 
 
@@ -182,6 +184,7 @@ class ShardedTrainStep:
                     static_argnames=("keep",)), "train.step")
         self._spanned: set = set()
         self._steps = 0
+        self._last_loss = None      # the step ledger's `device_dry` asks it
 
     def _model_losses(self, config):
         """(loss, loss with the model's own metrics or None) of the model
@@ -196,7 +199,7 @@ class ShardedTrainStep:
             if hasattr(model, "loss_and_metrics") else None)
 
     def _span(self, name: str, attrs: Optional[Dict[str, Any]] = None,
-              force: bool = False):
+              force: bool = False, entered: Optional[list] = None):
         """Host time to place the inputs and enqueue one program.  The
         FIRST call of each program holds its compile or cache load and is
         recorded whatever the tracing flag says (the start-up timeline
@@ -204,7 +207,8 @@ class ShardedTrainStep:
         flag, and a running profile."""
         first = name not in self._spanned
         self._spanned.add(name)
-        return tracing.trace_span(name, attrs, force=first or force)
+        return tracing.trace_span(name, attrs, force=first or force,
+                                  entered=entered)
 
     # -- init ---------------------------------------------------------------
     def _init_fn(self, rng):
@@ -300,6 +304,10 @@ class ShardedTrainStep:
                 beside = max(0, in_use - int(memory.argument_size_in_bytes))
             keep = limit is None or total is None or total + beside <= limit
         keep = self._everywhere(keep)
+        if keep and total is not None:
+            # the program that will run, temporaries and all: the HBM
+            # watermark's, which the allocator's peak leaves them out of
+            device_stats.note_program(total + beside)
         kept = "kept:" + (",".join(SAVE_ATTN_NAMES) if keep else "none")
         # the bytes are the FIRST rung's: what was held against the limit
         attrs.update(remat=kept, remat_program_bytes=total,
@@ -332,7 +340,18 @@ class ShardedTrainStep:
         # steps times.
         counted = (self._loss_and_metrics is not None
                    and self._steps & (self._steps - 1) == 0)
-        with self._span("train.step", attrs, force=counted):
+        # The step ledger's row (`session.StepLedger`), every step: the
+        # span's own clock reading, the block's seconds, and whether the
+        # last step's loss was ready already (asking never waits).
+        flags = session.STEP_SYNCED if counted else 0
+        if self._last_loss is not None and self._last_loss.is_ready():
+            flags |= session.STEP_DEVICE_DRY
+        entered: list = []
+        with self._span("train.step", attrs, force=counted, entered=entered):
+            t = time.perf_counter()
+            ledger = session.step_ledger
+            row = ledger.enter(self._steps, entered[0], flags | (
+                session.STEP_PROFILED if entered[1] else 0))
             batch = jax.device_put(batch, self.batch_sharding)
             with self._mesh_scope():
                 if self._keep is None:
@@ -341,6 +360,8 @@ class ShardedTrainStep:
             if counted:
                 attrs.update({k: float(v) for k, v in metrics.items()
                               if k not in _STEP_METRICS})
+            self._last_loss = metrics["loss"]
+            ledger.dispatched(row, time.perf_counter() - t)
             return state, metrics
 
     # -- eval ----------------------------------------------------------------

@@ -46,8 +46,9 @@ class Result:
     error: Optional[BaseException] = None
     metrics_history: List[Dict[str, Any]] = dataclasses.field(
         default_factory=list)
-    # Where the run's start-up went, as <path>/timeline.json holds it
-    # (`_write_timeline`); None when no attempt got as far as its loop.
+    # Where the run's start-up and each of its steps went, as
+    # <path>/timeline.json holds it (`_write_timeline`); None when no
+    # attempt got as far as its loop.
     timeline: Optional[Dict[str, Any]] = None
 
     @property
@@ -256,7 +257,8 @@ def _write_timeline(group, run_dir: str, since: float
     with every worker's (asked once, after the loops have ended), as
     span dicts (`tracing.span_row_to_dict`'s keys) with `worker` and
     `pid`; every `xla.compile` span apart; each process's `compile_totals`;
-    after a traced run, rank 0's `programs` (`TrainWorker.timeline`: that
+    each worker's step ledger under `steps` (`session.StepLedger`: one
+    row a step of the run, traced or not); after a traced run, rank 0's `programs` (`TrainWorker.timeline`: that
     worker compiles the step program's report then, from the cache, which
     is what the longer wait is for).  Written to
     <run_dir>/timeline.json beside the loggers' result.json and returned
@@ -270,12 +272,14 @@ def _write_timeline(group, run_dir: str, since: float
                  if s["start"] >= since
                  or s["name"] in tracing.RUNTIME_STARTUP_SPANS]
         totals = {"driver": device_stats.compile_totals()}
+        steps = {}
         for part in parts:
             spans.extend(part["spans"])
             totals[f"rank{part['rank']}"] = part["compile_totals"]
+            steps[f"rank{part['rank']}"] = part["steps"]
         doc = {"spans": [s for s in spans if s["name"] != "xla.compile"],
                "compiles": [s for s in spans if s["name"] == "xla.compile"],
-               "compile_totals": totals}
+               "compile_totals": totals, "steps": steps}
         programs = next((p["programs"] for p in parts if "programs" in p),
                         None)
         if programs:

@@ -19,14 +19,17 @@ from typing import Any, Callable, Dict, List, Optional
 import ray_tpu
 from ray_tpu.train.checkpoint import Checkpoint, StorageContext
 from ray_tpu.train import session as _session_mod
-from ray_tpu.train.session import TrainContext, _TrainSession
+from ray_tpu.train.session import (
+    TIMELINE_MAX_ROWS,
+    TrainContext,
+    _TrainSession,
+)
 from ray_tpu.util import device_stats, tracing
 
 # What a worker hands back for the run's timeline: the start-up phases,
-# the train programs' spans and every compile event.  Bounded: with
-# tracing enabled every step is in the ring.
+# the train programs' spans and every compile event.  Bounded
+# (`TIMELINE_MAX_ROWS`): with tracing enabled every step is in the ring.
 TIMELINE_PREFIXES = ("startup.", "train.", "xla.compile")
-TIMELINE_MAX_ROWS = 4096
 # The program a traced run's timeline reports on: `ShardedTrainStep`'s
 # name for its step (`device_stats.count_compiles`).
 STEP_PROGRAM = "train.step"
@@ -145,7 +148,8 @@ class TrainWorker:
     def timeline(self) -> Dict[str, Any]:
         """This worker's part of the run's timeline (`JaxTrainer.fit`
         asks once, after the loop has ended): its start-up, train and
-        compile spans, and `device_stats.compile_totals()`.  Rank 0 of a
+        compile spans, `device_stats.compile_totals()` and `steps`, the
+        process's step ledger (`session.StepLedger`).  Rank 0 of a
         TRACED run (tracing enabled here, or a profile ran in this
         process) adds `programs`: the step program's report, what a
         device trace's operations are joined to
@@ -153,7 +157,8 @@ class TrainWorker:
         nothing."""
         part = {"rank": self.rank,
                 "spans": timeline_spans(f"rank{self.rank}"),
-                "compile_totals": device_stats.compile_totals()}
+                "compile_totals": device_stats.compile_totals(),
+                "steps": _session_mod.step_ledger.snapshot()}
         if self.rank == 0 and (tracing.is_tracing_enabled()
                                or tracing.profile_seen()):
             try:

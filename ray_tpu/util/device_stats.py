@@ -57,6 +57,8 @@ _components: Dict[str, int] = {}
 
 _watermark_bytes = 0
 _watermark_fraction = 0.0
+# the most device bytes a program said it needs to run (`note_program`)
+_program_bytes = 0
 _last_step: Optional[Dict[str, Any]] = None
 
 _metrics_cache: Optional[Tuple[Any, Any, Any, Any]] = None
@@ -98,6 +100,7 @@ def enabled() -> bool:
 def reset() -> None:
     """Test hook: drop all per-process accumulated state."""
     global _watermark_bytes, _watermark_fraction, _last_step
+    global _program_bytes
     with _lock:
         _compiles.clear()
         _first_calls.clear()
@@ -105,6 +108,7 @@ def reset() -> None:
         _totals.update(_ZERO_TOTALS)
         _watermark_bytes = 0
         _watermark_fraction = 0.0
+        _program_bytes = 0
         _last_step = None
 
 
@@ -649,6 +653,19 @@ def attribute(component: str, nbytes: int) -> None:
         _components[component] = int(nbytes)
 
 
+def note_program(nbytes: int) -> None:
+    """The device bytes a program needs while it runs, with what the
+    device holds beside it: its `memory_analysis` total (`program_bytes`:
+    the TEMPORARIES too, which the allocator's `peak_bytes_in_use` leaves
+    out) plus the rest in use.  Whoever compiles a program and reads that
+    says so once, then, not a step (`ShardedTrainStep`'s first step); the
+    ledger's watermark is the larger of the most said here and the
+    allocator's peak."""
+    global _program_bytes
+    with _lock:
+        _program_bytes = max(_program_bytes, int(nbytes))
+
+
 def ledger(probe: bool = False) -> Dict[str, Any]:
     """The per-process HBM ledger.  ALWAYS returns a dict (CPU hosts
     get backend="cpu" with capacity from the attribution sum), so the
@@ -662,7 +679,8 @@ def ledger(probe: bool = False) -> Dict[str, Any]:
     if stats:
         used = int(stats.get("bytes_in_use", attributed))
         capacity = int(stats.get("bytes_limit", 0)) or used
-        peak = int(stats.get("peak_bytes_in_use", used))
+        # a running program's temporaries are in no allocator figure
+        peak = max(int(stats.get("peak_bytes_in_use", used)), _program_bytes)
     else:
         used = attributed
         capacity = _env_int("RAY_TPU_DEVICE_HBM_BYTES", 0) or used

@@ -257,7 +257,8 @@ def record_span(name: str, start: float, end: float,
 
 @contextmanager
 def trace_span(name: str, attributes: Optional[Dict[str, Any]] = None,
-               force: bool = False, start: Optional[float] = None):
+               force: bool = False, start: Optional[float] = None,
+               entered: Optional[list] = None):
     """Context manager for a nested span; cheap no-op when disabled.
     A caller-provided `attributes` dict is kept by identity, so fields
     added inside (or just after) the block land on the span.
@@ -266,11 +267,17 @@ def trace_span(name: str, attributes: Optional[Dict[str, Any]] = None,
     start-up timeline, a program's first call).  `start` is the epoch
     time the span began where that was before the block (process start,
     a caller's entry).  In a process that has loaded JAX the block also
-    runs under a "ray_tpu:<name>" profiler annotation (module docstring)."""
+    runs under a "ray_tpu:<name>" profiler annotation (module docstring).
+    A caller that keeps its own record of the block (the trainer's step
+    ledger) hands a list as `entered` and finds it `[t_epoch, profiled]`
+    inside: the span's own clock reading, the one the annotation carries,
+    and whether a profile was running."""
     global _profile_seen
     ann_cls = _annotation_cls()
     record = _enabled or force
     if not record and ann_cls is None:
+        if entered is not None:
+            entered[:] = (time.time(), False)
         yield None
         return
     span_id = parent = trace_id = None
@@ -282,15 +289,18 @@ def trace_span(name: str, attributes: Optional[Dict[str, Any]] = None,
     _names().append(name)
     t0 = time.time()
     ann = None
+    profiled = False
     if ann_cls is not None:
         # TraceMe encodes its keyword metadata only while a profile runs.
         meta = {}
         if ann_cls.is_enabled():
-            _profile_seen = True
+            _profile_seen = profiled = True
             meta = _scalars(attributes)
         ann = ann_cls(ANNOTATION_PREFIX + name,
                       **{**meta, "t_epoch": t0, "span_id": span_id or ""})
         ann.__enter__()
+    if entered is not None:
+        entered[:] = (t0, profiled)
     try:
         yield span_id
     finally:
@@ -453,15 +463,6 @@ def clear_spans() -> None:
         _spans.clear()
         _dropped_spans = 0
         _seq_end = 0
-
-
-def span_cursor() -> int:
-    """The cursor one past the newest recorded span (total spans ever
-    appended).  A harvester holding this value and calling
-    collect_spans_since(cursor) later gets exactly the spans recorded
-    in between."""
-    with _spans_lock:
-        return _seq_end
 
 
 def collect_spans_since(cursor: int, max_spans: int = 2048

@@ -45,6 +45,18 @@ module as `idle_in_program` and the compiled program's memory
 
     python scripts/opsdump.py --xplane t.xplane.pb \
         --timeline run/timeline.json --parts
+
+`--steps` prints the run's step ledger from timeline.json alone (every
+run has one, traced or not: `ray_tpu/train/session.py`): the steps' wall
+by the program's own clock (median, p99, longest; the first quarter's
+median against the last's: a drift), and every step over 1.25 x the
+median with where its time went (dispatch, report, compile, the loop's
+own) and its flags.  With `--xplane` beside it, each traced step's idle
+time on the device by the program's annotation that covers it:
+
+    python scripts/opsdump.py --timeline run/timeline.json --steps
+    python scripts/opsdump.py --timeline run/timeline.json --steps \
+        --xplane t.xplane.pb
 """
 
 from __future__ import annotations
@@ -61,6 +73,7 @@ from typing import Any, Dict, List, Optional, Tuple
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
+from ray_tpu.train import session  # noqa: E402
 from ray_tpu.util import journal  # noqa: E402
 from ray_tpu.util.tracing import (  # noqa: E402
     ANNOTATION_PREFIX,
@@ -377,6 +390,149 @@ def parts_table(xplane: str, timeline: str, top: int = 8) -> str:
     return "\n".join(lines)
 
 
+STEP_FLAG_NAMES = ((session.STEP_PROFILED, "profiled"),
+                   (session.STEP_SYNCED, "synced"),
+                   (session.STEP_DEVICE_DRY, "device_dry"))
+# a step is named in the table where its wall is over this many medians
+SLOW_STEP = 1.25
+STEP_ANNOTATIONS = (ANNOTATION_PREFIX + "train.step",
+                    ANNOTATION_PREFIX + "train.report")
+
+
+def _union(intervals: List[Tuple[float, float]]
+           ) -> List[Tuple[float, float]]:
+    out: List[Tuple[float, float]] = []
+    for a, b in sorted(intervals):
+        if out and a <= out[-1][1]:
+            out[-1] = (out[-1][0], max(out[-1][1], b))
+        else:
+            out.append((a, b))
+    return out
+
+
+def _within(merged: List[Tuple[float, float]], lo: float, hi: float
+            ) -> float:
+    """Seconds of the merged intervals that lie in [lo, hi]."""
+    return sum(max(0.0, min(b, hi) - max(a, lo)) for a, b in merged)
+
+
+def step_walls(rows: List[list], compiles: List[Tuple[float, float]]
+               ) -> List[Dict[str, Any]]:
+    """One record an interval of the ledger (a row to the next): the
+    wall, and its split.  Compile seconds are the union of the worker's
+    `xla.compile` spans inside the interval, taken out of dispatch where
+    they lie in the step's block and out of the loop's own elsewhere, so
+    that the four columns sum to the wall."""
+    merged = _union(compiles)
+    out = []
+    for (step, t, dispatch, report, flags), nxt in zip(rows, rows[1:]):
+        wall = nxt[1] - t
+        in_block = _within(merged, t, min(t + dispatch, nxt[1]))
+        outside = _within(merged, t, nxt[1]) - in_block
+        out.append({"step": step, "t_enter": t, "wall": wall, "flags": flags,
+                    "dispatch": dispatch - in_block, "report": report,
+                    "compile": in_block + outside,
+                    "own": wall - dispatch - report - outside})
+    return out
+
+
+def idle_by_annotation(planes: Dict[str, Dict[str, list]], offset_s: float,
+                       walls: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    """For each interval of `walls` the profile covers: the seconds the
+    first device ran no program in it, each gap put down to the program's
+    annotation (`STEP_ANNOTATIONS`) that covers its midpoint, else to
+    "neither"; all on the epoch clock (`clock_offset`)."""
+    device = sorted(p for p in planes if _DEVICE_PLANE.match(p))
+    if not device:
+        return []
+    busy = _union([(s / 1e9 + offset_s, (s + d) / 1e9 + offset_s)
+                   for evs in planes[device[0]].values() for _, s, d, _ in evs])
+    events = [(s / 1e9 + offset_s, (s + d) / 1e9 + offset_s, name)
+              for lines in planes.values() for evs in lines.values()
+              for name, s, d, _ in evs]
+    seen = (min(e[0] for e in events), max(e[1] for e in events))
+    notes = [e for e in events if e[2] in STEP_ANNOTATIONS]
+    out = []
+    for w in walls:
+        lo, hi = w["t_enter"], w["t_enter"] + w["wall"]
+        if lo < seen[0] or hi > seen[1]:
+            continue        # not wholly inside what the profile saw
+        idle = dict.fromkeys(STEP_ANNOTATIONS + ("neither",), 0.0)
+        edges = [lo] + [x for a, b in busy if b > lo and a < hi
+                        for x in (max(a, lo), min(b, hi))] + [hi]
+        for a, b in zip(edges[::2], edges[1::2]):
+            mid = (a + b) / 2
+            idle[next((n for s, e, n in notes if s <= mid <= e),
+                      "neither")] += b - a
+        out.append({"step": w["step"], "wall": w["wall"], "idle": idle})
+    return out
+
+
+def steps_table(timeline: str, planes: Optional[dict] = None,
+                offset_s: Optional[float] = None) -> str:
+    """A run's step ledger as text, a block a rank (module docstring)."""
+    with open(timeline) as f:
+        doc = json.load(f)
+    if not doc.get("steps"):
+        raise SystemExit(f"{timeline} holds no step ledger: the run's "
+                         "program wrote none (an earlier commit)")
+    ms = 1e3
+    lines: List[str] = []
+    for rank, led in sorted(doc["steps"].items()):
+        rows, tot = led["rows"], led["totals"]
+        walls = step_walls(rows, [
+            (c["start"], c["end"]) for c in doc.get("compiles", [])
+            if c.get("worker") == rank])
+        lines.append(
+            f"{rank}: {tot['steps']} steps ({len(rows)} rows kept, "
+            f"{led['dropped']} dropped); all steps: wall "
+            f"{tot['wall_s']:.3f} s, dispatch {tot['dispatch_s']:.3f}, "
+            f"report {tot['report_s']:.3f}, longest "
+            f"{tot['longest_wall_s'] * ms:.2f} ms at step "
+            f"{tot['longest_wall_step']}")
+        if len(walls) < 4:
+            continue
+        w = [x["wall"] for x in walls]
+        median = statistics.median(w)
+        p99 = statistics.quantiles(w, n=100, method="inclusive")[98]
+        quarter = len(w) // 4
+        lines.append(
+            f"  wall of a step, ms: median {median * ms:.2f}, p99 "
+            f"{p99 * ms:.2f}, longest {max(w) * ms:.2f}; "
+            f"first quarter's median "
+            f"{statistics.median(w[:quarter]) * ms:.2f}, last quarter's "
+            f"{statistics.median(w[-quarter:]) * ms:.2f}")
+        slow = [x for x in walls if x["wall"] > SLOW_STEP * median]
+        lines.append(f"  {len(slow)} steps over {SLOW_STEP} x the median"
+                     + (" (ms):" if slow else ""))
+        if slow:
+            lines.append(f"  {'step':>8}{'wall':>10}{'dispatch':>10}"
+                         f"{'report':>10}{'compile':>10}{'own':>10}  flags")
+        for x in slow:
+            flags = ",".join(n for bit, n in STEP_FLAG_NAMES
+                             if x["flags"] & bit)
+            lines.append(
+                f"  {x['step']:>8}" + "".join(
+                    f"{x[k] * ms:>10.2f}" for k in
+                    ("wall", "dispatch", "report", "compile", "own"))
+                + f"  {flags or '-'}")
+        if planes and offset_s is not None:
+            traced = idle_by_annotation(planes, offset_s, walls)
+            lines.append(f"  {len(traced)} steps inside the profile; the "
+                         "first device's idle time, ms, by the program's "
+                         "annotation over the gap's midpoint:")
+            if traced:
+                lines.append(f"  {'step':>8}{'wall':>10}{'train.step':>12}"
+                             f"{'train.report':>14}{'neither':>10}")
+            for x in traced:
+                lines.append(
+                    f"  {x['step']:>8}{x['wall'] * ms:>10.2f}"
+                    f"{x['idle'][STEP_ANNOTATIONS[0]] * ms:>12.3f}"
+                    f"{x['idle'][STEP_ANNOTATIONS[1]] * ms:>14.3f}"
+                    f"{x['idle']['neither'] * ms:>10.3f}")
+    return "\n".join(lines)
+
+
 def dump_stats(directory: str) -> Dict[str, Any]:
     out: Dict[str, Any] = {"dir": directory}
     for stream in STREAMS:
@@ -445,12 +601,19 @@ def main(argv=None) -> int:
                     help="print the step program's time by the model's "
                          "parts (needs --xplane and --timeline of a "
                          "traced run) instead of a trace")
+    ap.add_argument("--steps", action="store_true",
+                    help="print the run's step ledger (needs --timeline; "
+                         "with --xplane, each traced step's idle time on "
+                         "the device by the program's annotation) instead "
+                         "of a trace")
     args = ap.parse_args(argv)
     if args.parts:
         if not (args.xplane and args.timeline):
             ap.error("--parts needs --xplane and --timeline")
         print(parts_table(args.xplane, args.timeline))
         return 0
+    if args.steps and not args.timeline:
+        ap.error("--steps needs --timeline")
     if not (args.dir or args.xplane or args.timeline):
         ap.error("--dir required (or set RAY_TPU_OPS_JOURNAL_DIR), "
                  "unless --xplane or --timeline is given")
@@ -461,6 +624,10 @@ def main(argv=None) -> int:
     if args.xplane:
         planes = read_xplane(args.xplane)
         offset = clock_offset(planes)
+    if args.steps:
+        print(steps_table(args.timeline, planes,
+                          offset[0] if offset else None))
+        return 0
     if args.stats:
         stats = dump_stats(args.dir) if args.dir else {}
         if args.xplane:

@@ -151,6 +151,47 @@ def test_hbm_ledger_with_fake_memory_stats(monkeypatch):
     assert samp["components"]["weights"] == 6 * GB
 
 
+@pytest.mark.parametrize("program, watermark", [
+    (0, 9 * GB),                # none said: the allocator's peak
+    (15 * GB, 15 * GB),         # a program over the allocator's peak
+    (5 * GB, 9 * GB),           # .. and one under it
+])
+def test_the_watermark_counts_a_running_programs_temporaries(
+        monkeypatch, program, watermark):
+    """`peak_bytes_in_use` leaves out what a program needs WHILE it runs
+    (8.73 GB read after steps of a 16.79 GB program): the watermark is the
+    larger of the allocator's peak and what `note_program` was told."""
+    device_stats.reset()
+    monkeypatch.setattr(device_stats, "memory_stats", lambda: {
+        "bytes_in_use": 8 * GB, "bytes_limit": 16 * GB,
+        "peak_bytes_in_use": 9 * GB})
+    if program:
+        device_stats.note_program(program)
+    led = device_stats.ledger()
+    assert led["watermark_bytes"] == watermark
+    assert led["watermark_fraction"] == pytest.approx(watermark / (16 * GB))
+    # the most a program ever said stands, and what the allocator reads
+    # (`used_bytes`, `workspace_bytes`) is not moved by it
+    device_stats.note_program(1 * GB)
+    assert device_stats.ledger()["watermark_bytes"] == watermark
+    assert led["used_bytes"] == 8 * GB
+    device_stats.reset()
+    assert device_stats.ledger()["watermark_bytes"] == 9 * GB
+
+
+def test_a_host_that_reports_no_memory_counts_no_program(monkeypatch):
+    """The CPU: capacity is the attribution sum there, and a program's
+    bytes against it would be a fraction of nothing."""
+    device_stats.reset()
+    monkeypatch.setattr(device_stats, "memory_stats", lambda: None)
+    device_stats.attribute("weights", 1 * GB)
+    device_stats.note_program(4 * GB)
+    led = device_stats.ledger()
+    assert led["watermark_bytes"] == 1 * GB
+    assert led["watermark_fraction"] == pytest.approx(1.0)
+    device_stats.reset()
+
+
 # ---------------------------------------------------------------------------
 # Continuous roofline/MFU step hook
 # ---------------------------------------------------------------------------

@@ -166,6 +166,7 @@ def test_what_else_the_device_holds_counts_against_the_limit(monkeypatch,
     ts, state, batch = _step(_config())
     ts.step(state, batch)
     used, arguments = _used(_record()), _argument_bytes()
+    device_stats.reset()        # the watermark that first program left
     monkeypatch.setattr(dispatch, "_taken", {})
     tracing.clear_spans()
     beside = 12345
@@ -177,6 +178,10 @@ def test_what_else_the_device_holds_counts_against_the_limit(monkeypatch,
                          f"program{used}of{used + beside - over},"
                          f"beside{beside}")
     assert _first_span()["attributes"]["remat_beside_bytes"] == beside
+    # the rung that runs is what the HBM watermark counts, temporaries and
+    # all; a refused first rung is not the program that runs
+    assert device_stats.ledger()["watermark_bytes"] == (
+        arguments + beside if over else used + beside)
 
 
 def test_the_reading_is_of_the_meshs_devices_the_least_limit(monkeypatch):
