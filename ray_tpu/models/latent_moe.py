@@ -84,6 +84,7 @@ import numpy as np
 from ray_tpu.models import common, moe, stack
 from ray_tpu.models.swa_moe import _frozen, yarn_inv_freq
 from ray_tpu.models.transformer import rms_norm
+from ray_tpu.ops import dispatch
 from ray_tpu.parallel.sharding import with_logical_constraint
 
 F32 = jnp.float32
@@ -444,8 +445,15 @@ def _layer(x, lp, tables, *, kind: str, c: LatentMoEConfig):
         # makes attention's arrays again at attention's OWN backward: the
         # feed-forward's backward comes first and holds three copies of the
         # stream beside the routed buffers, and attention's 0.4 GiB (one
-        # row of 8192 tokens) waiting beside them are what does not fit
-        attention = jax.checkpoint(attention)
+        # row of 8192 tokens) waiting beside them are what does not fit.
+        # Of those the flash kernel's out and lse (65 MiB) do fit, and are
+        # kept, in ONE layer at a time: from the layer's forward made again
+        # inside its backward to its attention's backward, which then makes
+        # the projections a third time (q, kv and the rotary key, 0.33 GiB)
+        # and runs no flash forward
+        attention = common.maybe_remat(attention, True, "save_attn")
+        dispatch.record("latent_moe.attention_checkpoint",
+                        "kept:" + ",".join(common.SAVE_ATTN_NAMES))
     mixes = [] if kind == "moe" and c.hc_mult else None
     x = stack.residual(x, attention, lp, "hc_attn", c, mixes)
     x = stack.residual(x, feed_forward, lp, "hc_ffn", c, mixes)

@@ -123,7 +123,15 @@ class ShardedTrainStep:
     arguments (`bytes_in_use` at that moment, the state and the batch taken
     off).  That program is the step if it compiles and fits; where the
     compiler refuses it (RESOURCE_EXHAUSTED) or it does not fit, the step
-    is the bare `jax.checkpoint` program.  The reading is the least limit
+    is the bare `jax.checkpoint` program: nothing is kept across a LAYER's
+    checkpoint.  A model may still keep the two arrays INSIDE a layer's
+    backward: on that rung a stream of lanes (`models/latent_moe._layer`)
+    puts attention under a checkpoint of its own, which keeps out and lse
+    from the layer's forward made again to attention's backward, one
+    layer's pair at a time, so that the rung runs the flash forward twice a
+    layer and not three times
+    (`dispatch.taken()["latent_moe.attention_checkpoint"]`).  The reading
+    is the least limit
     and the most in use over this process's devices of the mesh, and the
     decision is ONE for the mesh's processes: all keep, or none does.  A
     backend with no `bytes_limit` (the CPU) takes the first program:

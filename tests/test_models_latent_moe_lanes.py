@@ -13,6 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from attention_cases import _pallas_calls
 from benchmark.reference import xing4_hc_mla_moe as xref
 from ray_tpu.models import latent_moe as lm, stack
 from test_models_latent_moe import (  # noqa: F401 (the fixture is autouse)
@@ -120,13 +121,30 @@ def test_lanes_and_the_second_loss_match_the_reference(xing):
     assert {"moe_rows_held", "moe_load_max"} <= set(metrics)
 
 
-def test_lanes_and_the_second_loss_gradient_matches_the_reference(xing):
+def _loss_grad(xing, config):
+    """jax.grad of the program's mean objective on the fixture's weights and
+    row, under `config`."""
+    _, params, tokens, _ = xing
+    return jax.jit(jax.grad(lambda p: lm.loss_fn(
+        p, {"tokens": jnp.asarray(tokens)}, config)))(params)
+
+
+@pytest.fixture(scope="module")
+def plain_grad(xing):
+    """The gradient with no checkpoint anywhere (the fixture's own config):
+    the reference's case and the lowest rung's share the one compile."""
+    with pytest.MonkeyPatch.context() as mp:    # module scope: before autouse
+        mp.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+        return _loss_grad(xing, xing[0])
+
+
+def test_lanes_and_the_second_loss_gradient_matches_the_reference(
+        xing, plain_grad):
     """jax.grad of the program's mean objective against the reference's
     walked back a sublayer at a time: the stack, the collapse, the block's
     group "mtp" and the embedding and the head, which both losses reach."""
     config, params, tokens, run = xing
-    got = jax.jit(jax.grad(lambda p: lm.loss_fn(
-        p, {"tokens": jnp.asarray(tokens)}, config)))(params)
+    got = plain_grad
     seen = set()
     for path, grad in run.grads(tokens[0, 1:]):
         for name, want in (grad.items() if isinstance(grad, dict)
@@ -146,6 +164,94 @@ def test_lanes_and_the_second_loss_gradient_matches_the_reference(xing):
         for path, leaf in leaves)
     assert ("mtp", "w_eh", None) in seen and ("hc_head", "w", None) in seen
     assert ("mtp", "hc_head", "scale", None) in seen
+
+
+@pytest.fixture(scope="module")
+def lowest_rung(xing):
+    """ONE pass for the two cases below, on the ladder's lowest rung
+    (`remat_policy="full"`: a layer's checkpoint keeps nothing).  name ->
+    (the loss's gradient, how often the jaxpr of ONE expert layer's vjp under
+    the layer's checkpoint calls the flash forward and the flash backward,
+    what the traces left under `latent_moe.attention_checkpoint`), for the
+    program "as_is" and for the "bare" form it had before PR 55, attention's
+    inner checkpoint keeping nothing: `SAVE_ATTN_NAMES` emptied, and
+    `stack.layer_fn`'s cache emptied round it, since `jax.checkpoint` finds a
+    layer's trace again by the function's identity."""
+    from ray_tpu.models import common
+    from ray_tpu.ops import dispatch
+
+    base, params, _, _ = xing
+    config = dataclasses.replace(base, remat=True, remat_policy="full")
+    lp = jax.tree.map(lambda a: a[0], params["layers"]["seg01"]["0"])
+    x = jax.random.normal(jax.random.PRNGKey(30),
+                          (1, 128, config.hc_mult * config.hidden_size))
+    tables = lm._tables(128, config)
+
+    def rung():
+        layer = stack.layer_fn(lm._layer, "moe", config)
+        before = dispatch.taken().get("latent_moe.attention_checkpoint", {})
+        vjp = jax.make_jaxpr(jax.grad(
+            lambda x, lp: jnp.sum(layer(x, lp, tables)[0] ** 2),
+            argnums=(0, 1)))(x, lp).jaxpr
+        grad = _loss_grad(xing, config)
+        after = dispatch.taken()["latent_moe.attention_checkpoint"]
+        kernels = [call.params["name"] for call in _pallas_calls(vjp)]
+        return (grad, kernels.count("flash_fwd"), kernels.count("flash_bwd"),
+                {k: n - before.get(k, 0) for k, n in after.items()
+                 if n > before.get(k, 0)})
+
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:    # module scope: before autouse
+        mp.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+        stack.layer_fn.cache_clear()
+        out["as_is"] = rung()
+        mp.setattr(common, "SAVE_ATTN_NAMES", ())
+        stack.layer_fn.cache_clear()
+        out["bare"] = rung()
+    stack.layer_fn.cache_clear()
+    return out
+
+
+def test_the_lowest_rung_keeps_the_gradients_bits(plain_grad, lowest_rung):
+    """Out and lse kept across attention's inner checkpoint are the kernel's
+    own outputs: the gradient is, leaf for leaf and bit for bit, the one the
+    bare inner checkpoint gave.  Against NO checkpoint it is float32's
+    rounding away and no bit for bit: XLA's CPU fusions differ between the
+    two programs (the bare form reads the same 1.6e-6 of a leaf's largest
+    entry)."""
+    got, bare = lowest_rung["as_is"][0], lowest_rung["bare"][0]
+    assert jax.tree.structure(got) == jax.tree.structure(bare) \
+        == jax.tree.structure(plain_grad)
+    for (path, g), b, plain in zip(
+            jax.tree_util.tree_flatten_with_path(got)[0],
+            jax.tree.leaves(bare), jax.tree.leaves(plain_grad)):
+        g, plain = np.asarray(g), np.asarray(plain)
+        assert np.array_equal(g, np.asarray(b)), path
+        assert np.abs(g - plain).max() <= 1e-5 * np.abs(plain).max(), path
+    assert len(jax.tree.leaves(got)) == 77
+
+
+def test_the_lowest_rung_runs_the_flash_forward_twice_a_layer(xing,
+                                                              lowest_rung):
+    """A layer's vjp under the layer's checkpoint: the forward, the layer's
+    remat, and at attention's backward NO third flash forward (the bare inner
+    checkpoint's); `dispatch.taken()` says that the mechanism engaged, once a
+    kind of layer.  One lane, or no remat: the branch is not taken."""
+    from ray_tpu.ops import dispatch
+
+    _, forwards, backwards, taken = lowest_rung["as_is"]
+    assert (forwards, backwards) == (2, 1)
+    # a trace a kind of layer: the vjp's is the loss's expert layers' too
+    assert taken == {"kept:attn_out,attn_lse": 2}
+    assert lowest_rung["bare"][1:] == (3, 1, {"kept:": 2})
+    before = dispatch.taken()["latent_moe.attention_checkpoint"]
+    config, params, _, _ = xing
+    one_lane = dataclasses.replace(_f32(), remat=True, remat_policy="full")
+    tokens = jnp.zeros((1, 128), jnp.int32)
+    for c, leaves in ((config, params), (one_lane, lm.init_params(
+            one_lane, jax.random.PRNGKey(31)))):
+        jax.make_jaxpr(lambda p: lm._stream(p, tokens, c)[0])(leaves)
+    assert dispatch.taken()["latent_moe.attention_checkpoint"] == before
 
 
 def test_the_probe_runs_the_lanes_alone_on_the_references_operands(xing):

@@ -22,15 +22,21 @@ from aot import (_chip_bytes, _kernel_op_names, _scope_pattern, config_doc,
 CONFIG = "xing4.0-29b-a4b-train-d5e8.json"
 
 # `_step_fn`'s static arguments: the ladder's LOWEST rung, nothing kept across
-# a layer's checkpoint (the first rung's program reads 16.44 GiB, over)
+# a LAYER's checkpoint (the first rung's program, out and lse of every layer
+# held from the forward to the backward, reads 16.44 GiB, over).  Inside a
+# layer's backward attention's own checkpoint keeps the flash kernel's out and
+# lse, one layer's 65 MiB at a time (`latent_moe._layer`, PR 55)
 STEP_STATIC = {"keep": False}
 
 
 # sha256 of the step program's optimised HLO, `aot._metadata_stripped`, as PR
-# 52's tree compiled it: `aot.hlo_is_as_recorded` has the rule (a change that
-# means to move the program replaces the digest and says so).
+# 55's tree compiled it: `aot.hlo_is_as_recorded` has the rule (a change that
+# means to move the program replaces the digest and says so).  PR 55 MEANT to
+# move it: attention's inner checkpoint keeps `attn_out` and `attn_lse`, so a
+# layer's backward runs no third flash forward (PR 52's digest was
+# 2955a191fd5b3381f5cf7c59d09625d964ff12b4c45191955f287a6bae6c9eaf).
 PARENT_HLO_SHA256 = (
-    "2955a191fd5b3381f5cf7c59d09625d964ff12b4c45191955f287a6bae6c9eaf")
+    "7434dd6485cc8065b6636ffa9aba3329ddc96ed221b03a20fd1fd85cc04f9fba")
 
 
 def test_cell_mhc_optimised_hlo_is_as_this_pr_compiled_it(step_program):
@@ -50,12 +56,14 @@ def test_cell_mhc_step_program_fits_a_v5e(step_program):
     """The whole step program (a dense layer, four expert layers of 8 held
     experts of 1024, an eighth of the untied vocabulary, a stream of four
     lanes, 1 x 8192 tokens, fused CE, bfloat16 moments) by AOT
-    memory_analysis: under 15.75 GiB with 0.45 of room for what stands
-    beside it on the chip (0.3), and over 13 (the state is 9.9 at 14 B)."""
+    memory_analysis: 15.295 GiB (PR 55; 15.219 before attention's inner
+    checkpoint kept out and lse), under 15.75 with 0.4 of room for what
+    stands beside it on the chip (0.3), and over 13 (the state is 9.9 at 14
+    B)."""
     compiled, _, tr, _ = step_program
     assert tr["batch_rows"] == 1 and tr["sequence_length"] == 8192
     total = _chip_bytes(compiled)
-    assert 13.0 * 2 ** 30 < total < 15.3 * 2 ** 30, total / 2 ** 30
+    assert 13.0 * 2 ** 30 < total < 15.35 * 2 ** 30, total / 2 ** 30
 
 
 # what the step program's trace left in `dispatch.taken()`; the grouped
@@ -85,6 +93,13 @@ def test_cell_mhc_takes_every_kernel(step_program, op):
     assert set(step_program[1][op]) == {"pallas"}
 
 
+def test_cell_mhc_attentions_own_checkpoint_keeps_out_and_lse(step_program):
+    """What `latent_moe._layer` recorded as it was traced: the mechanism
+    engaged in both bodies of a layer (the dense layer, the scan's)."""
+    assert step_program[1]["latent_moe.attention_checkpoint"] == {
+        "kept:attn_out,attn_lse": 2}
+
+
 def _found(compiled):
     """name -> the kernel calls' `op_name`s that the cell's faces find
     (benchmark/hc_faces.py)."""
@@ -109,18 +124,20 @@ def _found(compiled):
 # forward and again under remat, `hc_post` likewise less remat's last
 # (nothing of the backward reads the layer's output), each backward once; the
 # collapse is a forward and a backward `hc_pre`.  The flash forward runs
-# THREE times a body on this rung: forward, the layer's remat, and attention's
-# own inner checkpoint at its backward (`latent_moe._layer`).  The dense
-# body's outer checkpoint (`latent_moe._stream`) adds no call: all it makes
-# again is the embedding's copies.  The expert body: the three grouped faces
-# forward, under remat and in the backward's own checkpoint, on each side of
-# the buffer's conditional; transposed and dw.
+# TWICE a body on this rung: forward and the layer's remat.  Attention's own
+# inner checkpoint (`latent_moe._layer`) keeps the kernel's out and lse from
+# the layer's remat to attention's backward, which makes the projections a
+# third time and calls no forward kernel (PR 55; three calls a body before).
+# The dense body's outer checkpoint (`latent_moe._stream`) adds no call: all
+# it makes again is the embedding's copies.  The expert body: the three
+# grouped faces forward, under remat and in the backward's own checkpoint, on
+# each side of the buffer's conditional; transposed and dw.
 KERNELS = {
     "hc_pre_fwd": (2 * 4 + 1, 2 * 2, "resid.mix"),
     "hc_post_fwd": (2 * 3, 2, "resid.mix"),
     "hc_pre_bwd": (2 * 2 + 1, 0, "resid.mix"),
     "hc_post_bwd": (2 * 2, 0, "resid.mix"),
-    "flash_fwd": (2 * 3, 2 * 2, "attn.full"),
+    "flash_fwd": (2 * 2, 2 * 1, "attn.full"),
     "flash_bwd": (2, 0, "attn.full"),
     "forward": (18, 12, "moe.experts"),
     "transposed": (6, 0, "moe.experts"),
